@@ -325,4 +325,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from benchmarks.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,0 +1,849 @@
+"""chip_smoke.py — the main path once, on the chip, in one process.
+
+Drives training (`deepspeed_tpu.initialize` + `train_batch`), v1 serving
+(`init_inference(...).generate`) and v2 continuous batching
+(`InferenceEngineV2.generate`) at the full width of Qwen2.5-3B (hidden 2048,
+FFN 11008, 16 heads / 2 KV heads, vocab 151936; weights from `--seed`), and
+every Pallas kernel an engine can select against its `jax.numpy` reference
+at those shapes. Serving runs all 36 layers; training cuts depth to what one
+16 GB chip holds with fp32 master + Adam moments.
+
+    python chip_smoke.py                       # one chip (what the driver runs)
+    python chip_smoke.py --chips 4             # dp2 x tp2 train and tp2 generate,
+                                               # each against one chip, nothing else
+    JAX_PLATFORMS=cpu python chip_smoke.py --tiny --rehearsal   # control flow only
+
+One JSON object per phase, then the last line:
+`{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}`.
+Exit code 0 only if every phase passed on a TPU (or, with `--rehearsal`, on
+whatever backend there is — the last line still names it truthfully).
+`--tiny` changes sizes and nothing else. Without `--rehearsal` a non-TPU
+backend prints no result and exits 2.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import dataclasses
+import json
+import os
+import sys
+import time
+import traceback
+from typing import Any, Callable, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+# bf16 inputs round on the MXU, so kernel and XLA reference accumulate
+# differently on the chip (CLAUDE.md, tests/unit/ops/test_flash_attention.py)
+FWD_TOL, BWD_TOL = 2e-2, 1e-1
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Everything `--tiny` changes. Widths come from the preset; the rest
+    is batch, length and depth."""
+    preset: str
+    train_layers: int          # depth cut, training only
+    seq: int                   # training sequence length
+    loss_chunk: int            # chunked cross-entropy rows
+    micro_batch: int
+    global_batch: int          # sequences per step, whatever the mesh
+    prompt_lens: Tuple[int, int]   # v1 runs one batch per length; v2 mixes them
+    prompts_per_len: int
+    new_tokens: int
+    v2_slots: int              # fewer than the requests: sequences join and leave
+    v2_max_seq: int
+    v2_chunk: int              # split-fuse chunk; the long prompts span two
+    block: int                 # KV block size
+    # kernel-case shapes beyond the model's own widths
+    flash_long: int
+    decode_batch: int
+    decode_ctx: int
+    paged_batch: int
+    paged_blocks: int
+    prefill_batch: int
+    gmm_rows: int
+    gmm_experts: int
+    gmm_width: int
+    qmm_group: int
+
+
+FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
+             micro_batch=2, global_batch=8, prompt_lens=(96, 352),
+             prompts_per_len=4, new_tokens=32, v2_slots=4, v2_max_seq=1024,
+             v2_chunk=256, block=256, flash_long=32768, decode_batch=32,
+             decode_ctx=1024, paged_batch=64, paged_blocks=96,
+             prefill_batch=8, gmm_rows=4096, gmm_experts=64, gmm_width=1024,
+             qmm_group=256)
+TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
+             micro_batch=2, global_batch=8, prompt_lens=(8, 24),
+             prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
+             v2_chunk=16, block=16, flash_long=128, decode_batch=2,
+             decode_ctx=64, paged_batch=3, paged_blocks=9, prefill_batch=2,
+             gmm_rows=64, gmm_experts=4, gmm_width=32, qmm_group=32)
+
+
+def emit(obj: Dict[str, Any]) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def memory_stat(key: str, devices=None) -> List[int]:
+    """`memory_stats()[key]` of each device (0 where the backend reports
+    none). `peak_bytes_in_use` is a process-lifetime high-water mark: later
+    phases only raise it. `bytes_in_use`, read while an engine is live,
+    shows a mesh whose state all sits on device 0."""
+    return [int((d.memory_stats() or {}).get(key, 0))
+            for d in (jax.devices() if devices is None else devices)]
+
+
+def check_every_device_holds(out: Dict[str, Any]) -> None:
+    held = out["device_bytes_in_use"]
+    if any(held) and not all(held):  # a backend without stats reports zeros
+        raise AssertionError(f"a device of the mesh holds nothing: {held}")
+
+
+def rel_err(got, ref) -> float:
+    """max |got - ref| over max |ref|, NaN-propagating, in float32."""
+    errs = []
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        g = np.asarray(g, np.float32)
+        r = np.asarray(r, np.float32)
+        if g.shape != r.shape:
+            return float("inf")
+        errs.append(float(np.abs(g - r).max() / (np.abs(r).max() + 1e-9)))
+    return max(errs)
+
+
+# ------------------------------------------------------------- kernel cases
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelCase:
+    """One Pallas kernel at one shape: `fn` is the kernel as an engine
+    calls it, `ref` the same math in `jax.numpy`, `make` a traceable
+    `PRNGKey -> inputs` (so `jax.eval_shape(make, key)` gives the shapes
+    tests/unit/ops/test_chip_compile.py compiles for the described chip)."""
+    name: str
+    fn: Callable
+    ref: Callable
+    make: Callable
+    tol: float = FWD_TOL
+
+
+def kernel_cases(sz: Sizes) -> List[KernelCase]:
+    from deepspeed_tpu.inference.kv_cache import (dequantize_kv,
+                                                  quantize_kv_tokens)
+    from deepspeed_tpu.models.qwen2 import qwen2_config
+    from deepspeed_tpu.ops.attention import (blockwise_attention,
+                                             reference_attention)
+    from deepspeed_tpu.ops.pallas.block_sparse_attention import (
+        block_sparse_attention, padded_layout_indices)
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_prefill_attention)
+    from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
+    from deepspeed_tpu.ops.quantization import (dequantize_int8_blockwise,
+                                                quantize_int8_blockwise)
+    from deepspeed_tpu.ops.sparse_attention import BigBirdSparsityConfig
+
+    cfg = qwen2_config(sz.preset)
+    h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    hidden, ffn = cfg.hidden_size, cfg.intermediate_size
+    bf16 = jnp.bfloat16
+    cases: List[KernelCase] = []
+
+    def normal(key, shape, dtype=bf16):
+        return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+
+    # ---- flash attention (training; GQA) ----
+    def qkv(b, s):
+        def make(key):
+            kq, kk, kv = jax.random.split(key, 3)
+            return (normal(kq, (b, s, h, d)), normal(kk, (b, s, hkv, d)),
+                    normal(kv, (b, s, hkv, d)))
+        return make
+
+    def flash_loss(attn):
+        # a fixed non-uniform cotangent, so dq/dk/dv are not degenerate
+        def loss(q, k, v):
+            out = attn(q, k, v, causal=True).astype(jnp.float32)
+            return jnp.sum(out * jnp.cos(jnp.arange(d, dtype=jnp.float32)))
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    cases += [
+        KernelCase(f"flash_fwd_s{sz.seq}",
+                   lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   lambda q, k, v: reference_attention(q, k, v, causal=True),
+                   qkv(sz.micro_batch, sz.seq)),
+        KernelCase(f"flash_fwd_bwd_s{sz.seq}", flash_loss(flash_attention),
+                   flash_loss(reference_attention),
+                   qkv(sz.micro_batch, sz.seq), tol=BWD_TOL),
+        # the (S, S) logits of the plain reference do not fit at 32k: the
+        # blockwise online-softmax form is the jax.numpy reference there
+        KernelCase(f"flash_fwd_s{sz.flash_long}",
+                   lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   lambda q, k, v: blockwise_attention(q, k, v, causal=True),
+                   qkv(1, sz.flash_long)),
+    ]
+
+    # ---- dense decode (v1): one query per row over a padded cache ----
+    db, dm = sz.decode_batch, sz.decode_ctx
+
+    def make_decode(key):
+        kq, kk, kv, kl = jax.random.split(key, 4)
+        return (normal(kq, (db, 1, h, d)), normal(kk, (db, dm, hkv, d)),
+                normal(kv, (db, dm, hkv, d)),
+                jax.random.randint(kl, (db,), 1, dm + 1, jnp.int32))
+
+    def decode_ref(q, k, v, lengths):
+        mask = jnp.arange(k.shape[1])[None, None, :] < lengths[:, None, None]
+        return reference_attention(q, k, v, causal=False, segment_mask=mask)
+
+    def int8_kv(kernel, ref):
+        """(kernel, reference) over int8-at-rest K/V: the kernel gets the
+        quantized values and their per-token scales, the reference their
+        dequantized form. Works for the dense caches and the paged pools
+        alike: both quantize per (token, kv-head) over the head dim."""
+        def fn(q, k, v, *rest):
+            (kq, ks), (vq, vs) = quantize_kv_tokens(k), quantize_kv_tokens(v)
+            return kernel(q, kq, vq, *rest, k_scales=ks, v_scales=vs)
+
+        def fn_ref(q, k, v, *rest):
+            deq = lambda x: dequantize_kv(*quantize_kv_tokens(x), q.dtype)
+            return ref(q, deq(k), deq(v), *rest)
+        return fn, fn_ref
+
+    cases += [
+        KernelCase("decode_bf16", decode_attention, decode_ref, make_decode),
+        KernelCase("decode_int8kv", *int8_kv(decode_attention, decode_ref),
+                   make_decode),
+    ]
+
+    # ---- paged decode / prefill (v2): block tables over a shared pool ----
+    bs, nb, t = sz.block, sz.paged_blocks, sz.v2_max_seq // sz.block
+
+    def make_paged(batch, s):
+        def make(key):
+            kq, kk, kv, kt, kl, kn = jax.random.split(key, 6)
+            # rows may share physical blocks: the kernels only read them
+            tables = jax.random.randint(kt, (batch, t), 0, nb, jnp.int32)
+            # decode: valid tokens per row; prefill: where the s new start
+            cursor = jax.random.randint(kl, (batch,), 1, t * bs - s + 1,
+                                        jnp.int32)
+            new = normal(kn, (2, batch, hkv, d))
+            return (normal(kq, (batch, s, h, d)), normal(kk, (hkv, nb, bs, d)),
+                    normal(kv, (hkv, nb, bs, d)), tables, cursor, new)
+        return make
+
+    def dense_view(pool, tables):
+        # (Hkv, NB, BS, D) through (B, T) tables -> (B, T*BS, Hkv, D)
+        rows = jnp.take(pool, tables, axis=1)        # (Hkv, B, T, BS, D)
+        rows = jnp.moveaxis(rows, 0, 3)              # (B, T, BS, Hkv, D)
+        return rows.reshape(tables.shape[0], -1, pool.shape[0], pool.shape[3])
+
+    def paged_ref(q, kp, vp, tables, lengths, new, staged=False):
+        k, v = dense_view(kp, tables), dense_view(vp, tables)
+        if staged:  # the row's last valid token is the staged one
+            rows = jnp.arange(q.shape[0])
+            k = k.at[rows, lengths - 1].set(new[0])
+            v = v.at[rows, lengths - 1].set(new[1])
+        return decode_ref(q, k, v, lengths)
+
+    def prefill_ref(q, kp, vp, tables, starts, new):
+        k, v = dense_view(kp, tables), dense_view(vp, tables)
+        pos = starts[:, None] + jnp.arange(q.shape[1])[None, :]     # (B, S)
+        mask = jnp.arange(k.shape[1])[None, None, :] <= pos[:, :, None]
+        return reference_attention(q, k, v, causal=False, segment_mask=mask)
+
+    def paged_decode(q, kp, vp, tables, lengths, new, **scales):
+        return paged_decode_attention(q, kp, vp, tables, lengths, **scales)
+
+    def paged_prefill(q, kp, vp, tables, starts, new, **scales):
+        return paged_prefill_attention(q, kp, vp, tables, starts, **scales)
+
+    pb, fb = sz.paged_batch, sz.prefill_batch
+    cases += [
+        KernelCase("paged_decode_bf16", paged_decode, paged_ref,
+                   make_paged(pb, 1)),
+        KernelCase("paged_decode_staged",
+                   lambda q, kp, vp, tb, ln, new: paged_decode_attention(
+                       q, kp, vp, tb, ln, k_new=new[0], v_new=new[1]),
+                   lambda *a: paged_ref(*a, staged=True), make_paged(pb, 1)),
+        KernelCase("paged_decode_int8kv", *int8_kv(paged_decode, paged_ref),
+                   make_paged(pb, 1)),
+    ]
+    # the two slowest to compile (8 s each for the described chip): last, so
+    # a test window that closes early has seen the other twenty-two
+    slow_cases = [
+        KernelCase("paged_prefill_bf16", paged_prefill, prefill_ref,
+                   make_paged(fb, sz.v2_chunk)),
+        KernelCase("paged_prefill_int8kv",
+                   *int8_kv(paged_prefill, prefill_ref),
+                   make_paged(fb, sz.v2_chunk)),
+    ]
+
+    # ---- fused int8 dequant-GEMM: every projection shape, three M ----
+    def make_qmm(m, k, n):
+        def make(key):
+            kx, kw = jax.random.split(key)
+            w = jax.random.normal(kw, (k, n), jnp.float32)
+            return (normal(kx, (m, k)),) + quantize_int8_blockwise(
+                w, sz.qmm_group)
+        return make
+
+    def qmm_ref(x, q, s):
+        w = dequantize_int8_blockwise(q, s)
+        return (x.astype(jnp.float32) @ w).astype(x.dtype)
+
+    for k, n in ((hidden, h * d), (hidden, hkv * d), (hidden, ffn),
+                 (ffn, hidden)):
+        for m in (1, 32, 256):
+            cases.append(KernelCase(f"qmm_{k}x{n}_m{m}", quantized_matmul,
+                                    qmm_ref, make_qmm(m, k, n)))
+
+    # ---- megablox grouped GEMM (MoE experts) ----
+    rows, ne, width = sz.gmm_rows, sz.gmm_experts, sz.gmm_width
+
+    def make_gmm(key):
+        kl, kr = jax.random.split(key)
+        return (normal(kl, (rows, hidden)),
+                normal(kr, (ne, hidden, width)) * 0.05,
+                jnp.full((ne,), rows // ne, jnp.int32))
+
+    def gmm_ref(lhs, rhs, sizes):
+        # equal groups: rows of group g are a contiguous slab
+        out = jnp.einsum("gmk,gkn->gmn", lhs.reshape(ne, rows // ne, hidden),
+                         rhs, preferred_element_type=jnp.float32)
+        return out.reshape(rows, width).astype(lhs.dtype)
+
+    cases.append(KernelCase("grouped_gemm", grouped_gemm, gmm_ref, make_gmm))
+
+    # ---- block-sparse attention (MHA; layout is static host data) ----
+    sblk = min(64, sz.seq // 4)
+    layout = BigBirdSparsityConfig(num_heads=h, block=sblk).make_layout(sz.seq)
+    idx, nlive = padded_layout_indices(np.asarray(layout))
+
+    def make_sparse(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        shape = (1, sz.seq, h, d)
+        return normal(kq, shape), normal(kk, shape), normal(kv, shape)
+
+    def sparse_ref(q, k, v):
+        # the block layout expanded to an elementwise (H, S, S) mask
+        mask = np.kron(np.asarray(layout, bool), np.ones((sblk, sblk), bool))
+        mask &= np.tril(np.ones((sz.seq, sz.seq), bool))[None]
+        return reference_attention(q, k, v, causal=False,
+                                   segment_mask=jnp.asarray(mask)[None])
+
+    cases.append(KernelCase(
+        "block_sparse",
+        lambda q, k, v: block_sparse_attention(q, k, v, idx, nlive, sblk,
+                                               causal=True),
+        sparse_ref, make_sparse))
+    return cases + slow_cases
+
+
+def phase_kernels(sz: Sizes, seed: int) -> Dict[str, Any]:
+    errs, bad = {}, []
+    for i, case in enumerate(kernel_cases(sz)):
+        inputs = jax.jit(case.make)(jax.random.PRNGKey(seed + i))
+        got = jax.jit(case.fn)(*inputs)
+        ref = jax.jit(case.ref)(*inputs)
+        err = rel_err(got, ref)
+        errs[case.name] = round(err, 5)
+        if not err <= case.tol:  # NaN fails
+            bad.append(f"{case.name}: rel err {err:.3g} > {case.tol}")
+    if bad:
+        raise AssertionError("; ".join(bad))
+    return {"cases": len(errs), "rel_err": errs}
+
+
+# ---------------------------------------------------------------- dispatch
+
+
+@contextlib.contextmanager
+def dispatch_calls():
+    """Count, at trace time, which attention implementation the dispatchers
+    in `ops/attention.py` handed each call to, and every `kernel_fallback`
+    announcement — the answer to "did the engine select the kernel or
+    quietly take the XLA path". The callers look these names up at call
+    time, so wrapping the module attributes observes every selection
+    without touching the package."""
+    import deepspeed_tpu.ops.attention as disp
+    import deepspeed_tpu.ops.pallas.decode_attention as dense
+    import deepspeed_tpu.ops.pallas.flash_attention as flash
+    import deepspeed_tpu.ops.pallas.paged_attention as paged
+    import deepspeed_tpu.ops.pallas.sharded as sharded
+    sites = [(flash, "flash_attention"), (dense, "decode_attention"),
+             (paged, "paged_decode_attention"),
+             (paged, "paged_prefill_attention"),
+             (sharded, "sharded_decode_attention"),
+             (sharded, "sharded_paged_decode_attention"),
+             (sharded, "sharded_paged_prefill_attention"),
+             (sharded, "kernel_fallback"),
+             (disp, "reference_attention"), (disp, "blockwise_attention")]
+    counts: Dict[str, int] = {"kernel_fallback": 0}  # always reported
+    saved = [(mod, name, getattr(mod, name)) for mod, name in sites]
+
+    def counting(name, fn):
+        def wrapper(*a, **kw):
+            counts[name] = counts.get(name, 0) + 1
+            return fn(*a, **kw)
+        return wrapper
+
+    for mod, name, fn in saved:
+        setattr(mod, name, counting(name, fn))
+    try:
+        yield counts
+    finally:
+        for mod, name, fn in saved:
+            setattr(mod, name, fn)
+
+
+# ------------------------------------------------------------------- train
+
+
+def train_losses(sz: Sizes, seed: int, devices, dp: int = 1, tp: int = 1,
+                 steps: int = 4, ledger_path: str = "") -> Dict[str, Any]:
+    """`steps` fused train steps on a repeated batch over `devices`
+    (dp x tp mesh): ZeRO-3, bf16, FusedAdam, flash attention, remat and
+    chunked cross-entropy — the flagship recipe of bench.py at this model's
+    widths. The global batch is the same whatever the mesh. With a
+    `ledger_path` the program ledger records the compiled step (one more
+    compile) and its collectives are reported."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.qwen2 import (init_params_and_specs,
+                                            llama_loss_fn, materialize_params,
+                                            qwen2_config)
+    from deepspeed_tpu.telemetry.ledger import ProgramLedger, set_ledger
+    from deepspeed_tpu.utils import groups
+    from deepspeed_tpu.utils.groups import MeshTopology
+
+    ledger = set_ledger(ProgramLedger(path=ledger_path or None))
+    cfg = qwen2_config(sz.preset, num_hidden_layers=sz.train_layers,
+                       max_position_embeddings=sz.seq, remat=True,
+                       remat_policy="checkpoint_dots",
+                       loss_chunk_size=sz.loss_chunk, dtype=jnp.bfloat16)
+    groups.reset_topology()
+    topology = MeshTopology(dp=dp, tp=tp, devices=list(devices))
+    gas = sz.global_batch // (sz.micro_batch * dp)
+    # bf16 from the start: the engine casts to its bf16 model dtype before it
+    # builds the fp32 master anyway, and the fp32 tree would only crowd HBM
+    model, params = materialize_params(cfg, rng=jax.random.PRNGKey(seed),
+                                       param_dtype=jnp.bfloat16)
+    _, specs = init_params_and_specs(cfg)
+    with dispatch_calls() as calls:
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=model, model_parameters=params, topology=topology,
+            config={"train_micro_batch_size_per_gpu": sz.micro_batch,
+                    "gradient_accumulation_steps": gas,
+                    "steps_per_print": 0,
+                    "optimizer": {"type": "FusedAdam",
+                                  "params": {"lr": 2e-4}},
+                    "bf16": {"enabled": True},
+                    "zero_optimization": {"stage": 3},
+                    "tensor_parallel": {"tp_size": tp}},
+            loss_fn=llama_loss_fn(model), base_param_specs=specs)
+        del params  # the engine's state is the only copy from here on
+        batch = {"input_ids": np.random.default_rng(seed).integers(
+            0, cfg.vocab_size, size=(sz.global_batch, sz.seq)).astype(np.int32)}
+        losses, walls = [], []
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            losses.append(float(engine.train_batch(batch=batch)))
+            walls.append(time.perf_counter() - t0)
+    n_params = int(engine.total_params)
+    held = memory_stat("bytes_in_use", devices)
+    engine.state = None
+    engine._jit_cache.clear()
+    del engine
+    groups.reset_topology()
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"non-finite loss: {losses}")
+    run_s = float(np.median(walls[1:]))
+    out = {"layers": sz.train_layers, "params_m": round(n_params / 1e6, 1),
+           "mesh": {"dp": dp, "tp": tp}, "seq": sz.seq,
+           "global_batch": sz.global_batch, "micro_batch": sz.micro_batch,
+           "gas": gas, "losses": [round(l, 5) for l in losses],
+           "loss_first": round(losses[0], 5), "loss_last": round(losses[-1], 5),
+           "compile_s": round(walls[0] - run_s, 2), "run_s": round(run_s, 3),
+           "dispatch": dict(calls), "device_bytes_in_use": held}
+    row = ledger.row("train:train_batch")
+    if row is not None:
+        out["compiled_step"] = {k: row[k] for k in (
+            "comm_ops", "comm_bytes", "comm_bytes_by_axis", "peak_hbm_bytes")}
+    ledger.close()
+    return out
+
+
+def phase_train(sz: Sizes, seed: int) -> Dict[str, Any]:
+    out = train_losses(sz, seed, jax.devices()[:1])
+    if not out["loss_last"] < out["loss_first"]:
+        raise AssertionError(
+            f"loss did not fall on a repeated batch: {out['losses']}")
+    return out
+
+
+# ----------------------------------------------------------------- serving
+
+
+def serving_model(sz: Sizes, seed: int):
+    """The full-depth model and its bf16 weights from `seed`. Each engine
+    gets its own tree (an engine must own the only reference for its
+    leaf-wise relayout to free the old copy), identical by construction."""
+    from deepspeed_tpu.models.qwen2 import materialize_params, qwen2_config
+    cfg = qwen2_config(sz.preset, max_position_embeddings=sz.v2_max_seq,
+                       remat=False, dtype=jnp.bfloat16)
+    return materialize_params(cfg, rng=jax.random.PRNGKey(seed),
+                              param_dtype=jnp.bfloat16)
+
+
+def make_prompts(sz: Sizes, seed: int, vocab: int) -> List[np.ndarray]:
+    """`prompts_per_len` prompts of each length, shorter length first."""
+    rng = np.random.default_rng(seed + 1)
+    return [rng.integers(1, vocab, size=(n,)).astype(np.int32)
+            for n in sz.prompt_lens for _ in range(sz.prompts_per_len)]
+
+
+# Two correct bf16 programs for one model order their sums differently, and
+# 36 layers of bf16 rounding reach the logits: measured on the chip, cached
+# decode and the uncached forward disagree by up to ~8 bf16 steps (0.125 at
+# a top logit of 3.8). With seeded weights the top few of 151936 logits sit
+# that close, so "same argmax" is not the oracle; "argmax up to this
+# tolerance, and mostly the argmax itself" is. A wrong token (a cache or
+# position bug) lands a whole logit spread away, far outside it.
+TIE_TOL = 2.0 ** -4       # relative to the larger of the two logits
+MIN_ARGMAX_SHARE = 0.75   # of generated tokens that are the exact argmax
+
+
+def tie_gap(row: np.ndarray, got: int):
+    """None if `got` is the argmax of this row of reference logits, else
+    how far below it, relative to the larger of the two."""
+    want = int(np.argmax(row))
+    if got == want:
+        return None
+    return abs(float(row[got]) - float(row[want])) / max(
+        abs(float(row[got])), abs(float(row[want])), 1e-6)
+
+
+def judge(label, gaps, where, total,
+          min_share=MIN_ARGMAX_SHARE) -> Dict[str, Any]:
+    """Counts for a phase line; raises where a token is no tie, or where too
+    few are the argmax itself."""
+    out = {"tokens_argmax": total - len(gaps), "tokens_tied": len(gaps),
+           "tie_gaps": sorted(round(g, 4) for g in gaps),
+           "tied_at": where,  # [request, generated-token offset]
+           "tie_tolerance": TIE_TOL}
+    if gaps and max(gaps) > TIE_TOL:
+        i, t = where[int(np.argmax(gaps))]
+        raise AssertionError(f"{label}: request {i}, generated token {t}, "
+                             f"is {max(gaps):.3f} below the reference "
+                             f"argmax; {out}")
+    if total - len(gaps) < min_share * total:
+        raise AssertionError(f"{label}: only {total - len(gaps)} of {total} "
+                             f"tokens are the reference argmax; {out}")
+    return out
+
+
+def check_against_forward(ref_logits, prompts, sequences, label):
+    """Teacher-forced check of greedy decoding: `ref_logits[i][t - 1]` are
+    the uncached forward's logits for position t of sequence i GIVEN that
+    sequence's own prefix, so every generated token must be their argmax or
+    tie with it within `TIE_TOL`."""
+    gaps, where, total = [], [], 0
+    for i, (p, seq) in enumerate(zip(prompts, sequences)):
+        for t in range(len(p), len(seq)):
+            total += 1
+            gap = tie_gap(ref_logits[i][t - 1], int(seq[t]))
+            if gap is not None:
+                gaps.append(gap)
+                where.append([i, t - len(p)])
+    return judge(label, gaps, where, total)
+
+
+def check_first_tokens(anchor, prompts, sequences, label):
+    """The one check an engine cannot pass by agreeing with itself: each
+    request's FIRST generated token against `anchor`, the logits the RAW
+    weight tree gives for its prompt (`prompt_logits`, taken before any
+    engine placed, cast or re-laid the tree)."""
+    gaps, where = [], []
+    for i, (p, seq) in enumerate(zip(prompts, sequences)):
+        gap = tie_gap(anchor[i], int(seq[len(p)]))
+        if gap is not None:
+            gaps.append(gap)
+            where.append([i, 0])
+    # eight tokens are too few for a share: three ties in eight is chance
+    return judge(label + " first tokens vs raw weights", gaps, where,
+                 len(prompts), min_share=0.0)
+
+
+def prompt_logits(model, params, prompts) -> np.ndarray:
+    """(requests, vocab) float32: the uncached forward of the raw `params`
+    over each prompt, at its last position."""
+    width = max(len(p) for p in prompts)
+    padded = np.zeros((len(prompts), width), np.int32)
+    for i, p in enumerate(prompts):
+        padded[i, :len(p)] = p
+    last = np.asarray([len(p) - 1 for p in prompts], np.int32)
+
+    @jax.jit
+    def rows(params, ids, last):
+        logits = model.apply({"params": params}, ids)
+        return jnp.take_along_axis(logits, last[:, None, None],
+                                   axis=1)[:, 0].astype(jnp.float32)
+    return np.asarray(rows(params, padded, last))
+
+
+def forward_logits(apply, sequences) -> np.ndarray:
+    """Uncached forward over right-padded `sequences` (causal attention
+    never looks at the padding), as float32 on the host."""
+    width = max(len(s) for s in sequences)
+    padded = np.zeros((len(sequences), width), np.int32)
+    for i, s in enumerate(sequences):
+        padded[i, :len(s)] = s
+    logits = np.asarray(apply(padded), np.float32)
+    if not np.isfinite(logits).all():
+        raise AssertionError("non-finite logits from the uncached forward")
+    return logits
+
+
+def phase_v1(sz: Sizes, seed: int, keep: Dict[str, Any], tp: int = 1
+             ) -> Dict[str, Any]:
+    """`init_inference(...).generate` over one batch per prompt length, then
+    the engine's own uncached `forward` over what it generated: cached
+    decode must agree with the full forward token by token. Leaves the
+    prompts and sequences in `keep` for the v2 phase."""
+    import deepspeed_tpu
+    from deepspeed_tpu.utils import groups
+    groups.reset_topology()
+    model, params = serving_model(sz, seed)
+    prompts = make_prompts(sz, seed, model.cfg.vocab_size)
+    anchor = prompt_logits(model, params, prompts)
+    with dispatch_calls() as calls:
+        engine = deepspeed_tpu.init_inference(
+            model, params=params, dtype="bf16",
+            tensor_parallel={"tp_size": tp})
+        del params
+        sequences, compile_s, run_s = [], 0.0, 0.0
+        for n in sz.prompt_lens:
+            ids = np.stack([p for p in prompts if len(p) == n])
+            t0 = time.perf_counter()
+            engine.generate(ids, max_new_tokens=sz.new_tokens)
+            t1 = time.perf_counter()
+            out = engine.generate(ids, max_new_tokens=sz.new_tokens)
+            t2 = time.perf_counter()
+            compile_s += (t1 - t0) - (t2 - t1)
+            run_s += t2 - t1
+            if out.shape != (len(ids), n + sz.new_tokens) or \
+                    out.min() < 0 or out.max() >= model.cfg.vocab_size:
+                raise AssertionError(f"generate output {out.shape} out of "
+                                     "shape or vocabulary")
+            sequences += [row for row in out]
+        ref_logits = forward_logits(engine.forward, sequences)
+    label = f"v1 tp={tp}"
+    first = check_first_tokens(anchor, prompts, sequences, label)
+    checked = check_against_forward(ref_logits, prompts, sequences, label)
+    keep.update(prompts=prompts, sequences=sequences, anchor=anchor)
+    out = {"layers": model.cfg.num_hidden_layers, "tp": tp,
+           "device_bytes_in_use": memory_stat("bytes_in_use",
+                                              jax.devices()[:tp]),
+           "serve_mode": engine.serve_mode, "requests": len(prompts),
+           "prompt_lens": list(sz.prompt_lens), "new_tokens": sz.new_tokens,
+           "tokens_generated": len(prompts) * sz.new_tokens,
+           "first_vs_raw": first, "vs_forward": checked,
+           "compile_s": round(compile_s, 2), "run_s": round(run_s, 3),
+           "dispatch": dict(calls)}
+    engine.params = None
+    engine._generate_jit.clear()
+    del engine
+    groups.reset_topology()
+    return out
+
+
+def agreement(keep, sequences) -> Dict[str, int]:
+    """How far `sequences` follow the v1 phase's, request by request. Both
+    sides passed the teacher-forced check against the same weights, so a
+    place where they part is a tie in the reference logits, broken
+    differently by two programs; after it each follows its own prefix."""
+    part_at = []  # generated tokens in common before the first difference
+    for p, want, got in zip(keep["prompts"], keep["sequences"], sequences):
+        got = np.asarray(got)
+        if got.shape != want.shape or (got[:len(p)] != p).any():
+            raise AssertionError("a served sequence lost its prompt or length")
+        diff = np.nonzero(got != want)[0]
+        part_at.append((int(diff[0]) if len(diff) else len(got)) - len(p))
+    return {"identical_sequences": sum(n == len(s) - len(p) for n, s, p in zip(
+                part_at, sequences, keep["prompts"])),
+            "common_prefix_tokens": part_at}
+
+
+def phase_v2(sz: Sizes, seed: int, keep: Dict[str, Any]) -> Dict[str, Any]:
+    """`InferenceEngineV2(kv_layout="paged").generate` over the v1 phase's
+    prompts, long and short interleaved, on fewer slots than requests."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.utils import groups
+    if "sequences" not in keep:
+        raise AssertionError("no v1 tokens to compare with (v1 phase failed)")
+    groups.reset_topology()
+    model, params = serving_model(sz, seed)
+    prompts = keep["prompts"]
+    half = len(prompts) // 2
+    order = [i for pair in zip(range(half, len(prompts)), range(half))
+             for i in pair]  # long, short, long, short, ...
+    with dispatch_calls() as calls:
+        engine = InferenceEngineV2(
+            model, params=params, max_batch=sz.v2_slots,
+            max_seq_len=sz.v2_max_seq, kv_layout="paged",
+            cache_block_size=sz.block, split_fuse_chunk=sz.v2_chunk)
+        del params
+        requests = [prompts[i].tolist() for i in order]
+        t0 = time.perf_counter()
+        engine.generate(requests, max_new_tokens=sz.new_tokens)
+        t1 = time.perf_counter()
+        served = engine.generate(requests, max_new_tokens=sz.new_tokens)
+        t2 = time.perf_counter()
+        sequences = [None] * len(prompts)
+        for i, seq in zip(order, served):
+            sequences[i] = np.asarray(seq, np.int32)
+        forward = jax.jit(lambda p, ids: model.apply({"params": p}, ids))
+        ref_logits = forward_logits(lambda ids: forward(engine.params, ids),
+                                    sequences)
+    first = check_first_tokens(keep["anchor"], prompts, sequences, "v2")
+    checked = check_against_forward(ref_logits, prompts, sequences, "v2")
+    snap = engine.telemetry_snapshot()
+    out = {"layers": model.cfg.num_hidden_layers,
+           "serve_mode": engine.serve_mode, "kv_layout": engine.kv_layout,
+           "slots": sz.v2_slots, "requests": len(prompts),
+           "tokens_generated": len(prompts) * sz.new_tokens,
+           "first_vs_raw": first, "vs_forward": checked,
+           "vs_v1": agreement(keep, sequences),
+           "flushed_sequences": snap["flushed_sequences"],
+           "kv_util_peak": snap["kv_util_peak"],
+           "pinned_recompiles": snap["pinned_recompiles"],
+           "compile_s": round((t1 - t0) - (t2 - t1), 2),
+           "run_s": round(t2 - t1, 3), "dispatch": dict(calls)}
+    engine.params = engine.cache = None
+    engine._jits.clear()
+    del engine
+    groups.reset_topology()
+    return out
+
+
+# ------------------------------------------------------------- four chips
+
+
+def phase_train_sharded(sz: Sizes, seed: int) -> Dict[str, Any]:
+    """dp2 x tp2 against one chip: same global batch, same seed, same steps.
+    ZeRO-3 shards state over `data`, the projections over `model`; the
+    losses may differ by reduction order in bf16 and no more."""
+    tol = 5e-3  # relative, per step: bf16 sums reassociated across 4 chips
+    #             (measured 2.8e-4 on the 2x2 v5e)
+    os.makedirs("chiprun_out", exist_ok=True)
+    one = train_losses(sz, seed, jax.devices()[:1])
+    four = train_losses(
+        sz, seed, jax.devices()[:4], dp=2, tp=2,
+        ledger_path=os.path.join("chiprun_out", "chip_smoke_ledger.jsonl"))
+    check_every_device_holds(four)
+    worst = max(abs(a - b) / abs(a)
+                for a, b in zip(one["losses"], four["losses"]))
+    out = {"one_chip": one, "dp2_tp2": four, "loss_rel_diff": round(worst, 5),
+           "tolerance": tol}
+    if not worst <= tol:
+        raise AssertionError(f"sharded and one-chip losses differ by "
+                             f"{worst:.3g} > {tol}: {out}")
+    if not four["loss_last"] < four["loss_first"]:
+        raise AssertionError(f"sharded loss did not fall: {four['losses']}")
+    return out
+
+
+def phase_v1_tp(sz: Sizes, seed: int) -> Dict[str, Any]:
+    """v1 `generate` at tp_size=2 against tp=1, token for token (each side
+    checked against its own uncached forward; see `agreement`)."""
+    keep: Dict[str, Any] = {}
+    tp1 = phase_v1(sz, seed, keep, tp=1)
+    sharded: Dict[str, Any] = {}
+    tp2 = phase_v1(sz, seed, sharded, tp=2)
+    check_every_device_holds(tp2)
+    return {"tp1": tp1, "tp2": tp2,
+            "tp2_vs_tp1": agreement(keep, sharded["sequences"])}
+
+
+# -------------------------------------------------------------------- main
+
+
+def run_phase(name: str, fn: Callable[[], Dict[str, Any]]) -> bool:
+    """Run one phase and print its line. A failure is recorded with its
+    traceback (stderr) and the remaining phases still run: the script's
+    exit code reports it."""
+    t0 = time.perf_counter()
+    try:
+        body, ok = fn(), True
+    except Exception as e:  # phase boundary: report, keep going, fail at exit
+        traceback.print_exc()
+        body, ok = {"error": f"{type(e).__name__}: {e}"[:2000]}, False
+    emit({"phase": name, "ok": ok, "wall_s": round(time.perf_counter() - t0, 2),
+          **body, "peak_bytes": memory_stat("peak_bytes_in_use")})
+    return ok
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: the sharded train and tp generate paths, each "
+                         "against one chip, and no other phase")
+    ap.add_argument("--tiny", action="store_true",
+                    help="toy sizes, same control flow")
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="accept a backend that is not a TPU")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    from benchmarks.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    from deepspeed_tpu.accelerator import on_tpu
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if not on_tpu() and not args.rehearsal:
+        print(f"chip_smoke: default backend is {dev.platform!r}, not a TPU "
+              "(--rehearsal runs the control flow anyway)", file=sys.stderr)
+        return 2
+    switches = [v for v in ("DS_TPU_PALLAS_INTERPRET", "DS_TPU_DISABLE_PALLAS")
+                if os.environ.get(v)]
+    if on_tpu() and switches:
+        print(f"chip_smoke: {switches} set — on the chip the kernels run "
+              "compiled or the run fails; unset them", file=sys.stderr)
+        return 2
+    if device["count"] < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but JAX sees "
+              f"{device['count']} device(s)", file=sys.stderr)
+        return 2
+
+    sz = TINY if args.tiny else FULL
+    emit({"phase": "start", "jax": jax.__version__, "device": device,
+          "sizes": "tiny" if args.tiny else "full", "preset": sz.preset,
+          "seed": args.seed, "chips": args.chips, "compile_cache": cache_dir})
+    if args.chips == 4:
+        phases = [("train_sharded", lambda: phase_train_sharded(sz, args.seed)),
+                  ("v1_tp", lambda: phase_v1_tp(sz, args.seed))]
+    else:
+        keep: Dict[str, Any] = {}
+        phases = [("kernels", lambda: phase_kernels(sz, args.seed)),
+                  ("train", lambda: phase_train(sz, args.seed)),
+                  ("v1", lambda: phase_v1(sz, args.seed, keep)),
+                  ("v2", lambda: phase_v2(sz, args.seed, keep))]
+    results = [run_phase(name, fn) for name, fn in phases]  # run them all
+    ok = all(results)
+    emit({"ok": ok, "device": device})
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -16,7 +16,6 @@ from typing import Any, Callable, Optional
 
 __version__ = "0.1.0"
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  (installs shims)
 from deepspeed_tpu.accelerator import get_accelerator  # noqa: F401
 from deepspeed_tpu import comm  # noqa: F401
 from deepspeed_tpu.comm.comm import init_distributed  # noqa: F401

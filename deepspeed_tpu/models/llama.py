@@ -447,8 +447,13 @@ def init_params_and_specs(cfg: LlamaConfig, rng=None, seq_len: int = 8):
 
 
 def materialize_params(cfg: LlamaConfig, rng=None, seq_len: int = 8,
-                       shardings=None):
-    """Initialize real parameters (optionally directly into shardings)."""
+                       shardings=None, param_dtype=None):
+    """Initialize real parameters (optionally directly into shardings).
+
+    The modules declare fp32 params (the training master form). A serving
+    caller passes `param_dtype` to get the tree in that dtype from the SAME
+    program, the cast fused into each initializer — at 3B the fp32 tree
+    (12.4 GB) beside its bf16 copy does not fit one 16 GB chip."""
     from deepspeed_tpu.utils.partitioning import extract_params_and_specs
     model = LlamaForCausalLM(cfg)
     rng = rng if rng is not None else jax.random.PRNGKey(0)
@@ -457,6 +462,10 @@ def materialize_params(cfg: LlamaConfig, rng=None, seq_len: int = 8,
     def init_fn(rng):
         variables = model.init(rng, ids)
         raw, _ = extract_params_and_specs(variables)
+        if param_dtype is not None:
+            raw = jax.tree_util.tree_map(
+                lambda x: x.astype(param_dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating) else x, raw)
         return raw
 
     if shardings is not None:

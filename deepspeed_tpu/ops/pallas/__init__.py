@@ -1,14 +1,11 @@
-"""Pallas TPU kernels.
+"""Pallas TPU kernels."""
 
-Version compat: jax renamed ``pltpu.TPUCompilerParams`` →
-``pltpu.CompilerParams`` (and every kernel here uses the new name). On the
-older jax still found in some test environments, alias it once at package
-import — submodule imports always run this first, so all kernels see a
-consistent surface.
-"""
+import os
 
-from jax.experimental.pallas import tpu as _pltpu
+from deepspeed_tpu.accelerator import on_tpu
 
-if not hasattr(_pltpu, "CompilerParams"):  # jax < 0.5 naming
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-del _pltpu
+
+def _interpret() -> bool:
+    """Whether the kernels run in the Pallas interpreter: off the chip
+    (the CPU golden tests) or when DS_TPU_PALLAS_INTERPRET asks."""
+    return bool(os.environ.get("DS_TPU_PALLAS_INTERPRET")) or not on_tpu()

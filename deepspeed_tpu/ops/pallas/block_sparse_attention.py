@@ -30,7 +30,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF, _interpret
+from deepspeed_tpu.ops.pallas import _interpret
+from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
 
 
 def padded_layout_indices(layout: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF, _interpret
+from deepspeed_tpu.ops.pallas import _interpret
+from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
 
 DEFAULT_BLOCK_K = 512
 
@@ -145,7 +146,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         return (b_ * hkv + g, jnp.minimum(j, last), 0)
 
     def kv_scale_index(b_, g, j, L):
-        return kv_index(b_, g, j, L)[:2]
+        row, blk, _ = kv_index(b_, g, j, L)
+        return (row, 0, blk)
 
     in_specs = [
         pl.BlockSpec((1, n_rep, d), lambda b_, g, j, L: (b_ * hkv + g, 0, 0)),
@@ -155,12 +157,15 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     args = [lengths.astype(jnp.int32), qt2, kt2, vt2]
     quantized = k_scales is not None
     if quantized:
-        # (B, M, Hkv) → (B·Hkv, M): token scales along lanes, one tile
-        # per KV block beside its pool tile (same index map, D-less)
-        ks2 = jnp.swapaxes(k_scales, 1, 2).reshape(b * hkv, m)
-        vs2 = jnp.swapaxes(v_scales, 1, 2).reshape(b * hkv, m)
-        in_specs += [pl.BlockSpec((1, blk_k), kv_scale_index),
-                     pl.BlockSpec((1, blk_k), kv_scale_index)]
+        # (B, M, Hkv) → (B·Hkv, 1, M): token scales along lanes, one tile
+        # per KV block beside its pool tile (same index map). The unit
+        # sublane dim is there for Mosaic: a block's second-to-last dim
+        # must be a multiple of 8 or span the array's, and one row of
+        # (B·Hkv, M) is neither.
+        ks2 = jnp.swapaxes(k_scales, 1, 2).reshape(b * hkv, 1, m)
+        vs2 = jnp.swapaxes(v_scales, 1, 2).reshape(b * hkv, 1, m)
+        in_specs += [pl.BlockSpec((None, 1, blk_k), kv_scale_index),
+                     pl.BlockSpec((None, 1, blk_k), kv_scale_index)]
         args += [ks2, vs2]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -35,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF, _interpret
+from deepspeed_tpu.ops.pallas import _interpret
+from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
 
 
 def _paged_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
@@ -138,6 +139,22 @@ def _paged_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
+def _scale_operand(scales: jnp.ndarray) -> jnp.ndarray:
+    """(Hkv, NB, BS) pool scales as the kernels take them: (Hkv, NB, 1, BS).
+    Mosaic wants a block's second-to-last dim a multiple of 8 or the whole
+    array dim, and ONE physical block of the NB axis is neither; the unit
+    dim makes a block's (1, BS) tail span its array's."""
+    hkv, nb, bs = scales.shape
+    return scales.reshape(hkv, nb, 1, bs)
+
+
+def _scale_block_spec(hkv: int, bs: int, index_map) -> pl.BlockSpec:
+    """One physical block's scales for every KV head, riding the pools'
+    own 4-D index map; the NB dim is squeezed, so the kernels read a
+    (Hkv, 1, BS) ref."""
+    return pl.BlockSpec((hkv, None, 1, bs), index_map)
+
+
 def _mk_paged_kernel(quantized: bool, staged: bool, has_alibi: bool):
     """Fixed-arity wrapper for one (quantized, staged, alibi) variant —
     pallas passes refs positionally in args order (scales right after the
@@ -213,9 +230,6 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         phys = Tb[b_, jj]
         return (0, jnp.clip(phys, 0, nb - 1), 0, 0)
 
-    def kv_scale_index(b_, j, L, Tb):
-        return kv_index(b_, j, L, Tb)[:3]
-
     in_specs = [
         pl.BlockSpec((1, h, d), lambda b_, j, L, Tb: (b_, 0, 0)),
         pl.BlockSpec((hkv, 1, bs, d), kv_index),
@@ -225,9 +239,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             qt, k_pool, v_pool]
     quantized = k_scales is not None
     if quantized:
-        in_specs += [pl.BlockSpec((hkv, 1, bs), kv_scale_index),
-                     pl.BlockSpec((hkv, 1, bs), kv_scale_index)]
-        args += [k_scales, v_scales]
+        in_specs += [_scale_block_spec(hkv, bs, kv_index)] * 2
+        args += [_scale_operand(k_scales), _scale_operand(v_scales)]
     if staged:
         in_specs += [pl.BlockSpec((1, hkv, d), lambda b_, j, L, Tb: (b_, 0, 0)),
                      pl.BlockSpec((1, hkv, d), lambda b_, j, L, Tb: (b_, 0, 0))]
@@ -424,13 +437,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             qt, k_pool, v_pool]
     quantized = k_scales is not None
 
-    def kv_scale_index(b_, qi, j, S_, Tb):
-        return kv_index(b_, qi, j, S_, Tb)[:3]
-
     if quantized:
-        in_specs += [pl.BlockSpec((hkv, 1, bs), kv_scale_index),
-                     pl.BlockSpec((hkv, 1, bs), kv_scale_index)]
-        args += [k_scales, v_scales]
+        in_specs += [_scale_block_spec(hkv, bs, kv_index)] * 2
+        args += [_scale_operand(k_scales), _scale_operand(v_scales)]
     if alibi is not None:
         # per-s-row slope layout (row r of group g = head g·n_rep + r%n_rep),
         # 128-lane padded: the kernel lane-slices [:, :, :1] (see decode)

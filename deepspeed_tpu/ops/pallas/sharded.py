@@ -9,19 +9,15 @@ instead — consistent with the invariant that manual regions appear
 exactly where the wire format matters, which a Pallas call on sharded
 operands is.
 
-Three rules keep the regions portable across jax versions (0.4.x
-sandboxes run them through the `utils/jax_compat` shard_map adapter;
-verified by the parity suite on the virtual 8-device CPU mesh):
+Three rules the regions follow (verified by the parity suite on the
+virtual 8-device CPU mesh):
 
-- FULL-manual regions only (never an ``axis_names`` subset): the old
-  partitioner hard-CHECK-crashes (``IsManualSubgroup``, a process abort)
-  on partial-manual regions around some pallas calls.
-- never ``jax.lax.axis_index``/``axis_size`` inside a region (compiles to
-  ``PartitionId``, UNIMPLEMENTED on the old SPMD partitioner — the same
-  failure as the pp2 dryrun phase). Shard identity rides a SHARDED INPUT:
-  ``jnp.arange(n_shards) * per_shard`` with spec ``P(axis)``, each shard
-  reading element ``[0]`` — the SNIPPETS tpu_inference fused-MoE idiom.
-  Axis sizes come statically from ``mesh.shape``.
+- FULL-manual regions only (never an ``axis_names`` subset).
+- no ``jax.lax.axis_index``/``axis_size`` inside a region. Shard identity
+  rides a SHARDED INPUT: ``jnp.arange(n_shards) * per_shard`` with spec
+  ``P(axis)``, each shard reading element ``[0]`` — the SNIPPETS
+  tpu_inference fused-MoE idiom. Axis sizes come statically from
+  ``mesh.shape``.
 - replicated operands get an explicit ``P()`` spec (trailing dims of a
   PartitionSpec are unsharded, so ``P()`` replicates any rank).
 
@@ -43,6 +39,7 @@ disabled) falls back to the XLA path — loudly, via `kernel_fallback`
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Dict, Optional, Tuple
 
@@ -76,14 +73,20 @@ def kernel_fallback(kernel: str, reason: str) -> None:
         pass
 
 
+def kernel_shard_map(body, mesh, in_specs, out_specs):
+    """Full-manual `jax.shard_map` around a Pallas call. `check_vma` is off:
+    a `pallas_call`'s `out_shape` carries no varying-axes annotation, which
+    the checker refuses outright; every wrapper here states its sharding
+    in `out_specs` and takes no replication claim from the checker."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def sharded_kernels_supported() -> bool:
-    """Gate for every sharded-kernel route. `jax.shard_map` exists on
-    current jax and via the jax_compat adapter on 0.4.x, so this is
-    normally True; DS_TPU_DISABLE_SHARDED_KERNELS=1 is the kill switch
-    (forces the pre-r7 single-device-only dispatch everywhere)."""
-    if os.environ.get("DS_TPU_DISABLE_SHARDED_KERNELS"):
-        return False
-    return hasattr(jax, "shard_map")
+    """Gate for every sharded-kernel route: True unless
+    DS_TPU_DISABLE_SHARDED_KERNELS=1, the kill switch (forces the pre-r7
+    single-device-only dispatch everywhere)."""
+    return not os.environ.get("DS_TPU_DISABLE_SHARDED_KERNELS")
 
 
 def nontrivial_axes(mesh) -> Dict[str, int]:
@@ -175,9 +178,7 @@ def sharded_decode_attention(q, k_cache, v_cache, lengths, mesh,
         return decode_attention(q, kc, vc, ln, softmax_scale=softmax_scale,
                                 block_k=block_k, k_scales=ks, v_scales=vs)
 
-    fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=tuple(in_specs), out_specs=spec)
-    return fn(*args)
+    return kernel_shard_map(body, mesh, tuple(in_specs), spec)(*args)
 
 
 def sharded_paged_decode_attention(q, k_pool, v_pool, tables, lengths, mesh,
@@ -225,9 +226,7 @@ def sharded_paged_decode_attention(q, k_pool, v_pool, tables, lengths, mesh,
                                       window=window, alibi=al,
                                       k_scales=ks, v_scales=vs)
 
-    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=qspec)
-    return fn(*args)
+    return kernel_shard_map(body, mesh, tuple(in_specs), qspec)(*args)
 
 
 def sharded_paged_prefill_attention(q, k_pool, v_pool, tables, starts, mesh,
@@ -265,6 +264,64 @@ def sharded_paged_prefill_attention(q, k_pool, v_pool, tables, starts, mesh,
                                        block_q=block_q, window=window,
                                        alibi=al, k_scales=ks, v_scales=vs)
 
-    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=qspec)
-    return fn(*args)
+    return kernel_shard_map(body, mesh, tuple(in_specs), qspec)(*args)
+
+
+# ---- training flash attention (batch over the data axes, heads over
+# 'sequence'/'model') ----
+#
+# The chip's compiler refuses a bare Mosaic call in a partitioned program
+# ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+# call in a shard_map") — so on ANY multi-device training mesh the flash
+# kernel must sit in a manual region. Attention is independent per
+# (batch row, head): both dims shard with no collective.
+
+
+def flash_shard_specs(b: int, h: int, hkv: int):
+    """How `flash_attention` rides the installed topology. Returns
+    `(mesh, spec)`: `(None, None)` — call the bare kernel (single device,
+    or already inside a region manual over every nontrivial axis);
+    `(mesh, P(...))` — wrap it in a full-manual shard_map with this spec
+    for q, k, v and the output; `(mesh, None)` — this mesh cannot carry
+    the kernel (announced via `kernel_fallback`; the caller takes the XLA
+    path)."""
+    from deepspeed_tpu.utils.partitioning import (BATCH_AXES,
+                                                  ambient_manual_mesh)
+    mesh = _topology_mesh()
+    nt = nontrivial_axes(mesh) if mesh is not None else {}
+    _, manual = ambient_manual_mesh()  # axes an enclosing region took
+    if not set(nt) - manual:
+        return None, None
+    if not sharded_kernels_supported():
+        kernel_fallback("flash_attention", "sharded kernels disabled")
+        return mesh, None
+    if manual or "pipe" in nt:
+        # a nested region over the remaining auto axes is not built here
+        kernel_fallback("flash_attention",
+                        f"inside a region manual over {sorted(manual)} of "
+                        f"mesh axes {sorted(nt)}")
+        return mesh, None
+    batch = tuple(a for a in BATCH_AXES if a in nt)
+    heads = tuple(a for a in ("sequence", "model") if a in nt)
+    nb = math.prod(nt[a] for a in batch)
+    nh = math.prod(nt[a] for a in heads)
+    if b % nb or h % nh or hkv % nh:
+        kernel_fallback("flash_attention",
+                        f"batch {b} / heads (H={h}, Hkv={hkv}) don't divide "
+                        f"mesh axes {nt}")
+        return mesh, None
+    return mesh, P(batch or None, None, heads or None, None)
+
+
+def sharded_flash_attention(q, k, v, mesh, spec, causal: bool = True,
+                            softmax_scale: Optional[float] = None):
+    """`flash_attention` (custom-VJP, so differentiable through the region)
+    with q (B,S,H,D) and k/v (B,S,Hkv,D) sharded by `spec` from
+    `flash_shard_specs`."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def body(q, k, v):
+        return flash_attention(q, k, v, causal=causal,
+                               softmax_scale=softmax_scale)
+
+    return kernel_shard_map(body, mesh, (spec, spec, spec), spec)(q, k, v)

@@ -98,8 +98,6 @@ def _ring_body(q, k, v, axis: str, causal: bool, scale: float):
     """shard_map body: q (B, Sl, H, D), k/v (B, Sl, Hkv, D) — this device's
     sequence chunks. KV rotates un-expanded (GQA stays small on the wire)."""
     from deepspeed_tpu.comm.comms_logging import get_comms_logger
-    # ring attention is already in the 0.4.x-SIGABRT program class; the
-    # fast AttributeError here is the intended failure mode (jax_compat)
     p_size = jax.lax.axis_size(axis)  # tpulint: disable=no-set-mesh
     r = jax.lax.axis_index(axis)
     b, sl, h, d = q.shape

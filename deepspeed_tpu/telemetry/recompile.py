@@ -57,11 +57,8 @@ def abstract_args(args):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
             sh = getattr(x, "sharding", None) \
                 if getattr(x, "_committed", False) else None
-            try:
-                return jax.ShapeDtypeStruct(tuple(np.shape(x)), x.dtype,
-                                            sharding=sh)
-            except TypeError:  # older jax: no sharding kwarg
-                return jax.ShapeDtypeStruct(tuple(np.shape(x)), x.dtype)
+            return jax.ShapeDtypeStruct(tuple(np.shape(x)), x.dtype,
+                                        sharding=sh)
         return x
 
     return jax.tree_util.tree_map(one, args)

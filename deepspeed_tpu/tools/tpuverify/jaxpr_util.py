@@ -21,8 +21,10 @@ except Exception:  # pragma: no cover - version-dependent import path
 
 # Host-escape primitives: any of these inside a hot-path program means a
 # device→host→device round trip per step (pure_callback / io_callback /
-# jax.debug.print all lower to a callback eqn).
-CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback", "debug_callback"})
+# jax.debug.callback lower to a callback eqn; jax.debug.print has its own
+# debug_print primitive).
+CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback", "debug_callback",
+                            "debug_print"})
 
 # The scatter family as it appears in decode jaxprs. dynamic_update_slice
 # is included: XLA lowers cursor-indexed cache writes to either form, and

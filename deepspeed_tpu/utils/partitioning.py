@@ -64,6 +64,16 @@ def current_mesh():
         return None
 
 
+def ambient_manual_mesh():
+    """(abstract mesh, names of its Manual axes) while tracing inside a
+    shard_map region; (None, frozenset()) outside one."""
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
+        return None, frozenset()
+    return am, frozenset(name for name, t in zip(am.axis_names, am.axis_types)
+                         if str(t) == "Manual")
+
+
 def shard_along(x, *axes, rules: Optional[Dict] = None):
     """Constrain an activation's sharding (no-op without an installed topology).
 
@@ -77,15 +87,9 @@ def shard_along(x, *axes, rules: Optional[Dict] = None):
     # Inside a shard_map manual region (e.g. the pipeline rotation) the
     # constraint must be built against the ambient AbstractMesh, and specs
     # must not mention Manual axes (they're already mapped away).
-    manual_axes: set = set()
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            manual_axes = {name for name, t in zip(am.axis_names, am.axis_types)
-                           if str(t) == "Manual"}
-            mesh = am
-    except Exception:
-        pass
+    am, manual_axes = ambient_manual_mesh()
+    if am is not None:
+        mesh = am
     rules = {**DEFAULT_RULES, **(rules or {})}
 
     def resolve(entry):

@@ -2,10 +2,8 @@
 collectives must work inside shard_map manual regions, and the host-plane
 surface must report correct sizes.
 
-The `jax.set_mesh` pragmas below are deliberate: these collective tests
-exercise exactly the program class that SIGABRTs 0.4.x XLA:CPU, so
-jax_compat leaves set_mesh unshimmed and the fast AttributeError on old
-jax is the intended failure mode (see docs/static_analysis.md)."""
+The `no-set-mesh` pragmas below answer a tpulint rule that outlived its
+reason (ROADMAP C2 retires it; see docs/static_analysis.md)."""
 
 import jax
 import jax.numpy as jnp

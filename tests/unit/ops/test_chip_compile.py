@@ -1,0 +1,64 @@
+"""Every Pallas kernel an engine can select, compiled for a described TPU
+v5e at Qwen2.5-3B shapes — no chip attached, no chip time.
+
+Interpret mode accepts block specs the chip's compiler refuses (the int8
+scale operands of `quantized_matmul` and of both int8-KV decode kernels
+passed every interpret-mode test and could not lower at any real width).
+The cases are `chip_smoke.kernel_cases`: what this file compiles is what
+`chip_smoke.py` runs on the chip against the `jax.numpy` references.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from chip_smoke import FULL, kernel_cases
+
+CASES = kernel_cases(FULL)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One device of a described (not attached) 2x2 v5e. The persistent
+    compilation cache is off around these compiles: an executable for a
+    described device is written but cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Off the chip every kernel module answers `_interpret()` with True;
+    steer them to the compiled path here, in the test."""
+    from deepspeed_tpu.ops.pallas import (
+        block_sparse_attention, decode_attention, flash_attention,
+        grouped_gemm, paged_attention, quantized_matmul)
+    monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
+    for mod in (block_sparse_attention, decode_attention, flash_attention,
+                grouped_gemm, paged_attention, quantized_matmul):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c.name for c in CASES])
+def test_kernel_compiles_for_v5e(case, v5e_chip, compiled_kernels):
+    shapes = jax.eval_shape(case.make, jax.random.PRNGKey(0))
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e_chip),
+        shapes)
+    text = jax.jit(case.fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"

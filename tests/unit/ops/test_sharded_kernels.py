@@ -283,6 +283,79 @@ def test_cached_attention_unsupported_mesh_falls_back(monkeypatch):
                                     segment_mask=mask))
 
 
+# ------------------------------------------- training flash attention
+#
+# The chip's compiler refuses a bare Mosaic call in a partitioned program,
+# so on a multi-device training mesh `attention()` must wrap the flash
+# kernel in a shard_map (batch over the data axes, heads over 'model').
+
+
+def _train_mesh(dp, tp):
+    groups.reset_topology()
+    topo = groups.initialize(
+        MeshTopology(dp=dp, tp=tp, devices=jax.devices()[:dp * tp]))
+    return topo.mesh
+
+
+def _flash_inputs(b=4, s=128, h=4, hkv=2, d=32, seed=11):
+    rng = np.random.default_rng(seed)
+    mk = lambda hh: jnp.asarray(rng.standard_normal((b, s, hh, d)),
+                                jnp.float32)
+    return mk(h), mk(hkv), mk(hkv)
+
+
+@pytest.mark.parametrize("dp,tp,spec", [
+    (1, 1, None),                                      # bare kernel
+    (2, 2, ("data", None, "model", None)),
+    (4, 1, ("data", None, None, None)),
+    (1, 2, (None, None, "model", None)),
+])
+def test_flash_shard_specs(dp, tp, spec):
+    from deepspeed_tpu.ops.pallas.sharded import flash_shard_specs
+    mesh = _train_mesh(dp, tp)
+    got_mesh, got = flash_shard_specs(4, 4, 2)
+    if spec is None:
+        assert (got_mesh, got) == (None, None)
+    else:
+        assert got_mesh is mesh
+        assert got == jax.sharding.PartitionSpec(*spec)
+
+
+def test_attention_rides_sharded_flash_on_train_mesh(monkeypatch):
+    """Forward and gradients through `attention(impl='auto')` on a dp2 x
+    tp2 mesh match the XLA reference, and the kernel sits in a shard_map."""
+    from deepspeed_tpu.ops import attention as attn_mod
+    from deepspeed_tpu.ops.attention import attention, reference_attention
+    mesh = _train_mesh(2, 2)
+    monkeypatch.setattr(attn_mod, "_use_pallas", lambda: True)
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    q, k, v = _flash_inputs()
+    loss = lambda f: (lambda q, k, v: jnp.sum(f(q, k, v, causal=True) ** 2))
+    with mesh:
+        jaxpr = jax.make_jaxpr(attention)(q, k, v)
+        out = jax.jit(attention)(q, k, v)
+        grads = jax.jit(jax.grad(loss(attention), argnums=(0, 1, 2)))(q, k, v)
+    assert "shard_map" in str(jaxpr)
+    _close(out, reference_attention(q, k, v, causal=True), tol=2e-3)
+    ref = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(grads, ref):
+        _close(g, r, tol=5e-3)
+
+
+def test_attention_flash_fallback_is_announced(monkeypatch):
+    # 3 KV heads over model=2: no head sharding — XLA path, said out loud
+    from deepspeed_tpu.ops import attention as attn_mod
+    from deepspeed_tpu.ops.attention import attention, reference_attention
+    _train_mesh(2, 2)
+    monkeypatch.setattr(attn_mod, "_use_pallas", lambda: True)
+    sharded._WARNED.clear()
+    q, k, v = _flash_inputs(h=6, hkv=3)
+    out = attention(q, k, v)
+    assert any(isinstance(key, tuple) and key[0] == "flash_attention"
+               for key in sharded._WARNED)
+    _close(out, reference_attention(q, k, v, causal=True))
+
+
 # --------------------------------------------------------- MoE EP route
 
 def test_gmm_mesh_predicate():

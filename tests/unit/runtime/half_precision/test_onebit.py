@@ -2,8 +2,8 @@
 test_onebit.py): warmup parity with Adam, frozen variance + compressed
 momentum after freeze, and the sign-compressed allreduce backend.
 
-`jax.set_mesh` pragmas: the compressed-allreduce manual regions are the
-0.4.x-SIGABRT program class jax_compat deliberately leaves unshimmed."""
+The `no-set-mesh` pragmas answer a tpulint rule that outlived its reason
+(ROADMAP C2 retires it)."""
 
 import jax
 import jax.numpy as jnp

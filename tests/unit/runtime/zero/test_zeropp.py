@@ -1,9 +1,9 @@
 """ZeRO++ tests (reference tests/unit/runtime/zero/test_zeropp.py):
 quantized gradients (qgZ) and quantized weight gathers (qwZ).
 
-`jax.set_mesh` pragmas: the ZeRO++ quantized-collective manual regions
-are the 0.4.x-SIGABRT program class jax_compat deliberately leaves
-unshimmed."""
+The `no-set-mesh` pragmas answer a tpulint rule that outlived its reason
+(ROADMAP C2 retires it); `jax.set_mesh` is the plain way to run these
+manual regions."""
 
 import jax
 import jax.numpy as jnp
