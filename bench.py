@@ -441,7 +441,7 @@ def main(argv=None):
 
         kernel_micro = {
             "method": "chained fori_loop, ms/layer at B=64 Hkv=8 "
-                      "ctx=320/1024 (benchmarks/fastgen_breakdown.py)",
+                      "ctx=320/1024",
             "paged_decode_kernel_ms": _chain(
                 lambda q: paged_decode_attention(q, kpool, kpool, ktab,
                                                  klens)),

@@ -24,7 +24,8 @@ from deepspeed_tpu.accelerator import on_tpu
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.kv_cache import KVCache
 from deepspeed_tpu.resilience.faults import fault_point, is_oom_error
-from deepspeed_tpu.telemetry import RecompileDetector, annotate, get_hub
+from deepspeed_tpu.telemetry import (RecompileDetector, annotate,
+                                     compile_span, get_hub)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
@@ -272,33 +273,39 @@ class InferenceEngine:
         key = (b, s, int(max_new_tokens), float(temperature), int(top_k),
                float(top_p), eos_token_id, pad_token_id)
         rng = jax.random.PRNGKey(seed)
+        if key in self._generate_jit:
+            return self._dispatch_generate(key, input_ids, rng, b,
+                                           int(max_new_tokens))
+        # a new (b, s, new, sampling) key: its build, compile and first
+        # dispatch are one `compile` span under the program's ledger name
+        with compile_span(self._ledger_name(key), "v1"):
+            self._build_program(key, input_ids, rng)
+            return self._dispatch_generate(key, input_ids, rng, b,
+                                           int(max_new_tokens))
+
+    def _build_program(self, key, input_ids, rng) -> None:
         if getattr(self, "serve_mode", "dequant") == "capacity":
             # host-driven layer-streamed loop (capacity_scan) — the runner
             # owns placement/layouts, so the AUTO-layout pin never applies
             # (and it ledgers its own block program at first dispatch)
-            if key not in self._generate_jit:
-                fault_point("program_compile", label="capacity")
-                self._generate_jit[key] = self._capacity.bind_key(key)
+            fault_point("program_compile", label="capacity")
+            self._generate_jit[key] = self._capacity.bind_key(key)
         elif self._auto_layouts() and not getattr(self, "_layouts_pinned",
                                                   False):
             # FIRST program pins the layouts; later (b, s) programs
             # compile against the now-custom layouts of the live params
             # (re-placing per program would invalidate earlier programs'
             # compiled input layouts)
-            if key not in self._generate_jit:
-                self._generate_jit[key] = self._compile_auto_layout(
-                    self._build_for_key(key, auto_layout=True),
-                    input_ids, rng)
-                self._layouts_pinned = True
-                # the AOT executable already exists here — ledger it free
-                self._ledger_capture(key, compiled=self._last_aot_compiled,
-                                     input_ids=input_ids, rng=rng)
-        elif key not in self._generate_jit:
+            self._generate_jit[key] = self._compile_auto_layout(
+                self._build_for_key(key, auto_layout=True), input_ids, rng)
+            self._layouts_pinned = True
+            # the AOT executable already exists here — ledger it free
+            self._ledger_capture(key, compiled=self._last_aot_compiled,
+                                 input_ids=input_ids, rng=rng)
+        else:
             jfn = self._build_for_key(key)
             self._generate_jit[key] = jfn
             self._ledger_capture(key, jfn=jfn, input_ids=input_ids, rng=rng)
-        return self._dispatch_generate(key, input_ids, rng, b,
-                                       int(max_new_tokens))
 
     def _ledger_name(self, key) -> str:
         """Stable ledger row name for one generate key (same stability
@@ -602,6 +609,8 @@ class InferenceEngine:
                 if max_new_tokens > 1 else last[:, None]
             return jnp.concatenate([ids, new], axis=1)
 
+        # `jit_ds_v1_generate` on the device trace's `XLA Modules` line
+        gen.__name__ = "ds_v1_generate"
         if auto_layout:
             from deepspeed_tpu.utils.layouts import auto_input_format
             return jax.jit(gen, in_shardings=auto_input_format())

@@ -220,6 +220,7 @@ class PagedKVCache:
                          stage=_stage(), scales=_scales()),
             index=jnp.zeros((batch,), jnp.int32))
 
+    @jax.named_scope("kv_stage")
     def apply_stage(self) -> "PagedKVCache":
         """Land every layer's staged decode token in the pool with one
         batched scatter per pool (vs one per layer in unstaged decode).
@@ -364,6 +365,7 @@ def gather_paged_layer(layer: PagedLayer, dtype: Any = None) -> jnp.ndarray:
     return jnp.moveaxis(dense, 0, 2)                        # (B, M, Hkv, D)
 
 
+@jax.named_scope("kv_write")
 def update_layer(k_cache, v_cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
                  index: jnp.ndarray) -> Tuple[Any, Any]:
     """Insert `k_new`/`v_new` (B, S, Hkv, D) at per-row positions

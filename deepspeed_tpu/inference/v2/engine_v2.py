@@ -28,11 +28,12 @@ from deepspeed_tpu.inference.kv_cache import KVCache, PagedKVCache
 from deepspeed_tpu.inference.v2.ragged import DSStateManager
 from deepspeed_tpu.resilience.faults import fault_point, is_oom_error
 from deepspeed_tpu.telemetry import (RecompileDetector, RequestTracer,
-                                     annotate, get_hub)
+                                     compile_span, compile_totals, get_hub)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
 _BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
+_OFF = nullcontext()   # in place of a span while the tracer is off
 
 
 def _uid_fold(uid) -> int:
@@ -192,7 +193,13 @@ class InferenceEngineV2:
             "flushed_sequences": 0, "generated_tokens": 0,
             "decode_waves": 0, "mixed_rounds": 0,
             "spec_rounds": 0, "spec_draft_tokens": 0,
-            "spec_accepted_tokens": 0}
+            "spec_accepted_tokens": 0,
+            # counted where the work happens, tracing on or off: `put`
+            # rounds, block-table pushes to the device, and the token
+            # slots (rows x positions) the dispatched programs computed
+            # against the tokens they were fed (speculative rounds apart)
+            "rounds": 0, "table_syncs": 0,
+            "token_slots_computed": 0, "tokens_fed": 0}
         self._kv_util_peak = 0.0
         self._rng = jax.random.PRNGKey(0)
         self._setup_spec()
@@ -502,7 +509,7 @@ class InferenceEngineV2:
 
         return self._register(key, copy, donate=(0,))
 
-    def _maybe_sync_tables(self) -> None:
+    def _maybe_sync_tables(self):
         """Push host-side block-table edits to the device cache. Called
         before every compiled step; a no-op unless allocation changed (the
         common decode round re-uses the resident tables). Tables are
@@ -510,9 +517,10 @@ class InferenceEngineV2:
         would change the jit cache key and recompile the serving programs.
         Queued COW copies drain here FIRST (they read pre-step source
         content; steps only run after this sync), batched into one padded
-        gather/scatter — never a per-copy dispatch."""
+        gather/scatter — never a per-copy dispatch. Returns (the tables
+        were dirty, COW copies drained)."""
         if self.kv_layout != "paged":
-            return
+            return False, 0
         copies = (self.block_manager.drain_copies()
                   if self.block_manager is not None else [])
         if copies:
@@ -525,11 +533,14 @@ class InferenceEngineV2:
             self.cache = self._copy_blocks_fn(width)(
                 self.cache, jnp.asarray(src), jnp.asarray(dst))
             self._tables_dirty = True  # every cow rewrote a table entry
-        if self._tables_dirty:
+        dirty = self._tables_dirty
+        if dirty:
             self.cache = jax.device_put(
                 self.cache.with_tables(jnp.asarray(self._tables_np)),
                 self._cache_pin)
             self._tables_dirty = False
+            self.serving_counters["table_syncs"] += 1
+        return dirty, len(copies)
 
     def _match_prefix(self, seq, tokens) -> int:
         """Admission-time prefix match: share the longest committed block
@@ -605,21 +616,20 @@ class InferenceEngineV2:
     def _register(self, key, body, donate=(1,)):
         """Build-register a serving program: jit (donating the cache
         argument) + `_track` wrapping, or the eager body in capacity mode.
-        The `self._jits[key] = fn` assignment is the TimingDict hook
-        fastgen_breakdown.py instruments — every builder must go through
-        here (or assign the same way)."""
+        The jitted body is renamed so that the device trace's `XLA Modules`
+        line reads `jit_ds_v2_<program>`, not a closure's name."""
         if key in self._jits:
             return self._jits[key]
         fault_point("program_compile", label=self.serve_mode)
         if self._eager_serving:
             fn = self._track(key, body, raw=False)
         else:
+            body.__name__ = "ds_v2_" + (key if isinstance(key, str)
+                                        else str(key[0]))
             fn = self._track(key, jax.jit(body, donate_argnums=donate),
                              body=body)
         self._jits[key] = fn
-        # read back through the dict: a TimingDict __setitem__ may have
-        # wrapped fn, and callers must dispatch the instrumented version
-        return self._jits[key]
+        return fn
 
     def _track(self, key, fn, body=None, raw=True):
         """Wrap a compiled serving program with dispatch-time signature
@@ -654,13 +664,17 @@ class InferenceEngineV2:
         if fp:
             name = f"{name}@{fp}"
         det = self.recompiles
+        first = True
 
         def wrapped(*args):
+            nonlocal first
             if (body is not None and not self._layouts_pinned
                     and self._auto_layouts() and args
                     and args[0] is self.params):
                 rest = args[1:]
-                self._pin_param_layouts(body, rest)
+                with compile_span(name, "v2", phase="pin_layouts",
+                                  under=self.tracer.current()):
+                    self._pin_param_layouts(body, rest)
                 args = (self.params,) + rest
             det.observe(name, args)
             from deepspeed_tpu.telemetry.ledger import get_ledger
@@ -668,6 +682,12 @@ class InferenceEngineV2:
             if led.enabled and name not in self._ledger_captured:
                 self._ledger_captured.add(name)
                 led.capture(f"v2:{name}", fn=fn, args=args)
+            if first:
+                # the program's compile (or its load from the persistent
+                # cache) is this call: one `compile` span with its name
+                first = False
+                with compile_span(name, "v2", under=self.tracer.current()):
+                    return fn(*args)
             return fn(*args)
         # the raw jit and the detector name, for tools/tpuverify (the
         # wrapper hides .lower(); the verifier lowers the raw program and
@@ -803,6 +823,7 @@ class InferenceEngineV2:
                 **self.serving_counters}
 
     # ------------------------------------------------------------ compiled
+    @jax.named_scope("row_view")
     def _row_view(self, cache, slot, start):
         """A batch-of-1 view of `slot`'s cache row. Dense: slice the row
         arrays. Paged: slice only the (L, B, T) block tables — the pools are
@@ -823,6 +844,7 @@ class InferenceEngineV2:
             v=jax.lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1),
             index=start[None])
 
+    @jax.named_scope("merge_row")
     def _merge_row(self, cache, row, slot, new_index):
         """Fold a row view's updates back into the full cache."""
         if self.kv_layout == "paged":
@@ -840,9 +862,11 @@ class InferenceEngineV2:
         def prefill(params, cache, ids, slot, true_len):
             row = self._row_view(cache, slot, jnp.zeros((), jnp.int32))
             logits, row = apply(params, ids, row)
-            last = jnp.take_along_axis(
-                logits, (true_len - 1)[None, None, None].astype(jnp.int32),
-                axis=1)[0, 0]
+            with jax.named_scope("head"):
+                last = jnp.take_along_axis(
+                    logits,
+                    (true_len - 1)[None, None, None].astype(jnp.int32),
+                    axis=1)[0, 0]
             return self._merge_row(cache, row, slot, true_len), last
 
         return self._register(key, prefill)
@@ -859,9 +883,10 @@ class InferenceEngineV2:
         def chunk_into(params, cache, ids, slot, start, valid):
             row = self._row_view(cache, slot, start)
             logits, row = apply(params, ids, row)
-            last = jnp.take_along_axis(
-                logits, (valid - 1)[None, None, None].astype(jnp.int32),
-                axis=1)[0, 0]
+            with jax.named_scope("head"):
+                last = jnp.take_along_axis(
+                    logits, (valid - 1)[None, None, None].astype(jnp.int32),
+                    axis=1)[0, 0]
             return self._merge_row(cache, row, slot, start + valid), last
         return chunk_into
 
@@ -885,22 +910,26 @@ class InferenceEngineV2:
             # and the index scatter DROPS them — a parked row must never
             # collide with a live row's slot in the scatter (duplicate-index
             # scatter is last-wins)
-            rows = PagedKVCache(
-                k=cache.k.replace(tables=jnp.take(cache.k.tables, slots,
-                                                  axis=1, mode="clip"),
-                                  stage=None),  # chunks write the pool
-                v=cache.v.replace(tables=jnp.take(cache.v.tables, slots,
-                                                  axis=1, mode="clip"),
-                                  stage=None),
-                index=starts)
+            with jax.named_scope("table_gather"):
+                rows = PagedKVCache(
+                    k=cache.k.replace(tables=jnp.take(cache.k.tables, slots,
+                                                      axis=1, mode="clip"),
+                                      stage=None),  # chunks write the pool
+                    v=cache.v.replace(tables=jnp.take(cache.v.tables, slots,
+                                                      axis=1, mode="clip"),
+                                      stage=None),
+                    index=starts)
             logits, rows = apply(params, ids, rows)
-            index = cache.index.at[slots].set(starts + valids, mode="drop")
-            new_cache = PagedKVCache(k=cache.k.replace(pool=rows.k.pool),
-                                     v=cache.v.replace(pool=rows.v.pool),
-                                     index=index)
-            last = jnp.take_along_axis(
-                logits, jnp.maximum(valids - 1, 0)[:, None, None],
-                axis=1)[:, 0]          # (R, V) — one next-token row each
+            with jax.named_scope("merge_row"):
+                index = cache.index.at[slots].set(starts + valids,
+                                                  mode="drop")
+                new_cache = PagedKVCache(
+                    k=cache.k.replace(pool=rows.k.pool),
+                    v=cache.v.replace(pool=rows.v.pool), index=index)
+            with jax.named_scope("head"):
+                last = jnp.take_along_axis(
+                    logits, jnp.maximum(valids - 1, 0)[:, None, None],
+                    axis=1)[:, 0]      # (R, V) — one next-token row each
             return new_cache, last
         return chunk_batch
 
@@ -965,11 +994,12 @@ class InferenceEngineV2:
             logits, cache = apply(params, toks, cache)
             cache = cache.apply_stage()
             cache = cache.replace(index=jnp.where(active, old + 1, old))
-            last = logits[:, -1, :]
-            if sampled:
-                nxt = sample_logits(last, rng_i, *cfg, row_fold=fold)
-            else:
-                nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                last = logits[:, -1, :]
+                if sampled:
+                    nxt = sample_logits(last, rng_i, *cfg, row_fold=fold)
+                else:
+                    nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
             return cache, nxt
 
         if self._eager_serving:
@@ -1265,6 +1295,16 @@ class InferenceEngineV2:
             return need <= self.state_manager.block_allocator.free_blocks
         return True
 
+    def _count_slots(self, slots: int, fed: int, fields=None) -> None:
+        """Token slots (rows x positions) the dispatched program computes
+        against the tokens it was fed: into the lifetime counters always,
+        onto the round's span when there is one."""
+        self.serving_counters["token_slots_computed"] += slots
+        self.serving_counters["tokens_fed"] += fed
+        if fields is not None:
+            fields["token_slots"] = slots
+            fields["tokens_fed"] = fed
+
     def put(self, batch_uids: Sequence[int], batch_tokens: Sequence[np.ndarray],
             argmax_only: bool = False) -> Dict[int, np.ndarray]:
         """Schedule tokens for each uid (reference `put:107`): prompts for
@@ -1277,11 +1317,28 @@ class InferenceEngineV2:
         prompts never stall decode for more than one chunk of work. Returns
         next-token logits only for uids that produced one this round (a
         decode, or a prompt whose LAST chunk ran); keep calling put (with or
-        without new tokens) to drain the rest."""
+        without new tokens) to drain the rest.
+
+        Traced (`tracer.active`, read once here): depth-0 spans `schedule`
+        (validation, admission, prefix match, the decode batch) and
+        `prefill` / `chunk` / `decode`, whose children `feeds`, `sync`,
+        `dispatch`, `fetch`, `commit` follow each other; every record carries
+        this round's number. Untraced, a round makes no record, allocates
+        no field dict and reads no clock."""
         # BEFORE any mutation (like the validation loop below): a fault
         # retried by the caller must see un-admitted uids, not half-state
         fault_point("generate_dispatch", label="v2_put")
         tr = self.tracer
+        self.serving_counters["rounds"] += 1
+        if not tr.active:
+            return self._put(batch_uids, batch_tokens, argmax_only, tr, False)
+        tr.round = self.serving_counters["rounds"]
+        try:
+            return self._put(batch_uids, batch_tokens, argmax_only, tr, True)
+        finally:
+            tr.round = None
+
+    def _put(self, batch_uids, batch_tokens, argmax_only, tr, on):
         out: Dict[int, np.ndarray] = {}
         decode_uids: List[int] = []
         # argmax_only (the serving loop): reduce every result ON DEVICE and
@@ -1318,83 +1375,116 @@ class InferenceEngineV2:
 
             def _mat(x, fold=None):
                 return _g(x)
-        # Validate the WHOLE batch before any mutation: raising mid-loop
-        # would leave earlier uids half-admitted (slot consumed, no compute
-        # ran) and a retry would misread them as continuation feeds.
-        cap = min(self.max_seq_len, self.cache.max_len)
-        for uid, toks in zip(batch_uids, batch_tokens):
-            n = np.asarray(toks, np.int32).reshape(-1).shape[0]
-            if self.state_manager.known_sequence(uid):
-                seq = self.state_manager.get_sequence(uid)
-                # pending holds admitted-but-unprocessed prompt chunks —
-                # they WILL occupy cache rows, so a continuation fed while
-                # a chunked prefill drains must count them or it can still
-                # run past capacity into the silent drop-write region
-                seen = seq.seen_tokens + len(seq.pending)
-            else:
-                seen = 0
-            if seen + n > cap:
-                # cache writes past the row capacity DROP (bucketed-padding
-                # protection) — feeding past it would silently corrupt the
-                # sequence's KV, so refuse loudly at the serving boundary
-                # (paged rounds cache.max_len UP to block granularity, so
-                # the user-facing max_seq_len is the binding limit)
-                raise ValueError(
-                    f"sequence {uid} would reach {seen + n} tokens "
-                    f"but max_seq_len={cap} — raise max_seq_len or shorten "
-                    "the prompt/generation budget")
-        new_short: List[Any] = []
-        for uid, toks in zip(batch_uids, batch_tokens):
-            toks = np.asarray(toks, np.int32).reshape(-1)
-            if not self.state_manager.known_sequence(uid):
-                seq = self.state_manager.get_or_create_sequence(uid)
-                self._slot_uids[seq.slot] = _uid_fold(uid)
-                tr.begin_request(uid, prompt_tokens=len(toks), slot=seq.slot)
-                seq.tokens = list(map(int, toks))
-                matched = self._match_prefix(seq, toks)
-                if matched:
-                    tr.note(uid, prefix_matched=matched)
-                    # shared blocks cover the prefix; only the remainder
-                    # runs — through the CHUNK path (its programs take a
-                    # start cursor; the single-shot prefill assumes 0)
-                    seq.pending = list(map(int, toks[matched:]))
-                elif len(toks) <= self.split_fuse_chunk:
-                    new_short.append((uid, seq, toks))
+
+        # the children of a round's span; no-ops while the tracer is off
+        phase = tr.phase if on else (lambda name: None)
+
+        def dispatch(fn, *args):
+            """The `dispatch` child: feeds to the device and the call of the
+            compiled program (which returns before the device has run it)."""
+            f = phase("dispatch")
+            n0 = compile_totals()[0] if on else 0
+            res = fn(self.params, self.cache, *[jnp.asarray(a) for a in args])
+            if on:
+                f["program"] = fn._ds_program
+                f["compiled"] = compile_totals()[0] != n0
+            return res
+
+        def sync():
+            """The `sync` child around `_maybe_sync_tables`."""
+            f = phase("sync")
+            dirty, cow = self._maybe_sync_tables()
+            if on:
+                f["dirty"], f["cow_copies"] = dirty, cow
+
+        with tr.span("schedule") if on else _OFF:
+            # Validate the WHOLE batch before any mutation: raising mid-loop
+            # would leave earlier uids half-admitted (slot consumed, no
+            # compute ran) and a retry would misread them as continuation
+            # feeds.
+            cap = min(self.max_seq_len, self.cache.max_len)
+            for uid, toks in zip(batch_uids, batch_tokens):
+                n = np.asarray(toks, np.int32).reshape(-1).shape[0]
+                if self.state_manager.known_sequence(uid):
+                    seq = self.state_manager.get_sequence(uid)
+                    # pending holds admitted-but-unprocessed prompt chunks —
+                    # they WILL occupy cache rows, so a continuation fed
+                    # while a chunked prefill drains must count them or it
+                    # can still run past capacity into the silent
+                    # drop-write region
+                    seen = seq.seen_tokens + len(seq.pending)
                 else:
-                    seq.pending = list(map(int, toks))
-            else:
-                seq = self.state_manager.get_sequence(uid)
-                if len(toks) == 0:
+                    seen = 0
+                if seen + n > cap:
+                    # cache writes past the row capacity DROP
+                    # (bucketed-padding protection) — feeding past it would
+                    # silently corrupt the sequence's KV, so refuse loudly
+                    # at the serving boundary (paged rounds cache.max_len UP
+                    # to block granularity, so the user-facing max_seq_len
+                    # is the binding limit)
                     raise ValueError(
-                        f"put got an empty token list for known uid {uid} — "
-                        "a decode feed is exactly one token, a prefill "
-                        "continuation at least one")
-                seq.tokens.extend(map(int, toks))
-                if len(toks) == 1 and not seq.pending:
-                    decode_uids.append(uid)
-                else:  # prefill continuation feed (FastGen ragged semantics)
-                    seq.pending.extend(map(int, toks))
+                        f"sequence {uid} would reach {seen + n} tokens "
+                        f"but max_seq_len={cap} — raise max_seq_len or "
+                        "shorten the prompt/generation budget")
+            new_short: List[Any] = []
+            for uid, toks in zip(batch_uids, batch_tokens):
+                toks = np.asarray(toks, np.int32).reshape(-1)
+                if not self.state_manager.known_sequence(uid):
+                    seq = self.state_manager.get_or_create_sequence(uid)
+                    self._slot_uids[seq.slot] = _uid_fold(uid)
+                    if on:
+                        tr.begin_request(uid, prompt_tokens=len(toks),
+                                         slot=seq.slot)
+                    seq.tokens = list(map(int, toks))
+                    matched = self._match_prefix(seq, toks)
+                    if matched:
+                        if on:
+                            tr.note(uid, prefix_matched=matched)
+                        # shared blocks cover the prefix; only the remainder
+                        # runs — through the CHUNK path (its programs take a
+                        # start cursor; the single-shot prefill assumes 0)
+                        seq.pending = list(map(int, toks[matched:]))
+                    elif len(toks) <= self.split_fuse_chunk:
+                        new_short.append((uid, seq, toks))
+                    else:
+                        seq.pending = list(map(int, toks))
+                else:
+                    seq = self.state_manager.get_sequence(uid)
+                    if len(toks) == 0:
+                        raise ValueError(
+                            f"put got an empty token list for known uid "
+                            f"{uid} — a decode feed is exactly one token, a "
+                            "prefill continuation at least one")
+                    seq.tokens.extend(map(int, toks))
+                    if len(toks) == 1 and not seq.pending:
+                        decode_uids.append(uid)
+                    else:  # prefill continuation feed (FastGen ragged
+                        seq.pending.extend(map(int, toks))   # semantics)
         # Short prompts: a LONE one takes the single-shot bucketed prefill
         # (cheapest); SEVERAL arriving together go through the batched
         # chunk program instead — N joins cost one dispatch, not N
         # (reference ragged batching).
         def single_prefill(uid, seq, toks):
             sp = _bucket(len(toks))
-            with tr.span("prefill", uids=(uid,), bucket=sp,
-                         tokens=len(toks)):
+            with (tr.span("prefill", uids=(uid,), bucket=sp,
+                          tokens=len(toks)) if on else _OFF) as pf:
+                phase("feeds")
                 ids = np.zeros((1, sp), np.int32)
                 ids[0, :len(toks)] = toks
                 fn = self._prefill_fn(sp)
                 self._reserve(seq, len(toks))
-                self._maybe_sync_tables()
-                self.cache, last = fn(self.params, self.cache,
-                                      jnp.asarray(ids),
-                                      jnp.asarray(seq.slot, jnp.int32),
-                                      jnp.asarray(len(toks), jnp.int32))
+                self._count_slots(sp, len(toks), pf)
+                sync()
+                self.cache, last = dispatch(
+                    fn, ids, np.asarray(seq.slot, np.int32),
+                    np.asarray(len(toks), np.int32))
+                phase("fetch")
+                got = _mat(last, np.asarray([_uid_fold(uid)], np.int32)
+                           if getattr(last, "ndim", 1) == 2 else None)
+                phase("commit")
                 seq.seen_tokens = len(toks)
                 self._commit_prefix(seq)
-                out[uid] = _mat(last, np.asarray([_uid_fold(uid)], np.int32)
-                                if getattr(last, "ndim", 1) == 2 else None)
+                out[uid] = got
 
         lone_short = len(new_short) == 1 and (
             self.kv_layout != "paged" or not any(
@@ -1409,21 +1499,22 @@ class InferenceEngineV2:
             else:  # slot layout has no batched chunk program
                 for uid, seq, toks in new_short:
                     single_prefill(uid, seq, toks)
-        # every mid-prefill sequence advances one chunk this round, whether
-        # its tokens arrived in this call or an earlier one
-        chunk_uids = [uid for uid, seq in
-                      self.state_manager.tracked_sequences.items()
-                      if seq.pending]
+        with tr.span("schedule") if on else _OFF:
+            # every mid-prefill sequence advances one chunk this round,
+            # whether its tokens arrived in this call or an earlier one
+            chunk_uids = [uid for uid, seq in
+                          self.state_manager.tracked_sequences.items()
+                          if seq.pending]
 
-        # Build this put's decode batch once; it runs fused with the FIRST
-        # chunk if any prompt is mid-prefill.
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        active = np.zeros((self.max_batch,), bool)
-        for uid in decode_uids:
-            seq = self.state_manager.get_sequence(uid)
-            tokens[seq.slot, 0] = seq.tokens[-1]
-            active[seq.slot] = True
-            self._reserve(seq, seq.seen_tokens + 1)
+            # Build this put's decode batch once; it runs fused with the
+            # FIRST chunk if any prompt is mid-prefill.
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            active = np.zeros((self.max_batch,), bool)
+            for uid in decode_uids:
+                seq = self.state_manager.get_sequence(uid)
+                tokens[seq.slot, 0] = seq.tokens[-1]
+                active[seq.slot] = True
+                self._reserve(seq, seq.seen_tokens + 1)
 
         ran_decode = not decode_uids
         csz = self.split_fuse_chunk
@@ -1435,8 +1526,9 @@ class InferenceEngineV2:
             fused = not ran_decode and bool(decode_uids)
             span_uids = tuple(chunk_uids[:R]) + (tuple(decode_uids)
                                                  if fused else ())
-            with tr.span("chunk", uids=span_uids, fused=fused,
-                         rows=len(chunk_uids[:R])):
+            with (tr.span("chunk", uids=span_uids, fused=fused,
+                          rows=len(chunk_uids[:R])) if on else _OFF) as cf:
+                phase("feeds")
                 ids = np.zeros((R, csz), np.int32)
                 slots = np.full((R,), self.max_batch, np.int32)  # parked
                 starts = np.full((R,), self.cache.max_len, np.int32)
@@ -1451,24 +1543,34 @@ class InferenceEngineV2:
                     starts[i] = seq.seen_tokens
                     valids[i] = len(piece)
                     self._reserve(seq, seq.seen_tokens + len(piece))
-                self._maybe_sync_tables()
-                args = (jnp.asarray(ids), jnp.asarray(slots),
-                        jnp.asarray(starts), jnp.asarray(valids))
-                if not ran_decode:
-                    self.cache, logits, last = self._fused_batch_fn()(
-                        self.params, self.cache, jnp.asarray(tokens),
-                        jnp.asarray(active), *args)
+                fed = int(valids.sum())
+                if fused:
+                    self._count_slots(R * csz + self.max_batch,
+                                      fed + len(decode_uids), cf)
+                else:
+                    self._count_slots(R * csz, fed, cf)
+                folds = np.asarray([_uid_fold(u) for u in chunk_uids[:R]],
+                                   np.int32)
+                sync()
+                if fused:
+                    self.cache, logits, last = dispatch(
+                        self._fused_batch_fn(), tokens, active, ids, slots,
+                        starts, valids)
+                    phase("fetch")
                     logits_np = _mat(logits, self._slot_uids)
+                    last_np = _mat(last, folds)
+                    phase("commit")
                     for duid in decode_uids:
                         dseq = self.state_manager.get_sequence(duid)
                         dseq.seen_tokens += 1
                         out[duid] = logits_np[dseq.slot]
                     ran_decode = True
                 else:
-                    self.cache, last = self._chunk_batch_fn()(
-                        self.params, self.cache, *args)
-                last_np = _mat(last, np.asarray(
-                    [_uid_fold(u) for u in chunk_uids[:R]], np.int32))
+                    self.cache, last = dispatch(
+                        self._chunk_batch_fn(), ids, slots, starts, valids)
+                    phase("fetch")
+                    last_np = _mat(last, folds)
+                    phase("commit")
                 for i, uid in enumerate(chunk_uids[:R]):
                     seq = self.state_manager.get_sequence(uid)
                     piece = pieces[uid]
@@ -1480,56 +1582,69 @@ class InferenceEngineV2:
             chunk_uids = chunk_uids[R:]
         for uid in chunk_uids:  # slot layout: ONE chunk each this round
             fused = not ran_decode and bool(decode_uids)
-            with tr.span("chunk", uids=(uid,) + (tuple(decode_uids)
-                                                 if fused else ()),
-                         fused=fused, rows=1):
+            with (tr.span("chunk", uids=(uid,) + (tuple(decode_uids)
+                                                  if fused else ()),
+                          fused=fused, rows=1) if on else _OFF) as cf:
+                phase("feeds")
                 seq = self.state_manager.get_sequence(uid)
                 piece = seq.pending[:csz]
                 ids = np.zeros((1, csz), np.int32)
                 ids[0, :len(piece)] = piece
                 self._reserve(seq, seq.seen_tokens + len(piece))
-                self._maybe_sync_tables()
-                args = (self.params, self.cache, jnp.asarray(ids),
-                        jnp.asarray(seq.slot, jnp.int32),
-                        jnp.asarray(seq.seen_tokens, jnp.int32),
-                        jnp.asarray(len(piece), jnp.int32))
-                if not ran_decode:
-                    p, c, i, sl, st, vl = args
-                    self.cache, logits, last = self._fused_fn()(
-                        p, c, jnp.asarray(tokens), jnp.asarray(active),
-                        i, sl, st, vl)
+                if fused:
+                    self._count_slots(csz + self.max_batch,
+                                      len(piece) + len(decode_uids), cf)
+                else:
+                    self._count_slots(csz, len(piece), cf)
+                sync()
+                row = (ids, np.asarray(seq.slot, np.int32),
+                       np.asarray(seq.seen_tokens, np.int32),
+                       np.asarray(len(piece), np.int32))
+                if fused:
+                    self.cache, logits, last = dispatch(
+                        self._fused_fn(), tokens, active, *row)
+                    phase("fetch")
                     logits_np = _mat(logits, self._slot_uids)
+                else:
+                    self.cache, last = dispatch(self._chunk_fn(), *row)
+                    phase("fetch")
+                last_np = None
+                if len(seq.pending) <= len(piece):  # final chunk
+                    last_np = _mat(last,
+                                   np.asarray([_uid_fold(uid)], np.int32)
+                                   if getattr(last, "ndim", 1) == 2
+                                   else None)
+                phase("commit")
+                if fused:
                     for duid in decode_uids:
                         dseq = self.state_manager.get_sequence(duid)
                         dseq.seen_tokens += 1
                         out[duid] = logits_np[dseq.slot]
                     ran_decode = True
-                else:
-                    self.cache, last = self._chunk_fn()(*args)
                 seq.pending = seq.pending[len(piece):]
                 seq.seen_tokens += len(piece)
                 if not seq.pending:  # final chunk → next-token logits
                     self._commit_prefix(seq)
-                    out[uid] = _mat(last,
-                                    np.asarray([_uid_fold(uid)], np.int32)
-                                    if getattr(last, "ndim", 1) == 2
-                                    else None)
+                    out[uid] = last_np
 
         if not ran_decode:
             st0 = self._stall_total()
-            with tr.span("decode", uids=tuple(decode_uids)) as df:
+            with (tr.span("decode", uids=tuple(decode_uids))
+                  if on else _OFF) as df:
+                phase("feeds")
                 fn = self._decode_fn()
-                self._maybe_sync_tables()
-                self.cache, logits = fn(self.params, self.cache,
-                                        jnp.asarray(tokens),
-                                        jnp.asarray(active))
+                self._count_slots(self.max_batch, len(decode_uids), df)
+                sync()
+                self.cache, logits = dispatch(fn, tokens, active)
+                phase("fetch")
                 logits_np = _mat(logits, self._slot_uids)
+                phase("commit")
                 for uid in decode_uids:
                     seq = self.state_manager.get_sequence(uid)
                     seq.seen_tokens += 1
                     out[uid] = logits_np[seq.slot]
                 stall = self._stall_total() - st0
-                if stall:
+                if stall and on:
                     df["prefetch_stall_ms"] = round(stall, 3)
         return out
 
@@ -1796,14 +1911,14 @@ class InferenceEngineV2:
                     self._maybe_sync_tables()
                     self._rng, sub = jax.random.split(self._rng)
                     wave_fn = self._decode_scan_fn(k)
-                    with annotate("ds:decode_wave"):
-                        t_wave = time.perf_counter()
-                        self.cache, toks = wave_fn(
-                            self.params, self.cache, jnp.asarray(tokens),
-                            jnp.asarray(active), sub,
-                            jnp.asarray(self._slot_uids, jnp.int32))
-                        toks_np = np.asarray(toks)  # (K, B)
-                        wave_ms = (time.perf_counter() - t_wave) * 1e3
+                    t_wave = time.perf_counter()
+                    self.cache, toks = wave_fn(
+                        self.params, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(active), sub,
+                        jnp.asarray(self._slot_uids, jnp.int32))
+                    toks_np = np.asarray(toks)  # (K, B)
+                    wave_ms = (time.perf_counter() - t_wave) * 1e3
+                    self._count_slots(k * self.max_batch, k * len(live), wf)
                     from deepspeed_tpu.telemetry.ledger import get_ledger
                     led = get_ledger()
                     if led.enabled:
@@ -1846,8 +1961,7 @@ class InferenceEngineV2:
             with self.tracer.span("mixed_round", uids=tuple(live),
                                   round=self.serving_counters[
                                       "mixed_rounds"]) as mf:
-                with annotate("ds:mixed_round"):
-                    outs = self.put(step_uids, step_tokens, argmax_only=True)
+                outs = self.put(step_uids, step_tokens, argmax_only=True)
                 self.serving_counters["mixed_rounds"] += 1
                 retired = []
                 for uid in list(live):

@@ -187,5 +187,6 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="self_attn_dense_decode",
     )(*args)
     return out.reshape(b, 1, h, d)

@@ -381,6 +381,7 @@ def _fwd(qs, k, v, causal, blk_q, blk_k):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
+            name="self_attn_flash_fwd",
         )(qs, k, v)
         return out, lse
 
@@ -410,6 +411,7 @@ def _fwd(qs, k, v, causal, blk_q, blk_k):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="self_attn_flash_fwd",
     )(qs, k, v)
     return out, lse
 
@@ -453,6 +455,7 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
+            name="self_attn_flash_bwd",
         )(qs, k, v, do, lse, delta)
 
         def qcol_ix(b_, h_, t):
@@ -480,6 +483,7 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
+            name="self_attn_flash_bwd",
         )(qs, k, v, do, lse, delta)
     else:
         q_spec = pl.BlockSpec((1, 1, blk_q, d),
@@ -507,6 +511,7 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=_interpret(),
+            name="self_attn_flash_bwd",
         )(qs, k, v, do, lse, delta)
 
         # dk/dv: grid over kv blocks, loop q blocks; one (dk, dv) per
@@ -540,6 +545,7 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=_interpret(),
+            name="self_attn_flash_bwd",
         )(qs, k, v, do, lse, delta)
 
     if n_rep > 1:

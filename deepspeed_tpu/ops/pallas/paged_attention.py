@@ -273,6 +273,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="self_attn_paged_decode",
     )(*args)
     return out.reshape(b, 1, h, d)
 
@@ -470,6 +471,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="self_attn_paged_prefill",
     )(*args)
     # (B, NQ, Hkv, cq·n_rep, D) → (B, S, H, D)
     out = out.reshape(b, nq, hkv, cq, n_rep, d)

@@ -45,7 +45,7 @@ from deepspeed_tpu.runtime.precision import (
 from deepspeed_tpu.runtime.zero.partition import ZeroShardingPlan
 from deepspeed_tpu.ops.optimizers import GradientTransformation, build_optimizer
 from deepspeed_tpu.telemetry import (
-    MetricsState, RecompileDetector, TelemetryHub, annotate)
+    MetricsState, RecompileDetector, TelemetryHub, annotate, compile_span)
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.groups import MeshTopology
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -1007,7 +1007,11 @@ class DeepSpeedEngine:
         # on a state program means a recompile — counted, and visible in
         # the telemetry stream instead of reading as a mystery stall
         self.recompiles.observe(name, (state,) + tuple(rest))
-        out = self._get_jit(name)(state, *rest)
+        if name in self._jit_cache:
+            out = self._jit_cache[name](state, *rest)
+        else:   # the program's build, compile and first dispatch
+            with compile_span(f"train:{name}", "train"):
+                out = self._get_jit(name)(state, *rest)
         if self._offload_manual:
             out = self._restage(out) if isinstance(out, TrainState) \
                 else (self._restage(out[0]),) + tuple(out[1:])

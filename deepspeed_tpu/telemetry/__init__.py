@@ -19,6 +19,10 @@ Here the loop is one compiled program, so observability splits into:
   decomposition with an ``unattributed`` residual invariant, streaming
   TTFT/TPOT/e2e histograms, and Chrome-trace export;
 - ``trace_capture``/``annotate`` (tracing.py): perfetto trace hooks;
+  ``compile_span``/``compile_records``: set-up time by program, from one
+  ``jax.monitoring`` listener installed when this package is imported;
+- ``get_span_store`` (spans.py): the closed spans of the last rounds, kept
+  after their requests, process-global like ``get_hub()``;
 - ``MemoryPlane`` (memory.py): the tiered residency ledger every placement
   path registers into — per-tier/per-component byte accounting, watermarks,
   and formula reconciliation (docs/memory.md).
@@ -35,5 +39,9 @@ from deepspeed_tpu.telemetry.ledger import (  # noqa: F401
 from deepspeed_tpu.telemetry.metrics import MetricsState, host_metrics  # noqa: F401
 from deepspeed_tpu.telemetry.recompile import RecompileDetector  # noqa: F401
 from deepspeed_tpu.telemetry.spans import (  # noqa: F401
-    Histogram, RequestTracer, export_chrome_trace)
-from deepspeed_tpu.telemetry.tracing import annotate, trace_capture  # noqa: F401
+    Histogram, RequestTracer, SpanStore, export_chrome_trace, get_span_store)
+from deepspeed_tpu.telemetry.tracing import (  # noqa: F401
+    annotate, compile_records, compile_span, compile_totals,
+    install_compile_listener, trace_capture)
+
+install_compile_listener()

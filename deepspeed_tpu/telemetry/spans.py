@@ -21,7 +21,18 @@ Design constraints (the r6 hub discipline, CLAUDE.md):
 - Spans nest (put()'s prefill/chunk/decode inside _generate's
   mixed_round): only depth-0 intervals enter the wall-time decomposition
   so nothing double-counts; nested intervals still export to the Chrome
-  trace.
+  trace. Every record carries its own `id`, the id of the span that was
+  open around it (`parent`) and the `put` round it ran in (`round`); a
+  layer's self time is its span less its children. `phase()` opens the
+  sequential children of a round's span (feeds, sync, dispatch, fetch,
+  commit): each closes the one before it.
+- Closed spans outlive their requests in ONE bounded process-global
+  `SpanStore` (`get_span_store()`), on the tracer's clock itself
+  (`perf_counter`), so a reader can select a window by time after the run.
+  The tracer's own intervals are still pruned with the last open request.
+- An active `span()` also enters `jax.profiler.TraceAnnotation("ds:<name>")`,
+  so a profile taken around the serving loop shows the program's spans
+  over the device ops. Nothing is entered when the tracer is off.
 
 Attribution rule: a depth-0 interval overlapping a request's [admit, done]
 window is clipped to the window and credited to its span name when the
@@ -40,6 +51,8 @@ timeline in `export_chrome_trace`.
 from __future__ import annotations
 
 import bisect
+import collections
+import itertools
 import math
 import time
 import weakref
@@ -54,6 +67,45 @@ INSTANT_KINDS = ("fault", "retry", "watchdog", "serve_mode_degraded",
 
 _INSTANT_CAP = 4096      # bound the in-memory instant mirror
 _INTERVAL_CAP = 65536    # hard bound on retained intervals (safety valve)
+_STORE_CAP = 32768       # closed spans kept after their requests: ~4000 rounds
+ANNOTATION_PREFIX = "ds:"   # the program's names in a jax.profiler trace
+_IDS = itertools.count(1)   # span ids: unique in the process, across tracers
+
+
+# ------------------------------------------------------------- span store
+class SpanStore:
+    """The closed spans of the last rounds, process-global and bounded.
+
+    One record per closed span, as the tracer keeps it (`name`, `id`,
+    `parent`, `round`, `depth`, `uids`, `slots`, `fields`) plus `engine`,
+    with `t0`/`t1` in seconds on the clock the tracer runs on
+    (`perf_counter`), NOT from the tracer's epoch. The oldest fall out."""
+
+    def __init__(self, cap: int = _STORE_CAP):
+        self._spans: collections.deque = collections.deque(maxlen=cap)
+
+    def add(self, rec: Dict[str, Any]) -> None:
+        self._spans.append(rec)
+
+    def spans(self, t0: Optional[float] = None, t1: Optional[float] = None
+              ) -> List[Dict[str, Any]]:
+        """The stored spans that lie wholly inside [t0, t1], oldest first."""
+        return [r for r in self._spans
+                if (t0 is None or r["t0"] >= t0)
+                and (t1 is None or r["t1"] <= t1)]
+
+    def clear(self) -> None:
+        self._spans.clear()
+
+    def __len__(self) -> int:
+        return len(self._spans)
+
+
+_STORE = SpanStore()
+
+
+def get_span_store() -> SpanStore:
+    return _STORE
 
 
 # --------------------------------------------------------------- histogram
@@ -145,7 +197,8 @@ class RequestTracer:
         self._clock = clock
         self.epoch_unix = time.time()
         self._t0 = clock()
-        self._depth = 0
+        self._stack: List[Dict[str, Any]] = []   # the spans open now
+        self.round: Optional[int] = None   # the `put` round, set by put
         self._intervals: List[Dict[str, Any]] = []
         self._open: Dict[Any, Dict[str, Any]] = {}
         self.last_requests: Dict[Any, Dict[str, Any]] = {}
@@ -227,24 +280,65 @@ class RequestTracer:
         if not self.active:
             yield fields
             return
-        depth = self._depth
-        self._depth += 1
-        t0 = self.now()
+        rec = self._begin(name, uids, slots, fields)
         try:
             yield fields
         finally:
-            t1 = self.now()
-            self._depth = depth
-            self._record(name, t0, t1, uids, slots, depth, fields)
+            self._end(rec)
 
-    def _record(self, name, t0, t1, uids, slots, depth, fields) -> None:
+    def phase(self, name: str) -> Dict[str, Any]:
+        """Open the next sequential child of the innermost open span, and
+        close the child before it: `feeds`, `sync`, `dispatch`, `fetch`,
+        `commit` inside a round's `prefill` / `chunk` / `decode`. The
+        parent's exit closes the last one. Returns the child's mutable
+        fields. Only for a caller that has read `active` as true."""
+        now = self.now()   # one reading: the children tile their parent
+        if self._stack[-1]["phase"]:
+            self._end(self._stack[-1], now)
+        parent = self._stack[-1]
+        return self._begin(name, parent["uids"], parent["slots"], {},
+                           phase=True, now=now)["fields"]
+
+    def current(self):
+        """(id, round) of the innermost open span; (None, None) if none."""
+        if not self._stack:
+            return None, None
+        return self._stack[-1]["id"], self._stack[-1]["round"]
+
+    def _begin(self, name, uids, slots, fields, phase=False, now=None):
+        from jax.profiler import TraceAnnotation
+        note = TraceAnnotation(ANNOTATION_PREFIX + name)
+        note.__enter__()
+        rec = {"name": name, "depth": len(self._stack), "uids": uids,
+               "slots": slots, "fields": fields, "id": next(_IDS),
+               "parent": self._stack[-1]["id"] if self._stack else None,
+               "round": self.round, "phase": phase, "note": note,
+               "t0": self.now() if now is None else now}
+        self._stack.append(rec)
+        return rec
+
+    def _end(self, rec, now=None) -> None:
+        now = self.now() if now is None else now
+        while self._stack[-1] is not rec:   # an open phase, or an exception
+            self._end(self._stack[-1], now)  # that skipped an inner exit
+        rec["t1"] = now
+        self._stack.pop()
+        rec["note"].__exit__(None, None, None)
+        self._record(rec)
+
+    def _record(self, rec) -> None:
         if len(self._intervals) >= _INTERVAL_CAP:
             self._prune()
-        rec = {"name": name, "t0": t0, "t1": t1, "depth": depth,
-               "uids": None if uids is None else tuple(uids),
-               "slots": None if slots is None else tuple(slots),
-               "fields": dict(fields)}
-        self._intervals.append(rec)
+        name, t0, t1, depth = rec["name"], rec["t0"], rec["t1"], rec["depth"]
+        uids = None if rec["uids"] is None else tuple(rec["uids"])
+        slots = None if rec["slots"] is None else tuple(rec["slots"])
+        fields = dict(rec["fields"])
+        iv = {"name": name, "t0": t0, "t1": t1, "depth": depth, "uids": uids,
+              "slots": slots, "fields": fields, "id": rec["id"],
+              "parent": rec["parent"], "round": rec["round"]}
+        self._intervals.append(iv)
+        _STORE.add({**iv, "t0": self._t0 + t0, "t1": self._t0 + t1,
+                    "engine": self.engine})
         self.spans_recorded += 1
         hub = self._hub()
         if hub.enabled:
@@ -252,16 +346,17 @@ class RequestTracer:
             hub.emit("span", name=name, engine=self.engine,
                      t0_s=round(t0, 6), t1_s=round(t1, 6),
                      dur_ms=round((t1 - t0) * 1e3, 3), depth=depth,
+                     id=rec["id"], parent=rec["parent"], round=rec["round"],
                      uids=None if uids is None else list(uids),
                      slots=None if slots is None else list(slots),
-                     fields=dict(fields) or None)
+                     fields=fields or None)
             # the span's own JSONL write (json.dumps + file flush, ~100 µs
             # on the 1-core box) happened AFTER t1 — stretch the RETAINED
             # interval over it so tracing overhead attributes to the span
             # it traced instead of leaking into `unattributed`. The emitted
             # event keeps the pre-write t1 (its dur is the phase's own).
             if depth == 0:
-                rec["t1"] = self.now()
+                iv["t1"] = self.now()
 
     # ------------------------------------------------------ request records
     def begin_request(self, uid, prompt_tokens: int = 0,

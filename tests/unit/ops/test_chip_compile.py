@@ -9,6 +9,7 @@ The cases are `chip_smoke.kernel_cases`: what this file compiles is what
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -19,6 +20,15 @@ from jax.sharding import SingleDeviceSharding
 from chip_smoke import FULL, kernel_cases
 
 CASES = kernel_cases(FULL)
+# The attention kernels' names in the device trace's `XLA Ops` line: the HLO
+# instruction of a Mosaic call is named by `pallas_call(name=...)`. The
+# benchmark's kernel metrics search for these (`perfbench/metrics/`), and
+# its older ones for their common prefix `self_attn`.
+KERNEL_NAMES = {"flash_fwd_bwd": {"self_attn_flash_fwd", "self_attn_flash_bwd"},
+                "flash_fwd": {"self_attn_flash_fwd"},
+                "decode": {"self_attn_dense_decode"},
+                "paged_decode": {"self_attn_paged_decode"},
+                "paged_prefill": {"self_attn_paged_prefill"}}
 
 
 @pytest.fixture(scope="module")
@@ -62,3 +72,12 @@ def test_kernel_compiles_for_v5e(case, v5e_chip, compiled_kernels):
         shapes)
     text = jax.jit(case.fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    want = next((names for prefix, names in KERNEL_NAMES.items()
+                 if case.name.startswith(prefix + "_")), None)
+    if want:
+        # bare here; `transpose_jvp_self_attn_flash_bwd__` where grad wraps
+        # the kernel directly (inside a model's scopes it is bare again)
+        got = {re.search(r"self_attn(_[a-z]+)+", m).group(0)
+               for m in re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                                   text)}
+        assert got == want

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models.llama import llama_config, materialize_params
 from deepspeed_tpu.resilience.faults import clear_faults, configure_faults
-from deepspeed_tpu.telemetry import TelemetryHub
+from deepspeed_tpu.telemetry import TelemetryHub, get_span_store
 from deepspeed_tpu.telemetry.hub import set_hub
 from deepspeed_tpu.utils import groups
 
@@ -66,10 +66,18 @@ PROMPTS = [[5, 6, 7, 8], [9, 10, 11]]
 def test_tracing_off_is_free_on_is_fetch_free_and_bit_identical(tiny,
                                                                 tmp_path):
     model, params = tiny
+    store = get_span_store()
+    store.clear()
     off = _v2(model, params)
     out_off = off.generate(PROMPTS, max_new_tokens=6)
     assert off.tracer.spans_recorded == 0            # free when disabled
     assert off.tracer.last_requests == {}
+    # nothing stored either, but each program's `compile` span (set-up by
+    # program is recorded whatever the tracer's state)
+    assert {s["name"] for s in store.spans()} == {"compile"}
+    assert off.recompiles.pinned_misses == 0
+    assert off.serving_counters["token_slots_computed"] >= \
+        off.serving_counters["tokens_fed"] > 0      # counted all the same
 
     set_hub(TelemetryHub(enabled=True, jsonl_path=str(tmp_path / "t.jsonl")))
     on = _v2(model, params)

@@ -1,0 +1,272 @@
+"""The phases of a v2 `put` round, the counts made where the work happens,
+the span store that outlives the requests, and set-up by program.
+
+Host-only arithmetic first (fake clock, no jax program). Then ONE scripted
+`put` sequence on a tiny engine, run once per module with the tracer on and
+once with it off; the tests read what those two runs left behind.
+"""
+
+import json
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.models.llama import llama_config, materialize_params
+from deepspeed_tpu.telemetry import (TelemetryHub, compile_records,
+                                     compile_span, get_span_store)
+from deepspeed_tpu.telemetry.hub import get_hub, set_hub
+from deepspeed_tpu.telemetry.spans import RequestTracer, SpanStore
+from deepspeed_tpu.utils import groups
+
+from .test_spans import FakeClock
+
+PHASES = ["feeds", "sync", "dispatch", "fetch", "commit"]
+
+
+@pytest.fixture()
+def store():
+    s = get_span_store()
+    s.clear()
+    yield s
+    s.clear()
+
+
+def _tracer(force=True):
+    clk = FakeClock()
+    return RequestTracer(engine="test", clock=clk, force=force), clk
+
+
+# ------------------------------------------------------------ host-only
+def test_spans_carry_id_parent_and_round(store):
+    tr, clk = _tracer()
+    tr.round = 7
+    with tr.span("chunk", uids=(1,)):
+        clk.t += 1.0
+        with tr.span("inner"):
+            clk.t += 1.0
+    tr.round = None
+    with tr.span("flush"):
+        clk.t += 0.5
+    inner, chunk, flush = store.spans()
+    assert [s["name"] for s in (inner, chunk, flush)] == ["inner", "chunk",
+                                                         "flush"]
+    assert inner["parent"] == chunk["id"] and chunk["parent"] is None
+    assert len({inner["id"], chunk["id"], flush["id"]}) == 3
+    assert inner["round"] == chunk["round"] == 7 and flush["round"] is None
+    assert chunk["uids"] == (1,) and chunk["engine"] == "test"
+
+
+def test_phases_follow_each_other_and_tile_their_parent(store):
+    tr, clk = _tracer()
+    with tr.span("decode", uids=(3,)):
+        for name, dt in zip(PHASES, (1, 2, 3, 4, 5)):
+            fields = tr.phase(name)
+            fields["n"] = dt
+            clk.t += dt
+    *kids, parent = store.spans()
+    assert [k["name"] for k in kids] == PHASES
+    assert all(k["parent"] == parent["id"] and k["uids"] == (3,)
+               for k in kids)
+    assert [k["fields"]["n"] for k in kids] == [1, 2, 3, 4, 5]
+    # each child starts where the one before it ended; none is left over
+    assert kids[0]["t0"] == parent["t0"] and kids[-1]["t1"] == parent["t1"]
+    assert all(a["t1"] == b["t0"] for a, b in zip(kids, kids[1:]))
+    assert sum(k["t1"] - k["t0"] for k in kids) == parent["t1"] - parent["t0"]
+
+
+def test_an_exception_closes_the_open_phase(store):
+    tr, clk = _tracer()
+    with pytest.raises(RuntimeError):
+        with tr.span("chunk"):
+            tr.phase("dispatch")
+            clk.t += 1.0
+            raise RuntimeError("boom")
+    assert [s["name"] for s in store.spans()] == ["dispatch", "chunk"]
+    assert tr.current() == (None, None)
+
+
+def test_store_is_on_the_tracers_clock_and_outlives_the_requests(store):
+    tr, clk = _tracer()
+    clk.t += 5.0                        # the tracer's epoch is 100.0
+    tr.begin_request(1)
+    with tr.span("prefill", uids=(1,)):
+        clk.t += 2.0
+    tr.end_request(1, new_tokens=1)     # prunes the tracer's own intervals
+    (s,) = store.spans()
+    assert (s["t0"], s["t1"]) == (105.0, 107.0)       # not from the epoch
+    assert store.spans(t0=106.0) == [] and store.spans(t1=107.0) == [s]
+
+
+def test_store_is_bounded():
+    s = SpanStore(cap=3)
+    for i in range(5):
+        s.add({"t0": i, "t1": i + 1})
+    assert [r["t0"] for r in s.spans()] == [2, 3, 4] and len(s) == 3
+
+
+def test_inactive_tracer_stores_nothing(store):
+    set_hub(TelemetryHub(enabled=False))
+    tr, _ = _tracer(force=False)
+    with tr.span("chunk", uids=(1,)):
+        pass
+    assert len(store) == 0 and tr.spans_recorded == 0
+
+
+def test_compile_span_counts_the_backend_compiles_inside_it(store, tmp_path):
+    path = tmp_path / "t.jsonl"
+    set_hub(TelemetryHub(enabled=True, jsonl_path=str(path)))
+    try:
+        before = len(compile_records())
+        with compile_span("toy:program", "v2", phase="pin_layouts"):
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(5)).block_until_ready()
+        (s,) = store.spans()
+        assert s["name"] == "compile" and s["engine"] == "v2"
+        assert s["fields"]["program"] == "toy:program"
+        assert s["fields"]["phase"] == "pin_layouts"
+        assert s["fields"]["backend_compiles"] >= 1
+        assert 0 < s["fields"]["backend_compile_s"] <= s["t1"] - s["t0"]
+        new = compile_records()[before:]
+        assert new and all(r["program"] == "toy:program" for r in new)
+        assert get_hub().counters["compiles_total"] >= 1
+        assert get_hub().counters["compile_seconds_total"] > 0
+        (ev,) = [json.loads(l) for l in open(path)]
+        assert ev["kind"] == "compile" and ev["program"] == "toy:program"
+    finally:
+        set_hub(TelemetryHub(enabled=False))
+
+
+# ------------------------------------------------- one scripted put sequence
+MAX_BATCH, CHUNK = 4, 8
+P_SHORT = [5, 6, 7, 8, 9]                 # 5 tokens: the lone bucketed prefill
+P_LONG = list(range(10, 30))              # 20 tokens: chunks of 8, 8, 4
+# round: token slots computed, tokens fed
+#  1 prefill bucket 32                      32, 5
+#  2 decode, 4 rows                          4, 1
+#  3 fused: 4 x 8 chunk slots + 4 rows      36, 8 + 1
+#  4 fused                                  36, 8 + 1
+#  5 chunk alone                            32, 4
+SLOTS, FED, ROUNDS = 140, 28, 5
+
+
+def _script(model, params, traced, profile_dir=None):
+    groups.reset_topology()
+    eng = InferenceEngineV2(model, params=params, max_batch=MAX_BATCH,
+                            max_seq_len=64, split_fuse_chunk=CHUNK,
+                            cache_block_size=16, prefix_sharing=False)
+    eng.tracer.force = traced
+    if profile_dir:
+        jax.profiler.start_trace(str(profile_dir))
+    outs = []
+    out = eng.put([1], [np.asarray(P_SHORT, np.int32)], argmax_only=True)
+    outs.append(dict(out))
+    for feed in ([1], [1, 2], [1], []):
+        toks = [[int(out[1])] if u == 1 else np.asarray(P_LONG, np.int32)
+                for u in feed]
+        new = eng.put(feed, toks, argmax_only=True)
+        outs.append(dict(new))
+        out = {**out, **new}
+    eng._flush_batch([1, 2])
+    if profile_dir:
+        jax.profiler.stop_trace()
+    return eng, [{k: int(v) for k, v in o.items()} for o in outs]
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    cfg = llama_config("llama-tiny", dtype=jnp.float32)
+    model, params = materialize_params(cfg)
+    set_hub(TelemetryHub(enabled=False))
+    store = get_span_store()
+    store.clear()
+    off, outs_off = _script(model, params, traced=False)
+    stored_off = len([s for s in store.spans() if s["name"] != "compile"])
+    store.clear()
+    logdir = tmp_path_factory.mktemp("profile")
+    on, outs_on = _script(model, params, traced=True, profile_dir=logdir)
+    spans = store.spans()
+    store.clear()
+    return {"off": off, "on": on, "outs_off": outs_off, "outs_on": outs_on,
+            "stored_off": stored_off, "spans": spans, "logdir": logdir}
+
+
+def test_counts_are_exact_tracing_on_or_off(runs):
+    for eng in (runs["off"], runs["on"]):
+        c = eng.serving_counters
+        assert (c["token_slots_computed"], c["tokens_fed"],
+                c["rounds"]) == (SLOTS, FED, ROUNDS)
+        assert c["table_syncs"] >= 2          # both prompts took new blocks
+        snap = eng.telemetry_snapshot()
+        assert snap["token_slots_computed"] == SLOTS and snap["rounds"] == 5
+    parents = [s for s in runs["spans"]
+               if s["name"] in ("prefill", "chunk", "decode")]
+    assert [(s["fields"]["token_slots"], s["fields"]["tokens_fed"])
+            for s in parents] == [(32, 5), (4, 1), (36, 9), (36, 9), (32, 4)]
+    assert [s["round"] for s in parents] == [1, 2, 3, 4, 5]
+
+
+def test_tracing_off_makes_no_record_and_changes_no_output(runs):
+    assert runs["off"].tracer.spans_recorded == 0
+    assert runs["stored_off"] == 0
+    assert runs["off"].tracer.last_requests == {}
+    assert runs["outs_on"] == runs["outs_off"]            # bit-identical
+    assert runs["on"].recompiles.pinned_misses == 0
+    assert runs["off"].recompiles.pinned_misses == 0
+    assert runs["on"].tracer.spans_recorded > 0
+
+
+def test_children_nest_under_their_round_and_cover_it(runs):
+    by_id = {s["id"]: s for s in runs["spans"]}
+    parents = [s for s in runs["spans"]
+               if s["name"] in ("prefill", "chunk", "decode")]
+    assert len(parents) == ROUNDS
+    for p in parents:
+        kids = [s for s in runs["spans"] if s["parent"] == p["id"]]
+        assert [k["name"] for k in kids] == PHASES
+        assert all(k["round"] == p["round"] and k["uids"] == p["uids"]
+                   for k in kids)
+        covered = sum(k["t1"] - k["t0"] for k in kids)
+        assert covered >= 0.98 * (p["t1"] - p["t0"])
+        sync, dispatch = kids[1], kids[2]
+        assert set(sync["fields"]) == {"dirty", "cow_copies"}
+        assert dispatch["fields"]["program"].split(":")[0] in (
+            "prefill", "decode", "fused_batch", "chunk_batch")
+        # round 4 dispatches round 3's program again: nothing compiles
+        assert dispatch["fields"]["compiled"] is (p["round"] != 4)
+    # the head of put is a span of its own, in every round
+    heads = [s for s in runs["spans"] if s["name"] == "schedule"]
+    assert {s["round"] for s in heads} == {1, 2, 3, 4, 5}
+    assert all(s["parent"] is None for s in heads)
+    # a program's first dispatch is a `compile` span under that dispatch
+    compiles = [s for s in runs["spans"] if s["name"] == "compile"]
+    assert len(compiles) == 4
+    for c in compiles:
+        assert by_id[c["parent"]]["name"] == "dispatch"
+        assert by_id[c["parent"]]["fields"]["program"] == \
+            c["fields"]["program"]
+        assert c["fields"]["backend_compiles"] >= 1
+
+
+def test_a_put_driven_request_leaves_nothing_unattributed(runs):
+    for uid in (1, 2):
+        r = runs["on"].tracer.last_requests[uid]
+        assert r["unattributed_frac"] < 0.01, r
+        assert "schedule" in {k.replace("_other", "") for k in r["spans"]}
+
+
+def test_a_plain_profile_shows_the_ds_spans(runs):
+    """What an operator's own `jax.profiler` trace of a put loop holds: the
+    program's spans as `ds:` annotations in the host plane."""
+    import glob
+    (path,) = glob.glob(str(runs["logdir"] / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("ds:")}
+    assert {"ds:schedule", "ds:prefill", "ds:chunk", "ds:decode", "ds:flush",
+            "ds:compile"} | {"ds:" + p for p in PHASES} <= names
