@@ -14,7 +14,7 @@ from __future__ import annotations
 import functools
 import time
 from contextlib import nullcontext
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,24 @@ def _bucket(n: int) -> int:
         if n <= b:
             return b
     return -(-n // 4096) * 4096
+
+
+def chunk_row_widths(max_batch: int) -> Tuple[int, ...]:
+    """Row widths of the chunk half of `fused_batch` (paged layout),
+    ascending, the last always `max_batch`. How many prompts are mid-prefill
+    at once is set by the arrival rate and the rounds a prompt takes, not by
+    `max_batch`, so the narrow rung is a fixed small width. A round costs by
+    the row, parked or not, but little per row once it is narrow (on a v5e,
+    Qwen2.5-3B, 16-token chunks: 115 ms at 48 rows, 78 at 16, 75 at 8),
+    while every further width costs about 1.7 s of set-up even out of the
+    persistent cache (it is traced and lowered before the cache can be
+    asked): hence one narrow rung, and 16 rather than 8."""
+    return (16, max_batch) if max_batch > 16 else (max_batch,)
+
+
+def width_for(rows: int, max_batch: int) -> int:
+    """The narrowest compiled chunk width that holds `rows` prompts."""
+    return next(w for w in chunk_row_widths(max_batch) if w >= rows)
 
 
 class InferenceEngineV2:
@@ -155,6 +173,7 @@ class InferenceEngineV2:
         self._capacity = None
         self._quantized = False
         self._layouts_pinned = False
+        self._chunk_family_warm = False
         self._weight_bytes_cache = None
         self._jits: Dict[Any, Any] = {}
         self._ledger_captured: set = set()
@@ -272,6 +291,7 @@ class InferenceEngineV2:
         self._spec_draft = None
         self._spec_state = {}
         self._layouts_pinned = False
+        self._chunk_family_warm = False
         self._forced_mode = nxt
         self.params = self._place_params(src)
         del src
@@ -934,13 +954,19 @@ class InferenceEngineV2:
         return chunk_batch
 
     def _chunk_batch_fn(self):
+        """Chunks alone, `max_batch` rows wide: the round that has nothing
+        to decode and more prompts than the widest narrow rung holds."""
         return self._register(("chunk_batch", self.split_fuse_chunk),
                               self._chunk_batch_parts())
 
-    def _fused_batch_fn(self):
-        """Split-fuse, batched: ONE program decodes every live row AND runs
-        every pending prompt chunk."""
-        key = ("fused_batch", self.split_fuse_chunk)
+    def _fused_batch_fn(self, width: int):
+        """Split-fuse, batched: ONE program decodes every live row (always
+        `max_batch` wide: cache rows are slots) AND runs every pending
+        prompt chunk, `width` rows of them (`chunk_row_widths`). Below
+        `max_batch` it also serves the round with no row to decode: the
+        idle decode half costs less than `max_batch` chunk rows, and a
+        chunk-only program for each width would be one more to compile."""
+        key = ("fused_batch", self.split_fuse_chunk, width)
         apply = self._apply
         chunk_batch = self._chunk_batch_parts()
 
@@ -955,6 +981,41 @@ class InferenceEngineV2:
             return cache, logits_d[:, -1, :], last
 
         return self._register(key, fused)
+
+    def _parked_rows(self, width: int):
+        """(ids, slots, starts, valids) of `width` chunk rows, every one
+        parked: slot `max_batch` (the index scatter drops it), cursor at
+        `max_len` (its writes drop), nothing valid."""
+        return (np.zeros((width, self.split_fuse_chunk), np.int32),
+                np.full((width,), self.max_batch, np.int32),
+                np.full((width,), self.cache.max_len, np.int32),
+                np.zeros((width,), np.int32))
+
+    def _warm_chunk_family(self, reduce) -> None:
+        """Compile every batched chunk program (`chunk_batch`, and
+        `fused_batch` at each width), once, before the first of them serves
+        a round: each is dispatched with every row parked and no decode row
+        active, so its writes drop and the cache comes back as it went in,
+        and `reduce` (the round's own on-device reduce) is run on each
+        (width, V) output, which compiles per shape too. A caller warms
+        with a few prompts and then meets every width under load, where a
+        first compile would cost seconds inside a round. Not rounds:
+        nothing is counted. Widest first, so the layout pin (`_track`) is
+        resolved on the program that holds the most rows."""
+        decode = (np.zeros((self.max_batch, 1), np.int32),
+                  np.zeros((self.max_batch,), bool))
+        rng = self._rng   # a sampling reduce splits it; warming must not
+        self.cache, last = self._chunk_batch_fn()(
+            self.params, self.cache,
+            *map(jnp.asarray, self._parked_rows(self.max_batch)))
+        reduce(last)
+        for width in reversed(chunk_row_widths(self.max_batch)):
+            self.cache, _, last = self._fused_batch_fn(width)(
+                self.params, self.cache,
+                *map(jnp.asarray, decode + self._parked_rows(width)))
+            reduce(last)
+        self._rng = rng
+        self._chunk_family_warm = True
 
     def _fused_fn(self):
         """The split-fuse step: ONE compiled program decodes every live row
@@ -1314,7 +1375,12 @@ class InferenceEngineV2:
         sequence (fed this call or earlier) advances by ONE chunk of
         `split_fuse_chunk` tokens, the first chunk riding the same compiled
         step as this call's decode rows (dynamic split-fuse) — so long
-        prompts never stall decode for more than one chunk of work. Returns
+        prompts never stall decode for more than one chunk of work. Paged,
+        a round computes `width x split_fuse_chunk` token slots for its
+        chunks, `width` the narrowest of `chunk_row_widths(max_batch)` that
+        holds the sequences mid-prefill, plus `max_batch` decode rows when
+        any row decodes or the width is a narrow one; the first such round
+        compiles every width. Returns
         next-token logits only for uids that produced one this round (a
         decode, or a prompt whose LAST chunk ran); keep calling put (with or
         without new tokens) to drain the rest.
@@ -1379,11 +1445,16 @@ class InferenceEngineV2:
         # the children of a round's span; no-ops while the tracer is off
         phase = tr.phase if on else (lambda name: None)
 
-        def dispatch(fn, *args):
+        def dispatch(fn, *args, family=False):
             """The `dispatch` child: feeds to the device and the call of the
-            compiled program (which returns before the device has run it)."""
+            compiled program (which returns before the device has run it).
+            `family`: a batched chunk program, the first dispatch of which
+            compiles them all (`_warm_chunk_family`)."""
             f = phase("dispatch")
             n0 = compile_totals()[0] if on else 0
+            if family and not self._chunk_family_warm:
+                self._warm_chunk_family(
+                    lambda x: _mat(x, np.zeros((x.shape[0],), np.int32)))
             res = fn(self.params, self.cache, *[jnp.asarray(a) for a in args])
             if on:
                 f["program"] = fn._ds_program
@@ -1522,19 +1593,22 @@ class InferenceEngineV2:
             # Batched split-fuse: EVERY pending chunk rides one compiled
             # step (plus the decode rows, when any) — N joining prompts no
             # longer serialize (reference ragged_wrapper's mixed batch).
-            R = self.max_batch
-            fused = not ran_decode and bool(decode_uids)
-            span_uids = tuple(chunk_uids[:R]) + (tuple(decode_uids)
-                                                 if fused else ())
+            # The chunk half is as wide as the prompts that are prefilling
+            # (the narrowest compiled width that holds them), not as wide
+            # as max_batch: a parked row costs what a real one does. Only
+            # `fused_batch` has narrow widths, so a narrow round rides it
+            # even with no row to decode.
+            rows = chunk_uids[:self.max_batch]
+            R = width_for(len(rows), self.max_batch)
+            fused = (not ran_decode and bool(decode_uids)
+                     or R < self.max_batch)
+            span_uids = tuple(rows) + (tuple(decode_uids) if fused else ())
             with (tr.span("chunk", uids=span_uids, fused=fused,
-                          rows=len(chunk_uids[:R])) if on else _OFF) as cf:
+                          rows=len(rows), width=R) if on else _OFF) as cf:
                 phase("feeds")
-                ids = np.zeros((R, csz), np.int32)
-                slots = np.full((R,), self.max_batch, np.int32)  # parked
-                starts = np.full((R,), self.cache.max_len, np.int32)
-                valids = np.zeros((R,), np.int32)
+                ids, slots, starts, valids = self._parked_rows(R)
                 pieces = {}
-                for i, uid in enumerate(chunk_uids[:R]):
+                for i, uid in enumerate(rows):
                     seq = self.state_manager.get_sequence(uid)
                     piece = seq.pending[:csz]
                     pieces[uid] = piece
@@ -1549,15 +1623,15 @@ class InferenceEngineV2:
                                       fed + len(decode_uids), cf)
                 else:
                     self._count_slots(R * csz, fed, cf)
-                folds = np.asarray([_uid_fold(u) for u in chunk_uids[:R]],
-                                   np.int32)
+                folds = np.asarray([_uid_fold(u) for u in rows], np.int32)
                 sync()
                 if fused:
                     self.cache, logits, last = dispatch(
-                        self._fused_batch_fn(), tokens, active, ids, slots,
-                        starts, valids)
+                        self._fused_batch_fn(R), tokens, active, ids, slots,
+                        starts, valids, family=True)
                     phase("fetch")
-                    logits_np = _mat(logits, self._slot_uids)
+                    if decode_uids:
+                        logits_np = _mat(logits, self._slot_uids)
                     last_np = _mat(last, folds)
                     phase("commit")
                     for duid in decode_uids:
@@ -1567,11 +1641,12 @@ class InferenceEngineV2:
                     ran_decode = True
                 else:
                     self.cache, last = dispatch(
-                        self._chunk_batch_fn(), ids, slots, starts, valids)
+                        self._chunk_batch_fn(), ids, slots, starts, valids,
+                        family=True)
                     phase("fetch")
                     last_np = _mat(last, folds)
                     phase("commit")
-                for i, uid in enumerate(chunk_uids[:R]):
+                for i, uid in enumerate(rows):
                     seq = self.state_manager.get_sequence(uid)
                     piece = pieces[uid]
                     seq.pending = seq.pending[len(piece):]
@@ -1579,7 +1654,7 @@ class InferenceEngineV2:
                     if not seq.pending:  # final chunk → next-token logits
                         self._commit_prefix(seq)
                         out[uid] = last_np[i]
-            chunk_uids = chunk_uids[R:]
+            chunk_uids = chunk_uids[self.max_batch:]
         for uid in chunk_uids:  # slot layout: ONE chunk each this round
             fused = not ran_decode and bool(decode_uids)
             with (tr.span("chunk", uids=(uid,) + (tuple(decode_uids)

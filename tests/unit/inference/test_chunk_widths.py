@@ -1,0 +1,223 @@
+"""Chunk rounds as wide as the prompts that are prefilling (paged layout).
+
+The chunk half of `fused_batch` comes in a short ladder of row widths and
+`put` takes the narrowest that holds the round's pending prompts; a narrow
+round with nothing to decode rides it too, `chunk_batch` being `max_batch`
+wide only. What must hold: a row's outputs do not depend on the width it
+rode in, every program is compiled before the first chunk round returns,
+the counters count the width that ran, and the ladder always ends at
+`max_batch`.
+
+CPU, float32, llama-tiny; 20 rows, so the rungs are 16 and 20.
+"""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, engine_v2
+from deepspeed_tpu.inference.v2.engine_v2 import chunk_row_widths, width_for
+from deepspeed_tpu.models.llama import llama_config, materialize_params
+from deepspeed_tpu.telemetry import (TelemetryHub, compile_totals,
+                                     get_span_store)
+from deepspeed_tpu.telemetry.hub import set_hub
+from deepspeed_tpu.utils import groups
+
+MAX_BATCH, CHUNK = 20, 8
+WIDTHS = (16, 20)
+SHORT = 5                     # a lone prompt this long takes the prefill bucket
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    set_hub(TelemetryHub(enabled=False))
+    cfg = llama_config("llama-tiny", dtype=jnp.float32)
+    return (cfg,) + tuple(materialize_params(cfg))
+
+
+def _engine(tiny):
+    _, model, params = tiny
+    groups.reset_topology()
+    return InferenceEngineV2(model, params=params, max_batch=MAX_BATCH,
+                             max_seq_len=64, split_fuse_chunk=CHUNK,
+                             cache_block_size=16, kv_layout="paged",
+                             prefix_sharing=False)
+
+
+def _prompts(vocab, n, seed):
+    """`n` prompts longer than a chunk, of lengths that differ (so rows end
+    in different rounds and the round narrows) and that are no multiple of
+    the chunk (so each ends in a short chunk)."""
+    rng = np.random.default_rng([seed, n])
+    return [rng.integers(1, vocab, CHUNK + 1 + (3 * i) % 17).astype(np.int32)
+            for i in range(n)]
+
+
+def _serve(eng, vocab, pending, decoding, seed, argmax_only=False):
+    """One scripted mix: `decoding` short prompts join one by one and then
+    decode along, `pending` long prompts join together, rounds run until
+    every prompt is in, then two rounds of plain decode; everything is
+    flushed. Returns what each round gave, by uid."""
+    rounds, feed = [], {}
+    rng = np.random.default_rng([seed, 7])
+
+    def put(uids, toks):
+        uids, toks = list(feed) + uids, [[t] for t in feed.values()] + toks
+        got = eng.put(uids, toks, argmax_only=argmax_only)
+        for uid, o in got.items():
+            feed[uid] = int(o) if argmax_only else int(np.argmax(o))
+        rounds.append({u: np.asarray(o) for u, o in got.items()})
+
+    for d in range(decoding):
+        put([100 + d], [rng.integers(1, vocab, SHORT).astype(np.int32)])
+    put(list(range(1, pending + 1)), _prompts(vocab, pending, seed))
+    while any(s.pending for s in eng.state_manager.tracked_sequences.values()):
+        put([], [])
+    put([], [])
+    put([], [])
+    eng._flush_batch(list(feed))
+    return rounds
+
+
+# (rows pending, rows decoding): every rung, with and without decode rows
+MIXES = [(1, 0), (1, 2), (3, 0), (3, 2), (7, 0), (7, 2), (13, 0), (13, 3),
+         (MAX_BATCH, 0), (MAX_BATCH - 1, 1)]
+
+
+@pytest.fixture(scope="module")
+def transcripts(tiny):
+    """The same scripts on the ladder and on the ladder collapsed to
+    `[max_batch]` (what every chunk round was before there was a ladder)."""
+    vocab = tiny[0].vocab_size
+    out = {}
+    mp = pytest.MonkeyPatch()
+    for name in ("ladder", "collapsed"):
+        if name == "collapsed":
+            mp.setattr(engine_v2, "chunk_row_widths", lambda mb: (mb,))
+        try:
+            eng = _engine(tiny)
+            out[name] = [_serve(eng, vocab, p, d, seed=i)
+                         for i, (p, d) in enumerate(MIXES)]
+            out[name + "_programs"] = sorted(eng.recompiles._seen)
+        finally:
+            mp.undo()
+    return out
+
+
+@pytest.mark.parametrize("case", range(len(MIXES)),
+                         ids=[f"pending{p}-decoding{d}" for p, d in MIXES])
+def test_a_rows_outputs_do_not_depend_on_the_width_it_rode_in(transcripts,
+                                                              case):
+    ladder, flat = transcripts["ladder"][case], transcripts["collapsed"][case]
+    assert len(ladder) == len(flat)
+    produced = 0
+    for a, b in zip(ladder, flat):
+        assert sorted(a) == sorted(b)
+        for uid in a:
+            assert int(np.argmax(a[uid])) == int(np.argmax(b[uid]))
+            np.testing.assert_allclose(a[uid], b[uid], rtol=1e-5, atol=1e-5)
+            produced += 1
+    pending, decoding = MIXES[case]
+    assert produced >= pending + decoding
+
+
+def _family(widths):
+    return sorted([f"chunk_batch:{CHUNK}"] +
+                  [f"fused_batch:{CHUNK}:{w}" for w in widths])
+
+
+def test_the_two_runs_differ_only_in_the_ladder(transcripts):
+    assert [p for p in transcripts["ladder_programs"] if "batch" in p] == \
+        _family(WIDTHS)
+    assert [p for p in transcripts["collapsed_programs"] if "batch" in p] == \
+        _family([MAX_BATCH])
+
+
+@pytest.fixture(scope="module")
+def warmed(tiny):
+    """An engine that has served one lone prompt and one chunked prompt (the
+    first chunk round, which compiles the family) with decode rows beside
+    it, and flushed them."""
+    eng = _engine(tiny)
+    _serve(eng, tiny[0].vocab_size, 1, 1, seed=99, argmax_only=True)
+    return eng
+
+
+@pytest.mark.parametrize("decoding", [0, 1], ids=["alone", "fused"])
+@pytest.mark.parametrize("pending", range(1, MAX_BATCH + 1))
+def test_no_pending_count_compiles_after_the_first_chunk_round(
+        tiny, warmed, pending, decoding):
+    if pending + decoding > MAX_BATCH:
+        pytest.skip("every row is prefilling: none is left to decode")
+    n0, m0 = compile_totals()[0], warmed.recompiles.pinned_misses
+    programs = set(warmed.recompiles._seen)
+    _serve(warmed, tiny[0].vocab_size, pending, decoding, seed=pending,
+           argmax_only=True)
+    assert compile_totals()[0] == n0
+    assert warmed.recompiles.pinned_misses == m0
+    assert set(warmed.recompiles._seen) == programs
+
+
+def test_the_first_chunk_round_compiles_every_width_and_counts_one_round(tiny):
+    eng = _engine(tiny)
+    eng.put([1], [_prompts(tiny[0].vocab_size, 1, 0)[0]], argmax_only=True)
+    assert sorted(eng.recompiles._seen) == _family(WIDTHS)
+    c = eng.serving_counters
+    # the parked dispatches are not rounds and feed nothing; the one round
+    # rode the narrowest `fused_batch`, whose decode half ran idle
+    assert (c["rounds"], c["token_slots_computed"], c["tokens_fed"]) == \
+        (1, WIDTHS[0] * CHUNK + MAX_BATCH, CHUNK)
+
+
+@pytest.mark.parametrize("decoding", [0, 1], ids=["alone", "fused"])
+@pytest.mark.parametrize("pending,width", [(1, 16), (8, 16), (9, 16),
+                                           (16, 16), (17, 20), (19, 20)])
+def test_counters_and_span_carry_the_width_that_ran(tiny, warmed, pending,
+                                                    width, decoding):
+    store = get_span_store()
+    vocab = tiny[0].vocab_size
+    rng = np.random.default_rng(pending)
+    feed = {}
+    for d in range(decoding):
+        got = warmed.put([100 + d], [rng.integers(1, vocab, SHORT)],
+                         argmax_only=True)
+        feed[100 + d] = int(got[100 + d])
+    warmed.tracer.force = True
+    store.clear()
+    try:
+        c0 = dict(warmed.serving_counters)
+        uids = list(range(1, pending + 1))
+        warmed.put(list(feed) + uids, [[t] for t in feed.values()] +
+                   _prompts(vocab, pending, 0), argmax_only=True)
+        c1 = warmed.serving_counters
+        fused = bool(decoding) or width < MAX_BATCH
+        slots = width * CHUNK + (MAX_BATCH if fused else 0)
+        assert c1["token_slots_computed"] - c0["token_slots_computed"] == slots
+        assert c1["tokens_fed"] - c0["tokens_fed"] == pending * CHUNK + decoding
+        (chunk,) = [s for s in store.spans() if s["name"] == "chunk"]
+        f = chunk["fields"]
+        assert (f["rows"], f["width"], f["fused"]) == (pending, width, fused)
+        assert (f["token_slots"], f["tokens_fed"]) == (
+            slots, pending * CHUNK + decoding)
+        (disp,) = [s for s in store.spans() if s["name"] == "dispatch"]
+        assert disp["fields"]["program"] == (
+            f"fused_batch:{CHUNK}:{width}" if fused else f"chunk_batch:{CHUNK}")
+        assert disp["fields"]["compiled"] is False
+    finally:
+        warmed.tracer.force = False
+        store.clear()
+        warmed._flush_batch(list(feed) + uids)
+
+
+@pytest.mark.parametrize("max_batch", [1, 2, 3, 4, 5, 8, 9, 16, 17, 20, 48,
+                                       64, 256])
+def test_width_for_holds_the_rows_and_the_ladder_ends_at_max_batch(max_batch):
+    ladder = chunk_row_widths(max_batch)
+    assert 1 <= len(ladder) <= 2 and ladder[-1] == max_batch
+    assert list(ladder) == sorted(set(ladder))
+    for rows in range(1, max_batch + 1):
+        w = width_for(rows, max_batch)
+        assert w >= rows and w in ladder
+        assert not [v for v in ladder if rows <= v < w]   # the narrowest
+    assert chunk_row_widths(48) == (16, 48)

@@ -234,20 +234,28 @@ def test_children_nest_under_their_round_and_cover_it(runs):
         assert set(sync["fields"]) == {"dirty", "cow_copies"}
         assert dispatch["fields"]["program"].split(":")[0] in (
             "prefill", "decode", "fused_batch", "chunk_batch")
-        # round 4 dispatches round 3's program again: nothing compiles
-        assert dispatch["fields"]["compiled"] is (p["round"] != 4)
+        # round 3, the first chunk round, compiles the whole family of
+        # batched chunk programs (`chunk_batch`, and `fused_batch` at its
+        # one width of 4 rows), so rounds 4 and 5 dispatch what is compiled
+        assert dispatch["fields"]["compiled"] is (p["round"] <= 3)
     # the head of put is a span of its own, in every round
     heads = [s for s in runs["spans"] if s["name"] == "schedule"]
     assert {s["round"] for s in heads} == {1, 2, 3, 4, 5}
     assert all(s["parent"] is None for s in heads)
-    # a program's first dispatch is a `compile` span under that dispatch
+    # a program's first dispatch is a `compile` span under the `dispatch`
+    # that made it: its own, or the first chunk round's for its family
     compiles = [s for s in runs["spans"] if s["name"] == "compile"]
-    assert len(compiles) == 4
+    assert [(c["fields"]["program"], c["round"]) for c in compiles] == [
+        ("prefill:32", 1), ("decode", 2),
+        (f"chunk_batch:{CHUNK}", 3),
+        (f"fused_batch:{CHUNK}:{MAX_BATCH}", 3)]
     for c in compiles:
         assert by_id[c["parent"]]["name"] == "dispatch"
-        assert by_id[c["parent"]]["fields"]["program"] == \
-            c["fields"]["program"]
         assert c["fields"]["backend_compiles"] >= 1
+    assert by_id[compiles[-1]["parent"]]["fields"]["program"] == \
+        compiles[-1]["fields"]["program"]
+    widths = [s["fields"].get("width") for s in parents]
+    assert widths == [None, None, MAX_BATCH, MAX_BATCH, MAX_BATCH]
 
 
 def test_a_put_driven_request_leaves_nothing_unattributed(runs):
