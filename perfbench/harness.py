@@ -17,7 +17,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from perfbench import trace as trace_mod
 from perfbench.flops import peaks_for
-from perfbench.manifest import CHECKOUT, Manifest
+from perfbench.manifest import CHECKOUT, Manifest, config_problems
 
 CACHE_DIR = os.path.join(CHECKOUT, ".perfbench_cache")   # fixed: part of the cache key
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -195,7 +195,11 @@ def prepare(args, t_start: float, traced: bool):
     compile cache, the device check, the compile counter. Shared by `run.py`
     and `sweep.py`."""
     manifest = Manifest(getattr(args, "manifest", None))
-    ctx = Ctx(manifest, manifest.workload(args.workload), args.seed,
+    workload = manifest.workload(args.workload)
+    bad = config_problems(manifest, workload["config"])
+    if bad:   # what the benchmark's own test would refuse, no run measures
+        raise SystemExit("perfbench: " + "; ".join(bad))
+    ctx = Ctx(manifest, workload, args.seed,
               args.seconds, traced, args.rehearsal, t_start)
     for item in args.set or []:
         key, _, val = item.partition("=")

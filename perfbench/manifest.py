@@ -4,6 +4,7 @@ Whatever belongs to one configuration, one traffic mix or one metric sits in
 a file of its own, found by the NAME in `BENCHMARK.json`:
 
     configuration  its entry's `file`                    (sizes, as run)
+      its family   <dir>/configs/<adapter|reference|counts>.py, named in the file
     traffic mix    <dir>/traffic/<traffic>.json          (parameters)
     metric         <dir>/metrics/<name>.json             (declaration: reader + params)
     reader         <dir>/readers/<module>.py             (one module per source)
@@ -22,12 +23,21 @@ import os
 import re
 from typing import Any, Callable, Dict, List, Optional
 
+from perfbench import flops
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(HERE)
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# what `reduced` may never name: a width (the contract's list)
+WIDTH_WORDS = ("hidden_size", "intermediate_size", "latent", "state_size",
+               "proj", "head_dim", "expansion", "experts_per_tok")
+# what it may name only as the chip's share of a deployment the file states
+SHARE_WORDS = ("expert", "heads", "vocab")
+CONFIG_KEYS = ("source", "reduced", "reduced_from", "assumed", "adapter",
+               "reference", "rehearsal")
 
 
 class Manifest:
@@ -186,4 +196,73 @@ def problems(m: Manifest) -> List[str]:
     for c in doc["configs"]:
         if not any(w["config"] == c["name"] for w in doc["workloads"]):
             bad.append(f"config {c['name']} is used by no cell")
+        bad.extend(config_problems(m, c["name"]))
     return bad
+
+
+def _numbers(sizes: Dict[str, Any]) -> Dict[str, Any]:
+    """The top-level numeric keys: what the contract compares."""
+    return {k: v for k, v in sizes.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+def config_problems(m: Manifest, name: str) -> List[str]:
+    """One configuration against ITS OWN source, whatever its family: the
+    entry and the file agree on `source` and `reduced`; the file gives, under
+    `reduced_from`, the value the source publishes for each reduced key, and
+    runs another; no reduced key is a width; a count of experts, heads or
+    vocabulary rows is reduced only beside the `deployment` whose share it
+    is; and two files of one source differ, among their numbers, in the keys
+    their `reduced` lists and no others."""
+    entry = m.config_entry(name)
+    try:
+        sizes = m.config(name)
+    except (FileNotFoundError, ValueError) as e:
+        return [f"config {name}: {e}"]
+    bad = [f"no {key!r} in its file" for key in CONFIG_KEYS if key not in sizes]
+    if bad:
+        return [f"config {name}: {b}" for b in bad]
+    if not entry["file"].startswith(tuple(p + "/" for p in m.doc["paths"])):
+        bad.append(f"file {entry['file']!r} under none of `paths`")
+    if sizes["source"] != entry["source"]:
+        bad.append("source differs between BENCHMARK.json and its file")
+    reduced = list(entry["reduced"])
+    if sizes["reduced"] != reduced or list(sizes["reduced_from"]) != reduced:
+        bad.append(f"reduced {reduced} in BENCHMARK.json, {sizes['reduced']} "
+                   f"in its file, reduced_from {list(sizes['reduced_from'])}")
+    for key in reduced:
+        if not NAME_RE.match(key):
+            bad.append(f"reduced key {key!r} outside the allowed characters")
+        if any(w in key for w in WIDTH_WORDS) or key.endswith(("_dim", "_rank")):
+            bad.append(f"reduced names a width, {key}")
+        if any(w in key for w in SHARE_WORDS) and not (
+                isinstance(sizes.get("deployment"), str) and sizes["deployment"]):
+            bad.append(f"reduced names {key}, a chip's share, and the file "
+                       "states no `deployment`")
+        if key in sizes["reduced_from"] and \
+                sizes.get(key) == sizes["reduced_from"][key]:
+            bad.append(f"{key} is listed as reduced and runs at the "
+                       "source's value")
+    try:
+        own = flops.family_counts(sizes, m)
+        missing = [f for f in flops.COUNTS if not hasattr(own, f)] \
+            if own else []
+        if missing:
+            bad.append(f"counts module {sizes['counts']} lacks {missing}")
+    except (ValueError, FileNotFoundError) as e:
+        bad.append(str(e))
+    for other in m.doc["configs"]:
+        if other["name"] == name or other["source"] != entry["source"]:
+            continue
+        try:
+            theirs = m.config(other["name"])
+        except (FileNotFoundError, ValueError):
+            continue          # named under its own configuration
+        a, b = _numbers(sizes), _numbers(theirs)
+        differ = {k for k in set(a) | set(b) if a.get(k) != b.get(k)}
+        ours, other_red = set(reduced), set(other["reduced"])
+        if not (ours ^ other_red) <= differ <= (ours | other_red):
+            bad.append(f"differs from {other['name']} (same source) in "
+                       f"{sorted(differ)}, and their `reduced` list "
+                       f"{sorted(ours | other_red)}")
+    return [f"config {name}: {b}" for b in bad]

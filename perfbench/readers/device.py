@@ -8,8 +8,10 @@ def peak_hbm_gb(ctx):
 
 def mfu(ctx, rate, flops_counter="flops_per_token"):
     """Model FLOP/s utilization: counter `rate` (tokens/s) times the
-    operations a token needs (perfbench/flops.py, recompute not counted),
-    over chips times the chip's published bf16 peak. No peak, no number."""
+    operations a token needs, over chips times the chip's published bf16
+    peak. The runner counts them through `flops.train_flops_per_token`,
+    which asks the configuration's own `counts` first (active parameters
+    for sparse experts); recompute is not counted. No peak, no number."""
     if ctx.peaks is None or ctx.counters.get(rate) is None:
         return None
     return 100.0 * ctx.counters[rate] * ctx.counters[flops_counter] / (

@@ -6,7 +6,8 @@ Prints, as the last line of standard output, one JSON object with `correct`,
 `attempted`, `failed`, `metrics` and `device` (and, with `--trace 1`,
 `breakdown`). `--trace 0` reports the cell's end-to-end metrics, `--trace 1`
 its per-layer metrics. Exits non-zero and prints no result where JAX finds no
-accelerator, fewer chips than the cell asks for, or no program to measure.
+accelerator, fewer chips than the cell asks for, no program to measure, or a
+configuration file out of step with its entry (`manifest.config_problems`).
 
 Not for the driver: `--rehearsal` (toy sizes from the files' `rehearsal`
 blocks, any backend, numbers never reported as metrics), `--manifest` (a

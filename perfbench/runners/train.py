@@ -4,7 +4,8 @@ batch every step, each step ending in the fetched loss.
 The recipe is the traffic file's (`seq`, `sequences_per_step`, `micro_batch`,
 `mesh`, `zero_stage`, `remat_policy`, `loss_chunk`): the one `chip_smoke.py`
 proved on the chip (ZeRO-3 plan, bf16, FusedAdam, flash attention,
-`checkpoint_dots` remat, chunked cross-entropy).
+`checkpoint_dots` remat, chunked cross-entropy). `mesh` states `dp` and `tp`
+and may state `ep` (expert parallel, a division of the data-parallel ranks).
 """
 
 from __future__ import annotations
@@ -23,6 +24,31 @@ from perfbench.flops import train_flops_per_token
 # through the stack adds about as much. 0.5% is several times both, and far
 # inside what a wrong loss scale, label shift or lost layer norm would move.
 LOSS_TOL = 5e-3
+MESH_AXES = ("dp", "tp", "ep")     # of `MeshTopology`'s, those a cell may state
+
+
+def mesh_of(traffic: Dict[str, Any], chips: int) -> Dict[str, int]:
+    """The traffic file's `mesh` with every axis written out (1 where it
+    states none). An axis this runner does not build, or a product that
+    is not the cell's chips, ends the run."""
+    stated = traffic["mesh"]
+    unknown = sorted(set(stated) - set(MESH_AXES))
+    if unknown:
+        raise SystemExit(f"perfbench: mesh axis {unknown[0]!r} is none of "
+                         f"{', '.join(MESH_AXES)}")
+    mesh = {**dict.fromkeys(MESH_AXES, 1),
+            **{a: int(n) for a, n in stated.items()}}
+    if math.prod(mesh.values()) != chips:
+        raise SystemExit("perfbench: mesh " + " x ".join(
+            f"{a}{mesh[a]}" for a in MESH_AXES) + f" on {chips} device(s)")
+    return mesh
+
+
+def accumulation_steps(traffic: Dict[str, Any], mesh: Dict[str, int]) -> int:
+    """Micro-batches a step accumulates on each data-like rank, of which
+    there are dp x ep (`MeshTopology.dense_dp_size`)."""
+    return traffic["sequences_per_step"] // (
+        traffic["micro_batch"] * mesh["dp"] * mesh["ep"])
 
 
 def batch_for(rng: np.random.Generator, vocab: int, rows: int, seq: int
@@ -40,15 +66,13 @@ def run(ctx, devices) -> Dict[str, Any]:
 
     tf = ctx.traffic
     seq, rows, mbs = tf["seq"], tf["sequences_per_step"], tf["micro_batch"]
-    dp, tp = tf["mesh"]["dp"], tf["mesh"]["tp"]
-    if dp * tp != len(devices):
-        raise SystemExit(f"perfbench: mesh dp{dp} x tp{tp} on "
-                         f"{len(devices)} device(s)")
+    mesh = mesh_of(tf, len(devices))
+    dp, tp, ep = mesh["dp"], mesh["tp"], mesh["ep"]
     cfg = ctx.adapter.model_config(
         ctx.sizes, remat=True, remat_policy=tf["remat_policy"],
         loss_chunk_size=tf["loss_chunk"], dtype=jnp.bfloat16)
     groups.reset_topology()
-    topology = MeshTopology(dp=dp, tp=tp, devices=list(devices))
+    topology = MeshTopology(dp=dp, ep=ep, tp=tp, devices=list(devices))
     # bf16 from the start: the engine casts to bf16 before it builds its fp32
     # master anyway, and an fp32 tree would only crowd the chip
     model, params = ctx.adapter.materialize(cfg, ctx.seed, jnp.bfloat16)
@@ -61,7 +85,7 @@ def run(ctx, devices) -> Dict[str, Any]:
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, model_parameters=params, topology=topology,
         config={"train_micro_batch_size_per_gpu": mbs,
-                "gradient_accumulation_steps": rows // (mbs * dp),
+                "gradient_accumulation_steps": accumulation_steps(tf, mesh),
                 "steps_per_print": 0,
                 "optimizer": {"type": "FusedAdam", "params": {"lr": 2e-4}},
                 "bf16": {"enabled": True},
@@ -95,7 +119,8 @@ def run(ctx, devices) -> Dict[str, Any]:
     ctx.samples["step_ms"] = [t * 1e3 for t in times]
     ctx.counters.update(
         steps=steps, train_tok_s=steps * rows * seq / t_last,
-        flops_per_token=train_flops_per_token(ctx.sizes, seq),
+        flops_per_token=train_flops_per_token(ctx.sizes, seq,
+                                              manifest=ctx.manifest),
         compiles_in_window=compiles)
 
     if ctx.traced:

@@ -1,7 +1,9 @@
 """The command itself, end to end at toy sizes on the CPU (`--rehearsal`):
 same control flow as on the chip, no number reported as a metric. And the
 proof that a later PR adds a configuration, a traffic mix and a per-layer
-metric as NEW FILES AND ENTRIES ONLY."""
+metric as NEW FILES AND ENTRIES ONLY: once within the family the benchmark
+has, once for ANOTHER family (sparse experts, with its own adapter, plain
+reference and `counts`)."""
 
 import json
 import os
@@ -10,7 +12,8 @@ import sys
 
 import pytest
 
-from perfbench.manifest import CHECKOUT, Manifest
+from perfbench import flops
+from perfbench.manifest import CHECKOUT, Manifest, problems
 
 M = Manifest()
 CELLS = [w["name"] for w in M.doc["workloads"]]
@@ -91,6 +94,58 @@ def test_same_seed_same_offered_work():
 # ------------------------------------------------- adding without editing
 
 
+def benchmark_mtimes():
+    return {os.path.join(r, f): os.path.getmtime(os.path.join(r, f))
+            for p in M.doc["paths"]
+            for r, _, fs in os.walk(os.path.join(CHECKOUT, p))
+            for f in fs if not f.endswith(".pyc")}
+
+
+def test_a_second_family_is_new_files_only(second_family):
+    """A sparse-expert decoder the harness has never seen (4 experts, top-2,
+    over `models/mixtral.py` as it stands): its configuration file, `counts`,
+    adapter, plain reference, traffic mix and BENCHMARK.json sit in a
+    temporary directory, no file of the benchmark is edited, the manifest
+    has no problem, its FLOPs count the experts a token runs, and the
+    command serves it through v2 `put` with every first token the plain
+    reference's."""
+    before = benchmark_mtimes()
+    path = second_family()
+    m = Manifest(path)
+    assert problems(m) == []
+    sizes = m.config("toy-moe")
+    assert sizes["model_type"] != M.config(M.doc["configs"][0]["name"])["model_type"]
+    dense_guess = {k: v for k, v in sizes.items()
+                   if k not in ("counts", "num_local_experts")}
+    assert flops.train_flops_per_token(sizes, 32, manifest=m) > \
+        flops.train_flops_per_token(dense_guess, 32)
+    rc, lines, err = run_cell("--workload", "toy-moe.serve-toy", "--seed",
+                              "2147483777", "--seconds", "3", "--trace", "0",
+                              "--rehearsal", manifest=path)
+    assert rc == 0, err[-2000:]
+    line = result_of(lines)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] == 15
+    assert line["notes"]["check"]["checked"] == 4
+    assert set(line["rehearsal_metrics"]) == {"ttft_p80_ms", "tpot_p80_ms",
+                                              "setup_s"}
+    rc, lines, err = run_cell("--workload", "toy-moe.serve-toy", "--seed", "5",
+                              "--seconds", "2", "--trace", "1", "--rehearsal",
+                              manifest=path)
+    assert rc == 0, err[-2000:]
+    assert result_of(lines)["rehearsal_metrics"]["round_p50_ms"]["value"] > 0
+    assert before == benchmark_mtimes()
+
+
+def test_the_command_refuses_what_the_manifest_test_would(second_family):
+    def edit(doc, sizes):
+        del sizes["reduced_from"]
+    rc, lines, err = run_cell("--workload", "toy-moe.serve-toy", "--rehearsal",
+                              manifest=second_family(edit))
+    assert rc != 0 and lines == []
+    assert "no 'reduced_from' in its file" in err
+
+
 def test_new_config_traffic_and_metric_are_new_files_only(tmp_path):
     """A throw-away configuration, a bursty traffic mix and a per-layer
     metric with a reader of its own, in a directory that holds nothing
@@ -139,9 +194,7 @@ def test_new_config_traffic_and_metric_are_new_files_only(tmp_path):
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(doc))
 
-    before = {f: os.path.getmtime(os.path.join(r, f))
-              for r, _, fs in os.walk(os.path.join(CHECKOUT, "perfbench"))
-              for f in fs if not f.endswith(".pyc")}
+    before = benchmark_mtimes()
     rc, lines, err = run_cell("--workload", cell, "--seed", "4", "--seconds",
                               "3", "--trace", "1", "--rehearsal",
                               manifest=path)
@@ -155,7 +208,4 @@ def test_new_config_traffic_and_metric_are_new_files_only(tmp_path):
     assert rc == 0, err[-2000:]
     assert set(result_of(lines)["rehearsal_metrics"]) == {
         "ttft_p80_ms", "tpot_p80_ms", "setup_s"}
-    after = {f: os.path.getmtime(os.path.join(r, f))
-             for r, _, fs in os.walk(os.path.join(CHECKOUT, "perfbench"))
-             for f in fs if not f.endswith(".pyc")}
-    assert before == after
+    assert before == benchmark_mtimes()
