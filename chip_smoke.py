@@ -136,7 +136,9 @@ class KernelCase:
 
 
 def kernel_cases(sz: Sizes) -> List[KernelCase]:
-    from deepspeed_tpu.inference.kv_cache import (dequantize_kv,
+    from deepspeed_tpu.inference.kv_cache import (PagedLayer,
+                                                  _update_paged_layer,
+                                                  dequantize_kv,
                                                   quantize_kv_tokens)
     from deepspeed_tpu.models.qwen2 import qwen2_config
     from deepspeed_tpu.ops.attention import (blockwise_attention,
@@ -147,7 +149,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention, paged_prefill_attention)
+        paged_decode_attention, paged_kv_write, paged_prefill_attention)
     from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
     from deepspeed_tpu.ops.quantization import (dequantize_int8_blockwise,
                                                 quantize_int8_blockwise)
@@ -229,7 +231,12 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     # ---- paged decode / prefill (v2): block tables over a shared pool ----
     bs, nb, t = sz.block, sz.paged_blocks, sz.v2_max_seq // sz.block
 
-    def make_paged(batch, s):
+    def make_paged(batch, s, layers=0):
+        """`layers` > 0: the pools are a stack of that many, as the v2
+        programs hold them, and the last input is the layer to read (the
+        last one, so that a kernel that read layer 0 would be wrong)."""
+        pool = ((layers,) if layers else ()) + (hkv, nb, bs, d)
+
         def make(key):
             kq, kk, kv, kt, kl, kn = jax.random.split(key, 6)
             # rows may share physical blocks: the kernels only read them
@@ -238,8 +245,9 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             cursor = jax.random.randint(kl, (batch,), 1, t * bs - s + 1,
                                         jnp.int32)
             new = normal(kn, (2, batch, hkv, d))
-            return (normal(kq, (batch, s, h, d)), normal(kk, (hkv, nb, bs, d)),
-                    normal(kv, (hkv, nb, bs, d)), tables, cursor, new)
+            out = (normal(kq, (batch, s, h, d)), normal(kk, pool),
+                   normal(kv, pool), tables, cursor, new)
+            return out + ((jnp.int32(layers - 1),) if layers else ())
         return make
 
     def dense_view(pool, tables):
@@ -262,11 +270,18 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         mask = jnp.arange(k.shape[1])[None, None, :] <= pos[:, :, None]
         return reference_attention(q, k, v, causal=False, segment_mask=mask)
 
-    def paged_decode(q, kp, vp, tables, lengths, new, **scales):
-        return paged_decode_attention(q, kp, vp, tables, lengths, **scales)
+    def paged_decode(q, kp, vp, tables, lengths, new, layer=None, **scales):
+        return paged_decode_attention(q, kp, vp, tables, lengths,
+                                      layer=layer, **scales)
 
-    def paged_prefill(q, kp, vp, tables, starts, new, **scales):
-        return paged_prefill_attention(q, kp, vp, tables, starts, **scales)
+    def paged_prefill(q, kp, vp, tables, starts, new, layer=None, **scales):
+        return paged_prefill_attention(q, kp, vp, tables, starts,
+                                       layer=layer, **scales)
+
+    def of_layer(ref, **kw):
+        """`ref` on the layer the stacked case reads, cut out by hand."""
+        return lambda q, kp, vp, tb, cur, new, layer: ref(
+            q, kp[layer], vp[layer], tb, cur, new, **kw)
 
     pb, fb = sz.paged_batch, sz.prefill_batch
     cases += [
@@ -278,16 +293,65 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                    lambda *a: paged_ref(*a, staged=True), make_paged(pb, 1)),
         KernelCase("paged_decode_int8kv", *int8_kv(paged_decode, paged_ref),
                    make_paged(pb, 1)),
+        # the operand the v2 programs hand over: the whole stacked pool
+        # and the layer to read (staged, as a decode round has it)
+        KernelCase("paged_decode_stacked_staged",
+                   lambda q, kp, vp, tb, ln, new, layer:
+                   paged_decode_attention(q, kp, vp, tb, ln, k_new=new[0],
+                                          v_new=new[1], layer=layer),
+                   of_layer(paged_ref, staged=True), make_paged(pb, 1, 3)),
+        KernelCase("paged_decode_stacked_int8kv",
+                   *int8_kv(paged_decode, of_layer(paged_ref)),
+                   make_paged(pb, 1, 3)),
     ]
-    # the two slowest to compile (8 s each for the described chip): last, so
-    # a test window that closes early has seen the other twenty-two
+    # the slowest to compile (8 s each for the described chip): last, so
+    # a test window that closes early has seen the other twenty-six
     slow_cases = [
         KernelCase("paged_prefill_bf16", paged_prefill, prefill_ref,
                    make_paged(fb, sz.v2_chunk)),
         KernelCase("paged_prefill_int8kv",
                    *int8_kv(paged_prefill, prefill_ref),
                    make_paged(fb, sz.v2_chunk)),
+        KernelCase("paged_prefill_stacked_bf16", paged_prefill,
+                   of_layer(prefill_ref), make_paged(fb, sz.v2_chunk, 3)),
+        KernelCase("paged_prefill_stacked_int8kv",
+                   *int8_kv(paged_prefill, of_layer(prefill_ref)),
+                   make_paged(fb, sz.v2_chunk, 3)),
     ]
+
+    # ---- the paged pools' writer: new tokens into the stack, in place ----
+    def make_write(layers, s):
+        """Stacked pools, `layers` layers' worth of `s` new tokens a row
+        (a chunk into layer 2; a staged token into every layer), rows that
+        own their blocks (a block two rows wrote would have two orders),
+        one row parked."""
+        def make(key):
+            kk, kv, kn, kl = jax.random.split(key, 4)
+            tables = jnp.arange(fb * t, dtype=jnp.int32).reshape(fb, t)
+            starts = jax.random.randint(kl, (fb,), 0, t * bs - s + 1,
+                                        jnp.int32).at[0].set(t * bs)
+            return (normal(kk, (3, hkv, nb, bs, d)),
+                    normal(kv, (3, hkv, nb, bs, d)),
+                    normal(kn, (2, layers, fb, s, hkv, d)), tables, starts)
+        return make
+
+    def kv_write(first):
+        def fn(kp, vp, new, tables, starts):
+            return paged_kv_write(kp, vp, new[0], new[1], tables, starts,
+                                  first)[:2]
+
+        def ref(kp, vp, new, tables, starts):
+            for i in range(new.shape[1]):  # the XLA scatter, layer by layer
+                kp, vp = (_update_paged_layer(
+                    PagedLayer(pool=p, tables=tables,
+                               layer=jnp.int32(first + i)), x[i], starts).pool
+                    for p, x in ((kp, new[0]), (vp, new[1])))
+            return kp, vp
+        return fn, ref
+
+    cases += [KernelCase("kv_write_chunk", *kv_write(2),
+                         make_write(1, sz.v2_chunk)),
+              KernelCase("kv_write_stage", *kv_write(0), make_write(3, 1))]
 
     # ---- fused int8 dequant-GEMM: every projection shape, three M ----
     def make_qmm(m, k, n):

@@ -15,8 +15,10 @@ cache is just a scanned input/output of the block scan.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Tuple
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from flax import struct
@@ -150,12 +152,34 @@ class PagedLayer:
     written by the same scatters that write the pool (strictly local: an
     append never re-quantizes a neighbour). The stage buffer stays in the
     COMPUTE dtype — the staged token is folded into attention exactly and
-    only quantized when `apply_stage` lands it."""
+    only quantized when `apply_stage` lands it.
 
-    pool: jnp.ndarray    # (Hkv, NB, BS, D) — physical KV blocks
+    `layer` () int32 or None: set, `pool` and `scales` are the STACKED
+    (L, ...) arrays of the whole cache and this view is layer `layer` of
+    them, by index (`scan_paged_layers`): writes scatter at
+    `[layer, :, slot]`, the kernels fetch block `(layer, :, phys)`, and
+    nothing cuts a layer's pool out of the stack. None: `pool` is one
+    layer's own array, as a block scan that scans over the pools sees it."""
+
+    pool: jnp.ndarray    # ([L,] Hkv, NB, BS, D) — physical KV blocks
     tables: jnp.ndarray  # (B, T) int32 — logical block i of row b → pool id
     stage: Optional[jnp.ndarray] = None  # (B, Hkv, D) staged decode token
-    scales: Optional[jnp.ndarray] = None  # (Hkv, NB, BS) f32 — int8 pools
+    scales: Optional[jnp.ndarray] = None  # ([L,] Hkv, NB, BS) f32 — int8 pools
+    layer: Optional[jnp.ndarray] = None  # () int32 — pool/scales are stacked
+
+    def stacked(self):
+        """`(pool, scales, layer)` addressed by layer: the stacked arrays
+        as they are, or one layer's own arrays as a stack of one."""
+        if self.layer is not None:
+            return self.pool, self.scales, self.layer
+        return (self.pool[None],
+                None if self.scales is None else self.scales[None], 0)
+
+    def with_stacked(self, pool, scales) -> "PagedLayer":
+        """This view over what `stacked()` gave, once written."""
+        if self.layer is None:
+            pool, scales = pool[0], None if scales is None else scales[0]
+        return self.replace(pool=pool, scales=scales)
 
 
 @struct.dataclass
@@ -234,6 +258,14 @@ class PagedKVCache:
         l, hkv, nb, bs, d = self.k.pool.shape
         b, t = self.k.tables.shape[1:]
         pos = self.index - 1
+        write = _pool_writer(hkv)
+        if write is not None:  # the whole stack, from layer 0
+            k, v = _write_pools(write, self.k.replace(layer=0),
+                                self.v.replace(layer=0),
+                                self.k.stage[:, :, None],
+                                self.v.stage[:, :, None], self.k.tables[0], pos)
+            return self.replace(k=k.replace(layer=None),
+                                v=v.replace(layer=None))
         blk = jnp.clip(pos // bs, 0, t - 1)
         phys = self.k.tables[0, jnp.arange(b), blk]              # (B,)
         valid = jnp.logical_and(jnp.logical_and(pos >= 0, pos < t * bs),
@@ -271,11 +303,53 @@ class PagedKVCache:
                             v=self.v.replace(tables=jnp.array(tl)))
 
 
+def _pool_writer(hkv: int):
+    """The Pallas writer of new tokens into the pools (`ops/pallas/
+    paged_attention.py:paged_kv_write`, or its head-sharded wrapper) where
+    the paged attention kernels run, by their dispatcher's own rule: the
+    chip, and one device or a pure-'model' mesh that divides the KV heads.
+    The kernels read the pool tiled over (BS, D) and XLA's scatter wants
+    the KV-head dim second-minor, so a program that used both re-laid the
+    whole pool around every scatter. None elsewhere (CPU, other meshes):
+    the XLA scatters below, in a program whose attention is XLA's too."""
+    from deepspeed_tpu.ops import attention
+    if not attention._use_pallas():
+        return None
+    mesh, fallback = attention._decode_tp_mesh(hkv, hkv, "paged_kv_write")
+    if fallback:
+        return None
+    if mesh is None:
+        from deepspeed_tpu.ops.pallas.paged_attention import paged_kv_write
+        return paged_kv_write
+    from deepspeed_tpu.ops.pallas.sharded import sharded_paged_kv_write
+    return functools.partial(sharded_paged_kv_write, mesh=mesh)
+
+
+def _write_pools(write, k: PagedLayer, v: PagedLayer, k_new, v_new, tables,
+                 starts) -> Tuple[PagedLayer, PagedLayer]:
+    """`k_new`/`v_new` (NL, B, S, Hkv, D) through the writer into the pools
+    of `k`/`v`, for the NL layers from theirs on; int8 pools quantize
+    here, as the scatters do."""
+    (kp, ks, layer), (vp, vs, _) = k.stacked(), v.stacked()
+    k_ns = v_ns = None
+    if ks is not None:
+        (k_new, k_ns), (v_new, v_ns) = (quantize_kv_tokens(k_new),
+                                        quantize_kv_tokens(v_new))
+    kp, vp, ks, vs = write(kp, vp, k_new, v_new, tables, starts, layer,
+                           k_scales=ks, v_scales=vs, k_new_scales=k_ns,
+                           v_new_scales=v_ns)
+    return k.with_stacked(kp, ks), v.with_stacked(vp, vs)
+
+
 def _update_paged_layer(layer: PagedLayer, new: jnp.ndarray,
                         index: jnp.ndarray) -> PagedLayer:
     """Scatter `new` (B, S, Hkv, D) into the pool at each row's logical
     positions `index[b]..index[b]+S` via its block table. Positions at or
     past the logical capacity (parked rows) drop.
+
+    The target is `[layer, :, slot]` of the stacked pool (`PagedLayer.
+    stacked`): where the stack is a loop's carry or a donated argument the
+    scatter is in place, and no layer's pool is copied to be written.
 
     When S equals the block size and every cursor is block-aligned (the
     steady state of chunked prefill with chunk == block — each row's piece
@@ -284,17 +358,17 @@ def _update_paged_layer(layer: PagedLayer, new: jnp.ndarray,
     scatter at S=256 measured tens of ms/layer on v5e and dominated FastGen
     prefill. Runtime `lax.cond` picks the path, so misaligned callers
     (prefill continuations, tests) keep exact semantics."""
-    hkv, nb, bs, d = layer.pool.shape
+    pool, scales, l = layer.stacked()
+    hkv, nb, bs, d = pool.shape[1:]
     t = layer.tables.shape[1]
     b, s = new.shape[:2]
-    if layer.scales is not None:
-        qnew, snew = quantize_kv_tokens(new)                 # (B,S,Hkv,*)
-        vals = jnp.moveaxis(qnew, 2, 0)                      # (Hkv, B, S, D)
-        svals = jnp.moveaxis(snew, 2, 0)                     # (Hkv, B, S)
+    if scales is not None:
+        vals, svals = quantize_kv_tokens(new)       # (B,S,Hkv,D), (B,S,Hkv)
     else:
-        vals = jnp.moveaxis(new.astype(layer.pool.dtype), 2, 0)
-        svals = None
+        vals, svals = new.astype(pool.dtype), None
 
+    # an index of the form [l, :, i] puts i's axes first: the values go in
+    # as (*i.shape, Hkv, ...), which for tokens is how `new` arrives
     def token_scatter(carry):
         pool, scales = carry
         pos = index[:, None] + jnp.arange(s)[None, :]        # (B, S) logical
@@ -306,17 +380,13 @@ def _update_paged_layer(layer: PagedLayer, new: jnp.ndarray,
         # (phys < 0 — bucketed-prefill padding past the row's blocks)
         valid = jnp.logical_and(pos < t * bs, phys >= 0)
         flat = jnp.where(valid, flat, nb * bs)
-        pool_flat = pool.reshape(hkv, nb * bs, d)
-        pool_flat = pool_flat.at[:, flat].set(vals, mode="drop")
+        pool_flat = pool.reshape(-1, hkv, nb * bs, d)
+        pool_flat = pool_flat.at[l, :, flat].set(vals, mode="drop")
         if scales is not None:
-            sflat = scales.reshape(hkv, nb * bs)
-            scales = sflat.at[:, flat].set(svals,
-                                           mode="drop").reshape(hkv, nb, bs)
-        return pool_flat.reshape(hkv, nb, bs, d), scales
-
-    if s != bs:
-        pool, scales = token_scatter((layer.pool, layer.scales))
-        return layer.replace(pool=pool, scales=scales)
+            sflat = scales.reshape(-1, hkv, nb * bs)
+            scales = sflat.at[l, :, flat].set(
+                svals, mode="drop").reshape(scales.shape)
+        return pool_flat.reshape(pool.shape), scales
 
     def block_scatter(carry):
         pool, scales = carry
@@ -325,13 +395,18 @@ def _update_paged_layer(layer: PagedLayer, new: jnp.ndarray,
         ok = jnp.logical_and(index < t * bs, phys >= 0)
         phys = jnp.where(ok, phys, nb)                       # → drop
         if scales is not None:
-            scales = scales.at[:, phys].set(svals, mode="drop")
-        return pool.at[:, phys].set(vals, mode="drop"), scales
+            scales = scales.at[l, :, phys].set(
+                jnp.moveaxis(svals, 2, 1), mode="drop")      # (B, Hkv, BS)
+        return pool.at[l, :, phys].set(
+            jnp.moveaxis(vals, 2, 1), mode="drop"), scales   # (B,Hkv,BS,D)
 
-    aligned = jnp.all(index % bs == 0)
-    pool, scales = jax.lax.cond(aligned, block_scatter, token_scatter,
-                                (layer.pool, layer.scales))
-    return layer.replace(pool=pool, scales=scales)
+    if s != bs:
+        pool, scales = token_scatter((pool, scales))
+    else:
+        aligned = jnp.all(index % bs == 0)
+        pool, scales = jax.lax.cond(aligned, block_scatter, token_scatter,
+                                    (pool, scales))
+    return layer.with_stacked(pool, scales)
 
 
 def gather_paged_layer(layer: PagedLayer, dtype: Any = None) -> jnp.ndarray:
@@ -344,25 +419,25 @@ def gather_paged_layer(layer: PagedLayer, dtype: Any = None) -> jnp.ndarray:
     at serving shape) which measured ~140 ms/layer on v5e — the entire
     FastGen prefill cost. Block-granular is ~256 indices of 32 KB each and
     runs at HBM bandwidth. Unowned entries (-1) read block 0; callers mask
-    by validity, exactly as before.
+    by validity, exactly as before. The blocks are gathered by
+    `(layer, phys)` out of the stacked pool, never out of a slice of it.
 
     int8 pools dequantize here (block-gathered values × their scales, f32
     unless `dtype` says otherwise) — the only place the dense form of a
     quantized cache materializes, and only as this fallback's per-layer
     transient; the kernels fold the scales in-register instead."""
-    hkv, nb, bs, d = layer.pool.shape
+    pool, scales, l = layer.stacked()
+    hkv, nb, bs, d = pool.shape[1:]
     b, t = layer.tables.shape
     phys = jnp.maximum(layer.tables, 0).reshape(-1)         # (B·T,) unowned
-    blocks = jnp.take(layer.pool, phys, axis=1)             # → masked reads
-    if layer.scales is not None:
-        sc = jnp.take(layer.scales, phys, axis=1)           # (Hkv, B·T, BS)
-        blocks = dequantize_kv(
-            blocks.reshape(hkv, b * t * bs, d),
-            sc.reshape(hkv, b * t * bs), dtype or jnp.float32)
+    blocks = pool[l, :, phys]                # → masked reads; (B·T,Hkv,BS,D)
+    if scales is not None:
+        blocks = dequantize_kv(blocks, scales[l, :, phys],
+                               dtype or jnp.float32)
     elif dtype is not None:
         blocks = blocks.astype(dtype)
-    dense = blocks.reshape(hkv, b, t * bs, d)               # (Hkv, B, M, D)
-    return jnp.moveaxis(dense, 0, 2)                        # (B, M, Hkv, D)
+    dense = blocks.reshape(b, t, hkv, bs, d)
+    return jnp.moveaxis(dense, 2, 3).reshape(b, t * bs, hkv, d)
 
 
 @jax.named_scope("kv_write")
@@ -381,8 +456,12 @@ def update_layer(k_cache, v_cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
             # pools quantize at apply_stage, not here
             return (k_cache.replace(stage=k_new[:, 0].astype(k_cache.stage.dtype)),
                     v_cache.replace(stage=v_new[:, 0].astype(v_cache.stage.dtype)))
-        return (_update_paged_layer(k_cache, k_new, index),
-                _update_paged_layer(v_cache, v_new, index))
+        write = _pool_writer(k_cache.pool.shape[-4])
+        if write is None:
+            return (_update_paged_layer(k_cache, k_new, index),
+                    _update_paged_layer(v_cache, v_new, index))
+        return _write_pools(write, k_cache, v_cache, k_new[None], v_new[None],
+                            k_cache.tables, index)
     b, s = k_new.shape[:2]
     rows = jnp.arange(b)[:, None]                      # (B, 1)
     cols = index[:, None] + jnp.arange(s)[None, :]     # (B, S)
@@ -401,6 +480,71 @@ def update_layer(k_cache, v_cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
     v_cache = v_cache.at[rows, cols].set(v_new.astype(v_cache.dtype),
                                          mode="drop")
     return k_cache, v_cache
+
+
+class _PagedStep(nn.Module):
+    """One layer of `scan_paged_layers`: the family's block under this
+    module's own scope (so the parameter tree is the block's), handed the
+    stacked pools with this layer's index instead of a slice of them."""
+
+    block: Any  # () -> the block module, called (h, aux, (k, v))
+
+    @nn.compact
+    def __call__(self, carry, consts, xs):
+        h, carried = carry
+        aux, const = consts
+        k_pool, v_pool, k_scales, v_scales = (
+            const if carried is None else carried)
+        k, v, layer = xs
+        inner = self.block()
+        nn.share_scope(self, inner)
+        h, (k, v) = inner(
+            h, aux, (k.replace(pool=k_pool, scales=k_scales, layer=layer),
+                     v.replace(pool=v_pool, scales=v_scales, layer=layer)))
+        if carried is not None:
+            carried = (k.pool, v.pool, k.scales, v.scales)
+        return (h, carried), (k.stage, v.stage)
+
+
+def scan_paged_layers(block, h, aux, cache: PagedKVCache, s: int, *,
+                      name: str, **scan_kw):
+    """A zoo model's cached block scan over a `PagedKVCache`, in place of
+    its `nn.scan(Block, in_axes=(nn.broadcast, 0), out_axes=0, ...)` over
+    `(cache.k, cache.v)`; call it from the model's compact method. `block`
+    builds the block module (`functools.partial(Block, cfg)`), which is
+    called `(h, aux, (k, v))` and returns `(h, (k, v))` as before; `s` is
+    the number of new tokens a row; `name` and `scan_kw` (`variable_axes`,
+    `split_rngs`, `metadata_params`) are the family's own. Returns
+    `(h, new_cache)`, the cursors advanced by `s`.
+
+    From the program's argument to its result there is ONE buffer per pool:
+    the scan runs over the layer INDEX and the small per-layer leaves
+    (tables, stage) only. A pass that writes the pools (chunks, prefill,
+    unstaged decode) carries them, and each layer scatters into the carry
+    at `[layer, :, slot]`, which XLA does in place. A pass that cannot
+    write them (staged decode: the condition `update_layer` tests) closes
+    over them as constants of the loop. Scanned over instead, each layer's
+    pool was cut out of the stack and written back, twice a round, and
+    copied once more to be scattered into: 36 ms of an 80 ms round at
+    Qwen2.5-3B with a 3.77 GB pool (PERF.md, PR 29).
+
+    A dense `KVCache` has the same shape of problem and can take the same
+    shape of answer: carry or close over the stacked array, scan the index."""
+    pools = (cache.k.pool, cache.v.pool, cache.k.scales, cache.v.scales)
+    layers = cache.k.pool.shape[0]
+    writes = not (cache.k.stage is not None and s == 1)
+    scan = nn.scan(_PagedStep, in_axes=(nn.broadcast, 0), out_axes=0,
+                   length=layers, **scan_kw)
+    (h, carried), (k_stage, v_stage) = scan(block, name=name)(
+        (h, pools if writes else None), (aux, None if writes else pools),
+        (cache.k.replace(pool=None, scales=None),
+         cache.v.replace(pool=None, scales=None),
+         jnp.arange(layers, dtype=jnp.int32)))
+    k_pool, v_pool, k_scales, v_scales = carried if writes else pools
+    return h, cache.replace(
+        k=cache.k.replace(pool=k_pool, scales=k_scales, stage=k_stage),
+        v=cache.v.replace(pool=v_pool, scales=v_scales, stage=v_stage),
+        index=cache.index + s)
 
 
 def decode_mask(q_positions: jnp.ndarray, max_len: int,

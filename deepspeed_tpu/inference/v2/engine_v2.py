@@ -868,9 +868,10 @@ class InferenceEngineV2:
     def _merge_row(self, cache, row, slot, new_index):
         """Fold a row view's updates back into the full cache."""
         if self.kv_layout == "paged":
-            return PagedKVCache(k=cache.k.replace(pool=row.k.pool),
-                                v=cache.v.replace(pool=row.v.pool),
-                                index=cache.index.at[slot].set(new_index))
+            return PagedKVCache(
+                k=cache.k.replace(pool=row.k.pool, scales=row.k.scales),
+                v=cache.v.replace(pool=row.v.pool, scales=row.v.scales),
+                index=cache.index.at[slot].set(new_index))
         k = jax.lax.dynamic_update_slice_in_dim(cache.k, row.k, slot, axis=1)
         v = jax.lax.dynamic_update_slice_in_dim(cache.v, row.v, slot, axis=1)
         return KVCache(k=k, v=v, index=cache.index.at[slot].set(new_index))
@@ -944,8 +945,10 @@ class InferenceEngineV2:
                 index = cache.index.at[slots].set(starts + valids,
                                                   mode="drop")
                 new_cache = PagedKVCache(
-                    k=cache.k.replace(pool=rows.k.pool),
-                    v=cache.v.replace(pool=rows.v.pool), index=index)
+                    k=cache.k.replace(pool=rows.k.pool,
+                                      scales=rows.k.scales),
+                    v=cache.v.replace(pool=rows.v.pool,
+                                      scales=rows.v.scales), index=index)
             with jax.named_scope("head"):
                 last = jnp.take_along_axis(
                     logits, jnp.maximum(valids - 1, 0)[:, None, None],

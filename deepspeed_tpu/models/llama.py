@@ -20,6 +20,7 @@ TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -370,7 +371,8 @@ class LlamaForCausalLM(nn.Module):
         if cache is not None:
             # Cached decode/prefill path (reference inference/engine.py:579):
             # same params, scan carries KV through the stacked layer cache.
-            from deepspeed_tpu.inference.kv_cache import decode_mask
+            from deepspeed_tpu.inference.kv_cache import (
+                PagedKVCache, decode_mask, scan_paged_layers)
             b, s = input_ids.shape
             index = cache.index  # (B,) per-sequence cursors
             positions = index[:, None] + jnp.arange(s)[None, :]  # (B, S)
@@ -378,15 +380,23 @@ class LlamaForCausalLM(nn.Module):
                                     cfg.dtype)
             mask = decode_mask(positions, cache.max_len,
                                window=cfg.sliding_window)
-            ScanBlocks = nn.scan(
-                LlamaBlock, variable_axes={"params": 0},
-                split_rngs={"params": True},
-                in_axes=(nn.broadcast, 0), out_axes=0,
-                length=cfg.num_hidden_layers,
-                metadata_params={nn.meta.PARTITION_NAME: "layers"})
-            h, (k_new, v_new) = ScanBlocks(cfg, name="layers")(
-                h, (cos, sin, index, mask), (cache.k, cache.v))
-            new_cache = cache.replace(k=k_new, v=v_new, index=index + s)
+            if isinstance(cache, PagedKVCache):
+                # the pools stay whole: layers address them by index
+                h, new_cache = scan_paged_layers(
+                    functools.partial(LlamaBlock, cfg), h,
+                    (cos, sin, index, mask), cache, s, name="layers",
+                    variable_axes={"params": 0}, split_rngs={"params": True},
+                    metadata_params={nn.meta.PARTITION_NAME: "layers"})
+            else:
+                ScanBlocks = nn.scan(
+                    LlamaBlock, variable_axes={"params": 0},
+                    split_rngs={"params": True},
+                    in_axes=(nn.broadcast, 0), out_axes=0,
+                    length=cfg.num_hidden_layers,
+                    metadata_params={nn.meta.PARTITION_NAME: "layers"})
+                h, (k_new, v_new) = ScanBlocks(cfg, name="layers")(
+                    h, (cos, sin, index, mask), (cache.k, cache.v))
+                new_cache = cache.replace(k=k_new, v=v_new, index=index + s)
             h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(h)
             logits = self._lm_head(h, embed)
             return logits, new_cache

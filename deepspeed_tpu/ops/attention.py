@@ -274,7 +274,8 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
 
     q: (B, S, H, D); caches (B, M, Hkv, D) dense arrays OR
     `kv_cache.PagedLayer` views (block-paged pool + tables — the FastGen
-    layout); index (B,) pre-insert cursors; mask (B, S, M) validity over
+    layout; with `layer` set the pool is the whole stacked one and the
+    kernels fetch this layer's blocks out of it by index); index (B,) pre-insert cursors; mask (B, S, M) validity over
     logical positions.
 
     NOTE: the Pallas decode branches assume a PREFIX mask — slots 0..index
@@ -318,12 +319,12 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
         # that (bloom-tiny) — those sizes take the gather fallback, which
         # is cheap at tiny scale anyway
         alibi_kernel_ok = alibi is None or (
-            q.shape[-1] >= 128 and k_cache.pool.shape[2] >= 128)
+            q.shape[-1] >= 128 and k_cache.pool.shape[-2] >= 128)
         use_kernel = _use_pallas() and impl != "reference" and alibi_kernel_ok
         mesh = None
         if use_kernel:
             mesh, tp_fallback = _decode_tp_mesh(
-                q.shape[2], k_cache.pool.shape[0],
+                q.shape[2], k_cache.pool.shape[-4],
                 "paged_decode_attention" if q.shape[1] == 1
                 else "paged_prefill_attention")
             use_kernel = not tp_fallback
@@ -332,7 +333,7 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
             # dispatcher fell back to the dense-view gather for bloom/
             # mistral-family models, forfeiting paging entirely
             if window is None:  # banded masks aren't prefix masks
-                m_cap = k_cache.tables.shape[1] * k_cache.pool.shape[2]
+                m_cap = k_cache.tables.shape[1] * k_cache.pool.shape[-2]
                 _assert_prefix_mask(mask, index, m_cap, q.shape[1])
             if q.shape[1] == 1:
                 if mesh is not None:
@@ -344,7 +345,8 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
                         k_new=k_cache.stage if staged else None,
                         v_new=v_cache.stage if staged else None,
                         window=window, alibi=alibi,
-                        k_scales=k_cache.scales, v_scales=v_cache.scales)
+                        k_scales=k_cache.scales, v_scales=v_cache.scales,
+                        layer=k_cache.layer)
                 from deepspeed_tpu.ops.pallas.paged_attention import (
                     paged_decode_attention)
                 return paged_decode_attention(
@@ -352,7 +354,8 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
                     k_new=k_cache.stage if staged else None,
                     v_new=v_cache.stage if staged else None,
                     window=window, alibi=alibi,
-                    k_scales=k_cache.scales, v_scales=v_cache.scales)
+                    k_scales=k_cache.scales, v_scales=v_cache.scales,
+                    layer=k_cache.layer)
             # chunked prefill rides the paged flash kernel — the r3 XLA
             # fallback (token-gather + f32 (B,H,S,M) logits) measured
             # ~140 ms/layer at serving shape and WAS the FastGen prefill
@@ -362,14 +365,16 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
                 return sharded_paged_prefill_attention(
                     q, k_cache.pool, v_cache.pool, k_cache.tables, index,
                     mesh, window=window, alibi=alibi,
-                    k_scales=k_cache.scales, v_scales=v_cache.scales)
+                    k_scales=k_cache.scales, v_scales=v_cache.scales,
+                    layer=k_cache.layer)
             from deepspeed_tpu.ops.pallas.paged_attention import (
                 paged_prefill_attention)
             return paged_prefill_attention(q, k_cache.pool, v_cache.pool,
                                            k_cache.tables, index,
                                            window=window, alibi=alibi,
                                            k_scales=k_cache.scales,
-                                           v_scales=v_cache.scales)
+                                           v_scales=v_cache.scales,
+                                           layer=k_cache.layer)
         # XLA fallback: materialize the dense logical view, then the masked
         # path (CPU tests, alibi/window models). A staged token overlays
         # its row's cursor slot (the pool copy there is stale). int8 pools

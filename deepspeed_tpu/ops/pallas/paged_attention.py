@@ -21,8 +21,12 @@ actual memory traffic). Folding Hkv into the tile cuts grid steps by Hkv
 and makes every DMA Hkv× larger; same-shape chained-loop time dropped to
 ~0.17 ms (≈20×).
 
-Layout: q (B, 1, H, D); pools (Hkv, NB, BS, D) as stored by
-`inference/kv_cache.py:PagedKVCache`; tables (B, T) int32; lengths (B,).
+Layout: q (B, 1, H, D); pools (L, Hkv, NB, BS, D) as stored by
+`inference/kv_cache.py:PagedKVCache`, with the layer to read as a third
+scalar-prefetch operand, so that a block is fetched from `(layer, :, phys)`
+of the stacked pool and no program cuts a layer out of it first (or one
+layer's own (Hkv, NB, BS, D), a stack of one); tables (B, T) int32;
+lengths (B,).
 """
 
 from __future__ import annotations
@@ -39,7 +43,8 @@ from deepspeed_tpu.ops.pallas import _interpret
 from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
 
 
-def _paged_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
+                  o_ref,
                   m_scr, l_scr, acc_scr, *, scale, bs, nt, hkv, n_rep, d,
                   window=None, kn_ref=None, vn_ref=None, alibi_ref=None,
                   ks_ref=None, vs_ref=None):
@@ -139,27 +144,47 @@ def _paged_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
+def _stacked_pools(k_pool, v_pool, k_scales, v_scales, layer):
+    """The pools as the kernels take them: stacked (L, Hkv, NB, BS, D) with
+    `layer` a (1,) int32 for the scalar prefetch. Without a `layer` the
+    pools are one layer's own, and go in as a stack of one."""
+    if layer is None:
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+        layer = 0
+    return (k_pool, v_pool, k_scales, v_scales,
+            jnp.asarray(layer, jnp.int32).reshape(1))
+
+
 def _scale_operand(scales: jnp.ndarray) -> jnp.ndarray:
-    """(Hkv, NB, BS) pool scales as the kernels take them: (Hkv, NB, 1, BS).
-    Mosaic wants a block's second-to-last dim a multiple of 8 or the whole
-    array dim, and ONE physical block of the NB axis is neither; the unit
-    dim makes a block's (1, BS) tail span its array's."""
-    hkv, nb, bs = scales.shape
-    return scales.reshape(hkv, nb, 1, bs)
+    """(L, Hkv, NB, BS) pool scales as the kernels take them:
+    (L, Hkv, NB, 1, BS). Mosaic wants a block's second-to-last dim a
+    multiple of 8 or the whole array dim, and ONE physical block of the NB
+    axis is neither; the unit dim makes a block's (1, BS) tail span its
+    array's."""
+    l, hkv, nb, bs = scales.shape
+    return scales.reshape(l, hkv, nb, 1, bs)
+
+
+def _pool_block_spec(hkv: int, bs: int, d: int, index_map) -> pl.BlockSpec:
+    """One physical block of one layer for every KV head; the layer dim is
+    squeezed, so the kernels read a (Hkv, 1, BS, D) ref."""
+    return pl.BlockSpec((None, hkv, 1, bs, d), index_map)
 
 
 def _scale_block_spec(hkv: int, bs: int, index_map) -> pl.BlockSpec:
-    """One physical block's scales for every KV head, riding the pools'
-    own 4-D index map; the NB dim is squeezed, so the kernels read a
-    (Hkv, 1, BS) ref."""
-    return pl.BlockSpec((hkv, None, 1, bs), index_map)
+    """That block's scales, riding the pools' own 5-D index map; the layer
+    and NB dims are squeezed, so the kernels read a (Hkv, 1, BS) ref."""
+    return pl.BlockSpec((None, hkv, None, 1, bs), index_map)
 
 
 def _mk_paged_kernel(quantized: bool, staged: bool, has_alibi: bool):
     """Fixed-arity wrapper for one (quantized, staged, alibi) variant —
     pallas passes refs positionally in args order (scales right after the
     pools, then the staged pair, then alibi, then out + scratch)."""
-    def wrapper(lengths_ref, tables_ref, q_ref, k_ref, v_ref, *rest, **kw):
+    def wrapper(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
+                *rest, **kw):
         extra = list(rest[:-4])
         o_ref, m_scr, l_scr, acc_scr = rest[-4:]
         if quantized:
@@ -168,8 +193,8 @@ def _mk_paged_kernel(quantized: bool, staged: bool, has_alibi: bool):
             kw["kn_ref"], kw["vn_ref"] = extra.pop(0), extra.pop(0)
         if has_alibi:
             kw["alibi_ref"] = extra.pop(0)
-        _paged_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_scr, l_scr, acc_scr, **kw)
+        _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref,
+                      v_ref, o_ref, m_scr, l_scr, acc_scr, **kw)
     return wrapper
 
 
@@ -182,14 +207,17 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            window: Optional[int] = None,
                            alibi: Optional[jnp.ndarray] = None,
                            k_scales: Optional[jnp.ndarray] = None,
-                           v_scales: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """q: (B, 1, H, D); k/v_pool: (Hkv, NB, BS, D); tables: (B, T) int32
-    block tables; lengths: (B,) valid tokens per row — with `k_new`/`v_new`
-    (B, Hkv, D) the LAST valid token is the staged one (not yet in the
-    pool) and is folded in-register; without them the new token's slot
-    must already be written.
+                           v_scales: Optional[jnp.ndarray] = None,
+                           layer: Optional[jnp.ndarray] = None
+                           ) -> jnp.ndarray:
+    """q: (B, 1, H, D); k/v_pool: (L, Hkv, NB, BS, D) with `layer` () int32
+    the layer to read, or (Hkv, NB, BS, D) without one; tables: (B, T)
+    int32 block tables; lengths: (B,) valid tokens per row — with
+    `k_new`/`v_new` (B, Hkv, D) the LAST valid token is the staged one
+    (not yet in the pool) and is folded in-register; without them the new
+    token's slot must already be written.
 
-    `k_scales`/`v_scales` (Hkv, NB, BS) f32: int8-at-rest pools — the
+    `k_scales`/`v_scales` ([L,] Hkv, NB, BS) f32: int8-at-rest pools — the
     per-(kv-head, slot) dequant scales, DMA'd beside their blocks (same
     index map) and folded into logit/probability columns in-register
     (docs/kv_cache.md); staged tokens arrive in the compute dtype and are
@@ -203,7 +231,9 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     dense fallback for masked-decode families. Returns (B, 1, H, D)."""
     b, s, h, d = q.shape
     assert s == 1, "paged decode kernel is single-query"
-    hkv, nb, bs, _ = k_pool.shape
+    k_pool, v_pool, k_scales, v_scales, layer = _stacked_pools(
+        k_pool, v_pool, k_scales, v_scales, layer)
+    _, hkv, nb, bs, _ = k_pool.shape
     t = tables.shape[1]
     n_rep = h // hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
@@ -216,7 +246,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     # staged: pool holds lengths-1 valid tokens (the last is in-register)
     pool_len = lengths - 1 if staged else lengths
 
-    def kv_index(b_, j, L, Tb):
+    def kv_index(b_, j, L, Tb, Ly):
         # Clamp the logical block index into the row's LIVE band; repeated
         # physical ids make Pallas skip the HBM copies (above the cursor
         # AND, with a window, below the band). Clamp the table entry so a
@@ -228,37 +258,39 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             lo = jnp.maximum((L[b_] + qoff - window) // bs, 0)
             jj = jnp.maximum(jj, jnp.minimum(lo, last))
         phys = Tb[b_, jj]
-        return (0, jnp.clip(phys, 0, nb - 1), 0, 0)
+        return (Ly[0], 0, jnp.clip(phys, 0, nb - 1), 0, 0)
+
+    def row(b_, j, L, Tb, Ly):
+        return (b_, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, h, d), lambda b_, j, L, Tb: (b_, 0, 0)),
-        pl.BlockSpec((hkv, 1, bs, d), kv_index),
-        pl.BlockSpec((hkv, 1, bs, d), kv_index),
+        pl.BlockSpec((1, h, d), row),
+        _pool_block_spec(hkv, bs, d, kv_index),
+        _pool_block_spec(hkv, bs, d, kv_index),
     ]
-    args = [pool_len.astype(jnp.int32), tables.astype(jnp.int32),
+    args = [pool_len.astype(jnp.int32), tables.astype(jnp.int32), layer,
             qt, k_pool, v_pool]
     quantized = k_scales is not None
     if quantized:
         in_specs += [_scale_block_spec(hkv, bs, kv_index)] * 2
         args += [_scale_operand(k_scales), _scale_operand(v_scales)]
     if staged:
-        in_specs += [pl.BlockSpec((1, hkv, d), lambda b_, j, L, Tb: (b_, 0, 0)),
-                     pl.BlockSpec((1, hkv, d), lambda b_, j, L, Tb: (b_, 0, 0))]
+        in_specs += [pl.BlockSpec((1, hkv, d), row)] * 2
         args += [k_new, v_new]
     if alibi is not None:
         # (H, max(BS,128)) broadcast: Mosaic supports lane SLICES of a 2D
         # tile but not reshaping a lane vector into sublanes; the kernel
         # reads [:, :bs] ([:, :1] for the staged column)
         lw = max(bs, 128)
-        in_specs += [pl.BlockSpec((h, lw), lambda b_, j, L, Tb: (0, 0))]
+        in_specs += [pl.BlockSpec((h, lw), lambda b_, j, L, Tb, Ly: (0, 0))]
         args += [jnp.broadcast_to(
             jnp.asarray(alibi, jnp.float32).reshape(h, 1), (h, lw))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda b_, j, L, Tb: (b_, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, d), row),
         scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32),
                         pltpu.VMEM((h, 128), jnp.float32),
                         pltpu.VMEM((h, d), jnp.float32)],
@@ -278,7 +310,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     return out.reshape(b, 1, h, d)
 
 
-def _paged_prefill_kernel(starts_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_prefill_kernel(starts_ref, tables_ref, layer_ref, q_ref, k_ref,
+                          v_ref, o_ref,
                           m_scr, l_scr, acc_scr, *, scale, bs, nt, cq, hkv,
                           n_rep, d, window=None, alibi_ref=None,
                           ks_ref=None, vs_ref=None):
@@ -364,7 +397,8 @@ def _mk_paged_prefill_kernel(quantized: bool, has_alibi: bool):
     slopes) arrive as extra positional inputs between the pools and the
     output; route them to the matching kwargs (same scheme as
     _mk_paged_kernel on the decode side)."""
-    def wrapper(starts_ref, tables_ref, q_ref, k_ref, v_ref, *rest, **kw):
+    def wrapper(starts_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
+                *rest, **kw):
         extra = list(rest[:-4])
         o_ref, m_scr, l_scr, acc_scr = rest[-4:]
         if quantized:
@@ -372,8 +406,9 @@ def _mk_paged_prefill_kernel(quantized: bool, has_alibi: bool):
             kw["vs_ref"] = extra.pop(0)
         if has_alibi:
             kw["alibi_ref"] = extra.pop(0)
-        _paged_prefill_kernel(starts_ref, tables_ref, q_ref, k_ref, v_ref,
-                              o_ref, m_scr, l_scr, acc_scr, **kw)
+        _paged_prefill_kernel(starts_ref, tables_ref, layer_ref, q_ref,
+                              k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                              **kw)
     return wrapper
 
 
@@ -385,7 +420,8 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                             window: Optional[int] = None,
                             alibi: Optional[jnp.ndarray] = None,
                             k_scales: Optional[jnp.ndarray] = None,
-                            v_scales: Optional[jnp.ndarray] = None
+                            v_scales: Optional[jnp.ndarray] = None,
+                            layer: Optional[jnp.ndarray] = None
                             ) -> jnp.ndarray:
     """Chunked-prefill flash attention over the paged cache: q (B, S, H, D)
     are the S new tokens of each row (already written to the pool at
@@ -394,15 +430,19 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     `kv_cache.decode_mask` builds, evaluated in-kernel). The FastGen
     blocked-flash slot for MIXED prefill: replaces the r3 fallback
     (dense-view gather + f32 (B,H,S,M) logits) that measured ~140 ms/layer
-    at serving shape. Returns (B, S, H, D).
+    at serving shape. Returns (B, S, H, D). The pools are stacked
+    (L, Hkv, NB, BS, D) with `layer` () int32 the layer to read, or one
+    layer's own (Hkv, NB, BS, D) without one, as in the decode kernel.
 
-    k_scales/v_scales (Hkv, NB, BS) f32 mark an int8 pool: the kernel
+    k_scales/v_scales ([L,] Hkv, NB, BS) f32 mark an int8 pool: the kernel
     dequantizes by folding the per-token scale into the logit / probability
     columns (never materializing a dense bf16 cache). With unit scales the
     quantized path is bitwise-identical to the unquantized kernel on the
     same pool values."""
     b, s, h, d = q.shape
-    hkv, nb, bs, _ = k_pool.shape
+    k_pool, v_pool, k_scales, v_scales, layer = _stacked_pools(
+        k_pool, v_pool, k_scales, v_scales, layer)
+    _, hkv, nb, bs, _ = k_pool.shape
     t = tables.shape[1]
     n_rep = h // hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
@@ -416,7 +456,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     qt = q.reshape(b, nq, cq, hkv, n_rep, d)
     qt = jnp.moveaxis(qt, 3, 2).reshape(b, nq, hkv, cq * n_rep, d)
 
-    def kv_index(b_, qi, j, S_, Tb):
+    def kv_index(b_, qi, j, S_, Tb, Ly):
         # clamp to the row's last block live by the END of this prefill
         # (start + S tokens written); repeated ids elide the DMA — and,
         # with a window, blocks below the tile's band elide too
@@ -426,15 +466,17 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             lo = jnp.maximum((S_[b_] + qi * cq - window + 1) // bs, 0)
             jj = jnp.maximum(jj, jnp.minimum(lo, last))
         phys = Tb[b_, jj]
-        return (0, jnp.clip(phys, 0, nb - 1), 0, 0)
+        return (Ly[0], 0, jnp.clip(phys, 0, nb - 1), 0, 0)
+
+    def tile(b_, qi, j, S_, Tb, Ly):
+        return (b_, qi, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, hkv, cq * n_rep, d),
-                     lambda b_, qi, j, S_, Tb: (b_, qi, 0, 0, 0)),
-        pl.BlockSpec((hkv, 1, bs, d), kv_index),
-        pl.BlockSpec((hkv, 1, bs, d), kv_index),
+        pl.BlockSpec((1, 1, hkv, cq * n_rep, d), tile),
+        _pool_block_spec(hkv, bs, d, kv_index),
+        _pool_block_spec(hkv, bs, d, kv_index),
     ]
-    args = [starts.astype(jnp.int32), tables.astype(jnp.int32),
+    args = [starts.astype(jnp.int32), tables.astype(jnp.int32), layer,
             qt, k_pool, v_pool]
     quantized = k_scales is not None
 
@@ -448,14 +490,13 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         rows = jnp.broadcast_to(rows, (hkv, cq, n_rep, 1)).reshape(
             hkv, cq * n_rep, 1)
         in_specs += [pl.BlockSpec((hkv, cq * n_rep, 128),
-                                  lambda b_, qi, j, S_, Tb: (0, 0, 0))]
+                                  lambda b_, qi, j, S_, Tb, Ly: (0, 0, 0))]
         args += [jnp.broadcast_to(rows, (hkv, cq * n_rep, 128))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nq, t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, hkv, cq * n_rep, d),
-                               lambda b_, qi, j, S_, Tb: (b_, qi, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, hkv, cq * n_rep, d), tile),
         scratch_shapes=[pltpu.VMEM((hkv, cq * n_rep), jnp.float32),
                         pltpu.VMEM((hkv, cq * n_rep), jnp.float32),
                         pltpu.VMEM((hkv, cq * n_rep, d), jnp.float32)],
@@ -477,3 +518,167 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     out = out.reshape(b, nq, hkv, cq, n_rep, d)
     out = jnp.moveaxis(out, 2, 3).reshape(b, s, h, d)
     return out
+
+
+# ---- the write path: new tokens into the stacked pool, in place ----
+#
+# XLA's scatter wants the pool tiled with the KV-head dim second-minor (one
+# token's (Hkv, D) window is then one tile); the two kernels above read it
+# tiled over (BS, D). A program that scatters and attends therefore re-lays
+# the WHOLE pool before and after every scatter. This kernel writes in the
+# layout the attention kernels read: it takes the pools as they lie in HBM,
+# aliased to its outputs, and read-modify-writes only the blocks that the
+# new tokens fall in.
+
+
+def _kv_write_kernel(starts_ref, tables_ref, layer_ref, *refs, bs, t, nb, s,
+                     g, quantized):
+    n = 4 if quantized else 2          # K, V (and their scales)
+    news, pools, bufs, sem = refs[:n], refs[2 * n:3 * n], refs[3 * n:4 * n], \
+        refs[-1]                       # refs[n:2n]: the pools as inputs
+    layer = layer_ref[0] + pl.program_id(0)
+    b = pl.program_id(1)
+    start = starts_ref[b]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+
+    def piece(p, _):
+        lb = jnp.maximum(start, 0) // bs + p     # logical block
+        off = start - lb * bs                    # token 0's slot in it
+        lo, hi = jnp.maximum(off, 0), jnp.minimum(off + s, bs)
+        phys = tables_ref[b, jnp.minimum(lb, t - 1)]
+        # drop: parked rows and slots past capacity (lb >= t), unowned
+        # table entries (phys < 0), a stage with nothing before it
+        live = (start >= 0) & (lb < t) & (phys >= 0) & (phys < nb) & (hi > lo)
+
+        @pl.when(live)
+        def _piece():
+            # a block's scales are ONE row of the (NB, BS) tiles: they move
+            # with the `g` rows around them (Mosaic slices whole tiles)
+            first = jnp.minimum(phys // g * g, nb - g)
+            there = [pool.at[layer, :, phys] for pool in pools[:2]] + [
+                pool.at[layer, :, pl.ds(first, g)] for pool in pools[2:]]
+
+            def move(pairs):   # all in flight together, then all awaited
+                copies = [pltpu.make_async_copy(src, dst, sem.at[i])
+                          for i, (src, dst) in enumerate(pairs)]
+                for c in copies:
+                    c.start()
+                for c in copies:
+                    c.wait()
+
+            move(zip(there, bufs))
+            if s == 1:
+                hot = None
+            else:  # hot[r, c]: slot r of this block takes token c
+                hot = (jax.lax.broadcasted_iota(jnp.int32, (bs, s), 0) - off
+                       == jax.lax.broadcasted_iota(jnp.int32, (bs, s), 1))
+            keep = (rows >= lo) & (rows < hi)                    # (BS, 1)
+            for buf, new_ref in zip(bufs[:2], news[:2]):
+                new = new_ref[...]                               # (Hkv, S, D)
+                if hot is None:
+                    frame = new                  # broadcasts over the slots
+                else:
+                    # one term a slot, so exact in any dtype the MXU takes
+                    wide = jnp.float32 if new.dtype == jnp.float32 \
+                        else jnp.bfloat16
+                    frame = jax.lax.dot_general(
+                        jnp.broadcast_to(hot.astype(wide)[None],
+                                         new.shape[:1] + hot.shape),
+                        new.astype(wide), (((2,), (1,)), ((0,), (0,))),
+                        precision=jax.lax.Precision.HIGHEST
+                        if wide == jnp.float32 else None,
+                        preferred_element_type=jnp.float32)
+                buf[...] = jnp.where(keep[None], frame.astype(buf.dtype),
+                                     buf[...])
+            skeep = ((lanes >= lo) & (lanes < hi))[None] & (
+                jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1)
+                == phys - first)                                 # (1, g, BS)
+            for buf, new_ref in zip(bufs[2:], news[2:]):
+                new = new_ref[...]                               # (Hkv, S)
+                if hot is None:
+                    frame = new[:, :, None]                      # (Hkv,1,1)
+                else:
+                    frame = jnp.sum(jnp.where(hot[None], new[:, None, :],
+                                              0.0), axis=-1)[:, None, :]
+                buf[...] = jnp.where(skeep, frame, buf[...])     # (Hkv,g,BS)
+            move(zip(bufs, there))
+        return 0
+
+    # the row's S tokens fall in at most this many of its blocks; the loop
+    # keeps the kernel one piece long however many they are (a prefill
+    # bucket spans seventeen)
+    pieces = (s + bs - 2) // bs + 1
+    if pieces == 1:
+        piece(0, 0)
+    else:
+        jax.lax.fori_loop(0, pieces, piece, 0)
+
+
+def paged_kv_write(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
+                   k_new: jnp.ndarray, v_new: jnp.ndarray,
+                   tables: jnp.ndarray, starts: jnp.ndarray, layer=0,
+                   k_scales: Optional[jnp.ndarray] = None,
+                   v_scales: Optional[jnp.ndarray] = None,
+                   k_new_scales: Optional[jnp.ndarray] = None,
+                   v_new_scales: Optional[jnp.ndarray] = None):
+    """Write `k_new`/`v_new` (NL, B, S, Hkv, D), the S new tokens of each
+    row for layers `layer..layer+NL-1`, into the stacked pools
+    (L, Hkv, NB, BS, D) at logical positions `starts[b]..starts[b]+S-1`
+    through `tables` (B, T), IN PLACE: the pools are aliased to the
+    results, nothing else of them is read or written, and their tiling is
+    the one the attention kernels read. Returns `(k_pool, v_pool,
+    k_scales, v_scales)`.
+
+    What drops is what the XLA scatters of `kv_cache.py` drop: slots at or
+    past a row's capacity (parked rows), unowned table entries (< 0), and
+    a negative start. int8 pools take the new tokens already quantized,
+    with `k_new_scales`/`v_new_scales` (NL, B, S, Hkv) for the pools'
+    `k_scales`/`v_scales` (L, Hkv, NB, BS).
+
+    The grid's steps run one after another and each waits for its own
+    writes, so two rows that wrote one block would see each other's."""
+    nl, b, s, hkv, d = k_new.shape
+    _, _, nb, bs, _ = k_pool.shape
+    t = tables.shape[1]
+    quantized = k_scales is not None
+    g = min(8, nb)  # rows of a scale tile
+
+    def tokens(x):  # (NL, B, S, Hkv, ...) -> (NL, B, Hkv, S, ...)
+        return jnp.swapaxes(x, 2, 3)
+
+    def row_spec(*tail):  # one row's new tokens of one layer
+        return pl.BlockSpec((None, None) + tail,
+                            lambda li, b_, St, Tb, Ly: (li, b_) + (0,) * len(tail))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    args = [tokens(k_new.astype(k_pool.dtype)),
+            tokens(v_new.astype(v_pool.dtype))]
+    in_specs = [row_spec(hkv, s, d)] * 2
+    pools = [k_pool, v_pool]
+    scratch = [pltpu.VMEM((hkv, bs, d), k_pool.dtype),
+               pltpu.VMEM((hkv, bs, d), v_pool.dtype)]
+    if quantized:
+        args += [tokens(k_new_scales), tokens(v_new_scales)]
+        in_specs += [row_spec(hkv, s)] * 2
+        pools += [k_scales, v_scales]
+        scratch += [pltpu.VMEM((hkv, g, bs), jnp.float32)] * 2
+    first = 3 + len(args)  # scalar prefetch operands count as inputs
+    out = pl.pallas_call(
+        functools.partial(_kv_write_kernel, bs=bs, t=t, nb=nb, s=s, g=g,
+                          quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(nl, b),
+            in_specs=in_specs + [hbm] * len(pools),
+            out_specs=[hbm] * len(pools),
+            scratch_shapes=scratch + [
+                pltpu.SemaphoreType.DMA((len(pools),))]),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        input_output_aliases={first + i: i for i in range(len(pools))},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+        name="kv_write_paged",
+    )(starts.astype(jnp.int32), tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *args, *pools)
+    return tuple(out) + (None,) * (4 - len(out))

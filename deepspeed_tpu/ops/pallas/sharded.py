@@ -30,7 +30,7 @@ Supported matrix (docs/quantized_serving.md has the serving view):
 | fused int8 dequant-GEMM     | 'model'     | N-sharded (column-parallel)  |
 |                             |             | or K-sharded + psum          |
 | dense decode attention      | 'model'     | KV-head-sharded, no psum     |
-| paged decode/prefill        | 'model'     | KV-head-sharded, no psum     |
+| paged decode/prefill/write  | 'model'     | KV-head-sharded, no psum     |
 
 Everything else (other axes nontrivial, non-divisible shapes, kernels
 disabled) falls back to the XLA path — loudly, via `kernel_fallback`
@@ -181,25 +181,37 @@ def sharded_decode_attention(q, k_cache, v_cache, lengths, mesh,
     return kernel_shard_map(body, mesh, tuple(in_specs), spec)(*args)
 
 
+def _paged_pool_operands(k_pool, v_pool, k_scales, v_scales, layer):
+    """The stacked pools, their scales and the layer scalar as shard_map
+    operands: KV heads over 'model', the layer replicated."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _stacked_pools
+    k_pool, v_pool, k_scales, v_scales, layer = _stacked_pools(
+        k_pool, v_pool, k_scales, v_scales, layer)
+    pspec = P(None, "model", None, None, None)
+    specs, args = [pspec, pspec, P()], [k_pool, v_pool, layer]
+    if k_scales is not None:
+        specs += [P(None, "model", None, None)] * 2
+        args += [k_scales, v_scales]
+    return specs, args
+
+
 def sharded_paged_decode_attention(q, k_pool, v_pool, tables, lengths, mesh,
                                    softmax_scale: Optional[float] = None,
                                    k_new=None, v_new=None,
                                    window: Optional[int] = None,
                                    alibi=None,
-                                   k_scales=None, v_scales=None):
-    """`paged_decode_attention` with q (B,1,H,D), pools (Hkv,NB,BS,D) and
-    the (B,Hkv,D) staged token head-sharded over 'model'; tables/lengths
-    replicated. alibi slopes (H,) and the (Hkv,NB,BS) int8 scale leaves
-    shard with the heads."""
+                                   k_scales=None, v_scales=None, layer=None):
+    """`paged_decode_attention` with q (B,1,H,D), pools ([L,]Hkv,NB,BS,D)
+    and the (B,Hkv,D) staged token head-sharded over 'model'; tables,
+    lengths and the layer scalar replicated. alibi slopes (H,) and the
+    ([L,]Hkv,NB,BS) int8 scale leaves shard with the heads."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
     qspec = P(None, None, "model", None)
-    pspec = P("model", None, None, None)
-    in_specs = [qspec, pspec, pspec, P(), P()]
-    args = [q, k_pool, v_pool, tables, lengths]
     quantized = k_scales is not None
-    if quantized:
-        in_specs += [P("model", None, None)] * 2
-        args += [k_scales, v_scales]
+    pool_specs, pool_args = _paged_pool_operands(k_pool, v_pool, k_scales,
+                                                 v_scales, layer)
+    in_specs = [qspec, P(), P()] + pool_specs
+    args = [q, tables, lengths] + pool_args
     staged = k_new is not None
     if staged:
         in_specs += [P(None, "model", None)] * 2
@@ -209,7 +221,7 @@ def sharded_paged_decode_attention(q, k_pool, v_pool, tables, lengths, mesh,
         in_specs.append(P("model"))
         args.append(alibi)
 
-    def body(q, kp, vp, tb, ln, *rest):
+    def body(q, tb, ln, kp, vp, ly, *rest):
         kn = vn = al = ks = vs = None
         rest = list(rest)
         if quantized:
@@ -224,7 +236,7 @@ def sharded_paged_decode_attention(q, k_pool, v_pool, tables, lengths, mesh,
                                       softmax_scale=softmax_scale,
                                       k_new=kn, v_new=vn,
                                       window=window, alibi=al,
-                                      k_scales=ks, v_scales=vs)
+                                      k_scales=ks, v_scales=vs, layer=ly[0])
 
     return kernel_shard_map(body, mesh, tuple(in_specs), qspec)(*args)
 
@@ -234,25 +246,23 @@ def sharded_paged_prefill_attention(q, k_pool, v_pool, tables, starts, mesh,
                                     block_q: int = 256,
                                     window: Optional[int] = None,
                                     alibi=None,
-                                    k_scales=None, v_scales=None):
+                                    k_scales=None, v_scales=None, layer=None):
     """`paged_prefill_attention` head-sharded over 'model' (same layout
     contract as the decode wrapper; int8 scale leaves shard with the
     heads)."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_prefill_attention
     qspec = P(None, None, "model", None)
-    pspec = P("model", None, None, None)
-    in_specs = [qspec, pspec, pspec, P(), P()]
-    args = [q, k_pool, v_pool, tables, starts]
     quantized = k_scales is not None
-    if quantized:
-        in_specs += [P("model", None, None)] * 2
-        args += [k_scales, v_scales]
+    pool_specs, pool_args = _paged_pool_operands(k_pool, v_pool, k_scales,
+                                                 v_scales, layer)
+    in_specs = [qspec, P(), P()] + pool_specs
+    args = [q, tables, starts] + pool_args
     has_alibi = alibi is not None
     if has_alibi:
         in_specs.append(P("model"))
         args.append(alibi)
 
-    def body(q, kp, vp, tb, st, *rest):
+    def body(q, tb, st, kp, vp, ly, *rest):
         rest = list(rest)
         ks = vs = None
         if quantized:
@@ -262,9 +272,42 @@ def sharded_paged_prefill_attention(q, k_pool, v_pool, tables, starts, mesh,
         return paged_prefill_attention(q, kp, vp, tb, st,
                                        softmax_scale=softmax_scale,
                                        block_q=block_q, window=window,
-                                       alibi=al, k_scales=ks, v_scales=vs)
+                                       alibi=al, k_scales=ks, v_scales=vs,
+                                       layer=ly[0])
 
     return kernel_shard_map(body, mesh, tuple(in_specs), qspec)(*args)
+
+
+def sharded_paged_kv_write(k_pool, v_pool, k_new, v_new, tables, starts,
+                           layer, mesh, k_scales=None, v_scales=None,
+                           k_new_scales=None, v_new_scales=None):
+    """`paged_kv_write` with the stacked pools (L,Hkv,NB,BS,D), their
+    scales and the new tokens (NL,B,S,Hkv,D) head-sharded over 'model':
+    each shard writes its own heads' blocks in place; tables, starts and
+    the layer are replicated."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_kv_write
+    pspec = P(None, "model", None, None, None)
+    nspec = P(None, None, None, "model", None)
+    in_specs = [pspec, pspec, nspec, nspec, P(), P(), P()]
+    out_specs = [pspec, pspec]
+    args = [k_pool, v_pool, k_new, v_new, tables, starts,
+            jnp.asarray(layer, jnp.int32).reshape(1)]
+    quantized = k_scales is not None
+    if quantized:
+        sspec = P(None, "model", None, None)
+        in_specs += [sspec, sspec] + [P(None, None, None, "model")] * 2
+        out_specs += [sspec, sspec]
+        args += [k_scales, v_scales, k_new_scales, v_new_scales]
+
+    def body(kp, vp, kn, vn, tb, st, ly, *scales):
+        ks, vs, kns, vns = scales if quantized else (None,) * 4
+        return paged_kv_write(kp, vp, kn, vn, tb, st, ly[0], k_scales=ks,
+                              v_scales=vs, k_new_scales=kns,
+                              v_new_scales=vns)[:len(out_specs)]
+
+    out = kernel_shard_map(body, mesh, tuple(in_specs),
+                           tuple(out_specs))(*args)
+    return tuple(out) + (None,) * (4 - len(out))
 
 
 # ---- training flash attention (batch over the data axes, heads over

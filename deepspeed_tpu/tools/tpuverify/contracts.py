@@ -1,4 +1,4 @@
-"""The seven program-level contracts (docs/static_analysis.md, semantic
+"""The eight program-level contracts (docs/static_analysis.md, semantic
 layer). Each one is a perf-ledger incident turned into an executable
 claim; the ``incident`` string is the provenance the docs catalog renders.
 """
@@ -12,6 +12,7 @@ from deepspeed_tpu.tools.tpuverify.jaxpr_util import (
     CALLBACK_PRIMS,
     SHARD_MAP_PRIMS,
     aliasing_output_count,
+    arg_aliasing,
     count_cache_scatters,
     donated_leaves,
     primitive_eqns,
@@ -155,6 +156,74 @@ class KVScatterDiscipline(Contract):
                     f"program body (budget {put.scatter_budget}; body "
                     f"{path}) — stage appends and land them with one "
                     "batched scatter per step")
+
+
+# MLIR element types of the dtypes a KV pool or its scales can have
+_MLIR_ELT = {"float32": "f32", "bfloat16": "bf16", "float16": "f16",
+             "int8": "i8"}
+
+
+@register
+class KVPoolInPlace(Contract):
+    id = "kv-pool-in-place"
+    doc = ("A v2 serving program holds ONE buffer per paged KV pool from "
+           "argument to result: no scan slices a pool per layer (a scanned "
+           "input or output of the pool's shape), no dynamic_slice or "
+           "dynamic_update_slice takes a pool, and the lowered module "
+           "aliases every pool argument to an output. Layers address the "
+           "stacked pool by index.")
+    incident = ("PR 29: the cached block scan scanned over the stacked "
+                "pools, so each of a round's two passes cut every layer's "
+                "pool out and wrote it back, and the chunk scatter copied "
+                "it once more: 36 ms of an 80 ms round at Qwen2.5-3B, and a "
+                "second copy of the pool in every chunk program.")
+
+    def applies(self, put) -> bool:
+        return put.kind == "program" and bool(put.pool_shapes)
+
+    def check(self, put) -> Iterable[Violation]:
+        pools = set(put.pool_shapes)
+
+        def is_pool(var) -> bool:
+            aval = getattr(var, "aval", None)
+            return aval is not None and \
+                (tuple(aval.shape), str(aval.dtype)) in pools
+
+        for path, eqn in primitive_eqns(put.jaxpr(), {"scan"}):
+            skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+            sliced = [v for v in eqn.invars[skip:] if is_pool(v)] + \
+                [v for v in eqn.outvars[eqn.params["num_carry"]:]
+                 if is_pool(v)]
+            if sliced:
+                yield Violation(
+                    self.id, put.name,
+                    f"scan in {path} scans over {len(sliced)} pool-shaped "
+                    f"operand(s) {tuple(sliced[0].aval.shape)}: every layer's "
+                    "pool is cut out of the stack and written back — carry "
+                    "or close over the pool and scan the layer index "
+                    "(kv_cache.scan_paged_layers)")
+        for path, eqn in primitive_eqns(
+                put.jaxpr(), {"dynamic_slice", "dynamic_update_slice"}):
+            if is_pool(eqn.invars[0]):
+                yield Violation(
+                    self.id, put.name,
+                    f"{eqn.primitive.name} of a pool "
+                    f"{tuple(eqn.invars[0].aval.shape)} in {path} — address "
+                    "the stacked pool by (layer, block) index instead")
+        lowered = put.lowered()
+        if lowered is None:
+            return
+        args = arg_aliasing(lowered)
+        for shape, dtype in sorted(pools):
+            mine = [a for a in args
+                    if a[0] == shape and a[1] == _MLIR_ELT.get(dtype)]
+            loose = sum(1 for a in mine if not a[2])
+            if not mine or loose:
+                yield Violation(
+                    self.id, put.name,
+                    f"{loose or 'every'} pool argument(s) {shape} {dtype} "
+                    "not aliased to an output in the lowered module — the "
+                    "program holds a second copy of the pool")
 
 
 @register

@@ -136,3 +136,28 @@ def aliasing_output_count(lowered) -> int:
     except Exception:
         return -1  # not introspectable on this jax — treat as unknown
     return text.count("tf.aliasing_output")
+
+
+def arg_aliasing(lowered) -> List[Tuple[Tuple[int, ...], str, bool]]:
+    """(shape, MLIR element type, aliased to an output?) of every argument
+    of the lowered program's entry function, in order, read from its
+    StableHLO text (`%argN: tensor<2x8x16xf32> {tf.aliasing_output = 0 ...}`);
+    empty where the text cannot be had."""
+    import re
+    try:
+        text = lowered.as_text()
+    except Exception:
+        return []
+    head = re.search(r"func\.func public @main\((.*?)\)\s*->", text, re.S)
+    if head is None:
+        return []
+    out = []
+    # one chunk an argument: its attributes nest braces (shardings)
+    for chunk in re.split(r"%arg\d+: ", head.group(1))[1:]:
+        m = re.match(r"tensor<([^>]*)>", chunk)
+        if m is None:
+            continue
+        *dims, elt = m.group(1).split("x")
+        out.append((tuple(int(d) for d in dims), elt,
+                    "tf.aliasing_output" in chunk))
+    return out

@@ -39,6 +39,9 @@ class ProgramUnderTest:
     donate: Optional[Tuple[int, ...]] = None  # argnums contracted to donate
     cache_shapes: frozenset = frozenset()     # (shape, dtype) of KV buffers
     scatter_budget: int = 2      # per body per aval: one K + one V scatter
+    # (shape, dtype) of the stacked paged pools (and int8 scales) a v2
+    # program must keep whole and in place (kv-pool-in-place)
+    pool_shapes: frozenset = frozenset()
     allow_shard_map: bool = False
     check_callbacks: bool = True
     kind: str = "program"
@@ -321,6 +324,15 @@ def build_v2_puts(led, serve_mode: Optional[str] = None,
 
     label = "v2" if serve_mode in (None, "dequant") else f"v2[{serve_mode}]"
     cache_shapes = scatter_target_shapes(v2.cache)
+    # the zoo model's cached scan addresses the pools by layer; the
+    # layer_scan and capacity modes have loops of their own over per-layer
+    # views, which kv-pool-in-place does not hold yet
+    pool_shapes = frozenset()
+    if v2.kv_layout == "paged" and v2.serve_mode == "dequant":
+        pool_shapes = frozenset(
+            (tuple(x.shape), str(x.dtype))
+            for side in (v2.cache.k, v2.cache.v)
+            for x in (side.pool, side.scales) if x is not None)
     puts: List[Any] = []
     records = []
     for key, fn in v2._jits.items():
@@ -342,7 +354,7 @@ def build_v2_puts(led, serve_mode: Optional[str] = None,
         donate = (0,) if first == "cow_copy" else (1,)
         puts.append(ProgramUnderTest(
             name=f"v2:{det_name}", fn=raw, args=args, donate=donate,
-            cache_shapes=cache_shapes))
+            cache_shapes=cache_shapes, pool_shapes=pool_shapes))
     puts.append(EngineUnderTest(
         name=label, detector=v2.recompiles, records=records,
         pinned_trees=[(f"{label}.params", v2.params),

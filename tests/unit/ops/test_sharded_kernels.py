@@ -221,6 +221,57 @@ def test_sharded_paged_prefill_parity():
            paged_prefill_attention(q, kp, vp, tables, starts), tol=1e-4)
 
 
+@pytest.mark.parametrize("kind", ["decode", "prefill", "write",
+                                  "write_int8"])
+def test_sharded_paged_kernels_on_the_stacked_pool(kind):
+    """The operand the v2 programs hand over on a tp mesh: the whole
+    stacked pool, KV heads over 'model', and the layer to use replicated."""
+    from deepspeed_tpu.inference.kv_cache import quantize_kv_tokens
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_kv_write, paged_prefill_attention)
+    from deepspeed_tpu.ops.pallas.sharded import sharded_paged_kv_write
+    mesh = _tp_mesh()
+    rng = np.random.default_rng(8)
+    nl, b, hkv, nb, bs, d, h, t, s = 3, 2, 4, 8, 16, 64, 8, 4, 8
+    layer = jnp.int32(1)
+    kp = jnp.asarray(rng.standard_normal((nl, hkv, nb, bs, d)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((nl, hkv, nb, bs, d)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(nb)[: b * t].reshape(b, t), jnp.int32)
+    cursor = jnp.asarray([17, 40], jnp.int32)
+    if kind == "decode":
+        q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
+        got = sharded_paged_decode_attention(q, kp, vp, tables, cursor, mesh,
+                                             layer=layer)
+        want = paged_decode_attention(q, kp[1], vp[1], tables, cursor)
+    elif kind == "prefill":
+        q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+        got = sharded_paged_prefill_attention(q, kp, vp, tables, cursor, mesh,
+                                              layer=layer)
+        want = paged_prefill_attention(q, kp[1], vp[1], tables, cursor)
+    else:
+        new = jnp.asarray(rng.standard_normal((2, 1, b, s, hkv, d)),
+                          jnp.float32)
+        args, extra = (kp, vp, new[0], new[1]), {}
+        if kind == "write_int8":
+            (kq, ks), (vq, vs) = quantize_kv_tokens(kp), quantize_kv_tokens(vp)
+            (kn, kns), (vn, vns) = (quantize_kv_tokens(new[0]),
+                                    quantize_kv_tokens(new[1]))
+            args = (kq, vq, kn, vn)
+            extra = dict(k_scales=ks, v_scales=vs, k_new_scales=kns,
+                         v_new_scales=vns)
+        got = sharded_paged_kv_write(*args, tables, cursor, layer, mesh,
+                                     **extra)
+        want = paged_kv_write(*args, tables, cursor, layer, **extra)
+        got, want = [x for x in got if x is not None], \
+            [x for x in want if x is not None]
+        assert len(got) == (4 if extra else 2)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert not np.array_equal(np.asarray(got[0]), np.asarray(args[0]))
+        return
+    _close(got, want, tol=1e-4)
+
+
 # ------------------------------------------- cached_attention dispatch
 
 def _prefix_mask(index, m, s=1):

@@ -174,6 +174,110 @@ def test_scan_body_counts_per_step():
     assert verify([put], contracts=["kv-scatter-discipline"]) == []
 
 
+# ------------------------------------------------------- kv-pool-in-place
+
+_POOL = ((3, 2, 4, 8, 16), "float32")  # (L, Hkv, NB, BS, D) toy pool
+_POOLS = frozenset({_POOL})
+
+
+def _pool_put(fn, extra=(), donate=(0,), **kw):
+    return _put(jax.jit(fn, donate_argnums=donate),
+                [_sds(_POOL[0]), *extra], pool_shapes=_POOLS, **kw)
+
+
+def _layer_write(pool, layer, tok):
+    """One token row into `[layer, :, slot 5]` of the stacked pool."""
+    flat = pool.reshape(3, 2, 32, 16)
+    return flat.at[layer, :, 5].set(tok).reshape(pool.shape)
+
+
+def _by_index(pool, toks):
+    def body(p, x):
+        layer, tok = x
+        return _layer_write(p, layer, tok), ()
+    return jax.lax.scan(body, pool, (jnp.arange(3), toks))[0]
+
+
+def _by_scanning_the_pool(pool, toks):
+    def body(_, x):
+        p, tok = x                       # one layer's pool, cut out
+        return (), p.reshape(2, 32, 16).at[:, 5].set(tok).reshape(p.shape)
+    return jax.lax.scan(body, (), (pool, toks))[1]
+
+
+def _by_slicing(pool, toks):
+    for layer in range(3):
+        p = jax.lax.dynamic_index_in_dim(pool, layer, keepdims=False)
+        p = p.reshape(2, 32, 16).at[:, 5].set(toks[layer]).reshape(p.shape)
+        pool = jax.lax.dynamic_update_index_in_dim(pool, p, layer, 0)
+    return pool
+
+
+_TOKS = [_sds((3, 2, 16))]
+
+
+def test_kv_pool_in_place_clean():
+    put = _pool_put(_by_index, _TOKS)
+    assert verify([put], contracts=["kv-pool-in-place"]) == []
+
+
+@pytest.mark.parametrize("fn,what", [
+    (_by_scanning_the_pool, "scans over"),
+    (_by_slicing, "dynamic_slice of a pool"),
+    (_by_slicing, "dynamic_update_slice of a pool"),
+], ids=["scanned", "sliced", "written_back"])
+def test_kv_pool_in_place_violating(fn, what):
+    out = verify([_pool_put(fn, _TOKS)], contracts=["kv-pool-in-place"])
+    assert _ids(out) == ["kv-pool-in-place"]
+    assert any(what in v.message for v in out)
+
+
+def test_kv_pool_in_place_wants_every_pool_aliased():
+    # the pool comes back, but the program was not given its buffer
+    put = _pool_put(_by_index, _TOKS, donate=())
+    out = verify([put], contracts=["kv-pool-in-place"])
+    assert len(out) == 1 and "not aliased" in out[0].message
+    # two pools of one shape, one of them read only: one loose argument
+    def one_of_two(k, v, toks):
+        return _by_index(k, toks), v.sum()
+    put = _put(jax.jit(one_of_two, donate_argnums=(0, 1)),
+               [_sds(_POOL[0]), _sds(_POOL[0]), *_TOKS], pool_shapes=_POOLS)
+    out = verify([put], contracts=["kv-pool-in-place"])
+    assert len(out) == 1 and out[0].message.startswith("1 pool argument")
+
+
+def test_kv_pool_in_place_needs_pool_shapes():
+    # a program that names no pool (v1, train, a slot-layout engine)
+    put = _put(jax.jit(_by_scanning_the_pool), [_sds(_POOL[0]), *_TOKS])
+    assert verify([put], contracts=["kv-pool-in-place"]) == []
+
+
+def test_kv_scatter_budget_counts_the_stacked_target():
+    """The chunk scatter now targets the STACKED aval from inside the
+    layer scan: `scatter_target_shapes` lists it (and its token-flat
+    view), so the budget still counts one K and one V scatter a body, and
+    a third is still a finding."""
+    from deepspeed_tpu.inference.kv_cache import scatter_target_shapes
+    shapes = scatter_target_shapes({"k": _sds(_POOL[0])})
+    assert ((3, 2, 32, 16), "float32") in shapes
+
+    def chunk(k, v, toks, extra):
+        def body(kv, x):
+            layer, tok = x
+            k, v = kv
+            k, v = _layer_write(k, layer, tok), _layer_write(v, layer, tok)
+            if extra:
+                k = _layer_write(k, layer, tok + 1)
+            return (k, v), ()
+        return jax.lax.scan(body, (k, v), (jnp.arange(3), toks))[0]
+
+    for extra, want in ((False, []), (True, ["kv-scatter-discipline"])):
+        put = _put(jax.jit(lambda k, v, t: chunk(k, v, t, extra)),
+                   [_sds(_POOL[0]), _sds(_POOL[0]), *_TOKS],
+                   cache_shapes=shapes)
+        assert _ids(verify([put], contracts=["kv-scatter-discipline"])) == want
+
+
 # -------------------------------------------------------- no-host-callback
 
 
@@ -307,7 +411,7 @@ def test_unknown_contract_raises():
 
 def test_contract_catalog_complete():
     assert sorted(all_contracts()) == [
-        "donation-aliasing", "kv-scatter-discipline",
+        "donation-aliasing", "kv-pool-in-place", "kv-scatter-discipline",
         "manual-region-allowlist", "no-host-callback",
         "pinned-sharding", "registration-coverage", "residency-coverage"]
     for contract in all_contracts().values():
