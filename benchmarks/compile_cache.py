@@ -1,6 +1,6 @@
 """Where the entry scripts keep JAX's persistent compilation cache.
 
-`chip_smoke.py`, `bench.py` and the `benchmarks/` scripts call
+`chip_smoke.py` and `benchmarks/hf7b_decode.py` call
 `enable_compile_cache()` before anything compiles. The cache key includes the
 directory, so the directory never moves: `JAX_COMPILATION_CACHE_DIR` when
 the environment sets it (then no directory is set in code), otherwise

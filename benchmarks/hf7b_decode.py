@@ -32,7 +32,7 @@ r6 on-chip numbers pend the next TPU-attached run).
 CAPACITY mode (r7): `--capacity` serves the same checkpoint with the
 layers parked in HOST memory and streamed per layer with double-buffered
 `jax.device_put` prefetch (`inference/capacity_scan.py`) — the engine
-lift of the r5 `capacity_serve.py` probe's (b) outcome: XLA refuses to
+lift of an r5 probe's (b) outcome: XLA refuses to
 auto-stage pinned_host params into compute ("memory_space of all inputs
 passed to `gather` must be the same"), so staging must be an explicit
 per-layer transfer. At 7B this bounds HBM to ~2 layer slices (~0.4 GB

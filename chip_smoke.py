@@ -475,22 +475,18 @@ def dispatch_calls():
 
 
 def train_losses(sz: Sizes, seed: int, devices, dp: int = 1, tp: int = 1,
-                 steps: int = 4, ledger_path: str = "") -> Dict[str, Any]:
+                 steps: int = 4) -> Dict[str, Any]:
     """`steps` fused train steps on a repeated batch over `devices`
     (dp x tp mesh): ZeRO-3, bf16, FusedAdam, flash attention, remat and
-    chunked cross-entropy — the flagship recipe of bench.py at this model's
-    widths. The global batch is the same whatever the mesh. With a
-    `ledger_path` the program ledger records the compiled step (one more
-    compile) and its collectives are reported."""
+    chunked cross-entropy — the recipe of the benchmark's train cells at
+    this model's widths. The global batch is the same whatever the mesh."""
     import deepspeed_tpu
     from deepspeed_tpu.models.qwen2 import (init_params_and_specs,
                                             llama_loss_fn, materialize_params,
                                             qwen2_config)
-    from deepspeed_tpu.telemetry.ledger import ProgramLedger, set_ledger
     from deepspeed_tpu.utils import groups
     from deepspeed_tpu.utils.groups import MeshTopology
 
-    ledger = set_ledger(ProgramLedger(path=ledger_path or None))
     cfg = qwen2_config(sz.preset, num_hidden_layers=sz.train_layers,
                        max_position_embeddings=sz.seq, remat=True,
                        remat_policy="checkpoint_dots",
@@ -532,19 +528,14 @@ def train_losses(sz: Sizes, seed: int, devices, dp: int = 1, tp: int = 1,
     if not all(np.isfinite(losses)):
         raise AssertionError(f"non-finite loss: {losses}")
     run_s = float(np.median(walls[1:]))
-    out = {"layers": sz.train_layers, "params_m": round(n_params / 1e6, 1),
-           "mesh": {"dp": dp, "tp": tp}, "seq": sz.seq,
-           "global_batch": sz.global_batch, "micro_batch": sz.micro_batch,
-           "gas": gas, "losses": [round(l, 5) for l in losses],
-           "loss_first": round(losses[0], 5), "loss_last": round(losses[-1], 5),
-           "compile_s": round(walls[0] - run_s, 2), "run_s": round(run_s, 3),
-           "dispatch": dict(calls), "device_bytes_in_use": held}
-    row = ledger.row("train:train_batch")
-    if row is not None:
-        out["compiled_step"] = {k: row[k] for k in (
-            "comm_ops", "comm_bytes", "comm_bytes_by_axis", "peak_hbm_bytes")}
-    ledger.close()
-    return out
+    return {"layers": sz.train_layers, "params_m": round(n_params / 1e6, 1),
+            "mesh": {"dp": dp, "tp": tp}, "seq": sz.seq,
+            "global_batch": sz.global_batch, "micro_batch": sz.micro_batch,
+            "gas": gas, "losses": [round(l, 5) for l in losses],
+            "loss_first": round(losses[0], 5),
+            "loss_last": round(losses[-1], 5),
+            "compile_s": round(walls[0] - run_s, 2), "run_s": round(run_s, 3),
+            "dispatch": dict(calls), "device_bytes_in_use": held}
 
 
 def phase_train(sz: Sizes, seed: int) -> Dict[str, Any]:
@@ -809,11 +800,8 @@ def phase_train_sharded(sz: Sizes, seed: int) -> Dict[str, Any]:
     losses may differ by reduction order in bf16 and no more."""
     tol = 5e-3  # relative, per step: bf16 sums reassociated across 4 chips
     #             (measured 2.8e-4 on the 2x2 v5e)
-    os.makedirs("chiprun_out", exist_ok=True)
     one = train_losses(sz, seed, jax.devices()[:1])
-    four = train_losses(
-        sz, seed, jax.devices()[:4], dp=2, tp=2,
-        ledger_path=os.path.join("chiprun_out", "chip_smoke_ledger.jsonl"))
+    four = train_losses(sz, seed, jax.devices()[:4], dp=2, tp=2)
     check_every_device_holds(four)
     worst = max(abs(a - b) / abs(a)
                 for a, b in zip(one["losses"], four["losses"]))

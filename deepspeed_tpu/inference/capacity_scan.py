@@ -1,7 +1,7 @@
 """ZeRO-Inference capacity serve mode — layer-streamed decode with
 double-buffered host→HBM prefetch (models LARGER than device memory).
 
-The r5 probe (`benchmarks/capacity_serve.py`) measured outcome (b): XLA
+An r5 probe (since deleted) measured outcome (b): XLA
 will NOT auto-stage `pinned_host` params into compute ("memory_space of
 all inputs passed to `gather` must be the same"), and even *slicing* a
 host-memory-space jax Array enters compute with a host operand. So the
@@ -270,9 +270,6 @@ class CapacityRunner:
 
         # --- programs + prefetch state ---
         self._block = jax.jit(self._make_block())
-        self._block_captured = False   # program-ledger capture, first pass
-        self._ledger_row = None
-        self._block_other_arg_bytes = 0
         self._embed_jit = None
         self._head_jit = {}
         self._logits_jit = None
@@ -319,50 +316,6 @@ class CapacityRunner:
 
     def _layer_tree(self, bufs):
         return jax.tree_util.tree_unflatten(self._layer_treedef, bufs)
-
-    def _capture_block(self, h, buf, aux, kv) -> None:
-        """Program-ledger capture of the SHARED block program at its first
-        dispatch (one extra AOT compile, compile-time only — the hot layer
-        loop never touches this again), then the CapacityPlan-vs-
-        memory_analysis() check."""
-        if self._block_captured:
-            return
-        self._block_captured = True
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        led = get_ledger()
-        if not led.enabled:
-            return
-        try:
-            compiled = self._block.lower(h, buf, aux, kv).compile()
-            row = led.capture("v1:capacity:block", compiled=compiled)
-            if row is None:
-                return
-            self._ledger_row = row
-            # the block's NON-weight argument bytes (h, rope/mask aux, one
-            # layer's KV) are exact from the concrete args — the plan's
-            # own claim is slice_bytes, which is what the check exercises
-            self._block_other_arg_bytes = sum(
-                int(getattr(x, "nbytes", 0))
-                for x in jax.tree_util.tree_leaves((h, aux, kv)))
-            self.check_plan()
-        except Exception as e:
-            logger.debug(f"ledger: capacity block capture failed: {e}")
-
-    def check_plan(self, tolerance: float = 0.10) -> bool:
-        """Verify the CapacityPlan against what XLA actually compiled:
-        planned block argument bytes (plan.slice_bytes — the streamed
-        weight slice — plus the measured non-weight args) vs the compiled
-        block program's memory_analysis() argument bytes. A drifted plan
-        warns, emits a plan_check telemetry event, and returns False.
-        True (vacuously) before the first ledgered dispatch."""
-        if self._ledger_row is None:
-            return True
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        planned = self.plan.slice_bytes + self._block_other_arg_bytes
-        return get_ledger().verify_plan(
-            "v1:capacity:block", planned,
-            self._ledger_row["argument_bytes"], tolerance=tolerance,
-            what="block argument_bytes")
 
     def _host_slice(self, l: int) -> List[np.ndarray]:
         """Layer l's host leaves; NVMe-parked layers synchronize their
@@ -478,7 +431,6 @@ class CapacityRunner:
                 t0 = time.perf_counter()
                 buf = self._await_staged(buf, l)
                 stall += time.perf_counter() - t0
-                self._capture_block(h, buf, aux, (cache_k[l], cache_v[l]))
                 h, (cache_k[l], cache_v[l]) = self._block(
                     h, buf, aux, (cache_k[l], cache_v[l]))
                 _await_result(h)
@@ -493,7 +445,6 @@ class CapacityRunner:
             t0 = time.perf_counter()
             buf = self._await_staged(buf, l)
             stall += time.perf_counter() - t0
-            self._capture_block(h, buf, aux, (cache_k[l], cache_v[l]))
             h, (cache_k[l], cache_v[l]) = self._block(
                 h, buf, aux, (cache_k[l], cache_v[l]))
             if prev_out is not None:

@@ -277,8 +277,8 @@ class InferenceEngine:
             return self._dispatch_generate(key, input_ids, rng, b,
                                            int(max_new_tokens))
         # a new (b, s, new, sampling) key: its build, compile and first
-        # dispatch are one `compile` span under the program's ledger name
-        with compile_span(self._ledger_name(key), "v1"):
+        # dispatch are one `compile` span under the program's stable name
+        with compile_span(self._program_name(key), "v1"):
             self._build_program(key, input_ids, rng)
             return self._dispatch_generate(key, input_ids, rng, b,
                                            int(max_new_tokens))
@@ -287,7 +287,6 @@ class InferenceEngine:
         if getattr(self, "serve_mode", "dequant") == "capacity":
             # host-driven layer-streamed loop (capacity_scan) — the runner
             # owns placement/layouts, so the AUTO-layout pin never applies
-            # (and it ledgers its own block program at first dispatch)
             fault_point("program_compile", label="capacity")
             self._generate_jit[key] = self._capacity.bind_key(key)
         elif self._auto_layouts() and not getattr(self, "_layouts_pinned",
@@ -299,19 +298,14 @@ class InferenceEngine:
             self._generate_jit[key] = self._compile_auto_layout(
                 self._build_for_key(key, auto_layout=True), input_ids, rng)
             self._layouts_pinned = True
-            # the AOT executable already exists here — ledger it free
-            self._ledger_capture(key, compiled=self._last_aot_compiled,
-                                 input_ids=input_ids, rng=rng)
         else:
-            jfn = self._build_for_key(key)
-            self._generate_jit[key] = jfn
-            self._ledger_capture(key, jfn=jfn, input_ids=input_ids, rng=rng)
+            self._generate_jit[key] = self._build_for_key(key)
 
-    def _ledger_name(self, key) -> str:
-        """Stable ledger row name for one generate key (same stability
-        contract as the bench metric name). Multi-device programs carry
-        the mesh axes (`@model2` etc.) so `--diff-ledger` compares 1-dev
-        and N-dev runs like-for-like; single-device names are unchanged."""
+    def _program_name(self, key) -> str:
+        """Stable name of one generate key's program, as its `compile`
+        span carries it. Multi-device programs carry the mesh axes
+        (`@model2` etc.), so a 1-device and an N-device run are told
+        apart; single-device names are unchanged."""
         mode = getattr(self, "serve_mode", "dequant")
         prog = mode if mode in ("layer_scan", "capacity") else "generate"
         prog = self._kv_program_suffix(prog, mode)
@@ -323,53 +317,14 @@ class InferenceEngine:
     def _kv_program_suffix(self, prog: str, mode: str) -> str:
         """Append '@kv_int8' when the int8 cache is EFFECTIVE for this
         program (config asks AND the serve mode quantizes its cache) —
-        quantized-cache programs are distinct programs, so the ledger and
-        the RecompileDetector pin them under their own name and
-        --diff-ledger compares like-for-like. Dense/default names are
-        unchanged (same stability contract as the mesh suffix)."""
+        quantized-cache programs are distinct programs, so the compile
+        span and the RecompileDetector carry them under their own name.
+        Dense/default names are unchanged (same stability contract as the
+        mesh suffix)."""
         if mode == "dequant" and \
                 getattr(self._config, "kv_cache_dtype", None) == "int8":
             return f"{prog}@kv_int8"
         return prog
-
-    def _ledger_capture(self, key, compiled=None, jfn=None, input_ids=None,
-                        rng=None):
-        """Program-ledger capture of one generate program at BUILD time
-        (one extra AOT compile when only the traced jit exists; free on
-        the auto-layout path which already AOT-compiled). layer_scan rows
-        additionally verify the quantized-serving byte accounting against
-        the compiled program's memory_analysis()."""
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        led = get_ledger()
-        if not led.enabled:
-            return
-        name = self._ledger_name(key)
-        try:
-            args = (self.params, jnp.asarray(input_ids, jnp.int32), rng)
-            if compiled is None:
-                compiled = jfn.lower(*args).compile()
-            row = led.capture(name, compiled=compiled, args=args)
-            if row and getattr(self, "serve_mode", "dequant") == "layer_scan":
-                led.verify_plan(name,
-                                self._planned_argument_bytes(input_ids, rng),
-                                row["argument_bytes"])
-        except Exception as e:
-            logger.debug(f"ledger: v1 capture of {name} failed: {e}")
-
-    def _planned_argument_bytes(self, input_ids, rng) -> int:
-        """What the serving byte accounting predicts the generate program
-        BINDS as arguments: the per-step weight read (layers + final norm
-        + lm_head, at rest) plus the embedding (its gather's operand still
-        binds) and the ids/rng inputs. Divergence from the compiled
-        argument bytes means weight_bytes_per_step has drifted."""
-        from deepspeed_tpu.inference import quantized_layer_scan as qls
-        total = qls.weight_bytes_per_step(self.params)
-        embed = self.params.get("embed_tokens") \
-            if isinstance(self.params, dict) else None
-        total += int(getattr(embed, "nbytes", 0))
-        total += int(np.asarray(input_ids).nbytes)
-        total += int(getattr(rng, "nbytes", 8))
-        return total
 
     def _build_for_key(self, key, auto_layout: bool = False):
         """Build the generate program for one (b, s, new, sampling) key —
@@ -412,12 +367,6 @@ class InferenceEngine:
                 self._generate_jit[key](self.params, input_ids, rng))
         dt = _time.perf_counter() - t0
         self.last_decode_tok_s = (b * new_tokens / dt) if dt > 0 else None
-        # host-measured wall → the ledger row's measured/boundedness fields
-        # (host-side bookkeeping only; the np.asarray above was the fetch)
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        led = get_ledger()
-        if led.enabled:
-            led.observe_measured(self._ledger_name(key), dt * 1e3)
         hub = get_hub()
         if hub.enabled:
             wb, wb_dense = self._weight_bytes_per_step()
@@ -550,7 +499,6 @@ class InferenceEngine:
             abstract_args(self.params),
             jax.ShapeDtypeStruct(input_ids.shape, input_ids.dtype),
             jax.ShapeDtypeStruct(rng.shape, rng.dtype)).compile()
-        self._last_aot_compiled = compiled  # free ledger capture upstream
         fmts = compiled_input_formats(compiled)[0]
         leaves, treedef = jax.tree_util.tree_flatten(self.params)
         fmt_leaves = jax.tree_util.tree_leaves(fmts[0])

@@ -1,8 +1,8 @@
 """quantized_layer_scan serve mode — ZeRO-Inference int8 decode at scale.
 
 The v1 engine's whole-tree dequant holds int8 + bf16 trees live together
-(OOM at 7B on a 16 GB v5e); the r5 harness
-(`benchmarks/int8_layer_scan_decode.py`) proved the fix: an engine-LEVEL
+(OOM at 7B on a 16 GB v5e); an r5 harness
+(since deleted) proved the fix: an engine-LEVEL
 `lax.scan` whose xs are the per-layer-stacked int8+scales leaves, so the
 dequantized form of ONE layer is the only transient and peak HBM ≈ int8
 tree + KV cache + one layer. This module lifts that structure into the

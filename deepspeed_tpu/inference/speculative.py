@@ -338,7 +338,7 @@ class SpeculativeDecoder:
     """Engine-owned speculative decode dispatcher. Built by the v1 engine
     when `speculative={"enabled": True, ...}`; `engine.generate` routes
     here, so spec decode inherits the engine's program-per-key caching,
-    RecompileDetector pinning, ledger rows (`v1:spec:*`) and serving
+    RecompileDetector pinning and serving
     telemetry (plus the spec fields — docs/telemetry.md).
 
     Config keys: `k` (draft depth, default 4), `draft` ('self' | 'model'),
@@ -373,7 +373,6 @@ class SpeculativeDecoder:
         self._cap_jit = {}
         # generate key -> detector program name (tpuverify registration)
         self._program_names = {}
-        self._draft_ledgered = False
         self._draft_module = None
         self._draft_params = None
         self._draft_idx = None
@@ -698,7 +697,6 @@ class SpeculativeDecoder:
         from deepspeed_tpu.resilience.retry import Deadline
         deadline = Deadline(runner.dispatch_deadline_s,
                             "speculative capacity generate")
-        from deepspeed_tpu.telemetry.ledger import get_ledger
         while np.any(out_len < new):
             deadline.check(f"round {rounds}")
             # host-driven round protocol: acceptance must land on host to
@@ -707,16 +705,6 @@ class SpeculativeDecoder:
             done_before = np.asarray(done)  # tpulint: disable=no-hot-loop-fetch
             keys = jax.random.split(rng, k + 2)
             rng, acc_key, prop_keys = keys[0], keys[1], keys[2:]
-            if not self._draft_ledgered:
-                self._draft_ledgered = True
-                led = get_ledger()
-                if led.enabled:
-                    try:
-                        compiled = progs["propose"].lower(
-                            dstate, pend, pl, c, prop_keys).compile()
-                        led.capture("v1:spec:draft", compiled=compiled)
-                    except Exception as e:
-                        logger.debug(f"ledger: spec draft capture failed: {e}")
             cand, drafts, dprobs, dstate = progs["propose"](
                 dstate, pend, pl, c, prop_keys)
             h, aux = embed_jit(cand, c, max_len)
@@ -759,30 +747,16 @@ class SpeculativeDecoder:
         if eng.serve_mode == "capacity":
             self._cap_programs(key)
         elif key not in self._jit:
-            jfn = self._build_resident(key)
-            self._jit[key] = jfn
-            self._ledger_capture(key, jfn, input_ids, rng)
+            self._jit[key] = self._build_resident(key)
         return self._dispatch(key, input_ids, rng)
 
-    def _ledger_name(self, key) -> str:
+    def _program_name(self, key) -> str:
+        """Stable name of one spec generate key's program (the v1
+        engine's `_program_name`, for the spec family)."""
         name = f"v1:spec:b{key[0]}_s{key[1]}_n{key[2]}"
         from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
         fp = mesh_fingerprint(self.engine.mesh)
         return f"{name}@{fp}" if fp else name
-
-    def _ledger_capture(self, key, jfn, input_ids, rng):
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        led = get_ledger()
-        if not led.enabled:
-            return
-        name = self._ledger_name(key)
-        try:
-            args = (self.engine.params, self._draft_params,
-                    jnp.asarray(input_ids, jnp.int32), rng)
-            compiled = jfn.lower(*args).compile()
-            led.capture(name, compiled=compiled, args=args)
-        except Exception as e:
-            logger.debug(f"ledger: spec capture of {name} failed: {e}")
 
     def _dispatch(self, key, input_ids, rng):
         import time as _time
@@ -811,10 +785,6 @@ class SpeculativeDecoder:
         rounds, drafted, accepted = (int(x) for x in np.asarray(stats))
         eng.last_decode_tok_s = (b * new / dt) if dt > 0 else None
         self.last_acceptance_rate = (accepted / drafted) if drafted else None
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        led = get_ledger()
-        if led.enabled:
-            led.observe_measured(self._ledger_name(key), dt * 1e3)
         hub = get_hub()
         if hub.enabled:
             wb, wb_dense = eng._weight_bytes_per_step()

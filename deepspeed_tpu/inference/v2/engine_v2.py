@@ -176,7 +176,6 @@ class InferenceEngineV2:
         self._chunk_family_warm = False
         self._weight_bytes_cache = None
         self._jits: Dict[Any, Any] = {}
-        self._ledger_captured: set = set()
         # Serving telemetry: every serving program is PINNED — its input
         # signature is supposed to stay constant once compiled, so any
         # signature miss is a silent ~3.5 s recompile and warns loudly.
@@ -283,7 +282,6 @@ class InferenceEngineV2:
         re-prefills its own in-flight work when it retries)."""
         src, self.params = self.params, None
         self._jits = {}
-        self._ledger_captured = set()
         self._weight_bytes_cache = None
         self._capacity = None
         self._apply = None
@@ -655,10 +653,7 @@ class InferenceEngineV2:
         """Wrap a compiled serving program with dispatch-time signature
         tracking: a recompile of a pinned program (the Round-4 unpinned-
         cache-leaf bug class) becomes a loud warning + telemetry event
-        instead of a silent multi-second stall. With a program ledger
-        enabled, the FIRST dispatch also captures the compiled program's
-        cost/memory analysis (one extra AOT compile — compile time only,
-        never the per-round hot path).
+        instead of a silent multi-second stall.
 
         On layout-auto platforms (TPU), the FIRST jitted dispatch also
         pins the param tree's AUTO input layouts (`_pin_param_layouts`)
@@ -667,16 +662,12 @@ class InferenceEngineV2:
         layouts, so no bucket pays the v1 relayout-in-program +3 GB or a
         ~3.5 s signature-miss recompile."""
         name = key if isinstance(key, str) else ":".join(map(str, key))
-        # multi-device rows carry the mesh axes in the name so
-        # --diff-ledger compares 1-dev and N-dev runs like-for-like;
-        # single-device dequant names are unchanged (the stability
-        # contract). Non-default serve modes are DIFFERENT programs —
-        # suffix them (like @kv_int8) so detector pins and ledger rows
-        # stay like-for-like per mode.
+        # The name is what the detector pins and the `compile` span
+        # carries. Single-device dequant names are bare (the stability
+        # contract); a non-default serve mode, a quantized cache and a
+        # mesh each make a DIFFERENT program, so each adds its suffix.
         if self.serve_mode != "dequant":
             name = f"{name}@{self.serve_mode}"
-        # Quantized-cache programs are distinct programs — suffix them so
-        # the detector pins them and the ledger rows stay like-for-like.
         if getattr(self, "kv_cache_dtype", None):
             name = f"{name}@kv_{self.kv_cache_dtype}"
         from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
@@ -697,11 +688,6 @@ class InferenceEngineV2:
                     self._pin_param_layouts(body, rest)
                 args = (self.params,) + rest
             det.observe(name, args)
-            from deepspeed_tpu.telemetry.ledger import get_ledger
-            led = get_ledger()
-            if led.enabled and name not in self._ledger_captured:
-                self._ledger_captured.add(name)
-                led.capture(f"v2:{name}", fn=fn, args=args)
             if first:
                 # the program's compile (or its load from the persistent
                 # cache) is this call: one `compile` span with its name
@@ -711,7 +697,7 @@ class InferenceEngineV2:
             return fn(*args)
         # the raw jit and the detector name, for tools/tpuverify (the
         # wrapper hides .lower(); the verifier lowers the raw program and
-        # cross-checks detector/ledger coverage by name). Eager capacity
+        # cross-checks detector coverage by name). Eager capacity
         # bodies carry no raw jit — the verifier skips them.
         wrapped._ds_raw = fn if raw else None
         wrapped._ds_program = name
@@ -1988,24 +1974,12 @@ class InferenceEngineV2:
                         self._reserve(seq, seq.seen_tokens + k)
                     self._maybe_sync_tables()
                     self._rng, sub = jax.random.split(self._rng)
-                    wave_fn = self._decode_scan_fn(k)
-                    t_wave = time.perf_counter()
-                    self.cache, toks = wave_fn(
+                    self.cache, toks = self._decode_scan_fn(k)(
                         self.params, self.cache, jnp.asarray(tokens),
                         jnp.asarray(active), sub,
                         jnp.asarray(self._slot_uids, jnp.int32))
                     toks_np = np.asarray(toks)  # (K, B)
-                    wave_ms = (time.perf_counter() - t_wave) * 1e3
                     self._count_slots(k * self.max_batch, k * len(live), wf)
-                    from deepspeed_tpu.telemetry.ledger import get_ledger
-                    led = get_ledger()
-                    if led.enabled:
-                        # dispatch→host-materialize time per wave program —
-                        # the v2 counterpart of v1's generate measured_ms
-                        # rows (np.asarray is a REAL fetch, so the timing
-                        # is honest)
-                        led.observe_measured(f"v2:{wave_fn._ds_program}",
-                                             wave_ms)
                     self.serving_counters["decode_waves"] += 1
                     retired = []
                     for uid in list(live):
@@ -2074,7 +2048,7 @@ class InferenceEngineV2:
         layouts on the FIRST jitted dispatch (`_pin_param_layouts` —
         pin-once for the whole family), compiles the bucket's
         prefill/decode programs and registers their names with the
-        RecompileDetector and program ledger. Serving real prompts in
+        RecompileDetector. Serving real prompts in
         these buckets afterwards (same max_new_tokens → same decode-scan
         key) reports ZERO detector misses — the acceptance check
         tests/unit/inference/test_fastgen_v2_modes.py pins. Buckets that

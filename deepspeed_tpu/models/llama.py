@@ -69,8 +69,7 @@ class LlamaConfig:
     # InternLM-style bias on the o projection too (HF internlm `bias`)
     attention_o_bias: bool = False
     # Domino two-chunk batch interleave for TP overlap
-    # (runtime/domino/transformer.py; measured A/B in
-    # benchmarks/domino_ab.py)
+    # (runtime/domino/transformer.py, which tells its measured A/B)
     domino: bool = False
     sliding_window: Optional[int] = None
     # Explicit per-head width (HF configs with decoupled head_dim; also set

@@ -207,7 +207,7 @@ class MoE(nn.Module):
                           self.activation, name="experts")
         impl = self.dispatch_impl
         if impl == "auto":
-            # r5 on-chip A/B (benchmarks/moe_breakdown.py): gmm wins the
+            # r5 on-chip A/B (a probe since deleted): gmm wins the
             # fwd-only layer 1.2x (2.79 vs 3.35 ms), but its bwd kernels
             # (transpose_rhs gmm + tgmm) lose the train step 1.03-1.04x
             # even with the named-save remat policy — so auto picks gmm

@@ -13,8 +13,8 @@ custom-VJP grouped matmul (backward = gmm(grad, rhs^T) + tgmm for the
 weight grad), which tiles group-irregular row spans onto the MXU with
 per-tile store masks. This wrapper owns the policy bits:
 
-- tiling selection (swept on v5e at the qwen2-moe proxy shape, see
-  `benchmarks/moe_breakdown.py`),
+- tiling selection (swept on v5e at the qwen2-moe proxy shape by an r5
+  probe since deleted),
 - padding rows up to an m-tile multiple (padding rows are appended to the
   LAST group; they multiply zeros and their outputs are dropped),
 - interpret-mode fallback so CPU golden tests run the same code path.

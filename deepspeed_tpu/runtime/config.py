@@ -83,15 +83,12 @@ class TelemetryConfig(DeepSpeedConfigModel):
 
     ``flush_every``: steps between host fetches of the deferred metrics
     (1 = one fetch per step, riding the loss transfer; 0 = manual
-    ``hub.flush()`` — what bench.py uses so the timed loop stays async).
-    ``cost_analysis``: snapshot XLA cost_analysis() once per compiled train
-    program (costs one extra trace+compile per program — a debug tool).
+    ``hub.flush()``, which keeps a timed loop async).
     """
     enabled: bool = False
     jsonl_path: str = "telemetry.jsonl"
     prometheus_path: Optional[str] = None
     flush_every: int = 1
-    cost_analysis: bool = False
     trace_dir: Optional[str] = None
 
 

@@ -10,7 +10,7 @@ batch as two interleaved halves creates the independent work the scheduler
 can overlap. This layer applies exactly that transform declaratively; the
 async handle machinery has no analog because nothing blocks.
 
-MEASURED (r5, benchmarks/domino_ab.py, llama tp=2 on the virtual CPU
+MEASURED (r5, an A/B script since deleted, llama tp=2 on the virtual CPU
 mesh; real multi-chip TP is not available on the dev box): the transform
 wins NOTHING under XLA — identical loss, 0.97x wall-clock (the concat
 costs more than the break buys), and the optimized HLO carries the SAME

@@ -250,7 +250,6 @@ class DeepSpeedEngine:
         self.state: Optional[TrainState] = None
         self._shardings = None
         self._jit_cache: Dict[str, Any] = {}
-        self._raw_jits: Dict[str, Any] = {}
         self.training_dataloader = None
         if training_data is not None:
             from deepspeed_tpu.runtime.dataloader import DeepSpeedDataLoader
@@ -1110,46 +1109,8 @@ class DeepSpeedEngine:
         return self._cache_jit(name, fn)
 
     def _cache_jit(self, name: str, fn):
-        from deepspeed_tpu.telemetry.ledger import get_ledger
-        # unwrapped jit, kept for tools/tpuverify (the cost wrapper hides
-        # .lower(); the verifier needs the raw jit to AOT-lower)
-        self._raw_jits[name] = fn
-        want_cost = (self.telemetry.enabled and self.telemetry.cost_analysis
-                     and name != "eval")
-        want_ledger = get_ledger().enabled and name != "eval"
-        if want_cost or want_ledger:
-            fn = self._wrap_cost(name, fn, cost=want_cost,
-                                 ledger=want_ledger)
         self._jit_cache[name] = fn
         return fn
-
-    def _wrap_cost(self, name: str, fn, cost: bool = True,
-                   ledger: bool = False):
-        """First-dispatch compiled-program snapshot of a state jit: a
-        cost_analysis() event into the telemetry hub and/or a program-
-        ledger row (cost + memory_analysis + roofline). Costs ONE extra
-        trace+AOT-compile of the program (jax's AOT and traced-call caches
-        are separate) — gated behind telemetry.cost_analysis / an enabled
-        ledger, debug-and-bench knobs, never the hot default."""
-        tele = self.telemetry
-        snapped = []
-
-        def wrapped(*args):
-            if not snapped:
-                snapped.append(True)
-                try:
-                    compiled = fn.lower(*args).compile()
-                    if cost:
-                        tele.program_cost_event(name, compiled)
-                    if ledger:
-                        from deepspeed_tpu.telemetry.ledger import get_ledger
-                        get_ledger().capture(f"train:{name}",
-                                             compiled=compiled, args=args)
-                except Exception as e:
-                    logger.debug(f"telemetry: cost snapshot of {name} "
-                                 f"failed: {e}")
-            return fn(*args)
-        return wrapped
 
     # ------------------------------------------------------------------
     # user surface
@@ -1422,8 +1383,6 @@ class DeepSpeedEngine:
         self.lr_fn = lambda step: jnp.asarray(lr, jnp.float32)
         self._jit_cache.pop("step", None)
         self._jit_cache.pop("train_batch", None)
-        self._raw_jits.pop("step", None)
-        self._raw_jits.pop("train_batch", None)
 
     @property
     def skipped_steps(self) -> int:

@@ -8,12 +8,10 @@ Here the loop is one compiled program, so observability splits into:
 - ``MetricsState`` (metrics.py): metrics computed INSIDE the compiled step,
   delivered with the loss in one host fetch;
 - ``TelemetryHub`` (hub.py): the host bus merging MetricsState with timers,
-  cost_analysis snapshots, memory stats, comms volume and NVMe counters
-  into JSONL + a Prometheus text file;
+  memory stats, comms volume and NVMe counters into JSONL + a Prometheus
+  text file;
 - ``RecompileDetector`` (recompile.py): dispatch-time fingerprinting that
   turns silent ~3.5 s serving recompiles into warnings;
-- ``ProgramLedger`` (ledger.py): compile-time cost/memory capture per
-  pinned program with roofline attribution and a perf-regression diff CLI;
 - ``RequestTracer``/``Histogram``/``export_chrome_trace`` (spans.py):
   per-request span records for the serving engines — wall-time
   decomposition with an ``unattributed`` residual invariant, streaming
@@ -27,15 +25,12 @@ Here the loop is one compiled program, so observability splits into:
   path registers into — per-tier/per-component byte accounting, watermarks,
   and formula reconciliation (docs/memory.md).
 
-CLI: ``python -m deepspeed_tpu.telemetry --summarize run.jsonl`` and
-``python -m deepspeed_tpu.telemetry --diff-ledger old.jsonl new.jsonl``.
+CLI: ``python -m deepspeed_tpu.telemetry --summarize run.jsonl``.
 """
 
 from deepspeed_tpu.telemetry.hub import TelemetryHub, get_hub, set_hub  # noqa: F401
 from deepspeed_tpu.telemetry.memory import (  # noqa: F401
     MemoryPlane, get_plane, scratch_plane, set_plane)
-from deepspeed_tpu.telemetry.ledger import (  # noqa: F401
-    ProgramLedger, get_ledger, set_ledger)
 from deepspeed_tpu.telemetry.metrics import MetricsState, host_metrics  # noqa: F401
 from deepspeed_tpu.telemetry.recompile import RecompileDetector  # noqa: F401
 from deepspeed_tpu.telemetry.spans import (  # noqa: F401

@@ -4,9 +4,8 @@
     python -m deepspeed_tpu.telemetry --summarize run.jsonl --percentiles
     python -m deepspeed_tpu.telemetry --summarize run.jsonl \
         --export-trace trace.json
-    python -m deepspeed_tpu.telemetry --diff-ledger old.jsonl new.jsonl
 
-``--summarize`` prints a step-time / MFU / memory table from a telemetry
+``--summarize`` prints a step-time / memory table from a telemetry
 JSONL file (schema: docs/telemetry.md). ``--percentiles`` adds the
 streaming SLA histograms (`histogram` events: TTFT/TPOT/e2e p50/p95/p99)
 and a per-serve-mode request table aggregated from `request_span` events.
@@ -15,12 +14,7 @@ the last snapshot's per-component breakdown, reconcile drift rows).
 ``--export-trace OUT`` converts the file's span/request/instant events to
 Chrome trace_event JSON (chrome://tracing or ui.perfetto.dev; one track
 per request slot; `memory_snapshot` events become per-tier counter
-tracks). ``--diff-ledger`` compares two program-ledger files
-(telemetry/ledger.py) and exits NONZERO when any program regressed in
-flops / bytes accessed / compiled HBM peak / measured ms beyond
-``--threshold`` (default 0.2 = 20%) — wire it into a round's bench run so
-perf drift fails loudly. Pure-stdlib parsing for the summarizer — works on
-any box that can read the file.
+tracks). Pure-stdlib parsing — works on any box that can read the file.
 """
 
 from __future__ import annotations
@@ -78,8 +72,7 @@ def summarize(path: str) -> str:
 
     steps = by_kind.get("train_step", [])
     times = sorted(field_vals("step_time_s"))
-    mfus = field_vals("mfu")
-    losses = field_vals("loss", kinds=("train_step", "bench_phase"))
+    losses = field_vals("loss", kinds=("train_step",))
     peaks = field_vals("peak_hbm_gb") + [
         b / (1 << 30) for b in field_vals("peak_bytes_in_use")]
     norms = field_vals("grad_norm", kinds=("train_step",))
@@ -92,8 +85,6 @@ def summarize(path: str) -> str:
     lines.append(f"step time  mean {_fmt(sum(times) / len(times) if times else None, ' s')}"
                  f"   p50 {_fmt(_pct(times, 0.5), ' s')}"
                  f"   p95 {_fmt(_pct(times, 0.95), ' s')}")
-    lines.append(f"MFU        mean {_fmt(sum(mfus) / len(mfus) if mfus else None)}"
-                 f"   max {_fmt(max(mfus) if mfus else None)}")
     lines.append(f"peak HBM   {_fmt(max(peaks) if peaks else None, ' GB', 5)}")
     if norms:
         lines.append(f"grad norm  last {_fmt(norms[-1])}"
@@ -235,28 +226,15 @@ def memory_report(path: str) -> str:
                 f" {e.get('predicted_bytes', 0):>12}"
                 f" {_fmt(e.get('drift'), '', 3):>8}"
                 f" {'yes' if e.get('ok') else 'NO'}")
-    leaks = [e for e in events if e.get("kind") == "residency_leak"]
-    for e in leaks:
-        lines.append(f"LEAK: phase {e.get('phase', '-')} ended with "
-                     f"{e.get('leak_bytes', 0)} more registered hbm bytes "
-                     "than it started with")
     return "\n".join(lines)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m deepspeed_tpu.telemetry",
-        description="Summarize a telemetry JSONL file or diff two "
-                    "program-ledger files")
-    ap.add_argument("--summarize", metavar="JSONL",
+        description="Summarize a telemetry JSONL file")
+    ap.add_argument("--summarize", metavar="JSONL", required=True,
                     help="path to a telemetry JSONL file")
-    ap.add_argument("--diff-ledger", nargs=2, metavar=("OLD", "NEW"),
-                    help="two program-ledger JSONL files to compare; exits "
-                         "nonzero on any per-program regression beyond "
-                         "--threshold")
-    ap.add_argument("--threshold", type=float, default=0.2,
-                    help="relative regression threshold for --diff-ledger "
-                         "(default 0.2)")
     ap.add_argument("--percentiles", action="store_true",
                     help="with --summarize: print the SLA histogram section "
                          "and the per-serve-mode request table")
@@ -268,16 +246,6 @@ def main(argv=None) -> int:
                     help="with --summarize: write the file's span/request/"
                          "instant events as Chrome trace_event JSON to OUT")
     args = ap.parse_args(argv)
-    if args.diff_ledger:
-        from deepspeed_tpu.telemetry.ledger import (diff_ledgers, format_diff,
-                                                    load_rows)
-        old_path, new_path = args.diff_ledger
-        diff = diff_ledgers(load_rows(old_path), load_rows(new_path),
-                            threshold=args.threshold)
-        print(format_diff(diff, old_path, new_path))
-        return 1 if diff["regressions"] else 0
-    if not args.summarize:
-        ap.error("one of --summarize or --diff-ledger is required")
     print(summarize(args.summarize))
     if args.percentiles:
         print(percentiles(args.summarize))

@@ -1,8 +1,8 @@
 """TelemetryHub — the host-side telemetry bus.
 
 One sink for everything the stack can observe: the in-step ``MetricsState``
-(fetched WITH the loss — one transfer per flush), host timers, compiled-
-program ``cost_analysis()`` snapshots, accelerator ``memory_stats()``,
+(fetched WITH the loss — one transfer per flush), host timers,
+accelerator ``memory_stats()``,
 ``CommsLogger`` trace-time volume, NVMe aio counters and serving/recompile
 events. Emits structured JSONL (schema: docs/telemetry.md) plus a
 Prometheus-style text exposition file.
@@ -10,8 +10,8 @@ Prometheus-style text exposition file.
 Design constraints this encodes:
 - a device fetch syncs the host with the device → device values are
   DEFERRED and fetched in one batched ``jax.device_get`` at flush time
-  (``flush_every`` steps, or manually with ``flush_every: 0`` — what
-  bench.py uses so the timed loop stays fully async);
+  (``flush_every`` steps, or manually with ``flush_every: 0``, which
+  keeps a timed loop fully async);
 - step time is stamped dispatch-to-dispatch (host clock between successive
   step events), not via block_until_ready, which would serialize host and
   device every step.
@@ -25,7 +25,6 @@ import time
 from typing import Any, Dict, List, Optional
 
 from deepspeed_tpu.telemetry.spans import Histogram
-from deepspeed_tpu.utils.logging import logger
 
 # Module-level emit listeners (not per-hub: `set_hub` swaps instances but
 # subscribers — the RequestTracer's instant mirror — must keep seeing the
@@ -59,7 +58,6 @@ class TelemetryHub:
                  jsonl_path: Optional[str] = None,
                  prometheus_path: Optional[str] = None,
                  flush_every: int = 1,
-                 cost_analysis: bool = False,
                  trace_dir: Optional[str] = None,
                  rank0_only: bool = True):
         if enabled and rank0_only:
@@ -72,12 +70,10 @@ class TelemetryHub:
         self.jsonl_path = jsonl_path or "telemetry.jsonl"
         self.prometheus_path = prometheus_path
         self.flush_every = int(flush_every)
-        self.cost_analysis = bool(cost_analysis)
         self.trace_dir = trace_dir
         self._file = None
         self._deferred: List[Dict[str, Any]] = []
         self._last_step_ts: Optional[float] = None
-        self._cost_snapped: set = set()
         # counters/gauges/histograms update even when disabled (they're
         # cheap and the recompile detector's tests read them without a file)
         self.counters: Dict[str, float] = {}
@@ -95,7 +91,6 @@ class TelemetryHub:
         hub = cls(enabled=tcfg.enabled, jsonl_path=tcfg.jsonl_path,
                   prometheus_path=tcfg.prometheus_path,
                   flush_every=tcfg.flush_every,
-                  cost_analysis=tcfg.cost_analysis,
                   trace_dir=tcfg.trace_dir)
         if hub.enabled:
             set_hub(hub)
@@ -221,25 +216,6 @@ class TelemetryHub:
         if fields:
             self.emit("memory", **fields)
         return fields
-
-    def program_cost_event(self, name: str, compiled) -> None:
-        """cost_analysis() snapshot of one compiled program (flops, bytes
-        accessed, output bytes) — emitted once per program name."""
-        if not self.enabled or name in self._cost_snapped:
-            return
-        self._cost_snapped.add(name)
-        try:
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            ca = dict(ca or {})
-        except Exception as e:
-            logger.debug(f"telemetry: cost_analysis({name}) failed: {e}")
-            return
-        self.emit("program_cost", program=name,
-                  flops=float(ca.get("flops", 0.0)),
-                  bytes_accessed=float(ca.get("bytes accessed", 0.0)),
-                  utilization_keys=len(ca))
 
     def comms_event(self) -> None:
         """Trace-time collective volume from the CommsLogger (one event per

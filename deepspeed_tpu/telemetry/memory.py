@@ -3,8 +3,8 @@
 Every placement path registers its at-rest bytes here — named allocations
 ``{component, tier, bytes, owner}`` — so "where is every byte right now"
 has a runtime answer instead of a hand-derived one (the r6 int8
-7.63-vs-7.10 GB mismatch and the bench phase-order leak were both found
-by hand; this plane makes both mechanical).
+7.63-vs-7.10 GB mismatch and an r5 phase-order leak were both found
+by hand).
 
 Design rules (load-bearing, mirrored in docs/memory.md):
 
@@ -58,8 +58,7 @@ def owner_for(obj: Any, prefix: str) -> str:
     """Deterministic-per-process owner tag for ``obj`` (assigned once,
     stored on the object as ``_memory_owner``). A weakref finalizer drops
     the owner's allocations when the object is collected, so registered
-    bytes track LIVE placements — bench's cross-phase leak check relies
-    on torn-down engines releasing their rows."""
+    bytes track LIVE placements: a torn-down engine releases its rows."""
     tag = getattr(obj, "_memory_owner", None)
     if tag is None:
         tag = f"{prefix}:{next(_OWNER_COUNTER)}"

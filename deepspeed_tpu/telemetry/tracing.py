@@ -1,8 +1,8 @@
 """Trace capture hooks, and where set-up time goes.
 
 ``trace_capture`` wraps ``jax.profiler.start_trace``/``stop_trace`` so a
-perfetto trace of any step range is one context manager (bench.py exposes
-it as the ``DS_TPU_TRACE=<dir>`` flag). ``annotate`` is the named-phase
+perfetto trace of any step range is one context manager (``engine.trace``
+is its user-facing form). ``annotate`` is the named-phase
 marker (``jax.profiler.TraceAnnotation``) the engines place around
 fwd/bwd/step/fetch dispatches — annotations cost nothing when no trace is
 being captured, so the hot paths keep them unconditionally.

@@ -9,8 +9,7 @@ the paper's ZeRO schedule is defined by (docs/static_analysis.md,
 compiled layer).
 
 Import surface mirrors the siblings: the heavy builders live in
-``put.py`` and import jax lazily; ``hlo.py`` is stdlib-only so the
-program ledger can lazy-import it at capture time.
+``put.py`` and import jax lazily; ``hlo.py`` is stdlib-only.
 """
 
 from deepspeed_tpu.tools.tpucomms.core import (  # noqa: F401

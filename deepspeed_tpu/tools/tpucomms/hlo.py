@@ -1,11 +1,9 @@
 """Post-SPMD HLO text parsing: collective extraction + replica-group
 decoding back to canonical mesh axes.
 
-stdlib-only (``re``, no jax/numpy import) on purpose: the program ledger
-lazy-imports :func:`comm_summary` inside ``ProgramLedger.capture`` — at
-first dispatch of every pinned program — and must never pull a second
-copy of jax machinery into that path. Everything jax-flavored (jaxpr
-fallback, topology access) lives in ``fingerprint.py``.
+stdlib-only (``re``, no jax/numpy import): the parser reads text, and
+its tests run on any box. Everything jax-flavored (jaxpr fallback,
+topology access) lives in ``fingerprint.py``.
 
 What the parser understands (jax 0.4.37 → current ``compiled.as_text()``):
 
@@ -84,7 +82,7 @@ class CollectiveOp:
 
     @property
     def wire_bytes(self) -> int:
-        """Per-device wire bytes under the ledger's fixed conventions
+        """Per-device wire bytes under fixed conventions
         (chosen so the ideal ZeRO-3 schedule sums to exactly 3×P):
         all-gather = gathered output bytes; reduce-scatter = full input
         bytes (output × group); all-reduce = 2× operand bytes (its
@@ -282,33 +280,3 @@ def op_axes(op: CollectiveOp, sizes_map: Dict[str, int]
     if op.kind == "collective-permute":
         return pairs_to_axes(op.source_target_pairs, sizes_map)
     return groups_to_axes(op.replica_groups, sizes_map)
-
-
-# ------------------------------------------------------------ ledger summary
-
-
-def comm_summary(hlo_text: str,
-                 sizes_map: Optional[Dict[str, int]] = None
-                 ) -> Dict[str, object]:
-    """The append-only ledger-row fields: ``comm_ops`` (static collective
-    instruction count), ``comm_bytes`` (summed wire bytes, each
-    instruction counted ONCE — no loop multiplier; the ledger row is a
-    static compile-time artifact) and ``comm_bytes_by_axis`` (keys are
-    '+'-joined canonical axes, or ``g<group_size>`` buckets when no mesh
-    topology is available to decode against)."""
-    ops = parse_collectives(hlo_text)
-    by_axis: Dict[str, int] = {}
-    total = 0
-    for op in ops:
-        if sizes_map:
-            axes, regular = op_axes(op, sizes_map)
-            key = "+".join(axes) if axes else "none"
-            if not regular:
-                key = "irregular"
-        else:
-            key = f"g{op.group_size}"
-        wb = op.wire_bytes
-        total += wb
-        by_axis[key] = by_axis.get(key, 0) + wb
-    return {"comm_ops": len(ops), "comm_bytes": total,
-            "comm_bytes_by_axis": dict(sorted(by_axis.items()))}

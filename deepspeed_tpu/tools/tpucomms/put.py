@@ -13,7 +13,7 @@ here at all: the default matrix has no pp>1 engine, and any
 routed to the jaxpr path without touching backend_compile.
 
 ``build_comms_matrix`` reuses tpuverify's engine builders (same smoke
-dispatches, same scratch ledger) so the two tools stay in lockstep about
+dispatches) so the two tools stay in lockstep about
 what "the engine matrix" means; only the train component is rebuilt
 bigger here — comm-volume analysis needs token-heavy shapes (a tiny
 model's params fall under ``param_persistence_threshold`` and GSPMD
@@ -185,7 +185,7 @@ def build_train_comms(gas: int = 2, mbs: int = 16,
     p_bytes = _tree_bytes(engine.state.params)
     budget = analytic_step_bytes(3, p_bytes, gas)
     puts: List[CommsProgram] = []
-    for name, fn in engine._raw_jits.items():
+    for name, fn in engine._jit_cache.items():
         if name == "eval":
             continue
         args = engine.recompiles.abstract.get(name)
@@ -231,27 +231,25 @@ def build_comms_matrix(include: Sequence[str] = ("train", "v1", "v2",
     """The default matrix: the volume-sized train engine plus the same
     v1/v2 serving engines tpuverify smokes (dequant generate, v2 paged
     serving, v2 int8 layer_scan), all on the virtual CPU mesh."""
-    from deepspeed_tpu.tools.tpuverify.put import (_scratch_ledger,
-                                                   build_v1_puts,
+    from deepspeed_tpu.tools.tpuverify.put import (build_v1_puts,
                                                    build_v2_puts)
     serving = {
-        "v1": lambda led: build_v1_puts(led),
-        "v2": lambda led: build_v2_puts(led),
-        "v2_layer_scan": lambda led: build_v2_puts(
-            led, serve_mode="layer_scan", quant={"enabled": True}),
+        "v1": build_v1_puts,
+        "v2": build_v2_puts,
+        "v2_layer_scan": lambda: build_v2_puts(
+            serve_mode="layer_scan", quant={"enabled": True}),
     }
     unknown = [k for k in include if k != "train" and k not in serving]
     if unknown:
         raise KeyError(f"unknown matrix component(s): {unknown} "
                        f"(known: {['train'] + sorted(serving)})")
     puts: List[CommsProgram] = []
-    with _scratch_ledger() as led:
-        for k in include:
-            if k == "train":
-                puts.extend(build_train_comms())
-            else:
-                puts.extend(_convert_verify_puts(serving[k](led),
-                                                 SERVING_DECLARED))
+    for k in include:
+        if k == "train":
+            puts.extend(build_train_comms())
+        else:
+            puts.extend(_convert_verify_puts(serving[k](),
+                                             SERVING_DECLARED))
     return puts
 
 
@@ -266,7 +264,7 @@ def audit_train_engine(engine, declared_axes: FrozenSet[str] = TRAIN_DECLARED
     back to jaxpr extraction inside fingerprint()."""
     sizes = dict(engine.topology.sizes)
     problems: List[str] = []
-    for name, fn in getattr(engine, "_raw_jits", {}).items():
+    for name, fn in engine._jit_cache.items():
         if name == "eval":
             continue
         args = engine.recompiles.abstract.get(name)

@@ -24,7 +24,7 @@ from deepspeed_tpu.tools.tpulint.core import (
     save_baseline,
 )
 
-DEFAULT_PATHS = ("deepspeed_tpu", "benchmarks", "tests", "bench.py")
+DEFAULT_PATHS = ("deepspeed_tpu", "benchmarks", "tests")
 
 
 def _list_rules() -> str:

@@ -415,8 +415,7 @@ class TelemetrySchemaSync(Rule):
     def applies(self, path: str) -> bool:
         if _in_tools(path) or path.startswith("tests/"):
             return False
-        return path.startswith(("deepspeed_tpu/", "benchmarks/")) or \
-            path == "bench.py"
+        return path.startswith(("deepspeed_tpu/", "benchmarks/"))
 
     def begin_run(self, root: str) -> None:
         if self._loaded_root == root:
@@ -545,8 +544,7 @@ class TelemetryKindDeclared(Rule):
     def applies(self, path: str) -> bool:
         if _in_tools(path) or path.startswith("tests/"):
             return False
-        return path.startswith(("deepspeed_tpu/", "benchmarks/")) or \
-            path == "bench.py"
+        return path.startswith(("deepspeed_tpu/", "benchmarks/"))
 
     def begin_run(self, root: str) -> None:
         if self._loaded_root == root:

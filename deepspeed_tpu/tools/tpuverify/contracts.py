@@ -278,12 +278,12 @@ class ManualRegionAllowlist(Contract):
 class RegistrationCoverage(Contract):
     id = "registration-coverage"
     doc = ("After a smoke dispatch, every compiled program in the engine "
-           "caches is pinned in the RecompileDetector and has a "
-           "program-ledger row — no untracked programs.")
-    incident = ("r5: the paged decode kernel regressed 0.46 → 0.91 ms and "
-                "nobody noticed for a round because nothing durable "
-                "recorded per-program cost; untracked programs are "
-                "exactly the rows the ledger diff can never compare.")
+           "caches has a RecompileDetector identity and was observed "
+           "under it at dispatch — no untracked programs.")
+    incident = ("r4: the unpinned-cache-leaf recompiles (~3.5 s each) were "
+                "caught only where the detector watched; a program it "
+                "never saw recompiles with no warning, no `recompile` "
+                "event and no name on its `compile` span.")
 
     def applies(self, put) -> bool:
         return put.kind == "engine"
@@ -303,13 +303,6 @@ class RegistrationCoverage(Contract):
                     self.id, put.name,
                     f"{rec.label}: program {rec.detector_name!r} was "
                     "never observed by the RecompileDetector at dispatch")
-            if rec.ledger_row is not None \
-                    and rec.ledger_row not in put.ledger_programs:
-                yield Violation(
-                    self.id, put.name,
-                    f"{rec.label}: no program-ledger row "
-                    f"{rec.ledger_row!r} — --diff-ledger cannot track "
-                    "this program across rounds")
 
 
 @register

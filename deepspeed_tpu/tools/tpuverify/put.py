@@ -9,22 +9,19 @@ Two kinds:
   both chip-free static analyses.
 - ``EngineUnderTest`` (kind="engine"): one live engine's bookkeeping — the
   pinned param/cache trees, the RecompileDetector, and the
-  (compiled program → detector name → ledger row) records the
-  registration-coverage contract cross-checks.
+  (compiled program → detector name) records the registration-coverage
+  contract cross-checks.
 
 ``build_default_matrix`` constructs the tiny-model matrix (train engine,
 v1 generate, v2 serving) on the virtual CPU mesh, smoke-dispatches each
-engine once with signature recording and a scratch program ledger enabled,
-then harvests every compiled program out of the engine caches. Serve-mode
-variants (layer_scan / capacity / speculative) ride the same builders from
-the slow tests — the default matrix stays within the tier-1 budget.
+engine once with signature recording enabled, then harvests every
+compiled program out of the engine caches. Serve-mode variants
+(layer_scan / capacity / speculative) ride the same builders from the
+slow tests — the default matrix stays within the tier-1 budget.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
-import tempfile
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -67,12 +64,11 @@ class ProgramUnderTest:
 
 @dataclass(frozen=True)
 class CompiledRecord:
-    """One compiled program's registration triple: how the engine labels
-    it, what the RecompileDetector knows it as (None = untracked — itself
-    a violation), and its expected program-ledger row (None = exempt)."""
+    """One compiled program's registration pair: how the engine labels
+    it and what the RecompileDetector knows it as (None = untracked —
+    itself a violation)."""
     label: str
     detector_name: Optional[str]
-    ledger_row: Optional[str]
 
 
 @dataclass
@@ -81,7 +77,6 @@ class EngineUnderTest:
     detector: Any                                  # RecompileDetector
     records: List[CompiledRecord]
     pinned_trees: List[Tuple[str, Any]]            # (label, pytree)
-    ledger_programs: frozenset                     # rows captured in smoke
     check_signatures: bool = True
     bulk_bytes: int = 4096   # leaves at/above this entering a pinned
     #                          program must be committed (params/caches;
@@ -93,24 +88,6 @@ class EngineUnderTest:
 
 
 # ----------------------------------------------------------------- builders
-
-
-@contextlib.contextmanager
-def _scratch_ledger():
-    """Process-global ProgramLedger swapped to an enabled scratch one for
-    the smoke dispatches (registration coverage needs rows), restored
-    after."""
-    from deepspeed_tpu.telemetry import ledger as ledger_mod
-    prev = ledger_mod.get_ledger()
-    with tempfile.TemporaryDirectory(prefix="tpuverify_") as td:
-        led = ledger_mod.ProgramLedger(path=os.path.join(td, "ledger.jsonl"),
-                                       enabled=True)
-        ledger_mod.set_ledger(led)
-        try:
-            yield led
-        finally:
-            led.close()
-            ledger_mod.set_ledger(prev)
 
 
 def _reset_topology():
@@ -148,11 +125,11 @@ def _tiny_mlp():
     return model, params
 
 
-def build_train_puts(led) -> List[Any]:
+def build_train_puts() -> List[Any]:
     """ZeRO-3 train engine on the CPU mesh: one fused train_batch program.
     Contract surface: the TrainState (argnum 0) must be donated, no host
     callbacks, no rogue shard_map, and the program must be pinned in the
-    detector with a ledger row."""
+    detector."""
     import numpy as np
 
     import deepspeed_tpu
@@ -177,12 +154,11 @@ def build_train_puts(led) -> List[Any]:
     puts: List[Any] = []
     records = []
     donate = None if engine._offload_manual else (0,)
-    for name, fn in engine._raw_jits.items():
+    for name, fn in engine._jit_cache.items():
         if name == "eval":
             continue
         records.append(CompiledRecord(label=f"train:{name}",
-                                      detector_name=name,
-                                      ledger_row=f"train:{name}"))
+                                      detector_name=name))
         args = engine.recompiles.abstract.get(name)
         if args is None:
             continue  # built but never dispatched — registration flags it
@@ -190,7 +166,7 @@ def build_train_puts(led) -> List[Any]:
                                      donate=donate))
     puts.append(EngineUnderTest(
         name="train", detector=engine.recompiles, records=records,
-        pinned_trees=[], ledger_programs=frozenset(led.programs()),
+        pinned_trees=[],
         check_signatures=False,  # train batches are per-step host arrays
         residency=_engine_residency(engine)))
     return puts
@@ -218,7 +194,7 @@ def _v1_cache_shapes(eng, key) -> frozenset:
     return scatter_target_shapes(shape_tree)
 
 
-def build_v1_puts(led, serve_mode: Optional[str] = None,
+def build_v1_puts(serve_mode: Optional[str] = None,
                   quant: Optional[dict] = None,
                   speculative: Optional[dict] = None) -> List[Any]:
     """v1 inference engine (llama-tiny) smoke-dispatched through generate.
@@ -254,11 +230,9 @@ def build_v1_puts(led, serve_mode: Optional[str] = None,
     names = spec._program_names if spec is not None else eng._program_names
     for key, fn in jits.items():
         det_name = names.get(key)
-        ledger_row = (spec._ledger_name(key) if spec is not None
-                      else eng._ledger_name(key))
+        name = (spec or eng)._program_name(key)
         records.append(CompiledRecord(label=f"{label}:{key}",
-                                      detector_name=det_name,
-                                      ledger_row=ledger_row))
+                                      detector_name=det_name))
         if det_name is None or not hasattr(fn, "lower"):
             continue  # untracked (registration flags it) / auto-layout
         if spec is not None:
@@ -272,24 +246,23 @@ def build_v1_puts(led, serve_mode: Optional[str] = None,
             ids_sds = jax.ShapeDtypeStruct((key[0], key[1]), jnp.int32)
             args = abstract_args((eng.params, spec._draft_params, ids_sds,
                                   jax.random.PRNGKey(0)))
-            puts.append(ProgramUnderTest(name=ledger_row, fn=fn, args=args,
+            puts.append(ProgramUnderTest(name=name, fn=fn, args=args,
                                          donate=None))
             continue
         args = eng.recompiles.abstract.get(det_name)
         if args is None:
             continue
         puts.append(ProgramUnderTest(
-            name=ledger_row, fn=fn, args=args, donate=None,
+            name=name, fn=fn, args=args, donate=None,
             cache_shapes=_v1_cache_shapes(eng, key)))
     puts.append(EngineUnderTest(
         name=label, detector=eng.recompiles, records=records,
         pinned_trees=[(f"{label}.params", eng.params)],
-        ledger_programs=frozenset(led.programs()),
         residency=_engine_residency(eng)))
     return puts
 
 
-def build_v2_puts(led, serve_mode: Optional[str] = None,
+def build_v2_puts(serve_mode: Optional[str] = None,
                   quant: Optional[dict] = None) -> List[Any]:
     """v2 serving engine (llama-tiny): prefill + decode smoke, then every
     compiled program out of ``_jits``. Contract surface: cache (argnum 1)
@@ -343,9 +316,8 @@ def build_v2_puts(led, serve_mode: Optional[str] = None,
             continue
         raw = getattr(fn, "_ds_raw", None)
         det_name = getattr(fn, "_ds_program", None)
-        records.append(CompiledRecord(
-            label=f"{label}:{key}", detector_name=det_name,
-            ledger_row=f"v2:{det_name}" if det_name else None))
+        records.append(CompiledRecord(label=f"{label}:{key}",
+                                      detector_name=det_name))
         if raw is None or det_name is None:
             continue
         args = v2.recompiles.abstract.get(det_name)
@@ -359,7 +331,6 @@ def build_v2_puts(led, serve_mode: Optional[str] = None,
         name=label, detector=v2.recompiles, records=records,
         pinned_trees=[(f"{label}.params", v2.params),
                       (f"{label}.cache", v2.cache)],
-        ledger_programs=frozenset(led.programs()),
         residency=_engine_residency(v2)))
     return puts
 
@@ -369,20 +340,18 @@ def build_default_matrix(include: Sequence[str] = ("train", "v1", "v2",
                          ) -> List[Any]:
     """The tier-1 matrix: train + v1 dequant generate + v2 serving (dequant
     AND int8 layer_scan — the big-model mode's scan-body programs get the
-    same static checks), all on the virtual CPU mesh with a scratch
-    ledger. ~4 tiny-model compiles."""
+    same static checks), all on the virtual CPU mesh. ~4 tiny-model
+    compiles."""
     builders = {"train": build_train_puts,
                 "v1": build_v1_puts,
                 "v2": build_v2_puts,
-                "v2_layer_scan": lambda led: build_v2_puts(
-                    led, serve_mode="layer_scan",
-                    quant={"enabled": True})}
+                "v2_layer_scan": lambda: build_v2_puts(
+                    serve_mode="layer_scan", quant={"enabled": True})}
     unknown = [k for k in include if k not in builders]
     if unknown:
         raise KeyError(f"unknown matrix component(s): {unknown} "
                        f"(known: {sorted(builders)})")
     puts: List[Any] = []
-    with _scratch_ledger() as led:
-        for k in include:
-            puts.extend(builders[k](led))
+    for k in include:
+        puts.extend(builders[k]())
     return puts

@@ -237,8 +237,8 @@ def test_v2_prompt_longer_than_max_seq_fails_loudly(tiny):
 
 def test_generate_records_service_timing(tiny):
     """generate() must leave per-query SLA timestamps (admit <= first <=
-    done, new_tokens = produced count) — bench.py's effective-throughput
-    row consumes them (reference fastgen README:163 accounting)."""
+    done, new_tokens = produced count) — what an effective-throughput
+    row is computed from (reference fastgen README:163 accounting)."""
     cfg, model, params = tiny
     groups.reset_topology()
     v2 = InferenceEngineV2(model, params=params, max_batch=2, max_seq_len=64)

@@ -205,25 +205,6 @@ def test_streamed_program_names_carry_mode_suffix(tiny):
         sorted(deq.recompiles._seen)
 
 
-@pytest.mark.slow
-def test_decode_wave_feeds_ledger_measured_rows(tiny):
-    from deepspeed_tpu.telemetry.ledger import (ProgramLedger, get_ledger,
-                                                set_ledger)
-    model, params = tiny
-    prev = get_ledger()
-    set_ledger(ProgramLedger(path=None, enabled=True))
-    try:
-        eng = _v2(model, params)
-        eng.generate([PROMPTS[0]], max_new_tokens=6)
-        led = get_ledger()
-        rows = [p for p in led._rows if p.startswith("v2:decode_scan")]
-        assert rows, sorted(led._rows)
-        assert all(led._rows[p].get("measured_ms") is not None
-                   for p in rows)
-    finally:
-        set_ledger(prev)
-
-
 # -------------------------------------------------------------- degradation
 
 @pytest.mark.slow

@@ -1,7 +1,7 @@
 """Multi-device serving (r7): layer_scan on a pure-TP mesh rides the
 shard_map int8 kernel wrappers instead of falling back to dequant; the
 auto decision table aggregates HBM over the mesh; unsupported meshes fall
-back LOUDLY; ledger/recompile program names carry the mesh fingerprint
+back LOUDLY; span/recompile program names carry the mesh fingerprint
 (single-device names unchanged — stability contract)."""
 
 import jax
@@ -197,4 +197,4 @@ def test_tp2_program_names_carry_mesh_fingerprint():
     assert any(p.startswith("layer_scan@model2:")
                for p in eng.recompiles._seen)
     assert eng.recompiles.misses == 0
-    assert eng._ledger_name((2, 6, 3, None)).endswith("@model2")
+    assert eng._program_name((2, 6, 3, None)).endswith("@model2")

@@ -50,7 +50,7 @@ def test_nontrivial_axes_and_fingerprint():
     groups.reset_topology()
     topo = groups.initialize(MeshTopology(devices=jax.devices()[:1]))
     assert nontrivial_axes(topo.mesh) == {}
-    # single-device fingerprint is EMPTY — existing ledger names must not move
+    # single-device fingerprint is EMPTY — existing program names must not move
     assert mesh_fingerprint(topo.mesh) == ""
 
 

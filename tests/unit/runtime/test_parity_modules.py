@@ -65,7 +65,7 @@ def test_llama_domino_flag_exact():
     """LlamaConfig(domino=True) wires the two-chunk interleave into the
     block (VERDICT r4 #7) and must be numerically EXACT vs the plain
     block — batch rows are independent through the layer. (Measured A/B,
-    benchmarks/domino_ab.py @ tp2 CPU mesh: 0.97x — no win; XLA merges
+    r5 @ tp2 CPU mesh: 0.97x — no win; XLA merges
     the per-chunk all-reduces back into 3 ops either way.)"""
     from deepspeed_tpu.models.llama import llama_config, materialize_params
     from deepspeed_tpu.utils import groups

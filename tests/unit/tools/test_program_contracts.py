@@ -73,14 +73,12 @@ def test_donation_skips_non_lowerable():
 # --------------------------------------------------------- pinned-sharding
 
 
-def _engine(pinned_trees, records=(), ledger_programs=frozenset(),
-            detector=None, **kw):
+def _engine(pinned_trees, records=(), detector=None, **kw):
     from deepspeed_tpu.telemetry.recompile import RecompileDetector
     return EngineUnderTest(name="fixture-engine",
                            detector=detector or RecompileDetector(),
                            records=list(records),
-                           pinned_trees=list(pinned_trees),
-                           ledger_programs=ledger_programs, **kw)
+                           pinned_trees=list(pinned_trees), **kw)
 
 
 def test_pinned_sharding_violating():
@@ -354,19 +352,16 @@ def test_registration_violations():
     eng = _engine(
         [],
         records=[
-            CompiledRecord("ok", "v1:generate:b2", "v1:generate:b2"),
-            CompiledRecord("untracked", None, None),
-            CompiledRecord("unobserved", "v1:generate:b4", None),
-            CompiledRecord("no-row", "v1:generate:b2", "v1:missing-row"),
+            CompiledRecord("ok", "v1:generate:b2"),
+            CompiledRecord("untracked", None),
+            CompiledRecord("unobserved", "v1:generate:b4"),
         ],
-        ledger_programs=frozenset({"v1:generate:b2"}),
         detector=det)
     out = verify([eng], contracts=["registration-coverage"])
     msgs = "\n".join(v.message for v in out)
-    assert len(out) == 3
+    assert len(out) == 2
     assert "no RecompileDetector identity" in msgs
     assert "never observed" in msgs
-    assert "no program-ledger row" in msgs
 
 
 def test_registration_clean():
@@ -375,9 +370,7 @@ def test_registration_clean():
     det.observe("train:train_batch", (jnp.zeros((4,)),))
     eng = _engine(
         [],
-        records=[CompiledRecord("train:train_batch", "train:train_batch",
-                                "train:train_batch")],
-        ledger_programs=frozenset({"train:train_batch"}),
+        records=[CompiledRecord("train:train_batch", "train:train_batch")],
         detector=det)
     assert verify([eng], contracts=["registration-coverage"]) == []
 
@@ -396,7 +389,7 @@ def test_residency_coverage_clean_and_train_exempt_from_kv():
     eng = _engine([], residency={"params": 4096, "kv_cache": 512})
     assert verify([eng], contracts=["residency-coverage"]) == []
     train = EngineUnderTest(name="train", detector=None, records=[],
-                            pinned_trees=[], ledger_programs=frozenset(),
+                            pinned_trees=[],
                             residency={"params": 4096, "kv_cache": 0})
     assert verify([train], contracts=["residency-coverage"]) == []
 

@@ -107,21 +107,6 @@ ENTRY %main (p0: f32[8,16]) -> f32[16,16] {
     assert fp.bytes_by_axis[("data",)] == ar.wire_bytes + rs.wire_bytes
 
 
-def test_comm_summary_fields():
-    txt = """
-ENTRY %main (p0: f32[8,16]) -> f32[16,16] {
-  ROOT %ag = f32[16,16]{1,0} all-gather(f32[8,16]{1,0} %p0), replica_groups={{0,1},{2,3},{4,5},{6,7}}, dimensions={0}
-}
-"""
-    out = hlo.comm_summary(txt, SIZES)
-    assert out["comm_ops"] == 1
-    assert out["comm_bytes"] == 16 * 16 * 4
-    assert out["comm_bytes_by_axis"] == {"model": 16 * 16 * 4}
-    # without sizes the axis keys fall back to group-size buckets
-    assert hlo.comm_summary(txt, None)["comm_bytes_by_axis"] == \
-        {"g2": 16 * 16 * 4}
-
-
 # --------------------------------------------- decoding on the real mesh
 
 
@@ -284,7 +269,7 @@ def test_zero12_train_fingerprint_within_budget(stage):
              "y": rng.standard_normal((rows, 64)).astype(np.float32)}
     engine.train_batch(batch=batch)
     p_bytes = _tree_bytes(engine.state.params)
-    fn = engine._raw_jits["train_batch"]
+    fn = engine._jit_cache["train_batch"]
     args = engine.recompiles.abstract["train_batch"]
     put = CommsProgram(
         name=f"train:z{stage}", fn=fn, args=args,
