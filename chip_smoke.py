@@ -66,6 +66,8 @@ class Sizes:
     paged_batch: int
     paged_blocks: int
     prefill_batch: int
+    # a mostly-empty serving batch: rows, table blocks a row, chunk tokens
+    parked: Tuple[int, int, int]
     gmm_rows: int
     gmm_experts: int
     gmm_width: int
@@ -77,14 +79,15 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              prompts_per_len=4, new_tokens=32, v2_slots=4, v2_max_seq=1024,
              v2_chunk=256, block=256, flash_long=32768, decode_batch=32,
              decode_ctx=1024, paged_batch=64, paged_blocks=96,
-             prefill_batch=8, gmm_rows=4096, gmm_experts=64, gmm_width=1024,
-             qmm_group=256)
+             prefill_batch=8, parked=(48, 18, 16), gmm_rows=4096,
+             gmm_experts=64, gmm_width=1024, qmm_group=256)
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
              v2_chunk=16, block=16, flash_long=128, decode_batch=2,
              decode_ctx=64, paged_batch=3, paged_blocks=9, prefill_batch=2,
-             gmm_rows=64, gmm_experts=4, gmm_width=32, qmm_group=32)
+             parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
+             qmm_group=32)
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -231,10 +234,13 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     # ---- paged decode / prefill (v2): block tables over a shared pool ----
     bs, nb, t = sz.block, sz.paged_blocks, sz.v2_max_seq // sz.block
 
-    def make_paged(batch, s, layers=0):
+    def make_paged(batch, s, layers=0, t=t, live_every=1):
         """`layers` > 0: the pools are a stack of that many, as the v2
         programs hold them, and the last input is the layer to read (the
-        last one, so that a kernel that read layer 0 would be wrong)."""
+        last one, so that a kernel that read layer 0 would be wrong).
+        `live_every` > 1: only every so-manieth row holds a request; the
+        others are parked as the v2 engine parks them, past capacity with a
+        table of -1 (docs/kv_cache.md)."""
         pool = ((layers,) if layers else ()) + (hkv, nb, bs, d)
 
         def make(key):
@@ -244,6 +250,9 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             # decode: valid tokens per row; prefill: where the s new start
             cursor = jax.random.randint(kl, (batch,), 1, t * bs - s + 1,
                                         jnp.int32)
+            live = jnp.arange(batch) % live_every == 0
+            tables = jnp.where(live[:, None], tables, -1)
+            cursor = jnp.where(live, cursor, t * bs + 1)
             new = normal(kn, (2, batch, hkv, d))
             out = (normal(kq, (batch, s, h, d)), normal(kk, pool),
                    normal(kv, pool), tables, cursor, new)
@@ -283,6 +292,17 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         return lambda q, kp, vp, tb, cur, new, layer: ref(
             q, kp[layer], vp[layer], tb, cur, new, **kw)
 
+    def parked_rows(ref, staged=False):
+        """`ref` where a row holds a request; a parked row comes back as
+        zeros, or as its staged value for every head of the group."""
+        def fn(q, kp, vp, tb, cur, new, layer):
+            out = ref(q, kp, vp, tb, cur, new, layer)
+            alone = jnp.repeat(new[1], h // hkv, axis=1)[:, None] \
+                if staged else 0.0
+            parked = (cur > tb.shape[1] * bs)[:, None, None, None]
+            return jnp.where(parked, jnp.asarray(alone, out.dtype), out)
+        return fn
+
     pb, fb = sz.paged_batch, sz.prefill_batch
     cases += [
         KernelCase("paged_decode_bf16", paged_decode, paged_ref,
@@ -303,9 +323,18 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         KernelCase("paged_decode_stacked_int8kv",
                    *int8_kv(paged_decode, of_layer(paged_ref)),
                    make_paged(pb, 1, 3)),
+        # the serving cell's decode half, one row in six holding a request:
+        # the kernel runs no step for the others (PR 31)
+        KernelCase("paged_decode_parked",
+                   lambda q, kp, vp, tb, ln, new, layer:
+                   paged_decode_attention(q, kp, vp, tb, ln, k_new=new[0],
+                                          v_new=new[1], layer=layer),
+                   parked_rows(of_layer(paged_ref, staged=True), staged=True),
+                   make_paged(sz.parked[0], 1, 3, t=sz.parked[1],
+                              live_every=6)),
     ]
     # the slowest to compile (8 s each for the described chip): last, so
-    # a test window that closes early has seen the other twenty-six
+    # a test window that closes early has seen the other twenty-seven
     slow_cases = [
         KernelCase("paged_prefill_bf16", paged_prefill, prefill_ref,
                    make_paged(fb, sz.v2_chunk)),
@@ -317,6 +346,11 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         KernelCase("paged_prefill_stacked_int8kv",
                    *int8_kv(paged_prefill, of_layer(prefill_ref)),
                    make_paged(fb, sz.v2_chunk, 3)),
+        # its wide chunk half (`fused_batch:16:48`), as empty
+        KernelCase("paged_prefill_parked", paged_prefill,
+                   parked_rows(of_layer(prefill_ref)),
+                   make_paged(sz.parked[0], sz.parked[2], 3, t=sz.parked[1],
+                              live_every=6)),
     ]
 
     # ---- the paged pools' writer: new tokens into the stack, in place ----

@@ -54,12 +54,15 @@ def chunk_row_widths(max_batch: int) -> Tuple[int, ...]:
     """Row widths of the chunk half of `fused_batch` (paged layout),
     ascending, the last always `max_batch`. How many prompts are mid-prefill
     at once is set by the arrival rate and the rounds a prompt takes, not by
-    `max_batch`, so the narrow rung is a fixed small width. A round costs by
-    the row, parked or not, but little per row once it is narrow (on a v5e,
-    Qwen2.5-3B, 16-token chunks: 115 ms at 48 rows, 78 at 16, 75 at 8),
-    while every further width costs about 1.7 s of set-up even out of the
-    persistent cache (it is traced and lowered before the cache can be
-    asked): hence one narrow rung, and 16 rather than 8."""
+    `max_batch`, so the narrow rung is a fixed small width. Every row of a
+    round rides its matmuls, parked or not (a parked row costs the paged
+    attention kernels nothing since PR 31, and the layers' matmuls what a
+    real one does), and a row costs little once the round is narrow (on a
+    v5e, Qwen2.5-3B, 16-token chunks, measured while a parked row still
+    ran the kernels: 115 ms at 48 rows, 78 at 16, 75 at 8), while every
+    further width costs about 1.7 s of set-up even out of the persistent
+    cache (it is traced and lowered before the cache can be asked): hence
+    one narrow rung, and 16 rather than 8."""
     return (16, max_batch) if max_batch > 16 else (max_batch,)
 
 
@@ -217,7 +220,10 @@ class InferenceEngineV2:
             # slots (rows x positions) the dispatched programs computed
             # against the tokens they were fed (speculative rounds apart)
             "rounds": 0, "table_syncs": 0,
-            "token_slots_computed": 0, "tokens_fed": 0}
+            "token_slots_computed": 0, "tokens_fed": 0,
+            # rows of those programs whose cursor stood at capacity (they
+            # hold nothing, and the paged attention kernels skip them)
+            "rows_parked": 0}
         self._kv_util_peak = 0.0
         self._rng = jax.random.PRNGKey(0)
         self._setup_spec()
@@ -365,9 +371,15 @@ class InferenceEngineV2:
                                         kv_heads, head_dim, dtype=config.dtype)
             self.state_manager = DSStateManager(max_batch)
             self._cache_desc = f"{max_batch} slots × {max_seq_len} tokens"
-        # park every slot: cursor at max_len → writes drop, reads mask out
+        # park every slot: cursor at max_len → writes drop, reads mask out,
+        # and the paged attention kernels run no step for the row
+        # (docs/kv_cache.md, "A row that holds nothing")
         self.cache = self.cache.replace(
             index=jnp.full((max_batch,), self.cache.max_len, jnp.int32))
+        # the host's mirror of which device cursors stand below capacity: a
+        # slot leaves the parking when a program first writes its cursor
+        # (prefill, a chunk) or `fork` sets it, and returns on flush
+        self._unparked = np.zeros((max_batch,), bool)
         # Pin every cache leaf to ONE explicit sharding. jax.jit keys its
         # compile cache on input shardings: a freshly-created cache arrives
         # as uncommitted arrays, while the same program's donated output
@@ -613,6 +625,7 @@ class InferenceEngineV2:
         # un-park the child's device cursor (decode programs read it)
         self.cache = self.cache.replace(
             index=self.cache.index.at[child.slot].set(child.seen_tokens))
+        self._unparked[child.slot] = True
 
     # ----------------------------------------------------------- telemetry
     def _stall_total(self) -> float:
@@ -1355,6 +1368,16 @@ class InferenceEngineV2:
             fields["token_slots"] = slots
             fields["tokens_fed"] = fed
 
+    def _count_rows(self, rows: int, live: int, fields=None) -> None:
+        """Rows of the dispatched program (decode rows plus chunk rows)
+        against those whose cursor stands below capacity; the others are
+        parked, and the paged attention kernels skip them. Counted like
+        `_count_slots`."""
+        self.serving_counters["rows_parked"] += rows - live
+        if fields is not None:
+            fields["rows_live"] = live
+            fields["rows_parked"] = rows - live
+
     def put(self, batch_uids: Sequence[int], batch_tokens: Sequence[np.ndarray],
             argmax_only: bool = False) -> Dict[int, np.ndarray]:
         """Schedule tokens for each uid (reference `put:107`): prompts for
@@ -1543,6 +1566,7 @@ class InferenceEngineV2:
                            if getattr(last, "ndim", 1) == 2 else None)
                 phase("commit")
                 seq.seen_tokens = len(toks)
+                self._unparked[seq.slot] = True
                 self._commit_prefix(seq)
                 out[uid] = got
 
@@ -1584,7 +1608,8 @@ class InferenceEngineV2:
             # longer serialize (reference ragged_wrapper's mixed batch).
             # The chunk half is as wide as the prompts that are prefilling
             # (the narrowest compiled width that holds them), not as wide
-            # as max_batch: a parked row costs what a real one does. Only
+            # as max_batch: a parked row is skipped by the attention
+            # kernels but rides every matmul of the round. Only
             # `fused_batch` has narrow widths, so a narrow round rides it
             # even with no row to decode.
             rows = chunk_uids[:self.max_batch]
@@ -1610,8 +1635,14 @@ class InferenceEngineV2:
                 if fused:
                     self._count_slots(R * csz + self.max_batch,
                                       fed + len(decode_uids), cf)
+                    # the decode half runs first: a row admitted this round
+                    # is still parked in it
+                    self._count_rows(R + self.max_batch,
+                                     len(rows) + int(self._unparked.sum()),
+                                     cf)
                 else:
                     self._count_slots(R * csz, fed, cf)
+                    self._count_rows(R, len(rows), cf)
                 folds = np.asarray([_uid_fold(u) for u in rows], np.int32)
                 sync()
                 if fused:
@@ -1640,6 +1671,7 @@ class InferenceEngineV2:
                     piece = pieces[uid]
                     seq.pending = seq.pending[len(piece):]
                     seq.seen_tokens += len(piece)
+                    self._unparked[seq.slot] = True
                     if not seq.pending:  # final chunk → next-token logits
                         self._commit_prefix(seq)
                         out[uid] = last_np[i]
@@ -1658,8 +1690,11 @@ class InferenceEngineV2:
                 if fused:
                     self._count_slots(csz + self.max_batch,
                                       len(piece) + len(decode_uids), cf)
+                    self._count_rows(1 + self.max_batch,
+                                     1 + int(self._unparked.sum()), cf)
                 else:
                     self._count_slots(csz, len(piece), cf)
+                    self._count_rows(1, 1, cf)
                 sync()
                 row = (ids, np.asarray(seq.slot, np.int32),
                        np.asarray(seq.seen_tokens, np.int32),
@@ -1687,6 +1722,7 @@ class InferenceEngineV2:
                     ran_decode = True
                 seq.pending = seq.pending[len(piece):]
                 seq.seen_tokens += len(piece)
+                self._unparked[seq.slot] = True
                 if not seq.pending:  # final chunk → next-token logits
                     self._commit_prefix(seq)
                     out[uid] = last_np
@@ -1698,6 +1734,8 @@ class InferenceEngineV2:
                 phase("feeds")
                 fn = self._decode_fn()
                 self._count_slots(self.max_batch, len(decode_uids), df)
+                self._count_rows(self.max_batch, int(self._unparked.sum()),
+                                 df)
                 sync()
                 self.cache, logits = dispatch(fn, tokens, active)
                 phase("fetch")
@@ -1736,6 +1774,7 @@ class InferenceEngineV2:
             for uid in uids:
                 seq = self.state_manager.get_sequence(uid)
                 slots.append(seq.slot)
+                self._unparked[seq.slot] = False
                 ended.append((uid, len(seq.tokens)))
                 if self.kv_layout == "paged":
                     self._tables_np[seq.slot] = -1
@@ -1980,6 +2019,8 @@ class InferenceEngineV2:
                         jnp.asarray(self._slot_uids, jnp.int32))
                     toks_np = np.asarray(toks)  # (K, B)
                     self._count_slots(k * self.max_batch, k * len(live), wf)
+                    self._count_rows(k * self.max_batch,
+                                     k * int(self._unparked.sum()), wf)
                     self.serving_counters["decode_waves"] += 1
                     retired = []
                     for uid in list(live):

@@ -27,6 +27,17 @@ scalar-prefetch operand, so that a block is fetched from `(layer, :, phys)`
 of the stacked pool and no program cuts a layer out of it first (or one
 layer's own (Hkv, NB, BS, D), a stack of one); tables (B, T) int32;
 lengths (B,).
+
+PARKED ROWS (docs/kv_cache.md, "A row that holds nothing"): a cursor at or
+past the table's capacity `T * BS` means the row has no place to write,
+which only a row that holds no request has (the v2 engine parks an unused
+row at `cache.max_len`, which is `T * BS`). Both attention kernels read that
+off the lengths / starts they prefetch as scalars and run NO compute step
+for such a row: decode for `lengths > T * BS` (its caller passes cursor +
+1), prefill for `starts >= T * BS`. A row that holds a request can never
+meet the test (it decodes at a cursor of at most `T * BS - 1` and prefills
+with `start + valid <= T * BS`, `valid >= 1`), and runs exactly the steps
+it ran. The writer `paged_kv_write` drops the same rows.
 """
 
 from __future__ import annotations
@@ -63,7 +74,9 @@ def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
     # the new token is staged in-register
     qpos = length - 1 + (1 if kn_ref is not None else 0)
 
-    live = j * bs < length  # fully-dead logical blocks: no compute
+    # fully-dead logical blocks: no compute; a parked row arrives with a
+    # pool length of 0 (the wrapper), so all of its blocks are dead
+    live = j * bs < length
     if window is not None:
         # sliding window: only cols in (qpos − window, qpos] attend —
         # blocks entirely below the band skip compute too (their DMAs are
@@ -215,7 +228,10 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     int32 block tables; lengths: (B,) valid tokens per row — with
     `k_new`/`v_new` (B, Hkv, D) the LAST valid token is the staged one
     (not yet in the pool) and is folded in-register; without them the new
-    token's slot must already be written.
+    token's slot must already be written. A row with `lengths > T * BS`
+    is parked (its cursor stands at capacity, it holds nothing): it runs no
+    compute step and stays on one pool block, and comes back as zeros, or
+    as its staged value when one is folded.
 
     `k_scales`/`v_scales` ([L,] Hkv, NB, BS) f32: int8-at-rest pools — the
     per-(kv-head, slot) dequant scales, DMA'd beside their blocks (same
@@ -243,8 +259,12 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     # (B, H, D): head g·n_rep+r of the HF layout is group g, member r —
     # repeat_kv's grouping; the kernel re-splits (H, D) → (Hkv, n_rep, D)
     qt = jnp.swapaxes(q, 1, 2).reshape(b, h, d)
-    # staged: pool holds lengths-1 valid tokens (the last is in-register)
-    pool_len = lengths - 1 if staged else lengths
+    # staged: pool holds lengths-1 valid tokens (the last is in-register);
+    # a parked row (cursor at capacity) holds none: with a pool length of 0
+    # none of its blocks is live and its index map never leaves block 0 of
+    # its table, in every variant below
+    pool_len = jnp.where(lengths > t * bs, 0,
+                         lengths - 1 if staged else lengths)
 
     def kv_index(b_, j, L, Tb, Ly):
         # Clamp the logical block index into the row's LIVE band; repeated
@@ -329,7 +349,9 @@ def _paged_prefill_kernel(starts_ref, tables_ref, layer_ref, q_ref, k_ref,
     # this q tile's max key position: its last query attends start+qi·cq+cq−1
     hi = start + (qi + 1) * cq
 
-    live = j * bs < hi  # blocks entirely above the causal frontier: skip
+    # blocks entirely above the causal frontier: skip; and every block of a
+    # parked row (start at capacity: it holds nothing, nobody reads it)
+    live = jnp.logical_and(j * bs < hi, start < nt * bs)
     if window is not None:
         # blocks entirely below the tile's FIRST query's window: skip
         # (their DMAs are elided by the index-map lo clamp)
@@ -432,7 +454,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     (dense-view gather + f32 (B,H,S,M) logits) that measured ~140 ms/layer
     at serving shape. Returns (B, S, H, D). The pools are stacked
     (L, Hkv, NB, BS, D) with `layer` () int32 the layer to read, or one
-    layer's own (Hkv, NB, BS, D) without one, as in the decode kernel.
+    layer's own (Hkv, NB, BS, D) without one, as in the decode kernel. A
+    row with `starts >= T * BS` is parked (it holds nothing): none of its
+    query tiles runs a compute step, and it comes back as zeros.
 
     k_scales/v_scales ([L,] Hkv, NB, BS) f32 mark an int8 pool: the kernel
     dequantizes by folding the per-token scale into the logit / probability
@@ -460,7 +484,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         # clamp to the row's last block live by the END of this prefill
         # (start + S tokens written); repeated ids elide the DMA — and,
         # with a window, blocks below the tile's band elide too
-        last = jnp.maximum((S_[b_] + s + bs - 1) // bs - 1, 0)
+        # a parked row stays on block 0 of its table
+        last = jnp.where(S_[b_] >= t * bs, 0,
+                         jnp.maximum((S_[b_] + s + bs - 1) // bs - 1, 0))
         jj = jnp.minimum(j, last)
         if window is not None:
             lo = jnp.maximum((S_[b_] + qi * cq - window + 1) // bs, 0)

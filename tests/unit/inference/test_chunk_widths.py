@@ -200,6 +200,13 @@ def test_counters_and_span_carry_the_width_that_ran(tiny, warmed, pending,
         assert (f["rows"], f["width"], f["fused"]) == (pending, width, fused)
         assert (f["token_slots"], f["tokens_fed"]) == (
             slots, pending * CHUNK + decoding)
+        # the rows the paged kernels skip: the unused rows of the width,
+        # and in the decode half (which runs first) every row but those
+        # that decode, the prompts that join in this round included
+        rows = width + (MAX_BATCH if fused else 0)
+        live = pending + (decoding if fused else 0)
+        assert (f["rows_live"], f["rows_parked"]) == (live, rows - live)
+        assert c1["rows_parked"] - c0["rows_parked"] == rows - live
         (disp,) = [s for s in store.spans() if s["name"] == "dispatch"]
         assert disp["fields"]["program"] == (
             f"fused_batch:{CHUNK}:{width}" if fused else f"chunk_batch:{CHUNK}")
@@ -208,6 +215,34 @@ def test_counters_and_span_carry_the_width_that_ran(tiny, warmed, pending,
         warmed.tracer.force = False
         store.clear()
         warmed._flush_batch(list(feed) + uids)
+
+
+@pytest.mark.parametrize("pending,decoding", [(2, 1), (17, 1)],
+                         ids=["narrow", "wide"])
+def test_rounds_of_mostly_parked_rows_give_the_same_tokens_with_the_kernels_on(
+        tiny, monkeypatch, pending, decoding):
+    """Served rounds of `fused_batch` at each width, most rows parked in
+    the round the prompts join in, through the chip's path interpreted (both paged kernels, which
+    skip those rows, and the Pallas writer) against the XLA path, which
+    holds no kernel and is the parent's: the same tokens, round for round."""
+    import deepspeed_tpu.ops.attention as attention
+    runs = []
+    for kernels in (False, True):
+        monkeypatch.setattr(attention, "_use_pallas", lambda: kernels)
+        eng = _engine(tiny)
+        runs.append(_serve(eng, tiny[0].vocab_size, pending, decoding,
+                           seed=31))
+        width = f"fused_batch:{CHUNK}:{width_for(pending, MAX_BATCH)}"
+        assert width in eng.recompiles._seen
+        # the joining round alone parks more rows than a program is wide
+        assert eng.serving_counters["rows_parked"] > MAX_BATCH
+    xla, pallas = runs
+    assert len(xla) == len(pallas)
+    for a, b in zip(xla, pallas):
+        assert sorted(a) == sorted(b)
+        for uid in a:
+            assert int(np.argmax(a[uid])) == int(np.argmax(b[uid]))
+            np.testing.assert_allclose(a[uid], b[uid], rtol=2e-3, atol=2e-3)
 
 
 @pytest.mark.parametrize("max_batch", [1, 2, 3, 4, 5, 8, 9, 16, 17, 20, 48,

@@ -6,6 +6,9 @@ Three layers of proof, all on the CPU (Pallas kernels in interpret mode):
   equal their per-layer call on `pool[l]`, every `l`; the Pallas writer
   `paged_kv_write` leaves the pools as the XLA scatters of `kv_cache.py` do,
   bit for bit.
+- ROWS THAT HOLD NOTHING (PR 31): a row whose cursor stands at capacity runs
+  no step of either attention kernel; the rows beside it come out bit for
+  bit as they do without it.
 - PROGRAMS: `prefill`, `decode`, `chunk_batch` and `fused_batch` of a v2
   engine return the same logits and the same WHOLE cache, bit for bit, as
   the engine whose model keeps the per-layer-view scan this PR replaced
@@ -96,6 +99,96 @@ def test_prefill_kernel_reads_the_layer_it_is_given(quantized, n_rep):
                                       np.asarray(want, np.float32))
         outs.append(np.asarray(got, np.float32))
     assert not np.array_equal(outs[0], outs[1])
+
+
+# a batch with parked rows first, between and last; live rows own blocks
+# 1.. of the pool, block 0 is NaN
+PARKED_ROWS, LIVE_ROWS, WINDOW = [0, 2, 5], [1, 3, 4], 5
+
+
+def _poisoned(rng, quantized, stacked):
+    """Pools whose block 0 is NaN in every layer (an int8 pool holds no NaN:
+    its block 0 has NaN scales), tables of which the live rows own blocks
+    1.. and the parked rows nothing (-1: a read through it clips to block
+    0) or, row 2, what a request left behind; and what selects the layer."""
+    k, v, ks, vs = _pools(rng, quantized)
+    nan = float("nan")
+    if quantized:
+        ks, vs = ks.at[:, :, 0].set(nan), vs.at[:, :, 0].set(nan)
+    else:
+        k, v = k.at[:, :, 0].set(nan), v.at[:, :, 0].set(nan)
+    tables = np.full((6, T), -1, np.int32)
+    tables[LIVE_ROWS] = 1 + rng.permutation(NB - 1)[:3 * T].reshape(3, T)
+    tables[2] = [0, 4, 0]
+    if stacked:
+        pools = dict(k_scales=ks, v_scales=vs, layer=jnp.int32(1))
+    else:
+        k, v = k[1], v[1]
+        pools = dict(k_scales=None if ks is None else ks[1],
+                     v_scales=None if vs is None else vs[1])
+    return k, v, jnp.asarray(tables), pools
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("staged", [False, True], ids=["unstaged", "staged"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "stack_of_1"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_decode_kernel_skips_a_row_parked_at_capacity(quantized, stacked,
+                                                      staged, windowed):
+    """Fails at the parent of PR 31 for the parked rows only: it ran every
+    block of a parked row, on block 0."""
+    rng = np.random.default_rng(31)
+    n_rep = 4
+    k, v, tables, pools = _poisoned(rng, quantized, stacked)
+    q = jnp.asarray(rng.standard_normal((6, 1, HKV * n_rep, D)), jnp.bfloat16)
+    cap = T * BS
+    # as `cached_attention` passes them, cursor + 1: the engine parks at
+    # `cap`; live rows mid-block, on a block's edge, and at the last slot
+    lengths = np.asarray([cap + 1, 3, cap + 1, BS + 1, cap, cap + 7], np.int32)
+    new = jnp.asarray(rng.standard_normal((2, 6, HKV, D)), jnp.bfloat16)
+    kw = dict(window=WINDOW if windowed else None, **pools)
+
+    def run(rows):
+        staged_kw = dict(k_new=new[0, rows], v_new=new[1, rows]) \
+            if staged else {}
+        return np.asarray(paged_decode_attention(
+            q[rows], k, v, tables[rows], jnp.asarray(lengths[rows]),
+            **staged_kw, **kw), np.float32)
+
+    got = run(np.arange(6))
+    np.testing.assert_array_equal(got[LIVE_ROWS], run(np.asarray(LIVE_ROWS)))
+    assert np.isfinite(got).all() and np.abs(got[LIVE_ROWS]).min() > 0
+    want = np.zeros_like(got[PARKED_ROWS])
+    if staged:  # the staged token alone: its value, for every head of a group
+        want = np.repeat(np.asarray(new[1, PARKED_ROWS], np.float32),
+                         n_rep, axis=1)[:, None]
+    np.testing.assert_array_equal(got[PARKED_ROWS], want)
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "stack_of_1"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_prefill_kernel_skips_a_row_parked_at_capacity(quantized, stacked,
+                                                       windowed):
+    rng = np.random.default_rng(32)
+    n_rep, s = 4, 4
+    k, v, tables, pools = _poisoned(rng, quantized, stacked)
+    q = jnp.asarray(rng.standard_normal((6, s, HKV * n_rep, D)), jnp.bfloat16)
+    cap = T * BS
+    # live rows: a first chunk, one across a block's edge, one that ends on
+    # the last slot (start + valid == capacity)
+    starts = np.asarray([cap, 0, cap, BS - 2, cap - s, cap + 3], np.int32)
+    kw = dict(window=WINDOW if windowed else None, block_q=2, **pools)
+
+    def run(rows):
+        return np.asarray(paged_prefill_attention(
+            q[rows], k, v, tables[rows], jnp.asarray(starts[rows]), **kw),
+            np.float32)
+
+    got = run(np.arange(6))
+    np.testing.assert_array_equal(got[LIVE_ROWS], run(np.asarray(LIVE_ROWS)))
+    assert np.isfinite(got).all() and np.abs(got[LIVE_ROWS]).min() > 0
+    np.testing.assert_array_equal(got[PARKED_ROWS], 0.0)
 
 
 def _owned_tables(rng, b):

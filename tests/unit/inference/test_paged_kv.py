@@ -330,6 +330,54 @@ def test_paged_decode_kernel_staged_vs_reference():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kind", ["decode", "decode_staged", "prefill"])
+def test_a_row_that_ends_on_the_last_slot_is_not_skipped(kind):
+    """The paged kernels skip a row whose cursor stands AT capacity (it
+    holds nothing: docs/kv_cache.md). One slot below it a row holds a
+    request: a decode at `index = T*BS - 1`, a chunk with `start + valid =
+    T*BS`. Both against the float32 reference, beside a parked row, which
+    comes back as zeros (or as its staged value)."""
+    rng = np.random.default_rng(31)
+    hkv, n_rep, d, bs, t, nb = 2, 4, 64, 16, 4, 9
+    h, cap = hkv * n_rep, t * bs
+    s = 1 if kind != "prefill" else 8
+    pool_k = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
+    pool_v = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
+    tables = jnp.asarray(np.stack([rng.permutation(nb)[:t], np.full(t, -1),
+                                   rng.permutation(nb)[:t]]), jnp.int32)
+    index = jnp.asarray([cap - s, cap, 5], jnp.int32)   # last slot, parked
+    q = jnp.asarray(rng.normal(size=(3, s, h, d)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(2, 3, hkv, d)), jnp.float32)
+
+    from deepspeed_tpu.inference.kv_cache import PagedLayer
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_prefill_attention)
+    dense_k = gather_paged_layer(PagedLayer(pool=pool_k, tables=tables))
+    dense_v = gather_paged_layer(PagedLayer(pool=pool_v, tables=tables))
+    if kind == "prefill":
+        got = paged_prefill_attention(q, pool_k, pool_v, tables, index,
+                                      block_q=4)
+    elif kind == "decode_staged":
+        got = paged_decode_attention(q, pool_k, pool_v, tables, index + 1,
+                                     k_new=new[0], v_new=new[1])
+        rows = jnp.arange(3)
+        dense_k = dense_k.at[rows, index].set(new[0], mode="drop")
+        dense_v = dense_v.at[rows, index].set(new[1], mode="drop")
+    else:
+        got = paged_decode_attention(q, pool_k, pool_v, tables, index + 1)
+    mask = decode_mask(index[:, None] + jnp.arange(s)[None, :], cap)
+    ref = reference_attention(q, dense_k, dense_v, causal=False,
+                              segment_mask=mask)
+    live = np.asarray([0, 2])
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert np.abs(np.asarray(got)[0]).min() > 0
+    want = np.zeros((s, h, d), np.float32)
+    if kind == "decode_staged":
+        want = np.repeat(np.asarray(new[1, 1]), n_rep, axis=0)[None]
+    np.testing.assert_array_equal(np.asarray(got)[1], want)
+
+
 def test_staged_cache_parity_with_unstaged():
     """An engine-shaped staged decode round (update_layer staging +
     fallback attention + apply_stage) must equal the unstaged path."""

@@ -272,6 +272,47 @@ def test_sharded_paged_kernels_on_the_stacked_pool(kind):
     _close(got, want, tol=1e-4)
 
 
+@pytest.mark.parametrize("kind", ["decode", "decode_staged", "prefill"])
+def test_sharded_paged_kernels_skip_a_parked_row(kind):
+    """The wrappers hand lengths / starts through as they come, so a row
+    parked at capacity (`T * BS`; docs/kv_cache.md) is skipped on every
+    shard: with pool block 0 NaN, which an unowned table entry (-1) reads,
+    the parked row comes back finite and the live row as it does alone."""
+    mesh = _tp_mesh()
+    rng = np.random.default_rng(31)
+    nl, hkv, nb, bs, d, h, t, s = 2, 4, 8, 16, 64, 8, 3, 8
+    layer, cap = jnp.int32(1), t * bs
+    kp = jnp.asarray(rng.standard_normal((nl, hkv, nb, bs, d)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((nl, hkv, nb, bs, d)), jnp.float32)
+    kp, vp = kp.at[:, :, 0].set(jnp.nan), vp.at[:, :, 0].set(jnp.nan)
+    tables = jnp.asarray([[-1] * t, list(1 + rng.permutation(nb - 1)[:t])],
+                         jnp.int32)
+    if kind == "prefill":
+        q = jnp.asarray(rng.standard_normal((2, s, h, d)), jnp.float32)
+        starts = jnp.asarray([cap, cap - s], jnp.int32)
+
+        def run(rows):
+            return sharded_paged_prefill_attention(
+                q[rows], kp, vp, tables[rows], starts[rows], mesh,
+                layer=layer)
+    else:
+        q = jnp.asarray(rng.standard_normal((2, 1, h, d)), jnp.float32)
+        lengths = jnp.asarray([cap + 1, cap], jnp.int32)   # cursor + 1
+        new = jnp.asarray(rng.standard_normal((2, 2, hkv, d)), jnp.float32)
+
+        def run(rows):
+            kw = dict(k_new=new[0, rows], v_new=new[1, rows]) \
+                if kind == "decode_staged" else {}
+            return sharded_paged_decode_attention(
+                q[rows], kp, vp, tables[rows], lengths[rows], mesh,
+                layer=layer, **kw)
+    got = np.asarray(run(np.arange(2)))
+    np.testing.assert_array_equal(got[1:], np.asarray(run(np.arange(1, 2))))
+    assert np.isfinite(got).all() and np.abs(got[1]).min() > 0
+    if kind != "decode_staged":
+        np.testing.assert_array_equal(got[0], 0.0)
+
+
 # ------------------------------------------- cached_attention dispatch
 
 def _prefix_mask(index, m, s=1):

@@ -150,6 +150,17 @@ P_LONG = list(range(10, 30))              # 20 tokens: chunks of 8, 8, 4
 #  4 fused                                  36, 8 + 1
 #  5 chunk alone                            32, 4
 SLOTS, FED, ROUNDS = 140, 28, 5
+# round: rows of the program, of which live (cursor below capacity)
+#  2 decode                                  4, 1 (uid 1)
+#  3 fused: 4 chunk rows + 4 decode rows     8, 1 chunk + 1: uid 2 joins in
+#                                               this round, and its cursor is
+#                                               still parked in the decode
+#                                               half, which runs first
+#  4 fused                                   8, 1 chunk + 2: uid 2 rides the
+#                                               decode half mid-prefill
+#  5 chunk alone                             4, 1
+ROWS = [(4, 1), (8, 2), (8, 3), (4, 1)]
+PARKED = sum(rows - live for rows, live in ROWS)
 
 
 def _script(model, params, traced, profile_dir=None):
@@ -206,6 +217,24 @@ def test_counts_are_exact_tracing_on_or_off(runs):
     assert [(s["fields"]["token_slots"], s["fields"]["tokens_fed"])
             for s in parents] == [(32, 5), (4, 1), (36, 9), (36, 9), (32, 4)]
     assert [s["round"] for s in parents] == [1, 2, 3, 4, 5]
+
+
+def test_rows_live_and_parked_add_up_to_the_programs_rows(runs):
+    """`rows_parked` counts what the paged kernels skip: tracing on or
+    off in the counters, and on each `decode` / `chunk` span beside
+    `rows_live`, the two adding up to the rows of the program that ran."""
+    for eng in (runs["off"], runs["on"]):
+        assert eng.serving_counters["rows_parked"] == PARKED
+        assert eng.telemetry_snapshot()["rows_parked"] == PARKED
+        assert not eng._unparked.any()               # everything flushed
+    spans = [s for s in runs["spans"] if s["name"] in ("chunk", "decode")]
+    assert len(spans) == len(ROWS)
+    for s, (rows, live) in zip(spans, ROWS):
+        f = s["fields"]
+        width = (MAX_BATCH if s["name"] == "decode" else
+                 f["width"] + (MAX_BATCH if f["fused"] else 0))
+        assert f["rows_live"] + f["rows_parked"] == width == rows
+        assert f["rows_live"] == live
 
 
 def test_tracing_off_makes_no_record_and_changes_no_output(runs):
