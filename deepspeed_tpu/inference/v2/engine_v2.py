@@ -57,7 +57,11 @@ def chunk_row_widths(max_batch: int) -> Tuple[int, ...]:
     `max_batch`, so the narrow rung is a fixed small width. Every row of a
     round rides its matmuls, parked or not (a parked row costs the paged
     attention kernels nothing since PR 31, and the layers' matmuls what a
-    real one does), and a row costs little once the round is narrow (on a
+    real one does), which is why `put` FILLS the width: the rows no prompt
+    needs for its one chunk carry further chunks of the prompts that are
+    prefilling (PR 36), so the width is also the most chunks a round
+    takes in, and what a decode row can be held up by. A row costs little
+    once the round is narrow (on a
     v5e, Qwen2.5-3B, 16-token chunks, measured while a parked row still
     ran the kernels: 115 ms at 48 rows, 78 at 16, 75 at 8), while every
     further width costs about 1.7 s of set-up even out of the persistent
@@ -67,7 +71,10 @@ def chunk_row_widths(max_batch: int) -> Tuple[int, ...]:
 
 
 def width_for(rows: int, max_batch: int) -> int:
-    """The narrowest compiled chunk width that holds `rows` prompts."""
+    """The narrowest compiled chunk width that holds `rows` prompts, a row
+    each. The PROMPTS choose it, not their pending tokens: a round goes
+    `max_batch` wide only when more prompts prefill than the narrow rung
+    has rows."""
     return next(w for w in chunk_row_widths(max_batch) if w >= rows)
 
 
@@ -222,8 +229,10 @@ class InferenceEngineV2:
             "rounds": 0, "table_syncs": 0,
             "token_slots_computed": 0, "tokens_fed": 0,
             # rows of those programs whose cursor stood at capacity (they
-            # hold nothing, and the paged attention kernels skip them)
-            "rows_parked": 0}
+            # hold nothing, and the paged attention kernels skip them), and
+            # chunk rows that went to a prompt beyond its first of the round
+            # (they would have been parked)
+            "rows_parked": 0, "rows_refilled": 0}
         self._kv_util_peak = 0.0
         self._rng = jax.random.PRNGKey(0)
         self._setup_spec()
@@ -919,9 +928,16 @@ class InferenceEngineV2:
         """Batched chunk prefill (paged layout): R rows' prompt chunks run
         as ONE compiled call — the reference packs mixed prefill rows into
         one ragged batch (`inference/v2/ragged/ragged_wrapper.py`); here the
-        rows share the (R, C) program, each writing through its own block-
-        table row at its own cursor. Unused rows park (start = max_len →
-        writes drop, outputs ignored)."""
+        rows share the (R, C) program, each writing through its slot's
+        block-table row at its own cursor. Several rows may be ONE
+        sequence's, consecutive chunks at consecutive cursors: a layer
+        writes every row's K/V before its attention reads the pool, and
+        the attention masks by absolute position, so they are one longer
+        chunk, bit for bit (`tests/unit/inference/
+        test_filled_chunk_rounds.py`). `slots[i]` is the row's slot, or
+        `~slot` when another row of the same sequence follows it in this
+        call: only the last row of a sequence sets its cursor. Unused rows
+        park (start = max_len → writes drop, outputs ignored)."""
         apply = self._apply
 
         def chunk_batch(params, cache, ids, slots, starts, valids):
@@ -929,20 +945,28 @@ class InferenceEngineV2:
             # gather clips (their writes drop on the parked cursor anyway)
             # and the index scatter DROPS them — a parked row must never
             # collide with a live row's slot in the scatter (duplicate-index
-            # scatter is last-wins)
+            # scatter is last-wins). Nor may two rows of ONE sequence: a
+            # row that another row of its sequence follows carries ~slot,
+            # which reads the slot's table and leaves the cursor to the
+            # sequence's last row.
+            followed = slots < 0
+            tables_of = jnp.where(followed, ~slots, slots)
+            cursor_of = jnp.where(followed, self.max_batch, slots)
             with jax.named_scope("table_gather"):
                 rows = PagedKVCache(
-                    k=cache.k.replace(tables=jnp.take(cache.k.tables, slots,
-                                                      axis=1, mode="clip"),
+                    k=cache.k.replace(tables=jnp.take(cache.k.tables,
+                                                      tables_of, axis=1,
+                                                      mode="clip"),
                                       stage=None),  # chunks write the pool
-                    v=cache.v.replace(tables=jnp.take(cache.v.tables, slots,
-                                                      axis=1, mode="clip"),
+                    v=cache.v.replace(tables=jnp.take(cache.v.tables,
+                                                      tables_of, axis=1,
+                                                      mode="clip"),
                                       stage=None),
                     index=starts)
             logits, rows = apply(params, ids, rows)
             with jax.named_scope("merge_row"):
-                index = cache.index.at[slots].set(starts + valids,
-                                                  mode="drop")
+                index = cache.index.at[cursor_of].set(starts + valids,
+                                                      mode="drop")
                 new_cache = PagedKVCache(
                     k=cache.k.replace(pool=rows.k.pool,
                                       scales=rows.k.scales),
@@ -1383,16 +1407,22 @@ class InferenceEngineV2:
         """Schedule tokens for each uid (reference `put:107`): prompts for
         unknown uids (prefill), single continuation tokens for known ones
         (batched decode), multi-token feeds for known ones (prefill
-        continuation). One scheduling ROUND per call: every mid-prefill
-        sequence (fed this call or earlier) advances by ONE chunk of
-        `split_fuse_chunk` tokens, the first chunk riding the same compiled
-        step as this call's decode rows (dynamic split-fuse) — so long
-        prompts never stall decode for more than one chunk of work. Paged,
-        a round computes `width x split_fuse_chunk` token slots for its
-        chunks, `width` the narrowest of `chunk_row_widths(max_batch)` that
-        holds the sequences mid-prefill, plus `max_batch` decode rows when
-        any row decodes or the width is a narrow one; the first such round
-        compiles every width. Returns
+        continuation). One scheduling ROUND per call, the chunks riding the
+        same compiled step as this call's decode rows (dynamic split-fuse).
+        Paged, a round computes `width x split_fuse_chunk` token slots for
+        its chunks, `width` the narrowest of `chunk_row_widths(max_batch)`
+        that holds the sequences mid-prefill (fed this call or earlier) a
+        row each, plus `max_batch` decode rows when any row decodes or the
+        width is a narrow one; the first such round compiles every width.
+        The width is a token budget, and it is FILLED (the reference's
+        Dynamic SplitFuse): every sequence mid-prefill advances by one
+        chunk of `split_fuse_chunk` tokens, in admission order, so none
+        starves; the rows left over go to the same sequences in the same
+        order, each taking the further chunks it still has pending. What a
+        decode row can be held up by is one round of the width's fixed
+        size, however long the prompts beside it. (The slot layout has no
+        batched program: ONE chunk a sequence a round, a program each.)
+        Returns
         next-token logits only for uids that produced one this round (a
         decode, or a prompt whose LAST chunk ran); keep calling put (with or
         without new tokens) to drain the rest.
@@ -1584,8 +1614,8 @@ class InferenceEngineV2:
                 for uid, seq, toks in new_short:
                     single_prefill(uid, seq, toks)
         with tr.span("schedule") if on else _OFF:
-            # every mid-prefill sequence advances one chunk this round,
-            # whether its tokens arrived in this call or an earlier one
+            # every mid-prefill sequence is in this round, whether its
+            # tokens arrived in this call or an earlier one
             chunk_uids = [uid for uid, seq in
                           self.state_manager.tracked_sequences.items()
                           if seq.pending]
@@ -1608,42 +1638,57 @@ class InferenceEngineV2:
             # longer serialize (reference ragged_wrapper's mixed batch).
             # The chunk half is as wide as the prompts that are prefilling
             # (the narrowest compiled width that holds them), not as wide
-            # as max_batch: a parked row is skipped by the attention
-            # kernels but rides every matmul of the round. Only
-            # `fused_batch` has narrow widths, so a narrow round rides it
-            # even with no row to decode.
-            rows = chunk_uids[:self.max_batch]
-            R = width_for(len(rows), self.max_batch)
+            # as max_batch, and every row of it rides every matmul of the
+            # round, so the rows are FILLED (the reference's token budget,
+            # `RaggedBatchWrapper`): each prompt takes one, in admission
+            # order, and the rows left over go to the same prompts in the
+            # same order, each taking the further chunks it still has
+            # pending. Only `fused_batch` has narrow widths, so a narrow
+            # round rides it even with no row to decode.
+            seqs = [self.state_manager.get_sequence(uid)
+                    for uid in chunk_uids[:self.max_batch]]
+            R = width_for(len(seqs), self.max_batch)
+            spare = R - len(seqs)
+            plan = []     # (sequence, its first row, its rows, its tokens)
+            n_rows = 0
+            for seq in seqs:
+                take = 1 + min(spare, -(-len(seq.pending) // csz) - 1)
+                plan.append((seq, n_rows, take,
+                             min(take * csz, len(seq.pending))))
+                spare -= take - 1
+                n_rows += take
             fused = (not ran_decode and bool(decode_uids)
                      or R < self.max_batch)
-            span_uids = tuple(rows) + (tuple(decode_uids) if fused else ())
-            with (tr.span("chunk", uids=span_uids, fused=fused,
-                          rows=len(rows), width=R) if on else _OFF) as cf:
+            span_uids = tuple(s.uid for s in seqs) + (
+                tuple(decode_uids) if fused else ())
+            with (tr.span("chunk", uids=span_uids, fused=fused, rows=n_rows,
+                          sequences=len(seqs), width=R)
+                  if on else _OFF) as cf:
                 phase("feeds")
                 ids, slots, starts, valids = self._parked_rows(R)
-                pieces = {}
-                for i, uid in enumerate(rows):
-                    seq = self.state_manager.get_sequence(uid)
-                    piece = seq.pending[:csz]
-                    pieces[uid] = piece
-                    ids[i, :len(piece)] = piece
-                    slots[i] = seq.slot
-                    starts[i] = seq.seen_tokens
-                    valids[i] = len(piece)
-                    self._reserve(seq, seq.seen_tokens + len(piece))
-                fed = int(valids.sum())
+                folds = np.zeros((n_rows,), np.int32)
+                for seq, i, take, n in plan:
+                    ids[i:i + take].reshape(-1)[:n] = seq.pending[:n]
+                    # all but the sequence's last row leave the cursor alone
+                    slots[i:i + take] = ~seq.slot
+                    slots[i + take - 1] = seq.slot
+                    starts[i:i + take] = seq.seen_tokens + csz * np.arange(take)
+                    valids[i:i + take] = csz
+                    valids[i + take - 1] = n - csz * (take - 1)
+                    folds[i:i + take] = _uid_fold(seq.uid)
+                    self._reserve(seq, seq.seen_tokens + n)
+                self.serving_counters["rows_refilled"] += n_rows - len(seqs)
+                fed = sum(n for _, _, _, n in plan)
                 if fused:
                     self._count_slots(R * csz + self.max_batch,
                                       fed + len(decode_uids), cf)
                     # the decode half runs first: a row admitted this round
                     # is still parked in it
                     self._count_rows(R + self.max_batch,
-                                     len(rows) + int(self._unparked.sum()),
-                                     cf)
+                                     n_rows + int(self._unparked.sum()), cf)
                 else:
                     self._count_slots(R * csz, fed, cf)
-                    self._count_rows(R, len(rows), cf)
-                folds = np.asarray([_uid_fold(u) for u in rows], np.int32)
+                    self._count_rows(R, n_rows, cf)
                 sync()
                 if fused:
                     self.cache, logits, last = dispatch(
@@ -1666,15 +1711,13 @@ class InferenceEngineV2:
                     phase("fetch")
                     last_np = _mat(last, folds)
                     phase("commit")
-                for i, uid in enumerate(rows):
-                    seq = self.state_manager.get_sequence(uid)
-                    piece = pieces[uid]
-                    seq.pending = seq.pending[len(piece):]
-                    seq.seen_tokens += len(piece)
+                for seq, i, take, n in plan:
+                    seq.pending = seq.pending[n:]
+                    seq.seen_tokens += n
                     self._unparked[seq.slot] = True
                     if not seq.pending:  # final chunk → next-token logits
                         self._commit_prefix(seq)
-                        out[uid] = last_np[i]
+                        out[seq.uid] = last_np[i + take - 1]
             chunk_uids = chunk_uids[self.max_batch:]
         for uid in chunk_uids:  # slot layout: ONE chunk each this round
             fused = not ran_decode and bool(decode_uids)
@@ -1888,10 +1931,10 @@ class InferenceEngineV2:
         while pending or live:
             step_uids = [u for u in live if u not in prefilling]
             step_tokens: List[List[int]] = [[results[u][-1]] for u in step_uids]
-            # Admit new prompts INTO this step — a long prompt prefills one
-            # chunk per step, the chunk fused with the live rows' decode
+            # Admit new prompts INTO this step — a long prompt prefills a
+            # round's chunk rows per step, fused with the live rows' decode
             # (split-fuse), so ongoing generation never stalls for more than
-            # one chunk's worth of work.
+            # one round of the width's fixed size.
             admitted: List[int] = []  # filled DURING the span body — the
             # tracer snapshots uids at span exit, so late appends count
             adm_cm = (self.tracer.span("admit", uids=admitted)

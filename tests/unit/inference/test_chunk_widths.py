@@ -80,6 +80,28 @@ def _serve(eng, vocab, pending, decoding, seed, argmax_only=False):
     return rounds
 
 
+def _by_uid(rounds):
+    """What each uid produced, in order, whatever the round it came in."""
+    out = {}
+    for got in rounds:
+        for uid, o in got.items():
+            out.setdefault(uid, []).append(o)
+    return out
+
+
+def _filled(lengths, width):
+    """(rows that carry tokens, tokens fed) of the first chunk round of
+    prompts of these lengths in a round `width` rows wide: one row each in
+    admission order, then the rows left over to the same prompts in the
+    same order, a chunk a row."""
+    takes, spare = [], width - len(lengths)
+    for n in lengths:
+        more = min(spare, -(-n // CHUNK) - 1)
+        takes.append(1 + more)
+        spare -= more
+    return sum(takes), sum(min(t * CHUNK, n) for t, n in zip(takes, lengths))
+
+
 # (rows pending, rows decoding): every rung, with and without decode rows
 MIXES = [(1, 0), (1, 2), (3, 0), (3, 2), (7, 0), (7, 2), (13, 0), (13, 3),
          (MAX_BATCH, 0), (MAX_BATCH - 1, 1)]
@@ -109,17 +131,20 @@ def transcripts(tiny):
                          ids=[f"pending{p}-decoding{d}" for p, d in MIXES])
 def test_a_rows_outputs_do_not_depend_on_the_width_it_rode_in(transcripts,
                                                               case):
-    ladder, flat = transcripts["ladder"][case], transcripts["collapsed"][case]
-    assert len(ladder) == len(flat)
-    produced = 0
-    for a, b in zip(ladder, flat):
-        assert sorted(a) == sorted(b)
-        for uid in a:
-            assert int(np.argmax(a[uid])) == int(np.argmax(b[uid]))
-            np.testing.assert_allclose(a[uid], b[uid], rtol=1e-5, atol=1e-5)
-            produced += 1
+    ladder, flat = (_by_uid(transcripts[name][case])
+                    for name in ("ladder", "collapsed"))
+    # a wider round has more rows to fill, so a prompt may be in a round
+    # sooner and the rows beside it decode less often until the script
+    # ends: what a uid produced is compared output for output, as far as
+    # both runs went
+    assert sorted(ladder) == sorted(flat)
+    for uid in ladder:
+        for a, b in zip(ladder[uid], flat[uid]):
+            assert int(np.argmax(a)) == int(np.argmax(b))
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
     pending, decoding = MIXES[case]
-    assert produced >= pending + decoding
+    assert len(ladder) == pending + decoding
+    assert all(ladder[uid] and flat[uid] for uid in ladder)
 
 
 def _family(widths):
@@ -161,13 +186,16 @@ def test_no_pending_count_compiles_after_the_first_chunk_round(
 
 def test_the_first_chunk_round_compiles_every_width_and_counts_one_round(tiny):
     eng = _engine(tiny)
-    eng.put([1], [_prompts(tiny[0].vocab_size, 1, 0)[0]], argmax_only=True)
+    (prompt,) = _prompts(tiny[0].vocab_size, 1, 0)
+    got = eng.put([1], [prompt], argmax_only=True)
     assert sorted(eng.recompiles._seen) == _family(WIDTHS)
     c = eng.serving_counters
     # the parked dispatches are not rounds and feed nothing; the one round
-    # rode the narrowest `fused_batch`, whose decode half ran idle
+    # rode the narrowest `fused_batch`, whose decode half ran idle, and
+    # held the whole prompt (two rows of it)
     assert (c["rounds"], c["token_slots_computed"], c["tokens_fed"]) == \
-        (1, WIDTHS[0] * CHUNK + MAX_BATCH, CHUNK)
+        (1, WIDTHS[0] * CHUNK + MAX_BATCH, len(prompt))
+    assert list(got) == [1] and c["rows_refilled"] == 1
 
 
 @pytest.mark.parametrize("decoding", [0, 1], ids=["alone", "fused"])
@@ -188,23 +216,28 @@ def test_counters_and_span_carry_the_width_that_ran(tiny, warmed, pending,
     try:
         c0 = dict(warmed.serving_counters)
         uids = list(range(1, pending + 1))
-        warmed.put(list(feed) + uids, [[t] for t in feed.values()] +
-                   _prompts(vocab, pending, 0), argmax_only=True)
+        prompts = _prompts(vocab, pending, 0)
+        warmed.put(list(feed) + uids, [[t] for t in feed.values()] + prompts,
+                   argmax_only=True)
         c1 = warmed.serving_counters
         fused = bool(decoding) or width < MAX_BATCH
         slots = width * CHUNK + (MAX_BATCH if fused else 0)
+        filled, fed = _filled([len(p) for p in prompts], width)
+        assert pending <= filled <= width
         assert c1["token_slots_computed"] - c0["token_slots_computed"] == slots
-        assert c1["tokens_fed"] - c0["tokens_fed"] == pending * CHUNK + decoding
+        assert c1["tokens_fed"] - c0["tokens_fed"] == fed + decoding
+        assert c1["rows_refilled"] - c0["rows_refilled"] == filled - pending
         (chunk,) = [s for s in store.spans() if s["name"] == "chunk"]
         f = chunk["fields"]
-        assert (f["rows"], f["width"], f["fused"]) == (pending, width, fused)
-        assert (f["token_slots"], f["tokens_fed"]) == (
-            slots, pending * CHUNK + decoding)
-        # the rows the paged kernels skip: the unused rows of the width,
-        # and in the decode half (which runs first) every row but those
-        # that decode, the prompts that join in this round included
+        assert (f["rows"], f["sequences"], f["width"], f["fused"]) == (
+            filled, pending, width, fused)
+        assert (f["token_slots"], f["tokens_fed"]) == (slots, fed + decoding)
+        # the rows the paged kernels skip: the rows of the width that no
+        # prompt could fill, and in the decode half (which runs first)
+        # every row but those that decode, the prompts that join in this
+        # round included
         rows = width + (MAX_BATCH if fused else 0)
-        live = pending + (decoding if fused else 0)
+        live = filled + (decoding if fused else 0)
         assert (f["rows_live"], f["rows_parked"]) == (live, rows - live)
         assert c1["rows_parked"] - c0["rows_parked"] == rows - live
         (disp,) = [s for s in store.spans() if s["name"] == "dispatch"]
@@ -222,9 +255,11 @@ def test_counters_and_span_carry_the_width_that_ran(tiny, warmed, pending,
 def test_rounds_of_mostly_parked_rows_give_the_same_tokens_with_the_kernels_on(
         tiny, monkeypatch, pending, decoding):
     """Served rounds of `fused_batch` at each width, most rows parked in
-    the round the prompts join in, through the chip's path interpreted (both paged kernels, which
-    skip those rows, and the Pallas writer) against the XLA path, which
-    holds no kernel and is the parent's: the same tokens, round for round."""
+    the round the prompts join in and the live ones several to a prompt,
+    through the chip's path interpreted (both paged kernels, which skip
+    the parked rows, and the Pallas writer, which writes one block from
+    two rows) against the XLA path, which holds no kernel: the same
+    tokens, round for round."""
     import deepspeed_tpu.ops.attention as attention
     runs = []
     for kernels in (False, True):
@@ -234,8 +269,11 @@ def test_rounds_of_mostly_parked_rows_give_the_same_tokens_with_the_kernels_on(
                            seed=31))
         width = f"fused_batch:{CHUNK}:{width_for(pending, MAX_BATCH)}"
         assert width in eng.recompiles._seen
-        # the joining round alone parks more rows than a program is wide
+        # the joining round alone parks more rows than a program is wide,
+        # and in it every prompt has two rows or three: live rows of one
+        # round that share a slot, a block and a cursor's scatter
         assert eng.serving_counters["rows_parked"] > MAX_BATCH
+        assert eng.serving_counters["rows_refilled"] >= min(pending, 3)
     xla, pallas = runs
     assert len(xla) == len(pallas)
     for a, b in zip(xla, pallas):

@@ -152,19 +152,19 @@ def test_split_fuse_decode_rides_chunk_step(tiny):
     la = both.put([0], [np.asarray(p_a, np.int32)])[0]
     seq_a = [*p_a, int(np.argmax(la))]
     # B's long prompt arrives while A decodes: each put advances A by one
-    # token AND B by one chunk in the SAME fused step; B (30 tokens, chunk
-    # 8 → 4 chunks) completes on the 4th round without ever stalling A.
+    # token AND B by a round's chunk rows in the SAME fused step; B (30
+    # tokens, chunk 8 → 4 chunks, two rows a round at max_batch 2)
+    # completes on the 2nd round without ever stalling A.
     b_logits = None
-    rounds = 0
-    for _ in range(4):
-        outs = both.put([0], [[seq_a[-1]]]) if rounds else \
+    done_in = None
+    for rounds in range(1, 5):
+        outs = both.put([0], [[seq_a[-1]]]) if rounds > 1 else \
             both.put([0, 1], [[seq_a[-1]], np.asarray(p_b, np.int32)])
-        rounds += 1
         assert 0 in outs          # A decoded every round
         seq_a.append(int(np.argmax(outs[0])))
         if 1 in outs:
-            b_logits = outs[1]
-    assert b_logits is not None and rounds == 4  # B done on the last chunk
+            b_logits, done_in = outs[1], rounds
+    assert b_logits is not None and done_in == 2  # B done on the last chunk
     seq_a.append(int(np.argmax(both.put([0], [[seq_a[-1]]])[0])))
     np.testing.assert_array_equal(seq_a, ref_a)  # 1 + 4 + 1 = 6 new tokens
     # B continues decoding correctly after its chunked prefill
@@ -183,7 +183,7 @@ def test_split_fuse_continuation_feed(tiny):
     (prefill continuation) — equivalent to having sent one longer prompt."""
     cfg, model, params = tiny
     rng = np.random.default_rng(4)
-    prompt = list(rng.integers(0, cfg.vocab_size, 20))
+    prompt = list(rng.integers(0, cfg.vocab_size, 28))
 
     groups.reset_topology()
     ref_eng = InferenceEngineV2(model, params=params, max_batch=2,
@@ -193,11 +193,11 @@ def test_split_fuse_continuation_feed(tiny):
     groups.reset_topology()
     fed = InferenceEngineV2(model, params=params, max_batch=2, max_seq_len=64,
                             split_fuse_chunk=8)
-    first = fed.put([0], [np.asarray(prompt[:12], np.int32)])
-    assert 0 not in first            # 12 > chunk: one chunk ran, 4 pending
-    second = fed.put([], [])         # empty put drains one more chunk
+    first = fed.put([0], [np.asarray(prompt[:20], np.int32)])
+    assert 0 not in first            # two rows of the width ran, 4 pending
+    second = fed.put([], [])         # empty put drains the last chunk
     assert 0 in second               # first feed complete
-    out = fed.put([0], [np.asarray(prompt[12:], np.int32)])[0]
+    out = fed.put([0], [np.asarray(prompt[20:], np.int32)])[0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 

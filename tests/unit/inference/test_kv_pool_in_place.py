@@ -191,6 +191,42 @@ def test_prefill_kernel_skips_a_row_parked_at_capacity(quantized, stacked,
     np.testing.assert_array_equal(got[PARKED_ROWS], 0.0)
 
 
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "stack_of_1"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_prefill_kernel_rows_of_one_sequence_beside_parked_rows(
+        quantized, stacked, windowed):
+    """A filled chunk round (PR 36): the live rows are ONE sequence's, three
+    chunks at consecutive cursors through one table (the middle one across
+    a block's edge), parked rows before, between and after them. Each comes
+    out as it does with the parked rows gone, and the three as the one
+    chunk of three times the length that they are."""
+    rng = np.random.default_rng(36)
+    n_rep, s = 4, 4
+    k, v, tables, pools = _poisoned(rng, quantized, stacked)
+    tables = tables.at[np.asarray(LIVE_ROWS)].set(tables[LIVE_ROWS[0]])
+    q = jnp.asarray(rng.standard_normal((6, s, HKV * n_rep, D)), jnp.bfloat16)
+    cap, first = T * BS, BS - s - 2
+    starts = np.asarray([cap, first, cap, first + s, first + 2 * s, cap + 3],
+                        np.int32)
+    kw = dict(window=WINDOW if windowed else None, block_q=2, **pools)
+
+    def run(rows):
+        return np.asarray(paged_prefill_attention(
+            q[rows], k, v, tables[rows], jnp.asarray(starts[rows]), **kw),
+            np.float32)
+
+    got = run(np.arange(6))
+    np.testing.assert_array_equal(got[LIVE_ROWS], run(np.asarray(LIVE_ROWS)))
+    assert np.isfinite(got).all() and np.abs(got[LIVE_ROWS]).min() > 0
+    np.testing.assert_array_equal(got[PARKED_ROWS], 0.0)
+    live = np.asarray(LIVE_ROWS)
+    one = np.asarray(paged_prefill_attention(
+        q[live].reshape(1, 3 * s, HKV * n_rep, D), k, v, tables[live[:1]],
+        jnp.asarray(starts[live[:1]]), **kw), np.float32)
+    np.testing.assert_array_equal(got[LIVE_ROWS].reshape(one.shape), one)
+
+
 def _owned_tables(rng, b):
     """Each row owns a prefix of its table, of distinct blocks; the rest
     is unowned (-1)."""
@@ -207,9 +243,14 @@ def _owned_tables(rng, b):
 @pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_writer_kernel_is_the_scatter(dtype, quantized, s):
+@pytest.mark.parametrize("one_sequence", [False, True],
+                         ids=["rows_apart", "rows_of_one_sequence"])
+def test_writer_kernel_is_the_scatter(one_sequence, dtype, quantized, s):
     """`paged_kv_write` against `_update_paged_layer`: parked rows, unowned
-    entries, cursors anywhere, a piece that spans blocks, a whole block."""
+    entries, cursors anywhere, a piece that spans blocks, a whole block.
+    `rows_of_one_sequence` (a filled chunk round, PR 36): rows 2, 4 and 3
+    write through ONE table at consecutive cursors, so two grid steps
+    read, modify and write the same block one after the other."""
     changed = 0
     for seed in range(3):
         rng = np.random.default_rng(seed)
@@ -219,6 +260,11 @@ def test_writer_kernel_is_the_scatter(dtype, quantized, s):
         starts = rng.integers(0, T * BS + 2, (b,)).astype(np.int32)
         starts[0] = T * BS                                  # parked
         starts[1] = (starts[1] // BS) * BS                  # block-aligned
+        if one_sequence:
+            tables = tables.at[jnp.asarray([2, 3, 4])].set(
+                jnp.asarray(rng.permutation(NB)[:T], jnp.int32))
+            starts[2] = seed                      # 0, 1, 2: then on from it
+            starts[[4, 3]] = starts[2] + s, starts[2] + 2 * s
         starts = jnp.asarray(starts)
         kn = jnp.asarray(rng.standard_normal((b, s, HKV, D)), dtype)
         vn = jnp.asarray(rng.standard_normal((b, s, HKV, D)), dtype)
@@ -460,8 +506,9 @@ def test_put_rounds_with_shared_prefix_blocks(params, engine):
                                 split_fuse_chunk=BLOCK, prefix_sharing=True,
                                 **ENGINES[engine])
         outs = [eng.put([1], [np.asarray(prompts[1])])]
-        for _ in range(2):     # one chunk a round: the prompt's other two
-            outs.append(eng.put([], []))
+        # the prompt's three chunks ran in that one round, three rows of
+        # the four (PR 36); nothing is pending, and an empty put is empty
+        assert list(outs[0]) == [1] and eng.put([], []) == {}
         outs.append(eng.put([2, 3], [np.asarray(prompts[2]),
                                      np.asarray(prompts[3])]))
         outs.append(eng.put([1], [[4]]))      # a decode beside their chunks

@@ -142,31 +142,36 @@ def test_compile_span_counts_the_backend_compiles_inside_it(store, tmp_path):
 # ------------------------------------------------- one scripted put sequence
 MAX_BATCH, CHUNK = 4, 8
 P_SHORT = [5, 6, 7, 8, 9]                 # 5 tokens: the lone bucketed prefill
-P_LONG = list(range(10, 30))              # 20 tokens: chunks of 8, 8, 4
+P_LONG = list(range(10, 78))              # 68 tokens: chunks of 8 x 8 and a 4
+# A chunk round is FILLED: the one prompt prefilling takes all four rows of
+# the width, 32 tokens a round, and its last four tokens one row.
 # round: token slots computed, tokens fed
 #  1 prefill bucket 32                      32, 5
 #  2 decode, 4 rows                          4, 1
-#  3 fused: 4 x 8 chunk slots + 4 rows      36, 8 + 1
-#  4 fused                                  36, 8 + 1
+#  3 fused: 4 x 8 chunk slots + 4 rows      36, 32 + 1
+#  4 fused                                  36, 32 + 1
 #  5 chunk alone                            32, 4
-SLOTS, FED, ROUNDS = 140, 28, 5
+SLOTS, FED, ROUNDS = 140, 76, 5
 # round: rows of the program, of which live (cursor below capacity)
 #  2 decode                                  4, 1 (uid 1)
-#  3 fused: 4 chunk rows + 4 decode rows     8, 1 chunk + 1: uid 2 joins in
+#  3 fused: 4 chunk rows + 4 decode rows     8, 4 chunk + 1: uid 2 joins in
 #                                               this round, and its cursor is
 #                                               still parked in the decode
 #                                               half, which runs first
-#  4 fused                                   8, 1 chunk + 2: uid 2 rides the
+#  4 fused                                   8, 4 chunk + 2: uid 2 rides the
 #                                               decode half mid-prefill
 #  5 chunk alone                             4, 1
-ROWS = [(4, 1), (8, 2), (8, 3), (4, 1)]
+ROWS = [(4, 1), (8, 5), (8, 6), (4, 1)]
 PARKED = sum(rows - live for rows, live in ROWS)
+# chunk rounds: rows that carry tokens, the prompts they belong to, the width
+CHUNK_ROWS = [(4, 1, 4), (4, 1, 4), (1, 1, 4)]
+REFILLED = sum(rows - seqs for rows, seqs, _ in CHUNK_ROWS)
 
 
 def _script(model, params, traced, profile_dir=None):
     groups.reset_topology()
     eng = InferenceEngineV2(model, params=params, max_batch=MAX_BATCH,
-                            max_seq_len=64, split_fuse_chunk=CHUNK,
+                            max_seq_len=128, split_fuse_chunk=CHUNK,
                             cache_block_size=16, prefix_sharing=False)
     eng.tracer.force = traced
     if profile_dir:
@@ -215,7 +220,7 @@ def test_counts_are_exact_tracing_on_or_off(runs):
     parents = [s for s in runs["spans"]
                if s["name"] in ("prefill", "chunk", "decode")]
     assert [(s["fields"]["token_slots"], s["fields"]["tokens_fed"])
-            for s in parents] == [(32, 5), (4, 1), (36, 9), (36, 9), (32, 4)]
+            for s in parents] == [(32, 5), (4, 1), (36, 33), (36, 33), (32, 4)]
     assert [s["round"] for s in parents] == [1, 2, 3, 4, 5]
 
 
@@ -235,6 +240,24 @@ def test_rows_live_and_parked_add_up_to_the_programs_rows(runs):
                  f["width"] + (MAX_BATCH if f["fused"] else 0))
         assert f["rows_live"] + f["rows_parked"] == width == rows
         assert f["rows_live"] == live
+
+
+def test_chunk_spans_tell_rows_from_sequences(runs):
+    """A `chunk` span's `rows` are the rows of its `width` that carry
+    tokens and `sequences` the prompts they belong to; `rows_refilled`
+    counts, tracing on or off, the rows a prompt took beyond its first."""
+    for eng in (runs["off"], runs["on"]):
+        assert eng.serving_counters["rows_refilled"] == REFILLED
+        assert eng.telemetry_snapshot()["rows_refilled"] == REFILLED
+    chunks = [s["fields"] for s in runs["spans"] if s["name"] == "chunk"]
+    assert [(f["rows"], f["sequences"], f["width"])
+            for f in chunks] == CHUNK_ROWS
+    # every row that carries tokens is a live row of the program, and only
+    # a prompt's last row feeds less than a whole chunk
+    for f, decoding in zip(chunks, (1, 1, 0)):
+        assert f["rows_live"] - f["rows"] in (decoding, decoding + 1)
+        assert (f["rows"] - 1) * CHUNK < f["tokens_fed"] - decoding \
+            <= f["rows"] * CHUNK
 
 
 def test_tracing_off_makes_no_record_and_changes_no_output(runs):
