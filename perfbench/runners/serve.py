@@ -326,6 +326,13 @@ def collect(ctx, rec: Dict[str, Any], seconds: float) -> Dict[str, Any]:
               "out_tok_s": out_tokens / t_last if t_last > 0 else 0.0,
               "drain_s": max(0.0, t_last - seconds),
               "compiles_in_window": rec["compiles"], **tg.token_totals(window)}
+    # for a reader of untraced lines (`noise.py`): the first tokens' mean and
+    # the percentiles beside the judged one; the metrics' readers read `samples`
+    if s["ttft_ms"]:
+        counts.update(ttft_mean_ms=sum(s["ttft_ms"]) / len(s["ttft_ms"]),
+                      tpot_mean_ms=sum(s["tpot_ms"]) / len(s["tpot_ms"]),
+                      **{f"ttft_p{q}_ms": tg.percentile(s["ttft_ms"], q, n - done)
+                         for q in (50, 90)})
     return {"samples": s, "counts": counts}
 
 

@@ -13,6 +13,17 @@ with the process's local burstiness kept. (Drawing inside each stratum, at
 `(i + u_i)/N`, moved `ttft_p80_ms` by the width of the 61st stratum: 4.3%
 between seeds against 0.1% between two runs of one seed; my chip run, PR 23.)
 
+That still leaves the seed WHICH prompt stands behind which, and a tail of
+first-token times is made of just that: six seeds spread `ttft_p80_ms` by
+12-20%, and by 2-3.5% once they serve one schedule (PERF.md, PR 40). A mix
+that names
+`arrivals.cycle` (`seed`, `seconds`) takes that out as well: ONE schedule of
+`round(rate * cycle.seconds)` requests, drawn as above from the cycle's own
+seed, is laid on a circle, and `--seed` turns the circle: every seed offers
+the same requests at the same gaps behind the same neighbours, beginning at
+another of them, and the ramp is the stretch of the circle before the
+window's first. The token ids stay the seed's.
+
 Nothing here touches JAX.
 """
 
@@ -86,6 +97,37 @@ def request_count(traffic: Dict[str, Any], seconds: float) -> int:
     return int(round(float(traffic["arrivals"]["rate"]) * seconds))
 
 
+CYCLE_STREAM = 0x43594331      # the draw that turns the circle, apart from any stream's
+
+
+def cycle_slice(traffic: Dict[str, Any], seconds: float, seed: int,
+                start: float = 0.0):
+    """The stretch `[start, start + seconds)` of the mix's one schedule as
+    seed `seed` sees it: `due`, prompt lengths, output lengths, in order of
+    `due`. The schedule is `arrivals.cycle`: `N = round(rate * cycle.seconds)`
+    arrivals and sizes from `cycle.seed`, repeating every `cycle.seconds`.
+    The seed gives the moment of the circle that is the window's time 0 (one
+    draw, whatever the stream, so that a ramp runs into its window). A
+    stretch as long as the cycle holds each of the N requests once."""
+    cyc = traffic["arrivals"]["cycle"]
+    period = float(cyc["seconds"])
+    own = np.random.default_rng([int(cyc["seed"])])
+    n = request_count(traffic, period)
+    at = arrival_times(traffic["arrivals"], n, period, own)
+    plen = stratified(traffic["prompt"], n, own)
+    olen = stratified(traffic["output"], n, own)
+    turn = np.random.default_rng([int(seed), CYCLE_STREAM]).random() * period
+    first = np.mod(at - (turn + start), period)   # each one's wait from `start`
+    first[first >= period] = 0.0                  # a rounding of -0.0
+    laps = max(1, math.ceil(seconds / period))
+    wait = (first[None, :] + period * np.arange(laps)[:, None]).ravel()
+    which = np.tile(np.arange(n), laps)
+    keep = wait < seconds
+    order = np.argsort(wait[keep], kind="stable")
+    which = which[keep][order]
+    return start + wait[keep][order], plen[which], olen[which]
+
+
 def make_requests(traffic: Dict[str, Any], seconds: float, seed: int,
                   vocab: int, start: float = 0.0, uid0: int = 0,
                   stream: int = 0) -> List[Dict[str, Any]]:
@@ -94,10 +136,14 @@ def make_requests(traffic: Dict[str, Any], seconds: float, seed: int,
     `out` (tokens to generate, forced). `stream` separates the ramp's
     draws from the window's under one seed."""
     rng = np.random.default_rng([int(seed), int(stream)])
-    n = request_count(traffic, seconds)
-    due = start + arrival_times(traffic["arrivals"], n, seconds, rng)
-    plen = stratified(traffic["prompt"], n, rng)
-    olen = stratified(traffic["output"], n, rng)
+    if traffic["arrivals"].get("cycle"):
+        due, plen, olen = cycle_slice(traffic, seconds, seed, start)
+        n = len(due)
+    else:
+        n = request_count(traffic, seconds)
+        due = start + arrival_times(traffic["arrivals"], n, seconds, rng)
+        plen = stratified(traffic["prompt"], n, rng)
+        olen = stratified(traffic["output"], n, rng)
     pre = traffic.get("prefix")
     pool = []
     if pre:

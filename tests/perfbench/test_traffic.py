@@ -11,6 +11,8 @@ from perfbench import traffic as tg
 HERE = os.path.dirname(os.path.abspath(__file__))
 CHAT = json.load(open(os.path.join(HERE, "..", "..", "perfbench", "traffic",
                                    "serve-chat.json")))
+CHAT_POISSON = {**CHAT, "arrivals": {k: v for k, v in CHAT["arrivals"].items()
+                                     if k != "cycle"}}
 SEEDS = [0, 1, 7, 2147483659, 3000000019]
 VOCAB = 151936   # Qwen2.5's; a name, not a context length
 
@@ -30,9 +32,9 @@ def test_exact_count_sorted_inside_window(seed, rate, seconds):
 
 
 def test_same_seed_same_requests_other_seed_other_order():
-    a = tg.make_requests(CHAT, 45, 11, VOCAB)
-    b = tg.make_requests(CHAT, 45, 11, VOCAB)
-    c = tg.make_requests(CHAT, 45, 12, VOCAB)
+    a = tg.make_requests(CHAT_POISSON, 45, 11, VOCAB)
+    b = tg.make_requests(CHAT_POISSON, 45, 11, VOCAB)
+    c = tg.make_requests(CHAT_POISSON, 45, 12, VOCAB)
     assert all((x["prompt"] == y["prompt"]).all() and x["due"] == y["due"]
                for x, y in zip(a, b))
     assert [len(x["prompt"]) for x in a] != [len(x["prompt"]) for x in c]
@@ -43,7 +45,7 @@ def test_every_seed_offers_the_same_sizes_in_another_order():
     and so the same token totals, where independent draws from this
     heavy-tailed mix differ by several percent. The order and the arrival
     times are the seed's."""
-    runs = [tg.make_requests(CHAT, 51, s, VOCAB) for s in SEEDS]
+    runs = [tg.make_requests(CHAT_POISSON, 51, s, VOCAB) for s in SEEDS]
     assert len({json.dumps(tg.token_totals(r)) for r in runs}) == 1
     for key in ("prompt", "out"):
         size = lambda r: len(r[key]) if key == "prompt" else r[key]
@@ -60,10 +62,82 @@ def test_every_seed_offers_the_same_sizes_in_another_order():
 
 
 def test_ramp_and_window_streams_differ_under_one_seed():
-    ramp = tg.make_requests(CHAT, 6, 3, VOCAB, start=-6.0, stream=100)
-    win = tg.make_requests(CHAT, 6, 3, VOCAB, stream=0)
+    ramp = tg.make_requests(CHAT_POISSON, 6, 3, VOCAB, start=-6.0, stream=100)
+    win = tg.make_requests(CHAT_POISSON, 6, 3, VOCAB, stream=0)
     assert all(-6 <= r["due"] < 0 for r in ramp)
     assert [len(r["prompt"]) for r in ramp] != [len(r["prompt"]) for r in win]
+
+
+# ------------------------------------------------ one schedule, turned by the seed
+
+CYCLED = {**CHAT, "arrivals": {"process": "poisson", "rate": 1.5,
+                               "cycle": {"seed": 40, "seconds": 51}}}
+
+
+def _turned_to(run, head):
+    """`run` (sizes and gaps in order of `due`) begun at its first `head`."""
+    k = next(i for i, x in enumerate(run) if x[:2] == head)
+    return run[k:] + run[:k]
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_cycle_offers_every_seed_the_same_requests_behind_the_same_neighbours(seed):
+    """The same sizes AND the same gaps in the same cyclic order: only the
+    request the window begins at is the seed's."""
+    def ring(s):
+        reqs = tg.make_requests(CYCLED, 51, s, VOCAB)
+        due = [r["due"] for r in reqs]
+        assert len(reqs) == 76 and due == sorted(due) and 0 <= due[0] and due[-1] < 51
+        gaps = np.diff(due + [due[0] + 51.0])       # to the next one round the circle
+        return [(len(r["prompt"]), r["out"], g) for r, g in zip(reqs, gaps)]
+    base, mine = ring(11), ring(seed)
+    assert [x[:2] for x in mine] != [x[:2] for x in base]      # begun elsewhere
+    again = _turned_to(mine, base[0][:2])
+    assert [x[:2] for x in again] == [x[:2] for x in base]
+    assert np.allclose([x[2] for x in again], [x[2] for x in base], atol=1e-9)
+    assert tg.token_totals(tg.make_requests(CYCLED, 51, seed, VOCAB)) == \
+        tg.token_totals(tg.make_requests(CHAT_POISSON, 51, seed, VOCAB))
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_cycle_s_ramp_is_the_stretch_before_its_window(seed):
+    ramp = tg.make_requests(CYCLED, 10, seed, VOCAB, start=-10.0, stream=100)
+    win = tg.make_requests(CYCLED, 51, seed, VOCAB, stream=0)
+    assert ramp and all(-10 <= r["due"] < 0 for r in ramp)
+    tail = win[-len(ramp):]                          # the circle closes
+    assert [(len(r["prompt"]), r["out"]) for r in ramp] == \
+        [(len(r["prompt"]), r["out"]) for r in tail]
+    assert np.allclose([r["due"] + 51.0 for r in ramp], [r["due"] for r in tail])
+    # the ids are the stream's own: a ramp's prompt is no copy of the window's
+    assert not any((a["prompt"] == b["prompt"]).all() for a, b in zip(ramp, tail))
+
+
+@pytest.mark.parametrize("seconds,start", [(6.0, 0.0), (20.0, -3.0), (102.0, 0.0),
+                                           (120.5, -10.0)])
+def test_a_stretch_of_any_length_is_cut_from_the_same_circle(seconds, start):
+    whole = tg.make_requests(CYCLED, 51, 7, VOCAB)
+    part = tg.make_requests(CYCLED, seconds, 7, VOCAB, start=start)
+    due = [r["due"] for r in part]
+    assert due == sorted(due) and start <= due[0] and due[-1] < start + seconds
+    at = {round(r["due"], 6): len(r["prompt"]) for r in whole}
+    assert all(at[round(r["due"] % 51.0, 6)] == len(r["prompt"]) for r in part)
+    laps = seconds / 51.0
+    assert abs(len(part) - 76 * laps) <= 12 and (seconds != 102.0 or len(part) == 152)
+
+
+def test_a_cycle_is_the_same_seed_s_twice_and_follows_the_rate():
+    a = tg.make_requests(CYCLED, 51, SEEDS[-1], VOCAB)
+    b = tg.make_requests(CYCLED, 51, SEEDS[-1], VOCAB)
+    assert all((x["prompt"] == y["prompt"]).all() and x["due"] == y["due"]
+               for x, y in zip(a, b))
+    fast = {**CYCLED, "arrivals": {**CYCLED["arrivals"], "rate": 3.0}}
+    assert len(tg.make_requests(fast, 51, 3, VOCAB)) == 153
+
+
+def test_the_cell_s_cycle_is_as_long_as_a_run():
+    """Only then does every seed's window hold each request once."""
+    bench = json.load(open(os.path.join(HERE, "..", "..", "BENCHMARK.json")))
+    assert CHAT["arrivals"]["cycle"]["seconds"] == bench["run_seconds"]
 
 
 @pytest.mark.parametrize("cv", [1.0, 3.0])
@@ -76,7 +150,7 @@ def test_gamma_arrivals_keep_count_and_window(cv):
 
 
 def test_shared_prefix_heads_a_share_of_the_prompts():
-    tf = {**CHAT, "prefix": {"length": 2048, "share": 0.8, "pool": 2}}
+    tf = {**CHAT_POISSON, "prefix": {"length": 2048, "share": 0.8, "pool": 2}}
     reqs = tg.make_requests(tf, 45, 5, VOCAB)
     heads = {tuple(r["prompt"][:2048]) for r in reqs if len(r["prompt"]) > 2048}
     shared = sum(1 for r in reqs if len(r["prompt"]) > 2048
