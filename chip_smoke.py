@@ -72,6 +72,12 @@ class Sizes:
     gmm_experts: int
     gmm_width: int
     qmm_group: int
+    # a state-space decode step: recurrent layers, rows, heads, head dim,
+    # state size, groups (Nemotron-3-Nano's at the benchmark cell's batch)
+    ssm: Tuple[int, int, int, int, int, int]
+    # the grouped GEMM at a DECODE shape: rows, experts, K, N; 3 rows an
+    # expert, the rest of the rows no expert's (absent assignments)
+    gmm_decode: Tuple[int, int, int, int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -80,14 +86,15 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              v2_chunk=256, block=256, flash_long=32768, decode_batch=32,
              decode_ctx=1024, paged_batch=64, paged_blocks=96,
              prefill_batch=8, parked=(48, 18, 16), gmm_rows=4096,
-             gmm_experts=64, gmm_width=1024, qmm_group=256)
+             gmm_experts=64, gmm_width=1024, qmm_group=256,
+             ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
              v2_chunk=16, block=16, flash_long=128, decode_batch=2,
              decode_ctx=64, paged_batch=3, paged_blocks=9, prefill_batch=2,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
-             qmm_group=32)
+             qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -154,6 +161,8 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_kv_write, paged_prefill_attention)
     from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
+    from deepspeed_tpu.ops.pallas.ssm import (ssm_state_update,
+                                              ssm_state_update_reference)
     from deepspeed_tpu.ops.quantization import (dequantize_int8_blockwise,
                                                 quantize_int8_blockwise)
     from deepspeed_tpu.ops.sparse_attention import BigBirdSparsityConfig
@@ -422,6 +431,52 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         return out.reshape(rows, width).astype(lhs.dtype)
 
     cases.append(KernelCase("grouped_gemm", grouped_gemm, gmm_ref, make_gmm))
+
+    # the same kernel as a held expert layer calls it at decode: a 16-row
+    # tile, 3 rows an expert, and rows after the last group that are no
+    # expert's and must cost nothing (moe/sharded_moe.held_dispatch_gmm)
+    drows, dne, dk, dn = sz.gmm_decode
+
+    def make_gmm_decode(key):
+        kl, kr = jax.random.split(key)
+        return (normal(kl, (drows, dk)), normal(kr, (dne, dk, dn)) * 0.05,
+                jnp.full((dne,), 3, jnp.int32))
+
+    def held_rows(out):
+        return jnp.where(jnp.arange(drows)[:, None] < 3 * dne, out, 0)
+
+    def gmm_decode(lhs, rhs, sizes):
+        return held_rows(grouped_gemm(lhs, rhs, sizes,
+                                      tiling=(16, min(dk, 1024), min(dn, 1024))))
+
+    def gmm_decode_ref(lhs, rhs, sizes):
+        w = jnp.repeat(rhs, 3, axis=0, total_repeat_length=3 * dne)
+        out = jnp.einsum("mk,mkn->mn", lhs[:3 * dne], w,
+                         preferred_element_type=jnp.float32)
+        return held_rows(jnp.pad(out, ((0, drows - 3 * dne), (0, 0)))
+                         ).astype(lhs.dtype)
+
+    cases.append(KernelCase("grouped_gemm_decode", gmm_decode, gmm_decode_ref,
+                            make_gmm_decode))
+
+    # ---- the recurrent-state update of a Mamba-2 decode step ----
+    sl, sb, sh, sp, sn, sg = sz.ssm
+
+    def make_ssm(key):
+        ks = jax.random.split(key, 7)
+        f32 = jnp.float32
+        return (normal(ks[0], (sl, sb, sh, sp, sn), f32),
+                normal(ks[1], (sb, sh, sp), f32),
+                jax.nn.softplus(normal(ks[2], (sb, sh), f32) - 3.0),
+                -jnp.exp(normal(ks[3], (sh,), f32)),
+                normal(ks[4], (sb, sg, sn), f32),
+                normal(ks[5], (sb, sg, sn), f32), normal(ks[6], (sh,), f32))
+
+    cases.append(KernelCase(
+        "ssm_state_update",
+        lambda state, *rest: ssm_state_update(state, sl - 1, *rest),
+        lambda state, *rest: ssm_state_update_reference(state, sl - 1, *rest),
+        make_ssm))
 
     # ---- block-sparse attention (MHA; layout is static host data) ----
     sblk = min(64, sz.seq // 4)

@@ -93,6 +93,15 @@ def kv_cache_bytes(model_cfg, batch: int, max_len: int, dtype,
     return slots * d["head_dim"] * item
 
 
+def recurrent_state_bytes(model_cfg, batch: int, dtype) -> int:
+    """Bytes the model's recurrent layers hold for `batch` sequences
+    (`kv_cache.RecurrentState`): fixed a sequence, whatever its length, and
+    counted apart from K and V. 0 for a model that has no such layer; one
+    that has says so itself (`model_cfg.recurrent_state_bytes`)."""
+    own = getattr(model_cfg, "recurrent_state_bytes", None)
+    return int(own(batch, dtype)) if own is not None else 0
+
+
 def decode_workspace_bytes(model_cfg, batch: int, max_len: int, dtype) -> int:
     """Transient activation bytes one generate keeps live beside weights and
     KV: the block body's widest activations (h, normed h, and the MLP

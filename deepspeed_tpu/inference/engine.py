@@ -33,7 +33,9 @@ from deepspeed_tpu.utils.logging import logger, warn_once
 def _cache_dims(cfg) -> tuple:
     """(num_layers, kv_heads, head_dim) from a zoo model config (duck-typed
     over llama/gpt2/mixtral naming)."""
-    layers = getattr(cfg, "num_hidden_layers", None) or getattr(cfg, "n_layer")
+    layers = getattr(cfg, "num_kv_layers", None)   # a model of mixed layers
+    if layers is None:
+        layers = getattr(cfg, "num_hidden_layers", None) or getattr(cfg, "n_layer")
     heads = (getattr(cfg, "num_key_value_heads", None)
              or getattr(cfg, "num_kv_heads", None)  # falcon naming
              or getattr(cfg, "num_attention_heads", None) or getattr(cfg, "n_head"))
@@ -363,11 +365,23 @@ class InferenceEngine:
                                 (self.params, input_ids, rng))
         t0 = _time.perf_counter()
         with annotate("ds:generate"):
-            out = np.asarray(
-                self._generate_jit[key](self.params, input_ids, rng))
+            out = self._generate_jit[key](self.params, input_ids, rng)
+            counted = {}
+            if isinstance(out, tuple):   # (sequences, the model's counters)
+                out, counted = jax.device_get(out)   # one fetch for both
+                counted = {k: int(v) for k, v in counted.items()}
+            out = np.asarray(out)
         dt = _time.perf_counter() - t0
         self.last_decode_tok_s = (b * new_tokens / dt) if dt > 0 else None
         hub = get_hub()
+        kv = self._kv_telemetry(b, key[1], key[2])
+        # gauges and counters update on a disabled hub too (hub.py): what a
+        # benchmark reads of a call without the JSONL stream
+        for name in ("kv_bytes", "state_bytes"):
+            if name in kv:
+                hub.gauge(f"serving_v1/{name}", kv[name])
+        for name, value in counted.items():
+            hub.counter(f"serving_v1/{name}", value)
         if hub.enabled:
             wb, wb_dense = self._weight_bytes_per_step()
             extra = {}
@@ -387,8 +401,7 @@ class InferenceEngine:
                      weight_bytes_step_dense=wb_dense,
                      recompiles=self.recompiles.misses,
                      pinned_recompiles=self.recompiles.pinned_misses,
-                     **self._kv_telemetry(b, key[1], key[2]),
-                     **extra)
+                     **kv, **counted, **extra)
         return out
 
     def _kv_telemetry(self, b, s, new_tokens):
@@ -397,8 +410,8 @@ class InferenceEngine:
         kv_dtype is the EFFECTIVE at-rest element type: 'int8' only when
         the config asks for it AND this serve mode quantizes its cache
         (the layer-streamed modes keep dense KV, engine __init__ warns)."""
-        from deepspeed_tpu.inference.capacity_scan import (kv_cache_bytes,
-                                                           round_up_len)
+        from deepspeed_tpu.inference.capacity_scan import (
+            kv_cache_bytes, recurrent_state_bytes, round_up_len)
         mode = getattr(self, "serve_mode", "dequant")
         kvd = getattr(self._config, "kv_cache_dtype", None)
         eff = kvd if (kvd == "int8" and mode == "dequant") else None
@@ -408,8 +421,12 @@ class InferenceEngine:
                                   self._config.dtype, kv_dtype=eff)
         except Exception:
             return {}  # non-standard config dims: skip, never break serving
+        # K and V of the ATTENTION layers; what recurrent layers hold is
+        # counted apart (0 for a model that has none)
         return {"kv_dtype": eff or jnp.dtype(self._config.dtype).name,
-                "kv_bytes": int(kv_b)}
+                "kv_bytes": int(kv_b),
+                "state_bytes": recurrent_state_bytes(
+                    self.model_cfg, int(b), self._config.dtype)}
 
     def _register_serving_residency(self, key):
         """MemoryPlane rows for one generate key — the KV cache is created
@@ -417,7 +434,8 @@ class InferenceEngine:
         formulas the auto serve-mode accounting uses (host arithmetic
         only; generate-dispatch level, never per decode step)."""
         from deepspeed_tpu.inference.capacity_scan import (
-            decode_workspace_bytes, kv_cache_bytes, round_up_len)
+            decode_workspace_bytes, kv_cache_bytes, recurrent_state_bytes,
+            round_up_len)
         from deepspeed_tpu.telemetry.memory import get_plane, owner_for
         b, s, new_tokens = int(key[0]), int(key[1]), int(key[2])
         mode = getattr(self, "serve_mode", "dequant")
@@ -437,6 +455,11 @@ class InferenceEngine:
                        tier="hbm", nbytes=int(kv_b), owner=owner)
         plane.register(f"{owner}:workspace", component="workspace",
                        tier="hbm", nbytes=int(ws_b), owner=owner)
+        state_b = recurrent_state_bytes(self.model_cfg, b, self._config.dtype)
+        if state_b:
+            plane.register(f"{owner}:recurrent_state",
+                           component="recurrent_state", tier="hbm",
+                           nbytes=state_b, owner=owner)
 
     def _weight_bytes_per_step(self):
         """(at-rest, dense-equivalent) weight bytes one decode step reads —
@@ -527,12 +550,37 @@ class InferenceEngine:
                                  top_k=top_k, top_p=top_p)
 
         kv_int8 = getattr(cfg, "kv_cache_dtype", None) == "int8"
+        # a model whose layers keep more than K and V builds its own cache
+        # (kv_cache.HybridCache) and may count inside the program: its
+        # `program_counters` are summed over the call and returned with the
+        # sequences. Every other model's program is what it was.
+        make_cache = getattr(model, "make_cache", None)
+        counted = tuple(getattr(model, "program_counters", ()))
+
+        def forward(params, ids, cache, counts):
+            if not counted:
+                return model.apply({"params": params}, ids, cache=cache) \
+                    + (counts,)
+            (logits, cache), sown = model.apply(
+                {"params": params}, ids, cache=cache, mutable=["counters"])
+            sown = sown.get("counters", {})
+            return logits, cache, {
+                name: counts[name] + sum(
+                    jnp.sum(v) for path, v in
+                    jax.tree_util.tree_leaves_with_path(sown)
+                    if any(getattr(p, "key", None) == name for p in path))
+                for name in counted}
 
         def gen(params, ids, rng):
             params = self._maybe_dequant(params)
-            cache = KVCache.create(layers, b, max_len, kv_heads, head_dim,
-                                   dtype=cfg.dtype, quantized=kv_int8)
-            logits, cache = model.apply({"params": params}, ids, cache=cache)
+            if make_cache is not None:
+                cache = make_cache(b, max_len, dtype=cfg.dtype,
+                                   quantized=kv_int8)
+            else:
+                cache = KVCache.create(layers, b, max_len, kv_heads, head_dim,
+                                       dtype=cfg.dtype, quantized=kv_int8)
+            counts = {name: jnp.zeros((), jnp.int32) for name in counted}
+            logits, cache, counts = forward(params, ids, cache, counts)
             rng, sub = jax.random.split(rng)
             tok = sample(logits[:, -1, :], sub)
             done = jnp.zeros((b,), jnp.bool_)
@@ -540,22 +588,23 @@ class InferenceEngine:
                 done = tok == eos_token_id
 
             def step(carry, rng_i):
-                cache, tok, done = carry
-                logits, cache = model.apply({"params": params}, tok[:, None],
-                                            cache=cache)
+                cache, tok, done, counts = carry
+                logits, cache, counts = forward(params, tok[:, None], cache,
+                                                counts)
                 nxt = sample(logits[:, -1, :], rng_i)
                 if eos_token_id is not None:
                     nxt = jnp.where(done, pad_token_id, nxt)
                     done = done | (nxt == eos_token_id)
-                return (cache, nxt, done), tok
+                return (cache, nxt, done, counts), tok
 
             keys = jax.random.split(rng, max_new_tokens - 1) if max_new_tokens > 1 \
                 else jnp.zeros((0, 2), jnp.uint32)
-            (cache, last, done), toks = jax.lax.scan(
-                step, (cache, tok, done), keys)
+            (cache, last, done, counts), toks = jax.lax.scan(
+                step, (cache, tok, done, counts), keys)
             new = jnp.concatenate([toks.T, last[:, None]], axis=1) \
                 if max_new_tokens > 1 else last[:, None]
-            return jnp.concatenate([ids, new], axis=1)
+            out = jnp.concatenate([ids, new], axis=1)
+            return (out, counts) if counted else out
 
         # `jit_ds_v1_generate` on the device trace's `XLA Modules` line
         gen.__name__ = "ds_v1_generate"

@@ -127,6 +127,90 @@ class KVCache:
 
 
 @struct.dataclass
+class RecurrentState:
+    """What the recurrent (state-space) layers of a model keep between
+    tokens, stacked over THOSE layers only (docs/kv_cache.md): a fixed size a
+    sequence, whatever its length.
+
+    `ssm` (Lm, B, H, P, N) float32: a Mamba-2 head's state is `P x N`. It is
+    float32 at rest: the recurrence multiplies it by a decay just under 1 and
+    adds a small term every token, and bf16's 8 bits lose that term over a
+    few hundred steps. `ops/pallas/ssm.ssm_state_update` reads and writes one
+    layer of it in place a decode step; prefill writes a layer's slab whole.
+    `conv` (Lm, B, K - 1, C): the last K - 1 inputs of the causal depthwise
+    convolution, in the compute dtype."""
+
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+
+    @classmethod
+    def create(cls, num_layers: int, batch: int, heads: int, head_dim: int,
+               state_size: int, conv_kernel: int, conv_dim: int,
+               dtype: Any = jnp.bfloat16) -> "RecurrentState":
+        return cls(
+            ssm=jnp.zeros((num_layers, batch, heads, head_dim, state_size),
+                          jnp.float32),
+            conv=jnp.zeros((num_layers, batch, conv_kernel - 1, conv_dim),
+                           dtype))
+
+    @staticmethod
+    def nbytes(num_layers: int, batch: int, heads: int, head_dim: int,
+               state_size: int, conv_kernel: int, conv_dim: int,
+               dtype: Any = jnp.bfloat16) -> int:
+        """Bytes `create` would hold: host arithmetic for the telemetry and
+        the serve-mode accounting."""
+        return num_layers * batch * (
+            heads * head_dim * state_size * 4
+            + (conv_kernel - 1) * conv_dim * jnp.dtype(dtype).itemsize)
+
+
+@struct.dataclass
+class HybridCache:
+    """The cache of a model whose layers are of different kinds: a `KVCache`
+    over its ATTENTION layers only and a `RecurrentState` over its recurrent
+    ones, one pytree that the decode scan carries. Layers that keep nothing
+    (expert, dense FFN) have no row in either. The cursors are the
+    `KVCache`'s; `index`, `max_len` and `replace` read as a `KVCache`'s do,
+    so the engine handles it as it handles that."""
+
+    kv: KVCache
+    state: RecurrentState
+
+    @property
+    def index(self) -> jnp.ndarray:
+        return self.kv.index
+
+    @property
+    def max_len(self) -> int:
+        return self.kv.max_len
+
+    def advance(self, s: int) -> "HybridCache":
+        return self.replace(kv=self.kv.replace(index=self.kv.index + s))
+
+    def rows(self, start, count: int) -> "HybridCache":
+        """The cache of sequences `start .. start + count - 1` alone (`start`
+        may be traced): what a prefill that walks the batch a few rows at a
+        time hands the layers."""
+        def cut(t, axis=1):
+            return jax.lax.dynamic_slice_in_dim(t, start, count, axis)
+        return HybridCache(
+            kv=KVCache(k=cut(self.kv.k), v=cut(self.kv.v),
+                       index=cut(self.kv.index, 0)),
+            state=RecurrentState(ssm=cut(self.state.ssm),
+                                 conv=cut(self.state.conv)))
+
+    def with_rows(self, part: "HybridCache", start) -> "HybridCache":
+        """This cache with `part` (from `rows`) written back at `start`."""
+        def put(t, new, axis=1):
+            return jax.lax.dynamic_update_slice_in_dim(t, new, start, axis)
+        return HybridCache(
+            kv=KVCache(k=put(self.kv.k, part.kv.k), v=put(self.kv.v, part.kv.v),
+                       index=put(self.kv.index, part.kv.index, 0)),
+            state=RecurrentState(ssm=put(self.state.ssm, part.state.ssm),
+                                 conv=put(self.state.conv, part.state.conv)))
+
+
+@struct.dataclass
 class PagedLayer:
     """One layer's view of the block-paged cache: a pool of physical blocks
     plus the per-sequence block tables that map logical positions onto them

@@ -116,6 +116,15 @@ def resolve_serve_mode(engine, params) -> str:
                     and sharded_kernels_supported())
     scan_ok = layout_ok and (not multi_dev or tp_shardable)
     cap_ok = layout_ok and not multi_dev
+    if mode in ("layer_scan", "capacity") and \
+            getattr(engine.module, "make_cache", None) is not None:
+        # both stream a llama-layout stack a layer at a time and carry raw
+        # (K, V) through it: a model that keeps its own kind of cache is
+        # refused by name, never served by something else in silence
+        raise ValueError(
+            f"init_inference: serve_mode={mode!r} streams llama-layout trees; "
+            f"{type(engine.module).__name__} keeps its own cache and is "
+            "served device-resident (serve_mode 'dequant' or 'auto')")
     if mode == "layer_scan" and not scan_ok:
         if layout_ok and multi_dev:
             kernel_fallback(
@@ -149,7 +158,8 @@ def resolve_serve_mode(engine, params) -> str:
         return mode
     # ---- byte accounting for the auto decision table ----
     from deepspeed_tpu.inference.capacity_scan import (
-        decode_workspace_bytes, kv_cache_bytes, round_up_len)
+        decode_workspace_bytes, kv_cache_bytes, recurrent_state_bytes,
+        round_up_len)
     from deepspeed_tpu.inference.quantization import is_quantized_leaf
     itemsize = jnp.dtype(config.dtype).itemsize
     dense = int8 = 0
@@ -191,8 +201,11 @@ def resolve_serve_mode(engine, params) -> str:
         quantized=engine._quantized, layout_ok=layout_ok,
         multi_device=multi_dev, dense_bytes=dense, int8_bytes=int8,
         layer_bytes=dense // max(1, int(num_layers)),
+        # what the cache holds: K and V of the attention layers and, for a
+        # model that has them, the recurrent layers' state
         kv_bytes=kv_cache_bytes(engine.model_cfg, b, max_len,
-                                config.dtype, kv_dtype=kv_dtype),
+                                config.dtype, kv_dtype=kv_dtype)
+        + recurrent_state_bytes(engine.model_cfg, b, config.dtype),
         workspace_bytes=decode_workspace_bytes(
             engine.model_cfg, b, max_len, config.dtype),
         hbm_bytes=hbm,

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.moe.sharded_moe import (
     _gating_core, dispatch_combine, dispatch_combine_gmm,
-    dispatch_combine_ragged, topkgating)
+    dispatch_combine_ragged, held_dispatch_gmm, held_dispatch_ragged,
+    route_topk, topkgating)
 from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 
@@ -67,6 +68,19 @@ def is_moe_param_path(path) -> bool:
                for p in path)
 
 
+def _activate(up, gate, activation: str):
+    """The FFN's middle: `silu` is gated (mixtral-style), `gelu` plain,
+    `relu2` is relu squared with no gate (Nemotron-H), squared in float32."""
+    if activation == "silu":
+        return nn.silu(gate) * up
+    if activation == "gelu":
+        return nn.gelu(up)
+    if activation == "relu2":
+        return jnp.square(nn.relu(up.astype(jnp.float32))).astype(up.dtype)
+    raise ValueError(f"Experts.activation {activation!r}: 'silu' (gated), "
+                     "'gelu' or 'relu2'")
+
+
 class Experts(nn.Module):
     """Batched expert FFNs (E, ...) — reference moe/experts.py, computed as a
     single grouped matmul over the expert-sharded leading axis (the Pallas/
@@ -75,14 +89,17 @@ class Experts(nn.Module):
     hidden_size: int
     intermediate_size: int
     dtype: Any = jnp.bfloat16
-    activation: str = "silu"  # silu → gated (mixtral-style); gelu → plain
+    activation: str = "silu"  # silu: gated (mixtral-style); gelu, relu2: plain
 
     @nn.compact
-    def __call__(self, x, group_sizes=None):
+    def __call__(self, x, group_sizes=None, tm: Optional[int] = None):
         """Batched form: x (E, C, D) → (E, C, D). Grouped form (when
         `group_sizes` is given): x (M, D) rows sorted by expert, each
         expert's span through its FFN as megablox grouped GEMMs — same
-        params, no (E, C) padding."""
+        params, no (E, C) padding; rows past the last group are no group's
+        and are not computed. `tm`: the grouped kernel's row tile where the
+        caller knows how many rows an expert expects (its default suits
+        thousands of rows an expert)."""
         e, d, f = self.num_experts, self.hidden_size, self.intermediate_size
         init = nn.with_logical_partitioning(nn.initializers.normal(0.02),
                                             ("expert", "embed", "mlp"))
@@ -112,20 +129,19 @@ class Experts(nn.Module):
                 # a Pallas call is not a dot, so plain checkpoint_dots
                 # recomputes the whole grouped FFN in backward
                 # (remat_policy='checkpoint_dots_gmm' in models/llama.py)
-                out = (sharded_grouped_gemm(lhs, rhs, group_sizes, mesh)
+                tiling = None if tm is None else (
+                    tm, min(lhs.shape[1], 1024), min(rhs.shape[2], 1024))
+                out = (sharded_grouped_gemm(lhs, rhs, group_sizes, mesh,
+                                            tiling=tiling)
                        if mesh is not None
-                       else grouped_gemm(lhs, rhs, group_sizes))
+                       else grouped_gemm(lhs, rhs, group_sizes, tiling=tiling))
                 return checkpoint_name(out, "moe_gmm")
-            if self.activation == "silu":
-                h = nn.silu(gg(x, w_gate)) * gg(x, w_up)
-            else:
-                h = nn.gelu(gg(x, w_up))
-            return gg(h, w_down)
-        if self.activation == "silu":
-            h = nn.silu(jnp.einsum("ecd,edf->ecf", x, w_gate)) * \
-                jnp.einsum("ecd,edf->ecf", x, w_up)
-        else:
-            h = nn.gelu(jnp.einsum("ecd,edf->ecf", x, w_up))
+            gate = None if w_gate is None else gg(x, w_gate)
+            return gg(_activate(gg(x, w_up), gate, self.activation), w_down)
+        gate = None if w_gate is None else \
+            jnp.einsum("ecd,edf->ecf", x, w_gate)
+        h = _activate(jnp.einsum("ecd,edf->ecf", x, w_up), gate,
+                      self.activation)
         return jnp.einsum("ecf,efd->ecd", h, w_down)
 
 
@@ -142,23 +158,42 @@ class TopKGate(nn.Module):
     # (HF norm_topk_prob); True = mixtral/reference renormalize-over-kept
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
+    # how an expert is scored: 'softmax' over all of them, or 'sigmoid',
+    # each alone (DeepSeek-V3, Nemotron-H)
+    score_fn: str = "softmax"
+    # a learned (E,) bias added to the scores for the CHOICE only, never to
+    # the weights (`e_score_correction_bias`); the parameter `bias`
+    selection_bias: bool = False
+    bias_init: Callable = nn.initializers.zeros_init()
+    scale: float = 1.0      # `routed_scaling_factor`, on the weights
 
     @nn.compact
-    def __call__(self, x, train: bool = True, noise_rng=None, ragged: bool = False):
+    def __call__(self, x, train: bool = True, noise_rng=None,
+                 ragged: bool = False, routed_only: bool = False):
         wg = self.param("wg", nn.with_logical_partitioning(
             nn.initializers.normal(0.02), ("embed", None)),
             (x.shape[-1], self.num_experts), jnp.float32)
+        bias = self.param("bias", nn.with_logical_partitioning(
+            self.bias_init, (None,)), (self.num_experts,), jnp.float32) \
+            if self.selection_bias else None
         logits = (x.astype(jnp.float32) @ wg)
+        if routed_only:
+            # (weights (T, k), expert ids (T, k)) with no capacity: for a
+            # layer that drops nothing
+            return route_topk(logits, self.k, self.score_fn, bias,
+                              self.norm_topk_prob, self.scale)
         cf = self.capacity_factor if train else self.eval_capacity_factor
         policy = self.noisy_gate_policy if train else None
+        scoring = dict(score_fn=self.score_fn, select_bias=bias,
+                       scale=self.scale)
         if ragged:
             l_aux, gate_k, topk_idx, pos_k, kept, _, cap = _gating_core(
                 logits, self.k, cf, self.min_capacity, self.drop_tokens,
-                noise_rng, policy, self.norm_topk_prob)
+                noise_rng, policy, self.norm_topk_prob, **scoring)
             return l_aux, gate_k, topk_idx, pos_k, kept, cap
         return topkgating(logits, self.k, cf, self.min_capacity,
                           self.drop_tokens, noise_rng, policy,
-                          self.norm_topk_prob)
+                          self.norm_topk_prob, **scoring)
 
 
 class MoE(nn.Module):
@@ -189,9 +224,26 @@ class MoE(nn.Module):
     # GSPMD-partitionable (the EP path). 'einsum': the dense one-hot
     # formulation, O(T·E·C·D) — kept as the golden reference.
     dispatch_impl: str = "auto"
+    # the router (TopKGate): score function, selection bias, weight scale
+    score_fn: str = "softmax"
+    selection_bias: bool = False
+    bias_init: Callable = nn.initializers.zeros_init()
+    routed_scaling_factor: float = 1.0
+    # A layer that is TOLD which experts it holds: `held_experts` of the
+    # `num_experts` the router scores, from `held_offset` on (one chip's
+    # share under expert parallelism, the model-configs guide's section 4).
+    # It routes over all of them, drops the assignments to absent experts
+    # before dispatch, computes its own experts' part of the result and
+    # stands in for nobody: what the absent ones would add is left out.
+    # Nothing is dropped by capacity. None: every expert is held (above).
+    held_offset: int = 0
+    held_experts: Optional[int] = None
+    # a shared expert of this width beside the routed ones, same activation,
+    # run for every token and added once
+    shared_intermediate_size: Optional[int] = None
 
     @nn.compact
-    def __call__(self, hidden_states, train: bool = True):
+    def __call__(self, hidden_states, train: bool = True, valid=None):
         b, s, d = hidden_states.shape
         f = self.intermediate_size or 4 * d
         x = hidden_states.reshape(b * s, d)
@@ -200,9 +252,15 @@ class MoE(nn.Module):
         gate = TopKGate(self.num_experts, self.k, self.capacity_factor,
                         self.eval_capacity_factor, self.min_capacity,
                         self.drop_tokens, self.noisy_gate_policy,
-                        self.norm_topk_prob, self.dtype, name="gate")
+                        self.norm_topk_prob, self.dtype, self.score_fn,
+                        self.selection_bias, self.bias_init,
+                        self.routed_scaling_factor, name="gate")
         noise_rng = self.make_rng("gating") if self.has_rng("gating") else None
 
+        if self.held_experts is not None:
+            out = self._held(x, gate, f, None if valid is None
+                             else valid.reshape(b * s))
+            return out.reshape(b, s, d)
         experts = Experts(self.num_experts, d, f, self.dtype,
                           self.activation, name="experts")
         impl = self.dispatch_impl
@@ -277,3 +335,48 @@ class MoE(nn.Module):
                  init_fn=lambda: jnp.zeros([], jnp.float32),
                  reduce_fn=lambda a, b_: a + b_)
         return out.reshape(b, s, d)
+
+    def _held(self, x, gate, f, valid):
+        """This chip's part of the layer for tokens x (T, D): the held
+        experts' weighted outputs plus the shared expert. Sows the call's
+        `assignments` and `held_assignments` (collection `counters`)."""
+        t, d = x.shape
+        count, k = self.held_experts, self.k
+        experts = Experts(count, d, f, self.dtype, self.activation,
+                          name="experts")
+        gate_k, topk_idx = gate(x, routed_only=True)
+        impl = self.dispatch_impl
+        if impl == "auto":
+            # The grouped GEMM wherever the bare kernel may run (one device),
+            # at every size; a partitioned mesh takes the XLA buffer path.
+            # Measured on the chip at the decode shape (64 rows, 64 held of
+            # 128, top 6, 60 experts touched; PERF.md, PR 41): ALONE the buffer
+            # path's batched matmul is quicker, 1.88 against 2.16 ms, but in a
+            # program whose prefill runs the grouped GEMM it wants the experts'
+            # weights in another layout and the compiler keeps a second copy
+            # of them (temporaries 2.37 -> 5.88 GB beside 9.3 GB of weights).
+            impl = "gmm" if _unpartitioned_mesh() else "ragged"
+        if impl == "gmm":
+            # an m tile about the rows an expert expects, 16 (Mosaic's bf16
+            # minimum) at decode: a tile is then one expert's alone
+            tm = max(16, min(512, 1 << (t * k // count).bit_length()))
+            out, held = held_dispatch_gmm(
+                x, gate_k, topk_idx, self.held_offset, count,
+                lambda rows, sizes: experts(rows, sizes, tm), valid)
+        elif impl == "ragged":
+            out, held = held_dispatch_ragged(
+                x, gate_k, topk_idx, self.held_offset, count,
+                experts, valid)
+        else:
+            raise ValueError(f"a layer with held_experts dispatches by 'gmm' "
+                             f"or 'ragged', not {impl!r}")
+        if self.shared_intermediate_size:
+            shared = Experts(1, d, self.shared_intermediate_size, self.dtype,
+                             self.activation, name="shared_expert")
+            out = out + shared(x[None])[0].astype(jnp.float32)
+        total = t * k if valid is None else k * jnp.sum(valid.astype(jnp.int32))
+        for name, value in (("assignments", total), ("held_assignments", held)):
+            self.sow("counters", name, jnp.asarray(value, jnp.int32),
+                     init_fn=lambda: jnp.zeros([], jnp.int32),
+                     reduce_fn=lambda a, b_: a + b_)
+        return out.astype(x.dtype)

@@ -34,9 +34,49 @@ def _one_hot(idx, n):
     return jax.nn.one_hot(idx, n, dtype=jnp.float32)
 
 
+def route_scores(logits: jnp.ndarray, score_fn: str = "softmax",
+                 select_bias: Optional[jnp.ndarray] = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(scores, what the top-k is taken of), both (T, E) float32. `softmax`
+    scores are chosen by their logits, as they always were; `sigmoid`
+    scores (DeepSeek-V3, Nemotron-H) by the scores themselves, each expert
+    scored alone. A `select_bias` (E,) is added for the CHOICE only
+    (`e_score_correction_bias`): the weights stay the unbiased scores."""
+    logits = logits.astype(jnp.float32)
+    if score_fn == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        chosen_by = logits if select_bias is None else scores
+    elif score_fn == "sigmoid":
+        chosen_by = scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score_fn {score_fn!r}: 'softmax' or 'sigmoid'")
+    if select_bias is not None:
+        chosen_by = chosen_by + select_bias.astype(jnp.float32)
+    return scores, chosen_by
+
+
+def route_topk(logits: jnp.ndarray, k: int, score_fn: str = "softmax",
+               select_bias: Optional[jnp.ndarray] = None,
+               norm_topk_prob: bool = True, scale: float = 1.0
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k routing with no capacity: (weights (T, k) float32, expert ids
+    (T, k)). The weights are the scores of the chosen experts, over their
+    sum where `norm_topk_prob`, times `scale` (`routed_scaling_factor`)."""
+    scores, chosen_by = route_scores(logits, score_fn, select_bias)
+    _, topk_idx = jax.lax.top_k(chosen_by, k)
+    gate_k = jnp.take_along_axis(scores, topk_idx, axis=-1)
+    if norm_topk_prob:
+        gate_k = gate_k / jnp.maximum(
+            jnp.sum(gate_k, axis=-1, keepdims=True), 1e-20)
+    return gate_k * scale, topk_idx
+
+
 def _gating_core(logits: jnp.ndarray, k: int, capacity_factor: float,
                  min_capacity: int, drop_tokens: bool,
-                 noise_rng, noisy_gate_policy, norm_topk_prob: bool = True):
+                 noise_rng, noisy_gate_policy, norm_topk_prob: bool = True,
+                 score_fn: str = "softmax",
+                 select_bias: Optional[jnp.ndarray] = None,
+                 scale: float = 1.0):
     """Shared top-k decisions. Returns (l_aux, gate_k (T,k), topk_idx (T,k),
     pos_k (T,k), kept (T,k), masks (T,k,E), cap). Both the einsum and the
     ragged dispatch consume exactly these decisions."""
@@ -44,11 +84,11 @@ def _gating_core(logits: jnp.ndarray, k: int, capacity_factor: float,
     cap = _capacity(t, e, capacity_factor, min_capacity, k)
     if not drop_tokens:
         cap = t  # every token can fit
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-    select_from = logits
+    gates, select_from = route_scores(logits, score_fn, select_bias)
+    if score_fn == "softmax" and select_bias is None:
+        select_from = logits             # in the logits' own dtype, as before
     if noisy_gate_policy == "RSample" and noise_rng is not None:
-        select_from = logits + jax.random.gumbel(noise_rng, logits.shape)
+        select_from = select_from + jax.random.gumbel(noise_rng, logits.shape)
 
     # top-k expert ids per token
     _, topk_idx = jax.lax.top_k(select_from, k)          # (T, k)
@@ -74,6 +114,8 @@ def _gating_core(logits: jnp.ndarray, k: int, capacity_factor: float,
     if norm_topk_prob:
         denom = jnp.sum(gate_k, axis=-1, keepdims=True)
         gate_k = gate_k / jnp.maximum(denom, 1e-9)
+    if scale != 1.0:
+        gate_k = gate_k * scale
 
     pos_k = jnp.sum(pos * masks, axis=-1).astype(jnp.int32)      # (T, k)
     return l_aux, gate_k, topk_idx, pos_k, kept, masks, cap
@@ -86,7 +128,7 @@ def topkgating(logits: jnp.ndarray,
                drop_tokens: bool = True,
                noise_rng: Optional[jax.Array] = None,
                noisy_gate_policy: Optional[str] = None,
-               norm_topk_prob: bool = True
+               norm_topk_prob: bool = True, **scoring
                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, int]:
     """Generalized top-k gating (reference topkgating:374; top1/top2 are k=1,2).
 
@@ -95,7 +137,7 @@ def topkgating(logits: jnp.ndarray,
     at scale."""
     l_aux, gate_k, topk_idx, pos_k, kept, masks, cap = _gating_core(
         logits, k, capacity_factor, min_capacity, drop_tokens, noise_rng,
-        noisy_gate_policy, norm_topk_prob)
+        noisy_gate_policy, norm_topk_prob, **scoring)
     loc = _one_hot(pos_k, cap)                                   # (T, k, C)
     combine = jnp.einsum("tk,tke,tkc->tec", gate_k, masks, loc)  # (T, E, C)
     dispatch = combine > 0
@@ -213,3 +255,72 @@ def dispatch_combine_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,
     flat = expert_outputs.reshape(num_experts * cap, d)
     out_k = jnp.take(flat, dest, axis=0, mode="fill", fill_value=0)  # (T, k, D)
     return jnp.einsum("tk,tkd->td", gate_k.astype(x.dtype), out_k)
+
+
+# ------------------------------------------------- a chip's share of experts
+
+
+def held_assignments(topk_idx: jnp.ndarray, offset: int, count: int,
+                     valid: Optional[jnp.ndarray] = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Of the (T, k) assignments, those that fall on the `count` experts
+    from `offset` on, which this layer holds: (held (T, k) bool, the held
+    expert's LOCAL id, `count` where absent). A row of `valid` (T,) that is
+    False (padding) has no assignment at all."""
+    local = topk_idx - offset
+    held = jnp.logical_and(local >= 0, local < count)
+    if valid is not None:
+        held = jnp.logical_and(held, valid[:, None])
+    return held, jnp.where(held, local, count)
+
+
+def held_dispatch_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
+                      topk_idx: jnp.ndarray, offset: int, count: int,
+                      grouped_fn, valid: Optional[jnp.ndarray] = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`dispatch_combine_gmm` for a layer that holds experts `offset ..
+    offset + count - 1` of those the router scores: the rows of the held
+    assignments sorted by held expert, the absent ones after them and
+    OUTSIDE every group, so that they are no row of any GEMM (the grouped
+    kernel's grid ends with the last group). Returns (this chip's part of
+    the layer's result (T, D) float32, the number of held assignments)."""
+    t, d = x.shape
+    k = topk_idx.shape[1]
+    held, local = held_assignments(topk_idx, offset, count, valid)
+    key = local.reshape(-1)                             # absent sort last
+    order = jnp.argsort(key)                            # stable
+    xs = jnp.take(x, order // k, axis=0)
+    group_sizes = jnp.bincount(key, length=count + 1)[:count]
+    n_held = jnp.sum(group_sizes)
+    out_s = grouped_fn(xs, group_sizes)                 # (T*k, D)
+    # rows past the last group were never written
+    rows = jax.lax.broadcasted_iota(jnp.int32, (t * k, 1), 0)
+    out_s = jnp.where(rows < n_held, out_s, 0)
+    out_k = jnp.take(out_s, jnp.argsort(order), axis=0).reshape(t, k, d)
+    w = jnp.where(held, gate_k, 0.0)
+    return jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)), n_held
+
+
+def held_dispatch_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,
+                         topk_idx: jnp.ndarray, offset: int, count: int,
+                         expert_fn, valid: Optional[jnp.ndarray] = None
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The same share through the (count, T, D) expert buffer and one
+    batched matmul (XLA only, partitionable): every token fits, none is
+    dropped, an absent assignment falls out of bounds. For small T: the
+    buffer is `count x T` rows whatever was routed."""
+    t, d = x.shape
+    k = topk_idx.shape[1]
+    held, local = held_assignments(topk_idx, offset, count, valid)
+    flat = _one_hot(local.reshape(-1), count)           # absent: a zero row
+    pos = jnp.sum((jnp.cumsum(flat, axis=0) - flat) * flat, axis=-1)
+    dest = jnp.where(held, local * t + pos.reshape(t, k).astype(jnp.int32),
+                     count * t)
+    xk = jnp.broadcast_to(x[:, None], (t, k, d)).reshape(t * k, d)
+    buf = jnp.zeros((count * t, d), x.dtype)
+    buf = buf.at[dest.reshape(-1)].add(xk, mode="drop")
+    out = expert_fn(buf.reshape(count, t, d)).reshape(count * t, d)
+    out_k = jnp.take(out, dest, axis=0, mode="fill", fill_value=0)
+    w = jnp.where(held, gate_k, 0.0)
+    return (jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)),
+            jnp.sum(held.astype(jnp.int32)))

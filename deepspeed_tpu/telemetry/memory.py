@@ -16,7 +16,7 @@ Design rules (load-bearing, mirrored in docs/memory.md):
   per-layer streaming loops.
 - Tiers are physical: ``hbm`` / ``host_pinned`` / ``host`` / ``nvme``.
   Components are semantic: ``params`` / ``opt_state`` / ``kv_cache`` /
-  ``staging`` / ``workspace`` / ``spec_draft``.
+  ``recurrent_state`` / ``staging`` / ``workspace`` / ``spec_draft``.
 - ``logical=True`` allocations (e.g. KV block-manager occupancy, a view
   into an already-registered physical cache) appear in snapshots but are
   EXCLUDED from tier totals and watermarks — physical reconciliation
@@ -40,8 +40,8 @@ import weakref
 from dataclasses import dataclass
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-COMPONENTS = ("params", "opt_state", "kv_cache", "staging", "workspace",
-              "spec_draft")
+COMPONENTS = ("params", "opt_state", "kv_cache", "recurrent_state", "staging",
+              "workspace", "spec_draft")
 TIERS = ("hbm", "host_pinned", "host", "nvme")
 
 _OWNER_COUNTER = itertools.count()

@@ -29,6 +29,9 @@ KERNEL_NAMES = {"flash_fwd_bwd": {"self_attn_flash_fwd", "self_attn_flash_bwd"},
                 "decode": {"self_attn_dense_decode"},
                 "paged_decode": {"self_attn_paged_decode"},
                 "paged_prefill": {"self_attn_paged_prefill"}}
+# other kernels the benchmark's metrics find by name
+OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
+               "grouped_gemm_decode": "gmm"}
 
 
 @pytest.fixture(scope="module")
@@ -57,10 +60,10 @@ def compiled_kernels(monkeypatch):
     steer them to the compiled path here, in the test."""
     from deepspeed_tpu.ops.pallas import (
         block_sparse_attention, decode_attention, flash_attention,
-        grouped_gemm, paged_attention, quantized_matmul)
+        grouped_gemm, paged_attention, quantized_matmul, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
     for mod in (block_sparse_attention, decode_attention, flash_attention,
-                grouped_gemm, paged_attention, quantized_matmul):
+                grouped_gemm, paged_attention, quantized_matmul, ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -81,3 +84,8 @@ def test_kernel_compiles_for_v5e(case, v5e_chip, compiled_kernels):
                for m in re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
                                    text)}
         assert got == want
+    other = OTHER_NAMES.get(case.name)
+    if other:
+        # `ssm_update_ms.gen` and `moe_gmm_ms.gen` search for these
+        calls = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+        assert any(re.match(rf"{other}(\.\d+)*$", c) for c in calls), calls
