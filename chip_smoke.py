@@ -63,6 +63,9 @@ class Sizes:
     flash_long: int
     decode_batch: int
     decode_ctx: int
+    # the stacked dense cache of a v1 program, as the benchmark's two
+    # generate cells hold it: (layers, rows, n_rep, M) each
+    dense_stack: Tuple[Tuple[int, int, int, int], ...]
     paged_batch: int
     paged_blocks: int
     prefill_batch: int
@@ -84,7 +87,9 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              micro_batch=2, global_batch=8, prompt_lens=(96, 352),
              prompts_per_len=4, new_tokens=32, v2_slots=4, v2_max_seq=1024,
              v2_chunk=256, block=256, flash_long=32768, decode_batch=32,
-             decode_ctx=1024, paged_batch=64, paged_blocks=96,
+             decode_ctx=1024,
+             dense_stack=((36, 32, 8, 1280), (2, 64, 16, 1024)),
+             paged_batch=64, paged_blocks=96,
              prefill_batch=8, parked=(48, 18, 16), gmm_rows=4096,
              gmm_experts=64, gmm_width=1024, qmm_group=256,
              ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856))
@@ -92,7 +97,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
              v2_chunk=16, block=16, flash_long=128, decode_batch=2,
-             decode_ctx=64, paged_batch=3, paged_blocks=9, prefill_batch=2,
+             decode_ctx=64, dense_stack=((3, 2, 8, 64), (2, 4, 16, 32)),
+             paged_batch=3, paged_blocks=9, prefill_batch=2,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
              qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48))
 
@@ -155,7 +161,8 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                                              reference_attention)
     from deepspeed_tpu.ops.pallas.block_sparse_attention import (
         block_sparse_attention, padded_layout_indices)
-    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+    from deepspeed_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                           kv_write_dense)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
     from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -239,6 +246,56 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         KernelCase("decode_int8kv", *int8_kv(decode_attention, decode_ref),
                    make_decode),
     ]
+
+    # ---- the stacked dense cache (v1 generate): the kernel reads layer
+    # `layer` of (L, B, Hkv, M, D) where it lies, with the step's new token
+    # staged or already written; the writer lands a step's tokens ----
+    def make_stack(layers, rows, n_rep, m):
+        def make(key):
+            kq, kk, kv, kl, kn = jax.random.split(key, 5)
+            # the cursors; the last row parked (it has no slot: the kernel
+            # and the writer drop its token)
+            index = jax.random.randint(kl, (rows,), 0, m,
+                                       jnp.int32).at[-1].set(m)
+            return (normal(kq, (rows, 1, hkv * n_rep, d)),
+                    normal(kk, (layers, rows, hkv, m, d)),
+                    normal(kv, (layers, rows, hkv, m, d)), index,
+                    normal(kn, (2, layers, rows, hkv, d)),
+                    jnp.int32(layers - 1))
+        return make
+
+    def stack_ref(q, k, v, index, new, layer, staged=False):
+        k, v = (jnp.swapaxes(x[layer], 1, 2) for x in (k, v))  # (B, M, Hkv, D)
+        if staged:
+            rows = jnp.arange(q.shape[0])
+            k = k.at[rows, index].set(new[0, layer], mode="drop")
+            v = v.at[rows, index].set(new[1, layer], mode="drop")
+        return decode_ref(q, k, v, index + 1)
+
+    def stack_write_ref(q, k, v, index, new, layer):
+        rows = jnp.arange(q.shape[0])
+        return tuple(x.at[:, rows, :, index].set(
+            jnp.moveaxis(n, 1, 0), mode="drop") for x, n in ((k, new[0]),
+                                                             (v, new[1])))
+
+    for layers, rows, n_rep, m in sz.dense_stack:
+        shape = f"l{layers}_b{rows}_r{n_rep}_m{m}"
+        cases += [
+            KernelCase(f"decode_stacked_{shape}",
+                       lambda q, k, v, index, new, layer: decode_attention(
+                           q, k, v, index + 1, layer=layer),
+                       stack_ref, make_stack(layers, rows, n_rep, m)),
+            KernelCase(f"decode_stacked_staged_{shape}",
+                       lambda q, k, v, index, new, layer: decode_attention(
+                           q, k, v, index + 1, layer=layer,
+                           k_new=new[0, layer], v_new=new[1, layer]),
+                       lambda *a: stack_ref(*a, staged=True),
+                       make_stack(layers, rows, n_rep, m)),
+            KernelCase(f"kv_write_dense_{shape}",
+                       lambda q, k, v, index, new, layer: kv_write_dense(
+                           k, v, new[0], new[1], index),
+                       stack_write_ref, make_stack(layers, rows, n_rep, m)),
+        ]
 
     # ---- paged decode / prefill (v2): block tables over a shared pool ----
     bs, nb, t = sz.block, sz.paged_blocks, sz.v2_max_seq // sz.block

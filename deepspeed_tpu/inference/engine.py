@@ -550,10 +550,12 @@ class InferenceEngine:
                                  top_k=top_k, top_p=top_p)
 
         kv_int8 = getattr(cfg, "kv_cache_dtype", None) == "int8"
-        # a model whose layers keep more than K and V builds its own cache
-        # (kv_cache.HybridCache) and may count inside the program: its
-        # `program_counters` are summed over the call and returned with the
-        # sequences. Every other model's program is what it was.
+        # a model that has a say in its cache builds it (`make_cache`): the
+        # stacked view of the dense cache, which its layers address by index
+        # (llama), or more than K and V (kv_cache.HybridCache). Every other
+        # model gets the per-layer view. A model may also count inside the
+        # program: its `program_counters` are summed over the call and
+        # returned with the sequences.
         make_cache = getattr(model, "make_cache", None)
         counted = tuple(getattr(model, "program_counters", ()))
 

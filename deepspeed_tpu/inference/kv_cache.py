@@ -8,9 +8,17 @@ through jit, inserts are `lax.dynamic_update_slice_in_dim`, and validity is a
 position mask instead of a dynamic length. Static shapes keep XLA happy; the
 mask costs nothing against HBM-bound decode.
 
-Layout: (num_layers, batch, max_seq_len, kv_heads, head_dim) — the layer
-axis lines up with `nn.scan`'s stacked block parameters so the per-layer
-cache is just a scanned input/output of the block scan.
+Two layouts of the dense cache (docs/kv_cache.md):
+
+- the PER-LAYER VIEW, (num_layers, batch, max_seq_len, kv_heads, head_dim):
+  the layer axis lines up with `nn.scan`'s stacked block parameters, so a
+  layer's cache is a scanned input/output of the block scan, a bare
+  (B, M, Hkv, D) array (or a `QuantizedKVLayer`) inside it;
+- the STACKED VIEW (`KVCache.create_stacked`, `DenseLayer`),
+  (num_layers, batch, kv_heads, max_seq_len, head_dim), the decode kernel's
+  own order: one buffer from a v1 program's first decode step to its last,
+  which a layer addresses by index (`scan_dense_layers`) and a decode step
+  writes one token a row into (`KVCache.land`).
 """
 
 from __future__ import annotations
@@ -72,6 +80,27 @@ class QuantizedKVLayer:
 
 
 @struct.dataclass
+class DenseLayer:
+    """The stacked dense cache tensor (K or V) where it lies,
+    (L, B, Hkv, M, D), and with `layer` set one layer's view of it BY INDEX:
+    the dense twin of `PagedLayer` with `layer=`. `update_layer` and
+    `ops.attention.cached_attention` dispatch on the type: a write lands at
+    `[layer, b, :, slot]` of the stack, the decode kernel fetches block
+    `(layer, b, g, j)` of it, and nothing cuts a layer out of the stack or
+    re-lays it (the axis order is the kernel's).
+
+    `staged` (static): a single-token `update_layer` does not write; it
+    parks the new K/V in `stage` (B, Hkv, D), attention puts it in its
+    slot's place (in the tile the kernel fetched), and `KVCache.land` writes
+    every layer's staged token with ONE write a step."""
+
+    stack: jnp.ndarray                      # (L, B, Hkv, M, D)
+    layer: Optional[jnp.ndarray] = None     # () int32; None: the cache at rest
+    stage: Optional[jnp.ndarray] = None     # (B, Hkv, D) this layer's new token
+    staged: bool = struct.field(pytree_node=False, default=False)
+
+
+@struct.dataclass
 class KVCache:
     """Per-model KV cache: stacked per-layer K/V plus per-sequence cursors.
 
@@ -81,13 +110,19 @@ class KVCache:
     static-shape buffer.
     """
 
-    k: Any  # (L, B, M, Hkv, D) array, or QuantizedKVLayer at rest
-    v: Any  # (L, B, M, Hkv, D) array, or QuantizedKVLayer at rest
+    # (L, B, M, Hkv, D) array or QuantizedKVLayer: the per-layer view; or a
+    # DenseLayer over (L, B, Hkv, M, D): the stacked view
+    k: Any
+    v: Any
     index: jnp.ndarray  # (B,) int32
 
     @property
+    def stacked(self) -> bool:
+        return isinstance(self.k, DenseLayer)
+
+    @property
     def max_len(self) -> int:
-        return self.k.shape[2]
+        return self.k.stack.shape[3] if self.stacked else self.k.shape[2]
 
     @property
     def quantized(self) -> bool:
@@ -108,8 +143,45 @@ class KVCache:
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    index=jnp.zeros((batch,), jnp.int32))
 
+    @classmethod
+    def create_stacked(cls, num_layers: int, batch: int, max_len: int,
+                       kv_heads: int, head_dim: int,
+                       dtype: Any = jnp.bfloat16) -> "KVCache":
+        """The cache in the stacked view (`DenseLayer`): for a model whose
+        cached layers take `layer_views` of it (`scan_dense_layers`)."""
+        shape = (num_layers, batch, kv_heads, max_len, head_dim)
+        return cls(k=DenseLayer(jnp.zeros(shape, dtype)),
+                   v=DenseLayer(jnp.zeros(shape, dtype)),
+                   index=jnp.zeros((batch,), jnp.int32))
+
+    def layer_views(self, layer, staged: bool) -> Tuple[DenseLayer, DenseLayer]:
+        """Layer `layer`'s `(k, v)` views of a stacked cache, for
+        `update_layer` and `cached_attention`."""
+        return (DenseLayer(self.k.stack, layer, staged=staged),
+                DenseLayer(self.v.stack, layer, staged=staged))
+
+    def land(self, k_new: jnp.ndarray, v_new: jnp.ndarray) -> "KVCache":
+        """A stacked cache with every layer's staged token, `k_new`/`v_new`
+        (L, B, Hkv, D), written at the cursors `[:, b, :, index[b]]`: the
+        one write of a decode step. A row whose cursor is at or past
+        `max_len` (parked) is dropped. On the chip it goes through
+        `kv_write_dense`, which aliases the stacks and keeps the tiling the
+        decode kernel reads (`_pool_writer` tells why no XLA scatter)."""
+        write = _dense_writer(self.k.stack.shape[2])
+        if write is not None:
+            k, v = write(self.k.stack, self.v.stack, k_new, v_new, self.index)
+        else:
+            rows = jnp.arange(self.index.shape[0])
+            # an index [:, rows, :, slot] puts the rows' axis first
+            k, v = (stack.at[:, rows, :, self.index].set(
+                jnp.moveaxis(new, 1, 0).astype(stack.dtype), mode="drop")
+                for stack, new in ((self.k.stack, k_new),
+                                   (self.v.stack, v_new)))
+        return self.replace(k=DenseLayer(k), v=DenseLayer(v))
+
     def apply_stage(self) -> "KVCache":
-        """Uniform surface with `PagedKVCache` (dense rows write in place)."""
+        """Uniform surface with `PagedKVCache` (dense rows write in place;
+        a stacked cache's staged tokens land inside the model's step)."""
         return self
 
     def truncate(self, index: jnp.ndarray) -> "KVCache":
@@ -193,8 +265,9 @@ class HybridCache:
         time hands the layers."""
         def cut(t, axis=1):
             return jax.lax.dynamic_slice_in_dim(t, start, count, axis)
+        rows = functools.partial(jax.tree_util.tree_map, cut)  # either view
         return HybridCache(
-            kv=KVCache(k=cut(self.kv.k), v=cut(self.kv.v),
+            kv=KVCache(k=rows(self.kv.k), v=rows(self.kv.v),
                        index=cut(self.kv.index, 0)),
             state=RecurrentState(ssm=cut(self.state.ssm),
                                  conv=cut(self.state.conv)))
@@ -203,8 +276,10 @@ class HybridCache:
         """This cache with `part` (from `rows`) written back at `start`."""
         def put(t, new, axis=1):
             return jax.lax.dynamic_update_slice_in_dim(t, new, start, axis)
+        rows = functools.partial(jax.tree_util.tree_map, put)  # either view
         return HybridCache(
-            kv=KVCache(k=put(self.kv.k, part.kv.k), v=put(self.kv.v, part.kv.v),
+            kv=KVCache(k=rows(self.kv.k, part.kv.k),
+                       v=rows(self.kv.v, part.kv.v),
                        index=put(self.kv.index, part.kv.index, 0)),
             state=RecurrentState(ssm=put(self.state.ssm, part.state.ssm),
                                  conv=put(self.state.conv, part.state.conv)))
@@ -524,14 +599,76 @@ def gather_paged_layer(layer: PagedLayer, dtype: Any = None) -> jnp.ndarray:
     return jnp.moveaxis(dense, 2, 3).reshape(b, t * bs, hkv, d)
 
 
+def _dense_writer(hkv: int):
+    """`_pool_writer` for the stacked dense cache: `kv_write_dense` (or its
+    head-sharded wrapper) where the dense decode kernel can run."""
+    from deepspeed_tpu.ops import attention
+    if not attention._use_pallas():
+        return None
+    mesh, fallback = attention._decode_tp_mesh(hkv, hkv, "kv_write_dense")
+    if fallback:
+        return None
+    if mesh is None:
+        from deepspeed_tpu.ops.pallas.decode_attention import kv_write_dense
+        return kv_write_dense
+    from deepspeed_tpu.ops.pallas.sharded import sharded_kv_write_dense
+    return functools.partial(sharded_kv_write_dense, mesh=mesh)
+
+
+def _write_dense_rows(k: DenseLayer, v: DenseLayer, k_new: jnp.ndarray,
+                      v_new: jnp.ndarray, index: jnp.ndarray):
+    """`k_new`/`v_new` (B, S, Hkv, D) into layer `k.layer` of the stacks at
+    `[layer, b, :, index[b] .. index[b] + S - 1]`, slots at or past M
+    dropped: the write of a pass that CARRIES the stacks (prefill). A row
+    at a time, a slice read and written back in place: a dynamic slice
+    keeps whatever tiling the stack has, where a scatter along M asks XLA
+    for another and re-lays the whole stack around itself (`_pool_writer`)."""
+    m = k.stack.shape[3]
+    b, s, hkv, d = k_new.shape
+
+    def tokens(new, stack):  # (B, Hkv, 2S, D): S slots of nothing, then new
+        new = jnp.swapaxes(new, 1, 2).astype(stack.dtype)
+        return jnp.concatenate([jnp.zeros_like(new), new], axis=2)
+
+    news = (tokens(k_new, k.stack), tokens(v_new, v.stack))
+    keep_from = jnp.arange(s)[None, None, :, None]
+
+    def row(i, stacks):
+        # the window [start, start + S) lies inside the row; a cursor past
+        # M - S pushes its first `shift` slots onto tokens the row already
+        # holds (kept) and its last `shift` tokens past M (dropped)
+        start = jnp.clip(index[i], 0, m - s)
+        shift = jnp.clip(index[i] - start, 0, s)
+        at = (k.layer, i, 0, start, 0)
+        out = []
+        for stack, new in zip(stacks, news):
+            old = jax.lax.dynamic_slice(stack, at, (1, 1, hkv, s, d))
+            cand = jax.lax.dynamic_slice(new, (i, 0, s - shift, 0),
+                                         (1, hkv, s, d))
+            out.append(jax.lax.dynamic_update_slice(
+                stack, jnp.where(keep_from >= shift, cand[None], old), at))
+        return tuple(out)
+
+    k_stack, v_stack = jax.lax.fori_loop(0, b, row, (k.stack, v.stack))
+    return k.replace(stack=k_stack), v.replace(stack=v_stack)
+
+
 @jax.named_scope("kv_write")
 def update_layer(k_cache, v_cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
                  index: jnp.ndarray) -> Tuple[Any, Any]:
     """Insert `k_new`/`v_new` (B, S, Hkv, D) at per-row positions
-    `index` (B,) of one layer's cache — dense (B, M, Hkv, D) arrays or
-    `PagedLayer` views (the model zoo calls this without knowing which).
+    `index` (B,) of one layer's cache — dense (B, M, Hkv, D) arrays,
+    `DenseLayer` views of the stacked dense cache or `PagedLayer` views (the
+    model zoo calls this without knowing which).
     Out-of-range rows (slot parked at max_len) are dropped — the v2 engine
     uses that to mask inactive slots."""
+    if isinstance(k_cache, DenseLayer):
+        if k_cache.staged and k_new.shape[1] == 1:
+            # staged decode: no write inside the layer loop; attention folds
+            # the token in and `KVCache.land` writes every layer's at once
+            return (k_cache.replace(stage=k_new[:, 0].astype(k_cache.stack.dtype)),
+                    v_cache.replace(stage=v_new[:, 0].astype(v_cache.stack.dtype)))
+        return _write_dense_rows(k_cache, v_cache, k_new, v_new, index)
     if isinstance(k_cache, PagedLayer):
         if k_cache.stage is not None and k_new.shape[1] == 1:
             # staged decode append: no pool scatter here — attention folds
@@ -612,8 +749,8 @@ def scan_paged_layers(block, h, aux, cache: PagedKVCache, s: int, *,
     copied once more to be scattered into: 36 ms of an 80 ms round at
     Qwen2.5-3B with a 3.77 GB pool (PERF.md, PR 29).
 
-    A dense `KVCache` has the same shape of problem and can take the same
-    shape of answer: carry or close over the stacked array, scan the index."""
+    A dense `KVCache` in the stacked view takes the same answer:
+    `scan_dense_layers`, below."""
     pools = (cache.k.pool, cache.v.pool, cache.k.scales, cache.v.scales)
     layers = cache.k.pool.shape[0]
     writes = not (cache.k.stage is not None and s == 1)
@@ -629,6 +766,69 @@ def scan_paged_layers(block, h, aux, cache: PagedKVCache, s: int, *,
         k=cache.k.replace(pool=k_pool, scales=k_scales, stage=k_stage),
         v=cache.v.replace(pool=v_pool, scales=v_scales, stage=v_stage),
         index=cache.index + s)
+
+
+class _DenseStep(nn.Module):
+    """One layer of `scan_dense_layers`: `_PagedStep` for the stacked dense
+    cache. The block is handed `DenseLayer` views that name the stacks and
+    this layer's index."""
+
+    block: Any  # () -> the block module, called (h, aux, (k, v))
+    staged: bool
+
+    @nn.compact
+    def __call__(self, carry, consts, layer):
+        h, carried = carry
+        aux, const = consts
+        k_stack, v_stack = const if carried is None else carried
+        inner = self.block()
+        nn.share_scope(self, inner)
+        h, (k, v) = inner(
+            h, aux, (DenseLayer(k_stack, layer, staged=self.staged),
+                     DenseLayer(v_stack, layer, staged=self.staged)))
+        if carried is not None:
+            carried = (k.stack, v.stack)
+        return (h, carried), (k.stage, v.stage)
+
+
+def scan_dense_layers(block, h, aux, cache: KVCache, s: int, *,
+                      name: str, **scan_kw):
+    """`scan_paged_layers` for a dense `KVCache` in the stacked view
+    (`create_stacked`), in place of a zoo model's `nn.scan` over
+    `(cache.k, cache.v)`: same arguments, same result.
+
+    From a v1 program's first decode step to its last there is ONE buffer
+    for K and one for V. The scan runs over the layer INDEX alone. A pass
+    of many tokens a row (prefill) carries the stacks and each layer writes
+    its rows' slots into the carry (`_write_dense_rows`). Single-token
+    decode closes over the stacks as constants of the loop: a layer STAGES
+    its token (`DenseLayer.stage`), the decode kernel reads it into its
+    slot's place, and after the loop `KVCache.land` writes the step's L x B
+    tokens at once. Scanned over instead, each layer's whole K and V was cut out of the
+    stack, re-laid for the kernel and written back, every step: 3.1 ms of
+    a 17 ms step at Qwen2.5-3B, 32 rows (PERF.md, PR 42)."""
+    stacks = (cache.k.stack, cache.v.stack)
+    layers = stacks[0].shape[0]
+    staged = s == 1
+    scan = nn.scan(_DenseStep, in_axes=(nn.broadcast, 0), out_axes=0,
+                   length=layers, **scan_kw)
+    (h, carried), (k_new, v_new) = scan(block, staged, name=name)(
+        (h, None if staged else stacks), (aux, stacks if staged else None),
+        jnp.arange(layers, dtype=jnp.int32))
+    if staged:
+        cache = cache.land(k_new, v_new)
+    else:
+        cache = cache.replace(k=DenseLayer(carried[0]),
+                              v=DenseLayer(carried[1]))
+    return h, cache.replace(index=cache.index + s)
+
+
+def scan_cache_layers(block, h, aux, cache, s: int, **kw):
+    """The cached block scan over the layer index, for either cache that
+    stays whole (a `PagedKVCache`, a stacked `KVCache`)."""
+    scan = scan_paged_layers if isinstance(cache, PagedKVCache) \
+        else scan_dense_layers
+    return scan(block, h, aux, cache, s, **kw)
 
 
 def decode_mask(q_positions: jnp.ndarray, max_len: int,
@@ -685,6 +885,11 @@ def tp_cache_shardings(cache, mesh, axis: str = "model"):
                     mesh, P(None, axis, None, None)))
 
         return PagedKVCache(k=layer(cache.k), v=layer(cache.v), index=repl)
+    if isinstance(cache, KVCache) and cache.stacked:
+        if cache.k.stack.shape[2] % tp:
+            return all_repl()
+        s = DenseLayer(NamedSharding(mesh, P(None, None, axis, None, None)))
+        return KVCache(k=s, v=s, index=repl)
     if isinstance(cache, KVCache):
         if cache.k.shape[3] % tp:
             return all_repl()

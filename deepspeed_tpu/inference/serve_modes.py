@@ -116,11 +116,12 @@ def resolve_serve_mode(engine, params) -> str:
                     and sharded_kernels_supported())
     scan_ok = layout_ok and (not multi_dev or tp_shardable)
     cap_ok = layout_ok and not multi_dev
-    if mode in ("layer_scan", "capacity") and \
+    if mode in ("layer_scan", "capacity") and not layout_ok and \
             getattr(engine.module, "make_cache", None) is not None:
         # both stream a llama-layout stack a layer at a time and carry raw
-        # (K, V) through it: a model that keeps its own kind of cache is
-        # refused by name, never served by something else in silence
+        # (K, V) through it: a model of another layout that keeps its own
+        # kind of cache is refused by name, never served by something else
+        # in silence
         raise ValueError(
             f"init_inference: serve_mode={mode!r} streams llama-layout trees; "
             f"{type(engine.module).__name__} keeps its own cache and is "

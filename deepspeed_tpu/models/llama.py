@@ -371,7 +371,7 @@ class LlamaForCausalLM(nn.Module):
             # Cached decode/prefill path (reference inference/engine.py:579):
             # same params, scan carries KV through the stacked layer cache.
             from deepspeed_tpu.inference.kv_cache import (
-                PagedKVCache, decode_mask, scan_paged_layers)
+                PagedKVCache, decode_mask, scan_cache_layers)
             b, s = input_ids.shape
             index = cache.index  # (B,) per-sequence cursors
             positions = index[:, None] + jnp.arange(s)[None, :]  # (B, S)
@@ -379,14 +379,18 @@ class LlamaForCausalLM(nn.Module):
                                     cfg.dtype)
             mask = decode_mask(positions, cache.max_len,
                                window=cfg.sliding_window)
-            if isinstance(cache, PagedKVCache):
-                # the pools stay whole: layers address them by index
-                h, new_cache = scan_paged_layers(
+            if isinstance(cache, PagedKVCache) or cache.stacked:
+                # the pools / the stacked dense cache stay whole: layers
+                # address them by index
+                h, new_cache = scan_cache_layers(
                     functools.partial(LlamaBlock, cfg), h,
                     (cos, sin, index, mask), cache, s, name="layers",
                     variable_axes={"params": 0}, split_rngs={"params": True},
                     metadata_params={nn.meta.PARTITION_NAME: "layers"})
             else:
+                # the per-layer view (int8 dense caches, the v2 slot
+                # layout, docs/kv_cache.md): a layer's cache is a scanned
+                # input and output
                 ScanBlocks = nn.scan(
                     LlamaBlock, variable_axes={"params": 0},
                     split_rngs={"params": True},
@@ -433,6 +437,21 @@ class LlamaForCausalLM(nn.Module):
         if labels is None:
             return logits
         return causal_lm_loss(logits, input_ids, labels), {}
+
+    def make_cache(self, batch: int, max_len: int, dtype: Any = None,
+                   quantized: bool = False):
+        """The cache a v1 serving program carries for `batch` sequences of
+        up to `max_len` positions: the STACKED view, which the cached scan
+        above addresses by layer and never moves (docs/kv_cache.md). An
+        int8 cache keeps the per-layer view."""
+        from deepspeed_tpu.inference.kv_cache import KVCache
+        cfg = self.cfg
+        dims = (cfg.num_hidden_layers, batch, max_len,
+                cfg.num_key_value_heads, cfg.head_dim)
+        if quantized:
+            return KVCache.create(*dims, dtype=dtype or cfg.dtype,
+                                  quantized=True)
+        return KVCache.create_stacked(*dims, dtype=dtype or cfg.dtype)
 
     def _lm_head(self, h, embed):
         cfg = self.cfg

@@ -310,6 +310,7 @@ class Attention(nn.Module):
                 index[:, None] + jnp.arange(s)[None, :]
             cos, sin = rope_cos_sin(pos, hd, cfg.rope_theta, cfg.dtype)
             q, k = apply_rotary_emb(q, cos, sin), apply_rotary_emb(k, cos, sin)
+        views = None
         if kv is None:
             from deepspeed_tpu.ops.attention import attention
             ctx = attention(q, k, v, causal=True, impl=cfg.attn_impl)
@@ -317,18 +318,18 @@ class Attention(nn.Module):
             from deepspeed_tpu.inference.kv_cache import (decode_mask,
                                                           update_layer)
             from deepspeed_tpu.ops.attention import cached_attention
-            k_l, v_l = update_layer(kv.k[slot], kv.v[slot], k, v, index)
-            kv = kv.replace(
-                k=jax.lax.dynamic_update_index_in_dim(kv.k, k_l, slot, 0),
-                v=jax.lax.dynamic_update_index_in_dim(kv.v, v_l, slot, 0))
-
+            # this layer's views of the stacked cache, by its slot: a
+            # single token is staged (`Layers` lands the step's), a prefill
+            # writes its rows' slots into the stack
+            views = update_layer(*kv.layer_views(slot, staged=s == 1), k, v,
+                                 index)
             pos = index[:, None] + jnp.arange(s)[None, :]
-            ctx = cached_attention(q, k_l, v_l, index,
-                                   decode_mask(pos, k_l.shape[1]),
+            ctx = cached_attention(q, *views, index,
+                                   decode_mask(pos, kv.max_len),
                                    impl=cfg.attn_impl)
         out = _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
                      "o_proj")(ctx.reshape(b, s, nh * hd))
-        return out, kv
+        return out, views
 
 
 # ------------------------------------------------------------------- layers
@@ -366,16 +367,26 @@ class Layers(nn.Module):
         state = None if cache is None else cache.state
         kv = None if cache is None else cache.kv
         pattern = cfg.hybrid_override_pattern
+        staged = []  # a decode step's new (K, V) of each attention layer
         for i, kind in enumerate(pattern):
             slot = pattern[:i].count(kind)
             x = RMSNorm(cfg.norm_eps, cfg.dtype, name=f"layer_{i}_norm")(h)
             if kind == "M":
                 out, state = MambaMixer(cfg, name=f"layer_{i}")(x, state, slot)
             elif kind == "*":
-                out, kv = Attention(cfg, name=f"layer_{i}")(x, kv, slot)
+                out, views = Attention(cfg, name=f"layer_{i}")(x, kv, slot)
+                if views is not None:
+                    k_l, v_l = views
+                    if k_l.stage is not None:
+                        staged.append((k_l.stage, v_l.stage))
+                    else:  # written: the stacks go on
+                        kv = kv.replace(k=k_l.replace(layer=None),
+                                        v=v_l.replace(layer=None))
             else:
                 out = _experts(cfg, f"layer_{i}")(x, train=False)
             h = h + out
+        if staged:  # the step's one write, every attention layer's token
+            kv = kv.land(*(jnp.stack(side) for side in zip(*staged)))
         if cache is not None:
             cache = cache.replace(state=state, kv=kv)
         return h, cache
@@ -450,9 +461,9 @@ class NemotronHForCausalLM(nn.Module):
                              "for a hybrid cache (kv_cache_dtype=None)")
         dtype = dtype or cfg.dtype
         return HybridCache(
-            kv=KVCache.create(cfg.count("*"), batch, max_len,
-                              cfg.num_key_value_heads, cfg.head_dim,
-                              dtype=dtype),
+            kv=KVCache.create_stacked(cfg.count("*"), batch, max_len,
+                                      cfg.num_key_value_heads, cfg.head_dim,
+                                      dtype=dtype),
             state=RecurrentState.create(
                 cfg.count("M"), batch, cfg.mamba_num_heads, cfg.mamba_head_dim,
                 cfg.ssm_state_size, cfg.conv_kernel, cfg.conv_dim, dtype=dtype))

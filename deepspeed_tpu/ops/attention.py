@@ -272,7 +272,8 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
     decode kernel (skips blocks past each row's cursor); prefill and
     off-TPU use the masked XLA path.
 
-    q: (B, S, H, D); caches (B, M, Hkv, D) dense arrays OR
+    q: (B, S, H, D); caches (B, M, Hkv, D) dense arrays, `kv_cache.DenseLayer`
+    views of the stacked dense cache (`_stacked_dense_attention`) OR
     `kv_cache.PagedLayer` views (block-paged pool + tables — the FastGen
     layout; with `layer` set the pool is the whole stacked one and the
     kernels fetch this layer's blocks out of it by index); index (B,) pre-insert cursors; mask (B, S, M) validity over
@@ -309,7 +310,11 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
     the pool and are folded in-register (docs/kv_cache.md); only the XLA
     fallback materializes a dequantized dense view."""
     from deepspeed_tpu.inference.kv_cache import (
-        PagedLayer, QuantizedKVLayer, dequantize_kv, gather_paged_layer)
+        DenseLayer, PagedLayer, QuantizedKVLayer, dequantize_kv,
+        gather_paged_layer)
+    if isinstance(k_cache, DenseLayer):
+        return _stacked_dense_attention(q, k_cache, v_cache, index, mask,
+                                        impl, window, alibi)
     if isinstance(k_cache, PagedLayer):
         # staged decode (kv_cache.PagedLayer.stage): the new token's K/V is
         # in the stage buffer, not the pool, until the engine's apply_stage
@@ -404,21 +409,7 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
                                        segment_mask=mask, alibi=alibi)
         return reference_attention(q, k_cache, v_cache, causal=False,
                                    segment_mask=mask, alibi=alibi)
-    if impl == "decode_pallas" and window is not None:
-        raise NotImplementedError(
-            "the Pallas decode kernel is prefix-mask-only; a sliding window "
-            "needs the XLA path (impl='auto'/'reference')")
-    # impl='pallas' is the shared attn_impl knob (training flash kernel) —
-    # for a windowed decode it degrades to the masked XLA path instead of
-    # raising, so one config value can serve both phases
-    # The n_rep>=4 auto-dispatch crossover was measured on v5e (CLAUDE.md
-    # perf ledger); other TPU generations can move it —
-    # DS_TPU_DECODE_NREP_THRESHOLD overrides without a code change
-    # (re-measure with a chained fori_loop, not repeated same-input calls).
-    thresh = int(os.environ.get("DS_TPU_DECODE_NREP_THRESHOLD", "4"))
-    if window is None and q.shape[1] == 1 and _use_pallas() and (
-            impl in ("decode_pallas", "pallas")
-            or (impl == "auto" and n_rep >= thresh)):
+    if _decode_kernel_wanted(impl, window, n_rep) and q.shape[1] == 1:
         mesh, tp_fallback = _decode_tp_mesh(
             q.shape[2], k_cache.shape[2], "decode_attention")
         if not tp_fallback:
@@ -442,6 +433,73 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
                                    segment_mask=mask)
     return reference_attention(q, k_cache, v_cache, causal=False,
                                segment_mask=mask)
+
+
+def _decode_kernel_wanted(impl: str, window, n_rep: int) -> bool:
+    """The dense decode kernel's dispatch rule for a single-token call
+    (`cached_attention` tells where the crossover was measured): a forced
+    impl, or 'auto' from a GQA group of 4 up; never under a window."""
+    if impl == "decode_pallas" and window is not None:
+        raise NotImplementedError(
+            "the Pallas decode kernel is prefix-mask-only; a sliding window "
+            "needs the XLA path (impl='auto'/'reference')")
+    # impl='pallas' is the shared attn_impl knob (training flash kernel) —
+    # for a windowed decode it degrades to the masked XLA path instead of
+    # raising, so one config value can serve both phases
+    # The n_rep>=4 auto-dispatch crossover was measured on v5e (CLAUDE.md
+    # perf ledger); other TPU generations can move it —
+    # DS_TPU_DECODE_NREP_THRESHOLD overrides without a code change
+    # (re-measure with a chained fori_loop, not repeated same-input calls).
+    thresh = int(os.environ.get("DS_TPU_DECODE_NREP_THRESHOLD", "4"))
+    return window is None and _use_pallas() and (
+        impl in ("decode_pallas", "pallas")
+        or (impl == "auto" and n_rep >= thresh))
+
+
+def _stacked_dense_attention(q, k_cache, v_cache, index, mask, impl, window,
+                             alibi):
+    """`cached_attention` over `DenseLayer` views: layer `k_cache.layer` of
+    the stacked (L, B, Hkv, M, D) cache, by index. Single-token decode under
+    the dense kernel's dispatch rule hands the kernel the WHOLE stack and
+    the layer (and the staged token, if the layer staged one); everything
+    else cuts this layer's K/V out (one layer's worth, a transient) and
+    attends under `mask` in the stack's own axis order."""
+    b, s, h, d = q.shape
+    hkv, m = k_cache.stack.shape[2], k_cache.stack.shape[3]
+    n_rep = h // hkv
+    staged = k_cache.stage is not None
+    if _decode_kernel_wanted(impl, window, n_rep) and s == 1 \
+            and alibi is None:
+        mesh, tp_fallback = _decode_tp_mesh(h, hkv, "decode_attention")
+        if not tp_fallback:
+            _assert_prefix_mask(mask, index, m)
+            kw = dict(layer=k_cache.layer, k_new=k_cache.stage,
+                      v_new=v_cache.stage)
+            if mesh is not None:
+                from deepspeed_tpu.ops.pallas.sharded import (
+                    sharded_decode_attention)
+                return sharded_decode_attention(
+                    q, k_cache.stack, v_cache.stack, index + 1, mesh, **kw)
+            from deepspeed_tpu.ops.pallas.decode_attention import (
+                decode_attention)
+            return decode_attention(q, k_cache.stack, v_cache.stack,
+                                    index + 1, **kw)
+    k, v = (jax.lax.dynamic_index_in_dim(c.stack, c.layer, 0, keepdims=False)
+            for c in (k_cache, v_cache))                     # (B, Hkv, M, D)
+    if staged:  # the staged token overlays its row's cursor slot
+        rows = jnp.arange(b)
+        k = k.at[rows, :, index].set(k_cache.stage, mode="drop")
+        v = v.at[rows, :, index].set(v_cache.stage, mode="drop")
+    # grouped, so no head is repeated: head g*n_rep+r is member r of group g
+    logits = jnp.einsum("bqgrd,bgkd->bgrqk", q.reshape(b, s, hkv, n_rep, d),
+                        k).astype(jnp.float32) * (1.0 / (d ** 0.5))
+    if alibi is not None:
+        logits = logits + alibi.reshape(hkv, n_rep)[None, :, :, None, None] \
+            * jnp.arange(m, dtype=jnp.float32)
+    logits = jnp.where(mask[:, None, None], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bgkd->bqgrd", probs, v).reshape(b, s, h, d)
 
 
 def rms_norm_ref(x, weight, eps: float = 1e-6):

@@ -29,7 +29,7 @@ Supported matrix (docs/quantized_serving.md has the serving view):
 |                             |             | shard group_offset, psum     |
 | fused int8 dequant-GEMM     | 'model'     | N-sharded (column-parallel)  |
 |                             |             | or K-sharded + psum          |
-| dense decode attention      | 'model'     | KV-head-sharded, no psum     |
+| dense decode attn / write   | 'model'     | KV-head-sharded, no psum     |
 | paged decode/prefill/write  | 'model'     | KV-head-sharded, no psum     |
 
 Everything else (other axes nontrivial, non-divisible shapes, kernels
@@ -157,28 +157,53 @@ def decode_heads_shardable(h: int, hkv: int, tp: int) -> bool:
 
 def sharded_decode_attention(q, k_cache, v_cache, lengths, mesh,
                              softmax_scale: Optional[float] = None,
-                             block_k: int = 512,
-                             k_scales=None, v_scales=None):
-    """`decode_attention` with q (B,1,H,D) and the dense caches
-    (B,M,Hkv,D) head-sharded over 'model'. int8 caches carry (B,M,Hkv)
-    scale leaves sharded on the same head axis. Caller guarantees
-    `decode_heads_shardable`."""
+                             block_k: Optional[int] = None,
+                             k_scales=None, v_scales=None, layer=None,
+                             k_new=None, v_new=None):
+    """`decode_attention` with q (B,1,H,D) and the dense caches head-sharded
+    over 'model': a layer's own (B,M,Hkv,D), or with `layer` (replicated)
+    the stacked (L,B,Hkv,M,D), whose staged token `k_new`/`v_new` (B,Hkv,D)
+    shards with the heads. int8 caches carry (B,M,Hkv) scale leaves sharded
+    on the same head axis. Caller guarantees `decode_heads_shardable`."""
     from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
     spec = P(None, None, "model", None)
-    sspec = P(None, None, "model")
-    quantized = k_scales is not None
-    in_specs = [spec, spec, spec, P()]
+    cspec = spec if layer is None else P(None, None, "model", None, None)
+    quantized, staged = k_scales is not None, k_new is not None
+    in_specs = [spec, cspec, cspec, P()]
     args = [q, k_cache, v_cache, lengths]
+    if layer is not None:
+        in_specs.append(P())
+        args.append(jnp.asarray(layer, jnp.int32).reshape(1))
     if quantized:
-        in_specs += [sspec, sspec]
+        in_specs += [P(None, None, "model")] * 2
         args += [k_scales, v_scales]
+    if staged:
+        in_specs += [P(None, "model", None)] * 2
+        args += [k_new, v_new]
 
     def body(q, kc, vc, ln, *rest):
-        ks, vs = (rest[0], rest[1]) if quantized else (None, None)
+        rest = list(rest)
+        ly = None if layer is None else rest.pop(0)[0]
+        ks, vs = (rest.pop(0), rest.pop(0)) if quantized else (None, None)
+        kn, vn = (rest.pop(0), rest.pop(0)) if staged else (None, None)
         return decode_attention(q, kc, vc, ln, softmax_scale=softmax_scale,
-                                block_k=block_k, k_scales=ks, v_scales=vs)
+                                block_k=block_k, k_scales=ks, v_scales=vs,
+                                layer=ly, k_new=kn, v_new=vn)
 
     return kernel_shard_map(body, mesh, tuple(in_specs), spec)(*args)
+
+
+def sharded_kv_write_dense(k_stack, v_stack, k_new, v_new, starts, mesh):
+    """`kv_write_dense` with the stacks (L,B,Hkv,M,D) and the new tokens
+    (L,B,Hkv,D) head-sharded over 'model': each shard writes its own heads
+    in place; the cursors are replicated."""
+    from deepspeed_tpu.ops.pallas.decode_attention import kv_write_dense
+    sspec = P(None, None, "model", None, None)
+    nspec = P(None, None, "model", None)
+    return kernel_shard_map(kv_write_dense, mesh,
+                            (sspec, sspec, nspec, nspec, P()),
+                            (sspec, sspec))(k_stack, v_stack, k_new, v_new,
+                                            starts)
 
 
 def _paged_pool_operands(k_pool, v_pool, k_scales, v_scales, layer):

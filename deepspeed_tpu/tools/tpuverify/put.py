@@ -39,6 +39,11 @@ class ProgramUnderTest:
     # (shape, dtype) of the stacked paged pools (and int8 scales) a v2
     # program must keep whole and in place (kv-pool-in-place)
     pool_shapes: frozenset = frozenset()
+    # (shape, dtype) of the stacked dense cache a v1 generate program creates
+    # and must keep whole and in place from its first decode step to its
+    # last, and the length of its token loop (kv-pool-in-place)
+    stack_shapes: frozenset = frozenset()
+    token_loop: Optional[int] = None
     allow_shard_map: bool = False
     check_callbacks: bool = True
     kind: str = "program"
@@ -179,19 +184,73 @@ def _v1_cache_shapes(eng, key) -> frozenset:
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.inference.kv_cache import (KVCache,
-                                                  scatter_target_shapes)
+    from deepspeed_tpu.inference.kv_cache import scatter_target_shapes
+    return scatter_target_shapes(_v1_cache(eng, key))
+
+
+def _v1_cache(eng, key):
+    """The shape tree of the cache `_build_generate` creates for `key`: the
+    model's own (`make_cache`) where it has a say, the per-layer view else."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.kv_cache import KVCache
     b, s, new = key[0], key[1], key[2]
     max_len = -(-(s + new) // 128) * 128
     cfg = eng.model_cfg
     dtype = getattr(cfg, "dtype", jnp.float32)
     quantized = getattr(eng._config, "kv_cache_dtype", None) == "int8" and \
         getattr(eng, "serve_mode", "dequant") == "dequant"
-    shape_tree = jax.eval_shape(
+    make_cache = getattr(eng.module, "make_cache", None)
+    if make_cache is not None and \
+            getattr(eng, "serve_mode", "dequant") == "dequant":
+        return jax.eval_shape(lambda: make_cache(b, max_len, dtype=dtype,
+                                                 quantized=quantized))
+    return jax.eval_shape(
         lambda: KVCache.create(cfg.num_hidden_layers, b, max_len,
                                cfg.num_key_value_heads, cfg.head_dim,
                                dtype=dtype, quantized=quantized))
-    return scatter_target_shapes(shape_tree)
+
+
+def build_v1_chip_dispatch_put(model_cls=None) -> ProgramUnderTest:
+    """`jit_ds_v1_generate` of a tiny llama (GQA group of 4, so that 'auto'
+    picks the decode kernel) TRACED as the chip dispatches it: the dense
+    decode kernel on the stacked cache by layer, the staged token, the
+    Pallas writer. Off the chip the same program attends through the masked
+    XLA path, which cuts a layer out of the stack to read it; what
+    kv-pool-in-place holds is the chip's program, so its jaxpr is made here
+    under the chip's dispatch (nothing runs). `model_cls`: another module
+    class over the same tree (a test's per-layer-view reference)."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    import deepspeed_tpu
+    import deepspeed_tpu.ops.attention as attention
+    from deepspeed_tpu.models.llama import (LlamaForCausalLM, llama_config,
+                                            materialize_params)
+    from deepspeed_tpu.telemetry.recompile import abstract_args
+
+    _reset_topology()
+    cfg = llama_config("llama-tiny", dtype=jnp.float32, num_attention_heads=8)
+    _, params = materialize_params(cfg)
+    eng = deepspeed_tpu.init_inference((model_cls or LlamaForCausalLM)(cfg),
+                                       params=params, dtype="fp32")
+    key = (2, 8, 4, 0.0, 0, 1.0, None, 0)
+    args = abstract_args((eng.params, jax.ShapeDtypeStruct((2, 8), jnp.int32),
+                          jax.random.PRNGKey(0)))
+    with mock.patch.object(attention, "_use_pallas", lambda: True):
+        fn = eng._build_generate(*key)
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    cache = _v1_cache(eng, key)
+    cache = getattr(cache, "kv", cache)
+    return ProgramUnderTest(
+        name="v1:generate[chip dispatch]", fn=fn, args=args,
+        stack_shapes=frozenset(
+            (tuple(x.shape), str(x.dtype))
+            for x in jax.tree_util.tree_leaves((cache.k, cache.v))),
+        token_loop=key[2] - 1, _jaxpr=jaxpr)
 
 
 def build_v1_puts(serve_mode: Optional[str] = None,
@@ -259,6 +318,8 @@ def build_v1_puts(serve_mode: Optional[str] = None,
         name=label, detector=eng.recompiles, records=records,
         pinned_trees=[(f"{label}.params", eng.params)],
         residency=_engine_residency(eng)))
+    if serve_mode is None and quant is None and speculative is None:
+        puts.append(build_v1_chip_dispatch_put())
     return puts
 
 

@@ -102,5 +102,126 @@ def test_rows_of_a_hybrid_cache_round_trip():
     zero = jax.tree_util.tree_map(jnp.zeros_like, cache)
     back = zero.with_rows(part, jnp.int32(2))
     assert jnp.array_equal(back.state.ssm[:, 2:], cache.state.ssm[:, 2:])
-    assert jnp.array_equal(back.kv.k[:, 2:], cache.kv.k[:, 2:])
+    assert jnp.array_equal(back.kv.k.stack[:, 2:], cache.kv.k.stack[:, 2:])
     assert not back.state.conv[:, :2].any() and int(cache.max_len) == 16
+
+
+# ------------------------------------------- the stacked K/V of a hybrid cache
+
+
+def _per_layer_view_modules():
+    """`Attention` and `Layers` as they were before PR 42: an attention layer
+    cuts its K/V out of the per-layer-view stack (`kv.k[slot]`), scatters
+    into the slice and writes it back. The reference the stacked view must
+    equal."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.kv_cache import decode_mask, update_layer
+    from deepspeed_tpu.models import nemotron_h
+    from deepspeed_tpu.ops.attention import cached_attention
+
+    class PerLayerAttention(nemotron_h.Attention):
+        @nn.compact
+        def __call__(self, h, kv=None, slot=None):
+            cfg = self.cfg
+            hd, nh, nkv = cfg.head_dim, cfg.num_attention_heads, \
+                cfg.num_key_value_heads
+            b, s, _ = h.shape
+            dense = nemotron_h._dense
+            q = dense(nh * hd, ("embed", "heads"), cfg.dtype, "q_proj")(h)
+            k = dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "k_proj")(h)
+            v = dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "v_proj")(h)
+            q, k, v = (q.reshape(b, s, nh, hd), k.reshape(b, s, nkv, hd),
+                       v.reshape(b, s, nkv, hd))
+            index = kv.index
+            k_l, v_l = update_layer(kv.k[slot], kv.v[slot], k, v, index)
+            kv = kv.replace(
+                k=jax.lax.dynamic_update_index_in_dim(kv.k, k_l, slot, 0),
+                v=jax.lax.dynamic_update_index_in_dim(kv.v, v_l, slot, 0))
+            pos = index[:, None] + jnp.arange(s)[None, :]
+            ctx = cached_attention(q, k_l, v_l, index,
+                                   decode_mask(pos, k_l.shape[1]),
+                                   impl=cfg.attn_impl)
+            out = dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
+                        "o_proj")(ctx.reshape(b, s, nh * hd))
+            return out, kv
+
+    class PerLayerLayers(nemotron_h.Layers):
+        @nn.compact
+        def __call__(self, h, cache=None):
+            cfg = self.cfg
+            state, kv = cache.state, cache.kv
+            pattern = cfg.hybrid_override_pattern
+            for i, kind in enumerate(pattern):
+                slot = pattern[:i].count(kind)
+                x = nemotron_h.RMSNorm(cfg.norm_eps, cfg.dtype,
+                                       name=f"layer_{i}_norm")(h)
+                if kind == "M":
+                    out, state = nemotron_h.MambaMixer(
+                        cfg, name=f"layer_{i}")(x, state, slot)
+                elif kind == "*":
+                    out, kv = PerLayerAttention(cfg, name=f"layer_{i}")(
+                        x, kv, slot)
+                else:
+                    out = nemotron_h._experts(cfg, f"layer_{i}")(x, train=False)
+                h = h + out
+            return h, cache.replace(state=state, kv=kv)
+
+    return PerLayerLayers
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+def test_decode_steps_equal_the_per_layer_view_of_the_attention_layers(
+        monkeypatch, kernels):
+    """Prefill and 64 decode steps through a `HybridCache` whose K/V lie in
+    the stacked view (two attention layers, by slot; a decode step stages
+    both tokens and lands them with one write) against the per-layer view:
+    the logits of every step within 1e-5, rows at different cursors."""
+    import dataclasses
+    from deepspeed_tpu.models import nemotron_h
+    cfg = dataclasses.replace(CFG, hybrid_override_pattern="M*E*",
+                              num_attention_heads=8)
+    if kernels:
+        import deepspeed_tpu.ops.attention as attention
+        monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    model, params = materialize_params(cfg, jax.random.PRNGKey(0))
+    steps, rows = 64, 3
+    rng = np.random.default_rng(8)
+    ids = jnp.asarray(rng.integers(1, cfg.vocab_size, (rows, 5)))
+    forced = jnp.asarray(rng.integers(1, cfg.vocab_size, (rows, steps)))
+    new = model.make_cache(rows, 128, dtype=jnp.float32)
+    assert new.kv.stacked and new.kv.k.stack.shape == (2, rows, 2, 128, 16)
+    fill = jnp.asarray(rng.standard_normal((2,) + new.kv.k.stack.shape),
+                       jnp.float32)
+    index = jnp.asarray([0, 9, 30], jnp.int32)
+    from deepspeed_tpu.inference.kv_cache import DenseLayer
+    new = new.replace(kv=KVCache(k=DenseLayer(fill[0]), v=DenseLayer(fill[1]),
+                                 index=index))
+    old = new.replace(kv=KVCache(k=jnp.swapaxes(fill[0], 2, 3),
+                                 v=jnp.swapaxes(fill[1], 2, 3), index=index))
+
+    def walk(cache):
+        @jax.jit
+        def run(params, cache, ids, forced):
+            logits, cache = model.apply({"params": params}, ids, cache=cache)
+
+            def step(cache, tok):
+                out, cache = model.apply({"params": params}, tok[:, None],
+                                         cache=cache)
+                return cache, out[:, 0]
+            cache, outs = jax.lax.scan(step, cache, forced.T)
+            return logits[:, -1], outs, cache
+        return run(params, cache, ids, forced)
+
+    got = walk(new)
+    monkeypatch.setattr(nemotron_h, "Layers", _per_layer_view_modules())
+    want = walk(old)
+    for g, w in zip(got[:2], want[:2]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5,
+                                   rtol=0)
+    np.testing.assert_array_equal(np.asarray(got[2].index),
+                                  np.asarray(index) + 5 + steps)
+    np.testing.assert_allclose(
+        np.asarray(jnp.swapaxes(got[2].kv.k.stack, 2, 3)),
+        np.asarray(want[2].kv.k), atol=1e-5, rtol=0)
+    np.testing.assert_array_equal(np.asarray(got[2].state.ssm),
+                                  np.asarray(want[2].state.ssm))

@@ -13,9 +13,10 @@ Three layers of proof, all on the CPU (Pallas kernels in interpret mode):
   engine return the same logits and the same WHOLE cache, bit for bit, as
   the engine whose model keeps the per-layer-view scan this PR replaced
   (`PerLayerViewLlama`: the old form, built from `update_layer` on views).
-- STRUCTURE: the paged cached scan scans over no pool; the dense `KVCache`
-  scan, which `generate-batch` runs, still scans over `(cache.k, cache.v)`
-  and holds no layer operand.
+- STRUCTURE: the paged cached scan scans over no pool; a dense `KVCache` in
+  the per-layer view is still scanned over as `(cache.k, cache.v)` and holds
+  no layer operand (the stacked view, which `generate-batch` runs since PR
+  42, has its own file: `test_dense_cache_in_place.py`).
 """
 
 import flax.linen as nn
@@ -581,7 +582,8 @@ def test_paged_scan_scans_over_no_pool(params, staged, s):
 
 
 def test_dense_scan_is_the_parents(params):
-    """`generate-batch` runs this branch: it still scans over
+    """The per-layer view of the dense cache (`KVCache.create`: int8 caches,
+    the v2 slot layout, the other families): it still scans over
     `(cache.k, cache.v)`, and nothing of the paged protocol (a layer
     index) has entered it."""
     model = LlamaForCausalLM(CFG)

@@ -179,7 +179,7 @@ def test_a_bfloat16_state_fails(seeded):
 def test_the_cache_holds_each_kind_of_layer_its_own():
     model = nemotron_h.NemotronHForCausalLM(CFG)
     cache = model.make_cache(3, 128, dtype=jnp.bfloat16)
-    assert cache.kv.k.shape == (1, 3, 128, 2, 16)      # ONE attention layer
+    assert cache.kv.k.stack.shape == (1, 3, 2, 128, 16)  # ONE attention layer
     assert cache.state.ssm.shape == (2, 3, 4, 8, 16)   # two Mamba layers
     assert cache.state.ssm.dtype == jnp.float32
     assert cache.state.conv.shape == (2, 3, 3, 4 * 8 + 2 * 2 * 16)

@@ -250,6 +250,129 @@ def test_kv_pool_in_place_needs_pool_shapes():
     assert verify([put], contracts=["kv-pool-in-place"]) == []
 
 
+# the stacked dense cache of a v1 generate program (PR 42): held inside the
+# token loop, where the parent's program cut every layer out each step
+
+_STACK = ((3, 2, 2, 8, 16), "float32")  # (L, B, Hkv, M, D) toy stack
+_STEPS = 5
+
+
+def _token_loop(step):
+    """A generate-shaped program: the stack made inside, a token scan that
+    carries it through `step(stack, tok) -> stack`."""
+    def gen(toks):
+        stack = jnp.zeros(_STACK[0], jnp.float32)
+        return jax.lax.scan(lambda s, t: (step(s, t), ()), stack, toks)[0]
+    return _put(jax.jit(gen), [_sds((_STEPS, 3, 2, 2, 16))],
+                stack_shapes=frozenset({_STACK}), token_loop=_STEPS)
+
+
+def _land(stack, tok):
+    """One write a step: every layer's token at slot 5 of each row."""
+    return stack.at[:, jnp.arange(2), :, 5].set(jnp.moveaxis(tok, 1, 0))
+
+
+def _step_by_index(stack, tok):
+    def layer(h, l):   # reads the stack by index, writes nothing
+        return h + jnp.take(stack, l, axis=0).sum(), ()
+    h, _ = jax.lax.scan(layer, 0.0, jnp.arange(3))
+    return _land(stack, tok + h)
+
+
+def _step_scanning_the_stack(stack, tok):
+    def layer(_, x):
+        k, t = x        # one layer's cache, cut out and written back
+        return (), k.at[jnp.arange(2), :, 5].set(t)
+    return jax.lax.scan(layer, (), (stack, tok))[1]
+
+
+def _step_slicing(stack, tok):
+    k = jax.lax.dynamic_index_in_dim(stack, tok[0, 0, 0, 0].astype(jnp.int32),
+                                     keepdims=False)
+    return _land(stack, tok + k.sum())
+
+
+def _step_relaying(stack, tok):
+    k = jnp.swapaxes(jnp.take(stack, 1, axis=0), 1, 2)   # (B, M, Hkv, D)
+    return _land(stack, tok + k[:, 0].sum())
+
+
+def _step_through_a_kernel(aliased):
+    from jax.experimental import pallas as pl
+
+    def kernel(s_ref, o_ref):
+        o_ref[...] = s_ref[...] + 1.0
+
+    def step(stack, tok):
+        return pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(stack.shape, stack.dtype),
+            input_output_aliases={0: 0} if aliased else {},
+            interpret=True)(stack)
+    return step
+
+
+def test_dense_stack_in_place_clean():
+    for step in (_step_by_index, _step_through_a_kernel(True)):
+        assert verify([_token_loop(step)], contracts=["kv-pool-in-place"]) == []
+
+
+@pytest.mark.parametrize("step,what", [
+    (_step_scanning_the_stack, "scans over"),
+    (_step_slicing, "dynamic_slice of a cache-shaped"),
+    (_step_relaying, "transpose of a cache-shaped"),
+    (_step_through_a_kernel(False), "does not alias"),
+], ids=["scanned", "sliced", "re-laid", "kernel_copy"])
+def test_dense_stack_in_place_violating(step, what):
+    out = verify([_token_loop(step)], contracts=["kv-pool-in-place"])
+    assert _ids(out) == ["kv-pool-in-place"]
+    assert any(what in v.message for v in out)
+
+
+def test_dense_stack_in_place_is_not_vacuous():
+    # a program that names a stack but has no token loop holding one
+    put = _token_loop(_step_by_index)
+    put.token_loop = _STEPS + 1
+    out = verify([put], contracts=["kv-pool-in-place"])
+    assert len(out) == 1 and "nothing to hold" in out[0].message
+
+
+def test_v1_generate_keeps_its_dense_cache_in_place():
+    """`jit_ds_v1_generate` of a tiny llama as the chip dispatches it: the
+    token loop holds the decode kernel and the writer on the carried stack,
+    and no finding. Budget: K and V, one stack each; one writer a step."""
+    from deepspeed_tpu.tools.tpuverify.jaxpr_util import primitive_eqns
+    from deepspeed_tpu.tools.tpuverify.put import build_v1_chip_dispatch_put
+    put = build_v1_chip_dispatch_put()
+    assert len(put.stack_shapes) == 1 and put.token_loop == 3
+    assert verify([put]) == []
+    (loop,) = [e for _, e in primitive_eqns(put.jaxpr(), {"scan"})
+               if e.params["length"] == put.token_loop]
+    names = [str(e.params.get("name") or e.params["name_and_src_info"])
+             for _, e in primitive_eqns(loop.params["jaxpr"], {"pallas_call"})]
+    assert sum("kv_write_dense" in n for n in names) == 1
+    assert sum("self_attn_dense_decode" in n for n in names) == 1
+    stack = next(iter(put.stack_shapes))[0]
+    carried = [v for v in loop.invars[loop.params["num_consts"]:]
+               if tuple(v.aval.shape) == stack]
+    assert len(carried) == 2
+
+
+def test_the_parents_v1_program_fails_kv_pool_in_place():
+    """The per-layer view, which `jit_ds_v1_generate` held before PR 42 (a
+    model with no say in its cache still gets it): scanned over in the
+    token loop, and re-laid for the kernel."""
+    from deepspeed_tpu.models.llama import LlamaForCausalLM
+    from deepspeed_tpu.tools.tpuverify.put import build_v1_chip_dispatch_put
+
+    class PerLayerView(LlamaForCausalLM):
+        make_cache = None
+
+    out = verify([build_v1_chip_dispatch_put(PerLayerView)],
+                 contracts=["kv-pool-in-place"])
+    assert any("scans over" in v.message for v in out)
+    assert any("transpose of a cache-shaped" in v.message for v in out)
+
+
 def test_kv_scatter_budget_counts_the_stacked_target():
     """The chunk scatter now targets the STACKED aval from inside the
     layer scan: `scatter_target_shapes` lists it (and its token-flat
