@@ -11,6 +11,9 @@ plus `zero`, `comm`, `ops`, `moe`, `sequence`, `pipe` sub-packages.
 from __future__ import annotations
 
 import os
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # the `import` span opens here
 
 from typing import Any, Callable, Optional
 
@@ -24,6 +27,9 @@ from deepspeed_tpu.runtime.engine import DeepSpeedEngine  # noqa: F401
 from deepspeed_tpu.utils import groups  # noqa: F401
 from deepspeed_tpu.utils.groups import MeshTopology  # noqa: F401
 from deepspeed_tpu.utils.logging import logger  # noqa: F401
+from deepspeed_tpu.telemetry import note_import as _note_import
+
+_note_import(_IMPORT_T0)   # every import above, JAX's among them
 
 
 def initialize(args=None,

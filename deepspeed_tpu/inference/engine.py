@@ -25,7 +25,8 @@ from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.kv_cache import KVCache
 from deepspeed_tpu.resilience.faults import fault_point, is_oom_error
 from deepspeed_tpu.telemetry import (RecompileDetector, annotate,
-                                     compile_span, get_hub)
+                                     compile_span, device_busy, get_hub,
+                                     init_phase, init_span)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
@@ -55,8 +56,10 @@ class InferenceEngine:
     (already fused); `forward:579` ≡ `forward`/`generate` below.
     """
 
+    @init_span("v1")
     def __init__(self, model: Any, config: Optional[DeepSpeedInferenceConfig] = None,
                  params: Any = None):
+        init_phase("plan")
         if config is None:
             config = DeepSpeedInferenceConfig()
         self._config = config
@@ -87,7 +90,10 @@ class InferenceEngine:
                 "init_inference needs params: pass init_inference(model=(module, "
                 "params)) or init_inference(module, params=params). Use "
                 "deepspeed_tpu.module_inject.load_hf_checkpoint() for HF weights.")
+        part = init_phase("place_params")
         self.params = self._place_with_recovery(params)
+        part["async"] = device_busy(self.params)
+        init_phase("build_programs")
         if kvd == "int8" and self.serve_mode != "dequant":
             # the streamed modes carry raw (ck, cv, ix) array state through
             # _make_stack_forward — no QuantizedKVLayer seat there yet

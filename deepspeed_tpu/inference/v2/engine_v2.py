@@ -28,7 +28,9 @@ from deepspeed_tpu.inference.kv_cache import KVCache, PagedKVCache
 from deepspeed_tpu.inference.v2.ragged import DSStateManager
 from deepspeed_tpu.resilience.faults import fault_point, is_oom_error
 from deepspeed_tpu.telemetry import (RecompileDetector, RequestTracer,
-                                     compile_span, compile_totals, get_hub)
+                                     compile_span, compile_totals,
+                                     device_busy, get_hub, init_phase,
+                                     init_span)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
@@ -79,6 +81,7 @@ def width_for(rows: int, max_batch: int) -> int:
 
 
 class InferenceEngineV2:
+    @init_span("v2")
     def __init__(self, model: Any, config: Optional[DeepSpeedInferenceConfig] = None,
                  params: Any = None, max_batch: int = 8,
                  max_seq_len: int = 2048, split_fuse_chunk: int = 256,
@@ -116,6 +119,7 @@ class InferenceEngineV2:
         `capacity` — with the streamed modes driving every bucketed
         program through the shared `make_block_fn` scan body
         (docs/fastgen_v2.md has the serve-mode × layout matrix)."""
+        init_phase("plan")
         if config is None:
             config = DeepSpeedInferenceConfig()
         self._config = config
@@ -196,15 +200,17 @@ class InferenceEngineV2:
         # when enabled. Survives `_degrade_to` (the engine rebuild drops
         # programs and caches, never in-flight request traces).
         self.tracer = RequestTracer(engine="v2")
+        part = init_phase("place_params")
         self.params = self._place_with_recovery(params)
+        part["async"] = device_busy(self.params)
         if self.kv_cache_dtype == "int8" and self.serve_mode != "dequant":
             raise ValueError(
                 "kv_cache_dtype='int8' rides the paged dequant path; the "
                 f"layer-streamed serve mode {self.serve_mode!r} keeps dense "
                 "slot rows with no per-row view of a quantized cache — use "
                 "serve_mode='dequant' or drop the int8 cache")
-        self._apply = self._make_apply()
 
+        part = init_phase("alloc_cache")
         self.kv_layout = self._resolve_kv_layout(kv_layout)
         if kv_cache_dtype == "int8" and self.kv_layout != "paged":
             raise ValueError(
@@ -215,6 +221,9 @@ class InferenceEngineV2:
         self._num_cache_blocks = num_cache_blocks
         self._prefix_sharing = prefix_sharing
         self._setup_cache()
+        part["async"] = device_busy(self.cache)
+        init_phase("build_programs")
+        self._apply = self._make_apply()
         self._sample_cfg = None   # (temperature, top_k, top_p) or None
         self.last_timing: Dict[int, Dict[str, float]] = {}  # per-uid SLA
         self.serving_counters: Dict[str, int] = {

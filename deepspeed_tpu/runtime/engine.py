@@ -45,7 +45,8 @@ from deepspeed_tpu.runtime.precision import (
 from deepspeed_tpu.runtime.zero.partition import ZeroShardingPlan
 from deepspeed_tpu.ops.optimizers import GradientTransformation, build_optimizer
 from deepspeed_tpu.telemetry import (
-    MetricsState, RecompileDetector, TelemetryHub, annotate, compile_span)
+    MetricsState, RecompileDetector, TelemetryHub, annotate, compile_span,
+    device_busy, init_phase, init_span)
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.groups import MeshTopology
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -95,6 +96,7 @@ def _spec_tree_for_opt_state(opt_shapes, params_treedef, param_specs, params_num
 
 
 class DeepSpeedEngine:
+    @init_span("train")
     def __init__(self,
                  model: Any = None,
                  loss_fn: Optional[Callable] = None,
@@ -108,6 +110,7 @@ class DeepSpeedEngine:
                  optimizer: Optional[GradientTransformation] = None,
                  expert_param_fn: Optional[Callable] = None,
                  dont_materialize: bool = False):
+        init_phase("plan")
         self.config = config
         # Pipeline mode: the PipelineModule's loss_fn microbatches internally
         # (the rotation IS the GAS loop), so the engine's own GAS scan and
@@ -496,6 +499,7 @@ class DeepSpeedEngine:
             model_parameters)
         shardings = self.build_shardings(shapes, base_param_specs)
 
+        part = init_phase("place_params")
         # Initial placement on device memory — the state-build jit must be
         # fed device-resident inputs; offloaded leaves restage to pinned_host
         # right after (native mode's out_shardings already emit them there).
@@ -503,7 +507,9 @@ class DeepSpeedEngine:
             lambda x, s: jax.device_put(
                 jnp.asarray(x, self.model_dtype if _is_float(x) else None), s),
             model_parameters, self._shardings_device.params)
+        part["async"] = device_busy(params)
 
+        part = init_phase("init_optimizer")
         mixed = self.mixed_precision
         scaler_init = self.loss_scaler.init_state()
 
@@ -540,6 +546,7 @@ class DeepSpeedEngine:
             # model states go straight to their NVMe residency; the jit
             # outputs they came from are freed once parked
             self.state = self._nvme_park_state(self.state)
+        part["async"] = device_busy(self.state)
         n_params = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params))
         self.total_params = n_params
         self._register_state_residency()

@@ -17,8 +17,10 @@ Here the loop is one compiled program, so observability splits into:
   decomposition with an ``unattributed`` residual invariant, streaming
   TTFT/TPOT/e2e histograms, and Chrome-trace export;
 - ``trace_capture``/``annotate`` (tracing.py): perfetto trace hooks;
-  ``compile_span``/``compile_records``: set-up time by program, from one
-  ``jax.monitoring`` listener installed when this package is imported;
+  ``compile_span``/``compile_records``: set-up time by program (tracing,
+  lowering, backend compiles and what the persistent cache said of them),
+  from the ``jax.monitoring`` listeners installed when this package is
+  imported; ``init_span``/``init_phase``: an engine's construction by part;
 - ``get_span_store`` (spans.py): the closed spans of the last rounds, kept
   after their requests, process-global like ``get_hub()``;
 - ``MemoryPlane`` (memory.py): the tiered residency ledger every placement
@@ -36,7 +38,8 @@ from deepspeed_tpu.telemetry.recompile import RecompileDetector  # noqa: F401
 from deepspeed_tpu.telemetry.spans import (  # noqa: F401
     Histogram, RequestTracer, SpanStore, export_chrome_trace, get_span_store)
 from deepspeed_tpu.telemetry.tracing import (  # noqa: F401
-    annotate, compile_records, compile_span, compile_totals,
-    install_compile_listener, trace_capture)
+    annotate, compile_records, compile_span, compile_totals, device_busy,
+    init_phase, init_span, install_compile_listener, note_import,
+    trace_capture, union_seconds)
 
 install_compile_listener()

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import heapq
 import itertools
 import math
 import time
@@ -68,6 +69,7 @@ INSTANT_KINDS = ("fault", "retry", "watchdog", "serve_mode_degraded",
 _INSTANT_CAP = 4096      # bound the in-memory instant mirror
 _INTERVAL_CAP = 65536    # hard bound on retained intervals (safety valve)
 _STORE_CAP = 32768       # closed spans kept after their requests: ~4000 rounds
+_SETUP_CAP = 4096        # set-up's spans, kept apart from the rounds'
 ANNOTATION_PREFIX = "ds:"   # the program's names in a jax.profiler trace
 _IDS = itertools.count(1)   # span ids: unique in the process, across tracers
 
@@ -79,26 +81,32 @@ class SpanStore:
     One record per closed span, as the tracer keeps it (`name`, `id`,
     `parent`, `round`, `depth`, `uids`, `slots`, `fields`) plus `engine`,
     with `t0`/`t1` in seconds on the clock the tracer runs on
-    (`perf_counter`), NOT from the tracer's epoch. The oldest fall out."""
+    (`perf_counter`), NOT from the tracer's epoch. The oldest fall out.
+    Set-up's spans (`compile`, `init` and its parts, `import`) are few and
+    are kept apart, so that a long run's rounds do not push them out."""
 
     def __init__(self, cap: int = _STORE_CAP):
         self._spans: collections.deque = collections.deque(maxlen=cap)
+        self._setup: collections.deque = collections.deque(maxlen=_SETUP_CAP)
 
-    def add(self, rec: Dict[str, Any]) -> None:
-        self._spans.append(rec)
+    def add(self, rec: Dict[str, Any], setup: bool = False) -> None:
+        (self._setup if setup else self._spans).append(rec)
 
     def spans(self, t0: Optional[float] = None, t1: Optional[float] = None
               ) -> List[Dict[str, Any]]:
-        """The stored spans that lie wholly inside [t0, t1], oldest first."""
-        return [r for r in self._spans
+        """The stored spans that lie wholly inside [t0, t1], oldest first
+        (by their end)."""
+        return [r for r in heapq.merge(self._setup, self._spans,
+                                       key=lambda r: r["t1"])
                 if (t0 is None or r["t0"] >= t0)
                 and (t1 is None or r["t1"] <= t1)]
 
     def clear(self) -> None:
         self._spans.clear()
+        self._setup.clear()
 
     def __len__(self) -> int:
-        return len(self._spans)
+        return len(self._spans) + len(self._setup)
 
 
 _STORE = SpanStore()

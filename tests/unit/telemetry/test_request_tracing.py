@@ -72,9 +72,12 @@ def test_tracing_off_is_free_on_is_fetch_free_and_bit_identical(tiny,
     out_off = off.generate(PROMPTS, max_new_tokens=6)
     assert off.tracer.spans_recorded == 0            # free when disabled
     assert off.tracer.last_requests == {}
-    # nothing stored either, but each program's `compile` span (set-up by
-    # program is recorded whatever the tracer's state)
-    assert {s["name"] for s in store.spans()} == {"compile"}
+    # nothing stored either, but set-up, which is recorded whatever the
+    # tracer's state: each program's `compile` span, the engine's `init`
+    # span and its parts
+    assert {s["name"] for s in store.spans()} == {
+        "compile", "init", "plan", "place_params", "alloc_cache",
+        "build_programs"}
     assert off.recompiles.pinned_misses == 0
     assert off.serving_counters["token_slots_computed"] >= \
         off.serving_counters["tokens_fed"] > 0      # counted all the same
