@@ -46,15 +46,17 @@ def estimate_zero_memory(num_params: int, stage: int, dp_size: int,
 # of `hidden` (H) and `intermediate` (I). Whole-block remat ('nothing')
 # keeps only the residual stream at block boundaries; 'checkpoint_dots'
 # additionally keeps every matmul output (q/k/v/o projections + gate/up/down
-# inputs — the policy that OOMed at mbs4 and at 16k ctx on v5e, r2 ledger);
-# no remat keeps the full forward. Assumes flash attention (no S² logits).
+# inputs — the policy that OOMed at mbs4 and at 16k ctx on v5e, r2 ledger)
+# and the flash kernel's output, the size of q (its logsumexp is 4 bytes a
+# head and position); 'dots' keeps the matmul outputs alone; no remat keeps
+# the full forward. Assumes flash attention (no S² logits).
 _REMAT_FACTORS = {
     "nothing": lambda h, i: h,
     # host_offload stages the block-boundary residuals to pinned host
     # memory — their HBM share is ~0; the per-block working set (the
     # separate `working` term) still applies
     "host_offload": lambda h, i: 0,
-    "checkpoint_dots": lambda h, i: 4 * h + 3 * i,
+    "checkpoint_dots": lambda h, i: 5 * h + 3 * i,
     "dots": lambda h, i: 4 * h + 3 * i,
     None: lambda h, i: 14 * h + 4 * i,  # no remat
 }

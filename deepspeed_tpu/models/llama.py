@@ -121,25 +121,26 @@ def _host_offload_policy(*extra_names: str):
 def _remat_policy(name: str):
     if name == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if name == "checkpoint_dots":
-        # NOTE: do NOT add the named flash residuals here —
-        # save_from_both_policies(checkpoint_dots, save_only_these_names(
-        # 'flash_resid', 'flash_lse')) measured 18x SLOWER on the 2k-ctx
-        # flagship (v5e, r4): the named saves defeat XLA's scheduling of
-        # the dots-saved remat graph. The fwd-kernel re-run it would avoid
-        # is only ~2% of step FLOPs at 2k ctx; 'host_offload' (long ctx,
-        # where the re-run is ~22%) does save/offload them.
-        return jax.checkpoint_policies.checkpoint_dots
-    if name == "checkpoint_dots_gmm":
-        # checkpoint_dots + the named grouped-GEMM outputs (moe/layer.py
-        # Experts grouped path): megablox gmm is a Pallas call, not a dot,
-        # so without the named save the backward recomputes all three
-        # grouped GEMMs per MoE layer. Separate from 'checkpoint_dots'
-        # because combined-policy graphs measured pathological with flash
-        # names on the dense flagship (r4: 18x) — MoE models opt in.
+    if name in ("checkpoint_dots", "checkpoint_dots_gmm"):
+        # dot results plus the flash forward kernel's out and logsumexp
+        # (ops/pallas/flash_attention.py names them): a pallas_call is no
+        # dot, so without the names a layer's backward ran the forward
+        # kernel a second time. Measured on one v5e (PR 44, Qwen2.5-0.5B at
+        # 2 x 2048, seed 4400011001, parent and change in one call): the
+        # kernel runs 96 times a step and not 192, the step takes 589.5 ms
+        # and not 615.5, every loss is the same bit for bit; the "18x
+        # slower" of round 4 is not reproduced. A graph without the flash
+        # kernel holds no such name and the policy is checkpoint_dots.
+        names = ["flash_resid", "flash_lse"]
+        if name == "checkpoint_dots_gmm":
+            # ...and the named grouped-GEMM outputs (moe/layer.py Experts
+            # grouped path): megablox gmm is a Pallas call too, and without
+            # the name the backward recomputes all three grouped GEMMs per
+            # MoE layer. MoE configurations ask for it by this name.
+            names.append("moe_gmm")
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.checkpoint_dots,
-            jax.checkpoint_policies.save_only_these_names("moe_gmm"))
+            jax.checkpoint_policies.save_only_these_names(*names))
     if name == "host_offload":
         # FPDT's host-offload tier (reference `sequence/fpdt_layer.py:510`
         # `_FPDTGPUOffloadingAttentionImpl_` / `SequenceChunk:462` CPU↔GPU
