@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -81,6 +82,12 @@ class Sizes:
     # the grouped GEMM at a DECODE shape: rows, experts, K, N; 3 rows an
     # expert, the rest of the rows no expert's (absent assignments)
     gmm_decode: Tuple[int, int, int, int]
+    # differential decode attention over a stack of paired heads: layers,
+    # rows, groups, slots, pair width (Phi-4-mini-flash's eight rings and its
+    # one shared slab at the benchmark cell's batch and length)
+    diff_stack: Tuple[Tuple[int, int, int, int, int], ...]
+    # a Mamba-1 decode step: recurrent layers, rows, state size, channels
+    ssm_m1: Tuple[int, int, int, int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -92,7 +99,9 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              paged_batch=64, paged_blocks=96,
              prefill_batch=8, parked=(48, 18, 16), gmm_rows=4096,
              gmm_experts=64, gmm_width=1024, qmm_group=256,
-             ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856))
+             ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856),
+             diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
+             ssm_m1=(9, 64, 16, 5120))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
@@ -100,7 +109,9 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              decode_ctx=64, dense_stack=((3, 2, 8, 64), (2, 4, 16, 32)),
              paged_batch=3, paged_blocks=9, prefill_batch=2,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
-             qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48))
+             qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48),
+             diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
+             ssm_m1=(2, 4, 16, 256))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -168,7 +179,11 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_kv_write, paged_prefill_attention)
     from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
+    from deepspeed_tpu.ops.pallas.diff_attention import (
+        diff_decode_attention, diff_decode_attention_reference)
     from deepspeed_tpu.ops.pallas.ssm import (ssm_state_update,
+                                              ssm_state_update_m1,
+                                              ssm_state_update_m1_reference,
                                               ssm_state_update_reference)
     from deepspeed_tpu.ops.quantization import (dequantize_int8_blockwise,
                                                 quantize_int8_blockwise)
@@ -534,6 +549,53 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         lambda state, *rest: ssm_state_update(state, sl - 1, *rest),
         lambda state, *rest: ssm_state_update_reference(state, sl - 1, *rest),
         make_ssm))
+
+    # ---- a Mamba-1 decode step: the state lies (L, B, N, C) ----
+    ml, mb, mn, mc = sz.ssm_m1
+
+    def make_ssm_m1(key):
+        ks = jax.random.split(key, 7)
+        f32 = jnp.float32
+        return (normal(ks[0], (ml, mb, mn, mc), f32),
+                normal(ks[1], (mb, mc), f32),
+                jax.nn.softplus(normal(ks[2], (mb, mc), f32) - 3.0),
+                -jnp.exp(normal(ks[3], (mn, mc), f32)),
+                normal(ks[4], (mb, mn), f32), normal(ks[5], (mb, mn), f32),
+                normal(ks[6], (mc,), f32))
+
+    cases.append(KernelCase(
+        "ssm_state_update_m1",
+        lambda state, *rest: ssm_state_update_m1(state, ml - 1, *rest),
+        lambda state, *rest: ssm_state_update_m1_reference(state, ml - 1,
+                                                           *rest),
+        make_ssm_m1))
+
+    # ---- differential decode attention: a ring, then the shared slab ----
+    for stack, ring in zip(sz.diff_stack, (True, False)):
+        def make_diff(key, shape=stack, ring=ring):
+            l, b, g, m, w = shape
+            kq, kk, kv, kn, kl = jax.random.split(key, 5)
+            pos = jax.random.randint(kl, (b,), 0, 2 * m if ring else m,
+                                     jnp.int32)
+            # two pairs a group: [q1 | 0] twice, then [0 | q2] twice
+            q = normal(kq, (b, g, 4, w))
+            half = (jnp.arange(w) < w // 2)[None, None, None, :]
+            first = (jnp.arange(4) < 2)[None, None, :, None]
+            return (jnp.where(half == first, q, 0).astype(bf16),
+                    normal(kk, (l, b, g, m, w)), normal(kv, (l, b, g, m, w)),
+                    jnp.minimum(pos + 1, m), pos % m,
+                    normal(kn, (2, b, g, w)))
+
+        def run_diff(fn, q, k, v, lengths, slots, new, ring=ring,
+                     layer=stack[0] - 1):
+            return fn(q, k, v, layer, lengths, jnp.float32(0.7), 0.125,
+                      k_new=new[0], v_new=new[1], slots=slots, ring=ring)
+
+        cases.append(KernelCase(
+            "diff_attn_window_decode" if ring else "diff_attn_shared_decode",
+            functools.partial(run_diff, diff_decode_attention),
+            functools.partial(run_diff, diff_decode_attention_reference),
+            make_diff))
 
     # ---- block-sparse attention (MHA; layout is static host data) ----
     sblk = min(64, sz.seq // 4)

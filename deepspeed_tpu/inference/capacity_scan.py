@@ -84,13 +84,33 @@ def kv_cache_bytes(model_cfg, batch: int, max_len: int, dtype,
     knob): "int8" is the quantized cache — 1-byte payload plus one f32
     scale per (kv-head, token slot), a 4/head_dim relative overhead (≈3%
     at D=128; docs/kv_cache.md has the formula). None (or the serving
-    dtype) uses `dtype`'s width — the pre-r8 accounting unchanged."""
+    dtype) uses `dtype`'s width — the pre-r8 accounting unchanged.
+
+    A model whose layers keep K and V of different KINDS counts them itself
+    (`kv_bytes_by_kind`): the sum is what is held."""
+    kinds = kv_bytes_by_kind(model_cfg, batch, max_len, dtype)
+    if kinds:
+        return sum(kinds.values())
     d = _model_dims(model_cfg)
     slots = 2 * d["layers"] * batch * max_len * d["kv_heads"]
     if kv_dtype in ("int8", jnp.int8):
         return slots * (d["head_dim"] + 4)
     item = jnp.dtype(dtype).itemsize
     return slots * d["head_dim"] * item
+
+
+def kv_bytes_by_kind(model_cfg, batch: int, max_len: int,
+                     dtype) -> Dict[str, int]:
+    """K + V bytes by the KIND of cache that holds them (docs/kv_cache.md),
+    as the model's config counts them (`model_cfg.kv_bytes_by_kind`):
+    `window_kv_bytes`, rings of a window's slots whatever `max_len`, and
+    `shared_kv_bytes`, full-length slabs that layers without a cache of
+    their own read. Empty for a model of one kind of layer: `max_len` slots
+    a layer, all of it `kv_cache_bytes`."""
+    own = getattr(model_cfg, "kv_bytes_by_kind", None)
+    if own is None:
+        return {}
+    return {name: int(n) for name, n in own(batch, max_len, dtype).items()}
 
 
 def recurrent_state_bytes(model_cfg, batch: int, dtype) -> int:

@@ -383,7 +383,8 @@ class InferenceEngine:
         kv = self._kv_telemetry(b, key[1], key[2])
         # gauges and counters update on a disabled hub too (hub.py): what a
         # benchmark reads of a call without the JSONL stream
-        for name in ("kv_bytes", "state_bytes"):
+        for name in ("kv_bytes", "state_bytes", "window_kv_bytes",
+                     "shared_kv_bytes"):
             if name in kv:
                 hub.gauge(f"serving_v1/{name}", kv[name])
         for name, value in counted.items():
@@ -417,20 +418,24 @@ class InferenceEngine:
         the config asks for it AND this serve mode quantizes its cache
         (the layer-streamed modes keep dense KV, engine __init__ warns)."""
         from deepspeed_tpu.inference.capacity_scan import (
-            kv_cache_bytes, recurrent_state_bytes, round_up_len)
+            kv_bytes_by_kind, kv_cache_bytes, recurrent_state_bytes,
+            round_up_len)
         mode = getattr(self, "serve_mode", "dequant")
         kvd = getattr(self._config, "kv_cache_dtype", None)
         eff = kvd if (kvd == "int8" and mode == "dequant") else None
+        max_len = round_up_len(int(s) + int(new_tokens))
         try:
-            kv_b = kv_cache_bytes(self.model_cfg, int(b),
-                                  round_up_len(int(s) + int(new_tokens)),
+            kv_b = kv_cache_bytes(self.model_cfg, int(b), max_len,
                                   self._config.dtype, kv_dtype=eff)
         except Exception:
             return {}  # non-standard config dims: skip, never break serving
-        # K and V of the ATTENTION layers; what recurrent layers hold is
-        # counted apart (0 for a model that has none)
+        # K and V of the ATTENTION layers, and by kind where the model keeps
+        # more than one (rings, a shared slab); what recurrent layers hold
+        # is counted apart (0 for a model that has none)
         return {"kv_dtype": eff or jnp.dtype(self._config.dtype).name,
                 "kv_bytes": int(kv_b),
+                **kv_bytes_by_kind(self.model_cfg, int(b), max_len,
+                                   self._config.dtype),
                 "state_bytes": recurrent_state_bytes(
                     self.model_cfg, int(b), self._config.dtype)}
 

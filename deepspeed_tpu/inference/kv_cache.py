@@ -24,6 +24,7 @@ Two layouts of the dense cache (docs/kv_cache.md):
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -115,6 +116,11 @@ class KVCache:
     k: Any
     v: Any
     index: jnp.ndarray  # (B,) int32
+    # a stacked cache of WINDOW layers (static): `max_len` slots a row hold the
+    # last `max_len` positions, position p in slot p mod max_len. A key's
+    # place in the ring says nothing of its position: its reader must not
+    # ask (no mask or bias by slot; docs/kv_cache.md)
+    ring: bool = struct.field(pytree_node=False, default=False)
 
     @property
     def stacked(self) -> bool:
@@ -146,13 +152,15 @@ class KVCache:
     @classmethod
     def create_stacked(cls, num_layers: int, batch: int, max_len: int,
                        kv_heads: int, head_dim: int,
-                       dtype: Any = jnp.bfloat16) -> "KVCache":
+                       dtype: Any = jnp.bfloat16,
+                       ring: bool = False) -> "KVCache":
         """The cache in the stacked view (`DenseLayer`): for a model whose
-        cached layers take `layer_views` of it (`scan_dense_layers`)."""
+        cached layers take `layer_views` of it (`scan_dense_layers`). With
+        `ring`, `max_len` is the window: the slots a row keeps."""
         shape = (num_layers, batch, kv_heads, max_len, head_dim)
         return cls(k=DenseLayer(jnp.zeros(shape, dtype)),
                    v=DenseLayer(jnp.zeros(shape, dtype)),
-                   index=jnp.zeros((batch,), jnp.int32))
+                   index=jnp.zeros((batch,), jnp.int32), ring=ring)
 
     def layer_views(self, layer, staged: bool) -> Tuple[DenseLayer, DenseLayer]:
         """Layer `layer`'s `(k, v)` views of a stacked cache, for
@@ -162,18 +170,20 @@ class KVCache:
 
     def land(self, k_new: jnp.ndarray, v_new: jnp.ndarray) -> "KVCache":
         """A stacked cache with every layer's staged token, `k_new`/`v_new`
-        (L, B, Hkv, D), written at the cursors `[:, b, :, index[b]]`: the
-        one write of a decode step. A row whose cursor is at or past
-        `max_len` (parked) is dropped. On the chip it goes through
+        (L, B, Hkv, D), written at the cursors `[:, b, :, index[b]]` (a ring:
+        `index[b] mod max_len`): the one write of a decode step. A row whose
+        cursor is at or past `max_len` (parked) is dropped. On the chip it goes through
         `kv_write_dense`, which aliases the stacks and keeps the tiling the
         decode kernel reads (`_pool_writer` tells why no XLA scatter)."""
         write = _dense_writer(self.k.stack.shape[2])
+        # a ring keeps position p in slot p mod its length
+        at = self.index % self.max_len if self.ring else self.index
         if write is not None:
-            k, v = write(self.k.stack, self.v.stack, k_new, v_new, self.index)
+            k, v = write(self.k.stack, self.v.stack, k_new, v_new, at)
         else:
             rows = jnp.arange(self.index.shape[0])
             # an index [:, rows, :, slot] puts the rows' axis first
-            k, v = (stack.at[:, rows, :, self.index].set(
+            k, v = (stack.at[:, rows, :, at].set(
                 jnp.moveaxis(new, 1, 0).astype(stack.dtype), mode="drop")
                 for stack, new in ((self.k.stack, k_new),
                                    (self.v.stack, v_new)))
@@ -204,11 +214,15 @@ class RecurrentState:
     tokens, stacked over THOSE layers only (docs/kv_cache.md): a fixed size a
     sequence, whatever its length.
 
-    `ssm` (Lm, B, H, P, N) float32: a Mamba-2 head's state is `P x N`. It is
-    float32 at rest: the recurrence multiplies it by a decay just under 1 and
-    adds a small term every token, and bf16's 8 bits lose that term over a
-    few hundred steps. `ops/pallas/ssm.ssm_state_update` reads and writes one
-    layer of it in place a decode step; prefill writes a layer's slab whole.
+    `ssm` (Lm, B, *state_shape) float32, the shape of one sequence's state
+    being the family's: a Mamba-2 head's state is `P x N`, so `(H, P, N)`
+    (`ops/pallas/ssm.ssm_state_update`); Mamba-1 decays every (channel,
+    state) element on its own, `(N, C)` with the channels on the lanes
+    (`ops/pallas/ssm.ssm_state_update_m1`). It is float32 at rest: the
+    recurrence multiplies it by a decay just under 1 and adds a small term
+    every token, and bf16's 8 bits lose that term over a few hundred steps.
+    The kernel reads and writes one layer of it in place a decode step;
+    prefill writes a layer's slab whole.
     `conv` (Lm, B, K - 1, C): the last K - 1 inputs of the causal depthwise
     convolution, in the compute dtype."""
 
@@ -216,37 +230,46 @@ class RecurrentState:
     conv: jnp.ndarray
 
     @classmethod
-    def create(cls, num_layers: int, batch: int, heads: int, head_dim: int,
-               state_size: int, conv_kernel: int, conv_dim: int,
+    def create(cls, num_layers: int, batch: int, state_shape: Tuple[int, ...],
+               conv_kernel: int, conv_dim: int,
                dtype: Any = jnp.bfloat16) -> "RecurrentState":
         return cls(
-            ssm=jnp.zeros((num_layers, batch, heads, head_dim, state_size),
+            ssm=jnp.zeros((num_layers, batch) + tuple(state_shape),
                           jnp.float32),
             conv=jnp.zeros((num_layers, batch, conv_kernel - 1, conv_dim),
                            dtype))
 
     @staticmethod
-    def nbytes(num_layers: int, batch: int, heads: int, head_dim: int,
-               state_size: int, conv_kernel: int, conv_dim: int,
+    def nbytes(num_layers: int, batch: int, state_shape: Tuple[int, ...],
+               conv_kernel: int, conv_dim: int,
                dtype: Any = jnp.bfloat16) -> int:
         """Bytes `create` would hold: host arithmetic for the telemetry and
         the serve-mode accounting."""
         return num_layers * batch * (
-            heads * head_dim * state_size * 4
+            math.prod(state_shape) * 4
             + (conv_kernel - 1) * conv_dim * jnp.dtype(dtype).itemsize)
 
 
 @struct.dataclass
 class HybridCache:
-    """The cache of a model whose layers are of different kinds: a `KVCache`
-    over its ATTENTION layers only and a `RecurrentState` over its recurrent
-    ones, one pytree that the decode scan carries. Layers that keep nothing
-    (expert, dense FFN) have no row in either. The cursors are the
-    `KVCache`'s; `index`, `max_len` and `replace` read as a `KVCache`'s do,
-    so the engine handles it as it handles that."""
+    """The cache of a model whose layers are of different kinds, by KIND
+    (docs/kv_cache.md), one pytree that the decode scan carries:
+
+    - `kv`: a `KVCache` over the layers that keep K and V at FULL length. A
+      layer may be the only writer of its slab and later layers read it (a
+      shared slab: the readers hold nothing of their own);
+    - `window`: a ring `KVCache` (`KVCache.ring`) over the WINDOW layers, or
+      None for a model that has none;
+    - `state`: a `RecurrentState` over the recurrent layers.
+
+    Layers that keep nothing (expert, dense FFN, memory unit, a reader of a
+    shared slab) have no row in any. The cursors are `kv`'s, and `window`'s
+    are kept equal to them; `index`, `max_len` and `replace` read as a
+    `KVCache`'s do, so the engine handles it as it handles that."""
 
     kv: KVCache
     state: RecurrentState
+    window: Optional[KVCache] = None
 
     @property
     def index(self) -> jnp.ndarray:
@@ -257,32 +280,27 @@ class HybridCache:
         return self.kv.max_len
 
     def advance(self, s: int) -> "HybridCache":
-        return self.replace(kv=self.kv.replace(index=self.kv.index + s))
+        index = self.kv.index + s
+        return self.replace(
+            kv=self.kv.replace(index=index),
+            window=None if self.window is None
+            else self.window.replace(index=index))
 
     def rows(self, start, count: int) -> "HybridCache":
         """The cache of sequences `start .. start + count - 1` alone (`start`
         may be traced): what a prefill that walks the batch a few rows at a
-        time hands the layers."""
-        def cut(t, axis=1):
-            return jax.lax.dynamic_slice_in_dim(t, start, count, axis)
-        rows = functools.partial(jax.tree_util.tree_map, cut)  # either view
-        return HybridCache(
-            kv=KVCache(k=rows(self.kv.k), v=rows(self.kv.v),
-                       index=cut(self.kv.index, 0)),
-            state=RecurrentState(ssm=cut(self.state.ssm),
-                                 conv=cut(self.state.conv)))
+        time hands the layers. Every buffer of every kind is stacked over
+        its layers with the sequences second; the cursors are the only
+        leaves of one axis."""
+        return jax.tree_util.tree_map(
+            lambda t: jax.lax.dynamic_slice_in_dim(
+                t, start, count, int(t.ndim > 1)), self)
 
     def with_rows(self, part: "HybridCache", start) -> "HybridCache":
         """This cache with `part` (from `rows`) written back at `start`."""
-        def put(t, new, axis=1):
-            return jax.lax.dynamic_update_slice_in_dim(t, new, start, axis)
-        rows = functools.partial(jax.tree_util.tree_map, put)  # either view
-        return HybridCache(
-            kv=KVCache(k=rows(self.kv.k, part.kv.k),
-                       v=rows(self.kv.v, part.kv.v),
-                       index=put(self.kv.index, part.kv.index, 0)),
-            state=RecurrentState(ssm=put(self.state.ssm, part.state.ssm),
-                                 conv=put(self.state.conv, part.state.conv)))
+        return jax.tree_util.tree_map(
+            lambda t, new: jax.lax.dynamic_update_slice_in_dim(
+                t, new, start, int(t.ndim > 1)), self, part)
 
 
 @struct.dataclass

@@ -28,3 +28,5 @@ from deepspeed_tpu.models.qwen2 import (
     Qwen2Config, Qwen2ForCausalLM, qwen2_config)
 from deepspeed_tpu.models.nemotron_h import (
     NemotronHConfig, NemotronHForCausalLM, nemotron_h_loss_fn)
+from deepspeed_tpu.models.phi4flash import (
+    Phi4FlashConfig, Phi4FlashForCausalLM, phi4flash_loss_fn)

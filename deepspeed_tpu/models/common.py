@@ -97,6 +97,33 @@ def make_causal_loss_fn(model):
     return loss_fn
 
 
+def abstract_specs(model, rng=None, seq_len: int = 8):
+    """The partition specs of `model`'s parameter tree, from shapes alone."""
+    from deepspeed_tpu.utils.partitioning import extract_params_and_specs
+    rng = rng if rng is not None else jax.random.PRNGKey(0)
+    variables = jax.eval_shape(model.init, rng,
+                               jnp.zeros((1, seq_len), jnp.int32))
+    return extract_params_and_specs(variables)[1]
+
+
+def materialize(model, rng=None, seq_len: int = 8, param_dtype=None):
+    """`model`'s whole parameter tree on the device from the seed, ONE jitted
+    call; `param_dtype` casts inside it (a serving tree's float32 form beside
+    its bf16 copy fits no chip at the benchmark's sizes)."""
+    from deepspeed_tpu.utils.partitioning import extract_params_and_specs
+    rng = rng if rng is not None else jax.random.PRNGKey(0)
+    ids = jnp.zeros((1, seq_len), jnp.int32)
+
+    def init_fn(rng):
+        raw, _ = extract_params_and_specs(model.init(rng, ids))
+        if param_dtype is not None:
+            raw = jax.tree_util.tree_map(
+                lambda x: x.astype(param_dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating) else x, raw)
+        return raw
+    return jax.jit(init_fn)(rng)
+
+
 # ---------------------------------------------------------------- pipeline
 def apply_ln(sub_params, h, eps, dtype):
     """Apply a flax LayerNorm given its param subtree — pipeline head/embed

@@ -126,12 +126,17 @@ class NemotronHConfig:
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
+    @property
+    def ssm_state_shape(self) -> tuple:
+        """One sequence's state in one Mamba-2 layer: a head's is P x N."""
+        return (self.mamba_num_heads, self.mamba_head_dim,
+                self.ssm_state_size)
+
     def recurrent_state_bytes(self, batch: int, dtype=None) -> int:
         from deepspeed_tpu.inference.kv_cache import RecurrentState
         return RecurrentState.nbytes(
-            self.count("M"), batch, self.mamba_num_heads, self.mamba_head_dim,
-            self.ssm_state_size, self.conv_kernel, self.conv_dim,
-            dtype or self.dtype)
+            self.count("M"), batch, self.ssm_state_shape, self.conv_kernel,
+            self.conv_dim, dtype or self.dtype)
 
 
 # ------------------------------------------------------------------ Mamba-2
@@ -465,45 +470,26 @@ class NemotronHForCausalLM(nn.Module):
                                       cfg.num_key_value_heads, cfg.head_dim,
                                       dtype=dtype),
             state=RecurrentState.create(
-                cfg.count("M"), batch, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                cfg.ssm_state_size, cfg.conv_kernel, cfg.conv_dim, dtype=dtype))
+                cfg.count("M"), batch, cfg.ssm_state_shape, cfg.conv_kernel,
+                cfg.conv_dim, dtype=dtype))
 
 
 def init_params_and_specs(cfg: NemotronHConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.utils.partitioning import extract_params_and_specs
+    from deepspeed_tpu.models.common import abstract_specs
     model = NemotronHForCausalLM(cfg)
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    variables = jax.eval_shape(model.init, rng,
-                               jnp.zeros((1, seq_len), jnp.int32))
-    return model, extract_params_and_specs(variables)[1]
+    return model, abstract_specs(model, rng, seq_len)
 
 
 def materialize_params(cfg: NemotronHConfig, rng=None, seq_len: int = 8,
                        param_dtype=None):
     """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree of the serving cut is
-    18 GB and fits no chip beside its bf16 copy)."""
-    from deepspeed_tpu.utils.partitioning import extract_params_and_specs
+    `param_dtype` casts inside it (the float32 tree is 18 GB and fits no
+    chip beside its bf16 copy)."""
+    from deepspeed_tpu.models.common import materialize
     model = NemotronHForCausalLM(cfg)
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    ids = jnp.zeros((1, seq_len), jnp.int32)
-
-    def init_fn(rng):
-        raw, _ = extract_params_and_specs(model.init(rng, ids))
-        if param_dtype is not None:
-            raw = jax.tree_util.tree_map(
-                lambda x: x.astype(param_dtype)
-                if jnp.issubdtype(x.dtype, jnp.floating) else x, raw)
-        return raw
-    return model, jax.jit(init_fn)(rng)
+    return model, materialize(model, rng, seq_len, param_dtype)
 
 
 def nemotron_h_loss_fn(model: NemotronHForCausalLM):
-    from deepspeed_tpu.models.common import shift_labels
-
-    def loss_fn(params, batch, rng):
-        ids = batch["input_ids"]
-        labels = batch.get("labels")
-        return model.apply({"params": params}, ids,
-                           labels=shift_labels(ids) if labels is None else labels)
-    return loss_fn
+    from deepspeed_tpu.models.common import make_causal_loss_fn
+    return make_causal_loss_fn(model)

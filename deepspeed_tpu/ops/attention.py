@@ -152,6 +152,47 @@ def blockwise_attention(q, k, v, causal: bool = True,
     return jnp.swapaxes(out, 1, 2)
 
 
+def banded_attention(q, k, v, window: int,
+                     softmax_scale: Optional[float] = None) -> jnp.ndarray:
+    """Causal attention under a sliding `window` (a query at t sees keys
+    t - window + 1 .. t) over whole sequences, as pure XLA, for sequences of
+    several windows: queries go a block of `window` at a time and meet the
+    two blocks of keys that hold their band, so the work is 2 x S x window
+    where `blockwise_attention` scans all S x S / 2 block pairs and masks
+    most of them (PERF.md, PR 45: 10.6 ms against 30.4 at 8 x 2048 tokens,
+    40 heads of 128 on 10, window 512). q (B, S, H, D), k/v (B, S, Hkv, D),
+    any S; grouped, so no head is repeated. Live logits are
+    (B, H, window, 2 window) float32."""
+    b, s, h, d = q.shape
+    g = k.shape[2]
+    w = window
+    scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
+    pad = -s % w
+    # keys: one window of nothing in front (block 0's "block before"), and
+    # the tail padded like the queries'; padded queries' rows are cut off
+    q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    k, v = (jnp.pad(t, ((0, 0), (w, pad), (0, 0), (0, 0))) for t in (k, v))
+    qi = jnp.arange(w)[:, None]
+    kj = jnp.arange(2 * w)[None, :]
+    band = (kj <= qi + w) & (kj > qi)       # key block starts a window back
+
+    def block(i):
+        qb = jax.lax.dynamic_slice_in_dim(q, i * w, w, 1).reshape(
+            b, w, g, h // g, d)
+        kb, vb = (jax.lax.dynamic_slice_in_dim(t, i * w, 2 * w, 1)
+                  for t in (k, v))
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
+                            preferred_element_type=jnp.float32) * scale
+        keep = band & (kj + i * w >= w)     # nothing before position 0
+        probs = jax.nn.softmax(jnp.where(
+            keep, logits, jnp.finfo(jnp.float32).min), axis=-1)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(q.dtype),
+                          vb).reshape(b, w, h, d)
+
+    out = jax.lax.map(block, jnp.arange((s + pad) // w))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s + pad, h, d)[:, :s]
+
+
 def _use_pallas() -> bool:
     if os.environ.get("DS_TPU_DISABLE_PALLAS"):
         return False
@@ -500,6 +541,38 @@ def _stacked_dense_attention(q, k_cache, v_cache, index, mask, impl, window,
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrqk,bgkd->bqgrd", probs, v).reshape(b, s, h, d)
+
+
+def diff_decode(q, k_cache, v_cache, lengths, lam, softmax_scale: float,
+                eps: float, k_new=None, v_new=None, slots=None,
+                ring: bool = False):
+    """One decode step of differential attention over a stacked cache of
+    paired heads (`ops/pallas/diff_attention.py` has the layout): q
+    (B, G, 2r, W), `k_cache`/`v_cache` `DenseLayer` views of the
+    (L, B, G, M, W) stacks, `lengths` (B,) valid slots, and the row's staged
+    token `k_new`/`v_new` (B, G, W) standing in slot `slots[b]`. Returns
+    `RMSNorm(a1 - lam a2)` (B, G, r, W) float32, without a weight.
+
+    The Pallas kernel on the chip, where a pair is whole lanes and the
+    program is one device's (a bare Mosaic call cannot be partitioned);
+    elsewhere the same in plain `jax.numpy`."""
+    from deepspeed_tpu.ops.pallas import diff_attention as da
+    kernel = _use_pallas() and q.shape[-1] % 128 == 0
+    if kernel:
+        from deepspeed_tpu.ops.pallas.sharded import (_topology_mesh,
+                                                      kernel_fallback,
+                                                      nontrivial_axes)
+        topo = _topology_mesh()
+        if topo is not None and nontrivial_axes(topo):
+            kernel_fallback("diff_decode_attention",
+                            f"mesh axes {nontrivial_axes(topo)}: the kernel "
+                            "is one device's")
+            kernel = False
+    fn = da.diff_decode_attention if kernel \
+        else da.diff_decode_attention_reference
+    return fn(q, k_cache.stack, v_cache.stack, k_cache.layer, lengths, lam,
+              softmax_scale, eps, k_new=k_new, v_new=v_new, slots=slots,
+              ring=ring)
 
 
 def rms_norm_ref(x, weight, eps: float = 1e-6):

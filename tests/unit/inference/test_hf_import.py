@@ -292,6 +292,11 @@ def test_qwen2_moe_import(tmp_path):
         num_experts_per_tok=2, moe_intermediate_size=32,
         shared_expert_intermediate_size=64, norm_topk_prob=False,
         capacity_factor=100.0, max_position_embeddings=128, remat=False)
+    # the HF weights from a seed of their own: drawn from whatever state the
+    # worker's torch generator is in, one draw in some tens has a gate near
+    # enough a tie to move more than 1% of the logits (PR 45: the second of
+    # two whole runs of the suite)
+    torch.manual_seed(0)
     _logits_parity(transformers.Qwen2MoeForCausalLM(cfg), tmp_path,
                    tie_tolerant=True, config=zoo_cfg)
 

@@ -31,6 +31,9 @@ KERNEL_NAMES = {"flash_fwd_bwd": {"self_attn_flash_fwd", "self_attn_flash_bwd"},
                 "paged_prefill": {"self_attn_paged_prefill"}}
 # other kernels the benchmark's metrics find by name
 OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
+               "ssm_state_update_m1": "ssm_state_update_m1",
+               "diff_attn_window_decode": "diff_attn_window_decode",
+               "diff_attn_shared_decode": "diff_attn_shared_decode",
                "grouped_gemm_decode": "gmm"}
 
 
@@ -59,11 +62,12 @@ def compiled_kernels(monkeypatch):
     """Off the chip every kernel module answers `_interpret()` with True;
     steer them to the compiled path here, in the test."""
     from deepspeed_tpu.ops.pallas import (
-        block_sparse_attention, decode_attention, flash_attention,
-        grouped_gemm, paged_attention, quantized_matmul, ssm)
+        block_sparse_attention, decode_attention, diff_attention,
+        flash_attention, grouped_gemm, paged_attention, quantized_matmul, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
-    for mod in (block_sparse_attention, decode_attention, flash_attention,
-                grouped_gemm, paged_attention, quantized_matmul, ssm):
+    for mod in (block_sparse_attention, decode_attention, diff_attention,
+                flash_attention, grouped_gemm, paged_attention,
+                quantized_matmul, ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
