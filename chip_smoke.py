@@ -315,13 +315,14 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     # ---- paged decode / prefill (v2): block tables over a shared pool ----
     bs, nb, t = sz.block, sz.paged_blocks, sz.v2_max_seq // sz.block
 
-    def make_paged(batch, s, layers=0, t=t, live_every=1):
+    def make_paged(batch, s, layers=0, t=t, live_every=1, ragged=False):
         """`layers` > 0: the pools are a stack of that many, as the v2
         programs hold them, and the last input is the layer to read (the
         last one, so that a kernel that read layer 0 would be wrong).
         `live_every` > 1: only every so-manieth row holds a request; the
         others are parked as the v2 engine parks them, past capacity with a
-        table of -1 (docs/kv_cache.md)."""
+        table of -1 (docs/kv_cache.md). `ragged`: the live rows' lengths
+        run from 1 to `t * bs - 1`, evenly, instead of being drawn."""
         pool = ((layers,) if layers else ()) + (hkv, nb, bs, d)
 
         def make(key):
@@ -331,6 +332,10 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             # decode: valid tokens per row; prefill: where the s new start
             cursor = jax.random.randint(kl, (batch,), 1, t * bs - s + 1,
                                         jnp.int32)
+            if ragged:
+                n_live = -(-batch // live_every)
+                cursor = 1 + (jnp.arange(batch) // live_every) * (
+                    t * bs - 2) // max(n_live - 1, 1)
             live = jnp.arange(batch) % live_every == 0
             tables = jnp.where(live[:, None], tables, -1)
             cursor = jnp.where(live, cursor, t * bs + 1)
@@ -384,6 +389,12 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             return jnp.where(parked, jnp.asarray(alone, out.dtype), out)
         return fn
 
+    def staged_stacked(q, kp, vp, tb, ln, new, layer):
+        """What a v2 decode round hands over: the whole stacked pool, the
+        layer to read, the step's new token staged."""
+        return paged_decode_attention(q, kp, vp, tb, ln, k_new=new[0],
+                                      v_new=new[1], layer=layer)
+
     pb, fb = sz.paged_batch, sz.prefill_batch
     cases += [
         KernelCase("paged_decode_bf16", paged_decode, paged_ref,
@@ -394,25 +405,28 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                    lambda *a: paged_ref(*a, staged=True), make_paged(pb, 1)),
         KernelCase("paged_decode_int8kv", *int8_kv(paged_decode, paged_ref),
                    make_paged(pb, 1)),
-        # the operand the v2 programs hand over: the whole stacked pool
-        # and the layer to read (staged, as a decode round has it)
-        KernelCase("paged_decode_stacked_staged",
-                   lambda q, kp, vp, tb, ln, new, layer:
-                   paged_decode_attention(q, kp, vp, tb, ln, k_new=new[0],
-                                          v_new=new[1], layer=layer),
+        KernelCase("paged_decode_stacked_staged", staged_stacked,
                    of_layer(paged_ref, staged=True), make_paged(pb, 1, 3)),
         KernelCase("paged_decode_stacked_int8kv",
                    *int8_kv(paged_decode, of_layer(paged_ref)),
                    make_paged(pb, 1, 3)),
         # the serving cell's decode half, one row in six holding a request:
-        # the kernel runs no step for the others (PR 31)
-        KernelCase("paged_decode_parked",
-                   lambda q, kp, vp, tb, ln, new, layer:
-                   paged_decode_attention(q, kp, vp, tb, ln, k_new=new[0],
-                                          v_new=new[1], layer=layer),
+        # the others cost the kernel a step that copies nothing (PRs 31, 46)
+        KernelCase("paged_decode_parked", staged_stacked,
                    parked_rows(of_layer(paged_ref, staged=True), staged=True),
                    make_paged(sz.parked[0], 1, 3, t=sz.parked[1],
                               live_every=6)),
+        # the same batch with every length a row can have between its live
+        # rows, one token to a token short of capacity, parked rows between
+        # them: each row walks its own blocks and no other (PR 46)
+        KernelCase("paged_decode_ragged", staged_stacked,
+                   parked_rows(of_layer(paged_ref, staged=True), staged=True),
+                   make_paged(sz.parked[0], 1, 3, t=sz.parked[1],
+                              live_every=2, ragged=True)),
+        KernelCase("paged_decode_ragged_int8kv",
+                   *int8_kv(paged_decode, parked_rows(of_layer(paged_ref))),
+                   make_paged(sz.parked[0], 1, 3, t=sz.parked[1],
+                              live_every=2, ragged=True)),
     ]
     # the slowest to compile (8 s each for the described chip): last, so
     # a test window that closes early has seen the other twenty-seven

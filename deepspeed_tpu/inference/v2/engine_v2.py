@@ -241,7 +241,11 @@ class InferenceEngineV2:
             # hold nothing, and the paged attention kernels skip them), and
             # chunk rows that went to a prompt beyond its first of the round
             # (they would have been parked)
-            "rows_parked": 0, "rows_refilled": 0}
+            "rows_parked": 0, "rows_refilled": 0,
+            # (row, block) pairs of the decode rows that held tokens, which
+            # is what the paged decode kernel walks, against the entries of
+            # their block tables (rows x T), which is what it used to
+            "kv_blocks_live": 0, "kv_blocks_table": 0}
         self._kv_util_peak = 0.0
         self._rng = jax.random.PRNGKey(0)
         self._setup_spec()
@@ -1411,6 +1415,25 @@ class InferenceEngineV2:
             fields["rows_live"] = live
             fields["rows_parked"] = rows - live
 
+    def _count_blocks(self, fields=None, steps: int = 1) -> None:
+        """Pool blocks that hold tokens under the decode rows of the
+        dispatched program, one count a (row, block) pair and decode step,
+        against the entries of the block table (rows x T): the paged decode
+        kernel walks the former. From the host's mirror of the cursors (a
+        wave's rows advance a token a step); counted like `_count_slots`."""
+        if self.kv_layout != "paged":
+            return
+        blocks_for = self.state_manager.blocks_for
+        live = sum(blocks_for(seq.seen_tokens + i)
+                   for seq in self.state_manager.tracked_sequences.values()
+                   if self._unparked[seq.slot] for i in range(steps))
+        table = steps * self._tables_np.size
+        self.serving_counters["kv_blocks_live"] += live
+        self.serving_counters["kv_blocks_table"] += table
+        if fields is not None:
+            fields["kv_blocks_live"] = live
+            fields["kv_blocks_table"] = table
+
     def put(self, batch_uids: Sequence[int], batch_tokens: Sequence[np.ndarray],
             argmax_only: bool = False) -> Dict[int, np.ndarray]:
         """Schedule tokens for each uid (reference `put:107`): prompts for
@@ -1695,6 +1718,7 @@ class InferenceEngineV2:
                     # is still parked in it
                     self._count_rows(R + self.max_batch,
                                      n_rows + int(self._unparked.sum()), cf)
+                    self._count_blocks(cf)
                 else:
                     self._count_slots(R * csz, fed, cf)
                     self._count_rows(R, n_rows, cf)
@@ -1744,6 +1768,7 @@ class InferenceEngineV2:
                                       len(piece) + len(decode_uids), cf)
                     self._count_rows(1 + self.max_batch,
                                      1 + int(self._unparked.sum()), cf)
+                    self._count_blocks(cf)
                 else:
                     self._count_slots(csz, len(piece), cf)
                     self._count_rows(1, 1, cf)
@@ -1788,6 +1813,7 @@ class InferenceEngineV2:
                 self._count_slots(self.max_batch, len(decode_uids), df)
                 self._count_rows(self.max_batch, int(self._unparked.sum()),
                                  df)
+                self._count_blocks(df)
                 sync()
                 self.cache, logits = dispatch(fn, tokens, active)
                 phase("fetch")
@@ -2073,6 +2099,7 @@ class InferenceEngineV2:
                     self._count_slots(k * self.max_batch, k * len(live), wf)
                     self._count_rows(k * self.max_batch,
                                      k * int(self._unparked.sum()), wf)
+                    self._count_blocks(wf, steps=k)
                     self.serving_counters["decode_waves"] += 1
                     retired = []
                     for uid in list(live):

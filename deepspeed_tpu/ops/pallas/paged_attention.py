@@ -4,22 +4,38 @@ The blocked-flash slot of the reference's FastGen kernel set
 (`inference/v2/kernels/ragged_ops/blocked_flash/`, driven by the block
 tables of `inference/v2/ragged/blocked_allocator.py` /
 `sequence_descriptor.py`): one new query token per sequence attends only the
-physical KV blocks its block table names. The block table and per-row
-lengths arrive via scalar prefetch; the KV index map resolves logical block
-j of row b to `tables[b, j]` in the pool, and steps past a row's length are
-clamped to its last live block so Pallas elides their HBM copies — the
-kernel reads exactly the live blocks, which is what makes cache HBM (and
-decode bandwidth) scale with tokens in flight instead of max_batch·max_seq.
+physical KV blocks its block table names. The block table, the per-row
+lengths and the layer arrive via scalar prefetch; the pools stay in HBM
+(`memory_space=pl.ANY`) and the kernel copies logical block j of row b, pool
+block `tables[b, j]`, into VMEM itself. It reads exactly the live blocks,
+which is what makes cache HBM (and decode bandwidth) scale with tokens in
+flight instead of max_batch·max_seq.
 
-Grid (B, T) with WHOLE-HEAD tiles: each step DMAs one physical block for
-ALL Hkv KV heads — an (Hkv, BS, D) slab against the full (Hkv·n_rep, D)
-query tile. The r3 layout ran grid (B, Hkv, T) with one (n_rep, D) query
-sliver per step; at MHA (n_rep=1) that is B·Hkv·T programs of (1, D) work
-each, and per-step grid overhead dominated the whole serving loop (measured
-3.3 ms/layer at B=64, Hkv=8, T=4 on v5e — ~2048 programs of ~30 µs of
-actual memory traffic). Folding Hkv into the tile cuts grid steps by Hkv
-and makes every DMA Hkv× larger; same-shape chained-loop time dropped to
-~0.17 ms (≈20×).
+Grid (B,), the block loop inside (PR 46): a step is one row. It reads the
+row's pool length from the prefetched scalars, derives the span of logical
+blocks that hold a column the query attends (`lo`: the window's first
+block, else 0; `n`: the blocks below the length) and walks `lo..n-1` with
+two VMEM slots a pool: start block j+1, wait for block j, one online-softmax
+update over it, in ascending block order, float32 accumulators. On a row's
+LAST block the copy it starts is the first block of the next row that has
+one (found by a scalar scan over the lengths), so a row boundary exposes no
+DMA latency; the steps therefore run one after another ("arbitrary"), and
+the slot that copy lands in is handed over in SMEM. Work is the live
+(row, block) pairs plus one small constant a row (the step, the q / staged /
+output tiles); NOTHING is proportional to the table's length T. Before PR
+46 the grid was (B, T), one BlockSpec-pipelined block a step with the dead
+steps clamped onto a repeated block: 48 x 18 steps a layer at the serving
+cell's shape, of which five rows' worth computed, at about 0.19 us a dead
+step (PERF.md, PR 46: 182 -> 39 us a call at one row in six live, 262 ->
+140 at all rows live).
+
+WHOLE-HEAD tiles: each copy brings one physical block for ALL Hkv KV heads,
+an (Hkv, BS, D) slab against the full (Hkv·n_rep, D) query tile. The r3
+layout ran grid (B, Hkv, T) with one (n_rep, D) query sliver per step; at
+MHA (n_rep=1) that is B·Hkv·T programs of (1, D) work each, and per-step
+grid overhead dominated the whole serving loop (measured 3.3 ms/layer at
+B=64, Hkv=8, T=4 on v5e). Folding Hkv into the tile cut grid steps by Hkv
+and made every DMA Hkv× larger (≈20×).
 
 Layout: q (B, 1, H, D); pools (L, Hkv, NB, BS, D) as stored by
 `inference/kv_cache.py:PagedKVCache`, with the layer to read as a third
@@ -37,7 +53,9 @@ for such a row: decode for `lengths > T * BS` (its caller passes cursor +
 1), prefill for `starts >= T * BS`. A row that holds a request can never
 meet the test (it decodes at a cursor of at most `T * BS - 1` and prefills
 with `start + valid <= T * BS`, `valid >= 1`), and runs exactly the steps
-it ran. The writer `paged_kv_write` drops the same rows.
+it ran. In decode a parked row has no live block: it copies nothing, and
+its step writes zeros, or its staged value. The writer `paged_kv_write`
+drops the same rows.
 """
 
 from __future__ import annotations
@@ -54,41 +72,83 @@ from deepspeed_tpu.ops.pallas import _interpret
 from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
 
 
-def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
-                  o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, bs, nt, hkv, n_rep, d,
-                  window=None, kn_ref=None, vn_ref=None, alibi_ref=None,
-                  ks_ref=None, vs_ref=None):
+def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, *refs, scale, bs,
+                  nb, nrows, hkv, n_rep, d, window, quantized, staged,
+                  has_alibi):
+    """One grid step: one row of the batch, walking ITS OWN live blocks.
+    Refs after `q_ref`, in order: the pools as they lie in HBM (K, V, then
+    their scales when `quantized`), the staged pair, the alibi slopes, the
+    output; then scratch: a two-slot VMEM buffer a pool, the DMA semaphores
+    (pool, slot), the slot the next first block lands in (SMEM), and the
+    online-softmax state m / l / acc."""
+    refs = list(refs)
+    npool = 4 if quantized else 2
+    hbm = [refs.pop(0) for _ in range(npool)]
+    kn_ref, vn_ref = (refs.pop(0), refs.pop(0)) if staged else (None, None)
+    alibi_ref = refs.pop(0) if has_alibi else None
+    o_ref = refs.pop(0)
+    bufs = [refs.pop(0) for _ in range(npool)]
+    sems, slot_ref, m_scr, l_scr, acc_scr = refs
+    h = hkv * n_rep
+    qoff = 1 if staged else 0
+    layer = layer_ref[0]
     b = pl.program_id(0)
-    j = pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def span(r):
+        """Row r's live logical blocks [lo, n): below its pool length and,
+        with a window, not wholly below the band. A parked row arrives with
+        a pool length of 0 (the wrapper): it has none."""
+        length = lengths_ref[r]
+        n = (length + bs - 1) // bs
+        if window is None:
+            return jnp.int32(0), n
+        # lowest valid col = (L-1+qoff) - window + 1
+        return jnp.maximum(length + qoff - window, 0) // bs, n
+
+    def next_live(r):
+        """The first row at or after r with a block to read, or nrows."""
+        def dead(r):
+            lo, n = span(jnp.minimum(r, nrows - 1))
+            return jnp.logical_and(r < nrows, lo >= n)
+        return jax.lax.while_loop(dead, lambda r: r + 1, r)
+
+    def copies(r, j, slot):
+        """Logical block j of row r -> slot, every pool's; the table entry
+        is clamped so a stale row can never index out of pool."""
+        phys = jnp.clip(tables_ref[r, j], 0, nb - 1)
+        return [pltpu.make_async_copy(pool.at[layer, :, phys], buf.at[slot],
+                                      sems.at[i, slot])
+                for i, (pool, buf) in enumerate(zip(hbm, bufs))]
+
+    def start(r, j, slot):
+        for c in copies(r, j, slot):
+            c.start()
+
+    @pl.when(b == 0)
+    def _prime():
+        # nobody runs before the first live row to fetch its first block
+        slot_ref[0] = 0
+        r = next_live(jnp.int32(0))
+
+        @pl.when(r < nrows)
+        def _():
+            start(r, span(r)[0], 0)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
 
     length = lengths_ref[b]
-    h = hkv * n_rep
     # the query's absolute position: last pool slot, or one past it when
     # the new token is staged in-register
-    qpos = length - 1 + (1 if kn_ref is not None else 0)
+    qpos = length - 1 + qoff
+    lo, n = span(b)
 
-    # fully-dead logical blocks: no compute; a parked row arrives with a
-    # pool length of 0 (the wrapper), so all of its blocks are dead
-    live = j * bs < length
-    if window is not None:
-        # sliding window: only cols in (qpos − window, qpos] attend —
-        # blocks entirely below the band skip compute too (their DMAs are
-        # already elided by the index-map lo clamp)
-        live = jnp.logical_and(live, (j + 1) * bs > qpos - window)
-
-    @pl.when(live)
-    def _compute():
+    def attend(j, slot):
         q = q_ref[0].reshape(hkv, n_rep, d)  # the full head set, grouped
-        k = k_ref[:, 0]                      # (Hkv, BS, D) — one block, all heads
-        v = v_ref[:, 0]
-        if ks_ref is not None:
+        k = bufs[0][slot]                    # (Hkv, BS, D) — one block, all heads
+        v = bufs[1][slot]
+        if quantized:
             # int8 pool: the r6 scale-into-activation fold, attention
             # form — per-(head, slot) scales ride the LOGIT columns
             # (`(q·k_q)·s_j`, token scales live along lanes exactly like
@@ -98,7 +158,7 @@ def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
                 q.astype(jnp.float32), k.astype(jnp.float32),
                 (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)
-            s3 = s3 * ks_ref[:, 0][:, None, :]       # (Hkv, n_rep, BS)
+            s3 = s3 * bufs[2][slot][:, 0][:, None, :]    # (Hkv, n_rep, BS)
             s = s3.reshape(h, bs) * scale
         else:
             s = jax.lax.dot_general(
@@ -116,8 +176,8 @@ def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if vs_ref is not None:
-            p3 = p.reshape(hkv, n_rep, bs) * vs_ref[:, 0][:, None, :]
+        if quantized:
+            p3 = p.reshape(hkv, n_rep, bs) * bufs[3][slot][:, 0][:, None, :]
             pv = jax.lax.dot_general(
                 p3, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32).reshape(h, d)
@@ -129,32 +189,57 @@ def _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:, :1] = m_new
 
-    @pl.when(j == nt - 1)
-    def _finalize():
-        if kn_ref is not None:
-            # staged append (see kv_cache.PagedLayer.stage): the row's NEW
-            # token is not in the pool yet — fold its single key/value
-            # column (at position qpos, always inside its own window) into
-            # the online-softmax state in-register
-            q = q_ref[0].reshape(hkv, n_rep, d)
-            kn = kn_ref[0]                   # (Hkv, D)
-            vn = vn_ref[0].astype(jnp.float32)
-            sn = (jnp.sum(q.astype(jnp.float32) *
-                          kn.astype(jnp.float32)[:, None, :], axis=-1)
-                  .reshape(h, 1) * scale)    # (H, 1)
-            if alibi_ref is not None:
-                sn = sn + alibi_ref[:, :1] * qpos.astype(jnp.float32)  # (H,1)
-            m_prev = m_scr[:, :1]
-            m_new = jnp.maximum(m_prev, sn)
-            alpha = jnp.exp(m_prev - m_new)
-            pn = jnp.exp(sn - m_new)
-            l_scr[:, :1] = l_scr[:, :1] * alpha + pn
-            vb = jnp.broadcast_to(vn[:, None, :], (hkv, n_rep, d)).reshape(h, d)
-            acc_scr[:] = acc_scr[:] * alpha + pn * vb
-            m_scr[:, :1] = m_new
-        l = l_scr[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+    @pl.when(lo < n)
+    def _walk():
+        first = slot_ref[0]      # where this row's block `lo` is landing
+        # what follows this row's last block: the next live row's first, in
+        # flight while this row finishes, so that a row boundary exposes no
+        # DMA latency (the steps run one after another)
+        nxt = next_live(b + 1)
+        nxt_row = jnp.minimum(nxt, nrows - 1)
+        nxt_lo = span(nxt_row)[0]
+
+        def visit(j, _):
+            slot = (first + j - lo) & 1
+            more = j + 1 < n
+
+            @pl.when(jnp.logical_or(more, nxt < nrows))
+            def _():
+                start(jnp.where(more, b, nxt_row),
+                      jnp.where(more, j + 1, nxt_lo), 1 - slot)
+
+            for c in copies(b, j, slot):
+                c.wait()
+            attend(j, slot)
+            return 0
+
+        jax.lax.fori_loop(lo, n, visit, 0)
+        slot_ref[0] = (first + n - lo) & 1
+
+    if staged:
+        # staged append (see kv_cache.PagedLayer.stage): the row's NEW
+        # token is not in the pool yet — fold its single key/value
+        # column (at position qpos, always inside its own window) into
+        # the online-softmax state in-register
+        q = q_ref[0].reshape(hkv, n_rep, d)
+        kn = kn_ref[0]                   # (Hkv, D)
+        vn = vn_ref[0].astype(jnp.float32)
+        sn = (jnp.sum(q.astype(jnp.float32) *
+                      kn.astype(jnp.float32)[:, None, :], axis=-1)
+              .reshape(h, 1) * scale)    # (H, 1)
+        if alibi_ref is not None:
+            sn = sn + alibi_ref[:, :1] * qpos.astype(jnp.float32)  # (H,1)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, sn)
+        alpha = jnp.exp(m_prev - m_new)
+        pn = jnp.exp(sn - m_new)
+        l_scr[:, :1] = l_scr[:, :1] * alpha + pn
+        vb = jnp.broadcast_to(vn[:, None, :], (hkv, n_rep, d)).reshape(h, d)
+        acc_scr[:] = acc_scr[:] * alpha + pn * vb
+        m_scr[:, :1] = m_new
+    l = l_scr[:, :1]
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
 def _stacked_pools(k_pool, v_pool, k_scales, v_scales, layer):
@@ -192,25 +277,6 @@ def _scale_block_spec(hkv: int, bs: int, index_map) -> pl.BlockSpec:
     return pl.BlockSpec((None, hkv, None, 1, bs), index_map)
 
 
-def _mk_paged_kernel(quantized: bool, staged: bool, has_alibi: bool):
-    """Fixed-arity wrapper for one (quantized, staged, alibi) variant —
-    pallas passes refs positionally in args order (scales right after the
-    pools, then the staged pair, then alibi, then out + scratch)."""
-    def wrapper(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref,
-                *rest, **kw):
-        extra = list(rest[:-4])
-        o_ref, m_scr, l_scr, acc_scr = rest[-4:]
-        if quantized:
-            kw["ks_ref"], kw["vs_ref"] = extra.pop(0), extra.pop(0)
-        if staged:
-            kw["kn_ref"], kw["vn_ref"] = extra.pop(0), extra.pop(0)
-        if has_alibi:
-            kw["alibi_ref"] = extra.pop(0)
-        _paged_kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref,
-                      v_ref, o_ref, m_scr, l_scr, acc_scr, **kw)
-    return wrapper
-
-
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, tables: jnp.ndarray,
                            lengths: jnp.ndarray,
@@ -229,20 +295,20 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     `k_new`/`v_new` (B, Hkv, D) the LAST valid token is the staged one
     (not yet in the pool) and is folded in-register; without them the new
     token's slot must already be written. A row with `lengths > T * BS`
-    is parked (its cursor stands at capacity, it holds nothing): it runs no
-    compute step and stays on one pool block, and comes back as zeros, or
-    as its staged value when one is folded.
+    is parked (its cursor stands at capacity, it holds nothing): it copies
+    no pool block and computes over none, and comes back as zeros, or as
+    its staged value when one is folded.
 
     `k_scales`/`v_scales` ([L,] Hkv, NB, BS) f32: int8-at-rest pools — the
-    per-(kv-head, slot) dequant scales, DMA'd beside their blocks (same
-    index map) and folded into logit/probability columns in-register
+    per-(kv-head, slot) dequant scales, copied beside their blocks (same
+    pool block) and folded into logit/probability columns in-register
     (docs/kv_cache.md); staged tokens arrive in the compute dtype and are
     folded exactly. With unit scales the output is bitwise identical to
     the unquantized kernel on the same values (the interpret-parity test).
 
     `window`: sliding-window attention (mistral) — only the last `window`
-    positions attend; blocks below the band skip BOTH compute and DMA
-    (index-map lo clamp). `alibi`: (H,) per-head slopes added as
+    positions attend; blocks below the band are not walked (neither
+    copied nor computed). `alibi`: (H,) per-head slopes added as
     slopes[h]·key_position (bloom). These remove the r3 engine's silent
     dense fallback for masked-decode families. Returns (B, 1, H, D)."""
     b, s, h, d = q.shape
@@ -254,76 +320,66 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     n_rep = h // hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
     staged = k_new is not None
-    qoff = 1 if staged else 0
+    quantized = k_scales is not None
 
     # (B, H, D): head g·n_rep+r of the HF layout is group g, member r —
     # repeat_kv's grouping; the kernel re-splits (H, D) → (Hkv, n_rep, D)
     qt = jnp.swapaxes(q, 1, 2).reshape(b, h, d)
     # staged: pool holds lengths-1 valid tokens (the last is in-register);
     # a parked row (cursor at capacity) holds none: with a pool length of 0
-    # none of its blocks is live and its index map never leaves block 0 of
-    # its table, in every variant below
+    # it has no live block and fetches nothing, in every variant below
     pool_len = jnp.where(lengths > t * bs, 0,
                          lengths - 1 if staged else lengths)
 
-    def kv_index(b_, j, L, Tb, Ly):
-        # Clamp the logical block index into the row's LIVE band; repeated
-        # physical ids make Pallas skip the HBM copies (above the cursor
-        # AND, with a window, below the band). Clamp the table entry so a
-        # stale row can never index out of pool.
-        last = jnp.maximum((L[b_] + bs - 1) // bs - 1, 0)
-        jj = jnp.minimum(j, last)
-        if window is not None:
-            # lowest valid col = (L-1+qoff) - window + 1
-            lo = jnp.maximum((L[b_] + qoff - window) // bs, 0)
-            jj = jnp.maximum(jj, jnp.minimum(lo, last))
-        phys = Tb[b_, jj]
-        return (Ly[0], 0, jnp.clip(phys, 0, nb - 1), 0, 0)
+    def row(*tail):  # this step's row of a per-row operand
+        return pl.BlockSpec((1,) + tail,
+                            lambda b_, L, Tb, Ly: (b_,) + (0,) * len(tail))
 
-    def row(b_, j, L, Tb, Ly):
-        return (b_, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, h, d), row),
-        _pool_block_spec(hkv, bs, d, kv_index),
-        _pool_block_spec(hkv, bs, d, kv_index),
-    ]
-    args = [pool_len.astype(jnp.int32), tables.astype(jnp.int32), layer,
-            qt, k_pool, v_pool]
-    quantized = k_scales is not None
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    pools = [k_pool, v_pool]
+    slots = [pltpu.VMEM((2, hkv, bs, d), k_pool.dtype),
+             pltpu.VMEM((2, hkv, bs, d), v_pool.dtype)]
     if quantized:
-        in_specs += [_scale_block_spec(hkv, bs, kv_index)] * 2
-        args += [_scale_operand(k_scales), _scale_operand(v_scales)]
+        pools += [_scale_operand(k_scales), _scale_operand(v_scales)]
+        slots += [pltpu.VMEM((2, hkv, 1, bs), jnp.float32)] * 2
+    in_specs = [row(h, d)] + [hbm] * len(pools)
+    args = [pool_len.astype(jnp.int32), tables.astype(jnp.int32), layer,
+            qt] + pools
     if staged:
-        in_specs += [pl.BlockSpec((1, hkv, d), row)] * 2
+        in_specs += [row(hkv, d)] * 2
         args += [k_new, v_new]
     if alibi is not None:
         # (H, max(BS,128)) broadcast: Mosaic supports lane SLICES of a 2D
         # tile but not reshaping a lane vector into sublanes; the kernel
         # reads [:, :bs] ([:, :1] for the staged column)
         lw = max(bs, 128)
-        in_specs += [pl.BlockSpec((h, lw), lambda b_, j, L, Tb, Ly: (0, 0))]
+        in_specs += [pl.BlockSpec((h, lw), lambda b_, L, Tb, Ly: (0, 0))]
         args += [jnp.broadcast_to(
             jnp.asarray(alibi, jnp.float32).reshape(h, 1), (h, lw))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, t),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), row),
-        scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32),
-                        pltpu.VMEM((h, 128), jnp.float32),
-                        pltpu.VMEM((h, d), jnp.float32)],
+        out_specs=row(h, d),
+        scratch_shapes=slots + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32)],
     )
 
-    kernel = _mk_paged_kernel(quantized, staged, alibi is not None)
     out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, bs=bs, nt=t, hkv=hkv,
-                          n_rep=n_rep, d=d, window=window),
+        functools.partial(_paged_kernel, scale=scale, bs=bs, nb=nb, nrows=b,
+                          hkv=hkv, n_rep=n_rep, d=d, window=window,
+                          quantized=quantized, staged=staged,
+                          has_alibi=alibi is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # one after another: a step starts the first copy of the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
         name="self_attn_paged_decode",
     )(*args)

@@ -39,9 +39,9 @@ from deepspeed_tpu.ops.pallas.paged_attention import (paged_decode_attention,
 L, HKV, NB, BS, D, T = 3, 2, 10, 8, 16, 3
 
 
-def _pools(rng, quantized, dtype=jnp.bfloat16):
-    k = jnp.asarray(rng.standard_normal((L, HKV, NB, BS, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((L, HKV, NB, BS, D)), dtype)
+def _pools(rng, quantized, dtype=jnp.bfloat16, nb=NB):
+    k = jnp.asarray(rng.standard_normal((L, HKV, nb, BS, D)), dtype)
+    v = jnp.asarray(rng.standard_normal((L, HKV, nb, BS, D)), dtype)
     if not quantized:
         return k, v, None, None
     (k, ks), (v, vs) = quantize_kv_tokens(k), quantize_kv_tokens(v)
@@ -164,6 +164,131 @@ def test_decode_kernel_skips_a_row_parked_at_capacity(quantized, stacked,
         want = np.repeat(np.asarray(new[1, PARKED_ROWS], np.float32),
                          n_rep, axis=1)[:, None]
     np.testing.assert_array_equal(got[PARKED_ROWS], want)
+
+
+def _blocks_with_a_column(pool_len, qpos, window):
+    """The logical blocks of a row that hold a column the query attends:
+    below the pool length and, with a window, above `qpos - window`."""
+    cols = np.arange(T * BS)
+    keep = cols < pool_len
+    if window is not None:
+        keep &= cols > qpos - window
+    return sorted(set((cols[keep] // BS).tolist()))
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("staged", [False, True], ids=["unstaged", "staged"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "stack_of_1"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_decode_kernel_reads_only_the_blocks_that_hold_tokens(
+        quantized, stacked, staged, windowed):
+    """PR 46: the kernel walks a row's live blocks and no table entry
+    beside them. EVERY pool block that no live (row, block) pair names is
+    NaN (an int8 pool: NaN scales), every table entry past a row's last
+    live block is -1 and those below a window's band name a NaN block; the
+    batch comes out finite, and each row as it does served alone (which
+    also holds the hand-over of a row's first block from the row before it,
+    whatever rows lie between)."""
+    rng = np.random.default_rng(46)
+    n_rep, nb, cap = 4, 24, T * BS
+    window = WINDOW if windowed else None
+    # as `cached_attention` passes them, cursor + 1; parked rows first,
+    # between and last, live rows from one token to the last slot
+    lengths = np.asarray([cap + 1, 1, 2, cap + 1, BS, BS + 1, BS + 2,
+                          cap + 1, cap + 1, 2 * BS + 3, cap, cap + 9],
+                         np.int32)
+    b = len(lengths)
+    k, v, ks, vs = _pools(rng, quantized, nb=nb)
+    free = list(1 + rng.permutation(nb - 1))
+    tables = np.full((b, T), -1, np.int32)
+    named = []
+    for r, length in enumerate(lengths):
+        if length > cap:
+            continue
+        live = _blocks_with_a_column(length - 1 if staged else length,
+                                     length - 1, window)
+        if live:
+            tables[r, :live[-1]] = 0          # below the band: a NaN block
+        for j in live:
+            tables[r, j] = free.pop()
+            named.append(tables[r, j])
+    dead = np.setdiff1d(np.arange(nb), named)
+    assert len(named) >= 8 and 0 in dead
+    nan = float("nan")
+    if quantized:
+        ks, vs = ks.at[:, :, dead].set(nan), vs.at[:, :, dead].set(nan)
+    else:
+        k, v = k.at[:, :, dead].set(nan), v.at[:, :, dead].set(nan)
+    if stacked:
+        pools = dict(k_scales=ks, v_scales=vs, layer=jnp.int32(1))
+    else:
+        k, v = k[1], v[1]
+        pools = dict(k_scales=None if ks is None else ks[1],
+                     v_scales=None if vs is None else vs[1])
+    q = jnp.asarray(rng.standard_normal((b, 1, HKV * n_rep, D)), jnp.bfloat16)
+    new = jnp.asarray(rng.standard_normal((2, b, HKV, D)), jnp.bfloat16)
+
+    def run(rows):
+        staged_kw = dict(k_new=new[0, rows], v_new=new[1, rows]) \
+            if staged else {}
+        return np.asarray(paged_decode_attention(
+            q[rows], k, v, jnp.asarray(tables[rows]),
+            jnp.asarray(lengths[rows]), window=window, **staged_kw, **pools),
+            np.float32)
+
+    got = run(np.arange(b))
+    assert np.isfinite(got).all()
+    for r in range(b):
+        np.testing.assert_array_equal(got[r:r + 1], run(np.asarray([r])))
+    live_rows = np.flatnonzero(lengths <= cap)
+    assert np.abs(got[live_rows]).max(axis=(1, 2, 3)).min() > 0
+
+
+@pytest.mark.parametrize("staged", [False, True], ids=["unstaged", "staged"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_decode_kernel_at_every_edge_of_a_block_in_one_batch(quantized,
+                                                             staged):
+    """Pool lengths 0, 1, BS - 1, BS, BS + 1, T*BS - 1 and a parked row in
+    ONE batch, against the plain float32 softmax over the tokens each row
+    holds (an unstaged row of length 0 holds none and reads zeros)."""
+    rng = np.random.default_rng(47)
+    n_rep, cap = 4, T * BS
+    pool_len = np.asarray([0, 1, BS - 1, BS, BS + 1, cap - 1, 0], np.int32)
+    parked = np.asarray([False] * 6 + [True])
+    lengths = np.where(parked, cap + 1, pool_len + (1 if staged else 0))
+    b, h = len(lengths), HKV * n_rep
+    k, v, ks, vs = _pools(rng, quantized)
+    tables = rng.integers(0, NB, (b, T)).astype(np.int32)
+    tables[parked] = -1
+    q = jnp.asarray(rng.standard_normal((b, 1, h, D)), jnp.bfloat16)
+    new = jnp.asarray(rng.standard_normal((2, b, HKV, D)), jnp.bfloat16)
+    kw = dict(k_new=new[0], v_new=new[1]) if staged else {}
+    got = np.asarray(paged_decode_attention(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32),
+        layer=jnp.int32(2), k_scales=ks, v_scales=vs, **kw), np.float32)
+
+    def tokens(pool, scales):   # (L, Hkv, NB, BS, D) -> (B, T*BS, Hkv, D)
+        x = np.asarray(pool[2], np.float32)
+        if scales is not None:
+            x = x * np.asarray(scales[2], np.float32)[..., None]
+        rows = x[:, np.maximum(tables, 0)]          # (Hkv, B, T, BS, D)
+        return np.moveaxis(rows, 0, 3).reshape(b, cap, HKV, D)
+
+    kd, vd = tokens(k, ks), tokens(v, vs)
+    qf = np.asarray(q, np.float32)[:, 0].reshape(b, HKV, n_rep, D)
+    for r in range(b):
+        kr, vr = kd[r, :pool_len[r]], vd[r, :pool_len[r]]
+        if staged:
+            kr = np.concatenate([kr, np.asarray(new[0, r], np.float32)[None]])
+            vr = np.concatenate([vr, np.asarray(new[1, r], np.float32)[None]])
+        if not len(kr):
+            np.testing.assert_array_equal(got[r], 0.0)
+            continue
+        s = np.einsum("grd,tgd->grt", qf[r], kr) / np.sqrt(D)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("grt,tgd->grd", p / p.sum(-1, keepdims=True), vr)
+        np.testing.assert_allclose(got[r, 0], want.reshape(h, D), atol=3e-2,
+                                   rtol=3e-2)
 
 
 @pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
@@ -486,6 +611,29 @@ def test_program_equals_with_the_kernels_on(params, monkeypatch, engine,
     jaxpr = str(jax.make_jaxpr(
         lambda c: c.apply_stage())(new.cache))
     assert "kv_write_paged" in jaxpr and "scatter" not in jaxpr
+
+
+@pytest.mark.parametrize("engine", ["f32", "int8"])
+def test_decode_program_walks_no_grid_axis_of_the_tables_length(
+        params, monkeypatch, engine):
+    """PR 46: the `decode` program's `self_attn_paged_decode` call, once a
+    layer inside the layer scan, has a grid of the batch's rows alone: no
+    axis of the block table's length T, nor of rows x T."""
+    import deepspeed_tpu.ops.attention as attention
+    from deepspeed_tpu.tools.tpuverify.jaxpr_util import primitive_eqns
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    eng, _ = _engines(params, **ENGINES[engine])
+    t = eng.cache.k.tables.shape[-1]
+    assert t > 1 and t != MAX_BATCH
+    toks = jnp.zeros((MAX_BATCH, 1), jnp.int32)
+    active = jnp.ones((MAX_BATCH,), bool)
+    jaxpr = jax.make_jaxpr(eng._decode_fn())(eng.params, eng.cache, toks,
+                                             active)
+    grids = [tuple(e.params["grid_mapping"].grid)
+             for _, e in primitive_eqns(jaxpr.jaxpr, {"pallas_call"})
+             if "self_attn_paged_decode" in str(
+                 e.params.get("name") or e.params["name_and_src_info"])]
+    assert grids == [(MAX_BATCH,)]
 
 
 @pytest.mark.parametrize("engine", ["f32", "int8"])

@@ -163,6 +163,12 @@ SLOTS, FED, ROUNDS = 140, 76, 5
 #  5 chunk alone                             4, 1
 ROWS = [(4, 1), (8, 5), (8, 6), (4, 1)]
 PARKED = sum(rows - live for rows, live in ROWS)
+# rounds with a decode half: (row, block) pairs that hold tokens under its
+# rows, of the 4 x 8 entries of their block tables (blocks of 16 tokens)
+#  2 decode                                  uid 1 at 5 tokens: 1
+#  3 fused                                   uid 1 at 6: 1 (uid 2 still parked)
+#  4 fused                                   uid 1 at 7: 1, uid 2 at 32: 2
+BLOCKS = [(1, 32), (1, 32), (3, 32)]
 # chunk rounds: rows that carry tokens, the prompts they belong to, the width
 CHUNK_ROWS = [(4, 1, 4), (4, 1, 4), (1, 1, 4)]
 REFILLED = sum(rows - seqs for rows, seqs, _ in CHUNK_ROWS)
@@ -243,6 +249,52 @@ def test_rows_live_and_parked_add_up_to_the_programs_rows(runs):
                  f["width"] + (MAX_BATCH if f["fused"] else 0))
         assert f["rows_live"] + f["rows_parked"] == width == rows
         assert f["rows_live"] == live
+
+
+def test_kv_blocks_live_against_the_tables_entries(runs):
+    """`kv_blocks_live` counts the (row, block) pairs the paged decode
+    kernel walks, `kv_blocks_table` the table entries under the same rows:
+    tracing on or off in the counters, and on every span whose program has
+    a decode half (`decode`, a fused `chunk`), and on no other."""
+    for eng in (runs["off"], runs["on"]):
+        for key, total in zip(("kv_blocks_live", "kv_blocks_table"),
+                              map(sum, zip(*BLOCKS))):
+            assert eng.serving_counters[key] == total
+            assert eng.telemetry_snapshot()[key] == total
+    spans = [s["fields"] for s in runs["spans"]
+             if s["name"] in ("chunk", "decode")]
+    halves = [f for f in spans if f.get("fused", True)]
+    assert [(f["kv_blocks_live"], f["kv_blocks_table"])
+            for f in halves] == BLOCKS
+    assert all("kv_blocks_live" not in f and "kv_blocks_table" not in f
+               for f in spans if not f.get("fused", True))
+
+
+def test_a_decode_wave_counts_its_blocks_step_by_step(store):
+    """A wave of `k` steps runs the decode kernel `k` times over cursors
+    that advance: its span carries the (row, block) pairs summed over the
+    steps (the prompt's 14 tokens cross into a second block of 16 on the
+    way), against `k` times the table's entries."""
+    cfg = llama_config("llama-tiny", dtype=jnp.float32)
+    model, params = materialize_params(cfg)
+    set_hub(TelemetryHub(enabled=False))
+    groups.reset_topology()
+    eng = InferenceEngineV2(model, params=params, max_batch=MAX_BATCH,
+                            max_seq_len=128, split_fuse_chunk=CHUNK,
+                            cache_block_size=16, prefix_sharing=False)
+    eng.tracer.force = True
+    eng.generate([list(range(3, 17))], max_new_tokens=7)
+    waves = [s["fields"] for s in store.spans() if s["name"] == "decode_wave"]
+    assert waves and sum(f["k"] for f in waves) >= 4
+    seen, live, table = 14, 0, 0
+    for f in waves:
+        want = sum(-(-(seen + i) // 16) for i in range(f["k"]))
+        assert (f["kv_blocks_live"], f["kv_blocks_table"]) == (
+            want, f["k"] * MAX_BATCH * 8)
+        seen, live, table = seen + f["k"], live + want, table + f["k"] * 32
+    assert live > sum(f["k"] for f in waves)      # some step held two blocks
+    assert (eng.serving_counters["kv_blocks_live"],
+            eng.serving_counters["kv_blocks_table"]) == (live, table)
 
 
 def test_chunk_spans_tell_rows_from_sequences(runs):
