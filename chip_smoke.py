@@ -88,6 +88,10 @@ class Sizes:
     diff_stack: Tuple[Tuple[int, int, int, int, int], ...]
     # a Mamba-1 decode step: recurrent layers, rows, state size, channels
     ssm_m1: Tuple[int, int, int, int]
+    # a KDA decode step: layers, rows, heads, head width (the state d x d)
+    kda: Tuple[int, int, int, int]
+    # latent decode attention: layers, rows, query heads, slots, rank, rope
+    mla: Tuple[int, int, int, int, int, int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -101,7 +105,8 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              gmm_experts=64, gmm_width=1024, qmm_group=256,
              ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856),
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
-             ssm_m1=(9, 64, 16, 5120))
+             ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
+             mla=(1, 128, 32, 2048, 512, 64))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
@@ -111,7 +116,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
              qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48),
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
-             ssm_m1=(2, 4, 16, 256))
+             ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
+             mla=(2, 3, 4, 32, 32, 8))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -176,6 +182,11 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                                                            kv_write_dense)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
+    from deepspeed_tpu.ops.pallas.kda import (kda_state_update,
+                                              kda_state_update_reference)
+    from deepspeed_tpu.ops.pallas.mla import (latent_write_dense,
+                                              mla_latent_decode,
+                                              mla_latent_decode_reference)
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_kv_write, paged_prefill_attention)
     from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
@@ -610,6 +621,52 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             functools.partial(run_diff, diff_decode_attention),
             functools.partial(run_diff, diff_decode_attention_reference),
             make_diff))
+
+    # ---- a KDA decode step: a head's state a d x d matrix, (L, B, H, d, d) ----
+    kl, kb, kh, kd = sz.kda
+
+    def make_kda(key):
+        ks = jax.random.split(key, 6)
+        f32 = jnp.float32
+        unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+        return (normal(ks[0], (kl, kb, kh, kd, kd), f32),
+                unit(normal(ks[1], (kb, kh, kd), f32)) * kd ** -0.5,
+                unit(normal(ks[2], (kb, kh, kd), f32)),
+                normal(ks[3], (kb, kh, kd), f32),
+                -5.0 * jax.nn.sigmoid(normal(ks[4], (kb, kh, kd), f32) - 2.0),
+                jax.nn.sigmoid(normal(ks[5], (kb, kh), f32)))
+
+    cases.append(KernelCase(
+        "kda_state_update",
+        lambda state, *rest: kda_state_update(state, kl - 1, *rest),
+        lambda state, *rest: kda_state_update_reference(state, kl - 1, *rest),
+        make_kda))
+
+    # ---- latent (MLA) decode, absorbed, and the latent cache's writer ----
+    ll, lb, lh, lm, lrank, lrope = sz.mla
+
+    def make_mla(key):
+        kq, kr, kc, kn, kp = jax.random.split(key, 5)
+        pos = jax.random.randint(kp, (lb,), 0, lm, jnp.int32)
+        return (normal(kq, (lb, lh, lrank)), normal(kr, (lb, lh, lrope)),
+                normal(kc, (ll, lb, 1, lm, lrank + lrope)), pos,
+                normal(kn, (lb, lrank + lrope)))
+
+    def run_mla(fn, q_lat, q_rope, stack, pos, new):
+        return fn(q_lat, q_rope, stack, ll - 1, pos + 1,
+                  (lrank + lrope) ** -0.5, new=new, slots=pos)
+
+    cases.append(KernelCase(
+        "mla_latent_decode", functools.partial(run_mla, mla_latent_decode),
+        functools.partial(run_mla, mla_latent_decode_reference), make_mla))
+    cases.append(KernelCase(
+        "latent_write_dense",
+        lambda q_lat, q_rope, stack, pos, new: latent_write_dense(
+            stack, jnp.broadcast_to(new[None], (ll,) + new.shape), pos),
+        lambda q_lat, q_rope, stack, pos, new: stack.at[
+            :, jnp.arange(lb), 0, pos].set(
+                jnp.broadcast_to(new[None], (ll,) + new.shape)),
+        make_mla))
 
     # ---- block-sparse attention (MHA; layout is static host data) ----
     sblk = min(64, sz.seq // 4)

@@ -103,10 +103,11 @@ def kv_bytes_by_kind(model_cfg, batch: int, max_len: int,
                      dtype) -> Dict[str, int]:
     """K + V bytes by the KIND of cache that holds them (docs/kv_cache.md),
     as the model's config counts them (`model_cfg.kv_bytes_by_kind`):
-    `window_kv_bytes`, rings of a window's slots whatever `max_len`, and
+    `window_kv_bytes`, rings of a window's slots whatever `max_len`,
     `shared_kv_bytes`, full-length slabs that layers without a cache of
-    their own read. Empty for a model of one kind of layer: `max_len` slots
-    a layer, all of it `kv_cache_bytes`."""
+    their own read, and `latent_kv_bytes`, the latent-attention layers' one
+    row a token in place of K and V a head. Empty for a model of one kind of
+    layer: `max_len` slots a layer, all of it `kv_cache_bytes`."""
     own = getattr(model_cfg, "kv_bytes_by_kind", None)
     if own is None:
         return {}

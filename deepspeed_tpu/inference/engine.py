@@ -384,7 +384,7 @@ class InferenceEngine:
         # gauges and counters update on a disabled hub too (hub.py): what a
         # benchmark reads of a call without the JSONL stream
         for name in ("kv_bytes", "state_bytes", "window_kv_bytes",
-                     "shared_kv_bytes"):
+                     "shared_kv_bytes", "latent_kv_bytes"):
             if name in kv:
                 hub.gauge(f"serving_v1/{name}", kv[name])
         for name, value in counted.items():
@@ -430,8 +430,8 @@ class InferenceEngine:
         except Exception:
             return {}  # non-standard config dims: skip, never break serving
         # K and V of the ATTENTION layers, and by kind where the model keeps
-        # more than one (rings, a shared slab); what recurrent layers hold
-        # is counted apart (0 for a model that has none)
+        # more than one (rings, a shared slab, latent rows); what recurrent
+        # layers hold is counted apart (0 for a model that has none)
         return {"kv_dtype": eff or jnp.dtype(self._config.dtype).name,
                 "kv_bytes": int(kv_b),
                 **kv_bytes_by_kind(self.model_cfg, int(b), max_len,

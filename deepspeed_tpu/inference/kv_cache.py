@@ -251,6 +251,63 @@ class RecurrentState:
 
 
 @struct.dataclass
+class LatentCache:
+    """What latent-attention (MLA) layers keep, stacked over THOSE layers:
+    ONE array a token a layer that all heads share, the normalised KV latent
+    and the rotated rope key side by side, `(L, B, 1, M, rank + rope)`
+    (docs/kv_cache.md): the stacked dense layout with one "head", so it is a
+    `DenseLayer` and the dense writer lands it. Keys and values are never
+    stored: decode reads the latent through the absorbed projections
+    (`ops/pallas/mla.mla_latent_decode`), prefill expands its own tokens."""
+
+    c: DenseLayer                           # stack (L, B, 1, M, W)
+    index: jnp.ndarray                      # (B,) int32
+
+    @property
+    def max_len(self) -> int:
+        return self.c.stack.shape[3]
+
+    @classmethod
+    def create(cls, num_layers: int, batch: int, max_len: int, width: int,
+               dtype: Any = jnp.bfloat16) -> "LatentCache":
+        return cls(c=DenseLayer(jnp.zeros(
+            (num_layers, batch, 1, max_len, width), dtype)),
+            index=jnp.zeros((batch,), jnp.int32))
+
+    @staticmethod
+    def nbytes(num_layers: int, batch: int, max_len: int, width: int,
+               dtype: Any = jnp.bfloat16) -> int:
+        """Bytes `create` would hold, unpadded (host arithmetic)."""
+        return num_layers * batch * max_len * width * jnp.dtype(dtype).itemsize
+
+    def write_rows(self, layer, new: jnp.ndarray) -> "LatentCache":
+        """`new` (B, S, W), the tokens of positions 0 .. S - 1 of every row,
+        into layer `layer` of the EMPTY cache (a prefill): a dynamic slice
+        written whole, which keeps the stack's tiling."""
+        stack = self.c.stack
+        return self.replace(c=DenseLayer(jax.lax.dynamic_update_slice(
+            stack, new.astype(stack.dtype)[None, :, None],
+            (layer, 0, 0, 0, 0))))
+
+    def land(self, new: jnp.ndarray) -> "LatentCache":
+        """Every layer's staged token, `new` (L, B, W), written at the
+        cursors `[:, b, 0, index[b]]`: the one write of a decode step, on the
+        chip through `latent_write_dense`, which aliases the stack and keeps
+        the tiling the decode kernel reads (`_pool_writer` tells why no XLA
+        scatter). A row whose cursor is at or past `max_len` is dropped."""
+        from deepspeed_tpu.ops import attention
+        stack = self.c.stack
+        if attention._one_device_kernel("latent_write_dense"):
+            from deepspeed_tpu.ops.pallas.mla import latent_write_dense
+            stack = latent_write_dense(stack, new, self.index)
+        else:
+            rows = jnp.arange(self.index.shape[0])
+            stack = stack.at[:, rows, 0, self.index].set(
+                new.astype(stack.dtype), mode="drop")
+        return self.replace(c=DenseLayer(stack))
+
+
+@struct.dataclass
 class HybridCache:
     """The cache of a model whose layers are of different kinds, by KIND
     (docs/kv_cache.md), one pytree that the decode scan carries:
@@ -258,33 +315,40 @@ class HybridCache:
     - `kv`: a `KVCache` over the layers that keep K and V at FULL length. A
       layer may be the only writer of its slab and later layers read it (a
       shared slab: the readers hold nothing of their own);
-    - `window`: a ring `KVCache` (`KVCache.ring`) over the WINDOW layers, or
-      None for a model that has none;
+    - `window`: a ring `KVCache` (`KVCache.ring`) over the WINDOW layers;
+    - `latent`: a `LatentCache` over the latent-attention layers;
     - `state`: a `RecurrentState` over the recurrent layers.
 
-    Layers that keep nothing (expert, dense FFN, memory unit, a reader of a
-    shared slab) have no row in any. The cursors are `kv`'s, and `window`'s
-    are kept equal to them; `index`, `max_len` and `replace` read as a
-    `KVCache`'s do, so the engine handles it as it handles that."""
+    A kind the model has no layer of is None. Layers that keep nothing
+    (expert, dense FFN, memory unit, a reader of a shared slab) have no row
+    in any. The cursors are those of the first of `kv`, `latent` that is
+    there, and every kind's are kept equal to them; `index`, `max_len` and
+    `replace` read as a `KVCache`'s do, so the engine handles it as it
+    handles that."""
 
-    kv: KVCache
+    kv: Optional[KVCache]
     state: RecurrentState
     window: Optional[KVCache] = None
+    latent: Optional[LatentCache] = None
+
+    @property
+    def _full(self):
+        return self.kv if self.kv is not None else self.latent
 
     @property
     def index(self) -> jnp.ndarray:
-        return self.kv.index
+        return self._full.index
 
     @property
     def max_len(self) -> int:
-        return self.kv.max_len
+        return self._full.max_len
 
     def advance(self, s: int) -> "HybridCache":
-        index = self.kv.index + s
-        return self.replace(
-            kv=self.kv.replace(index=index),
-            window=None if self.window is None
-            else self.window.replace(index=index))
+        index = self.index + s
+        return self.replace(**{
+            kind: getattr(self, kind).replace(index=index)
+            for kind in ("kv", "window", "latent")
+            if getattr(self, kind) is not None})
 
     def rows(self, start, count: int) -> "HybridCache":
         """The cache of sequences `start .. start + count - 1` alone (`start`

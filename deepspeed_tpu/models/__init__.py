@@ -30,3 +30,5 @@ from deepspeed_tpu.models.nemotron_h import (
     NemotronHConfig, NemotronHForCausalLM, nemotron_h_loss_fn)
 from deepspeed_tpu.models.phi4flash import (
     Phi4FlashConfig, Phi4FlashForCausalLM, phi4flash_loss_fn)
+from deepspeed_tpu.models.ling_linear import (
+    LingLinearConfig, LingLinearForCausalLM, ling_linear_loss_fn)

@@ -1,0 +1,588 @@
+"""Ling-3.0-flash's language model (HF `BailingMoeV2_5`-style config keys;
+inclusionAI): a hybrid of LINEAR attention and LATENT attention over sparse
+experts. Every layer is `h += Mixer(RMSNorm(h)); h += FFN(RMSNorm(h))`, then
+a final RMSNorm and an untied head. By PUBLISHED layer index p:
+
+- the mixer is **latent attention (MLA)** where `(p + 1) % layer_group_size
+  == 0` and **Kimi Delta Attention (KDA)** elsewhere: five to one;
+- the FFN is a dense SwiGLU for the first `first_k_dense_replace` layers
+  and after them 512 sigmoid-routed SwiGLU experts (top 8, the choice
+  limited to 4 of 8 groups, a selection bias, weights over their sum times
+  2.5) beside one shared expert.
+
+**KDA** (Kimi Linear, arXiv:2510.26692), H heads of d: `q, k, v =
+silu(conv4(x W))` (causal depthwise, no bias); `q`, `k` L2-normalised a
+head, `q` times `d^-0.5`; a log-decay a CHANNEL `g = kda_lower_bound *
+sigmoid(exp(A_log)[head] * (x W_f + dt_bias))` (the bounded "safe" gate:
+`exp(g)` in `[e^-5, 1)`); `beta = sigmoid(x W_b)` a head. A head's state is
+a MATRIX `S` (d x d), float32:
+
+    S <- diag(exp(g_t)) S;  S <- S + beta_t k_t (v_t - S^T k_t)^T;  o_t = S^T q_t
+
+and the layer's output `(RMSNorm_head(o_t) * sigmoid(x W_g)) W_o`. One
+token is `ops/pallas/kda.kda_state_update` on the stored state; a sequence
+is `kda_chunked` below, exact against the recurrence.
+
+**MLA** (DeepSeek-V2's, no query compression): `q = x W_q` -> H x (128 nope
++ 64 rope); `[c, k_r] = x W_kva` (512 + 64), `c <- RMSNorm(c)`; a head's key
+is `[c W_k^h | k_r]` and its value `c W_v^h`; `q` is RMS-normalised a head
+and `k_r` once, then the rope parts are rotated; softmax scale `192^-0.5`; a
+head's output times `sigmoid(x W_gate)[head]`, then `W_o`. The cache keeps
+`[c | rotated k_r]`, 576 values a token (`kv_cache.LatentCache`). PREFILL
+expands its own tokens' keys and values; DECODE is the absorbed form over
+the latents (`ops/pallas/mla.mla_latent_decode`), the step's token staged
+and landed once.
+
+The layers are NOT stacked and scanned, for `models/nemotron_h.py`'s reason
+(the grouped expert GEMM under a scan would copy a layer's experts every
+step). The chip may hold a SHARE of the model: `num_experts` of
+`router_experts` from `expert_offset` on (whole groups), a slice of the
+vocabulary, and of the depth the layers `published_layers` names
+(`perfbench/configs/ling3-flash-l6-ep4.json` has the deployment; its
+`assumed` lists what the catalog's config does not settle).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.llama import RMSNorm, _dense
+from deepspeed_tpu.models.nemotron_h import _a_log_init
+from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
+
+F32 = jnp.float32
+# Tokens of a prefill that walk the layers together (`_RowGroups`): at 8 x
+# 1024 the chunked KDA form's float32 operands are 0.13 GB each, the experts'
+# sorted rows 0.34 GB, the expanded latent attention's keys 0.1 GB, beside
+# 8.8 GB of weights and 1.7 GB of cache.
+PREFILL_TOKENS = 8192
+KDA_CHUNK = 32      # positions a block of the chunked form (`kda_chunked`)
+L2_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class LingLinearConfig:
+    vocab_size: int = 157184
+    hidden_size: int = 2560
+    num_hidden_layers: int = 42
+    intermediate_size: int = 6144
+    first_k_dense_replace: int = 2
+    layer_group_size: int = 6
+    # the published indices of the layers held (None: all of them, 0 .. L-1):
+    # a layer's mixer is decided by its PUBLISHED index
+    published_layers: Optional[Tuple[int, ...]] = None
+    num_attention_heads: int = 32
+    head_dim: int = 128
+    # KDA
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    # MLA
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 6e6
+    # experts: `num_experts` are HELD here, of the `router_experts` the router
+    # scores (None: all of them are held), from `expert_offset` on
+    num_experts: int = 512
+    router_experts: Optional[int] = None
+    expert_offset: int = 0
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    moe_shared_expert_intermediate_size: int = 768
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    n_group: int = 8
+    topk_group: int = 4
+    # seeded router: the scale of the selection bias drawn at init (a zero
+    # one would hide a dropped term; `models/nemotron_h.py` has the readings)
+    router_bias_scale: float = 0.01
+    rms_norm_eps: float = 1e-6
+    max_position_embeddings: int = 131072
+    dtype: Any = jnp.bfloat16
+    attn_impl: str = "auto"
+    dispatch_impl: str = "auto"
+
+    def __post_init__(self):
+        pub = self.published_layers
+        if pub is not None:
+            object.__setattr__(self, "published_layers", tuple(pub))
+            if len(pub) != self.num_hidden_layers or list(pub) != sorted(set(pub)):
+                raise ValueError(
+                    f"published_layers {pub}: the {self.num_hidden_layers} "
+                    "held layers' published indices, ascending")
+        scored = self.router_experts or self.num_experts
+        size = max(scored // self.n_group, 1)
+        if self.n_group > 1 and (scored % self.n_group
+                                 or self.expert_offset % size
+                                 or self.num_experts % size):
+            raise ValueError(
+                f"experts {self.expert_offset} .. +{self.num_experts} of "
+                f"{scored} in {self.n_group} groups: a share is whole groups")
+
+    # ---- the walk
+    @property
+    def kinds(self) -> str:
+        """A layer's mixer, by its place here: `K` KDA, `A` latent attention."""
+        pub = self.published_layers or range(self.num_hidden_layers)
+        return "".join("A" if (p + 1) % self.layer_group_size == 0 else "K"
+                       for p in pub)
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that keep a cache that grows with the sequence."""
+        return self.kinds.count("A")
+
+    # ---- KDA sizes
+    @property
+    def d_inner(self) -> int:
+        return self.num_attention_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return 3 * self.d_inner                 # q, k and v side by side
+
+    @property
+    def kda_state_shape(self) -> tuple:
+        """One sequence's state in one KDA layer: a head's `S`, d_k x d_v."""
+        return (self.num_attention_heads, self.head_dim, self.head_dim)
+
+    # ---- MLA sizes
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    # ---- bytes, by kind (host arithmetic: telemetry, serve-mode accounting)
+    def kv_bytes_by_kind(self, batch: int, max_len: int, dtype=None) -> dict:
+        from deepspeed_tpu.inference.kv_cache import LatentCache
+        return {"latent_kv_bytes": LatentCache.nbytes(
+            self.num_kv_layers, batch, max_len, self.latent_width,
+            dtype or self.dtype)}
+
+    def recurrent_state_bytes(self, batch: int, dtype=None) -> int:
+        from deepspeed_tpu.inference.kv_cache import RecurrentState
+        return RecurrentState.nbytes(
+            self.kinds.count("K"), batch, self.kda_state_shape,
+            self.short_conv_kernel_size, self.conv_dim, dtype or self.dtype)
+
+
+# ---------------------------------------------------------------------- KDA
+
+
+def _neumann_inverse(a):
+    """`(I + a)^-1` for STRICTLY lower triangular `a` (..., C, C): the
+    product of `I + (-a)^(2^i)`, exact since `a^C = 0`."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    inv, power = eye - a, a @ a
+    for _ in range(max(0, math.ceil(math.log2(c)) - 1)):
+        inv, power = inv + inv @ power, power @ power
+    return inv
+
+
+def kda_chunked(q, k, v, g, beta, s0, chunk: int = KDA_CHUNK):
+    """The KDA recurrence over a sequence in CHUNKS: inside a block of
+    `chunk` positions the delta rule's dependence of each token on the ones
+    before it is a unit lower triangular system (the WY form), solved by
+    matrix products; the state is carried between blocks. Exact against the
+    recurrence (`ops/pallas/kda.kda_step` a position).
+
+    q, k (B, S, H, dk) as they enter the recurrence; v (B, S, H, dv); g
+    (B, S, H, dk) log-decay <= 0; beta (B, S, H); s0 (B, H, dk, dv), a head's
+    `S`: float32, products at `highest`. Returns (o (B, S, H, dv), the
+    state after position S - 1). Any S: the tail of the last block is padded
+    with g = 0, beta = 0, which leaves the state as it is.
+
+    With `G_i` the cumulative log-decay inside a block, the decay between
+    two of its positions, `exp(G_i - G_j)` a channel, is taken as
+    `exp(G_i - r) exp(r - G_j)` about the block's MIDDLE `r`: each exponent
+    is then at most `chunk / 2` steps' worth, 80 at the bound of -5 a step,
+    inside float32 either way (a whole block's, 160, is not)."""
+    bsz, s, nh, dk = q.shape
+    dv = v.shape[-1]
+    pad = -s % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    nc = (s + pad) // chunk
+    # (nc, B, H, C, ...): the scan slices blocks, a head's rows are matrices
+    blocks = lambda t: jnp.moveaxis(  # noqa: E731
+        t.reshape((bsz, nc, chunk) + t.shape[2:]), (1, 3), (0, 2))
+    q, k, v, g, beta = (blocks(t) for t in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=-2)                               # G_i, inclusive
+    mid = cum[..., chunk // 2 - 1:chunk // 2, :]
+    up, down = jnp.exp(cum - mid), jnp.exp(mid - cum)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    with jax.default_matmul_precision("highest"):
+        k_down = k * down
+        # a[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j), j < i
+        a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool),
+                      jnp.einsum("...ic,...jc->...ij", k * up, k_down), 0.0)
+        solve = _neumann_inverse(a * beta[..., None])          # (I + A)^-1
+        # p[i, j] = sum_c q_i k_j exp(G_i - G_j), j <= i
+        p = jnp.where(lower, jnp.einsum("...ic,...jc->...ij", q * up, k_down),
+                      0.0)
+        decay = jnp.exp(cum)                                   # from the start
+        k_in, q_in = k * decay, q * decay
+        to_end = k * jnp.exp(cum[..., -1:, :] - cum)
+        whole = decay[..., -1, :]                              # (nc, B, H, dk)
+
+        def block(state, blk):
+            k_in, q_in, v, beta, solve, p, to_end, whole = blk
+            # u_i = beta_i (v_i - S'^T k_i): the rows of (I + A) U = rhs
+            rhs = beta[..., None] * (v - k_in @ state)
+            u = solve @ rhs                                    # (B, H, C, dv)
+            o = q_in @ state + p @ u
+            state = state * whole[..., :, None] + jnp.einsum(
+                "...ic,...iv->...cv", to_end, u)
+            return state, o
+
+        s_last, o = jax.lax.scan(
+            block, s0, (k_in, q_in, v, beta, solve, p, to_end, whole))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, nc * chunk, nh, dv)
+    return o[:, :s], s_last
+
+
+def _dt_bias_init(key, shape, dtype=F32):
+    # the inverse softplus of dt log-uniform in [0.001, 0.1] (Mamba's, which
+    # the open KDA implementation keeps for its gate's bias)
+    lo, hi = math.log(0.001), math.log(0.1)
+    dt = jnp.exp(jax.random.uniform(key, shape, F32) * (hi - lo) + lo)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _l2_normalised(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+class KDAMixer(nn.Module):
+    cfg: LingLinearConfig
+
+    @nn.compact
+    def __call__(self, x, state=None, slot=None):
+        """x (B, S, D). `state`: None (a plain forward from a zero state), or
+        the model's stacked `RecurrentState` with this layer's `slot` in it:
+        S == 1 is a decode step on the stored state, S > 1 continues from it
+        by the chunked form. Returns (out, state)."""
+        cfg = self.cfg
+        nh, d, di = cfg.num_attention_heads, cfg.head_dim, cfg.d_inner
+        kw = cfg.short_conv_kernel_size
+        b, s, _ = x.shape
+        qkv = _dense(3 * di, ("embed", "heads"), cfg.dtype, "qkv_proj")(x)
+        f, gate = jnp.split(_dense(2 * di, ("embed", "heads"), cfg.dtype,
+                                   "fg_proj")(x), 2, axis=-1)
+        beta = jax.nn.sigmoid(_dense(nh, ("embed", None), cfg.dtype,
+                                     "b_proj")(x).astype(F32))  # (B, S, H)
+        bound = 1.0 / math.sqrt(kw)
+        conv_w = self.param(
+            "conv_kernel", lambda key, sh, dt=F32: jax.random.uniform(
+                key, sh, dt, -bound, bound), (kw, 3 * di), F32).astype(F32)
+        a = jnp.exp(self.param("A_log", _a_log_init, (nh,), F32).astype(F32))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (di,), F32)
+        norm_w = self.param("norm_weight", nn.initializers.ones_init(), (d,),
+                            F32)
+        # the bounded gate: a log-decay a channel in (kda_lower_bound, 0)
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            a[:, None] * (f.astype(F32) + dt_bias.astype(F32)
+                          ).reshape(b, s, nh, d))
+
+        tail = (jnp.zeros((b, kw - 1, 3 * di), qkv.dtype) if state is None
+                else state.conv[slot])
+        window = jnp.concatenate([tail, qkv], axis=1)      # (B, S + K - 1, C)
+        w32 = window.astype(F32)
+        if s == 1:
+            conv = jnp.einsum("kc,bkc->bc", conv_w, w32)[:, None]
+        else:
+            conv = sum(conv_w[j] * w32[:, j:j + s] for j in range(kw))
+        q, k, v = (t.reshape(b, s, nh, d) for t in
+                   jnp.split(jax.nn.silu(conv), 3, axis=-1))
+        q, k = _l2_normalised(q) * d ** -0.5, _l2_normalised(k)
+
+        if state is not None and s == 1:
+            from deepspeed_tpu.ops.attention import kda_update
+            o, ssm = kda_update(state.ssm, slot, q[:, 0], k[:, 0], v[:, 0],
+                                g[:, 0], beta[:, 0])
+            o = o[:, None]
+        else:
+            s0 = (jnp.zeros((b, nh, d, d), F32) if state is None
+                  else state.ssm[slot])
+            o, last = kda_chunked(q, k, v, g, beta, s0)
+            ssm = None if state is None else \
+                jax.lax.dynamic_update_index_in_dim(state.ssm, last, slot, 0)
+        if state is not None:
+            state = state.replace(
+                ssm=ssm, conv=jax.lax.dynamic_update_index_in_dim(
+                    state.conv, window[:, -(kw - 1):].astype(state.conv.dtype),
+                    slot, 0))
+        # the norm over each head's d, then the gate (sigmoid, full rank)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.rms_norm_eps) * norm_w.astype(F32)
+        o = o.reshape(b, s, di) * jax.nn.sigmoid(gate.astype(F32))
+        return _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
+                      "o_proj")(o.astype(cfg.dtype)), state
+
+
+# ---------------------------------------------------------------------- MLA
+
+
+class MLAMixer(nn.Module):
+    cfg: LingLinearConfig
+
+    @nn.compact
+    def __call__(self, x, latent=None, slot=None):
+        """x (B, S, D). `latent`: None (a plain causal pass), or the model's
+        `LatentCache` with this layer's `slot` in it. S > 1 is a PREFILL
+        FROM THE EMPTY CACHE (the v1 `generate` program's only use): the new
+        tokens attend each other in the expanded form and their latents are
+        written. S == 1 is a decode step in the absorbed form, its token
+        staged. Returns (out, the cache written, or the staged (B, W) row)."""
+        from deepspeed_tpu.ops.attention import apply_rotary_emb, rope_cos_sin
+        cfg = self.cfg
+        nh, dn, dr, dv, rank = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                                cfg.qk_rope_head_dim, cfg.v_head_dim,
+                                cfg.kv_lora_rank)
+        b, s, _ = x.shape
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)  # noqa: E731
+        q = _dense(nh * cfg.qk_head_dim, ("embed", "heads"), cfg.dtype,
+                   "q_proj")(x).reshape(b, s, nh, cfg.qk_head_dim)
+        c, k_r = jnp.split(_dense(rank + dr, ("embed", None), cfg.dtype,
+                                  "kv_a_proj")(x), [rank], axis=-1)
+        c = norm("kv_a_norm")(c)
+        w_kvb = self.param("kv_b_proj", nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), (None, "heads")),
+            (rank, nh * (dn + dv)), F32).astype(cfg.dtype).reshape(
+                rank, nh, dn + dv)
+        gate = jax.nn.sigmoid(_dense(nh, ("embed", None), cfg.dtype,
+                                     "g_proj")(x).astype(F32))  # (B, S, H)
+        # the norms before the rotation: a head's whole query, the shared
+        # rope key (the nope key is a map of the already normalised latent)
+        q, k_r = norm("q_norm")(q), norm("k_norm")(k_r)
+        index = jnp.zeros((b,), jnp.int32) if latent is None else latent.index
+        cos, sin = rope_cos_sin(index[:, None] + jnp.arange(s)[None, :], dr,
+                                cfg.rope_theta, cfg.dtype)
+        q_nope, q_rope = q[..., :dn], apply_rotary_emb(q[..., dn:], cos, sin)
+        k_r = apply_rotary_emb(k_r[:, :, None], cos, sin)[:, :, 0]
+        row = jnp.concatenate([c, k_r], axis=-1)               # (B, S, W)
+        scale = cfg.qk_head_dim ** -0.5
+
+        if latent is not None and s == 1:
+            from deepspeed_tpu.ops.attention import latent_decode
+            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_kvb[..., :dn])
+            o_lat = latent_decode(q_lat, q_rope[:, 0],
+                                  latent.c.replace(layer=slot), index + 1,
+                                  scale, new=row[:, 0], slots=index)
+            o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype),
+                           w_kvb[..., dn:])[:, None]
+            made = row[:, 0]
+        else:
+            from deepspeed_tpu.ops.attention import attention
+            kv = jnp.einsum("bsr,rhn->bshn", c, w_kvb)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_r[:, :, None],
+                                                (b, s, nh, dr))], axis=-1)
+            # one head width for q, k and v, a multiple of the lanes: zeros
+            # add nothing to a score and the value's are cut off again
+            wide = -(-cfg.qk_head_dim // 128) * 128
+            fill = lambda t: jnp.pad(  # noqa: E731
+                t, ((0, 0),) * 3 + ((0, wide - t.shape[-1]),))
+            o = attention(fill(jnp.concatenate([q_nope, q_rope], axis=-1)),
+                          fill(k), fill(kv[..., dn:]), causal=True,
+                          softmax_scale=scale, impl=cfg.attn_impl)[..., :dv]
+            made = None if latent is None else latent.write_rows(slot, row)
+        o = (o.astype(F32) * gate[..., None]).astype(cfg.dtype)
+        return _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
+                      "o_proj")(o.reshape(b, s, nh * dv)), made
+
+
+# ------------------------------------------------------------------- layers
+
+
+class DenseFFN(nn.Module):
+    """`W_down(silu(W_gate x) * W_up x)` at `intermediate_size`."""
+    cfg: LingLinearConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate, up = (_dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
+                           name)(x) for name in ("gate_proj", "up_proj"))
+        return _dense(cfg.hidden_size, ("mlp_in", "embed"), cfg.dtype,
+                      "down_proj")(jax.nn.silu(gate) * up)
+
+
+def _experts(cfg: LingLinearConfig, name: str):
+    """The expert layer as `moe/layer.MoE` computes it: sigmoid scores, the
+    selection bias in the choice only, the choice limited by groups, SwiGLU
+    experts of which this chip may hold a share, a shared expert, nothing
+    dropped by capacity."""
+    from deepspeed_tpu.moe.layer import MoE
+    return MoE(
+        hidden_size=cfg.hidden_size,
+        num_experts=cfg.router_experts or cfg.num_experts,
+        k=cfg.num_experts_per_tok,
+        intermediate_size=cfg.moe_intermediate_size,
+        norm_topk_prob=cfg.norm_topk_prob, drop_tokens=False,
+        dtype=cfg.dtype, activation="silu", dispatch_impl=cfg.dispatch_impl,
+        score_fn="sigmoid", selection_bias=True,
+        bias_init=nn.initializers.normal(cfg.router_bias_scale),
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        held_offset=cfg.expert_offset, held_experts=cfg.num_experts,
+        shared_intermediate_size=cfg.moe_shared_expert_intermediate_size,
+        name=name)
+
+
+class Layers(nn.Module):
+    """The walk over the layers: one loop over `cfg.kinds`, each layer's
+    mixer (`layer_<i>`) built by its kind and handed its slab of its kind's
+    stacked buffer, then its FFN (`layer_<i>_mlp`)."""
+    cfg: LingLinearConfig
+
+    @nn.compact
+    def __call__(self, h, cache=None):
+        cfg = self.cfg
+        state = None if cache is None else cache.state
+        latent = None if cache is None else cache.latent
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)  # noqa: E731
+        staged = []  # a decode step's new latent row of each MLA layer
+        for i, kind in enumerate(cfg.kinds):
+            slot = cfg.kinds[:i].count(kind)
+            x = norm(f"layer_{i}_norm")(h)
+            if kind == "K":
+                out, state = KDAMixer(cfg, name=f"layer_{i}")(x, state, slot)
+            else:
+                out, made = MLAMixer(cfg, name=f"layer_{i}")(x, latent, slot)
+                if made is not None and h.shape[1] == 1:
+                    staged.append(made)
+                elif made is not None:
+                    latent = made
+            h = h + out
+            x = norm(f"layer_{i}_mlp_norm")(h)
+            if i < cfg.first_k_dense_replace:
+                h = h + DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
+            else:
+                h = h + _experts(cfg, f"layer_{i}_mlp")(x, train=False)
+        if staged:  # the step's one write, every MLA layer's token
+            latent = latent.land(jnp.stack(staged))
+        if cache is not None:
+            cache = cache.replace(state=state, latent=latent)
+        return h, cache
+
+
+def _embedded(cfg: LingLinearConfig, embed, ids):
+    h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
+    return shard_along(h, BATCH_AXES, "sequence", None)
+
+
+class _RowGroups(nn.Module):
+    """`Layers` for `rows` sequences of the batch at a time, the whole cache
+    carried: the body of the scan a large prefill runs over its rows. It
+    shares `Layers`' scope, so the parameters are the same tree. The group's
+    tokens are embedded here (the whole batch's embedded prompt is 0.67 GB at
+    128 x 1024), and only each sequence's last position goes on to the head."""
+    cfg: LingLinearConfig
+    rows: int
+
+    @nn.compact
+    def __call__(self, cache, embed, group):
+        ids, start = group
+        layers = Layers(self.cfg)
+        nn.share_scope(self, layers)
+        h, part = layers(_embedded(self.cfg, embed, ids),
+                         cache.rows(start, self.rows))
+        return cache.with_rows(part, start), h[:, -1:]
+
+
+class LingLinearForCausalLM(nn.Module):
+    cfg: LingLinearConfig
+    # what the expert layers count inside a serving program, summed over the
+    # call by the engine (`serving` event)
+    program_counters = ("assignments", "held_assignments", "experts_touched",
+                        "experts_held")
+
+    @nn.compact
+    def __call__(self, input_ids, labels=None, cache=None):
+        cfg = self.cfg
+        embed = self.param("embed_tokens", nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), ("vocab", "embed")),
+            (cfg.vocab_size, cfg.hidden_size), F32)
+        b, s = input_ids.shape
+        rows = max((r for r in range(1, b + 1)
+                    if b % r == 0 and r * s <= PREFILL_TOKENS), default=1)
+        if cache is not None and s > 1 and rows < b:
+            walk = nn.scan(_RowGroups, variable_broadcast="params",
+                           variable_axes={"counters": 0},
+                           split_rngs={"params": False},
+                           in_axes=(nn.broadcast, 0), out_axes=0)
+            cache, h = walk(cfg, rows, name="layers")(
+                cache, embed, (input_ids.reshape(b // rows, rows, s),
+                               jnp.arange(0, b, rows, dtype=jnp.int32)))
+            h = h.reshape(b, 1, -1)
+        else:
+            h, cache = Layers(cfg, name="layers")(
+                _embedded(cfg, embed, input_ids), cache)
+            if cache is not None:
+                h = h[:, -1:]          # a serving pass samples the last one
+        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(h)
+        lm_head = self.param("lm_head", nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), ("embed", "vocab")),
+            (cfg.hidden_size, cfg.vocab_size), F32)
+        logits = h @ lm_head.astype(cfg.dtype)
+        if cache is not None:
+            return logits, cache.advance(s)
+        if labels is None:
+            return logits
+        from deepspeed_tpu.models.common import causal_lm_loss
+        return causal_lm_loss(logits, input_ids, labels)
+
+    def make_cache(self, batch: int, max_len: int, dtype: Any = None,
+                   quantized: bool = False):
+        """The cache a serving program carries for `batch` sequences of up to
+        `max_len` positions, by kind: the latent rows of the MLA layers, the
+        KDA layers' matrix states and convolution tails, no K or V at all."""
+        from deepspeed_tpu.inference.kv_cache import (HybridCache, LatentCache,
+                                                      RecurrentState)
+        cfg = self.cfg
+        if quantized:
+            raise ValueError("LingLinear: an int8 cache is not implemented "
+                             "for a hybrid cache (kv_cache_dtype=None)")
+        dtype = dtype or cfg.dtype
+        return HybridCache(
+            kv=None,
+            latent=LatentCache.create(cfg.num_kv_layers, batch, max_len,
+                                      cfg.latent_width, dtype=dtype),
+            state=RecurrentState.create(
+                cfg.kinds.count("K"), batch, cfg.kda_state_shape,
+                cfg.short_conv_kernel_size, cfg.conv_dim, dtype=dtype))
+
+
+def init_params_and_specs(cfg: LingLinearConfig, rng=None, seq_len: int = 8):
+    from deepspeed_tpu.models.common import abstract_specs
+    model = LingLinearForCausalLM(cfg)
+    return model, abstract_specs(model, rng, seq_len)
+
+
+def materialize_params(cfg: LingLinearConfig, rng=None, seq_len: int = 8,
+                       param_dtype=None):
+    """(model, the whole tree on the device from the seed), ONE jitted call;
+    `param_dtype` casts inside it (the float32 tree is 17.6 GB and fits no
+    chip)."""
+    from deepspeed_tpu.models.common import materialize
+    model = LingLinearForCausalLM(cfg)
+    return model, materialize(model, rng, seq_len, param_dtype)
+
+
+def ling_linear_loss_fn(model: LingLinearForCausalLM):
+    from deepspeed_tpu.models.common import make_causal_loss_fn
+    return make_causal_loss_fn(model)

@@ -105,9 +105,6 @@ class NemotronHConfig:
             raise ValueError(
                 f"hybrid_override_pattern {pat!r}: {self.num_hidden_layers} "
                 f"layers of kinds {KINDS!r} (M Mamba-2, E experts, * attention)")
-        if (self.n_group, self.topk_group) != (1, 1):
-            raise ValueError("routing limited by groups is not implemented "
-                             "(n_group, topk_group must be 1)")
 
     def count(self, kind: str) -> int:
         return self.hybrid_override_pattern.count(kind)
@@ -355,6 +352,7 @@ def _experts(cfg: NemotronHConfig, name: str):
         score_fn="sigmoid", selection_bias=True,
         bias_init=nn.initializers.normal(cfg.router_bias_scale),
         routed_scaling_factor=cfg.routed_scaling_factor,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
         held_offset=cfg.expert_offset, held_experts=cfg.n_routed_experts,
         shared_intermediate_size=cfg.moe_shared_expert_intermediate_size,
         name=name)

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.moe.sharded_moe import (
     _gating_core, dispatch_combine, dispatch_combine_gmm,
-    dispatch_combine_ragged, held_dispatch_gmm, held_dispatch_ragged,
-    route_topk, topkgating)
+    dispatch_combine_ragged, held_assignments, held_dispatch_gmm,
+    held_dispatch_ragged, route_topk, topkgating)
 from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 
@@ -166,6 +166,10 @@ class TopKGate(nn.Module):
     selection_bias: bool = False
     bias_init: Callable = nn.initializers.zeros_init()
     scale: float = 1.0      # `routed_scaling_factor`, on the weights
+    # routing limited by groups (`sharded_moe.limit_to_groups`): the best
+    # `topk_group` of `n_group` contiguous groups of experts stay in the choice
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x, train: bool = True, noise_rng=None,
@@ -181,11 +185,13 @@ class TopKGate(nn.Module):
             # (weights (T, k), expert ids (T, k)) with no capacity: for a
             # layer that drops nothing
             return route_topk(logits, self.k, self.score_fn, bias,
-                              self.norm_topk_prob, self.scale)
+                              self.norm_topk_prob, self.scale, self.n_group,
+                              self.topk_group)
         cf = self.capacity_factor if train else self.eval_capacity_factor
         policy = self.noisy_gate_policy if train else None
         scoring = dict(score_fn=self.score_fn, select_bias=bias,
-                       scale=self.scale)
+                       scale=self.scale, n_group=self.n_group,
+                       topk_group=self.topk_group)
         if ragged:
             l_aux, gate_k, topk_idx, pos_k, kept, _, cap = _gating_core(
                 logits, self.k, cf, self.min_capacity, self.drop_tokens,
@@ -229,6 +235,8 @@ class MoE(nn.Module):
     selection_bias: bool = False
     bias_init: Callable = nn.initializers.zeros_init()
     routed_scaling_factor: float = 1.0
+    n_group: int = 1                      # routing limited by groups
+    topk_group: int = 1
     # A layer that is TOLD which experts it holds: `held_experts` of the
     # `num_experts` the router scores, from `held_offset` on (one chip's
     # share under expert parallelism, the model-configs guide's section 4).
@@ -254,7 +262,8 @@ class MoE(nn.Module):
                         self.drop_tokens, self.noisy_gate_policy,
                         self.norm_topk_prob, self.dtype, self.score_fn,
                         self.selection_bias, self.bias_init,
-                        self.routed_scaling_factor, name="gate")
+                        self.routed_scaling_factor, self.n_group,
+                        self.topk_group, name="gate")
         noise_rng = self.make_rng("gating") if self.has_rng("gating") else None
 
         if self.held_experts is not None:
@@ -339,7 +348,9 @@ class MoE(nn.Module):
     def _held(self, x, gate, f, valid):
         """This chip's part of the layer for tokens x (T, D): the held
         experts' weighted outputs plus the shared expert. Sows the call's
-        `assignments` and `held_assignments` (collection `counters`)."""
+        `assignments` and `held_assignments`, and `experts_touched` of
+        `experts_held`: the held experts that received an assignment, whose
+        weights the call reads (collection `counters`)."""
         t, d = x.shape
         count, k = self.held_experts, self.k
         experts = Experts(count, d, f, self.dtype, self.activation,
@@ -375,7 +386,12 @@ class MoE(nn.Module):
                              self.activation, name="shared_expert")
             out = out + shared(x[None])[0].astype(jnp.float32)
         total = t * k if valid is None else k * jnp.sum(valid.astype(jnp.int32))
-        for name, value in (("assignments", total), ("held_assignments", held)):
+        local = held_assignments(topk_idx, self.held_offset, count, valid)[1]
+        touched = jnp.sum(jnp.bincount(local.reshape(-1), length=count + 1)
+                          [:count] > 0)
+        for name, value in (("assignments", total), ("held_assignments", held),
+                            ("experts_touched", touched),
+                            ("experts_held", count)):
             self.sow("counters", name, jnp.asarray(value, jnp.int32),
                      init_fn=lambda: jnp.zeros([], jnp.int32),
                      reduce_fn=lambda a, b_: a + b_)

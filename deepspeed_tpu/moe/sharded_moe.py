@@ -34,14 +34,39 @@ def _one_hot(idx, n):
     return jax.nn.one_hot(idx, n, dtype=jnp.float32)
 
 
+def limit_to_groups(chosen_by: jnp.ndarray, n_group: int,
+                    topk_group: int) -> jnp.ndarray:
+    """Routing limited by GROUPS (DeepSeek-V3's `n_group` / `topk_group`):
+    the experts lie in `n_group` contiguous groups of equal size, a group's
+    score is the sum of its best two entries of `chosen_by` (T, E), the best
+    `topk_group` groups stay and every expert outside them can no longer be
+    chosen (-inf). `n_group` 1: `chosen_by` as it is."""
+    if n_group == 1:
+        return chosen_by
+    t, e = chosen_by.shape
+    if e % n_group or not 0 < topk_group <= n_group or e // n_group < 2:
+        raise ValueError(f"routing limited by groups: {e} experts in "
+                         f"{n_group} groups of at least two, of which "
+                         f"{topk_group} stay")
+    grouped = chosen_by.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)   # (T, G)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    stays = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.int32), axis=1) > 0
+    return jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(t, e)
+
+
 def route_scores(logits: jnp.ndarray, score_fn: str = "softmax",
-                 select_bias: Optional[jnp.ndarray] = None
+                 select_bias: Optional[jnp.ndarray] = None,
+                 n_group: int = 1, topk_group: int = 1
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(scores, what the top-k is taken of), both (T, E) float32. `softmax`
     scores are chosen by their logits, as they always were; `sigmoid`
     scores (DeepSeek-V3, Nemotron-H) by the scores themselves, each expert
     scored alone. A `select_bias` (E,) is added for the CHOICE only
-    (`e_score_correction_bias`): the weights stay the unbiased scores."""
+    (`e_score_correction_bias`): the weights stay the unbiased scores. With
+    `n_group` above 1 the choice is limited by groups (`limit_to_groups`,
+    on the biased scores): the experts of the groups that fell out are -inf
+    in what the top-k is taken of."""
     logits = logits.astype(jnp.float32)
     if score_fn == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
@@ -52,17 +77,20 @@ def route_scores(logits: jnp.ndarray, score_fn: str = "softmax",
         raise ValueError(f"score_fn {score_fn!r}: 'softmax' or 'sigmoid'")
     if select_bias is not None:
         chosen_by = chosen_by + select_bias.astype(jnp.float32)
-    return scores, chosen_by
+    return scores, limit_to_groups(chosen_by, n_group, topk_group)
 
 
 def route_topk(logits: jnp.ndarray, k: int, score_fn: str = "softmax",
                select_bias: Optional[jnp.ndarray] = None,
-               norm_topk_prob: bool = True, scale: float = 1.0
+               norm_topk_prob: bool = True, scale: float = 1.0,
+               n_group: int = 1, topk_group: int = 1
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k routing with no capacity: (weights (T, k) float32, expert ids
     (T, k)). The weights are the scores of the chosen experts, over their
-    sum where `norm_topk_prob`, times `scale` (`routed_scaling_factor`)."""
-    scores, chosen_by = route_scores(logits, score_fn, select_bias)
+    sum where `norm_topk_prob`, times `scale` (`routed_scaling_factor`);
+    the choice is limited by groups where `n_group` is above 1."""
+    scores, chosen_by = route_scores(logits, score_fn, select_bias, n_group,
+                                     topk_group)
     _, topk_idx = jax.lax.top_k(chosen_by, k)
     gate_k = jnp.take_along_axis(scores, topk_idx, axis=-1)
     if norm_topk_prob:
@@ -76,7 +104,7 @@ def _gating_core(logits: jnp.ndarray, k: int, capacity_factor: float,
                  noise_rng, noisy_gate_policy, norm_topk_prob: bool = True,
                  score_fn: str = "softmax",
                  select_bias: Optional[jnp.ndarray] = None,
-                 scale: float = 1.0):
+                 scale: float = 1.0, n_group: int = 1, topk_group: int = 1):
     """Shared top-k decisions. Returns (l_aux, gate_k (T,k), topk_idx (T,k),
     pos_k (T,k), kept (T,k), masks (T,k,E), cap). Both the einsum and the
     ragged dispatch consume exactly these decisions."""
@@ -84,8 +112,9 @@ def _gating_core(logits: jnp.ndarray, k: int, capacity_factor: float,
     cap = _capacity(t, e, capacity_factor, min_capacity, k)
     if not drop_tokens:
         cap = t  # every token can fit
-    gates, select_from = route_scores(logits, score_fn, select_bias)
-    if score_fn == "softmax" and select_bias is None:
+    gates, select_from = route_scores(logits, score_fn, select_bias, n_group,
+                                      topk_group)
+    if score_fn == "softmax" and select_bias is None and n_group == 1:
         select_from = logits             # in the logits' own dtype, as before
     if noisy_gate_policy == "RSample" and noise_rng is not None:
         select_from = select_from + jax.random.gumbel(noise_rng, logits.shape)

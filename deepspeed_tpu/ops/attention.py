@@ -543,6 +543,23 @@ def _stacked_dense_attention(q, k_cache, v_cache, index, mask, impl, window,
     return jnp.einsum("bgrqk,bgkd->bqgrd", probs, v).reshape(b, s, h, d)
 
 
+def _one_device_kernel(name: str) -> bool:
+    """Whether a bare Pallas kernel may run here: on the chip, in a program
+    that is one device's (a bare Mosaic call cannot be partitioned; a mesh
+    with a nontrivial axis is announced and takes the `jax.numpy` path)."""
+    if not _use_pallas():
+        return False
+    from deepspeed_tpu.ops.pallas.sharded import (_topology_mesh,
+                                                  kernel_fallback,
+                                                  nontrivial_axes)
+    topo = _topology_mesh()
+    if topo is not None and nontrivial_axes(topo):
+        kernel_fallback(name, f"mesh axes {nontrivial_axes(topo)}: the "
+                              "kernel is one device's")
+        return False
+    return True
+
+
 def diff_decode(q, k_cache, v_cache, lengths, lam, softmax_scale: float,
                 eps: float, k_new=None, v_new=None, slots=None,
                 ring: bool = False):
@@ -557,22 +574,44 @@ def diff_decode(q, k_cache, v_cache, lengths, lam, softmax_scale: float,
     program is one device's (a bare Mosaic call cannot be partitioned);
     elsewhere the same in plain `jax.numpy`."""
     from deepspeed_tpu.ops.pallas import diff_attention as da
-    kernel = _use_pallas() and q.shape[-1] % 128 == 0
-    if kernel:
-        from deepspeed_tpu.ops.pallas.sharded import (_topology_mesh,
-                                                      kernel_fallback,
-                                                      nontrivial_axes)
-        topo = _topology_mesh()
-        if topo is not None and nontrivial_axes(topo):
-            kernel_fallback("diff_decode_attention",
-                            f"mesh axes {nontrivial_axes(topo)}: the kernel "
-                            "is one device's")
-            kernel = False
+    kernel = q.shape[-1] % 128 == 0 and _one_device_kernel(
+        "diff_decode_attention")
     fn = da.diff_decode_attention if kernel \
         else da.diff_decode_attention_reference
     return fn(q, k_cache.stack, v_cache.stack, k_cache.layer, lengths, lam,
               softmax_scale, eps, k_new=k_new, v_new=v_new, slots=slots,
               ring=ring)
+
+
+def latent_decode(q_lat, q_rope, latent, lengths, softmax_scale: float,
+                  new=None, slots=None):
+    """One decode step of latent attention (MLA) in its ABSORBED form over a
+    stacked latent cache (`ops/pallas/mla.py` has the layout): q_lat (B, H,
+    rank), the queries' nope parts taken through the key half of the
+    up-projection, q_rope (B, H, rope), `latent` a `DenseLayer` view of the
+    (L, B, 1, M, rank + rope) stack, `lengths` (B,) valid slots, and the
+    row's staged token `new` (B, rank + rope) standing in slot `slots[b]`.
+    Returns the weighted sum of the cached LATENTS (B, H, rank) float32.
+
+    The Pallas kernel on the chip in a one-device program; elsewhere the
+    same in plain `jax.numpy`."""
+    from deepspeed_tpu.ops.pallas import mla
+    fn = mla.mla_latent_decode if _one_device_kernel(mla.KERNEL_NAME) \
+        else mla.mla_latent_decode_reference
+    return fn(q_lat, q_rope, latent.stack, latent.layer, lengths,
+              softmax_scale, new=new, slots=slots)
+
+
+def kda_update(state, layer, q, k, v, g, beta):
+    """One decode step of the gated delta rule on layer `layer` of the
+    stacked float32 state (`ops/pallas/kda.py` has the layout and the
+    operands): `(o (B, H, dv) float32, state)`. The Pallas kernel, one read
+    and one write of the layer's state in place, on the chip in a one-device
+    program; elsewhere the same in plain `jax.numpy`."""
+    from deepspeed_tpu.ops.pallas import kda
+    fn = kda.kda_state_update if _one_device_kernel(kda.KERNEL_NAME) \
+        else kda.kda_state_update_reference
+    return fn(state, layer, q, k, v, g, beta)
 
 
 def rms_norm_ref(x, weight, eps: float = 1e-6):

@@ -34,7 +34,10 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "ssm_state_update_m1": "ssm_state_update_m1",
                "diff_attn_window_decode": "diff_attn_window_decode",
                "diff_attn_shared_decode": "diff_attn_shared_decode",
-               "grouped_gemm_decode": "gmm"}
+               "grouped_gemm_decode": "gmm",
+               "kda_state_update": "kda_state_update",
+               "mla_latent_decode": "mla_latent_decode",
+               "latent_write_dense": "latent_write_dense"}
 
 
 @pytest.fixture(scope="module")
@@ -63,10 +66,11 @@ def compiled_kernels(monkeypatch):
     steer them to the compiled path here, in the test."""
     from deepspeed_tpu.ops.pallas import (
         block_sparse_attention, decode_attention, diff_attention,
-        flash_attention, grouped_gemm, paged_attention, quantized_matmul, ssm)
+        flash_attention, grouped_gemm, kda, mla, paged_attention,
+        quantized_matmul, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
     for mod in (block_sparse_attention, decode_attention, diff_attention,
-                flash_attention, grouped_gemm, paged_attention,
+                flash_attention, grouped_gemm, kda, mla, paged_attention,
                 quantized_matmul, ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
