@@ -5,6 +5,12 @@ torch version needs retain_graph double-backward; JAX's `jax.jvp` over
 
 Used by MoQ (`runtime/quantize.py`) to schedule per-layer quantization
 periods by curvature.
+
+`loss_fn` is differentiated in forward mode over REVERSE mode: `jax.jvp`
+sees the forward and backward rules of any `jax.custom_vjp` inside it, not the
+function itself, so a model built with the chunked loss of
+`sequence/cross_entropy.py` gives the same eigenvalue as the unchunked
+`causal_lm_loss` (tests/unit/sequence/test_sequence.py).
 """
 
 from __future__ import annotations

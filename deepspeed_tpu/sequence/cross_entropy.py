@@ -5,10 +5,25 @@ Counterpart of reference `deepspeed/sequence/cross_entropy.py`
 (`sequence/fpdt_layer.py:1137`). The reference splits the vocab matmul per
 TP rank and all-reduces partial logsumexps; here the chunking is over the
 *sequence* axis — per chunk we compute (B, C, V) logits, reduce them to a
-per-token loss, and drop them before the next chunk, under `jax.checkpoint`
-so the backward recomputes each chunk instead of storing it. Vocab-parallel
-TP falls out declaratively: with `lm_head` sharded over 'model' on the vocab
+per-token loss, and drop them before the next chunk. Vocab-parallel TP
+falls out declaratively: with `lm_head` sharded over 'model' on the vocab
 dim, XLA reduces the chunk logsumexp across TP ranks.
+
+The logits and the softmax are computed ONCE. The loss is a
+`jax.custom_vjp` whose forward rule makes the gradient while a chunk's
+logits are live (`dlogits = (softmax - onehot) * mask`, every entry in
+[-1, 1] when it is rounded to the matmuls' dtype, then
+`dh_blk = dlogits @ W^T` and `dW += h_blk^T @ dlogits`, the latter carried
+across chunks in float32), and whose backward rule only scales `dh` and
+`dW` by `cotangent / count` in float32: three vocabulary-wide matmuls and
+one softmax pass a chunk, no recompute, and no second gather of a sharded
+head. Neither the mean nor a loss scale reaches a half-precision value
+before that last multiply, so float16 with any loss scale keeps what
+float32 would. The residuals are `dh` (the size of `h`), `dW` (the size of
+the head, in float32) and the token count. Without `grad` around it the
+loss runs the plain forward alone. `jax.jvp` straight over the loss is not
+defined; over its `jax.grad` it is (`runtime/eigenvalue.py`), because that
+differentiates the two rules.
 
 Peak logits memory: O(B · chunk · V) instead of O(B · S · V) — the piece
 that makes 128k-context training (BASELINE config 5) fit.
@@ -16,10 +31,107 @@ that makes 128k-context training (BASELINE config 5) fit.
 
 from __future__ import annotations
 
-from typing import Optional
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+
+def _chunk_terms(h_blk, y_blk, lm_head, ignore_index, tied_embedding):
+    """One chunk's float32 logits reduced to its loss sum, with what the
+    gradient needs of the same softmax pass: `exp(logits - max)`, its row
+    sums, the clipped labels and the mask."""
+    if tied_embedding:
+        logits = jnp.einsum("bcd,vd->bcv", h_blk, lm_head)
+    else:
+        logits = h_blk @ lm_head
+    logits = logits.astype(jnp.float32)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    e = jnp.exp(logits - top)
+    e_sum = jnp.sum(e, axis=-1, keepdims=True)
+    lse = (jnp.log(e_sum) + top)[..., 0]
+    y_safe = jnp.clip(y_blk, 0, logits.shape[-1] - 1)
+    gold = jnp.take_along_axis(logits, y_safe[..., None], axis=-1)[..., 0]
+    mask = (y_blk != ignore_index).astype(jnp.float32)
+    return jnp.sum((lse - gold) * mask), (e, e_sum, y_safe, mask)
+
+
+def _token_count(labels, ignore_index):
+    """The mean's denominator: known from the labels, before any logits."""
+    return jnp.maximum(
+        jnp.sum((labels != ignore_index).astype(jnp.float32)), 1.0)
+
+
+def _by_chunk(x, chunk):
+    """(B, S, ...) -> (S // chunk, B, chunk, ...): a scan's leading axis."""
+    b, s = x.shape[:2]
+    return jnp.moveaxis(x.reshape(b, s // chunk, chunk, *x.shape[2:]), 1, 0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _chunked_ce(h, lm_head, labels, chunk, ignore_index, tied_embedding):
+    def body(loss_sum, xs):
+        blk_sum, _ = _chunk_terms(*xs, lm_head, ignore_index, tied_embedding)
+        return loss_sum + blk_sum, None
+
+    loss_sum, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (_by_chunk(h, chunk), _by_chunk(labels, chunk)))
+    return loss_sum / _token_count(labels, ignore_index)
+
+
+def _chunked_ce_fwd(h, lm_head, labels, chunk, ignore_index, tied_embedding):
+    operand_dtype = jnp.result_type(h.dtype, lm_head.dtype)
+    # float16 alone can overflow on a sum over a chunk's tokens
+    dw_blk_dtype = (jnp.float32 if operand_dtype == jnp.float16
+                    else operand_dtype)
+
+    def body(carry, xs):
+        loss_sum, dw = carry
+        h_blk, y_blk = xs
+        blk_sum, (e, e_sum, y_safe, mask) = _chunk_terms(
+            h_blk, y_blk, lm_head, ignore_index, tied_embedding)
+        # UNSCALED, so every entry is in [-1, 1] when it is rounded to the
+        # matmuls' dtype: the mean's 1 / count and the cotangent (a loss
+        # scale, under float16) are applied in float32 by the backward, and
+        # neither can push a float16 `dlogits` or `dh` under its range
+        mask = mask[..., None]
+        softmax_part = e * (mask / e_sum)
+        hit = y_safe[..., None] == jnp.arange(e.shape[-1])
+        dlogits = jnp.where(hit, softmax_part - mask, softmax_part)
+        dlogits = dlogits.astype(operand_dtype)
+        # a chunk's products leave the matmul in its operands' dtype, as the
+        # transpose of the forward's would (and a sharded batch's partial
+        # `dw_blk` is reduced in it); the SUM over chunks is float32
+        if tied_embedding:
+            dh_blk = jnp.einsum("bcv,vd->bcd", dlogits, lm_head)
+            dw_blk = jnp.einsum("bcv,bcd->vd", dlogits, h_blk,
+                                preferred_element_type=dw_blk_dtype)
+        else:
+            dh_blk = jnp.einsum("bcv,dv->bcd", dlogits, lm_head)
+            dw_blk = jnp.einsum("bcd,bcv->dv", h_blk, dlogits,
+                                preferred_element_type=dw_blk_dtype)
+        return ((loss_sum + blk_sum, dw + dw_blk.astype(jnp.float32)),
+                dh_blk.astype(h.dtype))
+
+    (loss_sum, dw), dh = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32),
+               jnp.zeros(lm_head.shape, jnp.float32)),
+        (_by_chunk(h, chunk), _by_chunk(labels, chunk)))
+    dh = jnp.moveaxis(dh, 0, 1).reshape(h.shape)
+    count = _token_count(labels, ignore_index)
+    # the empty array carries the head's dtype to the backward, not the head
+    return loss_sum / count, (dh, dw, count, jnp.zeros((0,), lm_head.dtype))
+
+
+def _chunked_ce_bwd(chunk, ignore_index, tied_embedding, residuals, g):
+    dh, dw, count, head_like = residuals
+    scale = g.astype(jnp.float32) / count
+    return ((scale * dh).astype(dh.dtype),
+            (scale * dw).astype(head_like.dtype), None)
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 
 
 def chunked_softmax_cross_entropy(h: jnp.ndarray, lm_head, labels: jnp.ndarray,
@@ -32,35 +144,13 @@ def chunked_softmax_cross_entropy(h: jnp.ndarray, lm_head, labels: jnp.ndarray,
     h: (B, S, D); lm_head: (D, V) — or (V, D) with `tied_embedding=True`;
     labels: (B, S) int32, `ignore_index` masks tokens out.
     """
-    b, s, d = h.shape
+    s = h.shape[1]
     chunk = min(chunk_size, s)
     while s % chunk:
         chunk -= 1
-    n = s // chunk
-    hc = h.reshape(b, n, chunk, d)
-    yc = labels.reshape(b, n, chunk)
-
-    def body(carry, xs):
-        loss_sum, count = carry
-        h_blk, y_blk = xs  # (B, C, D), (B, C)
-        if tied_embedding:
-            logits = jnp.einsum("bcd,vd->bcv", h_blk, lm_head)
-        else:
-            logits = h_blk @ lm_head
-        logits = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        y_safe = jnp.clip(y_blk, 0, logits.shape[-1] - 1)
-        gold = jnp.take_along_axis(logits, y_safe[..., None], axis=-1)[..., 0]
-        mask = (y_blk != ignore_index).astype(jnp.float32)
-        loss_sum = loss_sum + jnp.sum((lse - gold) * mask)
-        count = count + jnp.sum(mask)
-        return (loss_sum, count), None
-
-    body = jax.checkpoint(body, prevent_cse=False)
-    (loss_sum, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (jnp.moveaxis(hc, 1, 0), jnp.moveaxis(yc, 1, 0)))
-    return loss_sum / jnp.maximum(count, 1.0)
+    with jax.named_scope("chunked_ce"):
+        return _chunked_ce(h, lm_head, labels, chunk, ignore_index,
+                           tied_embedding)
 
 
 def vocab_sequence_parallel_cross_entropy(h, lm_head, labels, chunk_size=2048,

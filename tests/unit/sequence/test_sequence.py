@@ -1,6 +1,8 @@
 """Sequence-parallel tests (reference tests/unit/sequence_parallelism/
 test_ulysses.py): a2a emission, uneven heads, chunked CE, long context."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,6 +81,324 @@ def test_chunked_ce_tied_embedding():
     chunked = chunked_softmax_cross_entropy(h, emb, labels, chunk_size=8,
                                             tied_embedding=True)
     np.testing.assert_allclose(float(chunked), float(dense), rtol=1e-6)
+
+
+def _checkpointed_ce(h, lm_head, labels, chunk_size, ignore_index=-100,
+                     tied_embedding=False):
+    """The form the loss had until PR 48, kept as the plain reference: each
+    chunk under `jax.checkpoint`, the gradient left to the scan's transpose
+    (which runs the chunk's logits and softmax a second time)."""
+    b, s, d = h.shape
+    chunk = min(chunk_size, s)
+    while s % chunk:
+        chunk -= 1
+    n = s // chunk
+
+    def body(carry, xs):
+        loss_sum, count = carry
+        h_blk, y_blk = xs
+        if tied_embedding:
+            logits = jnp.einsum("bcd,vd->bcv", h_blk, lm_head)
+        else:
+            logits = h_blk @ lm_head
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        y_safe = jnp.clip(y_blk, 0, logits.shape[-1] - 1)
+        gold = jnp.take_along_axis(logits, y_safe[..., None], axis=-1)[..., 0]
+        mask = (y_blk != ignore_index).astype(jnp.float32)
+        return (loss_sum + jnp.sum((lse - gold) * mask),
+                count + jnp.sum(mask)), None
+
+    (loss_sum, count), _ = jax.lax.scan(
+        jax.checkpoint(body, prevent_cse=False),
+        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+        (jnp.moveaxis(h.reshape(b, n, chunk, d), 1, 0),
+         jnp.moveaxis(labels.reshape(b, n, chunk), 1, 0)))
+    return loss_sum / jnp.maximum(count, 1.0)
+
+
+def _dense_ce(h, lm_head, labels, tied_embedding=False):
+    from deepspeed_tpu.models.common import cross_entropy_loss
+    logits = (jnp.einsum("bsd,vd->bsv", h, lm_head) if tied_embedding
+              else h @ lm_head)
+    return cross_entropy_loss(logits.astype(jnp.float32), labels)
+
+
+def _ce_case(dtype=jnp.float32, tied=False, b=2, s=64, d=32, v=100, seed=10):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    h = jax.random.normal(ks[0], (b, s, d)).astype(dtype)
+    w = (0.3 * jax.random.normal(ks[1], (v, d) if tied else (d, v))
+         ).astype(dtype)
+    labels = jax.random.randint(ks[2], (b, s), 0, v)
+    return h, w, labels.at[:, -5:].set(-100).at[0, 7].set(-100)
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+# bf16: dlogits and both products are rounded to 8 bits of mantissa, and
+# the checkpointed form summed the chunks' dW in bf16 where this one sums
+# in float32: 2^-6 of the largest gradient entry covers both
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("dtype,rtol,atol_of_max", [
+    (jnp.float32, 1e-5, 1e-6), (jnp.bfloat16, 0.0, 2.0 ** -6)],
+    ids=["f32", "bf16"])
+@pytest.mark.parametrize("against", ["dense", "checkpointed"])
+def test_chunked_ce_grads(against, dtype, rtol, atol_of_max, tied):
+    """dh AND d(head) of the loss whose forward makes its own gradient,
+    against the dense loss and against the checkpointed chunk scan."""
+    h, w, labels = _ce_case(dtype, tied)
+    if against == "dense":
+        def ref(h, w):
+            return _dense_ce(h, w, labels, tied)
+    else:
+        def ref(h, w):
+            return _checkpointed_ce(h, w, labels, 16, tied_embedding=tied)
+
+    def new(h, w):
+        return chunked_softmax_cross_entropy(h, w, labels, chunk_size=16,
+                                             tied_embedding=tied)
+
+    want_loss, want = jax.value_and_grad(ref, (0, 1))(h, w)
+    got_loss, got = jax.value_and_grad(new, (0, 1))(h, w)
+    np.testing.assert_allclose(float(got_loss), float(want_loss),
+                               rtol=1e-6 if dtype == jnp.float32 else 2e-3)
+    for g, r in zip(got, want):
+        assert g.dtype == r.dtype == dtype and g.shape == r.shape
+        np.testing.assert_allclose(
+            _f32(g), _f32(r), rtol=rtol,
+            atol=atol_of_max * float(np.abs(_f32(r)).max()))
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_ce_ignored_rows_give_zero_dh(tied):
+    h, w, labels = _ce_case(tied=tied)
+    dh, dw = jax.grad(lambda h, w: chunked_softmax_cross_entropy(
+        h, w, labels, chunk_size=16, tied_embedding=tied), (0, 1))(h, w)
+    ignored = np.asarray(labels == -100)
+    assert ignored.sum() == 11
+    assert not np.asarray(dh)[ignored].any()
+    assert np.abs(np.asarray(dh)[~ignored]).min(axis=-1).max() > 0
+    # and they are out of d(head) too: the gradient is that of the batch
+    # with those positions cut out of every chunk
+    kept = lambda h, w: _dense_ce(h[~ignored][None], w, labels[~ignored][None],
+                                  tied)
+    np.testing.assert_allclose(np.asarray(dw),
+                               np.asarray(jax.grad(kept, 1)(h, w)),
+                               rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_ce_all_ignored_is_zero_not_nan(dtype):
+    h, w, labels = _ce_case(dtype)
+    labels = jnp.full_like(labels, -100)
+    loss, (dh, dw) = jax.value_and_grad(
+        lambda h, w: chunked_softmax_cross_entropy(h, w, labels,
+                                                   chunk_size=16), (0, 1))(h, w)
+    assert float(loss) == 0.0
+    assert not _f32(dh).any() and not _f32(dw).any()   # zeros, so finite
+    # one chunk all ignored beside live ones: finite, and equal to the dense
+    labels = _ce_case(dtype)[2].at[:, 16:32].set(-100)
+    got = jax.grad(lambda h: chunked_softmax_cross_entropy(
+        h, w, labels, chunk_size=16))(h)
+    assert np.isfinite(_f32(got)).all() and not _f32(got)[:, 16:32].any()
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_ce_cotangent_scales_both_gradients(tied):
+    h, w, labels = _ce_case(tied=tied)
+
+    def loss(h, w):
+        return chunked_softmax_cross_entropy(h, w, labels, chunk_size=16,
+                                             tied_embedding=tied)
+
+    one = jax.grad(loss, (0, 1))(h, w)
+    three = jax.grad(lambda h, w: 3.0 * loss(h, w), (0, 1))(h, w)
+    for g1, g3 in zip(one, three):
+        np.testing.assert_allclose(np.asarray(g3), 3.0 * np.asarray(g1),
+                                   rtol=1e-6)
+    # and a cotangent handed to the pullback directly, as a float32 scalar
+    _, vjp = jax.vjp(lambda h: loss(h, w), h)
+    np.testing.assert_allclose(np.asarray(vjp(jnp.float32(3.0))[0]),
+                               np.asarray(three[0]), rtol=1e-6)
+
+
+# float16 under a loss scale, as the engine differentiates it
+# (`scale_loss(loss / gas)`): at 2,028 counted tokens and 4,096 words a
+# softmax entry over the count is 1e-7, under float16's smallest normal
+# (6e-5), and a gradient made from it is noise. The scale has to reach
+# `dlogits` in float32, or `dlogits` has to stay unscaled until float32
+# does the rest; the checkpointed form did the former, this one the latter.
+# 2^-8 of the largest entry: float16 rounds dlogits and both products to
+# 11 bits. "crowded" is the other end of the range: every token has one
+# label and a large common component, so a chunk's UNSCALED dW sums to
+# 1e5, past float16's 65,504, and is finite only as a float32 product.
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("crowded,loss_scale", [
+    (False, 1.0), (False, 65536.0), (True, 256.0)],
+    ids=["spread-x1", "spread-x65536", "crowded-x256"])
+def test_chunked_ce_float16_keeps_its_gradient(crowded, loss_scale, tied):
+    h, w, labels = _ce_case(jnp.float16, tied, b=4, s=512, d=32, v=4096)
+    if crowded:
+        h = (h + 100.0).astype(jnp.float16)
+        w = (w / 64).astype(jnp.float16)
+        labels = jnp.where(labels == -100, -100, 7)
+
+    def scaled(h, w):
+        return loss_scale * chunked_softmax_cross_entropy(
+            h, w, labels, chunk_size=256, tied_embedding=tied)
+
+    got = jax.grad(scaled, (0, 1))(h, w)
+    want = jax.grad(lambda h, w: _dense_ce(h, w, labels, tied), (0, 1))(
+        h.astype(jnp.float32), w.astype(jnp.float32))
+    for g, r in zip(got, want):
+        assert g.dtype == jnp.float16
+        r = np.asarray(r)
+        assert np.abs(r).max() * loss_scale < 65504   # float16 can hold it
+        np.testing.assert_allclose(
+            _f32(g) / loss_scale, r, rtol=0, atol=2.0 ** -8 * np.abs(r).max())
+
+
+def test_chunked_ce_hessian_vector_products():
+    """`runtime/eigenvalue.py` runs `jax.jvp` over `jax.grad`: that
+    differentiates the loss's forward and backward rules, and finds the
+    curvature the dense loss has. `jax.jvp` of the loss itself is refused."""
+    from deepspeed_tpu.runtime.eigenvalue import Eigenvalue
+    h, w, labels = _ce_case()
+    params = {"mix": jnp.eye(h.shape[-1]), "head": w}
+    chunked = lambda p: chunked_softmax_cross_entropy(
+        h @ p["mix"], p["head"], labels, chunk_size=16)
+    dense = lambda p: _dense_ce(h @ p["mix"], p["head"], labels)
+    power = Eigenvalue(max_iter=100, tol=1e-5)
+    want = power.compute_eigenvalue(dense, params)
+    assert want > 0
+    assert power.compute_eigenvalue(chunked, params) == pytest.approx(
+        want, rel=1e-4)
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(chunked, (params,), (params,))
+
+
+@pytest.mark.parametrize("chunk_size,chunk", [(16, 15), (7, 6), (1000, 60),
+                                              (60, 60), (1, 1)])
+def test_chunked_ce_chunk_that_does_not_divide_falls_back(chunk_size, chunk):
+    """S = 60: the chunk is the largest divisor of S at or under the asked
+    size, as before; value and gradients do not depend on it."""
+    h, w, labels = _ce_case(s=60)
+    fn = lambda h, w: chunked_softmax_cross_entropy(h, w, labels,
+                                                    chunk_size=chunk_size)
+    scans = [e for e in _eqns(jax.make_jaxpr(jax.grad(fn))(h, w).jaxpr)
+             if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [60 // chunk]
+    loss, grads = jax.value_and_grad(fn, (0, 1))(h, w)
+    want_loss, want = jax.value_and_grad(
+        lambda h, w: _dense_ce(h, w, labels), (0, 1))(h, w)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+    for g, r in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------- chunked CE: what a chunk runs
+def _vocab_work(fn, *args, b=2, chunk=16, v=100):
+    """(vocabulary-wide dot_generals, exps over (B, chunk, V), remat
+    equations, scans) in the jaxpr of `fn`: the scan over chunks is not
+    unrolled, so an equation in its body is work done once a chunk."""
+    eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    def shapes(e):
+        return [x.aval.shape for x in (*e.invars, *e.outvars)]
+
+    return (sum(e.primitive.name == "dot_general"
+                and any(v in s for s in shapes(e)) for e in eqns),
+            sum(e.primitive.name == "exp"
+                and e.outvars[0].aval.shape == (b, chunk, v) for e in eqns),
+            sum(e.primitive.name in ("checkpoint", "remat", "remat2")
+                for e in eqns),
+            sum(e.primitive.name == "scan" for e in eqns))
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_ce_gradient_is_three_matmuls_and_one_softmax_a_chunk(tied):
+    """The mechanism's counter: logits, dh and dW, one pass of `exp`, ONE
+    chunk loop and nothing rematerialised; the loss alone runs one matmul
+    and one `exp`. The checkpointed form reads four and two, in two loops."""
+    h, w, labels = _ce_case(tied=tied)
+
+    def new(h, w):
+        return chunked_softmax_cross_entropy(h, w, labels, chunk_size=16,
+                                             tied_embedding=tied)
+
+    assert _vocab_work(jax.grad(new, (0, 1)), h, w) == (3, 1, 0, 1)
+    assert _vocab_work(jax.value_and_grad(new, (0, 1)), h, w) == (3, 1, 0, 1)
+    assert _vocab_work(new, h, w) == (1, 1, 0, 1)
+    old = lambda h, w: _checkpointed_ce(h, w, labels, 16, tied_embedding=tied)
+    dots, exps, remats, scans = _vocab_work(jax.grad(old, (0, 1)), h, w)
+    assert (dots, exps, scans) == (4, 2, 2) and remats >= 1
+
+
+def test_chunked_ce_is_named_in_the_trace():
+    h, w, labels = _ce_case()
+    txt = jax.jit(jax.grad(lambda h: chunked_softmax_cross_entropy(
+        h, w, labels, chunk_size=16))).lower(h).compile().as_text()
+    dots = [l for l in txt.splitlines() if " dot(" in l or "dot_general" in l]
+    assert dots and all("chunked_ce" in l for l in dots)
+
+
+# --------------------------------------------- chunked CE on dp2 x tp2
+@pytest.mark.parametrize("head_spec", [("model", None), ("model", "data")],
+                         ids=["tp", "tp_zero3"])
+def test_chunked_ce_sharded_head_matches_single_device(head_spec):
+    """The head sharded over `model` on the vocabulary (and over `data` on
+    the width, as the ZeRO-3 plan lays it): loss and both gradients are the
+    single-device ones, the chunk loop is the only loop, and nothing is
+    gathered for the backward (the checkpointed form gathered the head a
+    second time there)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    h, w, labels = _ce_case(tied=True, b=4, v=128)
+
+    def fn(loss):
+        return jax.value_and_grad(lambda h, w, y: 3.0 * loss(
+            h, w, y, chunk_size=16, tied_embedding=True), (0, 1))
+
+    want_loss, want = fn(chunked_softmax_cross_entropy)(h, w, labels)
+    topo = groups.initialize(dp=2, tp=2, devices=jax.devices()[:4])
+    mesh = topo.mesh
+    sh, sw, sy = (NamedSharding(mesh, P(*spec)) for spec in (
+        ("data", None, None), head_spec, ("data", None)))
+
+    def compiled(loss):
+        return jax.jit(fn(loss), in_shardings=(sh, sw, sy),
+                       out_shardings=(None, (sh, sw))
+                       ).lower(h, w, labels).compile()
+
+    def backward_gathers(txt):
+        return [l for l in txt.splitlines()
+                if re.search(r" all-gather(-start)?\(", l)
+                and "transpose(" in l]
+
+    new = compiled(chunked_softmax_cross_entropy)
+    txt = new.as_text()
+    assert len(re.findall(r" while\(", txt)) == 1
+    assert not backward_gathers(txt)
+    if head_spec[1]:    # the yardstick can tell: the old form gathers twice
+        assert backward_gathers(compiled(_checkpointed_ce).as_text())
+    got_loss, got = new(jax.device_put(h, sh), jax.device_put(w, sw),
+                        jax.device_put(labels, sy))
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    for g, r, s in zip(got, want, (sh, sw)):
+        assert g.sharding.is_equivalent_to(s, g.ndim)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------- a2a in HLO
