@@ -179,6 +179,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.block_sparse_attention import (
         block_sparse_attention, padded_layout_indices)
     from deepspeed_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                           decode_plan,
                                                            kv_write_dense)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
@@ -276,13 +277,20 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     # ---- the stacked dense cache (v1 generate): the kernel reads layer
     # `layer` of (L, B, Hkv, M, D) where it lies, with the step's new token
     # staged or already written; the writer lands a step's tokens ----
-    def make_stack(layers, rows, n_rep, m):
+    def make_stack(layers, rows, n_rep, m, mixed=False):
         def make(key):
             kq, kk, kv, kl, kn = jax.random.split(key, 5)
             # the cursors; the last row parked (it has no slot: the kernel
             # and the writer drop its token)
             index = jax.random.randint(kl, (rows,), 0, m,
                                        jnp.int32).at[-1].set(m)
+            if mixed:
+                # the FIRST row group of a grid step (`decode_plan`): a
+                # block's last slot, the next block's first, a parked row
+                # and a single token side by side; then the cache's last slot
+                blk_k = decode_plan(rows, hkv, m, d, 2)[1]
+                index = index.at[:5].set(jnp.asarray(
+                    [blk_k - 1, blk_k, m, 0, m - 1], jnp.int32)[:rows])
             return (normal(kq, (rows, 1, hkv * n_rep, d)),
                     normal(kk, (layers, rows, hkv, m, d)),
                     normal(kv, (layers, rows, hkv, m, d)), index,
@@ -304,6 +312,10 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             jnp.moveaxis(n, 1, 0), mode="drop") for x, n in ((k, new[0]),
                                                              (v, new[1])))
 
+    def stack_staged(q, k, v, index, new, layer):
+        return decode_attention(q, k, v, index + 1, layer=layer,
+                                k_new=new[0, layer], v_new=new[1, layer])
+
     for layers, rows, n_rep, m in sz.dense_stack:
         shape = f"l{layers}_b{rows}_r{n_rep}_m{m}"
         cases += [
@@ -311,12 +323,12 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                        lambda q, k, v, index, new, layer: decode_attention(
                            q, k, v, index + 1, layer=layer),
                        stack_ref, make_stack(layers, rows, n_rep, m)),
-            KernelCase(f"decode_stacked_staged_{shape}",
-                       lambda q, k, v, index, new, layer: decode_attention(
-                           q, k, v, index + 1, layer=layer,
-                           k_new=new[0, layer], v_new=new[1, layer]),
+            KernelCase(f"decode_stacked_staged_{shape}", stack_staged,
                        lambda *a: stack_ref(*a, staged=True),
                        make_stack(layers, rows, n_rep, m)),
+            KernelCase(f"decode_stacked_staged_mixed_{shape}", stack_staged,
+                       lambda *a: stack_ref(*a, staged=True),
+                       make_stack(layers, rows, n_rep, m, mixed=True)),
             KernelCase(f"kv_write_dense_{shape}",
                        lambda q, k, v, index, new, layer: kv_write_dense(
                            k, v, new[0], new[1], index),

@@ -380,11 +380,13 @@ class InferenceEngine:
         dt = _time.perf_counter() - t0
         self.last_decode_tok_s = (b * new_tokens / dt) if dt > 0 else None
         hub = get_hub()
-        kv = self._kv_telemetry(b, key[1], key[2])
+        kv = self._kv_telemetry(b, key[1], key[2], token_loop=True)
         # gauges and counters update on a disabled hub too (hub.py): what a
         # benchmark reads of a call without the JSONL stream
         for name in ("kv_bytes", "state_bytes", "window_kv_bytes",
-                     "shared_kv_bytes", "latent_kv_bytes"):
+                     "shared_kv_bytes", "latent_kv_bytes",
+                     "dense_kv_slots_live", "dense_kv_slots_fetched",
+                     "dense_decode_grid_steps"):
             if name in kv:
                 hub.gauge(f"serving_v1/{name}", kv[name])
         for name, value in counted.items():
@@ -411,12 +413,14 @@ class InferenceEngine:
                      **kv, **counted, **extra)
         return out
 
-    def _kv_telemetry(self, b, s, new_tokens):
+    def _kv_telemetry(self, b, s, new_tokens, token_loop=False):
         """kv_dtype + kv_bytes for the serving event (docs/telemetry.md) —
         pure host arithmetic over the program shapes, zero device fetches.
         kv_dtype is the EFFECTIVE at-rest element type: 'int8' only when
         the config asks for it AND this serve mode quantizes its cache
-        (the layer-streamed modes keep dense KV, engine __init__ warns)."""
+        (the layer-streamed modes keep dense KV, engine __init__ warns).
+        `token_loop`: the call is `_build_generate`'s one-token-a-step scan
+        (not a speculative round), whose dense decode kernel is counted."""
         from deepspeed_tpu.inference.capacity_scan import (
             kv_bytes_by_kind, kv_cache_bytes, recurrent_state_bytes,
             round_up_len)
@@ -432,12 +436,51 @@ class InferenceEngine:
         # K and V of the ATTENTION layers, and by kind where the model keeps
         # more than one (rings, a shared slab, latent rows); what recurrent
         # layers hold is counted apart (0 for a model that has none)
+        kinds = kv_bytes_by_kind(self.model_cfg, int(b), max_len,
+                                 self._config.dtype)
+        dense = {}
+        if token_loop and mode == "dequant" and eff is None and not kinds \
+                and getattr(self.module, "make_cache", None) is not None:
+            dense = self._dense_decode_traffic(int(b), int(s),
+                                               int(new_tokens), max_len)
         return {"kv_dtype": eff or jnp.dtype(self._config.dtype).name,
-                "kv_bytes": int(kv_b),
-                **kv_bytes_by_kind(self.model_cfg, int(b), max_len,
-                                   self._config.dtype),
+                "kv_bytes": int(kv_b), **kinds,
                 "state_bytes": recurrent_state_bytes(
-                    self.model_cfg, int(b), self._config.dtype)}
+                    self.model_cfg, int(b), self._config.dtype),
+                **dense}
+
+    def _dense_decode_traffic(self, b, s, new_tokens, max_len):
+        """What the dense decode kernel fetches and the grid steps it takes
+        over a call's decode steps and attention layers, from the kernel's
+        own plan (`decode_attention.decode_plan`; host arithmetic, nothing
+        fetched): `dense_kv_slots_live` against `dense_kv_slots_fetched` (a
+        slot is one token's place in one row of one layer) and
+        `dense_decode_grid_steps`. For a model whose cache is the STACKED
+        dense one (its `make_cache`; one kind of K and V), and only where a
+        single-token step dispatches the kernel (`dense_decode_route`): {}
+        otherwise. Decode step t of `new_tokens - 1` attends `s + t + 1`
+        tokens a row, the step's own staged one among them."""
+        from deepspeed_tpu.ops.attention import dense_decode_route
+        from deepspeed_tpu.ops.pallas.decode_attention import (decode_plan,
+                                                               plan_traffic)
+        cfg = self.model_cfg
+        layers, hkv, d = _cache_dims(cfg)
+        kernel, mesh = dense_decode_route(
+            getattr(cfg, "attn_impl", "auto"),
+            getattr(cfg, "sliding_window", None),
+            int(cfg.num_attention_heads), hkv)
+        if not kernel or new_tokens < 2:
+            return {}
+        if mesh is not None:   # each shard's kernel holds its own KV heads
+            hkv //= mesh.shape["model"]
+        plan = decode_plan(b, hkv, max_len, d,
+                           jnp.dtype(self._config.dtype).itemsize)
+        lengths = np.broadcast_to(
+            s + 1 + np.arange(new_tokens - 1)[:, None], (new_tokens - 1, b))
+        live, fetched, steps = plan_traffic(plan, lengths, max_len)
+        return {"dense_kv_slots_live": layers * live,
+                "dense_kv_slots_fetched": layers * fetched,
+                "dense_decode_grid_steps": layers * steps}
 
     def _register_serving_residency(self, key):
         """MemoryPlane rows for one generate key — the KV cache is created

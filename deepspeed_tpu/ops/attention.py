@@ -442,7 +442,6 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
         # (the masked-XLA fallback); kernels fold the scales in-register
         return dequantize_kv(layer.data, layer.scales, q.dtype)
 
-    n_rep = q.shape[2] // k_cache.shape[2]
     if alibi is not None:
         if quant:
             return reference_attention(q, _dense_view(k_cache),
@@ -450,24 +449,23 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
                                        segment_mask=mask, alibi=alibi)
         return reference_attention(q, k_cache, v_cache, causal=False,
                                    segment_mask=mask, alibi=alibi)
-    if _decode_kernel_wanted(impl, window, n_rep) and q.shape[1] == 1:
-        mesh, tp_fallback = _decode_tp_mesh(
-            q.shape[2], k_cache.shape[2], "decode_attention")
-        if not tp_fallback:
-            _assert_prefix_mask(mask, index, k_cache.shape[1])
-            kd = k_cache.data if quant else k_cache
-            vd = v_cache.data if quant else v_cache
-            ks = k_cache.scales if quant else None
-            vs = v_cache.scales if quant else None
-            if mesh is not None:
-                from deepspeed_tpu.ops.pallas.sharded import (
-                    sharded_decode_attention)
-                return sharded_decode_attention(q, kd, vd, index + 1, mesh,
-                                                k_scales=ks, v_scales=vs)
-            from deepspeed_tpu.ops.pallas.decode_attention import (
-                decode_attention)
-            return decode_attention(q, kd, vd, index + 1,
-                                    k_scales=ks, v_scales=vs)
+    kernel, mesh = dense_decode_route(impl, window, q.shape[2],
+                                      k_cache.shape[2], q.shape[1])
+    if kernel:
+        _assert_prefix_mask(mask, index, k_cache.shape[1])
+        kd = k_cache.data if quant else k_cache
+        vd = v_cache.data if quant else v_cache
+        ks = k_cache.scales if quant else None
+        vs = v_cache.scales if quant else None
+        if mesh is not None:
+            from deepspeed_tpu.ops.pallas.sharded import (
+                sharded_decode_attention)
+            return sharded_decode_attention(q, kd, vd, index + 1, mesh,
+                                            k_scales=ks, v_scales=vs)
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            decode_attention)
+        return decode_attention(q, kd, vd, index + 1,
+                                k_scales=ks, v_scales=vs)
     if quant:
         return reference_attention(q, _dense_view(k_cache),
                                    _dense_view(v_cache), causal=False,
@@ -497,6 +495,17 @@ def _decode_kernel_wanted(impl: str, window, n_rep: int) -> bool:
         or (impl == "auto" and n_rep >= thresh))
 
 
+def dense_decode_route(impl: str, window, h: int, hkv: int, tokens: int = 1):
+    """(whether a call of `tokens` new tokens a row over a dense cache runs
+    the Pallas decode kernel here, the mesh to shard it over or None): the
+    dispatch rule and the mesh routing in one answer, for both views of the
+    cache and for the v1 engine's count of what the kernel fetches."""
+    if not (_decode_kernel_wanted(impl, window, h // hkv) and tokens == 1):
+        return False, None
+    mesh, tp_fallback = _decode_tp_mesh(h, hkv, "decode_attention")
+    return not tp_fallback, mesh
+
+
 def _stacked_dense_attention(q, k_cache, v_cache, index, mask, impl, window,
                              alibi):
     """`cached_attention` over `DenseLayer` views: layer `k_cache.layer` of
@@ -509,22 +518,20 @@ def _stacked_dense_attention(q, k_cache, v_cache, index, mask, impl, window,
     hkv, m = k_cache.stack.shape[2], k_cache.stack.shape[3]
     n_rep = h // hkv
     staged = k_cache.stage is not None
-    if _decode_kernel_wanted(impl, window, n_rep) and s == 1 \
-            and alibi is None:
-        mesh, tp_fallback = _decode_tp_mesh(h, hkv, "decode_attention")
-        if not tp_fallback:
-            _assert_prefix_mask(mask, index, m)
-            kw = dict(layer=k_cache.layer, k_new=k_cache.stage,
-                      v_new=v_cache.stage)
-            if mesh is not None:
-                from deepspeed_tpu.ops.pallas.sharded import (
-                    sharded_decode_attention)
-                return sharded_decode_attention(
-                    q, k_cache.stack, v_cache.stack, index + 1, mesh, **kw)
-            from deepspeed_tpu.ops.pallas.decode_attention import (
-                decode_attention)
-            return decode_attention(q, k_cache.stack, v_cache.stack,
-                                    index + 1, **kw)
+    kernel, mesh = dense_decode_route(impl, window, h, hkv, s)
+    if kernel and alibi is None:
+        _assert_prefix_mask(mask, index, m)
+        kw = dict(layer=k_cache.layer, k_new=k_cache.stage,
+                  v_new=v_cache.stage)
+        if mesh is not None:
+            from deepspeed_tpu.ops.pallas.sharded import (
+                sharded_decode_attention)
+            return sharded_decode_attention(
+                q, k_cache.stack, v_cache.stack, index + 1, mesh, **kw)
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            decode_attention)
+        return decode_attention(q, k_cache.stack, v_cache.stack,
+                                index + 1, **kw)
     k, v = (jax.lax.dynamic_index_in_dim(c.stack, c.layer, 0, keepdims=False)
             for c in (k_cache, v_cache))                     # (B, Hkv, M, D)
     if staged:  # the staged token overlays its row's cursor slot

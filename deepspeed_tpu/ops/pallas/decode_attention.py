@@ -9,17 +9,26 @@ so Pallas elides their HBM copies) — decode is KV-bandwidth-bound, so a
 200-token sequence in a 4096-slot cache reads 1/20th of the bytes the
 masked XLA path touches.
 
-HEAD-PACKED tiles: the grid is (B, Hkv, M/blk) and every step processes the
-whole GQA group — the n_rep = H/Hkv query heads that share one KV head ride
-one (n_rep, D) tile against the (blk_k, D) KV block, so a llama3-style
-8-way group turns the former (1, D)·(blk_k, D) sliver into an MXU-shaped
-(8, D)·(blk_k, D) matmul and cuts grid steps 8×. MHA degenerates to
-n_rep=1 (the old layout).
+WHOLE-GROUP steps (PR 49): the grid is (B / rb, M / blk_k) and a step carries
+EVERY KV head of a group of `rb` rows over one block of slots: the stack is
+(L, B, Hkv, M, D), a row's heads and a group's rows lie side by side, so one
+block (rb, Hkv, blk_k, D) covers them. Inside the step each (row, head) pair
+runs its own online softmax on its own float32 state, the n_rep = H/Hkv
+query heads that share the KV head as one (n_rep, D) tile against the
+(blk_k, D) KV tile (MHA degenerates to n_rep=1), each row masked at ITS OWN
+length. The group fetches blocks up to its longest row's last live one.
+Before PR 49 a step was one row's one KV head's one block, and a decode step
+of a 36-layer model at 32 rows paid 3,072 steps of 0.35 us each, half the
+kernel's time. What is left is a pair's update of a block, about 0.13 us +
+0.57 ns a slot, beside 0.63 ns a slot of bytes: blocks are long
+(`MAX_BLOCK_K`), and the sizes come from the shapes (`decode_plan`: one VMEM
+budget for the double-buffered K and V tiles, at least `MIN_STEPS` steps a
+call so that the first fetch, which nothing hides, stays a small share).
 
 Layout: q (B, 1, H, D). The cache is the STACKED dense cache as it lies at
 rest, (L, B, Hkv, M, D) (`inference/kv_cache.py:DenseLayer`), with the layer
 to read as a second scalar-prefetch operand: a block is fetched from
-`(layer, b, g, j)` of the stack, and no program cuts a layer out of it or
+`(layer, group, :, j)` of the stack, and no program cuts a layer out of it or
 re-lays it first. One layer's own (B, M, Hkv, D) array, the per-layer view,
 is re-laid here and goes in as a stack of one. KV-block axis sequential,
 online-softmax state in VMEM scratch.
@@ -32,53 +41,111 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _interpret
 from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
 
-DEFAULT_BLOCK_K = 512
-# the stacked cache's: a grid step costs about what 0.3 MB of K and V cost to
-# fetch (0.35 us on v5e), so at 512 slots a step a short cache's kernel is
-# mostly grid steps (PERF.md, PR 42: 36 layers' calls at 32 rows and M 1280
-# take 5.06 ms in blocks of 320 slots and 3.31 ms in blocks of 640)
-STACK_BLOCK_K = 1024
+# VMEM for the double-buffered K and V tiles of one grid step (two tiles, two
+# buffers each), of the 16 MB a kernel may use by default; the plan below
+# sizes a step's work under it
+KV_TILE_BUDGET = 8 * 1024 * 1024
+# KV slots a block at most. Each (row, head) pair's online-softmax update of
+# a block costs about 0.13 us whatever the block holds, beside 0.57 ns a slot
+# (PERF.md, PR 49: 36 layers' calls at 32 rows and M 1280 take 4.16 ms in
+# blocks of 256 slots and 2.31 in blocks of 640), so a block is as long as
+# the cache allows up to this
+MAX_BLOCK_K = 1024
+# grid steps a call at least, where the batch allows: the first step's fetch
+# waits for nothing to compute, so a call of S steps exposes 1 / S of its
+# bytes (PERF.md, PR 49: at M 512, one block a row, 32 rows take 1.15 ms in
+# four steps of 8 rows and 1.08 in eight of 4)
+MIN_STEPS = 8
 
 
-def _decode_kernel(lengths_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, blk_k, nk, n_rep,
-                   ks_ref=None, vs_ref=None, kn_ref=None, vn_ref=None):
+def _divisors_desc(n: int, cap: int):
+    return (x for x in range(max(min(n, cap), 1), 0, -1) if n % x == 0)
+
+
+def decode_plan(b: int, hkv: int, m: int, d: int, itemsize: int,
+                block_k: Optional[int] = None) -> Tuple[int, int]:
+    """(rows a grid step, KV slots a block) of `decode_attention` at these
+    shapes: the kernel's ONE sizing rule, and what the engine's telemetry
+    asks for the steps and slots a call costs (`plan_traffic`).
+
+    A grid step carries every KV head of `rb` rows over `blk_k` slots. Its
+    K and V tiles, double-buffered, stay under `KV_TILE_BUDGET`. `blk_k`
+    divides M, at most `MAX_BLOCK_K` (or the caller's `block_k`) and what the
+    budget leaves one row, in whole lane tiles of 128 where M has such a
+    divisor; `rb` is the largest divisor of B that the budget and
+    `MIN_STEPS` leave, 1 at worst."""
+    per_slot = 4 * hkv * d * itemsize     # one row's one slot in the tiles
+    cap = min(block_k or MAX_BLOCK_K, KV_TILE_BUDGET // per_slot)
+    blk_k = next((x for x in _divisors_desc(m, cap) if x % 128 == 0),
+                 next(_divisors_desc(m, cap)))
+    rb = next(_divisors_desc(b, min(KV_TILE_BUDGET // (per_slot * blk_k),
+                                    b * (m // blk_k) // MIN_STEPS)))
+    return rb, blk_k
+
+
+def plan_traffic(plan: Tuple[int, int], lengths, m: int):
+    """(slots that hold tokens, slots fetched, grid steps) of the calls at
+    `lengths` (..., B), one call a leading index, summed, on the host: a
+    slot is one token's place in one row, all heads, K and V. A group of
+    `rb` rows fetches whole blocks up to its longest row's last live one (at
+    least one: a group of empty rows still fetches its first block), and
+    every row of the group with them."""
+    rb, blk_k = plan
+    lengths = np.minimum(np.asarray(lengths, np.int64), m)
+    longest = lengths.reshape(lengths.shape[:-1] + (-1, rb)).max(axis=-1)
+    blocks = np.maximum(-(-longest // blk_k), 1)
+    return (int(lengths.sum()), int(blocks.sum()) * blk_k * rb,
+            longest.size * (m // blk_k))
+
+
+def _decode_kernel(lengths_ref, layer_ref, q_ref, k_ref, v_ref, *rest, scale,
+                   blk_k, nk, rb, hkv, n_rep, quantized, staged):
+    """One grid step: `rb` rows' every KV head over one block of slots.
+    Refs after the caches, in args order: the int8 scales, the staged pair,
+    the output, then the online-softmax state m / l / acc, one
+    (n_rep, .) tile a (row, head)."""
     del layer_ref  # the index maps read it
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    rest = list(rest)
+    ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quantized else (None, None)
+    kn_ref, vn_ref = (rest.pop(0), rest.pop(0)) if staged else (None, None)
+    o_ref, m_scr, l_scr, acc_scr = rest
+    i = pl.program_id(0)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = lengths_ref[b]
-
-    @pl.when(j * blk_k < length)  # skip fully-invalid blocks
-    def _compute():
-        q = q_ref[0]                         # (n_rep, D) — the GQA group
-        k = k_ref[...]                       # (blk_k, D)
-        v = v_ref[...]
-        if kn_ref is not None:
+    def attend(t, cols, slots):
+        """The online softmax of pair t, row `t // hkv`'s head `t % hkv`,
+        over this block, whose slots' numbers are `cols` (n_rep, blk_k) and
+        `slots` (blk_k, 1)."""
+        r, g = t // hkv, t % hkv
+        length = lengths_ref[i * rb + r]     # each row at its OWN length
+        valid, hit = cols < length, slots == length - 1
+        q = q_ref[t]                         # (n_rep, D) — the GQA group
+        k = k_ref[r, g]                      # (blk_k, D)
+        v = v_ref[r, g]
+        if staged:
             # staged token (kv_cache.DenseLayer.stage): the row's NEW key
             # and value are not in the stack yet; they take their slot's
             # place in the tile, so the arithmetic is the written token's
-            slot = jax.lax.broadcasted_iota(jnp.int32, (blk_k, 1), 0)
-            hit = slot == length - 1 - j * blk_k
-            k = jnp.where(hit, kn_ref[...], k)
-            v = jnp.where(hit, vn_ref[...], v)
-        if ks_ref is not None:
+            k = jnp.where(hit, kn_ref[t], k)
+            v = jnp.where(hit, vn_ref[t], v)
+        if quantized:
             # int8 cache: fold the per-token K scale into the LOGIT columns
             # (token scales ride the lane axis, matching the logits' key
             # axis — the r6 scale-into-activation trick)
@@ -86,50 +153,57 @@ def _decode_kernel(lengths_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                 q.astype(jnp.float32), k.astype(jnp.float32),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            s = s * ks_ref[0][None, :] * scale
+            s = s * ks_ref[t] * scale
         else:
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-        cols = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (n_rep, blk_k), 1)
-        s = jnp.where(cols < length, s, NEG_INF)
-        m_prev = m_scr[:, :1]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[t][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        # a row with no valid slot yet has m = NEG_INF, where exp(s - m) is
+        # 1 in every masked column: those are zeros, not probabilities
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if vs_ref is not None:
+        l_scr[t, :, :1] = l_scr[t][:, :1] * alpha \
+            + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
             # per-token V scale folds into the PROBABILITY columns
             pv = jax.lax.dot_general(
-                p * vs_ref[0][None, :], v.astype(jnp.float32),
+                p * vs_ref[t], v.astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         else:
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:, :1] = m_new
+        acc_scr[t] = acc_scr[t] * alpha + pv
+        m_scr[t, :, :1] = m_new
+
+    longest = functools.reduce(jnp.maximum,
+                               [lengths_ref[i * rb + r] for r in range(rb)])
+
+    # ONE branch a step, over the group: the pairs' updates are independent
+    # and the scheduler may interleave them (a branch a row kept each row's
+    # chain of two products and a softmax to itself: 4-8% slower, PERF.md,
+    # PR 49). A row the block lies wholly past is masked to a no-op:
+    # m and alpha stay, p is zero, l and acc are added nothing.
+    @pl.when(j * blk_k < longest)
+    def _group():
+        cols = j * blk_k + jax.lax.broadcasted_iota(
+            jnp.int32, (n_rep, blk_k), 1)
+        slots = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_k, 1), 0)
+        # the pair's update is traced ONCE and unrolled where it is lowered
+        # (straight-line code, constant indices): a Python loop traced it
+        # rb x Hkv times, 2 s more set-up a program of unrolled layers
+        jax.lax.fori_loop(0, rb * hkv,
+                          lambda t, _: attend(t, cols, slots), None,
+                          unroll=True)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-
-
-def _mk_decode_kernel(quantized: bool, staged: bool):
-    """Fixed-arity wrapper for one (quantized, staged) variant: pallas
-    passes refs in args order (scales right after the caches, then the
-    staged pair, then out + scratch)."""
-    def wrapper(lengths_ref, layer_ref, q_ref, k_ref, v_ref, *rest, **kw):
-        extra = list(rest[:-4])
-        if quantized:
-            kw["ks_ref"], kw["vs_ref"] = extra.pop(0), extra.pop(0)
-        if staged:
-            kw["kn_ref"], kw["vn_ref"] = extra.pop(0), extra.pop(0)
-        _decode_kernel(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                       *rest[-4:], **kw)
-    return wrapper
+        l = l_scr[...][:, :, :1]
+        o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
 
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -149,8 +223,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     result is bit for bit the token written then attended (and a row with
     `lengths > M`, parked, has no slot: its token is dropped, as the writer
     drops it). Without them the new token's slot must already be written.
-    `block_k`: KV slots a grid step (`DEFAULT_BLOCK_K` for a per-layer
-    view, `STACK_BLOCK_K` for the stack). Returns (B, 1, H, D).
+    `block_k` caps the KV slots a block (`decode_plan`). Returns
+    (B, 1, H, D).
 
     `k_scales`/`v_scales` (B, M, Hkv) f32 mark an int8 cache (per-layer
     view only): the kernel folds the per-token scale into the logit /
@@ -159,8 +233,6 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     unquantized kernel on the same cache values."""
     b, s, h, d = q.shape
     assert s == 1, "decode kernel is single-query; use flash_attention for prefill"
-    if block_k is None:
-        block_k = DEFAULT_BLOCK_K if layer is None else STACK_BLOCK_K
     if layer is None:
         # the per-layer view: re-laid (a copy of the layer) as a stack of one
         k_cache = jnp.swapaxes(k_cache, 1, 2)[None]
@@ -169,73 +241,69 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     hkv, m = k_cache.shape[2], k_cache.shape[3]
     n_rep = h // hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
-    blk_k = min(block_k, m)
-    while m % blk_k:
-        blk_k -= 1
+    rb, blk_k = decode_plan(b, hkv, m, d, jnp.dtype(k_cache.dtype).itemsize,
+                            block_k)
     nk = m // blk_k
     staged = k_new is not None
+    quantized = k_scales is not None
+
+    def pairs(i, j, L, Ly):
+        return (i, 0, 0)
+
+    def kv_block(i, j, L):
+        # Clamp the block index to the last valid block of the group's
+        # LONGEST row: steps past it revisit the same block, so Pallas
+        # elides their HBM copies — THIS is where the bandwidth saving
+        # happens (the `pl.when` alone only skips compute, not the DMA).
+        longest = functools.reduce(jnp.maximum,
+                                   [L[i * rb + r] for r in range(rb)])
+        return jnp.minimum(j, jnp.maximum((longest + blk_k - 1) // blk_k - 1, 0))
+
+    def kv_index(i, j, L, Ly):
+        return (Ly[0], i, 0, kv_block(i, j, L), 0)
 
     # (B·Hkv, n_rep, D): row-major over heads means head g*n_rep+r of the
-    # HF layout is group g, member r — exactly repeat_kv's grouping
-    qt2 = jnp.swapaxes(q, 1, 2).reshape(b * hkv, n_rep, d)
-
-    def row(b_, g, j, L, Ly):
-        return (b_ * hkv + g, 0, 0)
-
-    def kv_block(b_, j, L):
-        # Clamp the block index to this row's last valid block: steps past
-        # the row's length revisit the same block, so Pallas elides their
-        # HBM copies — THIS is where the bandwidth saving happens (the
-        # `pl.when` alone only skips compute, not the DMA).
-        last = jnp.maximum((L[b_] + blk_k - 1) // blk_k - 1, 0)
-        return jnp.minimum(j, last)
-
-    def kv_index(b_, g, j, L, Ly):
-        return (Ly[0], b_, g, kv_block(b_, j, L), 0)
-
-    def kv_scale_index(b_, g, j, L, Ly):
-        return (b_ * hkv + g, 0, kv_block(b_, j, L))
-
-    in_specs = [
-        pl.BlockSpec((1, n_rep, d), row),
-        pl.BlockSpec((None, None, None, blk_k, d), kv_index),
-        pl.BlockSpec((None, None, None, blk_k, d), kv_index),
-    ]
+    # HF layout is group g, member r — exactly repeat_kv's grouping; a
+    # step's block is its rb·Hkv (row, head) pairs
+    def per_pair(*tail):
+        return pl.BlockSpec((rb * hkv,) + tail, pairs)
+    kv_spec = pl.BlockSpec((None, rb, hkv, blk_k, d), kv_index)
+    in_specs = [per_pair(n_rep, d), kv_spec, kv_spec]
     args = [lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-            qt2, k_cache, v_cache]
-    quantized = k_scales is not None
+            q.reshape(b * hkv, n_rep, d), k_cache, v_cache]
     if quantized:
         # (B, M, Hkv) → (B·Hkv, 1, M): token scales along lanes, one tile
-        # per KV block beside its pool tile (same block). The unit
+        # per KV block beside its cache tile (same block). The unit
         # sublane dim is there for Mosaic: a block's second-to-last dim
         # must be a multiple of 8 or span the array's, and one row of
         # (B·Hkv, M) is neither.
-        ks2 = jnp.swapaxes(k_scales, 1, 2).reshape(b * hkv, 1, m)
-        vs2 = jnp.swapaxes(v_scales, 1, 2).reshape(b * hkv, 1, m)
-        in_specs += [pl.BlockSpec((None, 1, blk_k), kv_scale_index),
-                     pl.BlockSpec((None, 1, blk_k), kv_scale_index)]
-        args += [ks2, vs2]
-    if staged:  # one (1, D) row a (b, g): the unit dim spans its array's
-        in_specs += [pl.BlockSpec((None, 1, d), row)] * 2
+        scale_spec = pl.BlockSpec(
+            (rb * hkv, 1, blk_k), lambda i, j, L, Ly: (i, 0, kv_block(i, j, L)))
+        in_specs += [scale_spec, scale_spec]
+        args += [jnp.swapaxes(k_scales, 1, 2).reshape(b * hkv, 1, m),
+                 jnp.swapaxes(v_scales, 1, 2).reshape(b * hkv, 1, m)]
+    if staged:  # one (1, D) row a pair: the unit dim spans its array's
+        in_specs += [per_pair(1, d)] * 2
         args += [k_new.reshape(b * hkv, 1, d), v_new.reshape(b * hkv, 1, d)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, nk),
+        grid=(b // rb, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_rep, d), row),
-        scratch_shapes=[pltpu.VMEM((n_rep, 128), jnp.float32),
-                        pltpu.VMEM((n_rep, 128), jnp.float32),
-                        pltpu.VMEM((n_rep, d), jnp.float32)],
+        out_specs=per_pair(n_rep, d),
+        scratch_shapes=[pltpu.VMEM((rb * hkv, n_rep, 128), jnp.float32),
+                        pltpu.VMEM((rb * hkv, n_rep, 128), jnp.float32),
+                        pltpu.VMEM((rb * hkv, n_rep, d), jnp.float32)],
     )
 
     out = pl.pallas_call(
-        functools.partial(_mk_decode_kernel(quantized, staged),
-                          scale=scale, blk_k=blk_k, nk=nk, n_rep=n_rep),
+        functools.partial(_decode_kernel, scale=scale, blk_k=blk_k, nk=nk,
+                          rb=rb, hkv=hkv, n_rep=n_rep, quantized=quantized,
+                          staged=staged),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, n_rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
         name="self_attn_dense_decode",
     )(*args)

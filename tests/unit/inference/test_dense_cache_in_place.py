@@ -305,6 +305,57 @@ def test_v1_generate_returns_the_per_layer_engines_tokens():
     np.testing.assert_array_equal(outs[0], outs[1])
 
 
+@pytest.mark.parametrize("cls,kernels,counted", [
+    (LlamaForCausalLM, True, True),
+    (LlamaForCausalLM, False, False),    # the XLA path: no kernel, no count
+    (PerLayerViewDense, True, False),    # a per-layer model: not the stack
+], ids=["stacked-kernel", "stacked-xla", "per-layer"])
+def test_serving_event_counts_what_the_decode_kernel_fetches(
+        monkeypatch, tmp_path, cls, kernels, counted):
+    """`dense_kv_slots_live` / `dense_kv_slots_fetched` /
+    `dense_decode_grid_steps` (PR 49): from the kernel's own plan, on the
+    host, only where the stacked dense cache meets the kernel."""
+    import json
+
+    import deepspeed_tpu.ops.attention as attention
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_plan
+    from deepspeed_tpu.telemetry import TelemetryHub, get_hub, set_hub
+    from deepspeed_tpu.utils import groups
+    monkeypatch.setattr(attention, "_use_pallas", lambda: kernels)
+    cfg = _cfg(jnp.float32)
+    rows, prompt, new = 3, 9, 5
+    ids = np.asarray(np.random.default_rng(8).integers(1, cfg.vocab_size,
+                                                       (rows, prompt)))
+    path = tmp_path / "serving.jsonl"
+    set_hub(TelemetryHub(enabled=True, jsonl_path=str(path)))
+    try:
+        groups.reset_topology()
+        eng = deepspeed_tpu.init_inference(cls(cfg), params=_params(jnp.float32),
+                                           dtype="fp32")
+        eng.generate(ids, max_new_tokens=new)
+        event = [json.loads(l) for l in path.read_text().splitlines()
+                 if json.loads(l)["kind"] == "serving"][-1]
+        names = ("dense_kv_slots_live", "dense_kv_slots_fetched",
+                 "dense_decode_grid_steps")
+        if not counted:
+            assert not any(n in event for n in names)
+            assert not any(f"serving_v1/{n}" in get_hub().gauges for n in names)
+            return
+        layers, max_len = cfg.num_hidden_layers, 128   # 9 + 5, rounded up
+        rb, blk_k = decode_plan(rows, cfg.num_key_value_heads, max_len,
+                                cfg.head_dim, 4)
+        assert (rb, blk_k) == (1, 128)   # three rows: a group each
+        # four decode steps attend 10, 11, 12 and 13 tokens a row (the
+        # step's staged token among them), each inside the first block
+        assert event["dense_kv_slots_live"] == layers * rows * (10 + 11 + 12 + 13)
+        assert event["dense_kv_slots_fetched"] == layers * 4 * rows * blk_k
+        assert event["dense_decode_grid_steps"] == layers * 4 * rows * 1
+        for n in names:
+            assert get_hub().gauges[f"serving_v1/{n}"] == event[n]
+    finally:
+        set_hub(TelemetryHub(enabled=False))
+
+
 def test_an_int8_cache_keeps_the_per_layer_view():
     model = LlamaForCausalLM(_cfg(jnp.float32))
     assert model.make_cache(2, 128).stacked
