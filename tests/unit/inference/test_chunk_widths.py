@@ -250,30 +250,52 @@ def test_counters_and_span_carry_the_width_that_ran(tiny, warmed, pending,
         warmed._flush_batch(list(feed) + uids)
 
 
+@pytest.fixture(scope="module")
+def either_path(tiny):
+    """`serve(kernels, pending, decoding)`: the mix served by the module's
+    engine on the XLA path or on the chip's path interpreted (each built and
+    traced with `_use_pallas` saying so, and kept: an engine compiles every
+    program in its first chunk round, the kernels-on one for a quarter of a
+    minute); the rounds, the engine, and what its counters counted over
+    them. `_serve` leaves an engine with nothing tracked."""
+    import deepspeed_tpu.ops.attention as attention
+    engines = {}
+
+    def serve(kernels, pending, decoding):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(attention, "_use_pallas", lambda: kernels)
+            if kernels not in engines:
+                engines[kernels] = _engine(tiny)
+            eng = engines[kernels]
+            before = dict(eng.serving_counters)
+            rounds = _serve(eng, tiny[0].vocab_size, pending, decoding,
+                            seed=31)
+        return rounds, eng, {k: v - before[k]
+                             for k, v in eng.serving_counters.items()}
+    return serve
+
+
 @pytest.mark.parametrize("pending,decoding", [(2, 1), (17, 1)],
                          ids=["narrow", "wide"])
 def test_rounds_of_mostly_parked_rows_give_the_same_tokens_with_the_kernels_on(
-        tiny, monkeypatch, pending, decoding):
+        either_path, pending, decoding):
     """Served rounds of `fused_batch` at each width, most rows parked in
     the round the prompts join in and the live ones several to a prompt,
     through the chip's path interpreted (both paged kernels, which skip
     the parked rows, and the Pallas writer, which writes one block from
     two rows) against the XLA path, which holds no kernel: the same
     tokens, round for round."""
-    import deepspeed_tpu.ops.attention as attention
     runs = []
     for kernels in (False, True):
-        monkeypatch.setattr(attention, "_use_pallas", lambda: kernels)
-        eng = _engine(tiny)
-        runs.append(_serve(eng, tiny[0].vocab_size, pending, decoding,
-                           seed=31))
+        rounds, eng, counted = either_path(kernels, pending, decoding)
+        runs.append(rounds)
         width = f"fused_batch:{CHUNK}:{width_for(pending, MAX_BATCH)}"
         assert width in eng.recompiles._seen
         # the joining round alone parks more rows than a program is wide,
         # and in it every prompt has two rows or three: live rows of one
         # round that share a slot, a block and a cursor's scatter
-        assert eng.serving_counters["rows_parked"] > MAX_BATCH
-        assert eng.serving_counters["rows_refilled"] >= min(pending, 3)
+        assert counted["rows_parked"] > MAX_BATCH
+        assert counted["rows_refilled"] >= min(pending, 3)
     xla, pallas = runs
     assert len(xla) == len(pallas)
     for a, b in zip(xla, pallas):

@@ -32,10 +32,15 @@ from deepspeed_tpu.inference.kv_cache import (DenseLayer, KVCache,
 from deepspeed_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                         materialize_params)
 from deepspeed_tpu.ops.attention import cached_attention
-from deepspeed_tpu.ops.pallas.decode_attention import (decode_attention,
-                                                       kv_write_dense)
+from deepspeed_tpu.ops.pallas import decode_attention as da
 
 L, B, HKV, M, D, BLK = 3, 5, 2, 32, 16, 8
+
+# Each kernel under ONE `jax.jit` for the module: a bare call compiles the
+# interpreted kernel anew every time, and the kernel tests below call one up
+# to seven times at one shape.
+decode_attention = jax.jit(da.decode_attention, static_argnames=("block_k",))
+kv_write_dense = jax.jit(da.kv_write_dense)
 
 
 def _stacks(rng, dtype=jnp.float32):

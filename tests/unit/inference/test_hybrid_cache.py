@@ -50,7 +50,8 @@ def test_generate_is_the_greedy_walk_of_the_plain_forward(hub):
     assert out.shape == (3, 17) and np.array_equal(out[:, :11], ids)
     # every generated token is the argmax of the uncached forward over its
     # own prefix, or ties with it to float32 rounding
-    logits = np.asarray(model.apply({"params": params}, jnp.asarray(out)))
+    logits = np.asarray(jax.jit(lambda p, ids: model.apply({"params": p}, ids))(
+        params, jnp.asarray(out)))         # one compile, not one an op
     for t in range(11, 17):
         row = logits[:, t - 1]
         assert np.all(row[np.arange(3), out[:, t]] >= row.max(-1) - 1e-5)

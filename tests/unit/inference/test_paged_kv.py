@@ -103,7 +103,7 @@ def test_state_manager_block_accounting():
     assert sm.block_allocator.free_blocks == 6
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def tiny():
     cfg = llama_config("llama-tiny", dtype=jnp.float32)
     model, params = materialize_params(cfg)

@@ -45,7 +45,13 @@ for step in range(start, 4):
     losses.append(float(engine.train_batch(batch=local)))
     engine.save_checkpoint(ckpt)
     if step == 1 and gen == 0 and rank == 1:
-        sys.exit(17)  # simulated hardware failure AFTER step 2's checkpoint
+        # simulated hardware failure AFTER step 2's checkpoint. A failed
+        # host runs no exit handlers, so os._exit: a polite sys.exit goes
+        # through jax's exit hook into jax.distributed's shutdown barrier
+        # and waits its default 300 s for rank 0 (stuck in step 3's
+        # collective) before the agent can see an exit code
+        # (docs/resilience.md; the test took 330 s for it).
+        os._exit(17)
 
 with open(os.environ["DS_TEST_OUT"] + str(rank), "w") as f:
     f.write(f"{gen} {int(engine.state.global_step)} {losses[-1]:.8f}")

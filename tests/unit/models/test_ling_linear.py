@@ -1,12 +1,12 @@
 """Ling-linear (KDA 5 : 1 MLA over group-limited experts) against the float32
 reference (`perfbench/configs/ling_linear_reference.py`), at a small size on
-seeded weights, LOGITS not tokens: the plain forward; a prefill and then
-decoding through the caches (the latent rows, the matrix states); the
-chunked KDA form against the recurrence; the absorbed MLA form against the
-expanded one; the four EP4 shares against the uncut layer; and that a
-program which dropped a term of the mathematics would not pass."""
-
-import zlib
+seeded weights, LOGITS not tokens: the plain forward, the loss, and a prefill
+and then decoding through the caches (the latent rows, the matrix states):
+the questions all three hybrid families are asked, whose bodies are
+`hybrid_families.py`'s; and this family's own: the chunked KDA form against
+the recurrence; the absorbed MLA form against the expanded one; the four EP4
+shares against the uncut layer; and that a program which dropped a term of
+the mathematics would not pass."""
 
 import jax
 import jax.numpy as jnp
@@ -14,72 +14,21 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models import ling_linear
-from deepspeed_tpu.models.ling_linear import (LingLinearConfig, kda_chunked,
-                                              materialize_params)
+from deepspeed_tpu.models.ling_linear import LingLinearConfig, kda_chunked
 from deepspeed_tpu.ops.pallas.kda import kda_step
-from perfbench.manifest import Manifest
+from tests.unit.models import hybrid_families
+from tests.unit.models.hybrid_families import (LING_CFG as CFG,
+                                               LING_SIZES as SIZES,
+                                               LING_TOL as TOL,
+                                               compile_apply, family, walk)
 
-SIZES = dict(vocab_size=128, hidden_size=64, num_hidden_layers=6,
-             intermediate_size=96, first_k_dense_replace=1, layer_group_size=6,
-             published_layers=(0, 2, 3, 4, 5, 6), num_attention_heads=4,
-             head_dim=16, short_conv_kernel_size=4, kda_lower_bound=-5.0,
-             kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
-             v_head_dim=16, rope_theta=6e6, num_experts=8, router_experts=16,
-             expert_offset=0, num_experts_per_tok=4, moe_intermediate_size=32,
-             moe_shared_expert_intermediate_size=32, routed_scaling_factor=2.5,
-             norm_topk_prob=True, n_group=4, topk_group=2, rms_norm_eps=1e-6)
-CFG = LingLinearConfig(**SIZES, dtype=jnp.float32)
-REF = Manifest().module("configs", "ling_linear_reference")
-ROWS, LENGTH = 3, 50
-# float32 both sides: the orders of summation differ (a chunked solve against
-# a recurrence, absorbed against expanded products), nothing else; read 6e-7
-TOL = 5e-6
-
-
-def moved(params):
-    """The seeded tree with its small parameters moved off their initial
-    values (norm weights 1, a selection bias of 0.01): a term the program
-    dropped would otherwise not show."""
-    def bump(path, x):
-        key = jax.random.fold_in(jax.random.PRNGKey(7), zlib.crc32(
-            jax.tree_util.keystr(path).encode()) % 2 ** 31)
-        small = x.size < 3000 and "A_log" not in jax.tree_util.keystr(path)
-        return x + 0.1 * jax.random.normal(key, x.shape, x.dtype) if small \
-            else x
-    return jax.tree_util.tree_map_with_path(bump, params)
-
-
-def full_logits(params, ids, sizes=SIZES):
-    return np.asarray(REF.logits_at(params, ids, list(range(ids.shape[1])),
-                                    sizes))
+ROWS = 3
 
 
 @pytest.fixture(scope="module")
 def served():
-    model, params = materialize_params(CFG, jax.random.PRNGKey(0))
-    params = moved(params)
-    ids = jax.random.randint(jax.random.PRNGKey(1), (ROWS, LENGTH), 1, 128)
-    return model, params, ids, full_logits(params, ids)
-
-
-def through_the_caches(model, params, ids, prompt, round_state=False):
-    """(ROWS, LENGTH - prompt + 1, vocab): the prefill's last logits, then a
-    decode step a position, teacher-forced."""
-    cache = model.make_cache(ids.shape[0], 128, dtype=jnp.float32)
-    logits, cache = model.apply({"params": params}, ids[:, :prompt],
-                                cache=cache)
-    assert logits.shape == (ids.shape[0], 1, 128)   # the last position alone
-    step = jax.jit(lambda tok, cache: model.apply({"params": params}, tok,
-                                                  cache=cache))
-    out = [logits[:, 0]]
-    for t in range(prompt, ids.shape[1]):
-        if round_state:
-            cache = cache.replace(state=cache.state.replace(
-                ssm=jax.lax.reduce_precision(cache.state.ssm, 8, 7)))
-        logits, cache = step(ids[:, t:t + 1], cache)
-        out.append(logits[:, 0])
-    assert np.array_equal(np.asarray(cache.index), [ids.shape[1]] * ids.shape[0])
-    return np.stack([np.asarray(x) for x in out], axis=1)
+    fam = family("ling_linear")
+    return fam.model, fam.params, fam.ids, fam.want
 
 
 def test_layer_kinds_of_the_published_depth_and_of_the_cut():
@@ -96,36 +45,28 @@ def test_layer_kinds_of_the_published_depth_and_of_the_cut():
         LingLinearConfig(**{**SIZES, "num_experts": 6})
 
 
-def test_the_plain_forward_is_the_reference_s(served):
-    model, params, ids, want = served
-    got = model.apply({"params": params}, ids)
-    np.testing.assert_allclose(np.asarray(got), want, atol=TOL)
+def test_the_plain_forward_is_the_reference_s():
+    hybrid_families.the_plain_forward_is_the_reference_s("ling_linear")
 
 
-def test_the_loss_is_the_reference_s(served):
-    model, params, ids, _ = served
-    loss = ling_linear.ling_linear_loss_fn(model)(params, {"input_ids": ids},
-                                                  None)
-    assert float(loss) == pytest.approx(
-        float(REF.mean_loss(params, ids, SIZES)), rel=1e-5)
+def test_the_loss_is_the_reference_s():
+    hybrid_families.the_loss_is_the_reference_s("ling_linear")
 
 
 # a prompt shorter than the convolution, one that is no multiple of the KDA
 # chunk (32), one that is
 @pytest.mark.parametrize("prompt", [2, 23, 32])
-def test_prefill_then_decode_through_the_caches(served, prompt):
-    model, params, ids, want = served
-    got = through_the_caches(model, params, ids, prompt)
-    np.testing.assert_allclose(got, want[:, prompt - 1:], atol=TOL)
+def test_prefill_then_decode_through_the_caches(prompt):
+    hybrid_families.prefill_then_decode_is_the_reference_s("ling_linear",
+                                                           prompt)
 
 
 def test_a_prefill_a_few_rows_at_a_time_is_the_same(served, monkeypatch):
     model, params, ids, want = served
     ids = jnp.concatenate([ids, ids[:1]])           # 4 rows, groups of 2
     monkeypatch.setattr(ling_linear, "PREFILL_TOKENS", 2 * 23)
-    (logits, cache), counted = model.apply(
-        {"params": params}, ids[:, :23], mutable=["counters"],
-        cache=model.make_cache(4, 128, dtype=jnp.float32))
+    (logits, cache), counted = compile_apply(mutable=["counters"])(
+        model, params, ids[:, :23], model.make_cache(4, 128, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(logits[:3, 0]), want[:, 22],
                                atol=TOL)
     sums = {name: sum(int(jnp.sum(v)) for path, v in
@@ -137,7 +78,7 @@ def test_a_prefill_a_few_rows_at_a_time_is_the_same(served, monkeypatch):
     assert 0 < sums["held_assignments"] < sums["assignments"]
     assert sums["experts_held"] == 5 * 2 * 8
     assert 0 < sums["experts_touched"] <= sums["experts_held"]
-    logits, _ = model.apply({"params": params}, ids[:, 23:24], cache=cache)
+    logits, _ = compile_apply()(model, params, ids[:, 23:24], cache)
     np.testing.assert_allclose(np.asarray(logits[:3, 0]), want[:, 23],
                                atol=TOL)
 
@@ -161,7 +102,8 @@ def test_a_program_without_a_term_would_not_pass(served, term):
     without = jax.tree_util.tree_map_with_path(
         lambda path, x: jnp.zeros_like(x) if zero and jax.tree_util.keystr(
             path).endswith(zero) else x, params)
-    other = full_logits(without, ids, {**SIZES, **sizes})
+    other = family("ling_linear").reference_logits(without, ids,
+                                                   {**SIZES, **sizes})
     assert np.abs(other - want).max() > 20 * TOL
 
 
@@ -170,7 +112,7 @@ def test_the_state_is_kept_in_float32_between_tokens(served):
     bfloat16 between steps the walk through the caches misses the reference
     by a thousand times the tolerance (read: 2e-3 against 6e-7)."""
     model, params, ids, want = served
-    rounded = through_the_caches(model, params, ids, 5, round_state=True)
+    rounded, _ = walk(model, params, ids, 5, 128, state_bits=7)
     assert np.abs(rounded - want[:, 4:]).max() > 100 * TOL
 
 
@@ -229,9 +171,11 @@ def test_absorbed_mla_is_the_expanded_form(served):
     latent = LatentCache.create(1, ROWS, 32, CFG.latent_width, jnp.float32)
     got, latent = mixer.apply({"params": p}, x[:, :9], latent, 0)
     np.testing.assert_allclose(got, want[:, :9], atol=1e-6)
+    step = jax.jit(lambda x_t, latent: mixer.apply({"params": p}, x_t,
+                                                   latent, 0))
     for t in range(9, 20):
         latent = latent.replace(index=jnp.full((ROWS,), t, jnp.int32))
-        got, row = mixer.apply({"params": p}, x[:, t:t + 1], latent, 0)
+        got, row = step(x[:, t:t + 1], latent)
         np.testing.assert_allclose(got[:, 0], want[:, t], atol=1e-6)
         assert row.shape == (ROWS, 40)
         latent = latent.land(row[None])
@@ -272,7 +216,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(served):
     np.testing.assert_allclose(routed + shared_once, want, atol=1e-5)
     # and the reference's layer, given the whole, says the same
     sizes = {**SIZES, "num_experts": 16, "router_experts": 16}
-    ref_out, _ = REF._experts(x, params, sizes)
+    ref_out, _ = family("ling_linear").reference._experts(x, params, sizes)
     np.testing.assert_allclose(ref_out, want, atol=1e-5)
 
 

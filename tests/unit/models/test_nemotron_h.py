@@ -7,6 +7,9 @@ against a dense sum): logits of magnitude 0.5 agree to about 1e-6, and
 `TOL` = 2e-5 relative to the largest logit leaves room for that and none for
 a dropped term: each mutation below moves the logits by 1e-3 or more, fifty
 times the tolerance, and has to.
+
+The plain forward and the prefill-then-decode walk are the questions all
+three hybrid families are asked: their bodies are `hybrid_families.py`'s.
 """
 
 import dataclasses
@@ -16,93 +19,49 @@ import jax.numpy as jnp
 import pytest
 
 from deepspeed_tpu.models import nemotron_h
-from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
-                                             materialize_params, ssd_chunked)
-from perfbench.manifest import Manifest
-
-TOL = 2e-5
-REF = Manifest().module("configs", "nemotron_h_reference")
-CFG = NemotronHConfig(
-    vocab_size=128, hidden_size=64, num_hidden_layers=6,
-    hybrid_override_pattern="ME*MEE", num_attention_heads=4,
-    num_key_value_heads=2, head_dim=16, mamba_num_heads=4, mamba_head_dim=8,
-    ssm_state_size=16, n_groups=2, chunk_size=8, n_routed_experts=4,
-    router_experts=8, expert_offset=2, num_experts_per_tok=3,
-    moe_intermediate_size=32, moe_shared_expert_intermediate_size=48,
-    dtype=jnp.float32, dispatch_impl="gmm")
-SIZES = {f.name: getattr(CFG, f.name) for f in dataclasses.fields(CFG)
-         if f.name != "dtype"}
-
-
-def rel(got, want):
-    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+from deepspeed_tpu.models.nemotron_h import ssd_chunked
+from tests.unit.models import hybrid_families
+from tests.unit.models.hybrid_families import (NEMOTRON_CFG as CFG,
+                                               NEMOTRON_TOL as TOL,
+                                               compile_apply, family, rel,
+                                               walk, walked)
 
 
 @pytest.fixture(scope="module")
 def seeded():
-    model, params = materialize_params(CFG, jax.random.PRNGKey(3))
-    # random init barely uses the recurrence (its output is a hundredth of
-    # the skip term's): slow the decay and raise dt, so that the state
-    # carries hundreds of positions and a fault in it shows in the logits
-    for i, kind in enumerate(CFG.hybrid_override_pattern):
-        if kind == "M":
-            layer = params["layers"][f"layer_{i}"]
-            layer["A_log"] = jnp.full_like(layer["A_log"], -4.0)
-            layer["dt_bias"] = jnp.full_like(layer["dt_bias"], 1.0)
-            layer["D"] = jnp.zeros_like(layer["D"])
-    ids = jax.random.randint(jax.random.PRNGKey(4), (4, 29), 0, CFG.vocab_size)
-    return model, params, ids
+    return family("nemotron_h")
 
 
-def reference_logits(params, ids, sizes=SIZES):
-    h = REF.hidden_states(params, ids, sizes)
-    with jax.default_matmul_precision("highest"):
-        return h @ params["lm_head"]
-
-
-def served(model, params, ids, prompt, state_bits=None):
+def served(fam, prompt, model=None, params=None, **kw):
     """Logits of every position: a prefill of `prompt` positions, then one
-    decode step a position through the model's own cache."""
-    cache = model.make_cache(ids.shape[0], 64, dtype=jnp.float32)
-    out, cache = model.apply({"params": params}, ids[:, :prompt], cache=cache)
-    outs = [out]
-    for t in range(prompt, ids.shape[1]):
-        if state_bits is not None:   # what a lower-precision state would keep
-            cache = cache.replace(state=cache.state.replace(
-                ssm=jax.lax.reduce_precision(cache.state.ssm, 8, state_bits)))
-        out, cache = model.apply({"params": params}, ids[:, t:t + 1],
-                                 cache=cache)
-        outs.append(out)
-    assert int(cache.index[0]) == ids.shape[1]
-    return jnp.concatenate(outs, axis=1)
+    decode step a position through the model's own cache; of the family as
+    it stands, or of another `model` or other `params` on its prompts."""
+    return walk(model or fam.model, fam.params if params is None else params,
+                fam.ids, prompt, fam.cache_len, **kw)[0]
 
 
-def test_plain_forward_matches_the_reference(seeded):
-    model, params, ids = seeded
-    assert rel(model.apply({"params": params}, ids),
-               reference_logits(params, ids)) < TOL
+def test_plain_forward_matches_the_reference():
+    hybrid_families.the_plain_forward_is_the_reference_s("nemotron_h")
 
 
 @pytest.mark.parametrize("impl", ["gmm", "ragged"])
-def test_prefill_then_eight_decode_steps_match_the_full_forward(seeded, impl):
+def test_prefill_then_eight_decode_steps_match_the_full_forward(impl):
     """Logits, not tokens: a 21-token prefill (no multiple of the block of
     8), then 8 steps on the stored state, convolution tail and K/V."""
-    _, params, ids = seeded
-    model = nemotron_h.NemotronHForCausalLM(
-        dataclasses.replace(CFG, dispatch_impl=impl))
-    assert rel(served(model, params, ids, 21),
-               reference_logits(params, ids)) < TOL
+    hybrid_families.prefill_then_decode_is_the_reference_s("nemotron_h", 21,
+                                                           impl)
 
 
-def test_a_prefill_walked_a_few_rows_at_a_time_is_the_same(seeded, monkeypatch):
-    model, params, ids = seeded
-    whole = served(model, params, ids, 21)
+def test_a_prefill_walked_a_few_rows_at_a_time_is_the_same(seeded,
+                                                           monkeypatch):
+    fam, whole = seeded, walked("nemotron_h", 21)[0]
     monkeypatch.setattr(nemotron_h, "PREFILL_TOKENS", 2 * 21)   # 2 rows of 4
-    assert rel(served(model, params, ids, 21), whole) < 1e-6
+    by_rows = compile_apply()
+    assert rel(served(fam, 21, apply=by_rows), whole) < 1e-6
     # and a prefill continued from a cache that already holds 8 positions
-    cache = model.make_cache(4, 64, dtype=jnp.float32)
-    _, cache = model.apply({"params": params}, ids[:, :8], cache=cache)
-    out, _ = model.apply({"params": params}, ids[:, 8:21], cache=cache)
+    cache = fam.model.make_cache(4, 64, dtype=jnp.float32)
+    _, cache = by_rows(fam.model, fam.params, fam.ids[:, :8], cache)
+    out, _ = by_rows(fam.model, fam.params, fam.ids[:, 8:21], cache)
     assert rel(out, whole[:, 8:21]) < TOL
 
 
@@ -141,39 +100,39 @@ def _without_bias(params):
 
 def test_a_program_that_drops_a_term_fails(seeded, monkeypatch):
     """Each by at least 50 x `TOL`, against the reference as it stands."""
-    model, params, ids = seeded
-    want = reference_logits(params, ids)
+    fam, want = seeded, seeded.want
     # the selection bias (the program runs with a zero one)
-    assert rel(served(model, _without_bias(params), ids, 21), want) > 50 * TOL
+    assert rel(served(fam, 21, params=_without_bias(fam.params)),
+               want) > 50 * TOL
     # the routed scaling factor of 2.5
     flat = nemotron_h.NemotronHForCausalLM(
         dataclasses.replace(CFG, routed_scaling_factor=1.0))
-    assert rel(served(flat, params, ids, 21), want) > 50 * TOL
+    assert rel(served(fam, 21, model=flat), want) > 50 * TOL
     # rotary embedding in attention where the family applies none
     rotary = nemotron_h.NemotronHForCausalLM(
         dataclasses.replace(CFG, attention_rotary=True))
-    assert rel(served(rotary, params, ids, 21), want) > 50 * TOL
-    assert rel(served(rotary, params, ids, 21), reference_logits(
-        params, ids, {**SIZES, "attention_rotary": True})) < TOL
+    turned = served(fam, 21, model=rotary)
+    assert rel(turned, want) > 50 * TOL
+    assert rel(turned, fam.reference_logits(
+        fam.params, fam.ids, {**fam.sizes, "attention_rotary": True})) < TOL
     # the norm before the gate (the reference computed in the other order)
 
     def norm_then_gate(y, z, w, groups, eps):
         yg = y.reshape(y.shape[:-1] + (groups, y.shape[-1] // groups))
         yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + eps)
         return yg.reshape(y.shape) * w * jax.nn.silu(z)
-    monkeypatch.setattr(REF, "_gated_group_norm", norm_then_gate)
-    assert rel(served(model, params, ids, 21),
-               reference_logits(params, ids)) > 50 * TOL
+    monkeypatch.setattr(fam.reference, "_gated_group_norm", norm_then_gate)
+    assert rel(walked("nemotron_h", 21)[0],
+               fam.reference_logits(fam.params, fam.ids)) > 50 * TOL
 
 
 def test_a_bfloat16_state_fails(seeded):
     """The state kept to bf16's 8 bits between steps (float32 arithmetic
     inside a step, as a kernel would do it) over a 5-token prefill and 24
     steps: over `TOL` by a factor of 50. With 16 bits it passes."""
-    model, params, ids = seeded
-    want = reference_logits(params, ids)
-    assert rel(served(model, params, ids, 5, state_bits=7), want) > 50 * TOL
-    assert rel(served(model, params, ids, 5, state_bits=16), want) < TOL
+    fam = seeded
+    assert rel(served(fam, 5, state_bits=7), fam.want) > 50 * TOL
+    assert rel(served(fam, 5, state_bits=16), fam.want) < TOL
 
 
 def test_the_cache_holds_each_kind_of_layer_its_own():

@@ -1,12 +1,12 @@
-"""Phi-4-mini-flash through its three passes against the float32 reference
+"""Phi-4-mini-flash against the float32 reference
 (`perfbench/configs/phi4flash_reference.py`), at a small size on seeded
-weights, LOGITS not tokens: the plain forward; a prefill and then decoding
-through the caches (rings, the shared slab, the Mamba-1 state) with a prompt
-shorter than the window, a generation that crosses it and one well past it;
-and the prefill that walks only each row's last position through the cross
-decoder against the all-position walk."""
-
-import zlib
+weights, LOGITS not tokens: the plain forward, the loss, and a prefill and
+then decoding through the caches (rings, the shared slab, the Mamba-1 state)
+with a prompt shorter than the window, a generation that crosses it and one
+well past it (the questions all three hybrid families are asked: their
+bodies are `hybrid_families.py`'s); and this family's own: the prefill that
+walks only each row's last position through the cross decoder against the
+all-position walk, the cache by kind, and the state's precision."""
 
 import jax
 import jax.numpy as jnp
@@ -14,38 +14,17 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models import phi4flash
-from deepspeed_tpu.models.phi4flash import (Phi4FlashConfig, lambda_init,
-                                            materialize_params)
-from perfbench.manifest import Manifest
-
-SIZES = dict(vocab_size=128, hidden_size=64, intermediate_size=96,
-             num_hidden_layers=8, num_attention_heads=8,
-             num_key_value_heads=4, sliding_window=8, layer_norm_eps=1e-5)
-CFG = Phi4FlashConfig(**SIZES, dtype=jnp.float32)
-REF = Manifest().module("configs", "phi4flash_reference")
-ROWS, LENGTH = 3, 30
-
-
-def moved(params):
-    """The seeded tree with its small parameters moved off their initial
-    values (biases 0, norm weights 1, D 1): a term the program dropped, or
-    took from the wrong layer of a stack, would otherwise not show."""
-    def bump(path, x):
-        key = jax.random.fold_in(jax.random.PRNGKey(7), zlib.crc32(
-            jax.tree_util.keystr(path).encode()) % 2 ** 31)
-        small = x.size < 5000
-        return x + 0.1 * jax.random.normal(key, x.shape, x.dtype) if small \
-            else x
-    return jax.tree_util.tree_map_with_path(bump, params)
+from deepspeed_tpu.models.phi4flash import Phi4FlashConfig, lambda_init
+from tests.unit.models import hybrid_families
+from tests.unit.models.hybrid_families import (PHI4_CFG as CFG,
+                                               PHI4_SIZES as SIZES,
+                                               compile_apply, family, walk)
 
 
 @pytest.fixture(scope="module")
 def served():
-    model, params = materialize_params(CFG, jax.random.PRNGKey(0))
-    params = moved(params)
-    ids = jax.random.randint(jax.random.PRNGKey(1), (ROWS, LENGTH), 1, 128)
-    want = REF._head(REF.hidden_states(params, ids, SIZES), params)
-    return model, params, ids, np.asarray(want)
+    fam = family("phi4flash")
+    return fam.model, fam.params, fam.ids, fam.want
 
 
 def test_layer_kinds_of_the_published_depth():
@@ -68,40 +47,21 @@ def test_a_walk_the_program_has_not_is_refused(bad):
         Phi4FlashConfig(**{**SIZES, **bad})
 
 
-def test_the_plain_forward_is_the_reference_s(served):
-    model, params, ids, want = served
-    got = model.apply({"params": params}, ids)
-    # float32 both: the orders of summation differ, nothing else
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+def test_the_plain_forward_is_the_reference_s():
+    hybrid_families.the_plain_forward_is_the_reference_s("phi4flash")
 
 
-def test_the_loss_is_the_reference_s(served):
-    model, params, ids, _ = served
-    loss = phi4flash.phi4flash_loss_fn(model)(params, {"input_ids": ids}, None)
-    assert float(loss) == pytest.approx(
-        float(REF.mean_loss(params, ids, SIZES)), rel=1e-5)
+def test_the_loss_is_the_reference_s():
+    hybrid_families.the_loss_is_the_reference_s("phi4flash")
 
 
 # the window is 8: a prompt inside it whose generation crosses it, one that
 # fills it exactly, one past it by a part of a window (the ring is written
 # rolled), and one more than two windows long
 @pytest.mark.parametrize("prompt", [5, 8, 13, 20])
-def test_prefill_then_decode_through_the_caches(served, prompt):
-    model, params, ids, want = served
-    cache = model.make_cache(ROWS, 32, dtype=jnp.float32)
-    logits, cache = model.apply({"params": params}, ids[:, :prompt],
-                                cache=cache)
-    assert logits.shape == (ROWS, 1, 128)         # the last position's alone
-    np.testing.assert_allclose(np.asarray(logits[:, 0]), want[:, prompt - 1],
-                               atol=2e-5)
-    step = jax.jit(lambda tok, cache: model.apply({"params": params}, tok,
-                                                  cache=cache))
-    for t in range(prompt, LENGTH):
-        logits, cache = step(ids[:, t:t + 1], cache)
-        np.testing.assert_allclose(np.asarray(logits[:, 0]), want[:, t],
-                                   atol=2e-5, err_msg=f"position {t}")
-    assert np.array_equal(np.asarray(cache.index), [LENGTH] * ROWS)
-    assert np.array_equal(np.asarray(cache.window.index), cache.index)
+def test_prefill_then_decode_through_the_caches(prompt):
+    hybrid_families.prefill_then_decode_is_the_reference_s("phi4flash",
+                                                           prompt)
 
 
 @pytest.mark.parametrize("tokens", [10 ** 6, 2 * 13, 13])
@@ -114,14 +74,13 @@ def test_the_one_position_cross_prefill_is_the_all_position_walk(
     ids = jnp.concatenate([ids, ids[:1]])         # 4 rows: groups of 4, 2, 1
     want = np.concatenate([want, want[:1]])
     monkeypatch.setattr(phi4flash, "PREFILL_TOKENS", tokens)
-    (logits, cache), counted = model.apply(
-        {"params": params}, ids[:, :13], mutable=["counters"],
-        cache=model.make_cache(4, 32, dtype=jnp.float32))
+    (logits, cache), counted = compile_apply(mutable=["counters"])(
+        model, params, ids[:, :13], model.make_cache(4, 32, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(logits[:, 0]), want[:, 12],
                                atol=2e-5)
     assert {k: int(v) for k, v in counted["counters"].items()} == {
         "prompt_positions": 4 * 13, "cross_prefill_positions": 4}
-    logits, _ = model.apply({"params": params}, ids[:, 13:14], cache=cache)
+    logits, _ = compile_apply()(model, params, ids[:, 13:14], cache)
     np.testing.assert_allclose(np.asarray(logits[:, 0]), want[:, 13],
                                atol=2e-5)
 
@@ -160,22 +119,11 @@ def test_the_state_is_kept_in_float32_between_tokens(served):
         return x * 8.0 if "x_proj" in name else \
             x + 2.0 if name.endswith("['dt_proj']['bias']") else x
     params = jax.tree_util.tree_map_with_path(louder, params)
-    want = np.asarray(REF._head(REF.hidden_states(params, ids, SIZES), params))
+    want = np.asarray(family("phi4flash").reference_logits(params, ids))
 
-    def walk(round_state):
-        cache = model.make_cache(ROWS, 32, dtype=jnp.float32)
-        _, cache = model.apply({"params": params}, ids[:, :5], cache=cache)
-        step = jax.jit(lambda tok, cache: model.apply(
-            {"params": params}, tok, cache=cache))
-        worst = 0.0
-        for t in range(5, LENGTH):
-            if round_state:
-                cache = cache.replace(state=cache.state.replace(
-                    ssm=jax.lax.reduce_precision(cache.state.ssm, 8, 7)))
-            logits, cache = step(ids[:, t:t + 1], cache)
-            worst = max(worst, float(np.abs(np.asarray(logits[:, 0])
-                                            - want[:, t]).max()))
-        return worst
+    def worst(state_bits):      # over the 25 steps after a prefill of 5
+        got, _ = walk(model, params, ids, 5, 32, state_bits=state_bits)
+        return float(np.abs(np.asarray(got[:, 1:]) - want[:, 5:]).max())
 
-    kept, rounded = walk(False), walk(True)
+    kept, rounded = worst(None), worst(7)
     assert kept < 1e-6 and rounded > 2e-6     # read: 3.0e-7 and 4.1e-6

@@ -1,6 +1,7 @@
 """Decode-attention kernel golden tests (softmax_context slot): vs the
 masked XLA reference used by the model decode path."""
 
+import functools
 import os
 
 os.environ.setdefault("DS_TPU_PALLAS_INTERPRET", "1")
@@ -14,14 +15,21 @@ import deepspeed_tpu.ops.pallas.decode_attention as da
 from deepspeed_tpu.ops.attention import reference_attention
 from deepspeed_tpu.ops.pallas.decode_attention import (KV_TILE_BUDGET,
                                                        _interpret,
-                                                       decode_attention,
                                                        decode_plan,
                                                        kv_write_dense,
                                                        plan_traffic)
 
 TOL = 1e-5 if _interpret() else 2e-2
 
+# ONE `jax.jit` of the kernel for the module: a bare call compiles the
+# interpreted kernel anew every time, and the cases below call it up to six
+# times at one shape. A case that patches a constant the PLAN reads
+# (`KV_TILE_BUDGET`) calls `da.decode_attention` itself: a trace made under
+# another budget must not answer it.
+decode_attention = jax.jit(da.decode_attention, static_argnames=("block_k",))
 
+
+@jax.jit
 def _ref(q, k_cache, v_cache, lengths):
     m = k_cache.shape[1]
     mask = jnp.arange(m)[None, None, :] < lengths[:, None, None]  # (B,1,M)
@@ -178,12 +186,10 @@ def test_staged_equals_written_then_attended_bit_for_bit_under_jit(hkv, n_rep,
     q = _query(rng, b, hkv * n_rep, d)
     new = jnp.asarray(rng.standard_normal((2, 2, b, hkv, d)), jnp.float32)
     k_w, v_w = kv_write_dense(k, v, new[0], new[1], index)
-    staged = jax.jit(lambda *a: decode_attention(
-        a[0], a[1], a[2], a[3], layer=jnp.int32(1), block_k=BLK,
-        k_new=a[4], v_new=a[5]))
-    written = jax.jit(lambda *a: decode_attention(
-        a[0], a[1], a[2], a[3], layer=jnp.int32(1), block_k=BLK))
-    got = np.asarray(staged(q, k, v, index + 1, new[0, 1], new[1, 1]))
+    kw = dict(layer=jnp.int32(1), block_k=BLK)
+    written = functools.partial(decode_attention, **kw)
+    got = np.asarray(decode_attention(q, k, v, index + 1, k_new=new[0, 1],
+                                      v_new=new[1, 1], **kw))
     want = np.asarray(written(q, k_w, v_w, index + 1))
     np.testing.assert_array_equal(got, want)
     # the live rows against the plain reference, the empty row zeros, the
@@ -210,7 +216,8 @@ def test_a_batch_the_row_group_does_not_divide(monkeypatch, b, rb):
     k, v = _stack(rng, b, hkv, d, layers=1)
     q = _query(rng, b, hkv * 8, d)
     lengths = jnp.asarray(rng.integers(0, M + 1, b), jnp.int32)
-    got = decode_attention(q, k, v, lengths, layer=jnp.int32(0), block_k=BLK)
+    got = da.decode_attention(q, k, v, lengths, layer=jnp.int32(0),
+                              block_k=BLK)
     want = np.array(_ref(q, _per_layer(k, 0), _per_layer(v, 0), lengths))
     want[np.asarray(lengths) == 0] = 0.0   # an empty row writes zeros
     np.testing.assert_allclose(np.asarray(got), want, rtol=TOL, atol=TOL)
