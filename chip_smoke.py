@@ -92,6 +92,12 @@ class Sizes:
     kda: Tuple[int, int, int, int]
     # latent decode attention: layers, rows, query heads, slots, rank, rope
     mla: Tuple[int, int, int, int, int, int]
+    # learned sparse attention over the stacked dense cache: layers, rows, KV
+    # heads, query heads a KV head, slots, head width, index heads, index
+    # width AS STORED (a 64-value key in a whole lane row), positions chosen,
+    # queries a prefill chunk (Keye-VL-2.0's at the benchmark cell's batch
+    # and length)
+    sparse: Tuple[int, int, int, int, int, int, int, int, int, int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -106,7 +112,8 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856),
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
              ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
-             mla=(1, 128, 32, 2048, 512, 64))
+             mla=(1, 128, 32, 2048, 512, 64),
+             sparse=(2, 8, 4, 8, 33280, 128, 16, 128, 2048, 2048))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
@@ -117,7 +124,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48),
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
              ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
-             mla=(2, 3, 4, 32, 32, 8))
+             mla=(2, 3, 4, 32, 32, 8),
+             sparse=(2, 3, 2, 2, 64, 16, 4, 8, 8, 16))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -191,6 +199,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_kv_write, paged_prefill_attention)
     from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
+    from deepspeed_tpu.ops.pallas import sparse_select as sps
     from deepspeed_tpu.ops.pallas.diff_attention import (
         diff_decode_attention, diff_decode_attention_reference)
     from deepspeed_tpu.ops.pallas.ssm import (ssm_state_update,
@@ -679,6 +688,72 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
             :, jnp.arange(lb), 0, pos].set(
                 jnp.broadcast_to(new[None], (ll,) + new.shape)),
         make_mla))
+
+    # ---- learned sparse attention: a decode step's choice, attention under
+    # it, and a prefill chunk against a row's slabs. The index operands are
+    # small integers: their scores are exact in any order of summation, tie
+    # in plenty, and kernel and plain form make the SAME choice ----
+    (sl, sb, shkv, srep, sm, sd, shi, sdi, stopk, schunk) = sz.sparse
+    sh = shkv * srep
+
+    def small(key, shape):
+        return jax.random.randint(key, shape, -3, 4).astype(bf16)
+
+    def make_sparse_decode(key):
+        ks = jax.random.split(key, 10)
+        lengths = jax.random.randint(ks[9], (sb,), sm // 2, sm + 1, jnp.int32)
+        q_i, w = small(ks[0], (sb, shi, sdi)), small(ks[1], (sb, shi))
+        keys, new_i = small(ks[2], (sl, sb, 1, sm, sdi)), small(ks[3], (sb, sdi))
+        bias, _ = sps.sparse_index_select_reference(
+            q_i, w, keys, sl - 1, lengths, stopk, new_i)
+        return (q_i, w, keys, lengths, new_i, bias, normal(ks[4], (sb, sh, sd)),
+                normal(ks[5], (sl, sb, shkv, sm, sd)),
+                normal(ks[6], (sl, sb, shkv, sm, sd)),
+                normal(ks[7], (sb, shkv, sd)), normal(ks[8], (sb, shkv, sd)))
+
+    def run_select(fn, q_i, w, keys, lengths, new_i, *rest):
+        # 1 at a kept slot (the bias itself is 0 or -1e30), and the row's
+        # count of them as the kernel made it, of `topk`
+        bias, kept = fn(q_i, w, keys, sl - 1, lengths, stopk, new_i)
+        return jnp.concatenate([(bias == 0.0).astype(jnp.float32),
+                                kept[:, None] / stopk], axis=1)
+
+    def run_sparse_decode(fn, q_i, w, keys, lengths, new_i, bias, q, k, v, kn,
+                          vn):
+        return fn(q, k, v, sl - 1, lengths, bias, sd ** -0.5, kn, vn)
+
+    cases.append(KernelCase(
+        "sparse_index_select",
+        functools.partial(run_select, sps.sparse_index_select),
+        functools.partial(run_select, sps.sparse_index_select_reference),
+        make_sparse_decode))
+    cases.append(KernelCase(
+        "sparse_attn_decode",
+        functools.partial(run_sparse_decode, sps.sparse_attn_decode),
+        functools.partial(run_sparse_decode, sps.sparse_attn_decode_reference),
+        make_sparse_decode))
+
+    def make_sparse_prefill(key):
+        ks = jax.random.split(key, 6)
+        return (normal(ks[0], (schunk, sh, sd)), small(ks[1], (schunk, shi, sdi)),
+                small(ks[2], (schunk, shi)),
+                normal(ks[3], (sl, sb, shkv, sm, sd)),
+                normal(ks[4], (sl, sb, shkv, sm, sd)),
+                small(ks[5], (sl, sb, 1, sm, sdi)))
+
+    def run_sparse_prefill(fn, *ops):
+        # the row's last chunk but one: a causal edge inside the slab
+        out, kept = fn(*ops, sl - 1, sb - 1, sm - 2 * schunk, stopk,
+                       sd ** -0.5)
+        return jnp.concatenate([out.reshape(schunk, -1).astype(jnp.float32),
+                                kept[:, None] / stopk], axis=1)
+
+    cases.append(KernelCase(
+        "sparse_attn_prefill",
+        functools.partial(run_sparse_prefill, sps.sparse_attn_prefill),
+        functools.partial(run_sparse_prefill,
+                          sps.sparse_attn_prefill_reference),
+        make_sparse_prefill))
 
     # ---- block-sparse attention (MHA; layout is static host data) ----
     sblk = min(64, sz.seq // 4)

@@ -87,16 +87,18 @@ def kv_cache_bytes(model_cfg, batch: int, max_len: int, dtype,
     dtype) uses `dtype`'s width — the pre-r8 accounting unchanged.
 
     A model whose layers keep K and V of different KINDS counts them itself
-    (`kv_bytes_by_kind`): the sum is what is held."""
+    (`kv_bytes_by_kind`): the sum is what is held. `index_kv_bytes` is no
+    kind of K and V: it lies BESIDE them and is added to either count."""
     kinds = kv_bytes_by_kind(model_cfg, batch, max_len, dtype)
+    beside = kinds.pop("index_kv_bytes", 0)
     if kinds:
-        return sum(kinds.values())
+        return sum(kinds.values()) + beside
     d = _model_dims(model_cfg)
     slots = 2 * d["layers"] * batch * max_len * d["kv_heads"]
     if kv_dtype in ("int8", jnp.int8):
-        return slots * (d["head_dim"] + 4)
+        return slots * (d["head_dim"] + 4) + beside
     item = jnp.dtype(dtype).itemsize
-    return slots * d["head_dim"] * item
+    return slots * d["head_dim"] * item + beside
 
 
 def kv_bytes_by_kind(model_cfg, batch: int, max_len: int,
@@ -105,9 +107,11 @@ def kv_bytes_by_kind(model_cfg, batch: int, max_len: int,
     as the model's config counts them (`model_cfg.kv_bytes_by_kind`):
     `window_kv_bytes`, rings of a window's slots whatever `max_len`,
     `shared_kv_bytes`, full-length slabs that layers without a cache of
-    their own read, and `latent_kv_bytes`, the latent-attention layers' one
-    row a token in place of K and V a head. Empty for a model of one kind of
-    layer: `max_len` slots a layer, all of it `kv_cache_bytes`."""
+    their own read, `latent_kv_bytes`, the latent-attention layers' one
+    row a token in place of K and V a head, and `index_kv_bytes`, the one
+    index key a token a layer that a learned selection keeps beside K and V.
+    Empty for a model of one kind of layer: `max_len` slots a layer, all of
+    it `kv_cache_bytes`."""
     own = getattr(model_cfg, "kv_bytes_by_kind", None)
     if own is None:
         return {}

@@ -31,6 +31,22 @@ from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
 
+# A generate program's counters (the model's `program_counters`) are int32
+# and their sums over a call outgrow it (positions walked: 5e10 a batch of
+# 32k-token rows): a sum is carried as (high, low) limbs of this many bits.
+_LIMB = 16
+
+
+def _wide_add(total, leaves):
+    """`total` (high, low) plus every element of the int32 `leaves`, each
+    split into limbs before it is summed."""
+    high, low = total
+    for leaf in leaves:
+        high = high + jnp.sum(leaf >> _LIMB, dtype=jnp.int32)
+        low = low + jnp.sum(leaf & ((1 << _LIMB) - 1), dtype=jnp.int32)
+    return high + (low >> _LIMB), low & ((1 << _LIMB) - 1)
+
+
 def _cache_dims(cfg) -> tuple:
     """(num_layers, kv_heads, head_dim) from a zoo model config (duck-typed
     over llama/gpt2/mixtral naming)."""
@@ -375,7 +391,8 @@ class InferenceEngine:
             counted = {}
             if isinstance(out, tuple):   # (sequences, the model's counters)
                 out, counted = jax.device_get(out)   # one fetch for both
-                counted = {k: int(v) for k, v in counted.items()}
+                counted = {k: (int(high) << _LIMB) + int(low)
+                           for k, (high, low) in counted.items()}
             out = np.asarray(out)
         dt = _time.perf_counter() - t0
         self.last_decode_tok_s = (b * new_tokens / dt) if dt > 0 else None
@@ -384,7 +401,7 @@ class InferenceEngine:
         # gauges and counters update on a disabled hub too (hub.py): what a
         # benchmark reads of a call without the JSONL stream
         for name in ("kv_bytes", "state_bytes", "window_kv_bytes",
-                     "shared_kv_bytes", "latent_kv_bytes",
+                     "shared_kv_bytes", "latent_kv_bytes", "index_kv_bytes",
                      "dense_kv_slots_live", "dense_kv_slots_fetched",
                      "dense_decode_grid_steps"):
             if name in kv:
@@ -621,10 +638,9 @@ class InferenceEngine:
                 {"params": params}, ids, cache=cache, mutable=["counters"])
             sown = sown.get("counters", {})
             return logits, cache, {
-                name: counts[name] + sum(
-                    jnp.sum(v) for path, v in
-                    jax.tree_util.tree_leaves_with_path(sown)
-                    if any(getattr(p, "key", None) == name for p in path))
+                name: _wide_add(counts[name], [
+                    v for path, v in jax.tree_util.tree_leaves_with_path(sown)
+                    if any(getattr(p, "key", None) == name for p in path)])
                 for name in counted}
 
         def gen(params, ids, rng):
@@ -635,7 +651,8 @@ class InferenceEngine:
             else:
                 cache = KVCache.create(layers, b, max_len, kv_heads, head_dim,
                                        dtype=cfg.dtype, quantized=kv_int8)
-            counts = {name: jnp.zeros((), jnp.int32) for name in counted}
+            counts = {name: (jnp.zeros((), jnp.int32),) * 2
+                      for name in counted}
             logits, cache, counts = forward(params, ids, cache, counts)
             rng, sub = jax.random.split(rng)
             tok = sample(logits[:, -1, :], sub)

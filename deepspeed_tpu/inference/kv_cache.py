@@ -317,6 +317,9 @@ class HybridCache:
       shared slab: the readers hold nothing of their own);
     - `window`: a ring `KVCache` (`KVCache.ring`) over the WINDOW layers;
     - `latent`: a `LatentCache` over the latent-attention layers;
+    - `index_keys`: a `LatentCache` at the indexer's width BESIDE `kv`, over
+      the same layers and at the same length: the one key a token that a
+      learned selection scores the cache by (`ops/pallas/sparse_select.py`);
     - `state`: a `RecurrentState` over the recurrent layers.
 
     A kind the model has no layer of is None. Layers that keep nothing
@@ -327,9 +330,10 @@ class HybridCache:
     handles that."""
 
     kv: Optional[KVCache]
-    state: RecurrentState
+    state: Optional[RecurrentState] = None
     window: Optional[KVCache] = None
     latent: Optional[LatentCache] = None
+    index_keys: Optional[LatentCache] = None
 
     @property
     def _full(self):
@@ -343,12 +347,19 @@ class HybridCache:
     def max_len(self) -> int:
         return self._full.max_len
 
-    def advance(self, s: int) -> "HybridCache":
-        index = self.index + s
+    def _at(self, index) -> "HybridCache":
         return self.replace(**{
             kind: getattr(self, kind).replace(index=index)
-            for kind in ("kv", "window", "latent")
+            for kind in ("kv", "window", "latent", "index_keys")
             if getattr(self, kind) is not None})
+
+    def advance(self, s: int) -> "HybridCache":
+        return self._at(self.index + s)
+
+    def advance_row(self, row, s: int) -> "HybridCache":
+        """Sequence `row`'s cursors alone moved on by `s` (`row` may be
+        traced): a prefill that walks one row's chunks."""
+        return self._at(self.index.at[row].add(s))
 
     def rows(self, start, count: int) -> "HybridCache":
         """The cache of sequences `start .. start + count - 1` alone (`start`

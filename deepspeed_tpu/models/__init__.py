@@ -32,3 +32,5 @@ from deepspeed_tpu.models.phi4flash import (
     Phi4FlashConfig, Phi4FlashForCausalLM, phi4flash_loss_fn)
 from deepspeed_tpu.models.ling_linear import (
     LingLinearConfig, LingLinearForCausalLM, ling_linear_loss_fn)
+from deepspeed_tpu.models.keye_sparse import (
+    KeyeSparseConfig, KeyeSparseForCausalLM, keye_sparse_loss_fn)

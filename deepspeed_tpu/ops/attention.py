@@ -621,6 +621,58 @@ def kda_update(state, layer, q, k, v, g, beta):
     return fn(state, layer, q, k, v, g, beta)
 
 
+def sparse_select(q_index, w, index_keys, lengths, topk: int, new):
+    """A decode step's learned choice of cached positions
+    (`ops/pallas/sparse_select.py` has the scores and the layout): q_index
+    (B, Hi, Di), w (B, Hi), `index_keys` a `DenseLayer` view of the (L, B, 1,
+    M, Di) stack, `lengths` (B,) live slots whose last is the step's own
+    token, its index key `new` (B, Di) staged. Returns the bias (B, M)
+    float32, 0 at the `min(topk, length)` slots of largest score: the set
+    `jax.lax.top_k` gives, on both paths; and (B,) int32, the slots kept a
+    row, counted where the bias is written.
+
+    The Pallas kernel on the chip in a one-device program; elsewhere the
+    same in plain `jax.numpy`."""
+    from deepspeed_tpu.ops.pallas import sparse_select as ss
+    fn = ss.sparse_index_select if _one_device_kernel(ss.SELECT_NAME) \
+        else ss.sparse_index_select_reference
+    return fn(q_index, w, index_keys.stack, index_keys.layer, lengths, topk,
+              new)
+
+
+def sparse_decode(q, k_cache, v_cache, lengths, bias, softmax_scale: float,
+                  k_new, v_new):
+    """One decode step of attention over the CHOSEN slots of the stacked
+    dense cache: q (B, H, D), `k_cache`/`v_cache` `DenseLayer` views of the
+    (L, B, Hkv, M, D) stacks, the step's own token staged as `k_new`/`v_new`
+    (B, Hkv, D) in slot `lengths[b] - 1`, `bias` (B, M) from `sparse_select`.
+    Returns (B, H, D). Kernel and plain form as `sparse_select`."""
+    from deepspeed_tpu.ops.pallas import sparse_select as ss
+    fn = ss.sparse_attn_decode if _one_device_kernel(ss.DECODE_NAME) \
+        else ss.sparse_attn_decode_reference
+    return fn(q, k_cache.stack, v_cache.stack, k_cache.layer, lengths, bias,
+              softmax_scale, k_new, v_new)
+
+
+def sparse_prefill(q, q_index, w, k_cache, v_cache, index_keys, row, start,
+                   topk: int, softmax_scale: float):
+    """A chunk of ONE sequence's queries (q (C, H, D), q_index (C, Hi, Di),
+    w (C, Hi), positions `start ..`) against sequence `row`'s slabs of the
+    stacked caches, which already hold the chunk: each query's choice of
+    `topk` positions up to its own, and attention over them. Returns (C, H,
+    D) and (C,) int32, the slots each query kept. The kernels where the
+    chip's tiling takes the shapes (whole lane tiles of slots and of
+    queries: `models/keye_sparse.py` cuts every prompt of 128 tokens or more
+    into such chunks), else the plain form."""
+    from deepspeed_tpu.ops.pallas import sparse_select as ss
+    aligned = k_cache.stack.shape[3] % 128 == 0 and q.shape[0] % 128 == 0 \
+        and q.shape[-1] % 128 == 0
+    fn = ss.sparse_attn_prefill if aligned and _one_device_kernel(
+        ss.PREFILL_NAME) else ss.sparse_attn_prefill_reference
+    return fn(q, q_index, w, k_cache.stack, v_cache.stack, index_keys.stack,
+              k_cache.layer, row, start, topk, softmax_scale)
+
+
 def rms_norm_ref(x, weight, eps: float = 1e-6):
     """RMSNorm reference (csrc/transformer/inference/csrc/rms_norm.cu analog)."""
     dtype = x.dtype

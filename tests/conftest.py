@@ -49,6 +49,7 @@ def devices():
 # is named here too, until the harness traces into a directory a run.
 TRACED_REHEARSALS = frozenset((
     "test_the_traced_rehearsal_of_the_cell_runs_on_the_cpu",
+    "test_the_traced_rehearsal_of_the_keye_cell_runs_on_the_cpu",
     "test_traced_rehearsal_lists_every_new_program_metric",
     "test_setup_metrics_in_the_other_kinds_of_cell",
     "test_traced_rehearsal_reads_no_device_metric",
@@ -90,6 +91,8 @@ LONGEST_FIRST = (
     "tests/unit/inference/test_kv_pool_decode_kernel.py",
     "tests/unit/models/test_ling_linear.py",
     "tests/unit/inference/test_kv_pool_in_place.py",
+    "tests/perfbench/test_keye_cell.py",
+    "tests/unit/models/test_keye_sparse.py",
     "tests/perfbench/test_ling_cell.py",
     "tests/unit/ops/test_chip_compile.py",
     "tests/perfbench/test_oracle.py",
@@ -118,11 +121,30 @@ def pytest_configure(config):
 # for dense configurations only (ROADMAP B2 (i)).
 
 
+#
+# `tests/perfbench/test_manifest.py::test_problems_are_found` makes ONE more
+# cell of BENCHMARK.json a four-chip cell and asserts that `problems` names
+# the share of four-chip cells: true while the benchmark had four to seven
+# cells (a quarter, rounded down, is one). With the eighth cell (PR 51) two
+# may ask for four chips, and the edit the test makes is no problem any more.
+# The file is the benchmark's; it is held here, strictly, until a `benchmark`
+# PR makes its edit one cell more than the quarter (ROADMAP B2 (i)), and
+# `tests/perfbench/test_keye_cell.py` plants the same three faults one cell
+# past the quarter meanwhile.
+PREDATES_THE_EIGHTH_CELL = \
+    "tests/perfbench/test_manifest.py::test_problems_are_found"
+
+
 def pytest_collection_modifyitems(config, items):
     rank = {path: i for i, path in enumerate(LONGEST_FIRST)}
     items.sort(key=lambda item: rank.get(item.nodeid.split("::")[0],
                                          len(rank)))    # stable for the rest
     for item in items:
+        if item.nodeid.endswith(PREDATES_THE_EIGHTH_CELL):
+            item.add_marker(pytest.mark.xfail(strict=True, reason=(
+                "one four-chip cell more is within the quarter the contract "
+                "allows of eight cells")))
+            continue
         if getattr(item, "originalname", None) != \
                 "test_a_dense_configuration_owes_no_margin":
             continue

@@ -1,8 +1,8 @@
-"""What the tests of the three hybrid families share (`test_nemotron_h.py`,
-`test_phi4flash.py`, `test_ling_linear.py`): each family at a small size on
-seeded weights with the benchmark's plain float32 reference beside it, the
-model's `apply` under ONE `jax.jit`, the walk through the caches, and the
-questions asked of all three alike, written once (`the_plain_forward_...`,
+"""What the tests of the four hybrid families share (`test_nemotron_h.py`,
+`test_phi4flash.py`, `test_ling_linear.py`, `test_keye_sparse.py`): each
+family at a small size on seeded weights with the benchmark's plain float32
+reference beside it, the model's `apply` under ONE `jax.jit`, the walk
+through the caches, and the questions asked of all four alike, written once (`the_plain_forward_...`,
 `the_loss_...`, `prefill_then_decode_...`: LOGITS not tokens, each family
 held to its own tolerance in its own way, `Family.close`). Each family's
 file asks them under its own test names: a file is one worker's under
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import ling_linear, nemotron_h, phi4flash
+from deepspeed_tpu.models import keye_sparse, ling_linear, nemotron_h, phi4flash
 from perfbench.manifest import Manifest
 
 
@@ -112,6 +112,7 @@ class Family:
 NEMOTRON_TOL = 2e-5     # RELATIVE to the largest logit (magnitude 0.5: 1e-6)
 PHI4_TOL = 2e-5
 LING_TOL = 5e-6         # read 6e-7
+KEYE_TOL = 3e-6         # read 4e-7
 
 NEMOTRON_CFG = nemotron_h.NemotronHConfig(
     vocab_size=128, hidden_size=64, num_hidden_layers=6,
@@ -135,6 +136,17 @@ LING_SIZES = dict(
     moe_intermediate_size=32, moe_shared_expert_intermediate_size=32,
     routed_scaling_factor=2.5, norm_topk_prob=True, n_group=4, topk_group=2,
     rms_norm_eps=1e-6)
+# the file's keys, as the reference and the adapter read them: 8 positions
+# chosen of up to 40, experts 2-5 of 8 held
+KEYE_SIZES = dict(
+    vocab_size=128, hidden_size=64, num_hidden_layers=3,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    rope_theta=1e7, rope_scaling={"rope_type": "default"},
+    num_experts=4, num_local_experts=4, router_experts=8, expert_offset=2,
+    num_experts_per_tok=3, moe_intermediate_size=32, norm_topk_prob=True,
+    rms_norm_eps=1e-6, max_position_embeddings=4096,
+    sa_config=dict(indexer_num_heads=4, indexer_head_dim=8,
+                   indexer_num_kv_heads=1, topk=8))
 PHI4_CFG = phi4flash.Phi4FlashConfig(**PHI4_SIZES, dtype=jnp.float32)
 LING_CFG = ling_linear.LingLinearConfig(**LING_SIZES, dtype=jnp.float32)
 
@@ -204,8 +216,34 @@ def _ling_linear():
                   reference_logits, close, ling_linear.ling_linear_loss_fn)
 
 
+def _keye_sparse():
+    manifest = Manifest()
+    ref = manifest.module("configs", "keye_sparse_reference")
+    cfg = manifest.module("configs", "keye_sparse_adapter").model_config(
+        KEYE_SIZES, dtype=jnp.float32, dispatch_impl="gmm")
+    model, params = keye_sparse.materialize_params(cfg, jax.random.PRNGKey(0))
+    # off their initial values: the norms' weights, the index key's
+    # LayerNorm (weight 1, bias 0); and the index projections at a range at
+    # which the choice really chooses (as seeded every index score is near 0)
+    params = moved(params, 600)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * 20.0 if "index_" in jax.tree_util.keystr(path)
+        and "kernel" in jax.tree_util.keystr(path) else x, params)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (3, 40), 1, 128)
+
+    def reference_logits(params, ids, sizes=KEYE_SIZES):
+        return np.asarray(ref.logits_at(params, ids,
+                                        list(range(ids.shape[1])), sizes))
+
+    def close(got, want):
+        np.testing.assert_allclose(np.asarray(got), want, atol=KEYE_TOL)
+    return Family(ref, cfg, KEYE_SIZES, model, params, ids,
+                  reference_logits(params, ids), 64,
+                  reference_logits, close, keye_sparse.keye_sparse_loss_fn)
+
+
 FAMILIES = {"nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
-            "ling_linear": _ling_linear}
+            "ling_linear": _ling_linear, "keye_sparse": _keye_sparse}
 
 
 @functools.cache

@@ -37,7 +37,10 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "grouped_gemm_decode": "gmm",
                "kda_state_update": "kda_state_update",
                "mla_latent_decode": "mla_latent_decode",
-               "latent_write_dense": "latent_write_dense"}
+               "latent_write_dense": "latent_write_dense",
+               "sparse_index_select": "sparse_index_select",
+               "sparse_attn_decode": "sparse_attn_decode",
+               "sparse_attn_prefill": "sparse_attn_prefill"}
 
 
 @pytest.fixture(scope="module")
@@ -67,11 +70,11 @@ def compiled_kernels(monkeypatch):
     from deepspeed_tpu.ops.pallas import (
         block_sparse_attention, decode_attention, diff_attention,
         flash_attention, grouped_gemm, kda, mla, paged_attention,
-        quantized_matmul, ssm)
+        quantized_matmul, sparse_select, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
     for mod in (block_sparse_attention, decode_attention, diff_attention,
                 flash_attention, grouped_gemm, kda, mla, paged_attention,
-                quantized_matmul, ssm):
+                quantized_matmul, sparse_select, ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
