@@ -16,6 +16,7 @@ from deepspeed_tpu.comm.comm import (
     log_summary,
     ppermute,
     reduce_scatter,
+    reduce_scatter_by_exchange,
 )
 from deepspeed_tpu.comm.comms_logging import CommsLogger, get_comms_logger
 
@@ -23,5 +24,6 @@ __all__ = [
     "ReduceOp", "all_gather", "all_reduce", "all_to_all_single", "axis_index",
     "barrier", "broadcast", "get_local_rank", "get_rank", "get_world_size",
     "init_distributed", "initialize_mesh_device", "is_initialized",
-    "log_summary", "ppermute", "reduce_scatter", "CommsLogger", "get_comms_logger",
+    "log_summary", "ppermute", "reduce_scatter", "reduce_scatter_by_exchange",
+    "CommsLogger", "get_comms_logger",
 ]

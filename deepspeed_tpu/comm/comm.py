@@ -89,6 +89,41 @@ def reduce_scatter(tensor, group: Union[str, None] = "data", scatter_dim: int = 
     return jax.lax.psum_scatter(tensor, _axes(group), scatter_dimension=scatter_dim, tiled=True)
 
 
+def reduce_scatter_by_exchange(tensor, group: Union[str, Sequence[str]] = "data",
+                               scatter_dim: int = 0, sum_dtype=None):
+    """`reduce_scatter`'s result, made of `n - 1` `ppermute`s and a LOCAL sum.
+
+    Rank `i` keeps slice `i` of its own `tensor` along `scatter_dim`, sends
+    slice `j` straight to rank `j`, and adds what the peers send it in
+    `sum_dtype` (default: the tensor's own): `(n - 1) / n` of the tensor
+    on the wire in each direction, a ring reduce-scatter's bytes, and no
+    rounding between two partial sums. `group` may be a tuple of axes; ranks
+    count in the axes' row-major order, as `axis_index` does.
+
+    Why not `reduce_scatter`: on a v5e 2x2 the compiler keeps no
+    reduce-scatter (`psum_scatter` over two chips comes out as an
+    all-reduce of the WHOLE tensor and a slice, inside a `while` it is moved
+    after the loop as well) and an `all_to_all` of the same slices takes as
+    long as that all-reduce; the permute moves half the bytes and runs
+    under the next matmul (my chip run, PR 52: `PERF.md` section 6)."""
+    import jax
+    axes = _axes(group)
+    n = jax.lax.psum(1, axes)  # of a constant: the group's size, an int
+    width = tensor.shape[scatter_dim] // n
+    me = axis_index(axes)
+
+    def slice_of(rank):
+        return jax.lax.dynamic_slice_in_dim(tensor, rank * width, width,
+                                            scatter_dim)
+
+    total = slice_of(me).astype(sum_dtype or tensor.dtype)
+    for shift in range(1, n):
+        got = ppermute(slice_of((me + shift) % n),
+                       [(i, (i + shift) % n) for i in range(n)], axes)
+        total = total + got.astype(total.dtype)
+    return total
+
+
 def all_to_all_single(tensor, group: Union[str, None] = "sequence",
                       split_axis: int = 0, concat_axis: int = 0, tiled: bool = True):
     """lax.all_to_all; counterpart of all_to_all_single (comm/torch.py:282)."""

@@ -25,16 +25,105 @@ loss runs the plain forward alone. `jax.jvp` straight over the loss is not
 defined; over its `jax.grad` it is (`runtime/eigenvalue.py`), because that
 differentiates the two rules.
 
+On a mesh the loop names where its own collectives stand (`_loop_layout`:
+an installed topology whose axes larger than 1 are the batch's, `repl` /
+`data` / `expert`, and the vocabulary's, `model`; anything else, one device
+included, runs the loop with no sharding named in it, which is the
+partitioner's program). With the head at rest as the ZeRO-3 plan lays it,
+vocabulary over `model` and width over `data`:
+
+- OUTSIDE the loop, once a call: ONE all-gather of the head over `data`
+  (`_head_for_loop`); the scan closes over the whole-width copy, and both
+  of a chunk's uses (`logits`, `dh_blk`) read it. The partitioner alone
+  gathers it once a CHUNK: XLA hoists no collective out of a `while`.
+- INSIDE the loop, once a chunk: no all-gather; the all-reduces over
+  `model` that vocabulary parallelism needs (the row maxima, the row sums
+  with the label's logit, `dh_blk`); and ONE transfer over `data` that has
+  the SHARD's shape `(V / tp, D / dp)`: a rank's `dW` product is a partial
+  sum over its rows of the batch, each peer is sent its slice of it, and
+  the slices are added in float32 straight into the carry, which is laid
+  as that shard (`_onto_carry_shard`, `comm.reduce_scatter_by_exchange`).
+  The partitioner alone all-reduces the WHOLE `(V / tp, D)` product and
+  then keeps a slice. A reduce-scatter would say the same, but the chip's
+  compiler keeps none on a 2x2 (it makes an all-reduce and a slice of it
+  again, and moves it behind the loop); the permute is half the bytes and
+  runs under the `dh_blk` product.
+
+The gradient comes back on that shard; a head at rest in another layout
+(`(model, None)`, the vocabulary over `data`) is re-laid ONCE after the
+loop by whoever asked for it.
+
 Peak logits memory: O(B · chunk · V) instead of O(B · S · V) — the piece
 that makes 128k-context training (BASELINE config 5) fit.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.comm import comm
+from deepspeed_tpu.ops.pallas.sharded import nontrivial_axes
+from deepspeed_tpu.utils.partitioning import (BATCH_AXES, DEFAULT_RULES,
+                                              ambient_manual_mesh,
+                                              current_mesh, shard_along)
+
+
+def _loop_layout(h, lm_head, tied_embedding):
+    """`(mesh, batch axes, vocabulary axis)` where the chunk loop can place
+    its own collectives: a topology is installed, every axis of it larger
+    than 1 is one the batch (`BATCH_AXES`) or the vocabulary (the rules'
+    `"vocab"`) is sharded over, and each divides the dimension it cuts.
+    None otherwise (one device, a `sequence` or `pipe` axis, an enclosing
+    manual region, a shape that does not divide): the loop then carries no
+    constraint at all and the partitioner places what it needs, as before."""
+    mesh = current_mesh()
+    sizes = nontrivial_axes(mesh)
+    batch = tuple(a for a in BATCH_AXES if a in sizes)
+    vocab = DEFAULT_RULES["vocab"] if DEFAULT_RULES["vocab"] in sizes else None
+    if (not sizes or set(sizes) - {*batch, vocab}
+            or ambient_manual_mesh()[1]):
+        return None
+    v, d = lm_head.shape if tied_embedding else lm_head.shape[::-1]
+    nb = math.prod(sizes[a] for a in batch)
+    if h.shape[0] % nb or d % nb or v % sizes.get(vocab, 1):
+        return None
+    return mesh, batch, vocab
+
+
+def _head_for_loop(lm_head, tied_embedding, layout):
+    """The head as the chunk loop reads it: the vocabulary on its own axes,
+    the width WHOLE. A ZeRO-3 head at rest is gathered over `data` here,
+    once, outside the `lax.scan`, which then closes over the copy (XLA
+    hoists no collective out of a `while`)."""
+    if layout is None:
+        return lm_head
+    vocab = layout[2]
+    return shard_along(lm_head, *((vocab, None) if tied_embedding
+                                  else (None, vocab)))
+
+
+def _onto_carry_shard(product, tied_embedding, layout):
+    """`product(dlogits, h_blk)` with a sharded batch's partial sums reduced
+    straight onto the carry's shard: the head's width cut over the batch's
+    axes, the vocabulary on its own. A rank multiplies ITS rows of the
+    batch, sends each peer the peer's slice of the result and adds what it
+    is sent, in float32: `(n - 1) / n` of the matrix on the wire where the
+    partitioner all-reduces all of it and then keeps a slice."""
+    mesh, batch, vocab = layout
+
+    def exchanged(dlogits, h_blk):
+        return comm.reduce_scatter_by_exchange(
+            product(dlogits, h_blk), batch, sum_dtype=jnp.float32,
+            scatter_dim=1 if tied_embedding else 0)
+
+    return jax.shard_map(
+        exchanged, mesh=mesh, in_specs=(P(batch, None, vocab), P(batch)),
+        out_specs=P(vocab, batch) if tied_embedding else P(batch, vocab))
 
 
 def _chunk_terms(h_blk, y_blk, lm_head, ignore_index, tied_embedding):
@@ -70,6 +159,9 @@ def _by_chunk(x, chunk):
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _chunked_ce(h, lm_head, labels, chunk, ignore_index, tied_embedding):
+    lm_head = _head_for_loop(lm_head, tied_embedding,
+                             _loop_layout(h, lm_head, tied_embedding))
+
     def body(loss_sum, xs):
         blk_sum, _ = _chunk_terms(*xs, lm_head, ignore_index, tied_embedding)
         return loss_sum + blk_sum, None
@@ -85,6 +177,18 @@ def _chunked_ce_fwd(h, lm_head, labels, chunk, ignore_index, tied_embedding):
     # float16 alone can overflow on a sum over a chunk's tokens
     dw_blk_dtype = (jnp.float32 if operand_dtype == jnp.float16
                     else operand_dtype)
+    layout = _loop_layout(h, lm_head, tied_embedding)
+    lm_head = _head_for_loop(lm_head, tied_embedding, layout)
+
+    def dw_product(dlogits, h_blk):
+        if tied_embedding:
+            return jnp.einsum("bcv,bcd->vd", dlogits, h_blk,
+                              preferred_element_type=dw_blk_dtype)
+        return jnp.einsum("bcd,bcv->dv", h_blk, dlogits,
+                          preferred_element_type=dw_blk_dtype)
+
+    if layout is not None and layout[1]:
+        dw_product = _onto_carry_shard(dw_product, tied_embedding, layout)
 
     def body(carry, xs):
         loss_sum, dw = carry
@@ -102,15 +206,10 @@ def _chunked_ce_fwd(h, lm_head, labels, chunk, ignore_index, tied_embedding):
         dlogits = dlogits.astype(operand_dtype)
         # a chunk's products leave the matmul in its operands' dtype, as the
         # transpose of the forward's would (and a sharded batch's partial
-        # `dw_blk` is reduced in it); the SUM over chunks is float32
-        if tied_embedding:
-            dh_blk = jnp.einsum("bcv,vd->bcd", dlogits, lm_head)
-            dw_blk = jnp.einsum("bcv,bcd->vd", dlogits, h_blk,
-                                preferred_element_type=dw_blk_dtype)
-        else:
-            dh_blk = jnp.einsum("bcv,dv->bcd", dlogits, lm_head)
-            dw_blk = jnp.einsum("bcd,bcv->dv", h_blk, dlogits,
-                                preferred_element_type=dw_blk_dtype)
+        # `dw_blk` crosses the wire in it); the SUM over chunks is float32
+        dh_blk = jnp.einsum("bcv,vd->bcd" if tied_embedding else "bcv,dv->bcd",
+                            dlogits, lm_head)
+        dw_blk = dw_product(dlogits, h_blk)
         return ((loss_sum + blk_sum, dw + dw_blk.astype(jnp.float32)),
                 dh_blk.astype(h.dtype))
 

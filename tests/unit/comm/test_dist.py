@@ -63,6 +63,39 @@ def test_all_gather_reduce_scatter_all_to_all(mesh):
                                   np.asarray(x).T.reshape(-1))
 
 
+@pytest.mark.parametrize("group,scatter_dim", [
+    ("data", 0), ("data", 1), (("data", "model"), 1), ("model", 0)],
+    ids=["data_dim0", "data_dim1", "data_x_model", "model"])
+def test_reduce_scatter_by_exchange_is_reduce_scatter(mesh, group, scatter_dim):
+    """`n - 1` permutes of the peers' slices and a local sum give
+    `psum_scatter`'s result over one axis or two, along either dimension;
+    bf16 partials summed in float32 are the float32 sum of the partials
+    (nothing is rounded between two of them)."""
+    axes = (group,) if isinstance(group, str) else group
+    spec = P(axes)
+    x = jax.random.normal(jax.random.PRNGKey(3), (8, 16, 24))
+
+    def run(fn, x, **kw):
+        out_spec = P(*([None] * scatter_dim + [axes]))
+        f = jax.shard_map(lambda v: fn(v[0], group=group,
+                                       scatter_dim=scatter_dim, **kw),
+                          mesh=mesh, in_specs=spec, out_specs=out_spec,
+                          check_vma=False)
+        return jax.jit(f)(x)
+
+    n = int(np.prod([mesh.shape[a] for a in axes]))
+    x = x[:n]
+    np.testing.assert_allclose(
+        np.asarray(run(comm.reduce_scatter_by_exchange, x)),
+        np.asarray(run(comm.reduce_scatter, x)), rtol=1e-6, atol=1e-6)
+    half = x.astype(jnp.bfloat16)
+    out = run(comm.reduce_scatter_by_exchange, half, sum_dtype=jnp.float32)
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(half.astype(jnp.float32).sum(0)),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_ppermute_ring(mesh):
     f = _smap(lambda v: comm.ppermute(
         v[0], perm=[(i, (i + 1) % 4) for i in range(4)], group="data"),

@@ -117,6 +117,80 @@ def _checkpointed_ce(h, lm_head, labels, chunk_size, ignore_index=-100,
     return loss_sum / jnp.maximum(count, 1.0)
 
 
+def _partitioner_placed_ce(h, lm_head, labels, chunk_size, ignore_index=-100,
+                           tied_embedding=False):
+    """The form the loss had until PR 52, kept as the yardstick: the same
+    chunk loop and rules with NO sharding named in it, so on a mesh the
+    partitioner places the head's gather and `dW`'s reduction itself,
+    inside the loop. On one device it is what the loss still runs."""
+    from functools import partial
+    from deepspeed_tpu.sequence.cross_entropy import (_by_chunk, _chunk_terms,
+                                                       _token_count)
+
+    @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+    def _chunked_ce(h, lm_head, labels, chunk, ignore_index, tied_embedding):
+        def body(loss_sum, xs):
+            blk_sum, _ = _chunk_terms(*xs, lm_head, ignore_index,
+                                      tied_embedding)
+            return loss_sum + blk_sum, None
+
+        loss_sum, _ = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            (_by_chunk(h, chunk), _by_chunk(labels, chunk)))
+        return loss_sum / _token_count(labels, ignore_index)
+
+    def _chunked_ce_fwd(h, lm_head, labels, chunk, ignore_index,
+                        tied_embedding):
+        operand_dtype = jnp.result_type(h.dtype, lm_head.dtype)
+        dw_blk_dtype = (jnp.float32 if operand_dtype == jnp.float16
+                        else operand_dtype)
+
+        def body(carry, xs):
+            loss_sum, dw = carry
+            h_blk, y_blk = xs
+            blk_sum, (e, e_sum, y_safe, mask) = _chunk_terms(
+                h_blk, y_blk, lm_head, ignore_index, tied_embedding)
+            mask = mask[..., None]
+            softmax_part = e * (mask / e_sum)
+            hit = y_safe[..., None] == jnp.arange(e.shape[-1])
+            dlogits = jnp.where(hit, softmax_part - mask, softmax_part)
+            dlogits = dlogits.astype(operand_dtype)
+            if tied_embedding:
+                dh_blk = jnp.einsum("bcv,vd->bcd", dlogits, lm_head)
+                dw_blk = jnp.einsum("bcv,bcd->vd", dlogits, h_blk,
+                                    preferred_element_type=dw_blk_dtype)
+            else:
+                dh_blk = jnp.einsum("bcv,dv->bcd", dlogits, lm_head)
+                dw_blk = jnp.einsum("bcd,bcv->dv", h_blk, dlogits,
+                                    preferred_element_type=dw_blk_dtype)
+            return ((loss_sum + blk_sum, dw + dw_blk.astype(jnp.float32)),
+                    dh_blk.astype(h.dtype))
+
+        (loss_sum, dw), dh = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32),
+                   jnp.zeros(lm_head.shape, jnp.float32)),
+            (_by_chunk(h, chunk), _by_chunk(labels, chunk)))
+        dh = jnp.moveaxis(dh, 0, 1).reshape(h.shape)
+        count = _token_count(labels, ignore_index)
+        return loss_sum / count, (dh, dw, count,
+                                  jnp.zeros((0,), lm_head.dtype))
+
+    def _chunked_ce_bwd(chunk, ignore_index, tied_embedding, residuals, g):
+        dh, dw, count, head_like = residuals
+        scale = g.astype(jnp.float32) / count
+        return ((scale * dh).astype(dh.dtype),
+                (scale * dw).astype(head_like.dtype), None)
+
+    _chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
+    s = h.shape[1]
+    chunk = min(chunk_size, s)
+    while s % chunk:
+        chunk -= 1
+    with jax.named_scope("chunked_ce"):
+        return _chunked_ce(h, lm_head, labels, chunk, ignore_index,
+                           tied_embedding)
+
+
 def _dense_ce(h, lm_head, labels, tied_embedding=False):
     from deepspeed_tpu.models.common import cross_entropy_loss
     logits = (jnp.einsum("bsd,vd->bsv", h, lm_head) if tied_embedding
@@ -354,32 +428,56 @@ def test_chunked_ce_is_named_in_the_trace():
     assert dots and all("chunked_ce" in l for l in dots)
 
 
-# --------------------------------------------- chunked CE on dp2 x tp2
-@pytest.mark.parametrize("head_spec", [("model", None), ("model", "data")],
-                         ids=["tp", "tp_zero3"])
-def test_chunked_ce_sharded_head_matches_single_device(head_spec):
-    """The head sharded over `model` on the vocabulary (and over `data` on
-    the width, as the ZeRO-3 plan lays it): loss and both gradients are the
-    single-device ones, the chunk loop is the only loop, and nothing is
-    gathered for the backward (the checkpointed form gathered the head a
-    second time there)."""
+# ------------------------------------------------- chunked CE on a mesh
+def _scaled_grad(loss, tied):
+    return jax.value_and_grad(lambda h, w, y: 3.0 * loss(
+        h, w, y, chunk_size=16, tied_embedding=tied), (0, 1))
+
+
+def _on_mesh(mesh_dims, tied, head_spec, args):
+    """Installs the mesh; the operands' shardings (the batch over the
+    mesh's batch axes, the head as `head_spec`) and `compiled(loss)`:
+    `_scaled_grad` with the gradients asked back in those shardings."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    h, w, labels = _ce_case(tied=True, b=4, v=128)
-
-    def fn(loss):
-        return jax.value_and_grad(lambda h, w, y: 3.0 * loss(
-            h, w, y, chunk_size=16, tied_embedding=True), (0, 1))
-
-    want_loss, want = fn(chunked_softmax_cross_entropy)(h, w, labels)
-    topo = groups.initialize(dp=2, tp=2, devices=jax.devices()[:4])
-    mesh = topo.mesh
+    n = int(np.prod(list(mesh_dims.values())))
+    mesh = groups.initialize(devices=jax.devices()[:n], **mesh_dims).mesh
+    batch = tuple(a for a in ("data", "expert") if mesh.shape[a] > 1) or None
     sh, sw, sy = (NamedSharding(mesh, P(*spec)) for spec in (
-        ("data", None, None), head_spec, ("data", None)))
+        (batch, None, None), head_spec, (batch, None)))
 
     def compiled(loss):
-        return jax.jit(fn(loss), in_shardings=(sh, sw, sy),
-                       out_shardings=(None, (sh, sw))
-                       ).lower(h, w, labels).compile()
+        return jax.jit(_scaled_grad(loss, tied), in_shardings=(sh, sw, sy),
+                       out_shardings=(None, (sh, sw))).lower(*args).compile()
+
+    return (sh, sw, sy), compiled
+
+
+@pytest.mark.parametrize("mesh_dims,tied,head_spec", [
+    (dict(dp=2, tp=2), True, ("model", None)),
+    (dict(dp=2, tp=2), True, ("model", "data")),
+    (dict(dp=2, tp=2), False, ("data", "model")),
+    (dict(dp=2, tp=2), False, (None, "model")),
+    (dict(dp=4), True, (None, "data")),
+    (dict(dp=4), True, ("data", None)),
+    (dict(dp=2, ep=2), True, (None, ("data", "expert"))),
+    (dict(tp=4), True, ("model", None)),
+    (dict(dp=2, sp=2), True, (None, "data")),
+], ids=["tp", "tp_zero3", "untied_tp_zero3", "untied_tp", "dp_width",
+        "dp_vocab", "dp_ep", "tp_only", "dp_sp"])
+def test_chunked_ce_sharded_head_matches_single_device(mesh_dims, tied,
+                                                       head_spec):
+    """The head sharded over `model` on the vocabulary (and over `data` on
+    the width, as the ZeRO-3 plan lays it), both orientations; a mesh of
+    `data` alone with the head cut either way; the batch over two axes
+    (`data` x `expert`: the exchange runs among four ranks); `model` alone
+    and a `sequence` axis (no layout of the loop's own: the partitioner's
+    program): loss and both gradients are the single-device ones and come
+    back in the operands' own shardings, the chunk loop is the only loop,
+    and nothing is gathered for the backward (the checkpointed form
+    gathered the head a second time there)."""
+    args = _ce_case(tied=tied, b=4, v=128)
+    want_loss, want = _scaled_grad(chunked_softmax_cross_entropy, tied)(*args)
+    shardings, compiled = _on_mesh(mesh_dims, tied, head_spec, args)
 
     def backward_gathers(txt):
         return [l for l in txt.splitlines()
@@ -390,15 +488,130 @@ def test_chunked_ce_sharded_head_matches_single_device(head_spec):
     txt = new.as_text()
     assert len(re.findall(r" while\(", txt)) == 1
     assert not backward_gathers(txt)
-    if head_spec[1]:    # the yardstick can tell: the old form gathers twice
+    if head_spec == ("model", "data"):  # the yardstick can tell: it gathers twice
         assert backward_gathers(compiled(_checkpointed_ce).as_text())
-    got_loss, got = new(jax.device_put(h, sh), jax.device_put(w, sw),
-                        jax.device_put(labels, sy))
+    got_loss, got = new(*(jax.device_put(x, s)
+                          for x, s in zip(args, shardings)))
     np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
-    for g, r, s in zip(got, want, (sh, sw)):
+    for g, r, s in zip(got, want, shardings):
         assert g.sharding.is_equivalent_to(s, g.ndim)
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=1e-5, atol=1e-6)
+
+
+def _computations(txt):
+    """{name: its lines} of a compiled module's text, and the name of the
+    computation each `while` runs as its body."""
+    comps, name, bodies = {}, None, []
+    for line in txt.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+        bodies += re.findall(r" while\(.*body=%?([\w.\-]+)", line)
+    return comps, bodies
+
+
+def _collectives(lines):
+    """(op, the dims of every array in its result) of each collective."""
+    found = []
+    for line in lines:
+        m = re.search(r" = (.*?) (all-gather|all-reduce|reduce-scatter|"
+                      r"all-to-all|collective-permute)(?:-start)?\(", line)
+        if m:
+            found += [(m.group(2), tuple(int(n) for n in dims.split(",")))
+                      for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))]
+    return found
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_ce_loop_holds_no_gather_and_reduces_only_the_shard(tied):
+    """The mechanism's counter, on dp2 x tp2 with the head at rest as the
+    ZeRO-3 plan lays it (vocabulary over `model`, width over `data`), read
+    in the COMPILED program by computation. Inside the chunk loop's `while`
+    body: no all-gather, and what carries `dW` over `data` has the SHARD's
+    shape `(V / tp, D / dp)` (by its bytes, whatever the op: this
+    compiler spells it collective-permute, as the chip's does), with no
+    collective of the whole `(V / tp, D)` left. Outside it: ONE all-gather
+    of the head. The partitioner's own program, the form until PR 52, is
+    the yardstick that fails each count: it reduces the whole matrix in the
+    body and gathers the head twice."""
+    v, d, dp, tp = 128, 32, 2, 2
+    head_spec = ("model", "data") if tied else ("data", "model")
+    whole = (v // tp, d) if tied else (d, v // tp)
+    shard = (v // tp, d // dp) if tied else (d // dp, v // tp)
+    _, compiled = _on_mesh(dict(dp=dp, tp=tp), tied, head_spec,
+                           _ce_case(tied=tied, b=4, v=v))
+
+    def read(loss):
+        comps, bodies = _computations(compiled(loss).as_text())
+        assert len(bodies) == 1
+        everywhere = [c for lines in comps.values()
+                      for c in _collectives(lines)]
+        return (_collectives(comps[bodies[0]]),
+                [c for c in everywhere if c == ("all-gather", whole)])
+
+    body, head_gathers = read(chunked_softmax_cross_entropy)
+    assert not [c for c in body if c[0] == "all-gather"]
+    assert shard in [dims for _, dims in body]
+    assert whole not in [dims for _, dims in body]
+    assert len(head_gathers) == 1
+    body, head_gathers = read(_partitioner_placed_ce)
+    assert ("all-reduce", whole) in body
+    assert len(head_gathers) == 2
+
+
+@pytest.mark.parametrize("installed", [False, True],
+                         ids=["no_topology", "one_device_topology"])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_ce_on_one_device_is_the_plain_program(tied, installed):
+    """With nothing to shard over, the loop's constraints and the exchange
+    are GONE, not just cheap: the lowered text of `value_and_grad` is the
+    text of the form that names no sharding, with and without a topology
+    of one device installed (train-2k runs under one)."""
+    h, w, labels = _ce_case(tied=tied)
+    groups.reset_topology()
+    if installed:
+        groups.initialize(devices=jax.devices()[:1])
+
+    def lowered(loss):
+        def step(h, w):
+            return loss(h, w, labels, chunk_size=16, tied_embedding=tied)
+        return jax.jit(jax.value_and_grad(step, (0, 1))).lower(h, w).as_text()
+
+    text = lowered(chunked_softmax_cross_entropy)
+    assert text == lowered(_partitioner_placed_ce)
+    assert "sharding" not in text and "shard_map" not in text
+
+
+@pytest.mark.parametrize("mesh_dims,b,v,d,want", [
+    (dict(dp=2, tp=2), 4, 128, 32, (("data",), "model")),
+    (dict(dp=2, ep=2, tp=2), 4, 128, 32, (("data", "expert"), "model")),
+    (dict(dp=4), 4, 100, 32, (("data",), None)),
+    (dict(tp=4), 2, 128, 32, ((), "model")),
+    (dict(dp=2, sp=2), 4, 128, 32, None),      # an axis the loop does not name
+    (dict(pp=2, dp=2), 4, 128, 32, None),
+    (dict(dp=4), 6, 128, 32, None),            # rows the axes do not divide
+    (dict(dp=4), 4, 128, 30, None),            # a width they do not divide
+    (dict(tp=4), 4, 130, 32, None),            # a vocabulary `model` does not
+    (dict(dp=1), 4, 128, 32, None),            # one device
+], ids=["dp_tp", "dp_ep_tp", "dp", "tp", "sp", "pipe", "rows", "width",
+        "vocabulary", "one_device"])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_chunked_ce_names_only_a_mesh_it_knows(tied, mesh_dims, b, v, d, want):
+    """What the loop observes: the installed mesh's axes larger than 1 and
+    whether they divide the batch, the width and the vocabulary. Anything
+    else is the partitioner's program, slower on a ZeRO-3 x TP mesh and
+    never wrong."""
+    from deepspeed_tpu.sequence.cross_entropy import _loop_layout
+    n = int(np.prod(list(mesh_dims.values())))
+    groups.initialize(devices=jax.devices()[:n], **mesh_dims)
+    h = jnp.zeros((b, 16, d))
+    w = jnp.zeros((v, d) if tied else (d, v))
+    layout = _loop_layout(h, w, tied)
+    assert (layout and layout[1:]) == want
 
 
 # ---------------------------------------------------------------- a2a in HLO
