@@ -124,6 +124,41 @@ def reduce_scatter_by_exchange(tensor, group: Union[str, Sequence[str]] = "data"
     return total
 
 
+# Ranks up to which `all_reduce_by_exchange` exchanges; past it, `psum`.
+EXCHANGE_MAX_RANKS = 2
+
+
+def all_reduce_by_exchange(tensor, group: Union[str, Sequence[str]] = "model"):
+    """`all_reduce`'s sum over a SMALL group, made of `n - 1` `ppermute`s of
+    the whole partial and a local sum; `psum` itself past
+    `EXCHANGE_MAX_RANKS`.
+
+    Every rank sends its partial to each peer and adds what it is sent in
+    the tensor's own dtype. For `n` = 2 that is one permute each way and
+    one add: the tensor once on the wire in each direction, a ring
+    all-reduce's `2 (n - 1) / n` exactly, and `a + b` on one rank is
+    `b + a` on the other, so both hold the same bits, the all-reduce's own.
+    From `n` = 3 the exchange sends `n - 1` tensors where the ring sends
+    `4 / 3` of one (`n` = 4: 3 against 1.5) and the ranks would add in
+    different orders: there it is `psum`.
+
+    Why not `psum` on two chips: on a v5e 2x2 an all-reduce is a synchronous
+    op on the device's op line, nothing runs beside it; a
+    `collective-permute` is asynchronous, and the scheduler starts it early
+    and finishes it late around whatever independent work there is (my chip
+    runs, PRs 52 and 53: `PERF.md` section 6)."""
+    import jax
+    axes = _axes(group)
+    n = jax.lax.psum(1, axes)  # of a constant: the group's size, an int
+    if n > EXCHANGE_MAX_RANKS:
+        return all_reduce(tensor, group=group)
+    total = tensor
+    for shift in range(1, n):
+        total = total + ppermute(
+            tensor, [(i, (i + shift) % n) for i in range(n)], axes)
+    return total
+
+
 def all_to_all_single(tensor, group: Union[str, None] = "sequence",
                       split_axis: int = 0, concat_axis: int = 0, tiled: bool = True):
     """lax.all_to_all; counterpart of all_to_all_single (comm/torch.py:282)."""

@@ -29,6 +29,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.common import causal_lm_loss
 from deepspeed_tpu.ops.attention import apply_rotary_emb, attention, rope_cos_sin
+from deepspeed_tpu.runtime.domino.transformer import (
+    TP_EXCHANGE, DominoTransformerLayer, exchange_layout, hold_until,
+    merge_rows, parallel_products, split_rows)
 from deepspeed_tpu.sequence.layer import DistributedAttention
 from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
@@ -68,9 +71,6 @@ class LlamaConfig:
     attention_qkv_bias: bool = False
     # InternLM-style bias on the o projection too (HF internlm `bias`)
     attention_o_bias: bool = False
-    # Domino two-chunk batch interleave for TP overlap
-    # (runtime/domino/transformer.py, which tells its measured A/B)
-    domino: bool = False
     sliding_window: Optional[int] = None
     # Explicit per-head width (HF configs with decoupled head_dim; also set
     # by structural head pruning, which shrinks the head COUNT while each
@@ -120,7 +120,9 @@ def _host_offload_policy(*extra_names: str):
 
 def _remat_policy(name: str):
     if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(TP_EXCHANGE))
     if name in ("checkpoint_dots", "checkpoint_dots_gmm"):
         # dot results plus the flash forward kernel's out and logsumexp
         # (ops/pallas/flash_attention.py names them): a pallas_call is no
@@ -131,7 +133,12 @@ def _remat_policy(name: str):
         # and not 615.5, every loss is the same bit for bit; the "18x
         # slower" of round 4 is not reproduced. A graph without the flash
         # kernel holds no such name and the policy is checkpoint_dots.
-        names = ["flash_resid", "flash_lse"]
+        # ...and a row-parallel product's summed output where the layers'
+        # tensor-parallel reductions are exchanges in a manual region
+        # (runtime/domino/transformer.py): a `shard_map` equation is no dot
+        # either, and without the name the backward ran `o_proj`,
+        # `down_proj` AND their exchanges again.
+        names = ["flash_resid", "flash_lse", TP_EXCHANGE]
         if name == "checkpoint_dots_gmm":
             # ...and the named grouped-GEMM outputs (moe/layer.py Experts
             # grouped path): megablox gmm is a Pallas call too, and without
@@ -204,9 +211,10 @@ class RMSNorm(nn.Module):
         return ((x32 * jax.lax.rsqrt(var + self.eps)) * w).astype(self.dtype)
 
 
-def _dense(features, logical, dtype, name, use_bias: bool = False):
+def _dense(features, logical, dtype, name, use_bias: bool = False,
+           dot_general=None):
     return nn.Dense(features, use_bias=use_bias, dtype=dtype,
-                    param_dtype=jnp.float32,
+                    param_dtype=jnp.float32, dot_general=dot_general,
                     kernel_init=nn.with_logical_partitioning(
                         nn.initializers.normal(0.02), logical),
                     bias_init=nn.with_logical_partitioning(
@@ -216,16 +224,23 @@ def _dense(features, logical, dtype, name, use_bias: bool = False):
 
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
+    # an `ExchangeLayout` where the layer's tensor-parallel reductions are
+    # exchanges (`_exchange_layout`), else None
+    tp: Any = None
 
     @nn.compact
     def __call__(self, h, cos, sin, kv=None, mask=None, index=None):
         cfg = self.cfg
         hd, nh, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.num_key_value_heads
         qb = cfg.attention_qkv_bias  # Qwen2-style qkv bias (o_proj stays bias-free)
-        q = _dense(nh * hd, ("embed", "heads"), cfg.dtype, "q_proj", qb)(h)
-        k = _dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "k_proj", qb)(h)
-        v = _dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "v_proj", qb)(h)
         b, s = h.shape[:2]
+        column, row = parallel_products(h, self.tp)
+        q = _dense(nh * hd, ("embed", "heads"), cfg.dtype, "q_proj", qb,
+                   column)(h)
+        k = _dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "k_proj", qb,
+                   column)(h)
+        v = _dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "v_proj", qb,
+                   column)(h)
         q = q.reshape(b, s, nh, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
@@ -272,30 +287,40 @@ class LlamaAttention(nn.Module):
             ctx = DistributedAttention(core)(q, k, v)
         ctx = ctx.reshape(b, s, nh * hd)
         return _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
-                      "o_proj", cfg.attention_o_bias)(ctx)
+                      "o_proj", cfg.attention_o_bias, row)(ctx)
 
 
 class LlamaMLP(nn.Module):
     cfg: LlamaConfig
+    tp: Any = None      # as `LlamaAttention.tp`
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, held=None):
+        """`held`: an array that no one may use before this FFN's
+        activation stands (`DominoTransformerLayer` keeps its half-batches
+        a phase apart with it); then `(out, held)` is returned."""
         cfg = self.cfg
+        column, row = parallel_products(h, self.tp)
         gate_d = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
-                        "gate_proj")
+                        "gate_proj", dot_general=column)
         up_d = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
-                      "up_proj")
+                      "up_proj", dot_general=column)
         down_d = _dense(cfg.hidden_size, ("mlp_in", "embed"), cfg.dtype,
-                        "down_proj")
+                        "down_proj", dot_general=row)
         from jax.ad_checkpoint import checkpoint_name
 
-        def ffn(hc):
+        def ffn(hc, held=None):
             # gate/up outputs are the S-proportional dot saves that OOM
             # HBM at long context — 'host_offload_dense_mlp' offloads the
             # named tensors instead so backward skips both GEMM recomputes
             g = checkpoint_name(gate_d(hc), "mlp_gate_up")
             u = checkpoint_name(up_d(hc), "mlp_gate_up")
-            return down_d(nn.silu(g) * u)
+            if held is None:
+                return down_d(nn.silu(g) * u)
+            act, held = hold_until(nn.silu(g) * u, held)
+            return down_d(act), held
+        if held is not None:
+            return ffn(h, held)
         cs = cfg.mlp_chunk_size
         if not cs or h.shape[1] <= cs or h.shape[1] % cs:
             return ffn(h)
@@ -304,6 +329,22 @@ class LlamaMLP(nn.Module):
         # die before the next chunk's are born (fwd AND transposed bwd)
         outs = [ffn(hc) for hc in jnp.split(h, h.shape[1] // cs, axis=1)]
         return jnp.concatenate(outs, axis=1)
+
+
+# a half-batch's attention, traced once for both halves
+_SharedAttention = nn.jit(LlamaAttention)
+
+
+def _exchange_layout(cfg: LlamaConfig, rows: int):
+    """The layout under which a TRAINING layer walks `rows` as two
+    half-batches with its two tensor-parallel reductions exchanged
+    (`runtime/domino/transformer.exchange_layout`: read off the installed
+    mesh, the rows and the widths `model` must divide), or None: then
+    nothing is named and the partitioner's program is what it was."""
+    if cfg.attn_impl == "ring" or cfg.mlp_chunk_size:
+        return None
+    return exchange_layout(rows, cfg.num_attention_heads,
+                           cfg.num_key_value_heads, cfg.intermediate_size)
 
 
 class LlamaBlock(nn.Module):
@@ -323,37 +364,35 @@ class LlamaBlock(nn.Module):
                         name="post_attention_layernorm")(h))
             return h, new_kv
         cos, sin = cos_sin
-        h = shard_along(h, BATCH_AXES, "sequence", None)
+        # `h` is the rows, or a PAIR of half-batches where the layers'
+        # tensor-parallel reductions are exchanges (`_exchange_layout`, cut
+        # before the layer scan): each half's exchange then lies under the
+        # other half's products (runtime/domino/transformer.py). Same
+        # params (shared module instances), and a row's products do not
+        # depend on the other rows.
+        tp = (_exchange_layout(cfg, 2 * h[0].shape[0])
+              if isinstance(h, tuple) else None)
+        h = jax.tree_util.tree_map(
+            lambda x: shard_along(x, BATCH_AXES, "sequence", None), h)
         # name the block-boundary residual so the 'host_offload' remat
         # policy can stage it to pinned host memory (no-op otherwise)
         from jax.ad_checkpoint import checkpoint_name
         h = checkpoint_name(h, "fpdt_residual")
-        attn = LlamaAttention(cfg, name="self_attn")
-        ln1 = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm")
-        mlp = LlamaMLP(cfg, name="mlp")
-        ln2 = RMSNorm(cfg.rms_norm_eps, cfg.dtype,
-                      name="post_attention_layernorm")
-        if cfg.domino and h.shape[0] >= 2:
-            # Domino (runtime/domino/transformer.py): interleave two batch
-            # halves so each half's TP output-allreduce has the OTHER
-            # half's compute to overlap with — same params (shared module
-            # instances), numerically exact (batch dim is data-parallel
-            # within the layer).
-            b = h.shape[0]
-            x0, x1 = h[: b // 2], h[b // 2:]
-            a0 = attn(ln1(x0), cos, sin)
-            a1 = attn(ln1(x1), cos, sin)
-            h0 = checkpoint_name(x0 + a0, "resid_mid")
-            m0 = mlp(ln2(h0))
-            h1 = checkpoint_name(x1 + a1, "resid_mid")
-            m1 = mlp(ln2(h1))
-            return jnp.concatenate([h0 + m0, h1 + m1], axis=0), None
-        h = h + attn(ln1(h), cos, sin)
-        # mid-block residual: saving it lets backward rebuild mlp_normed
-        # with one cheap RMSNorm instead of re-running the o-projection
-        h = checkpoint_name(h, "resid_mid")
-        h = h + mlp(ln2(h))
-        return h, None
+        # (the two halves' attention is ONE traced function, `nn.jit`: it
+        # keeps the step's tracing and lowering, which is set-up time, near
+        # what one walk costs; the FFN's two calls differ by the hold)
+        attention = LlamaAttention if tp is None else _SharedAttention
+        attn = attention(cfg, tp, name="self_attn")
+        mlp = LlamaMLP(cfg, tp, name="mlp")
+        layer = DominoTransformerLayer(
+            lambda x: attn(x, cos, sin), mlp,
+            RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm"),
+            RMSNorm(cfg.rms_norm_eps, cfg.dtype,
+                    name="post_attention_layernorm"),
+            # mid-block residual: saving it lets backward rebuild mlp_normed
+            # with one cheap RMSNorm instead of re-running the o-projection
+            mid=lambda x: checkpoint_name(x, "resid_mid"))
+        return layer(h), None
 
 
 class LlamaForCausalLM(nn.Module):
@@ -417,7 +456,12 @@ class LlamaForCausalLM(nn.Module):
             block, variable_axes={"params": 0}, split_rngs={"params": True},
             in_axes=nn.broadcast, length=cfg.num_hidden_layers,
             metadata_params={nn.meta.PARTITION_NAME: "layers"})
+        tp = _exchange_layout(cfg, h.shape[0])
+        if tp is not None:
+            h = split_rows(h, tp)
         h, _ = ScanBlocks(cfg, name="layers")(h, (cos, sin))
+        if tp is not None:
+            h = merge_rows(h, tp)
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(h)
 
         if labels is not None and cfg.loss_chunk_size:

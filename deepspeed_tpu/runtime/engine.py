@@ -39,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.comm.comms_logging import get_comms_logger
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
+from deepspeed_tpu.runtime.domino.transformer import count_exchanges
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, build_lr_schedule
 from deepspeed_tpu.runtime.precision import (
     LossScaler, LossScaleState, cast_tree, clip_grads_by_global_norm, global_grad_norm)
@@ -1016,8 +1017,17 @@ class DeepSpeedEngine:
         if name in self._jit_cache:
             out = self._jit_cache[name](state, *rest)
         else:   # the program's build, compile and first dispatch
-            with compile_span(f"train:{name}", "train"):
-                out = self._get_jit(name)(state, *rest)
+            with compile_span(f"train:{name}", "train") as found:
+                fn = self._get_jit(name)
+                # read off the traced step (the call below traces nothing
+                # again): the layers' tensor-parallel reductions that are
+                # named exchanges, 0 where the partitioner places them
+                sites = count_exchanges(fn.trace(state, *rest).jaxpr)
+                found.update(tp_exchange_sites=sites,
+                             tp_half_batches=2 if sites else 1)
+                for field, value in found.items():
+                    self.telemetry.gauge(field, value)
+                out = fn(state, *rest)
         if self._offload_manual:
             out = self._restage(out) if isinstance(out, TrainState) \
                 else (self._restage(out[0]),) + tuple(out[1:])

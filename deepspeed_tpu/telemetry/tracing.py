@@ -175,7 +175,7 @@ def _since(kind: str, t0: float) -> List[Dict[str, Any]]:
 
 @contextlib.contextmanager
 def compile_span(program: str, engine: str, phase: str = "first_dispatch",
-                 under=(None, None)) -> Iterator[None]:
+                 under=(None, None)) -> Iterator[Dict[str, Any]]:
     """Around the first dispatch of a named program (or the ahead-of-time
     compile that pins v2's layouts): one span named `compile` in the span
     store, whatever the tracer's state, with what the listener heard inside
@@ -183,6 +183,8 @@ def compile_span(program: str, engine: str, phase: str = "first_dispatch",
     said of them as sums); a `compile` event on an enabled hub. The span
     less those is the rest of a first dispatch: argument checks, the feeds'
     `device_put`, the dispatch and, where the caller fetches, the run.
+    It yields a dict: what the caller puts there of the program it traced
+    (the train engine's `tp_exchange_sites`) joins the span's fields.
     `under` is the (id, round) of the span it happens in
     (`RequestTracer.current()`)."""
     from deepspeed_tpu.telemetry.hub import get_hub
@@ -190,9 +192,10 @@ def compile_span(program: str, engine: str, phase: str = "first_dispatch",
                                                get_span_store)
     _PROGRAM.append(program)
     t0 = time.perf_counter()
+    found: Dict[str, Any] = {}   # what the caller read off the program
     try:
         with annotate(ANNOTATION_PREFIX + "compile"):
-            yield
+            yield found
     finally:
         t1 = time.perf_counter()
         _PROGRAM.pop()
@@ -206,7 +209,8 @@ def compile_span(program: str, engine: str, phase: str = "first_dispatch",
                   "cache_hits": sum(r["cache"] == "hit" for r in backend),
                   "cache_misses": sum(r["cache"] == "miss" for r in backend),
                   "cache_retrieval_s": round(
-                      sum(r.get("retrieval_s", 0.0) for r in backend), 6)}
+                      sum(r.get("retrieval_s", 0.0) for r in backend), 6),
+                  **found}
         get_span_store().add({
             "name": "compile", "t0": t0, "t1": t1, "id": next(_IDS),
             "parent": under[0], "round": under[1], "uids": None,

@@ -96,6 +96,36 @@ def test_reduce_scatter_by_exchange_is_reduce_scatter(mesh, group, scatter_dim):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_all_reduce_by_exchange_is_psum(n, dtype):
+    """Over two ranks a permute each way and one local add give `psum`'s
+    bits on BOTH ranks (`a + b` is `b + a`), in the partials' own dtype;
+    past `EXCHANGE_MAX_RANKS` the exchange's bytes lose to the ring's and
+    the function is `psum` itself, with no permute in its trace."""
+    mesh = Mesh(np.asarray(jax.devices()).reshape(8 // n, n),
+                ("data", "model"))
+    x = jax.random.normal(jax.random.PRNGKey(5), (n, 16, 24)).astype(dtype)
+
+    def run(fn):
+        return jax.shard_map(lambda v: fn(v[0], group="model")[None],
+                             mesh=mesh, in_specs=P("model"),
+                             out_specs=P("model"), check_vma=False)
+
+    got = jax.jit(run(comm.all_reduce_by_exchange))(x)
+    want = jax.jit(run(lambda v, group: comm.all_reduce(v, group=group)))(x)
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    for rank in range(1, n):    # every rank holds the same sum
+        np.testing.assert_array_equal(np.asarray(got[rank], np.float32),
+                                      np.asarray(got[0], np.float32))
+    traced = str(jax.make_jaxpr(run(comm.all_reduce_by_exchange))(x))
+    assert ("ppermute" in traced) == (n <= comm.comm.EXCHANGE_MAX_RANKS)
+    assert ("psum" in traced) == (n > comm.comm.EXCHANGE_MAX_RANKS)
+
+
 def test_ppermute_ring(mesh):
     f = _smap(lambda v: comm.ppermute(
         v[0], perm=[(i, (i + 1) % 4) for i in range(4)], group="data"),
