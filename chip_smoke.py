@@ -98,6 +98,13 @@ class Sizes:
     # queries a prefill chunk (Keye-VL-2.0's at the benchmark cell's batch
     # and length)
     sparse: Tuple[int, int, int, int, int, int, int, int, int, int]
+    # latent attention under a learned choice: layers, rows, query heads,
+    # slots, rank, rope, nope and value widths, index heads, index width,
+    # positions chosen, queries a prefill chunk (DeepSeek-V3.2's at the
+    # benchmark cell's batch and length; a quarter-chunk of queries, whose
+    # plain form's scores are 4.4 GB)
+    mla_sparse: Tuple[int, int, int, int, int, int, int, int, int, int, int,
+                      int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -113,7 +120,9 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
              ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
              mla=(1, 128, 32, 2048, 512, 64),
-             sparse=(2, 8, 4, 8, 33280, 128, 16, 128, 2048, 2048))
+             sparse=(2, 8, 4, 8, 33280, 128, 16, 128, 2048, 2048),
+             mla_sparse=(2, 8, 128, 33280, 512, 64, 128, 128, 64, 128, 2048,
+                         256))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
@@ -125,7 +134,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
              ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
              mla=(2, 3, 4, 32, 32, 8),
-             sparse=(2, 3, 2, 2, 64, 16, 4, 8, 8, 16))
+             sparse=(2, 3, 2, 2, 64, 16, 4, 8, 8, 16),
+             mla_sparse=(2, 3, 4, 64, 32, 8, 16, 16, 4, 8, 8, 16))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -199,6 +209,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, paged_kv_write, paged_prefill_attention)
     from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
+    from deepspeed_tpu.ops.pallas import mla_sparse as mlas
     from deepspeed_tpu.ops.pallas import sparse_select as sps
     from deepspeed_tpu.ops.pallas.diff_attention import (
         diff_decode_attention, diff_decode_attention_reference)
@@ -754,6 +765,74 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         functools.partial(run_sparse_prefill,
                           sps.sparse_attn_prefill_reference),
         make_sparse_prefill))
+
+    # ---- latent attention under the learned choice: a decode step over the
+    # chosen rows in both forms of its read, and a prefill chunk (choice at
+    # this family's index sizes, then the expanded flash pass) ----
+    (ml, mb, mh, mm, mrank, mrope, mdn, mdv, mhi, mdi, mtopk,
+     mchunk) = sz.mla_sparse
+
+    def make_mla_sparse_decode(key):
+        ks = jax.random.split(key, 8)
+        lengths = jax.random.randint(ks[7], (mb,), mm // 2, mm + 1, jnp.int32)
+        q_i, w = small(ks[0], (mb, mhi, mdi)), small(ks[1], (mb, mhi))
+        keys, new_i = small(ks[2], (ml, mb, 1, mm, mdi)), small(ks[3], (mb, mdi))
+        bias, kept = sps.sparse_index_select_reference(
+            q_i, w, keys, ml - 1, lengths, mtopk, new_i)
+        return (normal(ks[4], (mb, mh, mrank)), normal(ks[5], (mb, mh, mrope)),
+                normal(ks[6], (ml, mb, 1, mm, mrank + mrope)), lengths, bias,
+                kept, normal(ks[0], (mb, mrank + mrope)))
+
+    mscale = (mdn + mrope) ** -0.5
+    cases.append(KernelCase(
+        "mla_sparse_decode",
+        lambda ql, qr, lat, n, bias, kept, new: mlas.mla_sparse_decode(
+            ql, qr, lat, ml - 1, n, bias, mscale, new),
+        lambda ql, qr, lat, n, bias, kept, new:
+            mlas.mla_sparse_decode_reference(ql, qr, lat, ml - 1, n, bias,
+                                             mscale, new),
+        make_mla_sparse_decode))
+    cases.append(KernelCase(
+        "mla_sparse_decode_gathered",
+        lambda ql, qr, lat, n, bias, kept, new:
+            mlas.mla_sparse_decode_gathered(ql, qr, lat, ml - 1, n, bias, kept,
+                                            mtopk, mscale, new),
+        lambda ql, qr, lat, n, bias, kept, new:
+            mlas.mla_sparse_decode_reference(ql, qr, lat, ml - 1, n, bias,
+                                             mscale, new),
+        make_mla_sparse_decode))
+
+    def make_mla_sparse_prefill(key):
+        ks = jax.random.split(key, 7)
+        return (normal(ks[0], (mchunk, mh, mdn)),
+                normal(ks[1], (mchunk, mh, mrope)),
+                normal(ks[2], (mrank, mh, mdn + mdv)) * mrank ** -0.5,
+                small(ks[3], (mchunk, mhi, mdi)), small(ks[4], (mchunk, mhi)),
+                normal(ks[5], (ml, mb, 1, mm, mrank + mrope)),
+                small(ks[6], (ml, mb, 1, mm, mdi)))
+
+    def run_mla_sparse_prefill(attend, choose, qn, qr, w_kvb, q_i, w, lat,
+                               keys):
+        # the row's last chunk but one: a causal edge inside the slab
+        row, start = mb - 1, mm - 2 * mchunk
+        bias, kept = choose(q_i, w, keys, row, start)
+        out = attend(qn, qr, w_kvb, bias, lat, ml - 1, row, start, mscale)
+        return jnp.concatenate([out.reshape(mchunk, -1).astype(jnp.float32),
+                                kept[:, None] / mtopk], axis=1)
+
+    def choose_plain(q_i, w, keys, row, start):
+        return sps.choice_plain(q_i, w, keys[ml - 1, row, 0],
+                                start + jnp.arange(mchunk), mtopk)
+
+    cases.append(KernelCase(
+        "mla_sparse_prefill",
+        functools.partial(
+            run_mla_sparse_prefill, mlas.mla_sparse_prefill,
+            lambda q_i, w, keys, row, start: sps.sparse_prefill_choice(
+                q_i, w, keys, ml - 1, row, start, mtopk)),
+        functools.partial(run_mla_sparse_prefill,
+                          mlas.mla_sparse_prefill_reference, choose_plain),
+        make_mla_sparse_prefill))
 
     # ---- block-sparse attention (MHA; layout is static host data) ----
     sblk = min(64, sz.seq // 4)

@@ -88,7 +88,9 @@ def kv_cache_bytes(model_cfg, batch: int, max_len: int, dtype,
 
     A model whose layers keep K and V of different KINDS counts them itself
     (`kv_bytes_by_kind`): the sum is what is held. `index_kv_bytes` is no
-    kind of K and V: it lies BESIDE them and is added to either count."""
+    kind of K and V: it lies BESIDE them (or beside the latent rows that
+    stand in their place: both kinds are then named, and summed) and is
+    added to either count."""
     kinds = kv_bytes_by_kind(model_cfg, batch, max_len, dtype)
     beside = kinds.pop("index_kv_bytes", 0)
     if kinds:
@@ -109,7 +111,8 @@ def kv_bytes_by_kind(model_cfg, batch: int, max_len: int,
     `shared_kv_bytes`, full-length slabs that layers without a cache of
     their own read, `latent_kv_bytes`, the latent-attention layers' one
     row a token in place of K and V a head, and `index_kv_bytes`, the one
-    index key a token a layer that a learned selection keeps beside K and V.
+    index key a token a layer that a learned selection keeps beside K and V
+    or beside the latent rows (a model may name both, as DeepSeek-sparse).
     Empty for a model of one kind of layer: `max_len` slots a layer, all of
     it `kv_cache_bytes`."""
     own = getattr(model_cfg, "kv_bytes_by_kind", None)

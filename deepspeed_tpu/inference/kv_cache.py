@@ -316,16 +316,22 @@ class HybridCache:
       layer may be the only writer of its slab and later layers read it (a
       shared slab: the readers hold nothing of their own);
     - `window`: a ring `KVCache` (`KVCache.ring`) over the WINDOW layers;
-    - `latent`: a `LatentCache` over the latent-attention layers;
-    - `index_keys`: a `LatentCache` at the indexer's width BESIDE `kv`, over
-      the same layers and at the same length: the one key a token that a
-      learned selection scores the cache by (`ops/pallas/sparse_select.py`);
+    - `latent`: a `LatentCache` over the latent-attention layers, in K and
+      V's place (`kv` None where every attention layer is latent);
+    - `index_keys`: a `LatentCache` at the indexer's width BESIDE what the
+      attention reads, `kv` (Keye-sparse) or `latent` (DeepSeek-sparse),
+      over the same layers and at the same length: the one key a token that
+      a learned selection scores the cache by
+      (`ops/pallas/sparse_select.py`);
     - `state`: a `RecurrentState` over the recurrent layers.
 
     A kind the model has no layer of is None. Layers that keep nothing
     (expert, dense FFN, memory unit, a reader of a shared slab) have no row
-    in any. The cursors are those of the first of `kv`, `latent` that is
-    there, and every kind's are kept equal to them; `index`, `max_len` and
+    in any. The combinations served: `kv` alone or with `window` and `state`;
+    `latent` with `state`; `kv` with `index_keys`; `latent` with
+    `index_keys`. The cursors are those of what the attention reads at full
+    length (`kv` where there is one, else `latent`), and every kind's,
+    `index_keys`' too, are kept equal to them; `index`, `max_len` and
     `replace` read as a `KVCache`'s do, so the engine handles it as it
     handles that."""
 
@@ -337,6 +343,8 @@ class HybridCache:
 
     @property
     def _full(self):
+        # what the attention reads at full length; never `index_keys`, which
+        # lies beside one of the two and follows its cursors
         return self.kv if self.kv is not None else self.latent
 
     @property

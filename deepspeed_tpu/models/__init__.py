@@ -34,3 +34,5 @@ from deepspeed_tpu.models.ling_linear import (
     LingLinearConfig, LingLinearForCausalLM, ling_linear_loss_fn)
 from deepspeed_tpu.models.keye_sparse import (
     KeyeSparseConfig, KeyeSparseForCausalLM, keye_sparse_loss_fn)
+from deepspeed_tpu.models.deepseek_sparse import (
+    DeepseekSparseConfig, DeepseekSparseForCausalLM, deepseek_sparse_loss_fn)

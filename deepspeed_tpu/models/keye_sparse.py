@@ -297,30 +297,56 @@ def prefill_chunks(s: int):
     return size, [min(i * size, s - size) for i in range(-(-s // size))]
 
 
-def _embedded(cfg: KeyeSparseConfig, embed, ids):
+def _embedded(cfg, embed, ids):
     h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
     return shard_along(h, BATCH_AXES, "sequence", None)
 
 
 class _Chunks(nn.Module):
-    """`Layers` for ONE chunk of ONE row, the whole cache carried: the body
-    of the scan a prefill runs over (row, chunk) pairs. It shares `Layers`'
+    """The family's `layers` (a module class `(cfg)` called `(h, cache,
+    row)`) for ONE chunk of ONE row, the whole cache carried: the body of
+    the scan a prefill runs over (row, chunk) pairs. It shares the layers'
     scope, so the parameters are the same tree. The chunk's tokens are
     embedded here, its row's cursors move on by its length, and only its
     last position goes on (the head reads each row's last chunk's). A chunk
     drawn `back` over its row's last one starts that far before the cursor:
     those positions' K, V and index keys are written again (from the same
     tokens against the same cache) and the layers count them again."""
-    cfg: KeyeSparseConfig
+    cfg: Any
+    layers: Any
 
     @nn.compact
     def __call__(self, cache, embed, chunk):
         ids, row, back = chunk                              # (1, C), (), ()
-        layers = Layers(self.cfg)
+        layers = self.layers(self.cfg)
         nn.share_scope(self, layers)
         h, cache = layers(_embedded(self.cfg, embed, ids),
                           cache.advance_row(row, -back), row)
         return cache.advance_row(row, ids.shape[1]), h[:, -1:]
+
+
+def prefill_walk(layers, cfg, cache, embed, input_ids):
+    """A prefill from the empty cache as a scan over (row, chunk) pairs
+    (`prefill_chunks`, `_Chunks`), the parameters under `layers`: (the cache
+    filled, each row's last position's hidden state (B, 1, hidden))."""
+    b, s = input_ids.shape
+    if s > cache.max_len:
+        raise ValueError(f"a prefill of {s} positions into a cache of "
+                         f"{cache.max_len}")
+    size, starts = prefill_chunks(s)
+    n = len(starts)
+    back = jnp.asarray([i * size - at for i, at in enumerate(starts)],
+                       jnp.int32)
+    ids = jnp.stack([input_ids[:, at:at + size] for at in starts], 1)
+    walk = nn.scan(_Chunks, variable_broadcast="params",
+                   variable_axes={"counters": 0},
+                   split_rngs={"params": False},
+                   in_axes=(nn.broadcast, 0), out_axes=0)
+    cache, h = walk(cfg, layers, name="layers")(
+        cache, embed, (ids.reshape(b * n, 1, size),
+                       jnp.repeat(jnp.arange(b, dtype=jnp.int32), n),
+                       jnp.tile(back, b)))
+    return cache, h.reshape(b, n, 1, -1)[:, -1]     # each row's last chunk's
 
 
 class KeyeSparseForCausalLM(nn.Module):
@@ -339,23 +365,7 @@ class KeyeSparseForCausalLM(nn.Module):
             (cfg.vocab_size, cfg.hidden_size), F32)
         b, s = input_ids.shape
         if cache is not None and s > 1:
-            if s > cache.max_len:
-                raise ValueError(f"a prefill of {s} positions into a cache "
-                                 f"of {cache.max_len}")
-            size, starts = prefill_chunks(s)
-            n = len(starts)
-            back = jnp.asarray([i * size - at for i, at in enumerate(starts)],
-                               jnp.int32)
-            ids = jnp.stack([input_ids[:, at:at + size] for at in starts], 1)
-            walk = nn.scan(_Chunks, variable_broadcast="params",
-                           variable_axes={"counters": 0},
-                           split_rngs={"params": False},
-                           in_axes=(nn.broadcast, 0), out_axes=0)
-            cache, h = walk(cfg, name="layers")(
-                cache, embed, (ids.reshape(b * n, 1, size),
-                               jnp.repeat(jnp.arange(b, dtype=jnp.int32), n),
-                               jnp.tile(back, b)))
-            h = h.reshape(b, n, 1, -1)[:, -1]       # each row's last chunk's
+            cache, h = prefill_walk(Layers, cfg, cache, embed, input_ids)
         else:
             h, cache = Layers(cfg, name="layers")(
                 _embedded(cfg, embed, input_ids), cache)

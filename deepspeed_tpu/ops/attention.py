@@ -10,6 +10,7 @@ implementation elsewhere. Supports MHA/GQA/MQA and causal masking.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -673,6 +674,58 @@ def sparse_prefill(q, q_index, w, k_cache, v_cache, index_keys, row, start,
               k_cache.layer, row, start, topk, softmax_scale)
 
 
+def latent_sparse_decode(q_lat, q_rope, latent, lengths, bias, kept,
+                         topk: int, softmax_scale: float, new):
+    """One decode step of ABSORBED latent attention over the rows a learned
+    choice kept (`ops/pallas/mla_sparse.py`): q_lat (B, H, rank), q_rope (B,
+    H, rope), `latent` a `DenseLayer` view of the (L, B, 1, M, rank + rope)
+    stack, `lengths` (B,) live slots whose last is the step's own token,
+    staged as `new` (B, rank + rope); `bias` (B, M) and `kept` (B,) from
+    `sparse_select`. Returns the weighted sum of the chosen LATENTS (B, H,
+    rank) float32. On the chip in a one-device program the kernel, over the
+    chosen rows GATHERED where the choice drops any (`topk` under the
+    slots); elsewhere the same in plain `jax.numpy`."""
+    from deepspeed_tpu.ops.pallas import mla_sparse as ms
+    if not _one_device_kernel(ms.DECODE_NAME):
+        return ms.mla_sparse_decode_reference(
+            q_lat, q_rope, latent.stack, latent.layer, lengths, bias,
+            softmax_scale, new)
+    if ms.DECODE_GATHERS and topk < latent.stack.shape[3]:
+        return ms.mla_sparse_decode_gathered(
+            q_lat, q_rope, latent.stack, latent.layer, lengths, bias, kept,
+            topk, softmax_scale, new)
+    return ms.mla_sparse_decode(q_lat, q_rope, latent.stack, latent.layer,
+                                lengths, bias, softmax_scale, new)
+
+
+def latent_sparse_prefill(q_nope, q_rope, w_kvb, q_index, w, latent,
+                          index_keys, row, start, topk: int,
+                          softmax_scale: float):
+    """A chunk of ONE sequence's queries (q_nope (C, H, dn), q_rope (C, H,
+    rope), q_index (C, Hi, Di), w (C, Hi), positions `start ..`) against
+    sequence `row`'s slabs of the latent cache and of the index keys, which
+    already hold the chunk: each query's choice of `topk` positions up to
+    its own (`sparse_select.py`'s), and EXPANDED attention over them through
+    the up-projection `w_kvb` (rank, H, dn + dv). Returns (C, H, dv) and (C,)
+    int32, the slots each query kept. The kernels where the chip's tiling
+    takes the shapes (whole lane tiles of slots and of queries), else the
+    plain form."""
+    from deepspeed_tpu.ops.pallas import mla_sparse as ms
+    from deepspeed_tpu.ops.pallas import sparse_select as ss
+    aligned = latent.stack.shape[3] % 128 == 0 and q_nope.shape[0] % 128 == 0
+    if aligned and _one_device_kernel(ms.PREFILL_NAME):
+        bias, kept = ss.sparse_prefill_choice(
+            q_index, w, index_keys.stack, index_keys.layer, row, start, topk)
+        fn = ms.mla_sparse_prefill
+    else:
+        bias, kept = ss.choice_plain(
+            q_index, w, ss.row_of(index_keys.stack, index_keys.layer, row)[0],
+            jnp.asarray(start, jnp.int32) + jnp.arange(q_nope.shape[0]), topk)
+        fn = ms.mla_sparse_prefill_reference
+    return fn(q_nope, q_rope, w_kvb, bias, latent.stack, latent.layer, row,
+              start, softmax_scale), kept
+
+
 def rms_norm_ref(x, weight, eps: float = 1e-6):
     """RMSNorm reference (csrc/transformer/inference/csrc/rms_norm.cu analog)."""
     dtype = x.dtype
@@ -681,10 +734,51 @@ def rms_norm_ref(x, weight, eps: float = 1e-6):
     return (x32 * jax.lax.rsqrt(var + eps)).astype(dtype) * weight
 
 
-def rope_cos_sin(positions, head_dim: int, theta: float = 10000.0, dtype=jnp.float32):
-    """cos/sin tables for rotary embedding; positions (B, S) or (S,)."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched `factor` times
+    (`0.1 mscale ln(factor) + 1`; 1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, scaling) -> jnp.ndarray:
+    """The rotary pairs' frequencies under YaRN (`rope_scaling` of type
+    `yarn`: `factor`, `original_max_position_embeddings`, `beta_fast`,
+    `beta_slow`, read as attributes): a pair that turns more than
+    `beta_fast` times over the original context keeps its frequency, one
+    that turns fewer than `beta_slow` times has it divided by `factor`, a
+    linear ramp in the pair's index between the two (the published
+    `find_correction_range` / `linear_ramp_factor`)."""
+    def pair_turning(turns):        # the (fractional) pair that turns so often
+        return head_dim * math.log(scaling.original_max_position_embeddings
+                                   / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair_turning(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_turning(scaling.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                                / head_dim))
+    return inv_freq / scaling.factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def rope_cos_sin(positions, head_dim: int, theta: float = 10000.0,
+                 dtype=jnp.float32, scaling=None):
+    """cos/sin tables for rotary embedding; positions (B, S) or (S,).
+    `scaling`: None, or a YaRN `rope_scaling` (`yarn_inv_freq`): the
+    frequencies are scaled and the tables times `yarn_mscale(factor, mscale)
+    / yarn_mscale(factor, mscale_all_dim)`, which is 1 where the two are
+    equal (the softmax's own `mscale ** 2` is the caller's)."""
+    if scaling is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+        m = 1.0
+    else:
+        inv_freq = yarn_inv_freq(head_dim, theta, scaling)
+        m = yarn_mscale(scaling.factor, scaling.mscale) \
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (..., S, D/2)
+    if m != 1.0:
+        return (jnp.cos(angles) * m).astype(dtype), \
+            (jnp.sin(angles) * m).astype(dtype)
     return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
 
 

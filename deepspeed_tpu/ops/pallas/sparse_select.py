@@ -1,11 +1,16 @@
-"""Pallas TPU kernels for LEARNED sparse attention over the stacked dense
-cache: DeepSeek-Sparse-Attention's lightning indexer on grouped-query
-attention (Keye-VL-2.0, `models/keye_sparse.py`).
+"""Pallas TPU kernels for LEARNED sparse attention: DeepSeek-Sparse-
+Attention's lightning indexer, whose CHOICE is this file's for every family
+that has one, and the attention under the choice over the stacked dense
+cache (grouped-query attention: Keye-VL-2.0, `models/keye_sparse.py`; over a
+latent cache it is `ops/pallas/mla_sparse.py`'s, DeepSeek-V3.2,
+`models/deepseek_sparse.py`).
 
-Beside K and V, `(L, B, Hkv, M, D)` each, a layer caches ONE index key a
-token that all heads share, `(L, B, 1, M, Di)` (`inference/kv_cache.
-HybridCache.index_keys`). A query t scores every cached position s <= t with
-`Hi` small index heads,
+Beside what the attention reads (K and V, `(L, B, Hkv, M, D)` each, or the
+latent rows), a layer caches ONE index key a token that all heads share,
+`(L, B, 1, M, Di)` (`inference/kv_cache.HybridCache.index_keys`), a whole
+lane row wide (Keye's 64 values and zeros; DeepSeek's 128). A query t scores
+every cached position s <= t with `Hi` small index heads (16 of 64, or 64 of
+128; the kernels take either),
 
     I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])        (float32)
 
@@ -79,7 +84,11 @@ _LANES = 128
 # tiles. Read on the chip at the cell's shapes, a chunk of 2,048 queries at
 # the row's end (PERF.md, PR 51): the choice 4.02 ms at (64 queries, 640
 # slots), 3.42 at (64, 2,560), 3.25 at (128, 2,560); the attention 9.72 ms
-# at (128, 640), 8.71 at (128, 1,280), 8.45 at (256, 1,280)
+# at (128, 640), 8.71 at (128, 1,280), 8.45 at (256, 1,280). At 64 index
+# heads of 128 and a row of 25,600 slots (PERF.md, PR 54) the choice reads
+# 5.99 ms at (128 queries, 2,560 slots), 6.12 at (64, 2,560), 5.90 at (128,
+# 1,280), and 256 queries pass the kernel's VMEM; a decode step's choice
+# 0.117 ms a layer at (8 rows, 1,280 slots), 71% of its roofline: kept
 SELECT_BLOCK = 1280
 DECODE_BLOCK = 1280
 CHOICE_BLOCK = 2560
@@ -314,9 +323,29 @@ def chosen(scores, live, topk: int):
     return kept.reshape(scores.shape)
 
 
+def choice_plain(q_index, w, index_keys, positions, topk: int):
+    """A chunk's choice in plain `jax.numpy`: the queries q_index (C, Hi,
+    Di), w (C, Hi) at `positions` (C,) against one sequence's index keys (M,
+    Di), whose slot IS the position. Returns the bias (C, M) float32, 0 at
+    the kept slots and `NEG_INF` elsewhere, and (C,) int32, the slots each
+    query kept."""
+    live = jnp.arange(index_keys.shape[0])[None, :] <= positions[:, None]
+    kept = chosen(index_scores(q_index, w, index_keys), live, topk)
+    return jnp.where(kept, 0.0, NEG_INF).astype(F32), \
+        jnp.sum(kept, axis=-1, dtype=jnp.int32)
+
+
 def _layer_of(stack, layer):
     return jax.lax.dynamic_index_in_dim(stack, jnp.asarray(layer, jnp.int32),
                                         0, keepdims=False)
+
+
+def row_of(stack, layer, row):
+    """Sequence `row`'s slab of layer `layer` of a stack (L, B, heads, M,
+    width): (heads, M, width)."""
+    return jax.lax.dynamic_index_in_dim(
+        _layer_of(stack, layer), jnp.asarray(row, jnp.int32), 0,
+        keepdims=False)
 
 
 def sparse_index_select_reference(q, w, stack, layer, lengths, topk, new):
@@ -696,10 +725,7 @@ def sparse_attn_prefill_reference(q, q_index, w, k_stack, v_stack,
                                   index_stack, layer, row, start, topk,
                                   softmax_scale):
     """`sparse_attn_prefill` in plain `jax.numpy`, float32."""
-    def of(stack):
-        return jax.lax.dynamic_index_in_dim(
-            _layer_of(stack, layer), jnp.asarray(row, jnp.int32), 0,
-            keepdims=False)
+    of = functools.partial(row_of, layer=layer, row=row)
     positions = jnp.asarray(start, jnp.int32) + jnp.arange(q.shape[0])
     o, kept = sparse_attention_plain(
         q, q_index, w, of(k_stack), of(v_stack), of(index_stack)[0],

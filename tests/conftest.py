@@ -50,6 +50,7 @@ def devices():
 TRACED_REHEARSALS = frozenset((
     "test_the_traced_rehearsal_of_the_cell_runs_on_the_cpu",
     "test_the_traced_rehearsal_of_the_keye_cell_runs_on_the_cpu",
+    "test_the_traced_rehearsal_of_the_deepseek_cell_runs_on_the_cpu",
     "test_traced_rehearsal_lists_every_new_program_metric",
     "test_setup_metrics_in_the_other_kinds_of_cell",
     "test_traced_rehearsal_reads_no_device_metric",
@@ -93,6 +94,7 @@ LONGEST_FIRST = (
     "tests/unit/inference/test_kv_pool_in_place.py",
     "tests/perfbench/test_keye_cell.py",
     "tests/unit/models/test_keye_sparse.py",
+    "tests/unit/models/test_deepseek_sparse.py",
     "tests/perfbench/test_ling_cell.py",
     "tests/unit/ops/test_chip_compile.py",
     "tests/perfbench/test_oracle.py",

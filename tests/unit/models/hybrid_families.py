@@ -1,8 +1,9 @@
-"""What the tests of the four hybrid families share (`test_nemotron_h.py`,
-`test_phi4flash.py`, `test_ling_linear.py`, `test_keye_sparse.py`): each
+"""What the tests of the five hybrid families share (`test_nemotron_h.py`,
+`test_phi4flash.py`, `test_ling_linear.py`, `test_keye_sparse.py`,
+`test_deepseek_sparse.py`): each
 family at a small size on seeded weights with the benchmark's plain float32
 reference beside it, the model's `apply` under ONE `jax.jit`, the walk
-through the caches, and the questions asked of all four alike, written once (`the_plain_forward_...`,
+through the caches, and the questions asked of all five alike, written once (`the_plain_forward_...`,
 `the_loss_...`, `prefill_then_decode_...`: LOGITS not tokens, each family
 held to its own tolerance in its own way, `Family.close`). Each family's
 file asks them under its own test names: a file is one worker's under
@@ -25,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import keye_sparse, ling_linear, nemotron_h, phi4flash
+from deepspeed_tpu.models import (deepseek_sparse, keye_sparse, ling_linear,
+                                  nemotron_h, phi4flash)
 from perfbench.manifest import Manifest
 
 
@@ -113,6 +115,7 @@ NEMOTRON_TOL = 2e-5     # RELATIVE to the largest logit (magnitude 0.5: 1e-6)
 PHI4_TOL = 2e-5
 LING_TOL = 5e-6         # read 6e-7
 KEYE_TOL = 3e-6         # read 4e-7
+DEEPSEEK_TOL = 5e-6
 
 NEMOTRON_CFG = nemotron_h.NemotronHConfig(
     vocab_size=128, hidden_size=64, num_hidden_layers=6,
@@ -147,6 +150,25 @@ KEYE_SIZES = dict(
     rms_norm_eps=1e-6, max_position_embeddings=4096,
     sa_config=dict(indexer_num_heads=4, indexer_head_dim=8,
                    indexer_num_kv_heads=1, topk=8))
+# the file's keys, as the reference and the adapter read them: 8 positions
+# chosen of up to 40, a dense layer and two expert layers, experts 4-7 of 16
+# held: the second half of group 0 and the first half of group 1 of 4 groups
+DEEPSEEK_SIZES = dict(
+    vocab_size=128, hidden_size=64, num_hidden_layers=3, intermediate_size=96,
+    first_k_dense_replace=1, num_attention_heads=4, num_key_value_heads=4,
+    q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, rope_theta=10000.0, index_n_heads=4, index_head_dim=16,
+    index_topk=8, n_routed_experts=4, router_experts=16, expert_offset=6,
+    num_experts_per_tok=4, moe_intermediate_size=32, n_shared_experts=1,
+    routed_scaling_factor=2.5, norm_topk_prob=True, n_group=4, topk_group=2,
+    router_bias_scale=0.01, rms_norm_eps=1e-6, max_position_embeddings=4096,
+    num_nextn_predict_layers=0,
+    # stretched from an original context of 16: of the 4 rotary pairs one
+    # keeps its frequency, one is divided by 40, two lie on the ramp, inside
+    # the tests' 40 positions
+    rope_scaling=dict(type="yarn", factor=40, beta_fast=32, beta_slow=1,
+                      mscale=1, mscale_all_dim=1,
+                      original_max_position_embeddings=16))
 PHI4_CFG = phi4flash.Phi4FlashConfig(**PHI4_SIZES, dtype=jnp.float32)
 LING_CFG = ling_linear.LingLinearConfig(**LING_SIZES, dtype=jnp.float32)
 
@@ -216,33 +238,46 @@ def _ling_linear():
                   reference_logits, close, ling_linear.ling_linear_loss_fn)
 
 
-def _keye_sparse():
+def _learned_choice(name, module, sizes, tol, loss_fn):
+    """A family whose attention attends a learned choice (`<name>_adapter`,
+    `<name>_reference` under `perfbench/configs/`): the small parameters off
+    their initial values (the norms' weights, the index key's LayerNorm:
+    weight 1, bias 0), and the index projections at a range at which the
+    choice really chooses (as seeded every index score is near 0)."""
     manifest = Manifest()
-    ref = manifest.module("configs", "keye_sparse_reference")
-    cfg = manifest.module("configs", "keye_sparse_adapter").model_config(
-        KEYE_SIZES, dtype=jnp.float32, dispatch_impl="gmm")
-    model, params = keye_sparse.materialize_params(cfg, jax.random.PRNGKey(0))
-    # off their initial values: the norms' weights, the index key's
-    # LayerNorm (weight 1, bias 0); and the index projections at a range at
-    # which the choice really chooses (as seeded every index score is near 0)
+    ref = manifest.module("configs", name + "_reference")
+    cfg = manifest.module("configs", name + "_adapter").model_config(
+        sizes, dtype=jnp.float32, dispatch_impl="gmm")
+    model, params = module.materialize_params(cfg, jax.random.PRNGKey(0))
     params = moved(params, 600)
     params = jax.tree_util.tree_map_with_path(
         lambda path, x: x * 20.0 if "index_" in jax.tree_util.keystr(path)
         and "kernel" in jax.tree_util.keystr(path) else x, params)
     ids = jax.random.randint(jax.random.PRNGKey(1), (3, 40), 1, 128)
 
-    def reference_logits(params, ids, sizes=KEYE_SIZES):
+    def reference_logits(params, ids, sizes=sizes):
         return np.asarray(ref.logits_at(params, ids,
                                         list(range(ids.shape[1])), sizes))
 
     def close(got, want):
-        np.testing.assert_allclose(np.asarray(got), want, atol=KEYE_TOL)
-    return Family(ref, cfg, KEYE_SIZES, model, params, ids,
-                  reference_logits(params, ids), 64,
-                  reference_logits, close, keye_sparse.keye_sparse_loss_fn)
+        np.testing.assert_allclose(np.asarray(got), want, atol=tol)
+    return Family(ref, cfg, sizes, model, params, ids,
+                  reference_logits(params, ids), 64, reference_logits, close,
+                  loss_fn)
 
 
-FAMILIES = {"nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
+def _keye_sparse():
+    return _learned_choice("keye_sparse", keye_sparse, KEYE_SIZES, KEYE_TOL,
+                           keye_sparse.keye_sparse_loss_fn)
+
+
+def _deepseek_sparse():
+    return _learned_choice("deepseek_sparse", deepseek_sparse, DEEPSEEK_SIZES,
+                           DEEPSEEK_TOL,
+                           deepseek_sparse.deepseek_sparse_loss_fn)
+
+
+FAMILIES = {"deepseek_sparse": _deepseek_sparse, "nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
             "ling_linear": _ling_linear, "keye_sparse": _keye_sparse}
 
 

@@ -40,7 +40,10 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "latent_write_dense": "latent_write_dense",
                "sparse_index_select": "sparse_index_select",
                "sparse_attn_decode": "sparse_attn_decode",
-               "sparse_attn_prefill": "sparse_attn_prefill"}
+               "sparse_attn_prefill": "sparse_attn_prefill",
+               "mla_sparse_decode": "mla_sparse_decode",
+               "mla_sparse_decode_gathered": "mla_sparse_decode",
+               "mla_sparse_prefill": "mla_sparse_prefill"}
 
 
 @pytest.fixture(scope="module")
@@ -69,12 +72,12 @@ def compiled_kernels(monkeypatch):
     steer them to the compiled path here, in the test."""
     from deepspeed_tpu.ops.pallas import (
         block_sparse_attention, decode_attention, diff_attention,
-        flash_attention, grouped_gemm, kda, mla, paged_attention,
+        flash_attention, grouped_gemm, kda, mla, mla_sparse, paged_attention,
         quantized_matmul, sparse_select, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
     for mod in (block_sparse_attention, decode_attention, diff_attention,
-                flash_attention, grouped_gemm, kda, mla, paged_attention,
-                quantized_matmul, sparse_select, ssm):
+                flash_attention, grouped_gemm, kda, mla, mla_sparse,
+                paged_attention, quantized_matmul, sparse_select, ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
