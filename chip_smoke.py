@@ -62,6 +62,9 @@ class Sizes:
     block: int                 # KV block size
     # kernel-case shapes beyond the model's own widths
     flash_long: int
+    # flash attention at Qwen2.5-0.5B's heads: query heads, KV heads, head
+    # width (the benchmark's train-2k cell's; the preset's own width is 128)
+    flash_narrow: Tuple[int, int, int]
     decode_batch: int
     decode_ctx: int
     # the stacked dense cache of a v1 program, as the benchmark's two
@@ -110,7 +113,8 @@ class Sizes:
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              micro_batch=2, global_batch=8, prompt_lens=(96, 352),
              prompts_per_len=4, new_tokens=32, v2_slots=4, v2_max_seq=1024,
-             v2_chunk=256, block=256, flash_long=32768, decode_batch=32,
+             v2_chunk=256, block=256, flash_long=32768,
+             flash_narrow=(14, 2, 64), decode_batch=32,
              decode_ctx=1024,
              dense_stack=((36, 32, 8, 1280), (2, 64, 16, 1024)),
              paged_batch=64, paged_blocks=96,
@@ -126,7 +130,8 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
-             v2_chunk=16, block=16, flash_long=128, decode_batch=2,
+             v2_chunk=16, block=16, flash_long=128, flash_narrow=(6, 2, 8),
+             decode_batch=2,
              decode_ctx=64, dense_stack=((3, 2, 8, 64), (2, 4, 16, 32)),
              paged_batch=3, paged_blocks=9, prefill_batch=2,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
@@ -231,7 +236,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         return jax.random.normal(key, shape, jnp.float32).astype(dtype)
 
     # ---- flash attention (training; GQA) ----
-    def qkv(b, s):
+    def qkv(b, s, h=h, hkv=hkv, d=d):
         def make(key):
             kq, kk, kv = jax.random.split(key, 3)
             return (normal(kq, (b, s, h, d)), normal(kk, (b, s, hkv, d)),
@@ -242,8 +247,14 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         # a fixed non-uniform cotangent, so dq/dk/dv are not degenerate
         def loss(q, k, v):
             out = attn(q, k, v, causal=True).astype(jnp.float32)
-            return jnp.sum(out * jnp.cos(jnp.arange(d, dtype=jnp.float32)))
+            return jnp.sum(out * jnp.cos(
+                jnp.arange(out.shape[-1], dtype=jnp.float32)))
         return jax.grad(loss, argnums=(0, 1, 2))
+
+    def blockwise_rows(q, k, v, causal):
+        # 256 query rows a block: what its backward keeps is a block's
+        # scores against the whole row, 0.5 GB a tensor at 32k
+        return blockwise_attention(q, k, v, causal=causal, block_q=256)
 
     cases += [
         KernelCase(f"flash_fwd_s{sz.seq}",
@@ -259,6 +270,16 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                    lambda q, k, v: flash_attention(q, k, v, causal=True),
                    lambda q, k, v: blockwise_attention(q, k, v, causal=True),
                    qkv(1, sz.flash_long)),
+        # the backward ONE kernel at head width 64 (train-2k's shape), and
+        # past the length whose dq stays in VMEM: the two-pass form
+        KernelCase(f"flash_fwd_bwd_s{sz.seq}_d{sz.flash_narrow[2]}",
+                   flash_loss(flash_attention),
+                   flash_loss(reference_attention),
+                   qkv(sz.micro_batch, sz.seq, *sz.flash_narrow),
+                   tol=BWD_TOL),
+        KernelCase(f"flash_fwd_bwd_s{sz.flash_long}",
+                   flash_loss(flash_attention), flash_loss(blockwise_rows),
+                   qkv(1, sz.flash_long), tol=BWD_TOL),
     ]
 
     # ---- dense decode (v1): one query per row over a padded cache ----

@@ -13,8 +13,16 @@ Design (standard flash attention 2 tiling, MXU-sized blocks):
   — no materialized `repeat_kv`;
 - causal blocks are predicated out with `pl.when` (upper-triangular block
   tiles never touch the MXU);
-- backward = separate dq and dk/dv kernels using the saved logsumexp plus
-  delta = rowsum(dO * O), the flash-2 recurrence.
+- backward = ONE kernel from the saved logsumexp plus delta =
+  rowsum(dO * O), the flash-2 recurrence: it walks the live block pairs kv
+  block by kv block, computes a pair's s, p, dp and ds once, and sums all
+  three gradients from them — dk/dv in a scratch a kv block, dq in a float32
+  scratch that holds the (batch, head)'s WHOLE query length (0.5 MB at
+  2048 x 64), zeroed at the walk's first step and written at its last. Five
+  products a pair. Past `ONE_PASS_DQ_BYTES` of resident dq (long-sequence
+  training) `_bwd` takes the two-pass form, separate dq and dk/dv kernels
+  that each compute the score tile (seven products), and says so
+  (`flash_bwd_two_pass`).
 
 Forward returns logsumexp as a residual for the backward pass.
 """
@@ -36,6 +44,19 @@ from deepspeed_tpu.ops.pallas import _interpret
 # 2048 overflows VMEM with the fp32 (blk_q, blk_k) logits tile.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+# The backward is ONE kernel while a (batch, head)'s whole dq, (sq, d)
+# float32 with d padded to a lane row, fits this many bytes of VMEM beside
+# the dk/dv walk: sq up to 16k at d <= 128. Past it `_bwd` takes the
+# two-pass form. Compiled for a described v5e (PR 55): the walk's own tiles
+# at blocks of 1024 take 10.3 MiB of the 16 MiB a kernel gets by default,
+# and the resident dq costs its scratch and a double-buffered output block
+# on top (8 bytes an element at bf16), so 4096 x 128 fits the default, 8192
+# does not (18.3 MiB) and the one-pass call asks for SCOPED_VMEM_BYTES plus
+# what it keeps. On the chip at 16384 x 128 (the scratch at this budget, a
+# 32 MiB limit of the 128 MiB the core has): 17.8 ms a call against the
+# two-pass form's 24.6, gradients bit for bit. Longer was not measured.
+ONE_PASS_DQ_BYTES = 8 * 1024 * 1024
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 NEG_INF = -1e30
 # The kernels work in the BASE-2 exponent domain: log2(e)·softmax_scale is
 # folded into q once outside, p = exp2(s2 − m2), and the saved lse residual
@@ -173,28 +194,52 @@ def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
-def _dq_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_scr,
-               mask_ij=None):
-    """dq accumulation for one block pair. qs pre-scaled (base-2 domain):
-    p = exp2(s2 − lse2) is the exact softmax probability; ds_raw carries no
-    scale — dq multiplies softmax_scale once at finalize."""
-    k = k_ref[0, 0]
+def _bwd_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
+                dq_scr=None, dq_block=None, dk_scr=None, dv_scr=None,
+                mask_ij=None, delta_from_o=False):
+    """The backward of one block pair: s, p, dp and ds are computed ONCE
+    and feed whichever float32 accumulators the kernel holds — `dq_scr`
+    (all of it, or query block `dq_block`'s rows of a resident (sq, d)
+    one), `dk_scr`, `dv_scr`. qs arrives pre-scaled (base-2 domain):
+    p = exp2(s2 − lse2) is the exact softmax probability and ds_raw carries
+    no scale, so dq takes softmax_scale and dk takes ln2
+    (dL/dk = scale·ds_rawᵀ·q = ln2·ds_rawᵀ·qs) once, at finalize."""
+    q, k = q_ref[0, 0], k_ref[0, 0]
     do = do_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = _apply_causal_mask(s, mask_ij)
-    p = jnp.exp2(s - lse_ref[0, 0])
+    p = jnp.exp2(s - lse_ref[0, 0])  # (blk_q, blk_k)
+    if dv_scr is not None:
+        dv_scr[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
                              (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0, 0])
-    dq_scr[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+    if delta_from_o:  # `delta_ref` is the forward's output block
+        delta = jnp.sum(do * delta_ref[0, 0].astype(jnp.float32), axis=-1,
+                        keepdims=True)
+    else:
+        delta = delta_ref[0, 0]
+    ds = p * (dp - delta)
+    if dk_scr is not None:
+        dk_scr[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    if dq_scr is not None:
+        rows = slice(None) if dq_block is None else pl.ds(
+            pl.multiple_of(dq_block * q.shape[0], q.shape[0]), q.shape[0])
+        dq_scr[rows, :] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
                *, scale, causal, blk_q, blk_k, nk, offset=0):
+    """dq alone over (b, h, nq, nk): the first of the TWO-PASS backward's
+    kernels, which `_bwd` keeps for query lengths whose dq no longer fits
+    in VMEM beside the dk/dv walk."""
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -202,9 +247,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    args = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_scr)
+    update = functools.partial(_bwd_update, q_ref, k_ref, v_ref, do_ref,
+                               lse_ref, delta_ref, dq_scr=dq_scr)
     if not causal:
-        _dq_update(*args)
+        update()
     else:
         full = j * blk_k + blk_k - 1 <= i * blk_q + offset
         partial = jnp.logical_and(
@@ -213,11 +259,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
 
         @pl.when(full)
         def _full():
-            _dq_update(*args)
+            update()
 
         @pl.when(partial)
         def _partial():
-            _dq_update(*args, mask_ij=(offset + i * blk_q, j * blk_k))
+            update(mask_ij=(offset + i * blk_q, j * blk_k))
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -226,7 +272,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
 
 def _dq_kernel_tri(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_scr, *, scale, blk, n):
-    """Causal dq over the triangular grid (see _fwd_kernel_tri)."""
+    """Two-pass causal dq over the triangular grid (see _fwd_kernel_tri)."""
     t = pl.program_id(2)
     i, j = _tri_row(t, n)
 
@@ -234,54 +280,53 @@ def _dq_kernel_tri(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    args = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_scr)
+    update = functools.partial(_bwd_update, q_ref, k_ref, v_ref, do_ref,
+                               lse_ref, delta_ref, dq_scr=dq_scr)
 
     @pl.when(j < i)
     def _interior():
-        _dq_update(*args)
+        update()
 
     @pl.when(j == i)
     def _diag():
-        _dq_update(*args, mask_ij=(i * blk, j * blk))
+        update(mask_ij=(i * blk, j * blk))
         dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_scr, dv_scr, mask_ij=None):
-    """dk/dv accumulation for one block pair. With qs pre-scaled,
-    dL/dk = scale·ds_rawᵀ·q = ln2·ds_rawᵀ·qs — the ln2 lands at finalize."""
-    q = q_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k_ref[0, 0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = _apply_causal_mask(s, mask_ij)
-    p = jnp.exp2(s - lse_ref[0, 0])  # (blk_q, blk_k)
-    dv_scr[:] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0, 0])
-    dk_scr[:] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _walk_refs(refs, one_pass):
+    """(dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr) of a dk/dv walk's
+    outputs and scratch; the dq pair is None in the two-pass form."""
+    return refs if one_pass else (None, *refs[:2], None, *refs[2:])
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, blk_q, blk_k, nq, offset=0):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                scale, one_pass, causal, blk_q, blk_k, nq, nk, offset=0):
+    """The dk/dv walk over (b, h, nk, nq), q blocks innermost. In the
+    ONE-PASS backward dq joins it: a float32 scratch holds the whole query
+    length of the (batch, head), zeroed at the walk's first step and
+    written at its last; a block pair's `ds` feeds all three gradients, and
+    `delta_ref` is the forward's output block. Else this is the second
+    kernel of the two-pass form."""
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = _walk_refs(refs, one_pass)
     j = pl.program_id(2)  # kv block
     i = pl.program_id(3)  # q block (sequential axis)
+
+    if one_pass:
+        @pl.when(jnp.logical_and(i == 0, j == 0))
+        def _init_dq():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
 
     @pl.when(i == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    args = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_scr, dv_scr)
+    update = functools.partial(
+        _bwd_update, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        dq_scr=dq_scr, dq_block=i, dk_scr=dk_scr, dv_scr=dv_scr,
+        delta_from_o=one_pass)
     if not causal:
-        _dkv_update(*args)
+        update()
     else:
         # a kv block is fully unmasked for q block i when every qi in the
         # block is at or past the block's last key
@@ -292,41 +337,62 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         @pl.when(full)
         def _full():
-            _dkv_update(*args)
+            update()
 
         @pl.when(partial)
         def _partial():
-            _dkv_update(*args, mask_ij=(offset + i * blk_q, j * blk_k))
+            update(mask_ij=(offset + i * blk_q, j * blk_k))
 
     @pl.when(i == nq - 1)
     def _finalize():
         dk_ref[0, 0] = (dk_scr[:] * LN2).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
+    if one_pass:
+        @pl.when(jnp.logical_and(i == nq - 1, j == nk - 1))
+        def _finalize_dq():
+            dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
-def _dkv_kernel_tri(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, blk, n):
-    """Causal dk/dv over the triangular grid: column-major enumeration —
-    for kv block j, q blocks i = j..n−1 (the diagonal block first)."""
+
+def _bwd_kernel_tri(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                    scale, one_pass, blk, n):
+    """The causal dk/dv walk over the triangular grid: column-major
+    enumeration — for kv block j, q blocks i = j..n−1 (the diagonal block
+    first). `one_pass` as in `_bwd_kernel`: dq joins the walk (a query
+    block's dq sums its kv blocks in ascending j, the two-pass order)."""
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = _walk_refs(refs, one_pass)
     t = pl.program_id(2)
     i, j = _tri_col(t, n)
+
+    if one_pass:
+        @pl.when(t == 0)
+        def _init_dq():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    update = functools.partial(
+        _bwd_update, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        dq_scr=dq_scr, dq_block=i, dk_scr=dk_scr, dv_scr=dv_scr,
+        delta_from_o=one_pass)
 
     @pl.when(i == j)
     def _init_and_diag():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
-        _dkv_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_scr, dv_scr, mask_ij=(i * blk, j * blk))
+        update(mask_ij=(i * blk, j * blk))
 
     @pl.when(i > j)
     def _interior():
-        _dkv_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_scr, dv_scr)
+        update()
 
     @pl.when(i == n - 1)
     def _finalize():
         dk_ref[0, 0] = (dk_scr[:] * LN2).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if one_pass:
+        @pl.when(t == n * (n + 1) // 2 - 1)
+        def _finalize_dq():
+            dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _pick_blocks(sq, sk, blk_q, blk_k):
@@ -416,6 +482,25 @@ def _fwd(qs, k, v, causal, blk_q, blk_k):
     return out, lse
 
 
+def _announce_two_pass(sq, d):
+    """The two-pass backward ran for length: say so once a shape (the
+    shared `warn_once` registry) and on the telemetry hub, so a
+    long-sequence user can see which form their step took."""
+    from deepspeed_tpu.utils.logging import warn_once
+    warn_once(("flash_bwd_two_pass", sq, d),
+              f"flash attention backward: the float32 dq of {sq} queries x "
+              f"{d} passes ONE_PASS_DQ_BYTES={ONE_PASS_DQ_BYTES} of VMEM; "
+              "taking the two-pass form (separate dq and dk/dv kernels, the "
+              "score tile computed twice)")
+    try:
+        from deepspeed_tpu.telemetry import get_hub
+        hub = get_hub()
+        if hub.enabled:
+            hub.emit("flash_bwd_two_pass", sq=sq, d=d)
+    except Exception:  # telemetry must never break a trace
+        pass
+
+
 def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
     """qs is the pre-scaled query (matches the saved forward residual)."""
     b, h, sq, d = qs.shape
@@ -425,128 +510,118 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
     nq, nk = sq // blk_q, sk // blk_k
     offset = sk - sq
 
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # (b,h,sq,1)
     tri = _use_tri(causal, sq, sk, blk_q, blk_k)
+    dq_lanes = sq * -(-d // 128) * 128  # VMEM pads the minor dim to a lane row
+    one_pass = dq_lanes * 4 <= ONE_PASS_DQ_BYTES
+    if one_pass:  # delta is made in the kernel, a pair at a time, from o
+        operands = (qs, k, v, do, lse, o)
+    else:
+        _announce_two_pass(sq, d)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1, keepdims=True)  # (b,h,sq,1)
+        operands = (qs, k, v, do, lse, delta)
     dq_shape = jax.ShapeDtypeStruct((b, h, sq, d), qs.dtype)
-    dkv_shape = [jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
-                 jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32)]
 
-    if tri:
-        n = nq
-
-        def qrow_ix(b_, h_, t):
-            return (b_, h_, _tri_row(t, n)[0], 0)
-
-        def kvrow_ix(b_, h_, t):
-            return (b_, h_ // n_rep, _tri_row(t, n)[1], 0)
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel_tri, scale=scale, blk=blk_q, n=n),
-            grid=(b, h, n * (n + 1) // 2),
-            in_specs=[pl.BlockSpec((1, 1, blk_q, d), qrow_ix),
-                      pl.BlockSpec((1, 1, blk_k, d), kvrow_ix),
-                      pl.BlockSpec((1, 1, blk_k, d), kvrow_ix),
-                      pl.BlockSpec((1, 1, blk_q, d), qrow_ix),
-                      pl.BlockSpec((1, 1, blk_q, 1), qrow_ix),
-                      pl.BlockSpec((1, 1, blk_q, 1), qrow_ix)],
-            out_specs=pl.BlockSpec((1, 1, blk_q, d), qrow_ix),
-            out_shape=dq_shape,
-            scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
+    def call(kernel, grid, in_specs, out_specs, out_shape, scratch,
+             carried=1, vmem_limit=None):
+        """`carried` trailing grid axes carry an accumulator (sequential)."""
+        semantics = (("parallel",) * (len(grid) - carried)
+                     + ("arbitrary",) * carried)
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
-            name="self_attn_flash_bwd",
-        )(qs, k, v, do, lse, delta)
+                dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
+            interpret=_interpret(), name="self_attn_flash_bwd")(*operands)
 
-        def qcol_ix(b_, h_, t):
-            return (b_, h_, _tri_col(t, n)[0], 0)
+    def pair_specs(q_ix, kv_ix):
+        """in_specs of `operands` for a walk's index maps."""
+        q_spec = pl.BlockSpec((1, 1, blk_q, d), q_ix)
+        kv_spec = pl.BlockSpec((1, 1, blk_k, d), kv_ix)
+        row_spec = pl.BlockSpec((1, 1, blk_q, 1), q_ix)
+        return [q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                q_spec if one_pass else row_spec]
 
-        def kvcol_ix(b_, h_, t):
-            return (b_, h_ // n_rep, _tri_col(t, n)[1], 0)
+    if not one_pass:  # dq in a kernel of its own, q blocks outermost
+        if tri:
+            dq_kernel = functools.partial(_dq_kernel_tri, scale=scale,
+                                          blk=blk_q, n=nq)
+            dq_grid = (b, h, nq * (nq + 1) // 2)
+
+            def q_ix(b_, h_, t):
+                return (b_, h_, _tri_row(t, nq)[0], 0)
+
+            def kv_ix(b_, h_, t):
+                return (b_, h_ // n_rep, _tri_row(t, nq)[1], 0)
+        else:
+            dq_kernel = functools.partial(
+                _dq_kernel, scale=scale, causal=causal, blk_q=blk_q,
+                blk_k=blk_k, nk=nk, offset=offset)
+            dq_grid = (b, h, nq, nk)
+
+            def q_ix(b_, h_, i, j):
+                return (b_, h_, i, 0)
+
+            def kv_ix(b_, h_, i, j):
+                if causal:  # elide causally-dead kv DMAs (see _fwd)
+                    hi = (i * blk_q + blk_q - 1 + offset) // blk_k
+                    j = jnp.minimum(j, hi)
+                return (b_, h_ // n_rep, j, 0)
+        dq = call(dq_kernel, dq_grid, pair_specs(q_ix, kv_ix),
+                  pl.BlockSpec((1, 1, blk_q, d), q_ix), dq_shape,
+                  [pltpu.VMEM((blk_q, d), jnp.float32)])
+
+    # the dk/dv walk, kv blocks outermost: one (dk, dv) per *query* head,
+    # summed over the GQA group outside; in the one-pass form dq rides it
+    if tri:
+        kernel = functools.partial(_bwd_kernel_tri, scale=scale,
+                                   one_pass=one_pass, blk=blk_q, n=nq)
+        grid = (b, h, nq * (nq + 1) // 2)
+
+        def q_ix(b_, h_, t):
+            return (b_, h_, _tri_col(t, nq)[0], 0)
+
+        def kv_ix(b_, h_, t):
+            return (b_, h_ // n_rep, _tri_col(t, nq)[1], 0)
 
         def kvout_ix(b_, h_, t):
-            return (b_, h_, _tri_col(t, n)[1], 0)
-        dk_full, dv_full = pl.pallas_call(
-            functools.partial(_dkv_kernel_tri, blk=blk_q, n=n),
-            grid=(b, h, n * (n + 1) // 2),
-            in_specs=[pl.BlockSpec((1, 1, blk_q, d), qcol_ix),
-                      pl.BlockSpec((1, 1, blk_k, d), kvcol_ix),
-                      pl.BlockSpec((1, 1, blk_k, d), kvcol_ix),
-                      pl.BlockSpec((1, 1, blk_q, d), qcol_ix),
-                      pl.BlockSpec((1, 1, blk_q, 1), qcol_ix),
-                      pl.BlockSpec((1, 1, blk_q, 1), qcol_ix)],
-            out_specs=[pl.BlockSpec((1, 1, blk_k, d), kvout_ix),
-                       pl.BlockSpec((1, 1, blk_k, d), kvout_ix)],
-            out_shape=dkv_shape,
-            scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
-                            pltpu.VMEM((blk_k, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
-            name="self_attn_flash_bwd",
-        )(qs, k, v, do, lse, delta)
+            return (b_, h_, _tri_col(t, nq)[1], 0)
     else:
-        q_spec = pl.BlockSpec((1, 1, blk_q, d),
-                              lambda b_, h_, i, j: (b_, h_, i, 0))
-        if causal:
-            def kv_ix(b_, h_, i, j):  # elide causally-dead kv DMAs (see _fwd)
-                hi = (i * blk_q + blk_q - 1 + offset) // blk_k
-                return (b_, h_ // n_rep, jnp.minimum(j, hi), 0)
-        else:
-            def kv_ix(b_, h_, i, j):
-                return (b_, h_ // n_rep, j, 0)
-        kv_spec = pl.BlockSpec((1, 1, blk_k, d), kv_ix)
-        row_spec = pl.BlockSpec((1, 1, blk_q, 1),
-                                lambda b_, h_, i, j: (b_, h_, i, 0))
+        kernel = functools.partial(
+            _bwd_kernel, scale=scale, one_pass=one_pass, causal=causal,
+            blk_q=blk_q, blk_k=blk_k, nq=nq, nk=nk, offset=offset)
+        grid = (b, h, nk, nq)
 
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel, scale=scale, causal=causal,
-                              blk_q=blk_q, blk_k=blk_k, nk=nk, offset=offset),
-            grid=(b, h, nq, nk),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=q_spec,
-            out_shape=dq_shape,
-            scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
-            interpret=_interpret(),
-            name="self_attn_flash_bwd",
-        )(qs, k, v, do, lse, delta)
-
-        # dk/dv: grid over kv blocks, loop q blocks; one (dk, dv) per
-        # *query* head, then sum over the GQA group outside.
-        if causal:
-            def q_ix2(b_, h_, j, i):  # elide q/do/delta DMAs above diagonal
+        def q_ix(b_, h_, j, i):
+            if causal:  # elide q/do/delta DMAs above the diagonal
                 lo = jnp.maximum((j * blk_k - offset) // blk_q, 0)
-                return (b_, h_, jnp.maximum(i, lo), 0)
-        else:
-            def q_ix2(b_, h_, j, i):
-                return (b_, h_, i, 0)
-        q_spec2 = pl.BlockSpec((1, 1, blk_q, d), q_ix2)
-        kv_spec2 = pl.BlockSpec((1, 1, blk_k, d),
-                                lambda b_, h_, j, i: (b_, h_ // n_rep, j, 0))
-        kvout_spec = pl.BlockSpec((1, 1, blk_k, d),
-                                  lambda b_, h_, j, i: (b_, h_, j, 0))
-        row_spec2 = pl.BlockSpec((1, 1, blk_q, 1),
-                                 lambda b_, h_, j, i: q_ix2(b_, h_, j, i))
+                i = jnp.maximum(i, lo)
+            return (b_, h_, i, 0)
 
-        dk_full, dv_full = pl.pallas_call(
-            functools.partial(_dkv_kernel, causal=causal,
-                              blk_q=blk_q, blk_k=blk_k, nq=nq, offset=offset),
-            grid=(b, h, nk, nq),
-            in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
-                      row_spec2],
-            out_specs=[kvout_spec, kvout_spec],
-            out_shape=dkv_shape,
-            scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
-                            pltpu.VMEM((blk_k, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
-            interpret=_interpret(),
-            name="self_attn_flash_bwd",
-        )(qs, k, v, do, lse, delta)
+        def kv_ix(b_, h_, j, i):
+            return (b_, h_ // n_rep, j, 0)
+
+        def kvout_ix(b_, h_, j, i):
+            return (b_, h_, j, 0)
+    kvout_spec = pl.BlockSpec((1, 1, blk_k, d), kvout_ix)
+    out_specs = [kvout_spec, kvout_spec]
+    out_shape = [jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32)] * 2
+    scratch = [pltpu.VMEM((blk_k, d), jnp.float32)] * 2
+    if one_pass:
+        # the whole query length's dq, resident for the (batch, head)'s walk
+        out_specs.insert(0, pl.BlockSpec((1, 1, sq, d),
+                                         lambda b_, h_, *_: (b_, h_, 0, 0)))
+        out_shape.insert(0, dq_shape)
+        scratch.insert(0, pltpu.VMEM((sq, d), jnp.float32))
+    # dq sums over the kv blocks too: both axes of a rectangular walk carry
+    outs = call(kernel, grid, pair_specs(q_ix, kv_ix), out_specs, out_shape,
+                scratch, carried=2 if one_pass and not tri else 1,
+                vmem_limit=(SCOPED_VMEM_BYTES + dq_lanes * (
+                    4 + 2 * qs.dtype.itemsize)) if one_pass else None)
+    if one_pass:
+        dq, dk_full, dv_full = outs
+    else:
+        dk_full, dv_full = outs
 
     if n_rep > 1:
         dk = dk_full.reshape(b, hkv, n_rep, sk, d).sum(axis=2).astype(k.dtype)
@@ -568,7 +643,8 @@ def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k):
     from jax.ad_checkpoint import checkpoint_name
     qs = (q * (scale * LOG2E)).astype(q.dtype)
     out, lse = _fwd(qs, k, v, causal, blk_q, blk_k)
-    # name the two residuals only the backward kernels need, so remat
+    # name the two residuals only the backward needs (one kernel; two past
+    # ONE_PASS_DQ_BYTES of resident dq: module docstring), so remat
     # policies can save/offload them instead of re-running the fwd kernel
     # (models/llama.py::_remat_policy: 'flash_resid' [the big attention
     # output] offloads to pinned host under 'host_offload' and is kept in

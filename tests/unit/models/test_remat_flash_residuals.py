@@ -3,8 +3,10 @@
 A `pallas_call` is no dot, so a policy that keeps dot results alone makes a
 layer's backward run `self_attn_flash_fwd` a second time for `out` and the
 logsumexp. `checkpoint_dots` (and `checkpoint_dots_gmm`, built on it) keep the
-two by the names the kernel's forward rule gives them. No chip: the graph is
-traced, and the one numeric case runs the kernels in the Pallas interpreter.
+two by the names the kernel's forward rule gives them. A layer's backward is
+ONE `self_attn_flash_bwd` call (the one-pass form, PR 55; two past
+`ONE_PASS_DQ_BYTES` of resident dq). No chip: the graph is traced, and the one
+numeric case runs the kernels in the Pallas interpreter.
 """
 
 from functools import partial
@@ -73,13 +75,13 @@ def _stack_scans(policy, layer=_layer, rows=1):
 
 @pytest.mark.parametrize("policy", KEEPING)
 def test_backward_scan_never_runs_the_forward_kernel(policy):
-    assert _stack_scans(policy) == [[FWD], [BWD, BWD]]
+    assert _stack_scans(policy) == [[FWD], [BWD]]
 
 
 def test_without_the_names_the_forward_kernel_runs_twice():
     """The same graph under a policy that keeps nothing: so the case above
     can fail."""
-    assert _stack_scans("nothing") == [[FWD], [BWD, BWD, FWD]]
+    assert _stack_scans("nothing") == [[FWD], [BWD, FWD]]
 
 
 @pytest.mark.parametrize("policy", KEEPING + ["nothing"])
@@ -94,7 +96,7 @@ def test_the_names_are_seen_through_the_sharded_wrapper(policy):
 
     again = [] if policy in KEEPING else [FWD]
     assert _stack_scans(policy, partial(_layer, attend=attend), rows=2) == [
-        [FWD], [BWD, BWD] + again]
+        [FWD], [BWD] + again]
 
 
 def test_a_graph_without_the_kernel_is_checkpoint_dots_exactly():
@@ -131,7 +133,7 @@ def test_the_model_layer_scan(policy):
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, i: loss_fn(p, {"input_ids": i}, None)[0]))(params, ids)
     again = [] if policy in KEEPING else [FWD]
-    assert _kernels_by_scan(jaxpr.jaxpr) == [[FWD], [BWD, BWD] + again]
+    assert _kernels_by_scan(jaxpr.jaxpr) == [[FWD], [BWD] + again]
 
 
 def test_gradients_equal_those_without_remat():

@@ -29,6 +29,11 @@ KERNEL_NAMES = {"flash_fwd_bwd": {"self_attn_flash_fwd", "self_attn_flash_bwd"},
                 "decode": {"self_attn_dense_decode"},
                 "paged_decode": {"self_attn_paged_decode"},
                 "paged_prefill": {"self_attn_paged_prefill"}}
+# `self_attn_flash_bwd` Mosaic calls in a backward: ONE while the whole
+# query length's dq stays in VMEM beside the dk/dv walk
+# (`flash_attention.ONE_PASS_DQ_BYTES`), the two-pass form's two past it
+FLASH_BWD_CALLS = {"flash_fwd_bwd_s2048": 1, "flash_fwd_bwd_s2048_d64": 1,
+                   "flash_fwd_bwd_s32768": 2}
 # other kernels the benchmark's metrics find by name
 OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "ssm_state_update_m1": "ssm_state_update_m1",
@@ -94,10 +99,13 @@ def test_kernel_compiles_for_v5e(case, v5e_chip, compiled_kernels):
     if want:
         # bare here; `transpose_jvp_self_attn_flash_bwd__` where grad wraps
         # the kernel directly (inside a model's scopes it is bare again)
-        got = {re.search(r"self_attn(_[a-z]+)+", m).group(0)
+        got = [re.search(r"self_attn(_[a-z]+)+", m).group(0)
                for m in re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
-                                   text)}
-        assert got == want
+                                   text)]
+        assert set(got) == want
+        if "self_attn_flash_bwd" in want:
+            assert got.count("self_attn_flash_bwd") == FLASH_BWD_CALLS[
+                case.name]
     other = OTHER_NAMES.get(case.name)
     if other:
         # `ssm_update_ms.gen` and `moe_gmm_ms.gen` search for these
