@@ -32,7 +32,7 @@ import os
 import sys
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -941,11 +941,14 @@ def dispatch_calls():
 
 
 def train_losses(sz: Sizes, seed: int, devices, dp: int = 1, tp: int = 1,
-                 steps: int = 4) -> Dict[str, Any]:
+                 steps: int = 4, trace_to: Optional[str] = None
+                 ) -> Dict[str, Any]:
     """`steps` fused train steps on a repeated batch over `devices`
     (dp x tp mesh): ZeRO-3, bf16, FusedAdam, flash attention, remat and
     chunked cross-entropy — the recipe of the benchmark's train cells at
-    this model's widths. The global batch is the same whatever the mesh."""
+    this model's widths. The global batch is the same whatever the mesh.
+    `trace_to`: a directory that takes a profile of the steps after the
+    first (`telemetry.trace_capture`, which leaves the program map there)."""
     import deepspeed_tpu
     from deepspeed_tpu.models.qwen2 import (init_params_and_specs,
                                             llama_loss_fn, materialize_params,
@@ -981,10 +984,14 @@ def train_losses(sz: Sizes, seed: int, devices, dp: int = 1, tp: int = 1,
         batch = {"input_ids": np.random.default_rng(seed).integers(
             0, cfg.vocab_size, size=(sz.global_batch, sz.seq)).astype(np.int32)}
         losses, walls = [], []
-        for _ in range(steps):
-            t0 = time.perf_counter()
-            losses.append(float(engine.train_batch(batch=batch)))
-            walls.append(time.perf_counter() - t0)
+        with contextlib.ExitStack() as traced:
+            for i in range(steps):
+                if i == 1 and trace_to:
+                    from deepspeed_tpu.telemetry import trace_capture
+                    traced.enter_context(trace_capture(trace_to))
+                t0 = time.perf_counter()
+                losses.append(float(engine.train_batch(batch=batch)))
+                walls.append(time.perf_counter() - t0)
     n_params = int(engine.total_params)
     held = memory_stat("bytes_in_use", devices)
     engine.state = None
@@ -999,6 +1006,7 @@ def train_losses(sz: Sizes, seed: int, devices, dp: int = 1, tp: int = 1,
             "global_batch": sz.global_batch, "micro_batch": sz.micro_batch,
             "gas": gas, "losses": [round(l, 5) for l in losses],
             "loss_first": round(losses[0], 5),
+            "loss_first_hex": float(losses[0]).hex(),
             "loss_last": round(losses[-1], 5),
             "compile_s": round(walls[0] - run_s, 2), "run_s": round(run_s, 3),
             "dispatch": dict(calls), "device_bytes_in_use": held}
@@ -1296,6 +1304,83 @@ def phase_v1_tp(sz: Sizes, seed: int) -> Dict[str, Any]:
 # -------------------------------------------------------------------- main
 
 
+def named_share(logdir: str) -> Dict[str, Any]:
+    """Of the busy time on the device line of the trace in `logdir`, the
+    share the program map beside it names (`telemetry.by_scope`)."""
+    from deepspeed_tpu.telemetry.program_map import join_logdir
+    maps, joined = join_logdir(logdir)
+    lost = sum(joined["unmatched"].values())
+    return {"programs": sorted(d["program"] for d in maps.values()),
+            "map_cache": sorted({d["cache"] for d in maps.values()}),
+            "map_seconds": round(sum(d["compile_s"] + d["parse_s"]
+                                     for d in maps.values()), 3),
+            "rows_that_ran": len(joined["rows"]),
+            "busy_s": round(joined["busy_s"], 6),
+            "named_share": round(1.0 - lost / joined["busy_s"], 6)
+            if joined["busy_s"] else None,
+            "unmatched": sorted(joined["unmatched"].items(),
+                                key=lambda kv: -kv[1])[:5]}
+
+
+def phase_scopes(sz: Sizes, seed: int) -> Dict[str, Any]:
+    """The one thing a CPU cannot check of the program map: that the
+    profiler's instruction names ARE the compiled text's. A train step and
+    a v1 generate are traced, and the map must name at least 99% of the
+    device line's busy time. And that the scope names are metadata only: a
+    train step with every `with jax.named_scope` made a no-op gives, bit
+    for bit, the same first loss. Off the chip a profile has no device line:
+    the share is not judged there."""
+    import tempfile
+    import deepspeed_tpu
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.utils import groups
+    devices = jax.devices()[:1]
+    real = jax.named_scope
+    jax.named_scope = lambda name: contextlib.nullcontext()
+    try:
+        bare = train_losses(sz, seed, devices, steps=1)["loss_first_hex"]
+    finally:
+        jax.named_scope = real
+    out: Dict[str, Any] = {}
+    telemetry.forget_programs()
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_scopes_") as logdir:
+        named = train_losses(sz, seed, devices, steps=3,
+                             trace_to=logdir)["loss_first_hex"]
+        out["train"] = named_share(logdir)
+    if named != bare:
+        raise AssertionError(f"first loss {named} with the scopes, {bare} "
+                             "without them")
+    telemetry.forget_programs()
+    groups.reset_topology()
+    model, params = serving_model(sz, seed)
+    engine = deepspeed_tpu.init_inference(model, params=params, dtype="bf16")
+    del params
+    n = sz.prompt_lens[0]
+    ids = np.stack([p for p in make_prompts(sz, seed, model.cfg.vocab_size)
+                    if len(p) == n])
+    engine.generate(ids, max_new_tokens=sz.new_tokens)
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_scopes_") as logdir:
+        with telemetry.trace_capture(logdir):
+            engine.generate(ids, max_new_tokens=sz.new_tokens)
+        out["v1"] = named_share(logdir)
+    engine.params = None
+    engine._generate_jit.clear()
+    del engine
+    groups.reset_topology()
+    telemetry.forget_programs()
+    for name in ("train", "v1"):
+        share = out[name]["named_share"]
+        if share is None:
+            if jax.devices()[0].platform == "tpu":
+                raise AssertionError(f"{name}: the trace has no device line")
+        elif share < 0.99:
+            raise AssertionError(
+                f"{name}: the program map names {share:.4f} of the device "
+                f"line's busy time; unmatched {out[name]['unmatched']}")
+    return {"loss_first_hex": named, "loss_first_hex_without_scopes": bare,
+            **out}
+
+
 def run_phase(name: str, fn: Callable[[], Dict[str, Any]]) -> bool:
     """Run one phase and print its line. A failure is recorded with its
     traceback (stderr) and the remaining phases still run: the script's
@@ -1321,6 +1406,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearsal", action="store_true",
                     help="accept a backend that is not a TPU")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", action="append",
+                    help="run this phase alone (may be given again)")
     args = ap.parse_args(argv)
 
     from benchmarks.compile_cache import enable_compile_cache
@@ -1356,7 +1443,10 @@ def main(argv=None) -> int:
         phases = [("kernels", lambda: phase_kernels(sz, args.seed)),
                   ("train", lambda: phase_train(sz, args.seed)),
                   ("v1", lambda: phase_v1(sz, args.seed, keep)),
-                  ("v2", lambda: phase_v2(sz, args.seed, keep))]
+                  ("v2", lambda: phase_v2(sz, args.seed, keep)),
+                  ("scopes", lambda: phase_scopes(sz, args.seed))]
+    if args.phase:
+        phases = [(n, fn) for n, fn in phases if n in args.phase]
     results = [run_phase(name, fn) for name, fn in phases]  # run them all
     ok = all(results)
     emit({"ok": ok, "device": device})

@@ -26,7 +26,8 @@ from deepspeed_tpu.inference.kv_cache import KVCache
 from deepspeed_tpu.resilience.faults import fault_point, is_oom_error
 from deepspeed_tpu.telemetry import (RecompileDetector, annotate,
                                      compile_span, device_busy, get_hub,
-                                     init_phase, init_span)
+                                     init_phase, init_span, jit_name,
+                                     keep_program)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
@@ -304,6 +305,15 @@ class InferenceEngine:
         # dispatch are one `compile` span under the program's stable name
         with compile_span(self._program_name(key), "v1"):
             self._build_program(key, input_ids, rng)
+            fn = self._generate_jit[key]
+            if hasattr(fn, "trace"):
+                # the tracing the dispatch below uses, kept for the
+                # program map (the layout-pinning compile keeps its own;
+                # the capacity runner's host loop is no one program)
+                keep_program(self._program_name(key),
+                             fn.trace(self.params, input_ids, rng),
+                             mesh=self.mesh,
+                             detector=self._detector_name(key))
             return self._dispatch_generate(key, input_ids, rng, b,
                                            int(max_new_tokens))
 
@@ -320,23 +330,47 @@ class InferenceEngine:
             # (re-placing per program would invalidate earlier programs'
             # compiled input layouts)
             self._generate_jit[key] = self._compile_auto_layout(
-                self._build_for_key(key, auto_layout=True), input_ids, rng)
+                self._build_for_key(key, auto_layout=True), input_ids, rng,
+                key)
             self._layouts_pinned = True
         else:
             self._generate_jit[key] = self._build_for_key(key)
 
     def _program_name(self, key) -> str:
         """Stable name of one generate key's program, as its `compile`
-        span carries it. Multi-device programs carry the mesh axes
+        span carries it and, through `telemetry.jit_name`, the device
+        trace's module line and the program map: one name a key.
+        Multi-device programs carry the mesh axes
         (`@model2` etc.), so a 1-device and an N-device run are told
         apart; single-device names are unchanged."""
         mode = getattr(self, "serve_mode", "dequant")
         prog = mode if mode in ("layer_scan", "capacity") else "generate"
         prog = self._kv_program_suffix(prog, mode)
         name = f"v1:{prog}:b{key[0]}_s{key[1]}_n{key[2]}"
+        # a key that samples is another program of the same shape: greedy
+        # names are bare, the others say how they differ
+        sampling = [f"{tag}{value}" for tag, value, default in zip(
+            ("t", "k", "p", "eos", "pad"), key[3:], (0.0, 0, 1.0, None, 0))
+            if value != default]
+        if sampling:
+            name = f"{name}:{'_'.join(sampling)}"
         from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
         fp = mesh_fingerprint(self.mesh)
         return f"{name}@{fp}" if fp else name
+
+    def _detector_name(self, key) -> str:
+        """The name the RecompileDetector knows one generate key's program
+        by (`generate:(8, 128, 64, 0.0, ...)`; the mesh and an int8 cache
+        add their suffixes to the part before the colon). The program map
+        keeps it beside the span's name."""
+        mode = getattr(self, "serve_mode", "dequant")
+        program = mode if mode in ("layer_scan", "capacity") else "generate"
+        program = self._kv_program_suffix(program, mode)
+        from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
+        fp = mesh_fingerprint(self.mesh)
+        if fp:  # mesh in the pinned-program identity (1-dev names stable)
+            program = f"{program}@{fp}"
+        return f"{program}:{key}"
 
     def _kv_program_suffix(self, prog: str, mode: str) -> str:
         """Append '@kv_int8' when the int8 cache is EFFECTIVE for this
@@ -363,7 +397,8 @@ class InferenceEngine:
             return build_layer_scan_generate(
                 self.model_cfg, self._config, *key,
                 fused=self._use_fused_int8(), auto_layout=auto_layout,
-                mesh=self.mesh if nontrivial_axes(self.mesh) else None)
+                mesh=self.mesh if nontrivial_axes(self.mesh) else None,
+                name=jit_name(self._program_name(key)))
         return self._build_generate(*key, auto_layout=auto_layout)
 
     def _dispatch_generate(self, key, input_ids, rng, b, new_tokens):
@@ -373,18 +408,12 @@ class InferenceEngine:
         'serving' hub event."""
         import time as _time
         mode = getattr(self, "serve_mode", "dequant")
-        program = mode if mode in ("layer_scan", "capacity") else "generate"
-        program = self._kv_program_suffix(program, mode)
-        from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
-        fp = mesh_fingerprint(self.mesh)
-        if fp:  # mesh in the pinned-program identity (1-dev names stable)
-            program = f"{program}@{fp}"
-        fault_point("generate_dispatch", label=program)
+        detector = self._detector_name(key)
+        fault_point("generate_dispatch", label=detector.split(":", 1)[0])
         if mode != "capacity":  # the capacity runner registers its own
             self._register_serving_residency(key)
-        self._program_names[key] = f"{program}:{key}"
-        self.recompiles.observe(f"{program}:{key}",
-                                (self.params, input_ids, rng))
+        self._program_names[key] = detector
+        self.recompiles.observe(detector, (self.params, input_ids, rng))
         t0 = _time.perf_counter()
         with annotate("ds:generate"):
             out = self._generate_jit[key](self.params, input_ids, rng)
@@ -569,7 +598,7 @@ class InferenceEngine:
             return bool(al)
         return on_tpu()
 
-    def _compile_auto_layout(self, jfn, input_ids, rng):
+    def _compile_auto_layout(self, jfn, input_ids, rng, key):
         """AOT-compile with AUTO input layouts and RE-PLACE self.params in
         the program's preferred layouts, leaf-by-leaf (rebinding each leaf
         so the old copy frees before the next relayouts — a whole-tree
@@ -589,10 +618,14 @@ class InferenceEngine:
         from deepspeed_tpu.telemetry.recompile import abstract_args
         from deepspeed_tpu.utils.layouts import (compiled_input_formats,
                                                  relayout_leaves)
-        compiled = jfn.lower(
+        traced = jfn.trace(
             abstract_args(self.params),
             jax.ShapeDtypeStruct(input_ids.shape, input_ids.dtype),
-            jax.ShapeDtypeStruct(rng.shape, rng.dtype)).compile()
+            jax.ShapeDtypeStruct(rng.shape, rng.dtype))
+        compiled = traced.lower().compile()
+        # this executable IS the program that runs: the map is read off it
+        keep_program(self._program_name(key), traced, mesh=self.mesh,
+                     detector=self._detector_name(key))
         fmts = compiled_input_formats(compiled)[0]
         leaves, treedef = jax.tree_util.tree_flatten(self.params)
         fmt_leaves = jax.tree_util.tree_leaves(fmts[0])
@@ -653,9 +686,14 @@ class InferenceEngine:
                                        dtype=cfg.dtype, quantized=kv_int8)
             counts = {name: (jnp.zeros((), jnp.int32),) * 2
                       for name in counted}
-            logits, cache, counts = forward(params, ids, cache, counts)
-            rng, sub = jax.random.split(rng)
-            tok = sample(logits[:, -1, :], sub)
+            # `prefill`, `decode`, `sample`: scope names the program map
+            # reads off the compiled text (docs/telemetry.md); metadata
+            # only, no instruction and no schedule changes with them
+            with jax.named_scope("prefill"):
+                logits, cache, counts = forward(params, ids, cache, counts)
+                rng, sub = jax.random.split(rng)
+                with jax.named_scope("sample"):
+                    tok = sample(logits[:, -1, :], sub)
             done = jnp.zeros((b,), jnp.bool_)
             if eos_token_id is not None:
                 done = tok == eos_token_id
@@ -664,7 +702,8 @@ class InferenceEngine:
                 cache, tok, done, counts = carry
                 logits, cache, counts = forward(params, tok[:, None], cache,
                                                 counts)
-                nxt = sample(logits[:, -1, :], rng_i)
+                with jax.named_scope("sample"):
+                    nxt = sample(logits[:, -1, :], rng_i)
                 if eos_token_id is not None:
                     nxt = jnp.where(done, pad_token_id, nxt)
                     done = done | (nxt == eos_token_id)
@@ -672,15 +711,19 @@ class InferenceEngine:
 
             keys = jax.random.split(rng, max_new_tokens - 1) if max_new_tokens > 1 \
                 else jnp.zeros((0, 2), jnp.uint32)
-            (cache, last, done, counts), toks = jax.lax.scan(
-                step, (cache, tok, done, counts), keys)
+            with jax.named_scope("decode"):
+                (cache, last, done, counts), toks = jax.lax.scan(
+                    step, (cache, tok, done, counts), keys)
             new = jnp.concatenate([toks.T, last[:, None]], axis=1) \
                 if max_new_tokens > 1 else last[:, None]
             out = jnp.concatenate([ids, new], axis=1)
             return (out, counts) if counted else out
 
-        # `jit_ds_v1_generate` on the device trace's `XLA Modules` line
-        gen.__name__ = "ds_v1_generate"
+        # `jit_ds_v1_generate_b8_s128_n64` on the device trace's `XLA
+        # Modules` line: the `compile` span's name, one module a key
+        gen.__name__ = gen.__qualname__ = jit_name(self._program_name(
+            (b, s, max_new_tokens, temperature, top_k, top_p, eos_token_id,
+             pad_token_id)))
         if auto_layout:
             from deepspeed_tpu.utils.layouts import auto_input_format
             return jax.jit(gen, in_shardings=auto_input_format())

@@ -350,10 +350,12 @@ def build_layer_scan_generate(model_cfg: Any, infer_cfg: Any,
                               pad_token_id: int,
                               fused: bool = True,
                               auto_layout: bool = False,
-                              mesh=None):
+                              mesh=None, name: Optional[str] = None):
     """One compiled prefill + decode-scan program over a per-layer-quantized
     llama tree — the layer-scan analog of `InferenceEngine._build_generate`
-    (same sampling/eos semantics, same KV-cache shapes)."""
+    (same sampling/eos semantics, same KV-cache shapes). `name`: the jitted
+    function's, so that the device trace names the module after the
+    program's `compile` span."""
     from deepspeed_tpu.inference.kv_cache import decode_mask
     from deepspeed_tpu.ops.attention import rope_cos_sin
     from deepspeed_tpu.ops.sampling import sample_logits
@@ -428,6 +430,8 @@ def build_layer_scan_generate(model_cfg: Any, infer_cfg: Any,
             if max_new_tokens > 1 else last[:, None]
         return jnp.concatenate([ids, new], axis=1)
 
+    if name:
+        gen.__name__ = gen.__qualname__ = name
     if auto_layout:
         from deepspeed_tpu.utils.layouts import auto_input_format
         return jax.jit(gen, in_shardings=auto_input_format())

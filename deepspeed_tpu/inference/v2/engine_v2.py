@@ -30,7 +30,7 @@ from deepspeed_tpu.resilience.faults import fault_point, is_oom_error
 from deepspeed_tpu.telemetry import (RecompileDetector, RequestTracer,
                                      compile_span, compile_totals,
                                      device_busy, get_hub, init_phase,
-                                     init_span)
+                                     init_span, jit_name, keep_program)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger, warn_once
 
@@ -670,19 +670,37 @@ class InferenceEngineV2:
         """Build-register a serving program: jit (donating the cache
         argument) + `_track` wrapping, or the eager body in capacity mode.
         The jitted body is renamed so that the device trace's `XLA Modules`
-        line reads `jit_ds_v2_<program>`, not a closure's name."""
+        line reads `jit_ds_v2_<program>` (`_program_name`), not a
+        closure's name."""
         if key in self._jits:
             return self._jits[key]
         fault_point("program_compile", label=self.serve_mode)
         if self._eager_serving:
             fn = self._track(key, body, raw=False)
         else:
-            body.__name__ = "ds_v2_" + (key if isinstance(key, str)
-                                        else str(key[0]))
+            body.__name__ = body.__qualname__ = jit_name(
+                "v2:" + self._program_name(key))
             fn = self._track(key, jax.jit(body, donate_argnums=donate),
                              body=body)
         self._jits[key] = fn
         return fn
+
+    def _program_name(self, key) -> str:
+        """The name the detector pins and the `compile` span carries; with
+        `v2:` before it and through `telemetry.jit_name`, the jitted
+        function's and so the module's in a device trace
+        (`jit_ds_v2_fused_batch_128_4`): one name a program. Single-device
+        dequant names are bare (the stability contract); a non-default
+        serve mode, a quantized cache and a mesh each make a DIFFERENT
+        program, so each adds its suffix."""
+        name = key if isinstance(key, str) else ":".join(map(str, key))
+        if self.serve_mode != "dequant":
+            name = f"{name}@{self.serve_mode}"
+        if getattr(self, "kv_cache_dtype", None):
+            name = f"{name}@kv_{self.kv_cache_dtype}"
+        from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
+        fp = mesh_fingerprint(self.mesh)
+        return f"{name}@{fp}" if fp else name
 
     def _track(self, key, fn, body=None, raw=True):
         """Wrap a compiled serving program with dispatch-time signature
@@ -696,19 +714,7 @@ class InferenceEngineV2:
         family: every later program compiles against the committed
         layouts, so no bucket pays the v1 relayout-in-program +3 GB or a
         ~3.5 s signature-miss recompile."""
-        name = key if isinstance(key, str) else ":".join(map(str, key))
-        # The name is what the detector pins and the `compile` span
-        # carries. Single-device dequant names are bare (the stability
-        # contract); a non-default serve mode, a quantized cache and a
-        # mesh each make a DIFFERENT program, so each adds its suffix.
-        if self.serve_mode != "dequant":
-            name = f"{name}@{self.serve_mode}"
-        if getattr(self, "kv_cache_dtype", None):
-            name = f"{name}@kv_{self.kv_cache_dtype}"
-        from deepspeed_tpu.ops.pallas.sharded import mesh_fingerprint
-        fp = mesh_fingerprint(self.mesh)
-        if fp:
-            name = f"{name}@{fp}"
+        name = self._program_name(key)
         det = self.recompiles
         first = True
 
@@ -728,6 +734,8 @@ class InferenceEngineV2:
                 # cache) is this call: one `compile` span with its name
                 first = False
                 with compile_span(name, "v2", under=self.tracer.current()):
+                    if raw:   # the tracing the call uses, for the map
+                        keep_program(name, fn.trace(*args), mesh=self.mesh)
                     return fn(*args)
             return fn(*args)
         # the raw jit and the detector name, for tools/tpuverify (the

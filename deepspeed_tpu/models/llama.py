@@ -500,12 +500,15 @@ class LlamaForCausalLM(nn.Module):
 
     def _lm_head(self, h, embed):
         cfg = self.cfg
-        if cfg.tie_word_embeddings:
-            return jnp.einsum("bsd,vd->bsv", h, embed.astype(cfg.dtype))
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-        return h @ lm_head.astype(cfg.dtype)
+        if not cfg.tie_word_embeddings:
+            lm_head = self.param("lm_head", nn.with_logical_partitioning(
+                nn.initializers.normal(0.02), ("embed", "vocab")),
+                (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+        # `head`: the scope the program map reads (docs/telemetry.md)
+        with jax.named_scope("head"):
+            if cfg.tie_word_embeddings:
+                return jnp.einsum("bsd,vd->bsv", h, embed.astype(cfg.dtype))
+            return h @ lm_head.astype(cfg.dtype)
 
 
 def init_params_and_specs(cfg: LlamaConfig, rng=None, seq_len: int = 8):

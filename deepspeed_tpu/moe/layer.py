@@ -294,18 +294,24 @@ class MoE(nn.Module):
                     "pure expert-parallel — using ragged dispatch")
             impl = "gmm" if gmm_ok else "ragged"
         assignments = float(b * s * self.k)
+        # `route`: the router's scores and choice, for the program map
+        # (docs/telemetry.md); `dispatch` / `combine` are set where the
+        # rows are sorted and gathered back (moe/sharded_moe.py)
         if impl == "gmm":
-            l_aux, gate_k, topk_idx, pos_k, kept, cap = gate(
-                x, train, noise_rng, ragged=True)
+            with jax.named_scope("route"):
+                l_aux, gate_k, topk_idx, pos_k, kept, cap = gate(
+                    x, train, noise_rng, ragged=True)
             out = dispatch_combine_gmm(x, gate_k, topk_idx,
                                        self.num_experts, experts)
         elif impl == "ragged":
-            l_aux, gate_k, topk_idx, pos_k, kept, cap = gate(
-                x, train, noise_rng, ragged=True)
+            with jax.named_scope("route"):
+                l_aux, gate_k, topk_idx, pos_k, kept, cap = gate(
+                    x, train, noise_rng, ragged=True)
             out = dispatch_combine_ragged(x, gate_k, topk_idx, pos_k, kept,
                                           cap, self.num_experts, experts)
         else:
-            l_aux, combine, dispatch, _ = gate(x, train, noise_rng)
+            with jax.named_scope("route"):
+                l_aux, combine, dispatch, _ = gate(x, train, noise_rng)
             out = dispatch_combine(x, combine, dispatch, experts)
         if impl in ("gmm", "ragged"):
             # router telemetry (pre-capacity): fraction of the T·k expert
@@ -355,7 +361,8 @@ class MoE(nn.Module):
         count, k = self.held_experts, self.k
         experts = Experts(count, d, f, self.dtype, self.activation,
                           name="experts")
-        gate_k, topk_idx = gate(x, routed_only=True)
+        with jax.named_scope("route"):
+            gate_k, topk_idx = gate(x, routed_only=True)
         impl = self.dispatch_impl
         if impl == "auto":
             # The grouped GEMM wherever the bare kernel may run (one device),

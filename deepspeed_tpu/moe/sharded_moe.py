@@ -246,13 +246,17 @@ def dispatch_combine_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
     """
     t, d = x.shape
     k = topk_idx.shape[1]
-    flat_e = topk_idx.reshape(-1)                       # (T·k,)
-    order = jnp.argsort(flat_e)                         # stable: token-order
-    xs = jnp.take(x, order // k, axis=0)                # within each expert
-    group_sizes = jnp.bincount(flat_e, length=num_experts)
+    # `dispatch` / `combine`: scope names for the program map
+    # (docs/telemetry.md); the experts' own work carries its flax name
+    with jax.named_scope("dispatch"):
+        flat_e = topk_idx.reshape(-1)                   # (T·k,)
+        order = jnp.argsort(flat_e)                     # stable: token-order
+        xs = jnp.take(x, order // k, axis=0)            # within each expert
+        group_sizes = jnp.bincount(flat_e, length=num_experts)
     out_s = grouped_fn(xs, group_sizes)                 # (T·k, D)
-    out_k = jnp.take(out_s, jnp.argsort(order), axis=0).reshape(t, k, d)
-    return jnp.einsum("tk,tkd->td", gate_k.astype(x.dtype), out_k)
+    with jax.named_scope("combine"):
+        out_k = jnp.take(out_s, jnp.argsort(order), axis=0).reshape(t, k, d)
+        return jnp.einsum("tk,tkd->td", gate_k.astype(x.dtype), out_k)
 
 
 def dispatch_combine_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,
@@ -271,19 +275,23 @@ def dispatch_combine_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,
     """
     t, d = x.shape
     k = topk_idx.shape[1]
-    dest = topk_idx * cap + pos_k                              # (T, k)
-    dest = jnp.where(kept > 0, dest, num_experts * cap)        # dropped → OOB
-    xk = jnp.broadcast_to(x[:, None], (t, k, d)).reshape(t * k, d)
-    buf = jnp.zeros((num_experts * cap, d), x.dtype)
-    # each (expert, slot) receives at most one token → add ≡ set, OOB dropped
-    buf = buf.at[dest.reshape(-1)].add(xk, mode="drop")
-    expert_inputs = buf.reshape(num_experts, cap, d)
-    expert_inputs = shard_along(expert_inputs, "expert", None, None)
+    with jax.named_scope("dispatch"):
+        dest = topk_idx * cap + pos_k                          # (T, k)
+        dest = jnp.where(kept > 0, dest, num_experts * cap)    # dropped → OOB
+        xk = jnp.broadcast_to(x[:, None], (t, k, d)).reshape(t * k, d)
+        buf = jnp.zeros((num_experts * cap, d), x.dtype)
+        # each (expert, slot) receives at most one token → add ≡ set, OOB
+        # dropped
+        buf = buf.at[dest.reshape(-1)].add(xk, mode="drop")
+        expert_inputs = buf.reshape(num_experts, cap, d)
+        expert_inputs = shard_along(expert_inputs, "expert", None, None)
     expert_outputs = expert_fn(expert_inputs)
-    expert_outputs = shard_along(expert_outputs, "expert", None, None)
-    flat = expert_outputs.reshape(num_experts * cap, d)
-    out_k = jnp.take(flat, dest, axis=0, mode="fill", fill_value=0)  # (T, k, D)
-    return jnp.einsum("tk,tkd->td", gate_k.astype(x.dtype), out_k)
+    with jax.named_scope("combine"):
+        expert_outputs = shard_along(expert_outputs, "expert", None, None)
+        flat = expert_outputs.reshape(num_experts * cap, d)
+        out_k = jnp.take(flat, dest, axis=0, mode="fill",
+                         fill_value=0)                         # (T, k, D)
+        return jnp.einsum("tk,tkd->td", gate_k.astype(x.dtype), out_k)
 
 
 # ------------------------------------------------- a chip's share of experts
@@ -315,19 +323,21 @@ def held_dispatch_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
     the layer's result (T, D) float32, the number of held assignments)."""
     t, d = x.shape
     k = topk_idx.shape[1]
-    held, local = held_assignments(topk_idx, offset, count, valid)
-    key = local.reshape(-1)                             # absent sort last
-    order = jnp.argsort(key)                            # stable
-    xs = jnp.take(x, order // k, axis=0)
-    group_sizes = jnp.bincount(key, length=count + 1)[:count]
-    n_held = jnp.sum(group_sizes)
+    with jax.named_scope("dispatch"):
+        held, local = held_assignments(topk_idx, offset, count, valid)
+        key = local.reshape(-1)                         # absent sort last
+        order = jnp.argsort(key)                        # stable
+        xs = jnp.take(x, order // k, axis=0)
+        group_sizes = jnp.bincount(key, length=count + 1)[:count]
+        n_held = jnp.sum(group_sizes)
     out_s = grouped_fn(xs, group_sizes)                 # (T*k, D)
-    # rows past the last group were never written
-    rows = jax.lax.broadcasted_iota(jnp.int32, (t * k, 1), 0)
-    out_s = jnp.where(rows < n_held, out_s, 0)
-    out_k = jnp.take(out_s, jnp.argsort(order), axis=0).reshape(t, k, d)
-    w = jnp.where(held, gate_k, 0.0)
-    return jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)), n_held
+    with jax.named_scope("combine"):
+        # rows past the last group were never written
+        rows = jax.lax.broadcasted_iota(jnp.int32, (t * k, 1), 0)
+        out_s = jnp.where(rows < n_held, out_s, 0)
+        out_k = jnp.take(out_s, jnp.argsort(order), axis=0).reshape(t, k, d)
+        w = jnp.where(held, gate_k, 0.0)
+        return jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)), n_held
 
 
 def held_dispatch_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,
@@ -340,16 +350,19 @@ def held_dispatch_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,
     buffer is `count x T` rows whatever was routed."""
     t, d = x.shape
     k = topk_idx.shape[1]
-    held, local = held_assignments(topk_idx, offset, count, valid)
-    flat = _one_hot(local.reshape(-1), count)           # absent: a zero row
-    pos = jnp.sum((jnp.cumsum(flat, axis=0) - flat) * flat, axis=-1)
-    dest = jnp.where(held, local * t + pos.reshape(t, k).astype(jnp.int32),
-                     count * t)
-    xk = jnp.broadcast_to(x[:, None], (t, k, d)).reshape(t * k, d)
-    buf = jnp.zeros((count * t, d), x.dtype)
-    buf = buf.at[dest.reshape(-1)].add(xk, mode="drop")
+    with jax.named_scope("dispatch"):
+        held, local = held_assignments(topk_idx, offset, count, valid)
+        flat = _one_hot(local.reshape(-1), count)       # absent: a zero row
+        pos = jnp.sum((jnp.cumsum(flat, axis=0) - flat) * flat, axis=-1)
+        dest = jnp.where(held,
+                         local * t + pos.reshape(t, k).astype(jnp.int32),
+                         count * t)
+        xk = jnp.broadcast_to(x[:, None], (t, k, d)).reshape(t * k, d)
+        buf = jnp.zeros((count * t, d), x.dtype)
+        buf = buf.at[dest.reshape(-1)].add(xk, mode="drop")
     out = expert_fn(buf.reshape(count, t, d)).reshape(count * t, d)
-    out_k = jnp.take(out, dest, axis=0, mode="fill", fill_value=0)
-    w = jnp.where(held, gate_k, 0.0)
-    return (jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)),
-            jnp.sum(held.astype(jnp.int32)))
+    with jax.named_scope("combine"):
+        out_k = jnp.take(out, dest, axis=0, mode="fill", fill_value=0)
+        w = jnp.where(held, gate_k, 0.0)
+        return (jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)),
+                jnp.sum(held.astype(jnp.int32)))

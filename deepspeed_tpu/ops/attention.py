@@ -637,8 +637,9 @@ def sparse_select(q_index, w, index_keys, lengths, topk: int, new):
     from deepspeed_tpu.ops.pallas import sparse_select as ss
     fn = ss.sparse_index_select if _one_device_kernel(ss.SELECT_NAME) \
         else ss.sparse_index_select_reference
-    return fn(q_index, w, index_keys.stack, index_keys.layer, lengths, topk,
-              new)
+    with jax.named_scope("choose"):   # for the program map
+        return fn(q_index, w, index_keys.stack, index_keys.layer, lengths,
+                  topk, new)
 
 
 def sparse_decode(q, k_cache, v_cache, lengths, bias, softmax_scale: float,
@@ -713,14 +714,20 @@ def latent_sparse_prefill(q_nope, q_rope, w_kvb, q_index, w, latent,
     from deepspeed_tpu.ops.pallas import mla_sparse as ms
     from deepspeed_tpu.ops.pallas import sparse_select as ss
     aligned = latent.stack.shape[3] % 128 == 0 and q_nope.shape[0] % 128 == 0
+    # `choose`: the scope the program map reads (docs/telemetry.md)
     if aligned and _one_device_kernel(ms.PREFILL_NAME):
-        bias, kept = ss.sparse_prefill_choice(
-            q_index, w, index_keys.stack, index_keys.layer, row, start, topk)
+        with jax.named_scope("choose"):
+            bias, kept = ss.sparse_prefill_choice(
+                q_index, w, index_keys.stack, index_keys.layer, row, start,
+                topk)
         fn = ms.mla_sparse_prefill
     else:
-        bias, kept = ss.choice_plain(
-            q_index, w, ss.row_of(index_keys.stack, index_keys.layer, row)[0],
-            jnp.asarray(start, jnp.int32) + jnp.arange(q_nope.shape[0]), topk)
+        with jax.named_scope("choose"):
+            bias, kept = ss.choice_plain(
+                q_index, w,
+                ss.row_of(index_keys.stack, index_keys.layer, row)[0],
+                jnp.asarray(start, jnp.int32) + jnp.arange(q_nope.shape[0]),
+                topk)
         fn = ms.mla_sparse_prefill_reference
     return fn(q_nope, q_rope, w_kvb, bias, latent.stack, latent.layer, row,
               start, softmax_scale), kept

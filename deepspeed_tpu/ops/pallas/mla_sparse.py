@@ -218,7 +218,10 @@ def mla_sparse_decode_gathered(q_lat, q_rope, stack, layer, lengths, bias,
     """`mla_sparse_decode` with the chosen rows GATHERED first: `kept` (B,)
     is the count of them a row (`sparse_index_select`'s second result), and
     the kernel walks `min(topk, M)` slots a row, not the slab."""
-    rows = gather_chosen(stack, layer, lengths, bias, topk, new)
+    # `choose`: the sort that lists the chosen slots and the gather are the
+    # choice's cost, not the kernel's (docs/telemetry.md, scopes)
+    with jax.named_scope("choose"):
+        rows = gather_chosen(stack, layer, lengths, bias, topk, new)
     return _decode_call(q_lat, q_rope, rows, 0, kept, softmax_scale, None,
                         None)
 
@@ -380,7 +383,8 @@ def mla_sparse_prefill(q_nope, q_rope, w_kvb, bias, stack, layer, row, start,
                              preferred_element_type=F32).astype(dt),
                 (i * kb, 0)) for buf, w in zip(bufs, ws))
 
-        bufs = jax.lax.fori_loop(0, live, expand, bufs)
+        with jax.named_scope("expand_kv"):   # for the program map
+            bufs = jax.lax.fori_loop(0, live, expand, bufs)
         return bufs, mla_sparse_prefill_attend(qn, qr, bias, *bufs, stack,
                                                layer, row, start)
 

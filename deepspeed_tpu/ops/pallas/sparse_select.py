@@ -700,8 +700,9 @@ def sparse_attn_prefill(q: jnp.ndarray, q_index: jnp.ndarray, w: jnp.ndarray,
     query keeps the `topk` positions up to its own of largest index score
     (`sparse_prefill_choice`) and attends those (`sparse_prefill_attend`).
     Returns (C, H, D) and (C,) int32, the slots each query kept."""
-    bias, kept = sparse_prefill_choice(q_index, w, index_stack, layer, row,
-                                       start, topk)
+    with jax.named_scope("choose"):   # for the program map (docs/telemetry.md)
+        bias, kept = sparse_prefill_choice(q_index, w, index_stack, layer,
+                                           row, start, topk)
     return sparse_prefill_attend(q, bias, k_stack, v_stack, layer, row, start,
                                  softmax_scale), kept
 

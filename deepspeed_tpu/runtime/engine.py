@@ -47,7 +47,7 @@ from deepspeed_tpu.runtime.zero.partition import ZeroShardingPlan
 from deepspeed_tpu.ops.optimizers import GradientTransformation, build_optimizer
 from deepspeed_tpu.telemetry import (
     MetricsState, RecompileDetector, TelemetryHub, annotate, compile_span,
-    device_busy, init_phase, init_span)
+    device_busy, init_phase, init_span, jit_name, keep_program)
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.groups import MeshTopology
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -602,19 +602,26 @@ class DeepSpeedEngine:
         loss_fn = self._normalized_loss_fn()
         gas = self._effective_gas
 
-        if self._onebit_wire:
-            grads, loss = self._wire_fwd_bwd(state, batch, rng, gas, loss_fn)
-            aux = {}
-        elif self._zeropp:
-            grads, loss = self._zeropp_fwd_bwd(state, batch, rng, gas, loss_fn)
-            aux = {}
-        else:
-            def scaled_loss(params):
-                loss, aux = loss_fn(params, batch, rng)
-                scaled = self.loss_scaler.scale_loss(loss / gas, state.scaler)
-                return scaled, (loss, aux)
+        # scope names are metadata only: the program map reads them off the
+        # compiled text (docs/telemetry.md, "Program map and scopes")
+        with jax.named_scope("micro"):
+            if self._onebit_wire:
+                grads, loss = self._wire_fwd_bwd(state, batch, rng, gas,
+                                                 loss_fn)
+                aux = {}
+            elif self._zeropp:
+                grads, loss = self._zeropp_fwd_bwd(state, batch, rng, gas,
+                                                   loss_fn)
+                aux = {}
+            else:
+                def scaled_loss(params):
+                    loss, aux = loss_fn(params, batch, rng)
+                    scaled = self.loss_scaler.scale_loss(loss / gas,
+                                                         state.scaler)
+                    return scaled, (loss, aux)
 
-            grads, (loss, aux) = jax.grad(scaled_loss, has_aux=True)(state.params)
+                grads, (loss, aux) = jax.grad(scaled_loss, has_aux=True)(
+                    state.params)
         if self.loss_scaler.enabled:
             # Per-micro overflow tracking (reference stage_1_and_2.py:1173
             # `update_overflow_tracker_for_param_grad`): detect non-finite
@@ -629,12 +636,14 @@ class DeepSpeedEngine:
                 scaler=self.loss_scaler.track_micro(state.scaler, ovf))
         else:
             ovf = jnp.asarray(False)
-        if state.grad_acc is None:  # elided buffers: first (only) micro
-            grad_acc = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32), grads)
-        else:
-            grad_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(jnp.float32), state.grad_acc, grads)
+        with jax.named_scope("grad_accumulate"):
+            if state.grad_acc is None:  # elided buffers: first (only) micro
+                grad_acc = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32), grads)
+            else:
+                grad_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(jnp.float32), state.grad_acc,
+                    grads)
         return state._replace(grad_acc=grad_acc), loss, aux, ovf
 
     # -------------------------------------------------------------- ZeRO++
@@ -793,6 +802,7 @@ class DeepSpeedEngine:
                            axis_names=set(manual), check_vma=False)
         return fn(grads, opt_state, target, lr)
 
+    @jax.named_scope("optimizer")
     def _take_model_step(self, state: TrainState, aux=None):
         """Boundary: unscale, clip, optimizer update, loss-scale update.
         Returns ``(new_state, MetricsState)`` — the metrics are computed
@@ -1019,12 +1029,14 @@ class DeepSpeedEngine:
         else:   # the program's build, compile and first dispatch
             with compile_span(f"train:{name}", "train") as found:
                 fn = self._get_jit(name)
-                # read off the traced step (the call below traces nothing
-                # again): the layers' tensor-parallel reductions that are
-                # named exchanges, 0 where the partitioner places them
-                sites = count_exchanges(fn.trace(state, *rest).jaxpr)
-                found.update(tp_exchange_sites=sites,
-                             tp_half_batches=2 if sites else 1)
+                # the tracing the call below uses (it traces nothing
+                # again): kept for the program map, and read for the
+                # layers' tensor-parallel reductions that are named
+                # exchanges, 0 where the partitioner places them
+                traced = fn.trace(state, *rest)
+                keep_program(f"train:{name}", traced, mesh=self.mesh,
+                             detector=name, under_mesh=True)
+                found.update(tp_exchange_sites=count_exchanges(traced.jaxpr))
                 for field, value in found.items():
                     self.telemetry.gauge(field, value)
                 out = fn(state, *rest)
@@ -1046,12 +1058,13 @@ class DeepSpeedEngine:
             # grad shardings never carry offload memory kinds
             # (partition.py only offloads 'master'/'param')
             micro_out = shardings._replace(grad_acc=self._grad_shardings)
-            fn = jax.jit(lambda st, b, r: self._micro_fwd_bwd(self._stage_in(st), b, r),
+            fn = jax.jit(self._named(name, lambda st, b, r: self._micro_fwd_bwd(
+                             self._stage_in(st), b, r)),
                          donate_argnums=donate,
                          out_shardings=(micro_out, None, None, None))
         elif name == "step":
-            fn = jax.jit(lambda st, aux: self._take_model_step(
-                             self._stage_in(st), aux),
+            fn = jax.jit(self._named(name, lambda st, aux: self._take_model_step(
+                             self._stage_in(st), aux)),
                          donate_argnums=donate,
                          out_shardings=(shardings, None))
         elif name == "train_batch":
@@ -1062,7 +1075,8 @@ class DeepSpeedEngine:
                         self._stage_in(state), batch, rng)
                     state, metrics = self._take_model_step(state, aux)
                     return state, loss, metrics
-                fn = jax.jit(fused_pipe, donate_argnums=donate,
+                fn = jax.jit(self._named(name, fused_pipe),
+                             donate_argnums=donate,
                              out_shardings=(shardings, None, None))
                 return self._cache_jit(name, fn)
 
@@ -1113,20 +1127,28 @@ class DeepSpeedEngine:
                     loss = jnp.mean(losses)
                 return state, loss, metrics
 
-            fn = jax.jit(fused, donate_argnums=donate,
+            fn = jax.jit(self._named(name, fused), donate_argnums=donate,
                          out_shardings=(shardings, None, None))
         elif name == "eval":
             loss_fn = self._normalized_loss_fn()
 
             def ev(params, batch, rng):
                 return loss_fn(params, batch, rng)
-            fn = jax.jit(ev)
+            fn = jax.jit(self._named(name, ev))
         else:
             raise KeyError(name)
         return self._cache_jit(name, fn)
 
     def _cache_jit(self, name: str, fn):
         self._jit_cache[name] = fn
+        return fn
+
+    @staticmethod
+    def _named(name: str, fn):
+        """`fn` under the name its program's `compile` span carries, so
+        that the device trace's `XLA Modules` line reads
+        `jit_ds_train_<name>` and the program map finds it."""
+        fn.__name__ = fn.__qualname__ = jit_name(f"train:{name}")
         return fn
 
     # ------------------------------------------------------------------
@@ -1445,6 +1467,16 @@ class DeepSpeedEngine:
         from deepspeed_tpu.telemetry.tracing import trace_capture
         return trace_capture(logdir or self.telemetry.trace_dir
                              or "/tmp/ds_tpu_trace")
+
+    def program_map(self, name: Optional[str] = None):
+        """The program map of this engine's compiled programs
+        (`telemetry.program_map`): every instruction of `train_batch` (or
+        `micro`, `step`, `eval`: `name`) by the scope it was traced under
+        and what a fusion holds. Built when asked."""
+        from deepspeed_tpu.telemetry import program_map
+        return {m: d for m, d in program_map(
+            f"train:{name}" if name else None).items()
+            if d["program"].startswith("train:")}
 
     def no_sync(self):
         """Grad sync is an XLA-scheduled collective at the boundary; nothing to

@@ -4,6 +4,7 @@
     python -m deepspeed_tpu.telemetry --summarize run.jsonl --percentiles
     python -m deepspeed_tpu.telemetry --summarize run.jsonl \
         --export-trace trace.json
+    python -m deepspeed_tpu.telemetry --by-scope /tmp/ds_tpu_trace
 
 ``--summarize`` prints a step-time / memory table from a telemetry
 JSONL file (schema: docs/telemetry.md). ``--percentiles`` adds the
@@ -15,6 +16,11 @@ the last snapshot's per-component breakdown, reconcile drift rows).
 Chrome trace_event JSON (chrome://tracing or ui.perfetto.dev; one track
 per request slot; `memory_snapshot` events become per-tier counter
 tracks). Pure-stdlib parsing — works on any box that can read the file.
+``--by-scope LOGDIR`` reads a directory ``engine.trace`` /
+``trace_capture`` wrote (the newest ``.xplane.pb`` there and the
+``program_map.json`` beside it) and prints the device's seconds by scope,
+by what an instruction is or holds, and by phase; it needs JAX's profile
+reader and touches no backend.
 """
 
 from __future__ import annotations
@@ -232,9 +238,13 @@ def memory_report(path: str) -> str:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m deepspeed_tpu.telemetry",
-        description="Summarize a telemetry JSONL file")
-    ap.add_argument("--summarize", metavar="JSONL", required=True,
+        description="Summarize a telemetry JSONL file, or a trace by scope")
+    ap.add_argument("--summarize", metavar="JSONL",
                     help="path to a telemetry JSONL file")
+    ap.add_argument("--by-scope", metavar="LOGDIR",
+                    help="a directory trace_capture wrote: print the device "
+                         "ops' seconds by scope, from its newest trace and "
+                         "the program_map.json beside it")
     ap.add_argument("--percentiles", action="store_true",
                     help="with --summarize: print the SLA histogram section "
                          "and the per-serve-mode request table")
@@ -246,6 +256,13 @@ def main(argv=None) -> int:
                     help="with --summarize: write the file's span/request/"
                          "instant events as Chrome trace_event JSON to OUT")
     args = ap.parse_args(argv)
+    if args.by_scope:
+        from deepspeed_tpu.telemetry.program_map import report
+        print(report(args.by_scope))
+        if not args.summarize:
+            return 0
+    elif not args.summarize:
+        ap.error("one of --summarize and --by-scope is required")
     print(summarize(args.summarize))
     if args.percentiles:
         print(percentiles(args.summarize))

@@ -61,8 +61,15 @@ _installed = False
 def trace_capture(logdir: str,
                   create_perfetto_link: bool = False) -> Iterator[str]:
     """Capture a profiler trace of the enclosed block into ``logdir``
-    (open the result with perfetto / tensorboard's profile plugin)."""
+    (open the result with perfetto / tensorboard's profile plugin). As it
+    closes it writes ``program_map.json`` beside the trace: the table of
+    every compiled program's instructions by scope
+    (``telemetry.program_map``), which ``python -m deepspeed_tpu.telemetry
+    --by-scope <logdir>`` joins to the device ops. That is the one place
+    the map is built unasked: after the trace has stopped, in a run whose
+    operator asked for a trace."""
     import jax
+    from deepspeed_tpu.telemetry.program_map import write_program_map
     os.makedirs(logdir, exist_ok=True)
     jax.profiler.start_trace(logdir,
                              create_perfetto_link=create_perfetto_link)
@@ -70,6 +77,7 @@ def trace_capture(logdir: str,
         yield logdir
     finally:
         jax.profiler.stop_trace()
+        write_program_map(logdir)
 
 
 @contextlib.contextmanager
@@ -173,6 +181,14 @@ def _since(kind: str, t0: float) -> List[Dict[str, Any]]:
     return out
 
 
+def _worst_cache(backend: Sequence[Dict[str, Any]]) -> str:
+    """`miss`, `uncached` or `hit`, whichever comes first among the
+    backend compiles of one span; `uncached` where there was none."""
+    said = {r["cache"] for r in backend}
+    return next((w for w in ("miss", "uncached", "hit") if w in said),
+                "uncached")
+
+
 @contextlib.contextmanager
 def compile_span(program: str, engine: str, phase: str = "first_dispatch",
                  under=(None, None)) -> Iterator[Dict[str, Any]]:
@@ -184,7 +200,9 @@ def compile_span(program: str, engine: str, phase: str = "first_dispatch",
     less those is the rest of a first dispatch: argument checks, the feeds'
     `device_put`, the dispatch and, where the caller fetches, the run.
     It yields a dict: what the caller puts there of the program it traced
-    (the train engine's `tp_exchange_sites`) joins the span's fields.
+    (the train engine's `tp_exchange_sites`) joins the span's fields. A
+    program kept for the map (`program_map.keep`, by the caller, inside
+    this span) is told what the persistent cache said of this compile.
     `under` is the (id, round) of the span it happens in
     (`RequestTracer.current()`)."""
     from deepspeed_tpu.telemetry.hub import get_hub
@@ -211,6 +229,10 @@ def compile_span(program: str, engine: str, phase: str = "first_dispatch",
                   "cache_retrieval_s": round(
                       sum(r.get("retrieval_s", 0.0) for r in backend), 6),
                   **found}
+        if phase == "first_dispatch":
+            from deepspeed_tpu.telemetry.program_map import \
+                note_first_dispatch
+            note_first_dispatch(program, _worst_cache(backend))
         get_span_store().add({
             "name": "compile", "t0": t0, "t1": t1, "id": next(_IDS),
             "parent": under[0], "round": under[1], "uids": None,

@@ -20,7 +20,15 @@ What the parser understands (jax 0.4.37 → current ``compiled.as_text()``):
   so a collective can be classified as living inside a while-loop body —
   the GAS ``lax.scan`` compiles to ONE while loop, and XLA's LICM hoists
   loop-invariant param gathers into the entry computation, which is why
-  static counting must know in-body from main-line.
+  static counting must know in-body from main-line;
+- every instruction of every computation (``parse_module``): its opcode,
+  its ``metadata={op_name=...}``, the computations it references
+  (``calls=``, ``to_apply=``, ``body=``, ``condition=``,
+  ``branch_computations=``, ``called_computations=``), a fusion's
+  ``kind=`` and a custom call's target. ``instruction_rows`` turns that
+  into the table ``telemetry.program_map`` keeps: one row for each
+  instruction the device line of a trace can name, with the scope it was
+  traced under and what a fusion HOLDS (``holds``).
 
 Replica-group decoding: partition id ``p`` maps to mesh coordinates via
 row-major unraveling over the canonical axis order
@@ -97,13 +105,6 @@ class CollectiveOp:
 
 # ------------------------------------------------------------------ parsing
 
-# `%name = TYPE op(` where TYPE is a shape or a tuple of shapes.
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*"
-    r"(?P<rtype>\([^)]*\)|[a-z][a-z0-9]*\[[^\]]*\](?:\{[^}]*\})?)\s+"
-    r"(?P<op>all-gather|all-reduce|reduce-scatter|collective-permute|"
-    r"all-to-all)(?:-start)?\(")
-
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 
 # `%name (args) -> result {` opens a computation (ENTRY or region).
@@ -119,6 +120,38 @@ _PAIRS_RE = re.compile(r"source_target_pairs=\{([\d,{}\s]*)\}")
 
 _IOTA_RE = re.compile(
     r"\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
+
+_MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
+
+# `%name = TYPE opcode(`: nothing in a TYPE (tuples, `{1,0:T(8,128)S(1)}`
+# tilings, `/*index=5*/` comments) is a space followed by `word(`, so the
+# first such word after the `=` is the opcode.
+_ANY_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<rtype>.*?)\s"
+    r"(?P<op>[a-z][\w\-]*)\(")
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_KIND_RE = re.compile(r"\bkind=(\w+)")
+_TARGET_RE = re.compile(r'custom_call_target="([^"]*)"')
+_REF_RE = re.compile(
+    r"\b(calls|to_apply|body|condition|branch_computations|"
+    r"called_computations)=(\{[^}]*\}|%?[\w.\-]+)")
+_REF_NAME_RE = re.compile(r"%?([\w.\-]+)")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _operands_end(tail: str) -> int:
+    """Index in `tail` (the text after `opcode(`) of the parenthesis that
+    closes the operand list."""
+    depth = 0
+    for i, ch in enumerate(tail):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            if depth == 0:
+                return i
+            depth -= 1
+    return len(tail)
 
 
 def _parse_result_shape(rtype: str) -> Tuple[str, Tuple[int, ...]]:
@@ -183,35 +216,96 @@ def parse_replica_groups(text: str) -> Tuple[Tuple[int, ...], ...]:
     return _parse_explicit_groups(text)
 
 
-def parse_collectives(hlo_text: str) -> List[CollectiveOp]:
-    """All collective instructions in one optimized-HLO module dump, each
-    tagged with its enclosing computation and whether that computation is
-    a while-loop body."""
+@dataclass(frozen=True)
+class Instruction:
+    """One instruction of one computation of an optimised module."""
+    name: str                       # `fusion.123`, no `%`
+    opcode: str                     # `fusion`, `while`, `all-reduce-start`
+    computation: str                # the computation it lies in
+    op_name: str                    # metadata op_name, "" where it has none
+    operands: Tuple[str, ...]       # the instructions it reads, in order
+    refs: Tuple[Tuple[str, str], ...]   # (attribute, computation) it names
+    kind: str                       # a fusion's `kind=`, else ""
+    target: str                     # a custom call's target, else ""
+    collective: Optional[CollectiveOp]  # decoded, for the five families
+
+    def called(self, *attrs: str) -> Tuple[str, ...]:
+        return tuple(c for a, c in self.refs if a in attrs)
+
+
+@dataclass(frozen=True)
+class Module:
+    name: str                       # `jit_train_batch`, as the trace prints it
+    entry: str                      # the ENTRY computation
+    computations: Dict[str, Tuple[Instruction, ...]]
+
+
+def _collective_of(line: str, kind: str, rtype: str, computation: str,
+                   in_loop: bool) -> CollectiveOp:
+    dtype, shape = _parse_result_shape(rtype)
+    gm = _GROUPS_RE.search(line)
+    groups = parse_replica_groups(gm.group(1)) if gm else ()
+    pm = _PAIRS_RE.search(line)
+    pairs: Tuple[Tuple[int, int], ...] = ()
+    if pm:
+        pairs = tuple(
+            (int(a), int(b))
+            for a, b in re.findall(r"\{(\d+),(\d+)\}", pm.group(0)))
+    return CollectiveOp(
+        kind=kind, dtype=dtype, shape=shape, replica_groups=groups,
+        source_target_pairs=pairs, computation=computation, in_loop=in_loop)
+
+
+def parse_module(hlo_text: str) -> Module:
+    """Every instruction of every computation of one optimised-HLO module
+    dump (`compiled.as_text()`), in the text's order."""
     bodies = set(_BODY_RE.findall(hlo_text))
-    ops: List[CollectiveOp] = []
-    computation = ""
+    name = entry = computation = ""
+    comps: Dict[str, List[Instruction]] = {}
     for line in hlo_text.splitlines():
+        if not name:
+            mod = _MODULE_RE.match(line)
+            if mod:
+                name = mod.group(1)
+                continue
         comp = _COMP_RE.match(line)
         if comp:
             computation = comp.group(1)
+            comps.setdefault(computation, [])
+            if line.lstrip().startswith("ENTRY"):
+                entry = computation
             continue
-        m = _INSTR_RE.match(line)
+        m = _ANY_INSTR_RE.match(line)
         if m is None:
             continue
-        dtype, shape = _parse_result_shape(m.group("rtype"))
-        gm = _GROUPS_RE.search(line)
-        groups = parse_replica_groups(gm.group(1)) if gm else ()
-        pm = _PAIRS_RE.search(line)
-        pairs: Tuple[Tuple[int, int], ...] = ()
-        if pm:
-            pairs = tuple(
-                (int(a), int(b))
-                for a, b in re.findall(r"\{(\d+),(\d+)\}", pm.group(0)))
-        ops.append(CollectiveOp(
-            kind=m.group("op"), dtype=dtype, shape=shape,
-            replica_groups=groups, source_target_pairs=pairs,
-            computation=computation, in_loop=computation in bodies))
-    return ops
+        op, tail = m.group("op"), line[m.end():]
+        end = _operands_end(tail)
+        operands, tail = tuple(_OPERAND_RE.findall(tail[:end])), tail[end:]
+        base = op[:-6] if op.endswith("-start") else op
+        coll = _collective_of(line, base, m.group("rtype"), computation,
+                              computation in bodies) \
+            if base in WIRE_KINDS else None
+        refs = tuple((attr, ref) for attr, val in _REF_RE.findall(tail)
+                     for ref in _REF_NAME_RE.findall(val))
+        meta, kind, target = (_OP_NAME_RE.search(tail), _KIND_RE.search(tail),
+                              _TARGET_RE.search(tail))
+        comps.setdefault(computation, []).append(Instruction(
+            name=m.group("name"), opcode=op, computation=computation,
+            op_name=meta.group(1) if meta else "", operands=operands,
+            refs=refs,
+            kind=kind.group(1) if kind and op == "fusion" else "",
+            target=target.group(1) if target else "", collective=coll))
+    return Module(name=name, entry=entry,
+                  computations={k: tuple(v) for k, v in comps.items()})
+
+
+def parse_collectives(hlo_text: str) -> List[CollectiveOp]:
+    """All collective instructions in one optimized-HLO module dump, each
+    tagged with its enclosing computation and whether that computation is
+    a while-loop body. (`-done` lines carry no group and are no `-start`.)"""
+    return [i.collective for instrs in parse_module(hlo_text
+                                                    ).computations.values()
+            for i in instrs if i.collective is not None]
 
 
 # ----------------------------------------------------------- axis decoding
@@ -280,3 +374,142 @@ def op_axes(op: CollectiveOp, sizes_map: Dict[str, int]
     if op.kind == "collective-permute":
         return pairs_to_axes(op.source_target_pairs, sizes_map)
     return groups_to_axes(op.replica_groups, sizes_map)
+
+
+# ------------------------------------------------------ instructions by scope
+
+# what `holds` lists of a fused computation besides its collectives: the
+# opcodes that say what kind of work the fusion is
+HELD_OPCODES = ("dot", "convolution", "dynamic-update-slice", "sort",
+                "gather", "scatter")
+_JIT_PART_RE = re.compile(r"^p?jit\(.*\)$")
+_SCOPE_NAME_RE = re.compile(r"[\w.\-]+")
+
+
+def split_path(path: str) -> List[str]:
+    """`a/transpose(jvp(b/c))/d` -> [`a`, `transpose(jvp(b/c))`, `d`]."""
+    parts, depth, cur = [], 0, []
+    for ch in path:
+        if ch == "/" and depth == 0:
+            parts.append("".join(cur))
+            cur = []
+            continue
+        depth += (ch == "(") - (ch == ")")
+        cur.append(ch)
+    parts.append("".join(cur))
+    return [p for p in parts if p]
+
+
+def scope_of(op_name: str) -> str:
+    """An instruction's `op_name` with the `jit(...)` / `pjit(...)` parts of
+    its path taken off: what is left is the `jax.named_scope` and flax
+    module names, JAX's own `jvp(...)` / `transpose(jvp(...))` and, last,
+    the primitive. Of a fusion's merged metadata (`a;b`) the first."""
+    return "/".join(p for p in split_path(op_name.split(";", 1)[0])
+                    if not _JIT_PART_RE.match(p))
+
+
+def phase_of(scope: str) -> Optional[str]:
+    """`bwd` under a `transpose(`, `fwd` under a `jvp(` alone, else None."""
+    if "transpose(" in scope:
+        return "bwd"
+    return "fwd" if "jvp(" in scope else None
+
+
+def scope_names(scope: str) -> Tuple[str, ...]:
+    """Every name in a scope, the ones inside `jvp(...)` included."""
+    return tuple(_SCOPE_NAME_RE.findall(scope))
+
+
+def _held_label(i: Instruction, sizes_map: Optional[Dict[str, int]]
+                ) -> Optional[str]:
+    if i.collective is not None:
+        if sizes_map is None:
+            return i.opcode
+        axes, _ = op_axes(i.collective, sizes_map)
+        return f"{i.opcode}[{','.join(axes)}]"
+    if i.opcode == "custom-call":
+        return f"custom-call:{i.target}"
+    return i.opcode if i.opcode in HELD_OPCODES else None
+
+
+def instruction_rows(hlo_text: str,
+                     sizes_map: Optional[Dict[str, int]] = None
+                     ) -> Tuple[str, List[Dict[str, object]]]:
+    """(module name, rows): one row for each instruction the device line
+    of a profile can name, which is every instruction of the entry
+    computation and of the computations control flow reaches from it
+    (`while` bodies and conditions, a conditional's branches, a `call`'s
+    target); the inside of a fusion runs as ONE op and is summed up in the
+    fusion's `holds`.
+
+    A row: `instr`, `module`, `opcode`, `scope` (`scope_of`), `phase`
+    (`phase_of`), `loop` (the innermost `while` body it lies in, else
+    None) and `holds`: for a `fusion`, a `call` or an async wrapper the
+    sorted `HELD_OPCODES`, `custom-call:<target>` and collectives of the
+    computation it calls, followed through nested calls; for any other
+    instruction its own label, so that "is, or holds" is one test. A
+    collective is written `all-reduce[data]`, `all-gather-start[data,model]`
+    with the mesh axes `op_axes` decodes under `sizes_map` (bare without
+    one). An instruction that carries no metadata of its own (a copy, a
+    product the compiler placed) takes the scope of the first of its
+    operands that has one, else, in a loop body, the `while`'s, and says
+    so (`inferred`)."""
+    mod = parse_module(hlo_text)
+    comps = mod.computations
+
+    held_of: Dict[str, Tuple[str, ...]] = {}
+
+    def held(comp: str, seen: Tuple[str, ...] = ()) -> Tuple[str, ...]:
+        if comp in held_of:
+            return held_of[comp]
+        out = set()
+        for i in comps.get(comp, ()):
+            label = _held_label(i, sizes_map)
+            if label:
+                out.add(label)
+            for c in i.called("calls", "called_computations"):
+                if c not in seen:
+                    out.update(held(c, seen + (comp,)))
+        held_of[comp] = tuple(sorted(out))
+        return held_of[comp]
+
+    rows: List[Dict[str, object]] = []
+    # (computation, the loop body it lies in, the scope of that `while`)
+    todo, done = [(mod.entry, None, "")], set()
+    while todo:
+        comp, loop, loop_scope = todo.pop()
+        if comp in done or comp not in comps:
+            continue
+        done.add(comp)
+        scopes: Dict[str, str] = {}     # of this computation's instructions
+        for i in comps[comp]:
+            scope, inferred = "", False
+            if i.opcode != "parameter":   # its `op_name` is the argument's
+                scope = scope_of(i.op_name)
+                if not scope:
+                    scope = next((scopes[o] for o in i.operands
+                                  if scopes.get(o)), loop_scope)
+                    inferred = bool(scope)
+            scopes[i.name] = scope
+            called = i.called("calls", "called_computations")
+            if called and i.opcode != "custom-call":
+                holds = tuple(sorted({h for c in called for h in held(c)}))
+            else:
+                label = _held_label(i, sizes_map)
+                holds = (label,) if label else ()
+            row: Dict[str, object] = {
+                "instr": i.name, "module": mod.name, "opcode": i.opcode,
+                "scope": scope, "phase": phase_of(scope), "loop": loop,
+                "holds": list(holds)}
+            if inferred:
+                row["inferred"] = True
+            rows.append(row)
+            if i.opcode == "while":
+                body = (i.called("body") or (loop,))[0]
+                for c in i.called("body", "condition"):
+                    todo.append((c, body, scope))
+            elif i.opcode in ("call", "conditional"):
+                for c in i.called("to_apply", "calls", "branch_computations"):
+                    todo.append((c, loop, loop_scope))
+    return mod.name, rows

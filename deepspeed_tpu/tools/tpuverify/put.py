@@ -213,7 +213,8 @@ def _v1_cache(eng, key):
 
 
 def build_v1_chip_dispatch_put(model_cls=None) -> ProgramUnderTest:
-    """`jit_ds_v1_generate` of a tiny llama (GQA group of 4, so that 'auto'
+    """A v1 generate program (`jit_ds_v1_generate_b<rows>_s<prompt>_n<new>` on
+    a device trace since PR 56) of a tiny llama (GQA group of 4, so that 'auto'
     picks the decode kernel) TRACED as the chip dispatches it: the dense
     decode kernel on the stacked cache by layer, the staged token, the
     Pallas writer. Off the chip the same program attends through the masked

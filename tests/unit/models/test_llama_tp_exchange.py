@@ -159,13 +159,13 @@ def test_a_mesh_the_exchange_does_not_know_gets_the_parent_program(
 
 
 @pytest.mark.parametrize("mesh_dims,want", [
-    (dict(dp=2, tp=2), (8, 2)), (dict(dp=1), (0, 1))],
+    (dict(dp=2, tp=2), 8), (dict(dp=1), 0)],
     ids=["dp2_tp2", "one_device"])
 def test_the_engine_counts_what_the_step_named(mesh_dims, want):
-    """`tp_exchange_sites` and `tp_half_batches` on the `compile` span of
-    `train:train_batch` and as hub gauges (docs/telemetry.md): read off the
-    traced step, 8 and 2 where the layers' reductions are exchanges, 0 and
-    1 where the partitioner places them."""
+    """`tp_exchange_sites` on the `compile` span of `train:train_batch`
+    and as a hub gauge (docs/telemetry.md): read off the traced step, 8
+    where the layers' reductions are exchanges (a layer then walks two
+    half-batches), 0 where the partitioner places them."""
     import deepspeed_tpu
     from deepspeed_tpu.telemetry.spans import get_span_store
     cfg, params, _ = _case(remat=True, remat_policy="checkpoint_dots")
@@ -188,6 +188,6 @@ def test_the_engine_counts_what_the_step_named(mesh_dims, want):
     fields = [s["fields"] for s in get_span_store().spans()
               if s["name"] == "compile"
               and s["fields"]["program"] == "train:train_batch"][-1]
-    assert (fields["tp_exchange_sites"], fields["tp_half_batches"]) == want
-    assert (engine.telemetry.gauges["tp_exchange_sites"],
-            engine.telemetry.gauges["tp_half_batches"]) == want
+    assert fields["tp_exchange_sites"] == want
+    assert "tp_half_batches" not in fields   # it was `sites > 0`: gone
+    assert engine.telemetry.gauges["tp_exchange_sites"] == want

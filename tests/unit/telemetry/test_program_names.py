@@ -27,7 +27,8 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.engine_v2 import chunk_row_widths
 from deepspeed_tpu.models.llama import llama_config, materialize_params
-from deepspeed_tpu.telemetry import TelemetryHub, get_span_store
+from deepspeed_tpu.telemetry import TelemetryHub, get_span_store, jit_name
+from deepspeed_tpu.telemetry.program_map import _KEPT
 from deepspeed_tpu.telemetry.hub import set_hub
 from deepspeed_tpu.utils import groups
 
@@ -157,7 +158,7 @@ CASES = [
     ("v1-greedy", "v1_run", f"generate:{GREEDY}",
      "v1:generate:b2_s8_n4", "v1"),
     ("v1-sampled", "v1_run", f"generate:{SAMPLED}",
-     "v1:generate:b2_s8_n4", "v1"),
+     "v1:generate:b2_s8_n4:t0.7_k5", "v1"),
 ] + [(f"v2-{name}", "v2_run", name, name, "v2") for name in (
     [f"prefill:{BUCKET}", "decode", f"chunk_batch:{CHUNK}"]
     + [f"fused_batch:{CHUNK}:{w}" for w in WIDTHS]
@@ -170,7 +171,7 @@ def test_a_program_is_registered_once_under_its_stable_name(
         request, run, detector_name, span_name, engine):
     first, second = request.getfixturevalue(run)
     # the two sampling keys of v1 are two programs of one shape: each
-    # leaves its own span under the shape's name
+    # leaves its own span, the sampled one's name saying how it samples
     shared = sum(1 for c in CASES if c[1] == run and c[3] == span_name)
     assert first["spans"].count((span_name, "first_dispatch", engine)) \
         == shared
@@ -178,6 +179,11 @@ def test_a_program_is_registered_once_under_its_stable_name(
             and s[1] != "first_dispatch"] == []
     assert first["seen"].get(detector_name) == 1, sorted(first["seen"])
     assert "@" not in detector_name and "@" not in span_name
+    # the jitted function, and so the module a device trace shows, is
+    # named after the span: the program map is kept under that name
+    kept = {(e["program"], e["detector"]): m for m, e in _KEPT.items()}
+    assert kept[(span_name, detector_name)] == "jit_" + jit_name(
+        ("v2:" if engine == "v2" else "") + span_name)
     # the second dispatch: no span, no new signature, no miss
     assert second["spans"] == []
     assert second["seen"][detector_name] == 1

@@ -13,7 +13,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-PHASES = ["start", "kernels", "train", "v1", "v2"]
+PHASES = ["start", "kernels", "train", "v1", "v2", "scopes"]
 
 
 def _run(args, cache_dir, **extra):
@@ -78,6 +78,23 @@ def test_phase_lines_carry_what_is_worth_knowing(rehearsal):
     assert by["v2"]["vs_v1"]["identical_sequences"] <= by["v2"]["requests"]
 
 
+def test_the_scopes_phase_holds_the_first_loss_and_names_its_programs(
+        rehearsal):
+    """Off the chip a profile has no device line, so the share the program
+    map names is not judged here; what is: the first loss with the scopes
+    and with every `with jax.named_scope` a no-op, bit for bit, and the map
+    built from the executables that ran."""
+    scopes = {l["phase"]: l for l in rehearsal["lines"]
+              if "phase" in l}["scopes"]
+    assert scopes["loss_first_hex"] == scopes["loss_first_hex_without_scopes"]
+    assert scopes["train"]["programs"] == ["train:train_batch"]
+    assert [p.split(":")[:2] for p in scopes["v1"]["programs"]] == \
+        [["v1", "generate"]]
+    for part in ("train", "v1"):
+        assert scopes[part]["map_cache"] == ["memory"]
+        assert scopes[part]["named_share"] is None
+
+
 def test_cache_goes_where_the_environment_says_and_nowhere_else(rehearsal):
     start = rehearsal["lines"][0]
     assert start["compile_cache"] == rehearsal["cache_dir"]
@@ -123,6 +140,7 @@ def test_a_phase_that_raises_fails_the_run(rehearsal):
                       DS_TPU_FAULTS="generate_dispatch:raise")
     assert out.returncode == 1
     by = {l["phase"]: l for l in lines if "phase" in l}
-    assert [by[p]["ok"] for p in PHASES[1:]] == [True, True, False, False]
+    assert [by[p]["ok"] for p in PHASES[1:]] == [True, True, False, False,
+                                                 False]
     assert "InjectedFault" in by["v1"]["error"]
     assert lines[-1]["ok"] is False and lines[-1]["device"]["platform"] == "cpu"

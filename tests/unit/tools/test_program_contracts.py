@@ -337,7 +337,8 @@ def test_dense_stack_in_place_is_not_vacuous():
 
 
 def test_v1_generate_keeps_its_dense_cache_in_place():
-    """`jit_ds_v1_generate` of a tiny llama as the chip dispatches it: the
+    """A v1 generate program (`jit_ds_v1_generate_b2_s8_n4`: one module a key
+    since PR 56) of a tiny llama as the chip dispatches it: the
     token loop holds the decode kernel and the writer on the carried stack,
     and no finding. Budget: K and V, one stack each; one writer a step."""
     from deepspeed_tpu.tools.tpuverify.jaxpr_util import primitive_eqns
@@ -358,7 +359,7 @@ def test_v1_generate_keeps_its_dense_cache_in_place():
 
 
 def test_the_parents_v1_program_fails_kv_pool_in_place():
-    """The per-layer view, which `jit_ds_v1_generate` held before PR 42 (a
+    """The per-layer view, which a v1 generate program held before PR 42 (a
     model with no say in its cache still gets it): scanned over in the
     token loop, and re-laid for the kernel."""
     from deepspeed_tpu.models.llama import LlamaForCausalLM
