@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.models.common import causal_lm_loss
 from deepspeed_tpu.ops.attention import apply_rotary_emb, attention, rope_cos_sin
 from deepspeed_tpu.runtime.domino.transformer import (
-    TP_EXCHANGE, DominoTransformerLayer, exchange_layout, hold_until,
+    TP_EXCHANGE, DominoTransformerLayer, exchange_layout, forward_hold, hold_until, land_dw,
     merge_rows, parallel_products, split_rows)
 from deepspeed_tpu.sequence.layer import DistributedAttention
 from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
@@ -229,18 +229,24 @@ class LlamaAttention(nn.Module):
     tp: Any = None
 
     @nn.compact
-    def __call__(self, h, cos, sin, kv=None, mask=None, index=None):
+    def __call__(self, h, cos, sin, kv=None, mask=None, index=None,
+                 landings=None, **tie):
+        """`landings`: the kernels' `dW` carriers where their reductions
+        over the batch axes are exchanges (`_dw_landings`), by name.
+        `tie`: one array by the keyword `parallel_products` knows it by
+        (`DominoTransformerLayer` orders its half-batches' backward phases
+        with it); then `(out, tied)` is returned."""
         cfg = self.cfg
         hd, nh, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.num_key_value_heads
         qb = cfg.attention_qkv_bias  # Qwen2-style qkv bias (o_proj stays bias-free)
         b, s = h.shape[:2]
-        column, row = parallel_products(h, self.tp)
+        column, row, tied = parallel_products(h, self.tp, landings, **tie)
         q = _dense(nh * hd, ("embed", "heads"), cfg.dtype, "q_proj", qb,
-                   column)(h)
+                   column("q_proj"))(h)
         k = _dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "k_proj", qb,
-                   column)(h)
+                   column("k_proj"))(h)
         v = _dense(nkv * hd, ("embed", "kv_heads"), cfg.dtype, "v_proj", qb,
-                   column)(h)
+                   column("v_proj"))(h)
         q = q.reshape(b, s, nh, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
@@ -286,8 +292,9 @@ class LlamaAttention(nn.Module):
 
             ctx = DistributedAttention(core)(q, k, v)
         ctx = ctx.reshape(b, s, nh * hd)
-        return _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
-                      "o_proj", cfg.attention_o_bias, row)(ctx)
+        out = _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
+                     "o_proj", cfg.attention_o_bias, row("o_proj"))(ctx)
+        return out if tied is None else (out, tied)
 
 
 class LlamaMLP(nn.Module):
@@ -295,32 +302,37 @@ class LlamaMLP(nn.Module):
     tp: Any = None      # as `LlamaAttention.tp`
 
     @nn.compact
-    def __call__(self, h, held=None):
-        """`held`: an array that no one may use before this FFN's
-        activation stands (`DominoTransformerLayer` keeps its half-batches
-        a phase apart with it); then `(out, held)` is returned."""
+    def __call__(self, h, hold=None, landings=None, **tie):
+        """`landings` and `tie`: as `LlamaAttention`'s. `hold`: an array
+        that no one may use before this FFN's activation stands
+        (`hold_until`); it comes back beside the output as a tied one
+        does, which is held the same way forward (`forward_hold`)."""
         cfg = self.cfg
-        column, row = parallel_products(h, self.tp)
+        column, row, tied = parallel_products(h, self.tp, landings, **tie)
         gate_d = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
-                        "gate_proj", dot_general=column)
+                        "gate_proj", dot_general=column("gate_proj"))
         up_d = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
-                      "up_proj", dot_general=column)
+                      "up_proj", dot_general=column("up_proj"))
         down_d = _dense(cfg.hidden_size, ("mlp_in", "embed"), cfg.dtype,
-                        "down_proj", dot_general=row)
+                        "down_proj", dot_general=row("down_proj"))
         from jax.ad_checkpoint import checkpoint_name
 
-        def ffn(hc, held=None):
+        def ffn(hc, hold=None, barrier=hold_until):
             # gate/up outputs are the S-proportional dot saves that OOM
             # HBM at long context — 'host_offload_dense_mlp' offloads the
             # named tensors instead so backward skips both GEMM recomputes
             g = checkpoint_name(gate_d(hc), "mlp_gate_up")
             u = checkpoint_name(up_d(hc), "mlp_gate_up")
-            if held is None:
+            if hold is None:
                 return down_d(nn.silu(g) * u)
-            act, held = hold_until(nn.silu(g) * u, held)
-            return down_d(act), held
-        if held is not None:
-            return ffn(h, held)
+            act, hold = barrier(nn.silu(g) * u, hold)
+            return down_d(act), hold
+        if tied is not None:
+            # a tied array is held as well, forward only: the backward has
+            # the tie, and the forward stays what `hold` made it
+            return ffn(h, tied, forward_hold)
+        if hold is not None:
+            return ffn(h, hold)
         cs = cfg.mlp_chunk_size
         if not cs or h.shape[1] <= cs or h.shape[1] % cs:
             return ffn(h)
@@ -345,6 +357,36 @@ def _exchange_layout(cfg: LlamaConfig, rows: int):
         return None
     return exchange_layout(rows, cfg.num_attention_heads,
                            cfg.num_key_value_heads, cfg.intermediate_size)
+
+
+# Which product each of a layer's kernels enters (`column_parallel`,
+# `row_parallel`), by child module and name.
+_LAYER_KERNELS = {
+    "self_attn": {"q_proj": "column", "k_proj": "column", "v_proj": "column",
+                  "o_proj": "row"},
+    "mlp": {"gate_proj": "column", "up_proj": "column", "down_proj": "row"}}
+
+
+def _dw_landings(block: nn.Module, tp) -> dict:
+    """`{child: {kernel name: carrier}}`: where a training layer's `dW`
+    reductions over the batch axes are exchanges onto the shards the ZeRO
+    plan gives the gradients' accumulators (`land_dw`: read off the plan of
+    the step being traced, the layer's own place in the parameters' tree
+    and the shapes). The block reads its children's kernels itself, once
+    for both half-batches, so that their partial `dW` add up before ONE
+    exchange a kernel. Empty where nothing is named: no layout, no plan, no
+    parameters yet (`init`)."""
+    if tp is None:
+        return {}
+    found = {}
+    for child, uses in _LAYER_KERNELS.items():
+        if not block.has_variable("params", child):
+            return {}
+        held = nn.meta.unbox(block.get_variable("params", child))
+        found[child] = land_dw(
+            tp, {name: (held[name]["kernel"].astype(block.cfg.dtype), product)
+                 for name, product in uses.items()}, (*block.path, child))
+    return found
 
 
 class LlamaBlock(nn.Module):
@@ -380,18 +422,24 @@ class LlamaBlock(nn.Module):
         h = checkpoint_name(h, "fpdt_residual")
         # (the two halves' attention is ONE traced function, `nn.jit`: it
         # keeps the step's tracing and lowering, which is set-up time, near
-        # what one walk costs; the FFN's two calls differ by the hold)
+        # what one walk costs; the FFN's two calls differ by the hold, and
+        # so do the attention's where the phases are `ordered`)
         attention = LlamaAttention if tp is None else _SharedAttention
         attn = attention(cfg, tp, name="self_attn")
         mlp = LlamaMLP(cfg, tp, name="mlp")
+        landings = _dw_landings(self, tp)
+        ordered = any(landings.values())
         layer = DominoTransformerLayer(
-            lambda x: attn(x, cos, sin), mlp,
+            lambda x, **tie: attn(x, cos, sin,
+                                  landings=landings.get("self_attn"), **tie),
+            lambda x, *hold, **tie: mlp(x, *hold,
+                                        landings=landings.get("mlp"), **tie),
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm"),
             RMSNorm(cfg.rms_norm_eps, cfg.dtype,
                     name="post_attention_layernorm"),
             # mid-block residual: saving it lets backward rebuild mlp_normed
             # with one cheap RMSNorm instead of re-running the o-projection
-            mid=lambda x: checkpoint_name(x, "resid_mid"))
+            mid=lambda x: checkpoint_name(x, "resid_mid"), ordered=ordered)
         return layer(h), None
 
 

@@ -39,11 +39,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.comm.comms_logging import get_comms_logger
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
-from deepspeed_tpu.runtime.domino.transformer import count_exchanges
+from deepspeed_tpu.runtime.domino.transformer import (DW_EXCHANGE,
+                                                      count_exchanges)
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, build_lr_schedule
 from deepspeed_tpu.runtime.precision import (
     LossScaler, LossScaleState, cast_tree, clip_grads_by_global_norm, global_grad_norm)
-from deepspeed_tpu.runtime.zero.partition import ZeroShardingPlan
+from deepspeed_tpu.runtime.zero.partition import (ZeroShardingPlan,
+                                                  landing_on)
 from deepspeed_tpu.ops.optimizers import GradientTransformation, build_optimizer
 from deepspeed_tpu.telemetry import (
     MetricsState, RecompileDetector, TelemetryHub, annotate, compile_span,
@@ -51,6 +53,7 @@ from deepspeed_tpu.telemetry import (
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.groups import MeshTopology
 from deepspeed_tpu.utils.logging import log_dist, logger
+from deepspeed_tpu.utils.partitioning import BATCH_AXES
 from deepspeed_tpu.utils.timer import (
     BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
     TRAIN_BATCH_TIMER, SynchronizedWallClockTimer, ThroughputTimer)
@@ -356,6 +359,15 @@ class DeepSpeedEngine:
             grad_acc=None if self._elide_grad_acc else grad_shardings,
             scaler=to_shard("misc")(scaler_specs))
         self._grad_shardings = grad_shardings
+        # where each leaf's gradient comes to rest, readable by the layers
+        # while the plain step is traced (`zero/partition.landing_on`; the
+        # wire mode's accumulators carry a worker axis and its step is a
+        # manual region, in which the layers name nothing)
+        self._grad_landing = None if self._onebit_wire else \
+            jax.tree_util.tree_map(
+                lambda p, s: jax.ShapeDtypeStruct(p.shape, jnp.float32,
+                                                  sharding=s),
+                params_shapes, grad_shardings)
         self._param_specs = param_specs
         self._grad_specs = grad_specs
         self._shardings = shardings
@@ -620,8 +632,9 @@ class DeepSpeedEngine:
                                                          state.scaler)
                     return scaled, (loss, aux)
 
-                grads, (loss, aux) = jax.grad(scaled_loss, has_aux=True)(
-                    state.params)
+                with landing_on(self.plan, self._grad_landing):
+                    grads, (loss, aux) = jax.grad(scaled_loss, has_aux=True)(
+                        state.params)
         if self.loss_scaler.enabled:
             # Per-micro overflow tracking (reference stage_1_and_2.py:1173
             # `update_overflow_tracker_for_param_grad`): detect non-finite
@@ -1031,12 +1044,16 @@ class DeepSpeedEngine:
                 fn = self._get_jit(name)
                 # the tracing the call below uses (it traces nothing
                 # again): kept for the program map, and read for the
-                # layers' tensor-parallel reductions that are named
+                # layers' tensor-parallel reductions and their kernels'
+                # `dW` reductions over the batch axes that are named
                 # exchanges, 0 where the partitioner places them
                 traced = fn.trace(state, *rest)
                 keep_program(f"train:{name}", traced, mesh=self.mesh,
                              detector=name, under_mesh=True)
-                found.update(tp_exchange_sites=count_exchanges(traced.jaxpr))
+                found.update(
+                    tp_exchange_sites=count_exchanges(traced.jaxpr),
+                    dw_exchange_sites=count_exchanges(
+                        traced.jaxpr, BATCH_AXES, DW_EXCHANGE))
                 for field, value in found.items():
                     self.telemetry.gauge(field, value)
                 out = fn(state, *rest)

@@ -16,6 +16,19 @@ This is the TPU-native replacement for the reference's hook-driven machinery:
   stage-3 params) placed in `pinned_host` memory via sharding memory kinds;
   XLA streams host↔HBM transfers around the step.
 
+Where the layer names the gradient sync itself (PR 57): "XLA emits the
+reduce-scatter" holds on paper; on a TPU v5e 2x2 the compiler keeps no
+reduce-scatter, so a kernel's `dW` over a `data`-cut accumulator came out as
+a SYNCHRONOUS all-reduce of the whole product and a slice, inside the
+backward's layer loop with nothing beside it (102.6 ms of a 925 ms step at
+Qwen2.5-3B, 20 layers, dp2 x tp2). `LlamaBlock`'s training path therefore
+exchanges each kernel's partial `dW` onto the accumulator's shard itself
+(`runtime/domino/transformer.land_dw`), and it has to KNOW that shard: the
+engine makes the accumulators' layout readable while it traces a step
+(`landing_on`, `accumulator_at_rest` below). A leaf the plan does not cut
+inside a layer, or calls too small (`param_persistence_threshold`), keeps the
+partitioner's form, as does every gradient outside those layers.
+
 The planner composes with tensor/sequence/expert parallelism: it starts from
 the model's own logical `PartitionSpec` (TP axes) and adds the ZeRO axes
 ('data','expert' for dense params, 'data' for per-expert params) to a free,
@@ -24,9 +37,11 @@ divisible dimension.
 
 from __future__ import annotations
 
+import contextlib
 import math
+from collections.abc import Mapping
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Iterator, Optional, Tuple
 
 import jax
 import numpy as np
@@ -91,6 +106,47 @@ def add_axes_to_spec(spec: Optional[P], shape: Tuple[int, ...],
             f"ZeRO: no dimension of shape {tuple(shape)} divisible by "
             f"{factor} over axes {new_axes}; leaf stays replicated")
     return P(*entries)
+
+
+# What the step being traced lands its gradients on: (plan, the gradient
+# accumulators as the engine lays them at rest), set by `landing_on` for the
+# time of a trace and read by the model's layers (`accumulator_at_rest`).
+_LANDING: Optional[Tuple["ZeroShardingPlan", Any]] = None
+
+
+@contextlib.contextmanager
+def landing_on(plan: "ZeroShardingPlan", accumulators) -> Iterator[None]:
+    """While a step's forward and backward are traced under this, a layer can
+    read where each leaf's gradient comes to rest: `accumulators` is the
+    parameters' tree of `jax.ShapeDtypeStruct`s whose `sharding` is the
+    accumulator's (`grad_accum_spec` on the installed mesh). The engine
+    enters it around its `jax.grad`; a bare `jax.grad` over a model sees
+    none, and names nothing."""
+    global _LANDING
+    was, _LANDING = _LANDING, (plan, accumulators)
+    try:
+        yield
+    finally:
+        _LANDING = was
+
+
+def accumulator_at_rest(path: Tuple[str, ...]):
+    """The accumulator (shape, dtype, sharding) of the parameter at `path`
+    (its keys in the parameters' tree) under the plan of the step being
+    traced; None with no plan, for a path the tree does not hold, and for a
+    leaf smaller than the plan's `param_persistence_threshold`: what the
+    plan itself calls too small to be worth a collective of its own."""
+    if _LANDING is None:
+        return None
+    plan, node = _LANDING
+    for key in path:
+        if not isinstance(node, Mapping) or key not in node:
+            return None
+        node = node[key]
+    if not hasattr(node, "sharding") or \
+            math.prod(node.shape) < plan.config.param_persistence_threshold:
+        return None
+    return node
 
 
 @dataclass

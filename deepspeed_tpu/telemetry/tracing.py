@@ -200,7 +200,8 @@ def compile_span(program: str, engine: str, phase: str = "first_dispatch",
     less those is the rest of a first dispatch: argument checks, the feeds'
     `device_put`, the dispatch and, where the caller fetches, the run.
     It yields a dict: what the caller puts there of the program it traced
-    (the train engine's `tp_exchange_sites`) joins the span's fields. A
+    (the train engine's `tp_exchange_sites` and `dw_exchange_sites`) joins the
+    span's fields. A
     program kept for the map (`program_map.keep`, by the caller, inside
     this span) is told what the persistent cache said of this compile.
     `under` is the (id, round) of the span it happens in

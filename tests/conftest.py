@@ -90,6 +90,7 @@ LONGEST_FIRST = (
     "tests/unit/pipe/test_pipeline_zoo.py",
     "tests/unit/ops/test_decode_attention.py",
     "tests/unit/inference/test_kv_pool_decode_kernel.py",
+    "tests/unit/models/test_llama_tp_exchange.py",
     "tests/unit/models/test_ling_linear.py",
     "tests/unit/inference/test_kv_pool_in_place.py",
     "tests/perfbench/test_keye_cell.py",
