@@ -95,6 +95,15 @@ class Sizes:
     kda: Tuple[int, int, int, int]
     # latent decode attention: layers, rows, query heads, slots, rank, rope
     mla: Tuple[int, int, int, int, int, int]
+    # the same kernel where the heads' operations meet the slab's bytes:
+    # layers, rows, query heads, slots, rank, rope (openPangu's at the
+    # benchmark cell's batch and length)
+    mla_wide: Tuple[int, int, int, int, int, int]
+    # dense causal latent prefill, a chunk of one row's queries against its
+    # slab: layers, rows, query heads, slots, rank, rope, nope and value
+    # widths, queries a chunk (openPangu's; a quarter-chunk of queries, as
+    # `mla_sparse`)
+    mla_dense: Tuple[int, int, int, int, int, int, int, int, int]
     # learned sparse attention over the stacked dense cache: layers, rows, KV
     # heads, query heads a KV head, slots, head width, index heads, index
     # width AS STORED (a 64-value key in a whole lane row), positions chosen,
@@ -124,6 +133,8 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
              ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
              mla=(1, 128, 32, 2048, 512, 64),
+             mla_wide=(5, 8, 128, 25600, 512, 64),
+             mla_dense=(2, 8, 128, 25600, 512, 64, 128, 128, 256),
              sparse=(2, 8, 4, 8, 33280, 128, 16, 128, 2048, 2048),
              mla_sparse=(2, 8, 128, 33280, 512, 64, 128, 128, 64, 128, 2048,
                          256))
@@ -139,6 +150,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
              ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
              mla=(2, 3, 4, 32, 32, 8),
+             mla_wide=(2, 3, 128, 64, 32, 8),
+             mla_dense=(2, 3, 4, 64, 32, 8, 16, 16, 16),
              sparse=(2, 3, 2, 2, 64, 16, 4, 8, 8, 16),
              mla_sparse=(2, 3, 4, 64, 32, 8, 16, 16, 4, 8, 8, 16))
 
@@ -698,7 +711,8 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     # ---- latent (MLA) decode, absorbed, and the latent cache's writer ----
     ll, lb, lh, lm, lrank, lrope = sz.mla
 
-    def make_mla(key):
+    def make_mla(key, shape=sz.mla):
+        ll, lb, lh, lm, lrank, lrope = shape
         kq, kr, kc, kn, kp = jax.random.split(key, 5)
         pos = jax.random.randint(kp, (lb,), 0, lm, jnp.int32)
         return (normal(kq, (lb, lh, lrank)), normal(kr, (lb, lh, lrope)),
@@ -706,12 +720,19 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                 normal(kn, (lb, lrank + lrope)))
 
     def run_mla(fn, q_lat, q_rope, stack, pos, new):
-        return fn(q_lat, q_rope, stack, ll - 1, pos + 1,
-                  (lrank + lrope) ** -0.5, new=new, slots=pos)
+        return fn(q_lat, q_rope, stack, stack.shape[0] - 1, pos + 1,
+                  stack.shape[-1] ** -0.5, new=new, slots=pos)
 
     cases.append(KernelCase(
         "mla_latent_decode", functools.partial(run_mla, mla_latent_decode),
         functools.partial(run_mla, mla_latent_decode_reference), make_mla))
+    # 128 heads over rows of 25,600 slots: the plan `mla.decode_block` takes
+    # where the heads' operations meet the slab's bytes
+    cases.append(KernelCase(
+        "mla_latent_decode_h128",
+        functools.partial(run_mla, mla_latent_decode),
+        functools.partial(run_mla, mla_latent_decode_reference),
+        functools.partial(make_mla, shape=sz.mla_wide)))
     cases.append(KernelCase(
         "latent_write_dense",
         lambda q_lat, q_rope, stack, pos, new: latent_write_dense(
@@ -854,6 +875,29 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         functools.partial(run_mla_sparse_prefill,
                           mlas.mla_sparse_prefill_reference, choose_plain),
         make_mla_sparse_prefill))
+
+    # ---- the same prefill kernel with NO bias: every cached row up to the
+    # query's own (openPangu's dense latent attention), a chunk whose causal
+    # edge lies inside the slab ----
+    (dl, db, dh, dm, drank, drope, ddn, ddv, dchunk) = sz.mla_dense
+
+    def make_mla_dense_prefill(key):
+        ks = jax.random.split(key, 4)
+        return (normal(ks[0], (dchunk, dh, ddn)),
+                normal(ks[1], (dchunk, dh, drope)),
+                normal(ks[2], (drank, dh, ddn + ddv)) * drank ** -0.5,
+                normal(ks[3], (dl, db, 1, dm, drank + drope)))
+
+    def run_mla_dense_prefill(fn, qn, qr, w_kvb, lat):
+        return fn(qn, qr, w_kvb, lat, dl - 1, db - 1, dm - 2 * dchunk,
+                  (ddn + drope) ** -0.5).astype(jnp.float32)
+
+    cases.append(KernelCase(
+        "mla_dense_prefill",
+        functools.partial(run_mla_dense_prefill, mlas.mla_dense_prefill),
+        functools.partial(run_mla_dense_prefill,
+                          mlas.mla_dense_prefill_reference),
+        make_mla_dense_prefill))
 
     # ---- block-sparse attention (MHA; layout is static host data) ----
     sblk = min(64, sz.seq // 4)

@@ -36,3 +36,5 @@ from deepspeed_tpu.models.keye_sparse import (
     KeyeSparseConfig, KeyeSparseForCausalLM, keye_sparse_loss_fn)
 from deepspeed_tpu.models.deepseek_sparse import (
     DeepseekSparseConfig, DeepseekSparseForCausalLM, deepseek_sparse_loss_fn)
+from deepspeed_tpu.models.openpangu import (
+    OpenPanguConfig, OpenPanguForCausalLM, openpangu_loss_fn)

@@ -4,6 +4,9 @@ a small learned INDEXER picks for it (DeepSeek Sparse Attention), over
 sigmoid-routed SwiGLU experts. Every layer is `h += Attn(RMSNorm(h)); h +=
 FFN(RMSNorm(h))`; for a token t with `u = RMSNorm(h)`:
 
+(the query's compression, the cached row, absorption and a chunk's write are
+`models/latent.py`'s, shared with `models/openpangu.py`)
+
 - query compression: `cq = RMSNorm(u W_qa)` (`q_lora_rank`); `q = cq W_qb`,
   H heads of `[q_nope (128) | q_rope (64)]`, rotary on the 64;
 - the cached row: `[ckv | kr] = u W_kva` (512 | 64), `c = RMSNorm(ckv)`, `kr
@@ -62,6 +65,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import latent
 from deepspeed_tpu.models.keye_sparse import (INDEX_NORM_EPS, _embedded,
                                               prefill_walk)
 from deepspeed_tpu.models.ling_linear import DenseFFN, _experts
@@ -143,17 +147,9 @@ class DeepseekSparseConfig:
         m = 1.0 if rs is None else yarn_mscale(rs.factor, rs.mscale_all_dim)
         return self.qk_head_dim ** -0.5 * m * m
 
-    @staticmethod
-    def cache_slots(max_len: int) -> int:
-        """The slots a row of the cache is GIVEN for `max_len` positions:
-        whole tiles of the choice's widest block (`sparse_select.
-        CHOICE_BLOCK`, 2,560 slots, which the narrower blocks of every kernel
-        that walks the row divide) once a row is longer than one. The engine
-        rounds a length to 128, and 24,832 = 128 x 2 x 97 would leave every
-        kernel tiles of 256 slots; 33,280 is 13 such blocks as it stands."""
-        from deepspeed_tpu.ops.pallas.sparse_select import CHOICE_BLOCK
-        return max_len if max_len < CHOICE_BLOCK \
-            else -(-max_len // CHOICE_BLOCK) * CHOICE_BLOCK
+    # the slots a row of the cache is given for a length (whole tiles of
+    # the choice's widest block)
+    cache_slots = staticmethod(latent.cache_slots)
 
     def kv_bytes_by_kind(self, batch: int, max_len: int, dtype=None) -> dict:
         """Both kinds this family holds (`capacity_scan.kv_cache_bytes` sums
@@ -184,55 +180,22 @@ class SparseLatentAttention(nn.Module):
         from deepspeed_tpu.ops.pallas import mla_sparse as ms
         from deepspeed_tpu.ops.pallas import sparse_select as ss
         cfg = self.cfg
-        nh, dn, dr, dv, rank = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                                cfg.qk_rope_head_dim, cfg.v_head_dim,
-                                cfg.kv_lora_rank)
+        nh, dn, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                      cfg.v_head_dim)
         hi, di = cfg.index_n_heads, cfg.index_head_dim
         b, s, _ = x.shape
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)  # noqa: E731
-        proj = lambda n, name: _dense(n, ("embed", "heads"), cfg.dtype, name)  # noqa: E731
-        cq = norm("q_a_norm")(_dense(cfg.q_lora_rank, ("embed", None),
-                                     cfg.dtype, "q_a_proj")(x))
-        q = proj(nh * cfg.qk_head_dim, "q_b_proj")(cq).reshape(
-            b, s, nh, cfg.qk_head_dim)
-        c, k_r = jnp.split(_dense(rank + dr, ("embed", None), cfg.dtype,
-                                  "kv_a_proj")(x), [rank], axis=-1)
-        c = norm("kv_a_norm")(c)
-        w_kvb = self.param("kv_b_proj", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), (None, "heads")),
-            (rank, nh * (dn + dv)), F32).astype(cfg.dtype).reshape(
-                rank, nh, dn + dv)
+        start = latent.chunk_start(cache, b, s, row)
+        cq, q_nope, q_rope, lat, w_kvb, positions, rotated = latent.project(
+            self, x, start, cfg.rope_scaling)
         # the indexer's queries come out of the query's own compression
-        q_i = proj(hi * di, "index_q_proj")(cq).reshape(b, s, hi, di)
+        q_i = _dense(hi * di, ("embed", "heads"), cfg.dtype,
+                     "index_q_proj")(cq).reshape(b, s, hi, di)
         k_i = nn.LayerNorm(epsilon=INDEX_NORM_EPS, dtype=cfg.dtype,
                            param_dtype=F32, name="index_k_norm")(
             _dense(di, ("embed", None), cfg.dtype, "index_k_proj")(x))
         w = _dense(hi, ("embed", None), cfg.dtype,
                    "index_w_proj")(x).astype(F32)               # (B, S, Hi)
-
-        if cache is None:
-            start = jnp.zeros((b,), jnp.int32)
-        elif s == 1:
-            start = cache.index
-        else:
-            start = jax.lax.dynamic_slice(cache.index, (row,), (1,))
-        positions = start[:, None] + jnp.arange(s)[None, :]
-        cos, sin = ops.rope_cos_sin(positions, dr, cfg.rope_theta, cfg.dtype,
-                                    cfg.rope_scaling)
-
-        def rotated(t, at):
-            """`t` (B, S, heads, width) with its `dr` values from `at` on
-            rotated."""
-            return jnp.concatenate(
-                [t[..., :at], ops.apply_rotary_emb(t[..., at:at + dr], cos,
-                                                   sin), t[..., at + dr:]],
-                axis=-1)
-
-        q = rotated(q, dn)
-        q_nope, q_rope = q[..., :dn], q[..., dn:]
-        k_r = rotated(k_r[:, :, None], 0)[:, :, 0]
         q_i, k_i = rotated(q_i, 0), rotated(k_i[:, :, None], 0)[:, :, 0]
-        lat = jnp.concatenate([c, k_r], axis=-1)                # (B, S, W)
         scale = cfg.softmax_scale
 
         made = None
@@ -248,16 +211,15 @@ class SparseLatentAttention(nn.Module):
             bias, kept = ops.sparse_select(
                 q_i[:, 0], w[:, 0], cache.index_keys.c.replace(layer=slot),
                 lengths, cfg.index_topk, k_i[:, 0])
-            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_kvb[..., :dn])
             o_lat = ops.latent_sparse_decode(
-                q_lat, q_rope[:, 0], cache.latent.c.replace(layer=slot),
-                lengths, bias, kept, cfg.index_topk, scale, lat[:, 0])
-            o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype),
-                           w_kvb[..., dn:])[:, None]
+                latent.absorbed(q_nope[:, 0], w_kvb), q_rope[:, 0],
+                cache.latent.c.replace(layer=slot), lengths, bias, kept,
+                cfg.index_topk, scale, lat[:, 0])
+            o = latent.through_values(o_lat, w_kvb, dn, cfg.dtype)[:, None]
             made = (lat[:, 0], k_i[:, 0])
         else:
-            made = cache = _write_chunk(cache, slot, row, start[0], lat[0],
-                                        k_i[0])
+            made = cache = latent.write_chunk(
+                cache, slot, row, start[0], latent=lat[0], index_keys=k_i[0])
             o, kept = ops.latent_sparse_prefill(
                 q_nope[0], q_rope[0], w_kvb, q_i[0], w[0],
                 cache.latent.c.replace(layer=slot),
@@ -276,21 +238,6 @@ class SparseLatentAttention(nn.Module):
         out = _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
                      "o_proj")(o.astype(cfg.dtype).reshape(b, s, nh * dv))
         return out, made
-
-
-def _write_chunk(cache, slot, row, start, lat, k_i):
-    """The cache with a chunk of sequence `row`, latent rows (C, W) and index
-    keys (C, Di), written into layer `slot`'s slabs at positions `start ..`:
-    dynamic slices written whole, which keep the stacks' tiling."""
-    from deepspeed_tpu.inference.kv_cache import DenseLayer
-
-    def put(kind, new):
-        stack = kind.c.stack
-        return kind.replace(c=DenseLayer(jax.lax.dynamic_update_slice(
-            stack, new.astype(stack.dtype)[None, None, None],
-            (slot, row, 0, start, 0))))
-    return cache.replace(latent=put(cache.latent, lat),
-                         index_keys=put(cache.index_keys, k_i))
 
 
 class Layers(nn.Module):

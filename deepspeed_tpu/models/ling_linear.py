@@ -423,10 +423,13 @@ class DenseFFN(nn.Module):
 
 def _experts(cfg: LingLinearConfig, name: str):
     """The expert layer as `moe/layer.MoE` computes it: sigmoid scores, the
-    selection bias in the choice only, the choice limited by groups, SwiGLU
-    experts of which this chip may hold a share, a shared expert, nothing
-    dropped by capacity."""
+    selection bias in the choice only (none where the family's
+    `router_bias_scale` is None), the choice limited by groups (`n_group` 1:
+    the best of all at once), SwiGLU experts of which this chip may hold a
+    share, a shared expert, nothing dropped by capacity. The three sigmoid
+    families' (this one, `deepseek_sparse`, `openpangu`)."""
     from deepspeed_tpu.moe.layer import MoE
+    biased = cfg.router_bias_scale is not None
     return MoE(
         hidden_size=cfg.hidden_size,
         num_experts=cfg.router_experts or cfg.num_experts,
@@ -434,8 +437,9 @@ def _experts(cfg: LingLinearConfig, name: str):
         intermediate_size=cfg.moe_intermediate_size,
         norm_topk_prob=cfg.norm_topk_prob, drop_tokens=False,
         dtype=cfg.dtype, activation="silu", dispatch_impl=cfg.dispatch_impl,
-        score_fn="sigmoid", selection_bias=True,
-        bias_init=nn.initializers.normal(cfg.router_bias_scale),
+        score_fn="sigmoid", selection_bias=biased,
+        bias_init=nn.initializers.normal(cfg.router_bias_scale) if biased
+        else nn.initializers.zeros_init(),
         routed_scaling_factor=cfg.routed_scaling_factor,
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         held_offset=cfg.expert_offset, held_experts=cfg.num_experts,

@@ -733,6 +733,24 @@ def latent_sparse_prefill(q_nope, q_rope, w_kvb, q_index, w, latent,
               start, softmax_scale), kept
 
 
+def latent_dense_prefill(q_nope, q_rope, w_kvb, latent, row, start,
+                         softmax_scale: float):
+    """A chunk of ONE sequence's queries (q_nope (C, H, dn), q_rope (C, H,
+    rope), positions `start ..`) against EVERY row of sequence `row`'s slab
+    of the latent cache up to each query's own, the slab already holding the
+    chunk: EXPANDED attention through the up-projection `w_kvb` (rank, H, dn
+    + dv), no choice and no bias (`ops/pallas/mla_sparse.mla_dense_prefill`:
+    what lies above the diagonal is neither fetched nor computed). Returns
+    (C, H, dv). The kernel where the chip's tiling takes the shapes (whole
+    lane tiles of slots and of queries), else the plain form."""
+    from deepspeed_tpu.ops.pallas import mla_sparse as ms
+    aligned = latent.stack.shape[3] % 128 == 0 and q_nope.shape[0] % 128 == 0
+    fn = ms.mla_dense_prefill if aligned and _one_device_kernel(
+        ms.DENSE_PREFILL_NAME) else ms.mla_dense_prefill_reference
+    return fn(q_nope, q_rope, w_kvb, latent.stack, latent.layer, row, start,
+              softmax_scale)
+
+
 def rms_norm_ref(x, weight, eps: float = 1e-6):
     """RMSNorm reference (csrc/transformer/inference/csrc/rms_norm.cu analog)."""
     dtype = x.dtype

@@ -48,10 +48,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _interpret
 from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
+from deepspeed_tpu.ops.pallas.sparse_select import block_of
 
 KERNEL_NAME = "mla_latent_decode"
 WRITE_NAME = "latent_write_dense"
 _BLOCK_SLOTS = 512      # 0.66 MB of latent a block (576 -> 640 lanes, bf16)
+# Heads from which a block is WIDE, and its slots. A block costs its bytes or
+# its operations, whichever is longer, and a grid step's fixed cost beside
+# them. Under 128 heads the bytes are the longer (32 heads: a quarter of the
+# operations) and 512 slots were read on the chip (PERF.md, PR 47). At 128
+# heads the two meet (242 FLOP a byte against the chip's 240) and a row of
+# 25,600 slots is 50 such steps a layer. Read on the chip at 8 rows of 24,704
+# live slots and 5 layers (`tools/mla_dense_sweep.py`; PERF.md, PR 58), the
+# least the mathematics allows 1.40 ms a step: 2.75 ms at 512 slots, 2.20 at
+# 1,024, 2.09 at 1,280, 1.88 at 2,560 and 1.83 at 5,120. 2,560 is what every
+# row `models/latent.cache_slots` gives divides (33,280 slots would fall to
+# 3,328 under a cap of 5,120), and the last 3% are 0.1% of that cell's batch.
+_WIDE_HEADS = 128
+_WIDE_BLOCK_SLOTS = 2560
+
+
+def decode_block(h: int, m: int) -> int:
+    """Slots a grid step of `mla_latent_decode` fetches, from the shapes:
+    `h` query heads over rows of `m` slots. ONE rule, the largest divisor of
+    the row up to the cap its heads give."""
+    return block_of(m, _WIDE_BLOCK_SLOTS if h >= _WIDE_HEADS
+                    else _BLOCK_SLOTS)
 
 
 def _kernel(lengths_ref, slots_ref, layer_ref, qc_ref, qr_ref, lat_ref,
@@ -121,9 +143,7 @@ def mla_latent_decode(q_lat: jnp.ndarray, q_rope: jnp.ndarray,
     value half of the up-projection."""
     b, h, rank = q_lat.shape
     m, width = stack.shape[3:]
-    blk = _BLOCK_SLOTS
-    while m % blk:
-        blk //= 2
+    blk = decode_block(h, m)
     nk = m // blk
     staged = new is not None
     lengths = jnp.minimum(lengths.astype(jnp.int32), m)
@@ -158,7 +178,11 @@ def mla_latent_decode(q_lat: jnp.ndarray, q_rope: jnp.ndarray,
                             pltpu.VMEM((h, rank), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            # a wide block's tile, scores and probabilities outgrow the
+            # default scoped limit; the narrow plan is compiled as it was
+            vmem_limit_bytes=64 * 1024 * 1024 if blk > _BLOCK_SLOTS
+            else None),
         interpret=_interpret(),
         name=KERNEL_NAME,
     )(*args)

@@ -29,6 +29,11 @@ the device trace:
   a block of keys at a time, live blocks only); the rope key is read from
   the latent slab's lanes 512.., once for all heads, so no operand is padded
   to 256.
+- `mla_dense_prefill`: the SAME kernel with no bias, for a model that
+  attends EVERY cached row up to the query's own (`models/openpangu.py`): no
+  chunk x cache array exists; tiles wholly above the diagonal are neither
+  fetched nor computed (as under a bias), and a live tile is masked by
+  position (an iota compare in the kernel, 1% over the bare walk).
 """
 
 from __future__ import annotations
@@ -47,6 +52,7 @@ from deepspeed_tpu.ops.pallas.sparse_select import (_live_tile, _scalar,
 
 DECODE_NAME = "mla_sparse_decode"
 PREFILL_NAME = "mla_sparse_prefill"
+DENSE_PREFILL_NAME = "mla_dense_prefill"
 F32 = jnp.float32
 _LANES = 128
 DECODE_BLOCK = 512      # slots a block: 0.66 MB of latent (576 -> 640 lanes)
@@ -249,12 +255,15 @@ def mla_sparse_decode_reference(q_lat, q_rope, stack, layer, lengths, bias,
 # ---------------------------------------------------------------- prefill
 
 
-def _prefill_kernel(start_ref, layer_ref, row_ref, qn_ref, qr_ref, bias_ref,
-                    kn_ref, v_ref, lat_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                    tq, tk, nk, rank, dn, dv):
+def _prefill_kernel(start_ref, layer_ref, row_ref, qn_ref, qr_ref, *rest,
+                    tq, tk, nk, rank, dn, dv, biased):
     del layer_ref, row_ref
+    rest = list(rest)
+    bias_ref = rest.pop(0) if biased else None
+    kn_ref, v_ref, lat_ref, o_ref, m_scr, l_scr, acc_scr = rest
     i, j = pl.program_id(1), pl.program_id(2)
     heads = qn_ref.shape[0]
+    first = start_ref[0] + i * tq           # the tile's first query's position
 
     @pl.when(j == 0)
     def _init():
@@ -262,19 +271,29 @@ def _prefill_kernel(start_ref, layer_ref, row_ref, qn_ref, qr_ref, bias_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j * tk <= start_ref[0] + (i + 1) * tq - 1)
+    @pl.when(j * tk <= first + tq - 1)      # some key a query may see
     def _block():
-        bias = bias_ref[...].astype(F32)                        # (tq, tk)
+        if biased:
+            bias = bias_ref[...].astype(F32)                    # (tq, tk)
+        else:
+            # EVERY live tile is masked by position, the ones wholly below
+            # the diagonal too: a second body for those (no mask) read 53.6
+            # ms a chunk on the chip where this one reads 32.6 and the bare
+            # walk without any mask 32.3 (PERF.md, PR 58)
+            seen = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1) \
+                <= first + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
         kr = lat_ref[:, rank:]                                  # (tk, rope)
         for g in range(heads):      # a head's keys and values: whole lanes
             # the queries come scaled. No `where` on the probabilities: a
             # query whose tiles so far hold no kept slot has m = NEG_INF and
             # gathers ones, and its first kept slot (every query keeps its
-            # `min(topk, t + 1)` >= 1) wipes them with alpha = exp(-1e30) = 0
+            # `min(topk, t + 1)` >= 1; with no bias slot 0, in the first
+            # tile) wipes them with alpha = exp(-1e30) = 0
             s = jax.lax.dot_general(qn_ref[g], kn_ref[:, g * dn:(g + 1) * dn],
                                     _NT, preferred_element_type=F32) \
                 + jax.lax.dot_general(qr_ref[g], kr, _NT,
-                                      preferred_element_type=F32) + bias
+                                      preferred_element_type=F32)
+            s = s + bias if biased else jnp.where(seen, s, NEG_INF)
             m_prev = m_scr[g][:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -297,7 +316,8 @@ def mla_sparse_prefill_attend(q_nope, q_rope, bias, k_nope, v, stack, layer,
                               row, start):
     """Attention of a chunk of ONE row's queries, a group of `G` heads:
     q_nope (G, C, dn) and q_rope (G, C, rope), SCALED, at positions `start
-    ..` of sequence `row`; bias (C, M) from `sparse_prefill_choice`; k_nope
+    ..` of sequence `row`; bias (C, M) from `sparse_prefill_choice`, or None:
+    every position up to the query's own (`mla_dense_prefill`); k_nope
     (M, G * dn) and v (M, G * dv), the heads' expanded keys and values of
     that row's latents, a token's heads side by side as the expansion's
     matmul leaves them (slots past the chunk's end are never read); the rope
@@ -319,14 +339,15 @@ def mla_sparse_prefill_attend(q_nope, q_rope, bias, k_nope, v, stack, layer,
         return pl.BlockSpec((tk, hb * w), lambda h, i, j, St, Ly, Rw: (
             block(i, j, St), h))
 
+    biased = bias is not None
+    chosen = [pl.BlockSpec((tq, tk), lambda h, i, j, St, Ly, Rw: (
+        i, block(i, j, St)))] if biased else []
     return pl.pallas_call(
         functools.partial(_prefill_kernel, tq=tq, tk=tk, nk=nk, rank=rank,
-                          dn=dn, dv=dv),
+                          dn=dn, dv=dv, biased=biased),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(g // hb, nq, nk),
-            in_specs=[queries(dn), queries(width - rank),
-                      pl.BlockSpec((tq, tk), lambda h, i, j, St, Ly, Rw: (
-                          i, block(i, j, St))),
+            in_specs=[queries(dn), queries(width - rank), *chosen,
                       keys(dn), keys(dv),
                       pl.BlockSpec((None, None, None, tk, width),
                                    lambda h, i, j, St, Ly, Rw: (
@@ -340,15 +361,16 @@ def mla_sparse_prefill_attend(q_nope, q_rope, bias, k_nope, v, stack, layer,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_interpret(),
-        name=PREFILL_NAME,
-    )(_scalar(start), _scalar(layer), _scalar(row), q_nope, q_rope, bias,
-      k_nope, v, stack)
+        name=PREFILL_NAME if biased else DENSE_PREFILL_NAME,
+    )(_scalar(start), _scalar(layer), _scalar(row), q_nope, q_rope,
+      *([bias] if biased else []), k_nope, v, stack)
 
 
 def mla_sparse_prefill(q_nope, q_rope, w_kvb, bias, stack, layer, row, start,
                        softmax_scale: float):
     """A chunk of ONE row's queries against that row's latent cache, which
-    already holds the chunk, under the choice's `bias` (C, M): q_nope (C, H,
+    already holds the chunk, under the choice's `bias` (C, M), or with
+    `bias` None against every row up to the query's own: q_nope (C, H,
     dn), q_rope (C, H, rope) rotated, w_kvb (rank, H, dn + dv) the
     up-projection. A GROUP of `EXPAND_HEADS` heads at a time: their keys'
     nope parts and their values are expanded from the row's latents a block
@@ -425,3 +447,30 @@ def mla_sparse_prefill_reference(q_nope, q_rope, w_kvb, bias, stack, layer,
     return mla_sparse_attention_plain(
         q_nope, q_rope, w_kvb, bias, row_of(stack, layer, row)[0],
         softmax_scale).astype(stack.dtype)
+
+
+def mla_dense_prefill(q_nope, q_rope, w_kvb, stack, layer, row, start,
+                      softmax_scale: float):
+    """`mla_sparse_prefill` with no choice: a chunk of ONE row's queries
+    (positions `start ..`) against EVERY row of that sequence's latent cache
+    up to each query's own, the cache already holding the chunk. The same
+    expansion, the same kernel without its bias operand, under its own name
+    in the device trace. Returns (C, H, dv)."""
+    return mla_sparse_prefill(q_nope, q_rope, w_kvb, None, stack, layer, row,
+                              start, softmax_scale)
+
+
+def causal_bias(start, c: int, m: int):
+    """(C, M) float32: 0 where slot s <= start + t, `NEG_INF` above the
+    diagonal. The plain forms' mask; the kernel makes no such array."""
+    at = jnp.asarray(start, jnp.int32) + jnp.arange(c)[:, None]
+    return jnp.where(jnp.arange(m)[None, :] <= at, 0.0, NEG_INF).astype(F32)
+
+
+def mla_dense_prefill_reference(q_nope, q_rope, w_kvb, stack, layer, row,
+                                start, softmax_scale):
+    """`mla_dense_prefill` in plain `jax.numpy`, float32."""
+    return mla_sparse_prefill_reference(
+        q_nope, q_rope, w_kvb,
+        causal_bias(start, q_nope.shape[0], stack.shape[3]), stack, layer,
+        row, start, softmax_scale)

@@ -1,9 +1,9 @@
-"""What the tests of the five hybrid families share (`test_nemotron_h.py`,
+"""What the tests of the six hybrid families share (`test_nemotron_h.py`,
 `test_phi4flash.py`, `test_ling_linear.py`, `test_keye_sparse.py`,
-`test_deepseek_sparse.py`): each
+`test_deepseek_sparse.py`, `test_openpangu.py`): each
 family at a small size on seeded weights with the benchmark's plain float32
 reference beside it, the model's `apply` under ONE `jax.jit`, the walk
-through the caches, and the questions asked of all five alike, written once (`the_plain_forward_...`,
+through the caches, and the questions asked of all six alike, written once (`the_plain_forward_...`,
 `the_loss_...`, `prefill_then_decode_...`: LOGITS not tokens, each family
 held to its own tolerance in its own way, `Family.close`). Each family's
 file asks them under its own test names: a file is one worker's under
@@ -27,7 +27,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models import (deepseek_sparse, keye_sparse, ling_linear,
-                                  nemotron_h, phi4flash)
+                                  nemotron_h, openpangu, phi4flash)
 from perfbench.manifest import Manifest
 
 
@@ -116,6 +116,7 @@ PHI4_TOL = 2e-5
 LING_TOL = 5e-6         # read 6e-7
 KEYE_TOL = 3e-6         # read 4e-7
 DEEPSEEK_TOL = 5e-6
+OPENPANGU_TOL = 5e-6    # read 4e-7: absorbed against expanded, sorted rows
 
 NEMOTRON_CFG = nemotron_h.NemotronHConfig(
     vocab_size=128, hidden_size=64, num_hidden_layers=6,
@@ -169,6 +170,18 @@ DEEPSEEK_SIZES = dict(
     rope_scaling=dict(type="yarn", factor=40, beta_fast=32, beta_slow=1,
                       mscale=1, mscale_all_dim=1,
                       original_max_position_embeddings=16))
+# the file's keys, as the reference and the adapter read them: a dense layer
+# and two expert layers, four norms each; experts 6-9 of 16 held, the 4 best
+# of all 16 taken at once (no groups, no selection bias)
+OPENPANGU_SIZES = dict(
+    vocab_size=128, hidden_size=64, num_hidden_layers=3, intermediate_size=96,
+    first_k_dense_replace=1, num_attention_heads=4, num_key_value_heads=4,
+    q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, rope_theta=25600000.0, n_routed_experts=4,
+    router_experts=16, expert_offset=6, num_experts_per_tok=4,
+    moe_intermediate_size=32, n_shared_experts=1, routed_scaling_factor=2.5,
+    norm_topk_prob=True, rms_norm_eps=1e-5, max_position_embeddings=4096,
+    num_nextn_predict_layers=0, sandwich_norm=True)
 PHI4_CFG = phi4flash.Phi4FlashConfig(**PHI4_SIZES, dtype=jnp.float32)
 LING_CFG = ling_linear.LingLinearConfig(**LING_SIZES, dtype=jnp.float32)
 
@@ -238,12 +251,13 @@ def _ling_linear():
                   reference_logits, close, ling_linear.ling_linear_loss_fn)
 
 
-def _learned_choice(name, module, sizes, tol, loss_fn):
-    """A family whose attention attends a learned choice (`<name>_adapter`,
+def _from_adapter(name, module, sizes, tol, loss_fn, spread):
+    """A family built through its adapter (`<name>_adapter`,
     `<name>_reference` under `perfbench/configs/`): the small parameters off
-    their initial values (the norms' weights, the index key's LayerNorm:
-    weight 1, bias 0), and the index projections at a range at which the
-    choice really chooses (as seeded every index score is near 0)."""
+    their initial values (the norms' weights, an index key's LayerNorm:
+    weight 1, bias 0), and the kernels whose path `spread` names at 20 x
+    their seeded range, where what they decide really decides (as seeded
+    every index score, and every router's logit, is near 0)."""
     manifest = Manifest()
     ref = manifest.module("configs", name + "_reference")
     cfg = manifest.module("configs", name + "_adapter").model_config(
@@ -251,8 +265,8 @@ def _learned_choice(name, module, sizes, tol, loss_fn):
     model, params = module.materialize_params(cfg, jax.random.PRNGKey(0))
     params = moved(params, 600)
     params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x * 20.0 if "index_" in jax.tree_util.keystr(path)
-        and "kernel" in jax.tree_util.keystr(path) else x, params)
+        lambda path, x: x * 20.0 if spread(jax.tree_util.keystr(path)) else x,
+        params)
     ids = jax.random.randint(jax.random.PRNGKey(1), (3, 40), 1, 128)
 
     def reference_logits(params, ids, sizes=sizes):
@@ -266,18 +280,32 @@ def _learned_choice(name, module, sizes, tol, loss_fn):
                   loss_fn)
 
 
+def _index_kernels(path):
+    """A learned choice's index projections."""
+    return "index_" in path and "kernel" in path
+
+
 def _keye_sparse():
-    return _learned_choice("keye_sparse", keye_sparse, KEYE_SIZES, KEYE_TOL,
-                           keye_sparse.keye_sparse_loss_fn)
+    return _from_adapter("keye_sparse", keye_sparse, KEYE_SIZES, KEYE_TOL,
+                         keye_sparse.keye_sparse_loss_fn, _index_kernels)
 
 
 def _deepseek_sparse():
-    return _learned_choice("deepseek_sparse", deepseek_sparse, DEEPSEEK_SIZES,
-                           DEEPSEEK_TOL,
-                           deepseek_sparse.deepseek_sparse_loss_fn)
+    return _from_adapter("deepseek_sparse", deepseek_sparse, DEEPSEEK_SIZES,
+                         DEEPSEEK_TOL,
+                         deepseek_sparse.deepseek_sparse_loss_fn,
+                         _index_kernels)
 
 
-FAMILIES = {"deepseek_sparse": _deepseek_sparse, "nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
+def _openpangu():
+    """The FOUR norms a layer and the two compressions' off their seeded 1;
+    the routers at a range at which the ungrouped choice decides."""
+    return _from_adapter("openpangu", openpangu, OPENPANGU_SIZES,
+                         OPENPANGU_TOL, openpangu.openpangu_loss_fn,
+                         lambda path: path.endswith("['gate']['wg']"))
+
+
+FAMILIES = {"deepseek_sparse": _deepseek_sparse, "openpangu": _openpangu, "nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
             "ling_linear": _ling_linear, "keye_sparse": _keye_sparse}
 
 

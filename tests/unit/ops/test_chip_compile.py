@@ -42,6 +42,8 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "grouped_gemm_decode": "gmm",
                "kda_state_update": "kda_state_update",
                "mla_latent_decode": "mla_latent_decode",
+               "mla_latent_decode_h128": "mla_latent_decode",
+               "mla_dense_prefill": "mla_dense_prefill",
                "latent_write_dense": "latent_write_dense",
                "sparse_index_select": "sparse_index_select",
                "sparse_attn_decode": "sparse_attn_decode",
