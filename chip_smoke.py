@@ -85,6 +85,10 @@ class Sizes:
     # the grouped GEMM at a DECODE shape: rows, experts, K, N; 3 rows an
     # expert, the rest of the rows no expert's (absent assignments)
     gmm_decode: Tuple[int, int, int, int]
+    # a held expert layer at a PREFILL chunk's shape: tokens, top k, hidden,
+    # expert width, held experts, the router's experts (DeepSeek-V3.2's chunk
+    # of 2,048 tokens on a chip that holds 16 of 256)
+    held_rows: Tuple[int, int, int, int, int, int]
     # differential decode attention over a stack of paired heads: layers,
     # rows, groups, slots, pair width (Phi-4-mini-flash's eight rings and its
     # one shared slab at the benchmark cell's batch and length)
@@ -130,6 +134,7 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              prefill_batch=8, parked=(48, 18, 16), gmm_rows=4096,
              gmm_experts=64, gmm_width=1024, qmm_group=256,
              ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856),
+             held_rows=(2048, 8, 7168, 2048, 16, 256),
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
              ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
              mla=(1, 128, 32, 2048, 512, 64),
@@ -147,6 +152,7 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              paged_batch=3, paged_blocks=9, prefill_batch=2,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
              qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48),
+             held_rows=(288, 4, 64, 32, 1, 16),
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
              ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
              mla=(2, 3, 4, 32, 32, 8),
@@ -202,6 +208,9 @@ class KernelCase:
     ref: Callable
     make: Callable
     tol: float = FWD_TOL
+    # (scope names, ms): on the chip the device time a call spends under
+    # those scopes, by the trace joined to the program map, may not pass it
+    scopes_ms: Optional[Tuple[Tuple[str, ...], float]] = None
 
 
 def kernel_cases(sz: Sizes) -> List[KernelCase]:
@@ -210,6 +219,8 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                                                   dequantize_kv,
                                                   quantize_kv_tokens)
     from deepspeed_tpu.models.qwen2 import qwen2_config
+    from deepspeed_tpu.moe.sharded_moe import (held_dispatch_gmm,
+                                               held_row_bound, held_row_tile)
     from deepspeed_tpu.ops.attention import (blockwise_attention,
                                              reference_attention)
     from deepspeed_tpu.ops.pallas.block_sparse_attention import (
@@ -622,6 +633,40 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     cases.append(KernelCase("grouped_gemm_decode", gmm_decode, gmm_decode_ref,
                             make_gmm_decode))
 
+    # a held expert layer at a prefill chunk's shape: sorted rows sized by
+    # the chip's share (`held_row_bound`), against the full-width body on
+    # the same routing; `dispatch` + `combine` timed by the trace
+    ht, hk, hd, hf, hheld, hexperts = sz.held_rows
+    htile = held_row_tile(ht * hk, hexperts)
+
+    def make_held(key):
+        kx, kr, *kw = jax.random.split(key, 5)
+        gate, idx = jax.lax.top_k(jax.nn.sigmoid(
+            jax.random.normal(kr, (ht, hexperts), jnp.float32)), hk)
+        return (normal(kx, (ht, hd)), gate / gate.sum(-1, keepdims=True),
+                idx.astype(jnp.int32), normal(kw[0], (hheld, hd, hf)) * 0.02,
+                normal(kw[1], (hheld, hd, hf)) * 0.02,
+                normal(kw[2], (hheld, hf, hd)) * 0.02)
+
+    def held_layer(bounded):
+        def fn(x, gate, idx, w_gate, w_up, w_down):
+            def grouped(rows, sizes):
+                def gg(lhs, rhs):
+                    return grouped_gemm(lhs, rhs, sizes, tiling=(
+                        htile, min(lhs.shape[1], 1024),
+                        min(rhs.shape[2], 1024)))
+                return gg(jax.nn.silu(gg(rows, w_gate)) * gg(rows, w_up),
+                          w_down)
+            bound = held_row_bound(ht * hk, hheld, hexperts, htile)
+            assert bound < ht * hk
+            return held_dispatch_gmm(x, gate, idx, 0, hheld, grouped,
+                                     bound=bound if bounded else None)[0]
+        return fn
+
+    cases.append(KernelCase("held_rows", held_layer(True), held_layer(False),
+                            make_held,
+                            scopes_ms=(("dispatch", "combine"), 1.5)))
+
     # ---- the recurrent-state update of a Mamba-2 decode step ----
     sl, sb, sh, sp, sn, sg = sz.ssm
 
@@ -924,8 +969,35 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     return cases + slow_cases
 
 
+def scopes_ms(case: KernelCase, inputs, reps: int = 10) -> Optional[float]:
+    """ms a call of `case.fn` the device spends under `case.scopes_ms`'s
+    scopes: `reps` calls traced and joined to the program map. None where
+    the profile has no device line (off the chip)."""
+    import tempfile
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.telemetry.program_map import join_logdir, seconds_where
+
+    def named(*a):
+        return case.fn(*a)
+    named.__name__ = f"ds_smoke_{case.name}"
+    jitted = jax.jit(named)
+    telemetry.forget_programs()
+    telemetry.keep_program(f"smoke:{case.name}", jitted.trace(*inputs))
+    jax.block_until_ready(jitted(*inputs))
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_case_") as logdir:
+        with telemetry.trace_capture(logdir):
+            for _ in range(reps):
+                out = jitted(*inputs)
+            jax.block_until_ready(out)
+        joined = join_logdir(logdir)[1]
+    telemetry.forget_programs()
+    if not joined["busy_s"]:
+        return None
+    return 1e3 * seconds_where(joined, any_scope=case.scopes_ms[0]) / reps
+
+
 def phase_kernels(sz: Sizes, seed: int) -> Dict[str, Any]:
-    errs, bad = {}, []
+    errs, timed, bad = {}, {}, []
     for i, case in enumerate(kernel_cases(sz)):
         inputs = jax.jit(case.make)(jax.random.PRNGKey(seed + i))
         got = jax.jit(case.fn)(*inputs)
@@ -934,9 +1006,18 @@ def phase_kernels(sz: Sizes, seed: int) -> Dict[str, Any]:
         errs[case.name] = round(err, 5)
         if not err <= case.tol:  # NaN fails
             bad.append(f"{case.name}: rel err {err:.3g} > {case.tol}")
+        if case.scopes_ms:
+            ms = scopes_ms(case, inputs)
+            timed[case.name] = None if ms is None else round(ms, 4)
+            if ms is None and jax.devices()[0].platform == "tpu":
+                bad.append(f"{case.name}: the trace has no device line")
+            elif ms is not None and not ms <= case.scopes_ms[1]:
+                bad.append(f"{case.name}: {ms:.3f} ms under "
+                           f"{'+'.join(case.scopes_ms[0])} > "
+                           f"{case.scopes_ms[1]} ms")
     if bad:
         raise AssertionError("; ".join(bad))
-    return {"cases": len(errs), "rel_err": errs}
+    return {"cases": len(errs), "rel_err": errs, "scopes_ms": timed}
 
 
 # ---------------------------------------------------------------- dispatch

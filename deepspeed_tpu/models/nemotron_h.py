@@ -414,8 +414,9 @@ class _RowGroups(nn.Module):
 class NemotronHForCausalLM(nn.Module):
     cfg: NemotronHConfig
     # what the expert layers count inside a serving program, summed over the
-    # call by the engine (`serving` event: `assignments`, `held_assignments`)
-    program_counters = ("assignments", "held_assignments")
+    # call by the engine (`serving` event: `assignments`, `held_assignments`,
+    # `held_wide_calls`)
+    program_counters = ("assignments", "held_assignments", "held_wide_calls")
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

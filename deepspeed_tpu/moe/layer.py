@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.moe.sharded_moe import (
     _gating_core, dispatch_combine, dispatch_combine_gmm,
     dispatch_combine_ragged, held_assignments, held_dispatch_gmm,
-    held_dispatch_ragged, route_topk, topkgating)
+    held_dispatch_ragged, held_row_bound, held_row_tile, route_topk,
+    topkgating)
 from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 
@@ -99,7 +100,10 @@ class Experts(nn.Module):
         params, no (E, C) padding; rows past the last group are no group's
         and are not computed. `tm`: the grouped kernel's row tile where the
         caller knows how many rows an expert expects (its default suits
-        thousands of rows an expert)."""
+        thousands of rows an expert). `x` None: the grouped form ITSELF,
+        `(rows, group_sizes) -> rows` over the weights read here, a function
+        of arrays alone that a caller may trace under `lax.cond`
+        (`sharded_moe.held_dispatch_gmm`), where a module may not be called."""
         e, d, f = self.num_experts, self.hidden_size, self.intermediate_size
         init = nn.with_logical_partitioning(nn.initializers.normal(0.02),
                                             ("expert", "embed", "mlp"))
@@ -109,7 +113,7 @@ class Experts(nn.Module):
         w_down = self.param("down", init_out, (e, f, d), jnp.float32).astype(self.dtype)
         w_gate = (self.param("gate", init, (e, d, f), jnp.float32)
                   .astype(self.dtype) if self.activation == "silu" else None)
-        if group_sizes is not None:
+        if group_sizes is not None or x is None:
             from jax.ad_checkpoint import checkpoint_name
             from deepspeed_tpu.ops.pallas.grouped_gemm import (
                 grouped_gemm, sharded_grouped_gemm)
@@ -124,20 +128,31 @@ class Experts(nn.Module):
                     f"partitioned mesh is not pure expert-parallel with "
                     f"{e} % ep == 0; running unsharded (operands gathered)")
 
-            def gg(lhs, rhs):
+            def gg(lhs, rhs, sizes):
                 # named so remat policies can SAVE grouped-GEMM outputs:
                 # a Pallas call is not a dot, so plain checkpoint_dots
                 # recomputes the whole grouped FFN in backward
                 # (remat_policy='checkpoint_dots_gmm' in models/llama.py)
                 tiling = None if tm is None else (
                     tm, min(lhs.shape[1], 1024), min(rhs.shape[2], 1024))
-                out = (sharded_grouped_gemm(lhs, rhs, group_sizes, mesh,
+                out = (sharded_grouped_gemm(lhs, rhs, sizes, mesh,
                                             tiling=tiling)
                        if mesh is not None
-                       else grouped_gemm(lhs, rhs, group_sizes, tiling=tiling))
+                       else grouped_gemm(lhs, rhs, sizes, tiling=tiling))
                 return checkpoint_name(out, "moe_gmm")
-            gate = None if w_gate is None else gg(x, w_gate)
-            return gg(_activate(gg(x, w_up), gate, self.activation), w_down)
+
+            def grouped(rows, sizes):
+                gate = None if w_gate is None else gg(rows, w_gate, sizes)
+                return gg(_activate(gg(rows, w_up, sizes), gate,
+                                    self.activation), w_down, sizes)
+            if x is None:
+                def scoped(rows, sizes):
+                    # traced outside this module's call: the scope's name
+                    # the program map knows the experts by (docs/telemetry.md)
+                    with jax.named_scope(self.name):
+                        return grouped(rows, sizes)
+                return scoped
+            return grouped(x, group_sizes)
         gate = None if w_gate is None else \
             jnp.einsum("ecd,edf->ecf", x, w_gate)
         h = _activate(jnp.einsum("ecd,edf->ecf", x, w_up), gate,
@@ -354,9 +369,12 @@ class MoE(nn.Module):
     def _held(self, x, gate, f, valid):
         """This chip's part of the layer for tokens x (T, D): the held
         experts' weighted outputs plus the shared expert. Sows the call's
-        `assignments` and `held_assignments`, and `experts_touched` of
-        `experts_held`: the held experts that received an assignment, whose
-        weights the call reads (collection `counters`)."""
+        `assignments` and `held_assignments`, `held_wide_calls` (1 where the
+        held rows passed the bound the call was sized for,
+        `sharded_moe.held_row_bound`, and the full-width body ran), and
+        `experts_touched` of `experts_held`: the held experts that received
+        an assignment, whose weights the call reads (collection
+        `counters`)."""
         t, d = x.shape
         count, k = self.held_experts, self.k
         experts = Experts(count, d, f, self.dtype, self.activation,
@@ -374,13 +392,13 @@ class MoE(nn.Module):
             # weights in another layout and the compiler keeps a second copy
             # of them (temporaries 2.37 -> 5.88 GB beside 9.3 GB of weights).
             impl = "gmm" if _unpartitioned_mesh() else "ragged"
+        wide = 0
         if impl == "gmm":
-            # an m tile about the rows an expert expects, 16 (Mosaic's bf16
-            # minimum) at decode: a tile is then one expert's alone
-            tm = max(16, min(512, 1 << (t * k // count).bit_length()))
-            out, held = held_dispatch_gmm(
+            tm = held_row_tile(t * k, self.num_experts)
+            out, held, wide = held_dispatch_gmm(
                 x, gate_k, topk_idx, self.held_offset, count,
-                lambda rows, sizes: experts(rows, sizes, tm), valid)
+                experts(None, tm=tm), valid,
+                held_row_bound(t * k, count, self.num_experts, tm))
         elif impl == "ragged":
             out, held = held_dispatch_ragged(
                 x, gate_k, topk_idx, self.held_offset, count,
@@ -397,6 +415,7 @@ class MoE(nn.Module):
         touched = jnp.sum(jnp.bincount(local.reshape(-1), length=count + 1)
                           [:count] > 0)
         for name, value in (("assignments", total), ("held_assignments", held),
+                            ("held_wide_calls", wide),
                             ("experts_touched", touched),
                             ("experts_held", count)):
             self.sow("counters", name, jnp.asarray(value, jnp.int32),

@@ -311,33 +311,137 @@ def held_assignments(topk_idx: jnp.ndarray, offset: int, count: int,
     return held, jnp.where(held, local, count)
 
 
+# `held_row_bound`'s three constants (the rule is in `held_dispatch_gmm`)
+HELD_ROWS_MARGIN = 2        # the bound over the rows a share expects
+HELD_NARROW_FROM = 1024     # assignments a call up to which none is set
+HELD_NARROW_UNDER = 4       # ... and T x k over the largest bound that is
+
+
+def held_row_tile(rows: int, num_experts: int) -> int:
+    """The grouped GEMM's row tile for a call of `rows` = T x k assignments
+    over a router of `num_experts`: two to four times the rows an expert
+    expects. A tile is multiplied, masked, against every expert whose rows
+    lie in it, and an expert's weights are read once a tile its rows touch;
+    on this chip the two costs meet there (64 rows an expert: 256 against
+    128, 512 and 64, PERF.md, PR 59). 16 (Mosaic's bf16 minimum) at decode:
+    a tile is then one expert's alone."""
+    return max(16, min(512, 1 << (2 * rows // num_experts).bit_length()))
+
+
+def held_row_bound(rows: int, count: int, num_experts: int, tile: int) -> int:
+    """Of a call's `rows` = T x k assignments, how many sorted rows a layer
+    that holds `count` of `num_experts` experts moves and multiplies: twice
+    what its share expects, in whole row tiles of the grouped GEMM; `rows`
+    itself (no bound) where that is over a quarter of them or the call is
+    small."""
+    expected = -(-rows * count // num_experts)
+    bound = -(-HELD_ROWS_MARGIN * expected // tile) * tile
+    if rows <= HELD_NARROW_FROM or HELD_NARROW_UNDER * bound > rows:
+        return rows
+    return bound
+
+
 def held_dispatch_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
                       topk_idx: jnp.ndarray, offset: int, count: int,
-                      grouped_fn, valid: Optional[jnp.ndarray] = None
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                      grouped_fn, valid: Optional[jnp.ndarray] = None,
+                      bound: Optional[int] = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """`dispatch_combine_gmm` for a layer that holds experts `offset ..
     offset + count - 1` of those the router scores: the rows of the held
     assignments sorted by held expert, the absent ones after them and
     OUTSIDE every group, so that they are no row of any GEMM (the grouped
     kernel's grid ends with the last group). Returns (this chip's part of
-    the layer's result (T, D) float32, the number of held assignments)."""
+    the layer's result (T, D) float32, the number of held assignments,
+    1 where they passed `bound` else 0).
+
+    `bound` (static, `held_row_bound`; None: T x k): the sorted rows the
+    call is SIZED for. With the held rows first, `order[:bound]` names every
+    one of them whenever there are `bound` or fewer, and then the gather, the
+    grouped FFN's operands and the way back to token order are `bound` rows
+    and never T x k: the chip moves the rows it holds, as under expert
+    parallelism the all-to-all would bring it no others. The way back is a
+    second sort of those `bound` rows by token, which lays a token's rows
+    side by side; running sums of the weighted float32 rows within a token
+    (by doubling: a token has at most k); and each token gathering the LAST
+    of its rows (a token's terms added by held expert and in pairs, not in
+    the choice's order: float32 rounding of the same sum). No scatter: XLA's
+    row scatter-add read 0.7 to 11 ms for these rows on this chip, by the
+    width and by what the compiler fused into it. EXACT for every routing:
+    a call whose held rows pass the bound (a skewed router, a hot chunk)
+    takes the full-width body under `lax.cond`; nothing is dropped or
+    capped. `grouped_fn` is a function of arrays alone (it is traced in both
+    branches).
+
+    The rule, by shape alone: bound = HELD_ROWS_MARGIN (2) x the share's
+    expected rows, T x k x count / num_experts, in whole row tiles. It is
+    set only where it is T x k / HELD_NARROW_UNDER (a quarter) or less, a
+    share of an eighth: at a quarter held (Ling: 32,768 of 65,536 rows) no
+    form of the narrow body beat the full-width one (13.1 ms a call against
+    13.0-22.1), and at a half (Nemotron) the bound is T x k itself. And only
+    past HELD_NARROW_FROM (1,024) assignments a call: a decode step's are
+    fewer in every cell and its whole dispatch 0.03-0.19 ms
+    (`moe_dispatch_ms.gen`). Elsewhere the program is the full-width body
+    alone, no branch. The chip readings that fixed the three are in PERF.md,
+    PR 59."""
     t, d = x.shape
     k = topk_idx.shape[1]
     with jax.named_scope("dispatch"):
         held, local = held_assignments(topk_idx, offset, count, valid)
         key = local.reshape(-1)                         # absent sort last
         order = jnp.argsort(key)                        # stable
-        xs = jnp.take(x, order // k, axis=0)
         group_sizes = jnp.bincount(key, length=count + 1)[:count]
         n_held = jnp.sum(group_sizes)
-    out_s = grouped_fn(xs, group_sizes)                 # (T*k, D)
-    with jax.named_scope("combine"):
-        # rows past the last group were never written
-        rows = jax.lax.broadcasted_iota(jnp.int32, (t * k, 1), 0)
-        out_s = jnp.where(rows < n_held, out_s, 0)
-        out_k = jnp.take(out_s, jnp.argsort(order), axis=0).reshape(t, k, d)
-        w = jnp.where(held, gate_k, 0.0)
-        return jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32)), n_held
+
+    def wide():
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(x, order // k, axis=0)
+        out_s = grouped_fn(xs, group_sizes)             # (T*k, D)
+        with jax.named_scope("combine"):
+            # rows past the last group were never written
+            rows = jax.lax.broadcasted_iota(jnp.int32, (t * k, 1), 0)
+            out_s = jnp.where(rows < n_held, out_s, 0)
+            out_k = jnp.take(out_s, jnp.argsort(order),
+                             axis=0).reshape(t, k, d)
+            w = jnp.where(held, gate_k, 0.0)
+            return jnp.einsum("tk,tkd->td", w, out_k.astype(jnp.float32))
+
+    def narrow():
+        with jax.named_scope("dispatch"):
+            first = order[:bound]                       # every held row
+            xs = jnp.take(x, first // k, axis=0)
+        out_s = grouped_fn(xs, group_sizes)             # (bound, D)
+        with jax.named_scope("combine"):
+            # the second, SHORT sort: the held rows by token, the rows past
+            # them (never written) after every token's, as zeros
+            tok = jnp.where(jnp.arange(bound) < n_held, first // k, t)
+            by_tok = jnp.argsort(tok)
+            tok = jnp.take(tok, by_tok)
+            w = jnp.take(gate_k.reshape(-1), jnp.take(first, by_tok))
+            terms = jnp.where(
+                (tok < t)[:, None],
+                jnp.take(out_s, by_tok, axis=0).astype(jnp.float32)
+                * w[:, None], 0.0)
+            # running sums within a token: each row takes the one `step`
+            # before it where that is the same token's
+            step = 1
+            while step < k:
+                same = jnp.concatenate([jnp.zeros((step,), jnp.bool_),
+                                        tok[step:] == tok[:-step]])
+                before = jnp.concatenate(
+                    [jnp.zeros((step, d), jnp.float32), terms[:-step]])
+                terms = terms + jnp.where(same[:, None], before, 0.0)
+                step *= 2
+            per_token = jnp.bincount(tok, length=t + 1)[:t]
+            last = jnp.cumsum(per_token) - 1
+            return jnp.where(
+                (per_token > 0)[:, None],
+                jnp.take(terms, jnp.maximum(last, 0), axis=0), 0.0)
+
+    if bound is None or bound >= t * k:
+        return wide(), n_held, jnp.zeros((), jnp.int32)
+    fits = n_held <= bound
+    return (jax.lax.cond(fits, narrow, wide), n_held,
+            1 - fits.astype(jnp.int32))
 
 
 def held_dispatch_ragged(x: jnp.ndarray, gate_k: jnp.ndarray,

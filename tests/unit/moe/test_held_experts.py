@@ -144,3 +144,146 @@ def test_softmax_routing_is_what_it_was():
     want = jnp.take_along_axis(p, want_idx, -1)
     assert jnp.array_equal(idx, want_idx)
     assert jnp.array_equal(w, want / want.sum(-1, keepdims=True))
+
+
+# ------------------------------------------- rows sized by the chip's share
+# `held_dispatch_gmm` under a bound on the sorted rows (`held_row_bound`):
+# the narrow body, the full-width body it falls back to, the expert buffer
+# (`held_dispatch_ragged`) and a float32 dense sum, on the same inputs.
+NT, NK, NE, TILE = 288, 4, 16, 16          # 1,152 assignments: past the rule's 1,024
+
+
+def _narrow_inputs(routing, count, padded):
+    """(x, weights (T, k), expert ids (T, k), valid, the held experts' FFNs)."""
+    ks = jax.random.split(jax.random.PRNGKey(59), 5)
+    x = jax.random.normal(ks[0], (NT, D))
+    gate, idx = jax.lax.top_k(jax.nn.sigmoid(
+        jax.random.normal(ks[1], (NT, NE))), NK)
+    if routing == "one_expert":        # every token's first: held expert 0
+        idx = jnp.concatenate([jnp.zeros_like(idx[:, :1]),
+                               1 + idx[:, 1:] % (NE - 1)], axis=1)
+    elif routing == "absent":          # none on a held one
+        idx = count + idx % (NE - count)
+    valid = None
+    if padded:
+        valid = jnp.arange(NT) % 5 != 3
+    ws = {"up": jax.random.normal(ks[2], (count, D, F)) * 0.3,
+          "down": jax.random.normal(ks[3], (count, F, D)) * 0.3}
+    return x, gate, idx.astype(jnp.int32), valid, ws
+
+
+def _grouped(ws):
+    from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
+
+    def fn(rows, sizes):
+        h = grouped_gemm(rows, ws["up"], sizes, tiling=(TILE, D, F))
+        return grouped_gemm(jnp.square(jax.nn.relu(h)), ws["down"], sizes,
+                            tiling=(TILE, F, D))
+    return fn
+
+
+def _buffered(ws):
+    def fn(buf):                       # (count, T, D), the batched form
+        h = jnp.square(jax.nn.relu(jnp.einsum("ecd,edf->ecf", buf, ws["up"])))
+        return jnp.einsum("ecf,efd->ecd", h, ws["down"])
+    return fn
+
+
+def _dense_sum(x, gate, idx, valid, ws, count):
+    out = jnp.zeros_like(x)
+    for e in range(count):
+        w_e = jnp.sum(jnp.where(idx == e, gate, 0.0), -1)
+        if valid is not None:
+            w_e = jnp.where(valid, w_e, 0.0)
+        out += w_e[:, None] * (jnp.square(jax.nn.relu(x @ ws["up"][e]))
+                               @ ws["down"][e])
+    return out
+
+
+# (id, held of 16, routing, padding rows, the bound: "rule" or an offset from
+#  the held rows' own number, the full-width body runs)
+NARROW = [
+    ("share_1_16", 1, "drawn", False, "rule", False),
+    ("share_1_8", 2, "drawn", False, "rule", False),
+    ("share_1_4", 4, "drawn", False, "rule", False),    # the rule: no bound
+    ("share_1_4_bounded", 4, "drawn", False, 40, False),
+    ("share_1_2", 8, "drawn", False, "rule", False),    # the rule: no bound
+    ("padding_rows", 2, "drawn", True, "rule", False),
+    ("held_at_the_bound", 2, "drawn", False, 0, False),
+    ("held_one_past_the_bound", 2, "drawn", False, -1, True),
+    ("padding_one_past", 2, "drawn", True, -1, True),
+    ("no_held_row", 2, "absent", False, "rule", False),
+    ("all_on_one_held_expert", 1, "one_expert", False, "rule", True),
+]
+
+
+@pytest.mark.parametrize("case", NARROW, ids=[c[0] for c in NARROW])
+def test_a_held_layer_moves_the_rows_it_holds(case):
+    """Whatever the bound, the result is the full-width body's and the
+    buffer path's within float32 rounding of the dense sum (the narrow body
+    adds a token's terms by held expert, in pairs); past the bound the
+    full-width body runs, bit for bit, and is counted; nothing is dropped."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+    _, count, routing, padded, at, runs_wide = case
+    x, gate, idx, valid, ws = _narrow_inputs(routing, count, padded)
+    held = (idx < count) if valid is None else (idx < count) & valid[:, None]
+    n = int(held.sum())
+    bound = sm.held_row_bound(NT * NK, count, NE, TILE) if at == "rule" \
+        else n + at
+    if at == "rule" and count <= NE // 8:
+        assert bound == 2 * NT * NK * count // NE and bound % TILE == 0
+    elif at == "rule":
+        assert bound == NT * NK                         # today's program
+    run = jax.jit(sm.held_dispatch_gmm, static_argnames=(
+        "offset", "count", "grouped_fn", "bound"))
+    got, n_got, wide = run(x, gate, idx, 0, count, _grouped(ws), valid, bound)
+    full, n_full, never = run(x, gate, idx, 0, count, _grouped(ws), valid,
+                              None)
+    buf, n_buf = sm.held_dispatch_ragged(x, gate, idx, 0, count,
+                                         _buffered(ws), valid)
+    want = _dense_sum(x, gate, idx, valid, ws, count)
+    assert int(n_got) == int(n_full) == int(n_buf) == n
+    assert (int(wide), int(never)) == (int(runs_wide), 0)
+    assert runs_wide == (n > bound)
+    scale = max(float(jnp.abs(want).max()), 1e-6)
+    for other in (full, buf, want):
+        assert float(jnp.abs(got - other).max()) / scale < TOL
+    if runs_wide:
+        assert jnp.array_equal(got, full)
+    if routing == "absent":
+        assert n == 0 and not jnp.any(got)
+    if routing == "one_expert":
+        assert n == NT > bound                          # no assignment lost
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["seeded", "skewed"])
+def test_the_layer_sizes_its_rows_by_its_share_and_counts_the_wide_calls(
+        skewed):
+    """A layer that holds 1 of 16 experts, 1,152 assignments a call: the
+    bound comes from the shapes (no option: 256 rows, a row tile), the
+    result is the expert buffer's, and a router that sends every token to
+    the held expert takes the full-width body and says so
+    (`held_wide_calls`)."""
+    def moe(impl):
+        return MoE(hidden_size=D, num_experts=NE, k=NK, intermediate_size=F,
+                   drop_tokens=False, dtype=jnp.float32, activation="silu",
+                   dispatch_impl=impl, score_fn="sigmoid",
+                   selection_bias=True, held_offset=4, held_experts=1)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, NT // 2, D))
+    params = nn.meta.unbox(moe("gmm").init(jax.random.PRNGKey(4), x,
+                                           train=False)["params"])
+    params["gate"]["wg"] = params["gate"]["wg"] * 40.0
+    if skewed:
+        params["gate"]["bias"] = params["gate"]["bias"].at[4].set(9.0)
+    got, sown = moe("gmm").apply({"params": params}, x, train=False,
+                                 mutable=["counters"])
+    want, other = moe("ragged").apply({"params": params}, x, train=False,
+                                      mutable=["counters"])
+    assert rel(got, want) < TOL
+    counted = sown["counters"]
+    assert int(counted["held_wide_calls"]) == int(skewed)
+    assert int(other["counters"]["held_wide_calls"]) == 0
+    assert int(counted["held_assignments"]) == \
+        int(other["counters"]["held_assignments"])
+    if skewed:
+        assert int(counted["held_assignments"]) == NT   # one of a token's 4
