@@ -121,6 +121,13 @@ class Sizes:
     # plain form's scores are 4.4 GB)
     mla_sparse: Tuple[int, int, int, int, int, int, int, int, int, int, int,
                       int]
+    # the dense decode kernel over RINGS: layers, rows, KV heads, query heads
+    # a KV head, slots (Trinity-Mini's twelve window layers at the benchmark
+    # cell's batch)
+    ring_stack: Tuple[int, int, int, int, int]
+    # the BANDED flash forward: rows, positions, query heads, KV heads,
+    # window (two of Trinity-Mini's prompts, as its prefill walks them)
+    flash_band: Tuple[int, int, int, int, int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -142,7 +149,9 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              mla_dense=(2, 8, 128, 25600, 512, 64, 128, 128, 256),
              sparse=(2, 8, 4, 8, 33280, 128, 16, 128, 2048, 2048),
              mla_sparse=(2, 8, 128, 33280, 512, 64, 128, 128, 64, 128, 2048,
-                         256))
+                         256),
+             ring_stack=(12, 32, 4, 8, 2048),
+             flash_band=(2, 8192, 32, 4, 2048))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
@@ -159,7 +168,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              mla_wide=(2, 3, 128, 64, 32, 8),
              mla_dense=(2, 3, 4, 64, 32, 8, 16, 16, 16),
              sparse=(2, 3, 2, 2, 64, 16, 4, 8, 8, 16),
-             mla_sparse=(2, 3, 4, 64, 32, 8, 16, 16, 4, 8, 8, 16))
+             mla_sparse=(2, 3, 4, 64, 32, 8, 16, 16, 4, 8, 8, 16),
+             ring_stack=(2, 4, 2, 2, 16), flash_band=(1, 64, 4, 2, 24))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -306,6 +316,35 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                    qkv(1, sz.flash_long), tol=BWD_TOL),
     ]
 
+    # ---- the banded flash forward (a prefill's window layers): against
+    # the masked reference, a query block of rows at a time ----
+    fb_rows, fb_s, fb_h, fb_hkv, fb_w = sz.flash_band
+
+    def band_ref(q, k, v):
+        """The masked reference for `size` queries at a time, each block
+        against the keys its band can reach."""
+        size = min(fb_s, 1024)
+        back = -(-fb_w // size) * size          # whole blocks a window spans
+        span = min(size + back, fb_s)
+
+        def block(first):
+            lo = jnp.clip(first - back, 0, fb_s - span)
+            cut = lambda t, at, n: jax.lax.dynamic_slice_in_dim(t, at, n, 1)  # noqa: E731
+            qi = first + jnp.arange(size)[:, None]
+            kj = lo + jnp.arange(span)[None, :]
+            keep = (kj <= qi) & (kj > qi - fb_w)
+            return reference_attention(
+                cut(q, first, size), cut(k, lo, span), cut(v, lo, span),
+                causal=False, segment_mask=jnp.broadcast_to(
+                    keep, (q.shape[0],) + keep.shape))
+        out = jax.lax.map(block, jnp.arange(0, fb_s, size))
+        return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+
+    cases.append(KernelCase(
+        f"flash_band_s{fb_s}_w{fb_w}",
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=fb_w),
+        band_ref, qkv(fb_rows, fb_s, fb_h, fb_hkv, d)))
+
     # ---- dense decode (v1): one query per row over a padded cache ----
     db, dm = sz.decode_batch, sz.decode_ctx
 
@@ -380,6 +419,37 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     def stack_staged(q, k, v, index, new, layer):
         return decode_attention(q, k, v, index + 1, layer=layer,
                                 k_new=new[0, layer], v_new=new[1, layer])
+
+    # ---- the same kernel over a RING (a window layer's cache): a count of
+    # live slots and the staged token's slot; rows not yet full, exactly
+    # full and wrapped side by side ----
+    rl, rrows, rkv, rrep, rm = sz.ring_stack
+
+    def make_ring(key):
+        kq, kk, kv, kl, kn = jax.random.split(key, 5)
+        # positions so far: under the ring's length, at it, and far past it
+        index = jax.random.randint(kl, (rrows,), 0, 5 * rm, jnp.int32)
+        index = index.at[:4].set(jnp.asarray([0, rm - 1, rm, 3 * rm + 5],
+                                             jnp.int32))
+        return (normal(kq, (rrows, 1, rkv * rrep, d)),
+                normal(kk, (rl, rrows, rkv, rm, d)),
+                normal(kv, (rl, rrows, rkv, rm, d)), index,
+                normal(kn, (2, rl, rrows, rkv, d)), jnp.int32(rl - 1))
+
+    def ring_staged(q, k, v, index, new, layer):
+        return decode_attention(q, k, v, jnp.minimum(index + 1, rm),
+                                layer=layer, k_new=new[0, layer],
+                                v_new=new[1, layer], slots=index % rm)
+
+    def ring_ref(q, k, v, index, new, layer):
+        k, v = (jnp.swapaxes(x[layer], 1, 2) for x in (k, v))  # (B, M, Hkv, D)
+        rows = jnp.arange(q.shape[0])
+        k = k.at[rows, index % rm].set(new[0, layer])
+        v = v.at[rows, index % rm].set(new[1, layer])
+        return decode_ref(q, k, v, jnp.minimum(index + 1, rm))
+
+    cases.append(KernelCase(f"decode_ring_l{rl}_b{rrows}_r{rrep}_m{rm}",
+                            ring_staged, ring_ref, make_ring))
 
     for layers, rows, n_rep, m in sz.dense_stack:
         shape = f"l{layers}_b{rows}_r{n_rep}_m{m}"

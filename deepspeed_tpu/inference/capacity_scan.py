@@ -109,7 +109,8 @@ def kv_bytes_by_kind(model_cfg, batch: int, max_len: int,
     as the model's config counts them (`model_cfg.kv_bytes_by_kind`):
     `window_kv_bytes`, rings of a window's slots whatever `max_len`,
     `shared_kv_bytes`, full-length slabs that layers without a cache of
-    their own read, `latent_kv_bytes`, the latent-attention layers' one
+    their own read, `full_kv_bytes`, full-length rows of the full layers of
+    a model that mixes them with window layers, `latent_kv_bytes`, the latent-attention layers' one
     row a token in place of K and V a head, and `index_kv_bytes`, the one
     index key a token a layer that a learned selection keeps beside K and V
     or beside the latent rows (a model may name both, as DeepSeek-sparse).

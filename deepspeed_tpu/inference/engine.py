@@ -430,7 +430,8 @@ class InferenceEngine:
         # gauges and counters update on a disabled hub too (hub.py): what a
         # benchmark reads of a call without the JSONL stream
         for name in ("kv_bytes", "state_bytes", "window_kv_bytes",
-                     "shared_kv_bytes", "latent_kv_bytes", "index_kv_bytes",
+                     "shared_kv_bytes", "full_kv_bytes", "latent_kv_bytes",
+                     "index_kv_bytes",
                      "dense_kv_slots_live", "dense_kv_slots_fetched",
                      "dense_decode_grid_steps"):
             if name in kv:

@@ -93,12 +93,20 @@ class DenseLayer:
     `staged` (static): a single-token `update_layer` does not write; it
     parks the new K/V in `stage` (B, Hkv, D), attention puts it in its
     slot's place (in the tile the kernel fetched), and `KVCache.land` writes
-    every layer's staged token with ONE write a step."""
+    every layer's staged token with ONE write a step.
+
+    `ring` (static): the view is of a RING (`KVCache.ring`): its M slots hold
+    the last M positions, position p in slot p mod M. A softmax reader
+    (`ops.attention.cached_attention`) is handed the cursors as for any
+    cache and makes of them the two things a ring's reader needs: the COUNT
+    of live slots, `min(index + 1, M)`, and the staged token's slot, `index
+    mod M` (docs/kv_cache.md, "A ring's contract")."""
 
     stack: jnp.ndarray                      # (L, B, Hkv, M, D)
     layer: Optional[jnp.ndarray] = None     # () int32; None: the cache at rest
     stage: Optional[jnp.ndarray] = None     # (B, Hkv, D) this layer's new token
     staged: bool = struct.field(pytree_node=False, default=False)
+    ring: bool = struct.field(pytree_node=False, default=False)
 
 
 @struct.dataclass
@@ -165,8 +173,18 @@ class KVCache:
     def layer_views(self, layer, staged: bool) -> Tuple[DenseLayer, DenseLayer]:
         """Layer `layer`'s `(k, v)` views of a stacked cache, for
         `update_layer` and `cached_attention`."""
-        return (DenseLayer(self.k.stack, layer, staged=staged),
-                DenseLayer(self.v.stack, layer, staged=staged))
+        return (DenseLayer(self.k.stack, layer, staged=staged, ring=self.ring),
+                DenseLayer(self.v.stack, layer, staged=staged, ring=self.ring))
+
+    def write_prefill(self, layer, k_new: jnp.ndarray,
+                      v_new: jnp.ndarray) -> "KVCache":
+        """A stacked cache with layer `layer`'s K and V of a prefill FROM
+        THE EMPTY CACHE, `k_new`/`v_new` (B, S, Hkv, D), the tokens of
+        positions 0 .. S - 1 of every row (`write_prefill_rows`: a ring
+        keeps its last `max_len`)."""
+        k, v = (write_prefill_rows(side.stack, layer, new, self.ring)
+                for side, new in ((self.k, k_new), (self.v, v_new)))
+        return self.replace(k=DenseLayer(k), v=DenseLayer(v))
 
     def land(self, k_new: jnp.ndarray, v_new: jnp.ndarray) -> "KVCache":
         """A stacked cache with every layer's staged token, `k_new`/`v_new`
@@ -206,6 +224,19 @@ class KVCache:
         the cursor to `committed + accepted + 1`; rejected tokens never
         become attendable. jit-safe (index replacement, no data movement)."""
         return self.replace(index=jnp.asarray(index, jnp.int32))
+
+
+def write_prefill_rows(stack, layer, new, ring: bool):
+    """`new` (B, S, G, W), the tokens of positions 0 .. S - 1, into layer
+    `layer` of the stacked cache `(L, B, G, M, W)`, which held nothing:
+    positions as slots, or for a ring its last M tokens, position p in slot
+    p mod M. One dynamic-update-slice: it keeps the stack's tiling."""
+    m = stack.shape[3]
+    s = new.shape[1]
+    new = jnp.swapaxes(new, 1, 2).astype(stack.dtype)        # (B, G, S, W)
+    if ring and s > m:
+        new = jnp.roll(new[:, :, s - m:], (s - m) % m, axis=2)
+    return jax.lax.dynamic_update_slice(stack, new[None], (layer, 0, 0, 0, 0))
 
 
 @struct.dataclass
@@ -328,7 +359,9 @@ class HybridCache:
     A kind the model has no layer of is None. Layers that keep nothing
     (expert, dense FFN, memory unit, a reader of a shared slab) have no row
     in any. The combinations served: `kv` alone or with `window` and `state`;
-    `latent` with `state`; `kv` with `index_keys`; `latent` with
+    `kv` with `window` and no `state` (window and full softmax layers mixed:
+    rings of the window's slots beside full-length rows, one cursor a row for
+    both); `latent` with `state`; `kv` with `index_keys`; `latent` with
     `index_keys`. The cursors are those of what the attention reads at full
     length (`kv` where there is one, else `latent`), and every kind's,
     `index_keys`' too, are kept equal to them; `index`, `max_len` and

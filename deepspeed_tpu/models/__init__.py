@@ -38,3 +38,5 @@ from deepspeed_tpu.models.deepseek_sparse import (
     DeepseekSparseConfig, DeepseekSparseForCausalLM, deepseek_sparse_loss_fn)
 from deepspeed_tpu.models.openpangu import (
     OpenPanguConfig, OpenPanguForCausalLM, openpangu_loss_fn)
+from deepspeed_tpu.models.afmoe import (
+    AfmoeConfig, AfmoeForCausalLM, afmoe_loss_fn)

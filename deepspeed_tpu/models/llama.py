@@ -268,8 +268,9 @@ class LlamaAttention(nn.Module):
             from deepspeed_tpu.inference.kv_cache import update_layer
             from deepspeed_tpu.ops.attention import cached_attention
             k_cache, v_cache = update_layer(kv[0], kv[1], k, v, index)
-            # `window` tells the dispatcher the mask is banded, keeping the
-            # prefix-mask-only Pallas decode kernel off that path
+            # `window` tells the dispatcher the mask is banded over a
+            # full-length cache, keeping the Pallas decode kernel (which
+            # masks by a count of live slots) off that path
             ctx = cached_attention(q, k_cache, v_cache, index, mask,
                                    impl=cfg.attn_impl,
                                    window=cfg.sliding_window)

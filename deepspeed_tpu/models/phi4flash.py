@@ -337,18 +337,6 @@ class MemoryUnit(nn.Module):
 # ---------------------------------------------------- differential attention
 
 
-def _write_prefill(stack, layer, new, ring: bool):
-    """`new` (B, S, G, W), the tokens of positions 0 .. S - 1, into layer
-    `layer` of the stacked cache `(L, B, G, M, W)`: positions as slots, or
-    for a ring its last M tokens, position p in slot p mod M."""
-    m = stack.shape[3]
-    s = new.shape[1]
-    new = jnp.swapaxes(new, 1, 2).astype(stack.dtype)        # (B, G, S, W)
-    if ring and s > m:
-        new = jnp.roll(new[:, :, s - m:], (s - m) % m, axis=2)
-    return jax.lax.dynamic_update_slice(stack, new[None], (layer, 0, 0, 0, 0))
-
-
 class DiffAttention(nn.Module):
     """Differential attention over pairs of heads. Heads 2p and 2p + 1 are
     pair p's `(q1, q2)`, KV heads 2g and 2g + 1 group g's `(k1, k2)` and
@@ -426,8 +414,10 @@ class DiffAttention(nn.Module):
                                               keepdims=True)
                                      + cfg.layer_norm_eps)
             if views is not None:   # a prefill: the empty cache takes them
+                from deepspeed_tpu.inference.kv_cache import (
+                    write_prefill_rows)
                 ring = self.window is not None
-                made = tuple(c.replace(stack=_write_prefill(
+                made = tuple(c.replace(stack=write_prefill_rows(
                     c.stack, c.layer, new, ring))
                     for c, new in zip(views, made))
         else:

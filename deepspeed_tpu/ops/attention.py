@@ -237,8 +237,10 @@ def attention(q, k, v, causal: bool = True, softmax_scale: Optional[float] = Non
               alibi: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Flash attention (Pallas) on TPU; XLA reference elsewhere; `blockwise`
     (or long sequences off-TPU) → memory-efficient XLA online-softmax.
-    `window` (sliding-window attention) routes to the masked XLA paths —
-    the Pallas kernel has no band support yet."""
+    `window` (sliding-window attention) routes to the masked XLA paths,
+    which have a backward: the Pallas kernel's band is FORWARD only
+    (`flash_attention(window=)`, a prefill's: `banded_prefill` below), and
+    `impl='pallas'` with a window is that kernel."""
     if alibi is not None:
         # positional bias lives in the logits — masked XLA paths only
         if impl == "pallas":
@@ -254,9 +256,11 @@ def attention(q, k, v, causal: bool = True, softmax_scale: Optional[float] = Non
         return blockwise_attention(q, k, v, causal=causal,
                                    softmax_scale=softmax_scale, window=window)
     if impl == "pallas" and window is not None:
-        raise NotImplementedError(
-            "the Pallas flash kernel has no sliding-window band; use "
-            "impl='auto'/'reference'/'blockwise' with window")
+        # forward only: differentiated, it raises by name
+        from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+        assert causal, "window requires causal attention"
+        return flash_attention(q, k, v, causal=True,
+                               softmax_scale=softmax_scale, window=window)
 
     def xla_attention():
         if q.shape[1] * k.shape[1] > 4096 * 4096:
@@ -281,6 +285,27 @@ def attention(q, k, v, causal: bool = True, softmax_scale: Optional[float] = Non
         return xla_attention()
     return sharded_flash_attention(q, k, v, mesh, spec, causal=causal,
                                    softmax_scale=softmax_scale)
+
+
+def banded_prefill(q, k, v, window: int,
+                   softmax_scale: Optional[float] = None) -> jnp.ndarray:
+    """Causal attention under a sliding `window` over WHOLE sequences,
+    FORWARD only: what a prefill's window layers run. q (B, S, H, D), k/v
+    (B, S, Hkv, D). On the chip, in a one-device program and where the
+    tiling takes the shapes, the banded flash kernel (`flash_attention(
+    window=)`, traced as `self_attn_flash_fwd_band`: only the band's key
+    blocks are visited); elsewhere XLA's band (`banded_attention`). A window
+    that covers the sequence bands nothing: the plain causal dispatch."""
+    s, d = q.shape[1], q.shape[-1]
+    if window >= s:
+        return attention(q, k, v, causal=True, softmax_scale=softmax_scale)
+    if s % 128 == 0 and d % 128 == 0:
+        from deepspeed_tpu.ops.pallas import flash_attention as fa
+        if _one_device_kernel(fa.BAND_NAME):
+            return fa.flash_attention(q, k, v, causal=True,
+                                      softmax_scale=softmax_scale,
+                                      window=window)
+    return banded_attention(q, k, v, window, softmax_scale)
 
 
 def _assert_prefix_mask(mask, index, m: int, s: int = 1):
@@ -323,9 +348,14 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
 
     NOTE: the Pallas decode branches assume a PREFIX mask — slots 0..index
     valid, exactly what `kv_cache.decode_mask(positions)` produces (every
-    in-tree caller). A sliding window puts holes in the mask: pass it as
-    `window` and the dispatcher keeps such calls on the XLA path that
-    honors `mask` elementwise (callers with other non-prefix masks —
+    in-tree caller). A sliding window over a FULL-LENGTH cache puts holes
+    in the mask: pass it as `window` and the dispatcher keeps such calls on
+    the XLA path that honors `mask` elementwise. A window layer whose cache
+    is a RING (`DenseLayer.ring`: `KVCache.create_stacked(ring=True)`) has
+    no holes: its slots hold the window and nothing else, and a single-token
+    call reads it with the dense kernel (`self_attn_ring_decode`,
+    `_stacked_dense_attention`); `mask` and `window` are not consulted
+    there (callers with other non-prefix masks —
     left-padding etc. — must force impl='reference'; DS_TPU_CHECK_MASKS=1
     verifies the contract at runtime via a best-effort debug callback —
     see `_assert_prefix_mask` for its async-dispatch caveats).
@@ -478,11 +508,17 @@ def cached_attention(q, k_cache, v_cache, index, mask, impl: str = "auto",
 def _decode_kernel_wanted(impl: str, window, n_rep: int) -> bool:
     """The dense decode kernel's dispatch rule for a single-token call
     (`cached_attention` tells where the crossover was measured): a forced
-    impl, or 'auto' from a GQA group of 4 up; never under a window."""
+    impl, or 'auto' from a GQA group of 4 up. `window` is a band over a
+    FULL-LENGTH cache, whose live slots are no prefix: never the kernel,
+    which masks by a count of live slots. A window layer that keeps a RING
+    is not such a call: its reader passes `window=None` (every slot of a
+    ring is live up to a count; `_stacked_dense_attention`)."""
     if impl == "decode_pallas" and window is not None:
         raise NotImplementedError(
-            "the Pallas decode kernel is prefix-mask-only; a sliding window "
-            "needs the XLA path (impl='auto'/'reference')")
+            "the Pallas decode kernel masks by a COUNT of live slots: a "
+            "sliding window over a full-length cache needs the XLA path "
+            "(impl='auto'/'reference'), or a ring cache "
+            "(KVCache.create_stacked(ring=True)), which the kernel reads")
     # impl='pallas' is the shared attn_impl knob (training flash kernel) —
     # for a windowed decode it degrades to the masked XLA path instead of
     # raising, so one config value can serve both phases
@@ -494,6 +530,14 @@ def _decode_kernel_wanted(impl: str, window, n_rep: int) -> bool:
     return window is None and _use_pallas() and (
         impl in ("decode_pallas", "pallas")
         or (impl == "auto" and n_rep >= thresh))
+
+
+def ring_live(index, m: int):
+    """(the COUNT of live slots, the slot the staged token stands in) of a
+    ring of `m` slots whose rows have cached `index` positions before this
+    step's token (docs/kv_cache.md, "A ring's contract"): all a softmax
+    reader needs of a ring."""
+    return jnp.minimum(index + 1, m), index % m
 
 
 def dense_decode_route(impl: str, window, h: int, hkv: int, tokens: int = 1):
@@ -514,31 +558,52 @@ def _stacked_dense_attention(q, k_cache, v_cache, index, mask, impl, window,
     the dense kernel's dispatch rule hands the kernel the WHOLE stack and
     the layer (and the staged token, if the layer staged one); everything
     else cuts this layer's K/V out (one layer's worth, a transient) and
-    attends under `mask` in the stack's own axis order."""
+    attends under `mask` in the stack's own axis order.
+
+    A RING's views (`DenseLayer.ring`; one staged token a row, no alibi):
+    `index` gives the count of live slots, `min(index + 1, M)`, and the
+    staged token's slot, `index mod M`; `mask` and `window` are not
+    consulted (the ring holds the window and nothing else, and a key
+    rotated before it was cached needs no position). The kernel under its
+    ring name where the dense kernel would run, else the same count as a
+    mask over the slots."""
     b, s, h, d = q.shape
     hkv, m = k_cache.stack.shape[2], k_cache.stack.shape[3]
     n_rep = h // hkv
     staged = k_cache.stage is not None
+    ring = k_cache.ring
+    at = index
+    if ring:
+        if s != 1 or not staged or alibi is not None:
+            raise NotImplementedError(
+                "a ring is read one staged token a row (a prefill attends "
+                "its own tokens: ops.attention.banded_prefill)")
+        window = None
+        count, at = ring_live(index, m)
+        mask = (jnp.arange(m) < count[:, None])[:, None]
     kernel, mesh = dense_decode_route(impl, window, h, hkv, s)
-    if kernel and alibi is None:
-        _assert_prefix_mask(mask, index, m)
+    if kernel and alibi is None and (mesh is None or not ring):
         kw = dict(layer=k_cache.layer, k_new=k_cache.stage,
                   v_new=v_cache.stage)
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            decode_attention)
+        if ring:
+            return decode_attention(q, k_cache.stack, v_cache.stack, count,
+                                    slots=at, **kw)
+        _assert_prefix_mask(mask, index, m)
         if mesh is not None:
             from deepspeed_tpu.ops.pallas.sharded import (
                 sharded_decode_attention)
             return sharded_decode_attention(
                 q, k_cache.stack, v_cache.stack, index + 1, mesh, **kw)
-        from deepspeed_tpu.ops.pallas.decode_attention import (
-            decode_attention)
         return decode_attention(q, k_cache.stack, v_cache.stack,
                                 index + 1, **kw)
     k, v = (jax.lax.dynamic_index_in_dim(c.stack, c.layer, 0, keepdims=False)
             for c in (k_cache, v_cache))                     # (B, Hkv, M, D)
-    if staged:  # the staged token overlays its row's cursor slot
+    if staged:  # the staged token overlays its row's slot (its cursor's)
         rows = jnp.arange(b)
-        k = k.at[rows, :, index].set(k_cache.stage, mode="drop")
-        v = v.at[rows, :, index].set(v_cache.stage, mode="drop")
+        k = k.at[rows, :, at].set(k_cache.stage, mode="drop")
+        v = v.at[rows, :, at].set(v_cache.stage, mode="drop")
     # grouped, so no head is repeated: head g*n_rep+r is member r of group g
     logits = jnp.einsum("bqgrd,bgkd->bgrqk", q.reshape(b, s, hkv, n_rep, d),
                         k).astype(jnp.float32) * (1.0 / (d ** 0.5))

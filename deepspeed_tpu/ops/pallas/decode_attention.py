@@ -33,6 +33,15 @@ re-lays it first. One layer's own (B, M, Hkv, D) array, the per-layer view,
 is re-laid here and goes in as a stack of one. KV-block axis sequential,
 online-softmax state in VMEM scratch.
 
+A RING (`KVCache.ring`, a window layer's cache: position p in slot p mod M)
+is read by the same body under a second name (`RING_NAME`). Keys are rotated
+BEFORE they are cached and a softmax does not care in which slot a key lies,
+so the reader needs no position of any slot: only how many slots are live
+(`min(index + 1, M)`, what `lengths` carries) and, GIVEN APART from that
+count, the slot the step's staged token stands in (`slots`, `index mod M`:
+once the ring has wrapped it is the oldest key's, the one that leaves the
+window as this token enters it).
+
 `kv_write_dense` is the stack's writer: a decode step's one new token a row
 of every layer, in place (the stacks are aliased to the results).
 """
@@ -67,6 +76,9 @@ MAX_BLOCK_K = 1024
 # bytes (PERF.md, PR 49: at M 512, one block a row, 32 rows take 1.15 ms in
 # four steps of 8 rows and 1.08 in eight of 4)
 MIN_STEPS = 8
+# the kernel's names on the device trace: full-length rows, and a ring
+DENSE_NAME = "self_attn_dense_decode"
+RING_NAME = "self_attn_ring_decode"
 
 
 def _divisors_desc(n: int, cap: int):
@@ -109,14 +121,17 @@ def plan_traffic(plan: Tuple[int, int], lengths, m: int):
             longest.size * (m // blk_k))
 
 
-def _decode_kernel(lengths_ref, layer_ref, q_ref, k_ref, v_ref, *rest, scale,
-                   blk_k, nk, rb, hkv, n_rep, quantized, staged):
+def _decode_kernel(lengths_ref, layer_ref, *rest, scale, blk_k, nk, rb, hkv,
+                   n_rep, quantized, staged, ring):
     """One grid step: `rb` rows' every KV head over one block of slots.
-    Refs after the caches, in args order: the int8 scales, the staged pair,
-    the output, then the online-softmax state m / l / acc, one
+    Refs after the two scalars, in args order: a ring's staged slots (a
+    third scalar), the query and the caches, the int8 scales, the staged
+    pair, the output, then the online-softmax state m / l / acc, one
     (n_rep, .) tile a (row, head)."""
     del layer_ref  # the index maps read it
     rest = list(rest)
+    slots_ref = rest.pop(0) if ring else None
+    q_ref, k_ref, v_ref = rest.pop(0), rest.pop(0), rest.pop(0)
     ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quantized else (None, None)
     kn_ref, vn_ref = (rest.pop(0), rest.pop(0)) if staged else (None, None)
     o_ref, m_scr, l_scr, acc_scr = rest
@@ -135,7 +150,9 @@ def _decode_kernel(lengths_ref, layer_ref, q_ref, k_ref, v_ref, *rest, scale,
         `slots` (blk_k, 1)."""
         r, g = t // hkv, t % hkv
         length = lengths_ref[i * rb + r]     # each row at its OWN length
-        valid, hit = cols < length, slots == length - 1
+        # the staged token's slot: the last live one, or where a ring says
+        valid = cols < length
+        hit = slots == (slots_ref[i * rb + r] if ring else length - 1)
         q = q_ref[t]                         # (n_rep, D) — the GQA group
         k = k_ref[r, g]                      # (blk_k, D)
         v = v_ref[r, g]
@@ -214,7 +231,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                      v_scales: Optional[jnp.ndarray] = None,
                      layer: Optional[jnp.ndarray] = None,
                      k_new: Optional[jnp.ndarray] = None,
-                     v_new: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                     v_new: Optional[jnp.ndarray] = None,
+                     slots: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """q: (B, 1, H, D); k/v_cache: the stacked cache (L, B, Hkv, M, D) with
     `layer` () int32 the layer to read, or without one a layer's own
     (B, M, Hkv, D); lengths: (B,) valid tokens per row. With `k_new`/`v_new`
@@ -225,6 +243,11 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     drops it). Without them the new token's slot must already be written.
     `block_k` caps the KV slots a block (`decode_plan`). Returns
     (B, 1, H, D).
+
+    `slots` (B,) marks a RING (the module text): `lengths` is then the COUNT
+    of live slots, `min(index + 1, M)`, and the staged token stands in slot
+    `slots[b]` (`index mod M`), which the count alone does not give once
+    the ring has wrapped. Same body, traced as `RING_NAME`.
 
     `k_scales`/`v_scales` (B, M, Hkv) f32 mark an int8 cache (per-layer
     view only): the kernel folds the per-token scale into the logit /
@@ -246,8 +269,10 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     nk = m // blk_k
     staged = k_new is not None
     quantized = k_scales is not None
+    ring = slots is not None
+    assert staged or not ring, "a ring's reader is given the step's token"
 
-    def pairs(i, j, L, Ly):
+    def pairs(i, j, *_):
         return (i, 0, 0)
 
     def kv_block(i, j, L):
@@ -259,7 +284,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                                    [L[i * rb + r] for r in range(rb)])
         return jnp.minimum(j, jnp.maximum((longest + blk_k - 1) // blk_k - 1, 0))
 
-    def kv_index(i, j, L, Ly):
+    def kv_index(i, j, L, Ly, *_):
         return (Ly[0], i, 0, kv_block(i, j, L), 0)
 
     # (B·Hkv, n_rep, D): row-major over heads means head g*n_rep+r of the
@@ -269,8 +294,11 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         return pl.BlockSpec((rb * hkv,) + tail, pairs)
     kv_spec = pl.BlockSpec((None, rb, hkv, blk_k, d), kv_index)
     in_specs = [per_pair(n_rep, d), kv_spec, kv_spec]
-    args = [lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-            q.reshape(b * hkv, n_rep, d), k_cache, v_cache]
+    scalars = [lengths.astype(jnp.int32),
+               jnp.asarray(layer, jnp.int32).reshape(1)]
+    if ring:
+        scalars.append(slots.astype(jnp.int32))
+    args = scalars + [q.reshape(b * hkv, n_rep, d), k_cache, v_cache]
     if quantized:
         # (B, M, Hkv) → (B·Hkv, 1, M): token scales along lanes, one tile
         # per KV block beside its cache tile (same block). The unit
@@ -278,7 +306,8 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         # must be a multiple of 8 or span the array's, and one row of
         # (B·Hkv, M) is neither.
         scale_spec = pl.BlockSpec(
-            (rb * hkv, 1, blk_k), lambda i, j, L, Ly: (i, 0, kv_block(i, j, L)))
+            (rb * hkv, 1, blk_k),
+            lambda i, j, L, *_: (i, 0, kv_block(i, j, L)))
         in_specs += [scale_spec, scale_spec]
         args += [jnp.swapaxes(k_scales, 1, 2).reshape(b * hkv, 1, m),
                  jnp.swapaxes(v_scales, 1, 2).reshape(b * hkv, 1, m)]
@@ -287,7 +316,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         args += [k_new.reshape(b * hkv, 1, d), v_new.reshape(b * hkv, 1, d)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b // rb, nk),
         in_specs=in_specs,
         out_specs=per_pair(n_rep, d),
@@ -299,13 +328,13 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, blk_k=blk_k, nk=nk,
                           rb=rb, hkv=hkv, n_rep=n_rep, quantized=quantized,
-                          staged=staged),
+                          staged=staged, ring=ring),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, n_rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-        name="self_attn_dense_decode",
+        name=RING_NAME if ring else DENSE_NAME,
     )(*args)
     return out.reshape(b, 1, h, d)
 

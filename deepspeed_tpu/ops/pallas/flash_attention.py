@@ -25,6 +25,16 @@ Design (standard flash attention 2 tiling, MXU-sized blocks):
   (`flash_bwd_two_pass`).
 
 Forward returns logsumexp as a residual for the backward pass.
+
+A static `window` BANDS the causal forward (a query at t sees keys t - window
++ 1 .. t: a prefill's window layers): a query block VISITS only the key
+blocks its band touches (`_band_blocks`: the grid's last axis is as long as
+the widest band, dead blocks are neither fetched nor computed), the blocks on
+the band's two edges are masked, those between run the unmasked update. It is
+traced as `BAND_NAME`. With `window=None` nothing here differs from the
+kernel without a band, instruction for instruction. The BACKWARD under a
+window does not exist (`_flash_bwd_rule` raises by name): no cell trains a
+window family (ROADMAP.md, B6).
 """
 
 from __future__ import annotations
@@ -58,6 +68,18 @@ DEFAULT_BLOCK_K = 1024
 ONE_PASS_DQ_BYTES = 8 * 1024 * 1024
 SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 NEG_INF = -1e30
+FWD_NAME = "self_attn_flash_fwd"
+BAND_NAME = "self_attn_flash_fwd_band"      # the forward under a `window`
+# Query / key block of the BANDED forward. A query block of `blk` rows meets
+# `blk + window - 1` keys, which whole blocks cover with up to
+# `window / blk + 1` of them: at window 2,048, 3 blocks of 1024 (3,072 key
+# columns for 2,048 live ones a query) or 5 of 512 (2,560). The fewer columns
+# lose: on the chip (PERF.md, PR 60, `tools/flash_band_sweep.py`: two rows of
+# 8,192, 32 heads on 4 of 128) a call reads 6.53 ms at 1024 x 1024, 7.04 at
+# 512 x 1024, 9.00 at 1024 x 512, 9.72 at 512 x 512 and 17.48 at 256 x 256,
+# beside 9.80 for the plain causal kernel: a block pair's fixed cost and the
+# narrower products outweigh the dead columns.
+BAND_BLOCK = 1024
 # The kernels work in the BASE-2 exponent domain: log2(e)·softmax_scale is
 # folded into q once outside, p = exp2(s2 − m2), and the saved lse residual
 # is base-2 (lse2 = m2 + log2(l)) — one fewer VPU multiply per element in
@@ -92,31 +114,41 @@ def _tri_col(t, n):
     return j + (t - base(j)), j
 
 
-def _apply_causal_mask(s, mask_ij):
-    """Mask score block `s` to ki <= qi when `mask_ij` = (qi_base, ki_base);
-    identity when None. ONE definition — fwd and both bwd kernels must stay
-    mask-consistent."""
+def _apply_causal_mask(s, mask_ij, window=None):
+    """Mask score block `s` to ki <= qi when `mask_ij` = (qi_base, ki_base),
+    and under a `window` to qi - window < ki as well; identity when None.
+    ONE definition — fwd and both bwd kernels must stay mask-consistent."""
     if mask_ij is None:
         return s
     qi_base, ki_base = mask_ij
     blk_q, blk_k = s.shape
     qi = qi_base + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
     ki = ki_base + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    return jnp.where(ki <= qi, s, NEG_INF)
+    keep = ki <= qi
+    if window is not None:
+        keep = jnp.logical_and(keep, ki > qi - window)
+    return jnp.where(keep, s, NEG_INF)
 
 
-def _fwd_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, mask_ij=None):
+def _fwd_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, mask_ij=None,
+                window=None):
     """One online-softmax step over the current (blk_q, blk_k) block pair.
     q arrives PRE-SCALED by log2(e)·softmax_scale; the whole recurrence
     runs in the base-2 domain. `mask_ij` = (qi_base, ki_base) applies the
-    causal mask — only diagonal blocks pay for iota+compare+select."""
+    causal mask (and the `window`'s) — only edge blocks pay for
+    iota+compare+select."""
     s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    s = _apply_causal_mask(s, mask_ij)
+    s = _apply_causal_mask(s, mask_ij, window)
     m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp2(s - m_new)
+    if window is not None and mask_ij is not None:
+        # a band's edge block may hold NO key of a query's (its window
+        # starts further on): the row's max is then NEG_INF and exp2(0)
+        # would count every masked column
+        p = jnp.where(s > NEG_INF, p, 0.0)
     alpha = jnp.exp2(m_prev - m_new)
     l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
@@ -163,6 +195,48 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             _fwd_update(*args, mask_ij=(offset + i * blk_q, j * blk_k))
 
     @pl.when(j == nk - 1)
+    def _finalize():
+        _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _band_blocks(i, blk_q, blk_k, window):
+    """(first, last) key block that query block `i`'s band touches: keys
+    `i * blk_q - window + 1 .. i * blk_q + blk_q - 1`, none before 0."""
+    return (jnp.maximum(i * blk_q - window + 1, 0) // blk_k,
+            (i * blk_q + blk_q - 1) // blk_k)
+
+
+def _fwd_kernel_band(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                     acc_scr, *, blk_q, blk_k, nb, window):
+    """The causal forward under a `window` over (b, h, nq, nb): step `j` of
+    query block `i` is key block `first + j` of its band, the steps past the
+    band's last block are skipped (their index is clamped: no fetch)."""
+    i = pl.program_id(2)
+    j = pl.program_id(3)
+    first, last = _band_blocks(i, blk_q, blk_k, window)
+    kb = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    args = (q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr)
+    # every key of the block at or before the block's first query, and after
+    # its last query's window's start: no mask
+    full = jnp.logical_and(kb * blk_k + blk_k - 1 <= i * blk_q,
+                           kb * blk_k > i * blk_q + blk_q - 1 - window)
+
+    @pl.when(jnp.logical_and(kb <= last, full))
+    def _full():
+        _fwd_update(*args)
+
+    @pl.when(jnp.logical_and(kb <= last, jnp.logical_not(full)))
+    def _edge():
+        _fwd_update(*args, mask_ij=(i * blk_q, kb * blk_k), window=window)
+
+    @pl.when(j == nb - 1)
     def _finalize():
         _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
@@ -408,7 +482,7 @@ def _use_tri(causal, sq, sk, blk_q, blk_k):
     return causal and sq == sk and blk_q == blk_k
 
 
-def _fwd(qs, k, v, causal, blk_q, blk_k):
+def _fwd(qs, k, v, causal, blk_q, blk_k, window=None):
     """qs is the pre-scaled query (log2(e)·softmax_scale folded in)."""
     b, h, sq, d = qs.shape
     hkv, sk = k.shape[1], k.shape[2]
@@ -422,6 +496,37 @@ def _fwd(qs, k, v, causal, blk_q, blk_k):
     scratch = [pltpu.VMEM((blk_q, 128), jnp.float32),
                pltpu.VMEM((blk_q, 128), jnp.float32),
                pltpu.VMEM((blk_q, d), jnp.float32)]
+
+    if window is not None:
+        assert causal and sq == sk, "a window bands whole causal sequences"
+        # the widest band, in key blocks: the grid's last axis
+        nb = max((i * blk_q + blk_q - 1) // blk_k
+                 - max(i * blk_q - window + 1, 0) // blk_k + 1
+                 for i in range(nq))
+
+        def q_ix(b_, h_, i, j):
+            return (b_, h_, i, 0)
+
+        def kv_ix(b_, h_, i, j):
+            first, last = _band_blocks(i, blk_q, blk_k, window)
+            return (b_, h_ // n_rep, jnp.minimum(first + j, last), 0)
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel_band, blk_q=blk_q, blk_k=blk_k,
+                              nb=nb, window=window),
+            grid=(b, h, nq, nb),
+            in_specs=[pl.BlockSpec((1, 1, blk_q, d), q_ix),
+                      pl.BlockSpec((1, 1, blk_k, d), kv_ix),
+                      pl.BlockSpec((1, 1, blk_k, d), kv_ix)],
+            out_specs=[pl.BlockSpec((1, 1, blk_q, d), q_ix),
+                       pl.BlockSpec((1, 1, blk_q, 1), q_ix)],
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=_interpret(),
+            name=BAND_NAME,
+        )(qs, k, v)
 
     if _use_tri(causal, sq, sk, blk_q, blk_k):
         n = nq
@@ -447,7 +552,7 @@ def _fwd(qs, k, v, causal, blk_q, blk_k):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
-            name="self_attn_flash_fwd",
+            name=FWD_NAME,
         )(qs, k, v)
         return out, lse
 
@@ -477,7 +582,7 @@ def _fwd(qs, k, v, causal, blk_q, blk_k):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-        name="self_attn_flash_fwd",
+        name=FWD_NAME,
     )(qs, k, v)
     return out, lse
 
@@ -631,16 +736,22 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhsd(q, k, v, scale, causal, blk_q, blk_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhsd(q, k, v, scale, causal, blk_q, blk_k, window=None):
     # fold softmax scale AND the base-2 conversion into q once
     qs = (q * (scale * LOG2E)).astype(q.dtype)
-    out, _ = _fwd(qs, k, v, causal, blk_q, blk_k)
+    out, _ = _fwd(qs, k, v, causal, blk_q, blk_k, window)
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k):
+def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k, window):
     from jax.ad_checkpoint import checkpoint_name
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention(window=): the banded flash BACKWARD does not "
+            "exist (the forward serves a prefill's window layers; train a "
+            "window family through ops.attention.attention, whose window "
+            "runs XLA's masked paths)")
     qs = (q * (scale * LOG2E)).astype(q.dtype)
     out, lse = _fwd(qs, k, v, causal, blk_q, blk_k)
     # name the two residuals only the backward needs (one kernel; two past
@@ -659,7 +770,8 @@ def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k):
     return out, (qs, k, v, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, blk_q, blk_k, res, do):
+def _flash_bwd_rule(scale, causal, blk_q, blk_k, window, res, do):
+    del window  # None here: the forward rule refuses a window by name
     qs, k, v, o, lse = res  # qs pre-scaled; _bwd rescales dq at finalize
     return _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k)
 
@@ -670,11 +782,20 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_attention(q, k, v, causal: bool = True,
                     softmax_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None) -> jnp.ndarray:
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Flash attention. q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D) → (B, Sq, H, D).
 
     Block sizes: explicit args > DS_TPU_FLASH_BLOCK_Q/K env (bench sweeps) >
-    defaults."""
+    defaults (`BAND_BLOCK` under a window).
+
+    `window` (static; causal whole sequences, Sq == Sk): the BANDED forward
+    (the module text); forward only. A window that covers the sequence bands
+    nothing and is the plain causal kernel."""
+    if window is not None and window >= q.shape[1]:
+        window = None
+    if window is not None and block_q is None and block_k is None:
+        block_q = block_k = BAND_BLOCK
     if block_q is None:
         block_q = int(os.environ.get("DS_TPU_FLASH_BLOCK_Q", DEFAULT_BLOCK_Q))
     if block_k is None:
@@ -684,5 +805,5 @@ def flash_attention(q, k, v, causal: bool = True,
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    out = _flash_bhsd(qt, kt, vt, scale, causal, block_q, block_k)
+    out = _flash_bhsd(qt, kt, vt, scale, causal, block_q, block_k, window)
     return jnp.swapaxes(out, 1, 2)

@@ -52,6 +52,7 @@ TRACED_REHEARSALS = frozenset((
     "test_the_traced_rehearsal_of_the_keye_cell_runs_on_the_cpu",
     "test_the_traced_rehearsal_of_the_deepseek_cell_runs_on_the_cpu",
     "test_the_traced_rehearsal_of_the_openpangu_cell_runs_on_the_cpu",
+    "test_the_traced_rehearsal_of_the_afmoe_cell_runs_on_the_cpu",
     "test_traced_rehearsal_lists_every_new_program_metric",
     "test_setup_metrics_in_the_other_kinds_of_cell",
     "test_traced_rehearsal_reads_no_device_metric",
@@ -93,6 +94,7 @@ LONGEST_FIRST = (
     "tests/unit/inference/test_kv_pool_decode_kernel.py",
     "tests/unit/models/test_llama_tp_exchange.py",
     "tests/unit/models/test_ling_linear.py",
+    "tests/unit/models/test_afmoe.py",
     "tests/unit/inference/test_kv_pool_in_place.py",
     "tests/perfbench/test_keye_cell.py",
     "tests/unit/models/test_keye_sparse.py",

@@ -1,6 +1,6 @@
-"""What the tests of the six hybrid families share (`test_nemotron_h.py`,
+"""What the tests of the seven hybrid families share (`test_nemotron_h.py`,
 `test_phi4flash.py`, `test_ling_linear.py`, `test_keye_sparse.py`,
-`test_deepseek_sparse.py`, `test_openpangu.py`): each
+`test_deepseek_sparse.py`, `test_openpangu.py`, `test_afmoe.py`): each
 family at a small size on seeded weights with the benchmark's plain float32
 reference beside it, the model's `apply` under ONE `jax.jit`, the walk
 through the caches, and the questions asked of all six alike, written once (`the_plain_forward_...`,
@@ -26,8 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import (deepseek_sparse, keye_sparse, ling_linear,
-                                  nemotron_h, openpangu, phi4flash)
+from deepspeed_tpu.models import (afmoe, deepseek_sparse, keye_sparse,
+                                  ling_linear, nemotron_h, openpangu,
+                                  phi4flash)
 from perfbench.manifest import Manifest
 
 
@@ -117,6 +118,7 @@ LING_TOL = 5e-6         # read 6e-7
 KEYE_TOL = 3e-6         # read 4e-7
 DEEPSEEK_TOL = 5e-6
 OPENPANGU_TOL = 5e-6    # read 4e-7: absorbed against expanded, sorted rows
+AFMOE_TOL = 5e-6        # read 5e-7: rings and staged tokens against whole rows
 
 NEMOTRON_CFG = nemotron_h.NemotronHConfig(
     vocab_size=128, hidden_size=64, num_hidden_layers=6,
@@ -182,6 +184,22 @@ OPENPANGU_SIZES = dict(
     moe_intermediate_size=32, n_shared_experts=1, routed_scaling_factor=2.5,
     norm_topk_prob=True, rms_norm_eps=1e-5, max_position_embeddings=4096,
     num_nextn_predict_layers=0, sandwich_norm=True)
+# the file's keys, as the reference and the adapter read them: a dense layer
+# and four expert layers, three window layers (8 positions, with rotary)
+# among two full ones (no position), so each kind's stack has several slots
+# and neither kind's are contiguous; experts 6-9 of 16 held, the 4 best of
+# all 16 biased scores taken at once
+AFMOE_SIZES = dict(
+    vocab_size=128, hidden_size=64, intermediate_size=96,
+    num_hidden_layers=5, num_dense_layers=1, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=16, global_attn_every_n_layers=4,
+    layer_types=["sliding_attention", "full_attention", "sliding_attention",
+                 "sliding_attention", "full_attention"],
+    sliding_window=8, rope_theta=10000.0, num_experts=4, router_experts=16,
+    expert_offset=6, num_experts_per_tok=4, moe_intermediate_size=32,
+    num_shared_experts=1, route_norm=True, route_scale=2.826,
+    mup_enabled=True, rms_norm_eps=1e-5, max_position_embeddings=4096,
+    window_layers=3, full_layers=2)
 PHI4_CFG = phi4flash.Phi4FlashConfig(**PHI4_SIZES, dtype=jnp.float32)
 LING_CFG = ling_linear.LingLinearConfig(**LING_SIZES, dtype=jnp.float32)
 
@@ -305,8 +323,19 @@ def _openpangu():
                          lambda path: path.endswith("['gate']['wg']"))
 
 
+def _afmoe():
+    """The four norms a layer, the head norms and the selection bias off
+    their seeded values; the routers at a range at which the choice
+    decides. 40 positions under a window of 8: every ring wraps in the
+    prefill and again in decode."""
+    return _from_adapter("afmoe", afmoe, AFMOE_SIZES, AFMOE_TOL,
+                         afmoe.afmoe_loss_fn,
+                         lambda path: path.endswith("['gate']['wg']"))
+
+
 FAMILIES = {"deepseek_sparse": _deepseek_sparse, "openpangu": _openpangu, "nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
-            "ling_linear": _ling_linear, "keye_sparse": _keye_sparse}
+            "ling_linear": _ling_linear, "keye_sparse": _keye_sparse,
+            "afmoe": _afmoe}
 
 
 @functools.cache

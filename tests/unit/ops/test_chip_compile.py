@@ -26,6 +26,8 @@ CASES = kernel_cases(FULL)
 # its older ones for their common prefix `self_attn`.
 KERNEL_NAMES = {"flash_fwd_bwd": {"self_attn_flash_fwd", "self_attn_flash_bwd"},
                 "flash_fwd": {"self_attn_flash_fwd"},
+                "flash_band": {"self_attn_flash_fwd_band"},
+                "decode_ring": {"self_attn_ring_decode"},
                 "decode": {"self_attn_dense_decode"},
                 "paged_decode": {"self_attn_paged_decode"},
                 "paged_prefill": {"self_attn_paged_prefill"}}
