@@ -89,6 +89,10 @@ class Sizes:
     # expert width, held experts, the router's experts (DeepSeek-V3.2's chunk
     # of 2,048 tokens on a chip that holds 16 of 256)
     held_rows: Tuple[int, int, int, int, int, int]
+    # the same layer at a LONG prefill's shape, the kernel's way back
+    # (`ops/pallas/held_combine.py`) over many token tiles (Trinity-Mini's
+    # two prompts of 8,192 on a chip that holds 16 of 128)
+    held_rows_long: Tuple[int, int, int, int, int, int]
     # differential decode attention over a stack of paired heads: layers,
     # rows, groups, slots, pair width (Phi-4-mini-flash's eight rings and its
     # one shared slab at the benchmark cell's batch and length)
@@ -142,6 +146,7 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              gmm_experts=64, gmm_width=1024, qmm_group=256,
              ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856),
              held_rows=(2048, 8, 7168, 2048, 16, 256),
+             held_rows_long=(16384, 8, 2048, 1024, 16, 128),
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
              ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
              mla=(1, 128, 32, 2048, 512, 64),
@@ -162,6 +167,7 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
              qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48),
              held_rows=(288, 4, 64, 32, 1, 16),
+             held_rows_long=(576, 4, 128, 32, 1, 16),
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
              ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
              mla=(2, 3, 4, 32, 32, 8),
@@ -240,6 +246,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                                                            kv_write_dense)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
+    from deepspeed_tpu.ops.pallas.held_combine import held_combine
     from deepspeed_tpu.ops.pallas.kda import (kda_state_update,
                                               kda_state_update_reference)
     from deepspeed_tpu.ops.pallas.mla import (latent_write_dense,
@@ -703,39 +710,73 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     cases.append(KernelCase("grouped_gemm_decode", gmm_decode, gmm_decode_ref,
                             make_gmm_decode))
 
-    # a held expert layer at a prefill chunk's shape: sorted rows sized by
-    # the chip's share (`held_row_bound`), against the full-width body on
-    # the same routing; `dispatch` + `combine` timed by the trace
-    ht, hk, hd, hf, hheld, hexperts = sz.held_rows
-    htile = held_row_tile(ht * hk, hexperts)
+    # a held expert layer at a prefill chunk's shape and at a long prefill's:
+    # sorted rows sized by the chip's share (`held_row_bound`), against the
+    # full-width body on the same routing; `dispatch` + `combine` timed by
+    # the trace (read on the chip, PERF.md PR 61: 0.57 and 2.15 ms; the
+    # choice is a program PARAMETER here, (T, k) int32 sixteen times padded,
+    # and `dispatch` reads 0.19 and 1.25 ms of those where a model's own
+    # `top_k` feeds it and it reads 0.04 and 0.33)
+    def held_case(name, shape, limit_ms):
+        ht, hk, hd, hf, hheld, hexperts = shape
+        htile = held_row_tile(ht * hk, hexperts)
+        bound = held_row_bound(ht * hk, hheld, hexperts, htile)
+        assert bound < ht * hk
 
-    def make_held(key):
-        kx, kr, *kw = jax.random.split(key, 5)
+        def make_held(key):
+            kx, kr, *kw = jax.random.split(key, 5)
+            gate, idx = jax.lax.top_k(jax.nn.sigmoid(
+                jax.random.normal(kr, (ht, hexperts), jnp.float32)), hk)
+            return (normal(kx, (ht, hd)), gate / gate.sum(-1, keepdims=True),
+                    idx.astype(jnp.int32),
+                    normal(kw[0], (hheld, hd, hf)) * 0.02,
+                    normal(kw[1], (hheld, hd, hf)) * 0.02,
+                    normal(kw[2], (hheld, hf, hd)) * 0.02)
+
+        def held_layer(bounded):
+            def fn(x, gate, idx, w_gate, w_up, w_down):
+                def grouped(rows, sizes):
+                    def gg(lhs, rhs):
+                        return grouped_gemm(lhs, rhs, sizes, tiling=(
+                            htile, min(lhs.shape[1], 1024),
+                            min(rhs.shape[2], 1024)))
+                    return gg(jax.nn.silu(gg(rows, w_gate)) * gg(rows, w_up),
+                              w_down)
+                return held_dispatch_gmm(x, gate, idx, 0, hheld, grouped,
+                                         bound=bound if bounded else None)[0]
+            return fn
+
+        return KernelCase(name, held_layer(True), held_layer(False), make_held,
+                          scopes_ms=(("dispatch", "combine"), limit_ms))
+
+    cases.append(held_case("held_rows", sz.held_rows, 1.5))
+    cases.append(held_case("held_rows_long", sz.held_rows_long, 2.8))
+
+    # the way back ALONE at the long shape, against each token's float32
+    # sum of its rows found through the sort; what lies past the held rows
+    # is large, so a row read from there shows
+    ct, ck, cd, _, cheld, cexperts = sz.held_rows_long
+    cbound = held_row_bound(ct * ck, cheld, cexperts,
+                            held_row_tile(ct * ck, cexperts))
+
+    def make_combine(key):
+        kr, ko = jax.random.split(key)
         gate, idx = jax.lax.top_k(jax.nn.sigmoid(
-            jax.random.normal(kr, (ht, hexperts), jnp.float32)), hk)
-        return (normal(kx, (ht, hd)), gate / gate.sum(-1, keepdims=True),
-                idx.astype(jnp.int32), normal(kw[0], (hheld, hd, hf)) * 0.02,
-                normal(kw[1], (hheld, hd, hf)) * 0.02,
-                normal(kw[2], (hheld, hf, hd)) * 0.02)
+            jax.random.normal(kr, (ct, cexperts), jnp.float32)), ck)
+        local = jnp.where(idx < cheld, idx, cheld).astype(jnp.int32)
+        rows = jnp.arange(cbound)[:, None] < jnp.sum(local < cheld)
+        return (jnp.where(rows, normal(ko, (cbound, cd)), 1e4
+                          ).astype(jnp.bfloat16), local, gate)
 
-    def held_layer(bounded):
-        def fn(x, gate, idx, w_gate, w_up, w_down):
-            def grouped(rows, sizes):
-                def gg(lhs, rhs):
-                    return grouped_gemm(lhs, rhs, sizes, tiling=(
-                        htile, min(lhs.shape[1], 1024),
-                        min(rhs.shape[2], 1024)))
-                return gg(jax.nn.silu(gg(rows, w_gate)) * gg(rows, w_up),
-                          w_down)
-            bound = held_row_bound(ht * hk, hheld, hexperts, htile)
-            assert bound < ht * hk
-            return held_dispatch_gmm(x, gate, idx, 0, hheld, grouped,
-                                     bound=bound if bounded else None)[0]
-        return fn
+    def combine_ref(out_s, local, gate):
+        place = jnp.argsort(jnp.argsort(local.reshape(-1))).reshape(ct, ck)
+        rows = jnp.take(out_s, jnp.minimum(place, cbound - 1), axis=0)
+        return jnp.sum(jnp.where(local < cheld, gate, 0.0)[:, :, None]
+                       * rows.astype(jnp.float32), axis=1)   # no MXU rounding
 
-    cases.append(KernelCase("held_rows", held_layer(True), held_layer(False),
-                            make_held,
-                            scopes_ms=(("dispatch", "combine"), 1.5)))
+    cases.append(KernelCase(
+        "held_combine", lambda *a: held_combine(*a, cheld), combine_ref,
+        make_combine, tol=1e-5))
 
     # ---- the recurrent-state update of a Mamba-2 decode step ----
     sl, sb, sh, sp, sn, sg = sz.ssm

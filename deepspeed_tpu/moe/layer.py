@@ -20,8 +20,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.moe.sharded_moe import (
     _gating_core, dispatch_combine, dispatch_combine_gmm,
     dispatch_combine_ragged, held_assignments, held_dispatch_gmm,
-    held_dispatch_ragged, held_row_bound, held_row_tile, route_topk,
-    topkgating)
+    held_dispatch_ragged, held_group_sizes, held_row_bound, held_row_tile,
+    route_topk, topkgating)
 from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 
@@ -412,8 +412,7 @@ class MoE(nn.Module):
             out = out + shared(x[None])[0].astype(jnp.float32)
         total = t * k if valid is None else k * jnp.sum(valid.astype(jnp.int32))
         local = held_assignments(topk_idx, self.held_offset, count, valid)[1]
-        touched = jnp.sum(jnp.bincount(local.reshape(-1), length=count + 1)
-                          [:count] > 0)
+        touched = jnp.sum(held_group_sizes(local, count) > 0)
         for name, value in (("assignments", total), ("held_assignments", held),
                             ("held_wide_calls", wide),
                             ("experts_touched", touched),

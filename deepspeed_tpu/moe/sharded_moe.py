@@ -19,6 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.pallas.held_combine import held_combine
 from deepspeed_tpu.utils.partitioning import shard_along
 
 
@@ -91,8 +92,16 @@ def route_topk(logits: jnp.ndarray, k: int, score_fn: str = "softmax",
     the choice is limited by groups where `n_group` is above 1."""
     scores, chosen_by = route_scores(logits, score_fn, select_bias, n_group,
                                      topk_group)
-    _, topk_idx = jax.lax.top_k(chosen_by, k)
-    gate_k = jnp.take_along_axis(scores, topk_idx, axis=-1)
+    gate_k, topk_idx = jax.lax.top_k(chosen_by, k)
+    if chosen_by is not scores:
+        # what the top-k was taken of is NOT the scores (logits, a selection
+        # bias, groups): the chosen experts' scores by a select and a sum
+        # over the expert axis, one term of which is not zero, so the
+        # gathered value bit for bit without a gather of T x k scalars
+        experts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, scores.shape[-1]),
+                                           2)
+        gate_k = jnp.sum(jnp.where(topk_idx[..., None] == experts,
+                                   scores[:, None, :], 0.0), axis=-1)
     if norm_topk_prob:
         gate_k = gate_k / jnp.maximum(
             jnp.sum(gate_k, axis=-1, keepdims=True), 1e-20)
@@ -311,6 +320,14 @@ def held_assignments(topk_idx: jnp.ndarray, offset: int, count: int,
     return held, jnp.where(held, local, count)
 
 
+def held_group_sizes(local: jnp.ndarray, count: int) -> jnp.ndarray:
+    """How many assignments each of the `count` held experts received, from
+    `held_assignments`' local ids: a compare and a sum over the values an id
+    can take, one fused pass and no scatter into bins."""
+    return jnp.sum(local.reshape(-1, 1) == jnp.arange(count, dtype=local.dtype),
+                   axis=0, dtype=jnp.int32)
+
+
 # `held_row_bound`'s three constants (the rule is in `held_dispatch_gmm`)
 HELD_ROWS_MARGIN = 2        # the bound over the rows a share expects
 HELD_NARROW_FROM = 1024     # assignments a call up to which none is set
@@ -359,14 +376,17 @@ def held_dispatch_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
     one of them whenever there are `bound` or fewer, and then the gather, the
     grouped FFN's operands and the way back to token order are `bound` rows
     and never T x k: the chip moves the rows it holds, as under expert
-    parallelism the all-to-all would bring it no others. The way back is a
-    second sort of those `bound` rows by token, which lays a token's rows
-    side by side; running sums of the weighted float32 rows within a token
-    (by doubling: a token has at most k); and each token gathering the LAST
-    of its rows (a token's terms added by held expert and in pairs, not in
-    the choice's order: float32 rounding of the same sum). No scatter: XLA's
-    row scatter-add read 0.7 to 11 ms for these rows on this chip, by the
-    width and by what the compiler fused into it. EXACT for every routing:
+    parallelism the all-to-all would bring it no others. The way back is ONE
+    Pallas pass over those rows as the grouped GEMM left them
+    (`ops/pallas/held_combine.py`: the sort is stable and a token's k ids
+    are distinct, so a tile of tokens needs `count` contiguous windows of
+    the rows, one in each expert's group; each token's float32 sum of
+    float32(row) x weight, its terms added by held expert and not in the
+    choice's order: float32 rounding of the same sum). No second sort, no
+    gather and no scatter: XLA's row gathers and shifted passes cost 217 ns
+    a bound ROW whatever its width (PERF.md, PR 61), its row scatter-add 0.7
+    to 11 ms for 2,048 rows by the width and by what the compiler fused
+    into it. EXACT for every routing:
     a call whose held rows pass the bound (a skewed router, a hot chunk)
     takes the full-width body under `lax.cond`; nothing is dropped or
     capped. `grouped_fn` is a function of arrays alone (it is traced in both
@@ -389,7 +409,7 @@ def held_dispatch_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
         held, local = held_assignments(topk_idx, offset, count, valid)
         key = local.reshape(-1)                         # absent sort last
         order = jnp.argsort(key)                        # stable
-        group_sizes = jnp.bincount(key, length=count + 1)[:count]
+        group_sizes = held_group_sizes(local, count)
         n_held = jnp.sum(group_sizes)
 
     def wide():
@@ -407,35 +427,11 @@ def held_dispatch_gmm(x: jnp.ndarray, gate_k: jnp.ndarray,
 
     def narrow():
         with jax.named_scope("dispatch"):
-            first = order[:bound]                       # every held row
-            xs = jnp.take(x, first // k, axis=0)
+            # every held row; `order` is a permutation, so in range
+            xs = x.at[order[:bound] // k].get(mode="promise_in_bounds")
         out_s = grouped_fn(xs, group_sizes)             # (bound, D)
         with jax.named_scope("combine"):
-            # the second, SHORT sort: the held rows by token, the rows past
-            # them (never written) after every token's, as zeros
-            tok = jnp.where(jnp.arange(bound) < n_held, first // k, t)
-            by_tok = jnp.argsort(tok)
-            tok = jnp.take(tok, by_tok)
-            w = jnp.take(gate_k.reshape(-1), jnp.take(first, by_tok))
-            terms = jnp.where(
-                (tok < t)[:, None],
-                jnp.take(out_s, by_tok, axis=0).astype(jnp.float32)
-                * w[:, None], 0.0)
-            # running sums within a token: each row takes the one `step`
-            # before it where that is the same token's
-            step = 1
-            while step < k:
-                same = jnp.concatenate([jnp.zeros((step,), jnp.bool_),
-                                        tok[step:] == tok[:-step]])
-                before = jnp.concatenate(
-                    [jnp.zeros((step, d), jnp.float32), terms[:-step]])
-                terms = terms + jnp.where(same[:, None], before, 0.0)
-                step *= 2
-            per_token = jnp.bincount(tok, length=t + 1)[:t]
-            last = jnp.cumsum(per_token) - 1
-            return jnp.where(
-                (per_token > 0)[:, None],
-                jnp.take(terms, jnp.maximum(last, 0), axis=0), 0.0)
+            return held_combine(out_s, local, gate_k, count)
 
     if bound is None or bound >= t * k:
         return wide(), n_held, jnp.zeros((), jnp.int32)

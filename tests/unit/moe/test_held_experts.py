@@ -287,3 +287,75 @@ def test_the_layer_sizes_its_rows_by_its_share_and_counts_the_wide_calls(
         int(other["counters"]["held_assignments"])
     if skewed:
         assert int(counted["held_assignments"]) == NT   # one of a token's 4
+
+
+# ---------------------------------- no gather or scatter round the GEMMs
+ROUTERS = [
+    ("softmax", "softmax", False, 1, 1),
+    ("sigmoid", "sigmoid", False, 1, 1),         # `top_k`'s own values
+    ("sigmoid_select_bias", "sigmoid", True, 1, 1),
+    ("softmax_select_bias", "softmax", True, 1, 1),
+    ("sigmoid_groups", "sigmoid", False, 4, 2),
+    ("sigmoid_select_bias_groups", "sigmoid", True, 4, 2),
+]
+
+
+@pytest.mark.parametrize("router", ROUTERS, ids=[r[0] for r in ROUTERS])
+@pytest.mark.parametrize("norm", [True, False], ids=["normed", "raw"])
+def test_the_chosen_weights_are_the_gathered_scores_bit_for_bit(router, norm):
+    """`route_topk` picks the chosen experts' scores with no gather (a select
+    and a sum of one term, or the top-k's own values where it was taken of
+    the scores): the same bits as `take_along_axis` of the scores, for every
+    score function, with a selection bias and with groups."""
+    from deepspeed_tpu.moe.sharded_moe import route_scores
+    _, score_fn, biased, n_group, topk_group = router
+    ks = jax.random.split(jax.random.PRNGKey(61), 2)
+    logits = jax.random.normal(ks[0], (37, 16)) * 2.0
+    bias = jax.random.normal(ks[1], (16,)) * 0.3 if biased else None
+    got, idx = route_topk(logits, 3, score_fn, bias, norm, 2.5, n_group,
+                          topk_group)
+    scores, chosen_by = route_scores(logits, score_fn, bias, n_group,
+                                     topk_group)
+    _, want_idx = jax.lax.top_k(chosen_by, 3)
+    want = jnp.take_along_axis(scores, want_idx, axis=-1)
+    if norm:
+        want = want / jnp.maximum(want.sum(-1, keepdims=True), 1e-20)
+    assert jnp.array_equal(idx, want_idx)
+    assert jnp.array_equal(got, want * 2.5)
+    assert float(got.min()) > 0.0
+
+
+@pytest.mark.parametrize("count,padded,routing", [
+    (1, False, "drawn"), (2, True, "drawn"), (4, False, "drawn"),
+    (8, True, "drawn"), (2, False, "absent"), (1, False, "one_expert")])
+def test_the_counts_are_bincounts_without_a_scatter(count, padded, routing):
+    """`held_group_sizes` (a compare and a sum) is `bincount` of the held
+    experts' local ids, and the layer's `experts_touched` the number of its
+    non-empty bins, padding rows and absent assignments in no bin."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+    x, gate, idx, valid, ws = _narrow_inputs(routing, count, padded)
+    held, local = sm.held_assignments(idx, 0, count, valid)
+    want = jnp.bincount(local.reshape(-1), length=count + 1)[:count]
+    got = jax.jit(sm.held_group_sizes, static_argnums=1)(local, count)
+    assert got.dtype == jnp.int32 and jnp.array_equal(got, want)
+    assert int(got.sum()) == int(held.sum())
+    _, n_held, _ = sm.held_dispatch_gmm(x, gate, idx, 0, count, _grouped(ws),
+                                        valid)
+    assert int(n_held) == int(want.sum())
+
+    moe = MoE(hidden_size=D, num_experts=NE, k=NK, intermediate_size=F,
+              drop_tokens=False, dtype=jnp.float32, activation="relu2",
+              dispatch_impl="gmm", score_fn="sigmoid", held_offset=0,
+              held_experts=count)
+    xs = x.reshape(2, NT // 2, D)
+    params = nn.meta.unbox(moe.init(jax.random.PRNGKey(4), xs,
+                                    train=False)["params"])
+    params["gate"]["wg"] = params["gate"]["wg"] * 40.0
+    ok = None if valid is None else valid.reshape(2, NT // 2)
+    _, sown = moe.apply({"params": params}, xs, train=False, valid=ok,
+                        mutable=["counters"])
+    _, chosen = route_topk(x @ params["gate"]["wg"], NK, "sigmoid")
+    mine = sm.held_assignments(chosen, 0, count, valid)[1]
+    bins = jnp.bincount(mine.reshape(-1), length=count + 1)[:count]
+    assert int(sown["counters"]["experts_touched"]) == int((bins > 0).sum())
+    assert int(sown["counters"]["held_assignments"]) == int(bins.sum())

@@ -42,6 +42,9 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "diff_attn_window_decode": "diff_attn_window_decode",
                "diff_attn_shared_decode": "diff_attn_shared_decode",
                "grouped_gemm_decode": "gmm",
+               "held_rows": "held_combine",
+               "held_rows_long": "held_combine",
+               "held_combine": "held_combine",
                "kda_state_update": "kda_state_update",
                "mla_latent_decode": "mla_latent_decode",
                "mla_latent_decode_h128": "mla_latent_decode",
@@ -81,12 +84,13 @@ def compiled_kernels(monkeypatch):
     steer them to the compiled path here, in the test."""
     from deepspeed_tpu.ops.pallas import (
         block_sparse_attention, decode_attention, diff_attention,
-        flash_attention, grouped_gemm, kda, mla, mla_sparse, paged_attention,
-        quantized_matmul, sparse_select, ssm)
+        flash_attention, grouped_gemm, held_combine, kda, mla, mla_sparse,
+        paged_attention, quantized_matmul, sparse_select, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
     for mod in (block_sparse_attention, decode_attention, diff_attention,
-                flash_attention, grouped_gemm, kda, mla, mla_sparse,
-                paged_attention, quantized_matmul, sparse_select, ssm):
+                flash_attention, grouped_gemm, held_combine, kda, mla,
+                mla_sparse, paged_attention, quantized_matmul, sparse_select,
+                ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 

@@ -11,8 +11,9 @@ compiled text by the program map's own rows (`tools/tpucomms/hlo.py`), as
   or a result of `T x k` rows by `D`; inside that body they are what they
   were (so the test can tell);
 - a decode step (4 assignments a row) is under the rule's floor: its program
-  has no `conditional` of the layer's own (the interpreted grouped GEMM's
-  `pl.when`s under `experts` are the kernel's, and the CPU's alone).
+  has no `conditional` of the layer's own (the `pl.when`s of the interpreted
+  grouped GEMM under `experts`, and of the interpreted way back under
+  `combine`, are the kernels', and the CPU's alone).
 """
 
 import re
@@ -72,10 +73,10 @@ def _held(rows):
 
 
 def _own_branches(rows):
-    """The layer's own `conditional`s (the interpreted kernel's lie under
-    `experts`)."""
+    """The layer's own `conditional`s (the interpreted kernels' lie under
+    `experts` and under `combine`)."""
     return [r for r in _held(rows) if r["opcode"] == "conditional"
-            and "experts" not in hlo.scope_names(r["scope"])]
+            and not {"experts", "combine"} & set(hlo.scope_names(r["scope"]))]
 
 
 def _reached_from(comps, start):
