@@ -23,7 +23,7 @@ layers and after them `router_experts` sigmoid-scored experts: the
 `num_experts_per_tok` largest of `s + b` (`b` the selection bias, in the
 CHOICE only; no groups), weights `s` over their sum (`route_norm`) times
 `route_scale`, beside `num_shared_experts` shared ones, unweighted
-(`moe/layer.MoE` as `models/ling_linear._experts` builds it). Then a final
+(`moe/layer.MoE` as `hybrid.held_experts` builds it). Then a final
 RMSNorm and an untied head.
 
 THE CACHE (`make_cache`; `inference/kv_cache.HybridCache`): `kv`, full-length
@@ -37,7 +37,7 @@ rotated BEFORE it is cached, so its slot need say nothing of its position).
 A pass of S > 1 over a cache is a PREFILL FROM THE EMPTY CACHE: whole rows
 through the flash forward, BANDED in the window layers
 (`ops.attention.banded_prefill`), a few rows of the batch at a time through
-all the layers (`_RowGroups`), and returns logits `(B, 1, V)`: the head at
+all the layers (`hybrid.row_groups`), and returns logits `(B, 1, V)`: the head at
 each row's last position only.
 
 The layers are NOT stacked and scanned, for `models/nemotron_h.py`'s reason
@@ -59,13 +59,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.ling_linear import DenseFFN, _experts
+from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.llama import RMSNorm, _dense
-from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 F32 = jnp.float32
 SLIDING, FULL = "sliding_attention", "full_attention"
-# Tokens of a prefill that walk the layers together (`_RowGroups`): two rows
+# Tokens of a prefill that walk the layers together (`hybrid.row_groups`): two
+# rows
 # of 8,192. The widest temporaries are a dense layer's 2 x 6,144 a token (0.4
 # GB at 16,384 tokens) and the gathered rows of the held experts.
 PREFILL_TOKENS = 16384
@@ -103,7 +103,7 @@ class AfmoeConfig:
     dispatch_impl: str = "auto"
     attn_impl: str = "auto"
 
-    # the family's ONE router, as `ling_linear._experts` reads it: the best
+    # the family's ONE router, as `hybrid.held_experts` reads it: the best
     # of ALL the biased scores at once. Constants of the class, not fields
     n_group = 1
     topk_group = 1
@@ -127,7 +127,7 @@ class AfmoeConfig:
                              "groups")
         object.__setattr__(self, "layer_types", kinds)
 
-    # ---- what `ling_linear._experts` reads, under the names it reads
+    # ---- what `hybrid.held_experts` reads, under the names it reads
     @property
     def norm_topk_prob(self) -> bool:
         return self.route_norm
@@ -282,9 +282,13 @@ class Layers(nn.Module):
             h = h + norm(f"layer_{i}_post_attn_norm")(out)
             x = norm(f"layer_{i}_mlp_norm")(h)
             if i < cfg.num_dense_layers:
-                out = DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
+                out = hybrid.DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
             else:
-                out = _experts(cfg, f"layer_{i}_mlp")(x, train=False)
+                out = hybrid.held_experts(
+                    cfg, f"layer_{i}_mlp", held=cfg.num_experts,
+                    activation="silu", score_fn="sigmoid",
+                    shared=cfg.moe_shared_expert_intermediate_size)(
+                        x, train=False)
             h = h + norm(f"layer_{i}_post_mlp_norm")(out)
         if decode:      # the step's one write a kind, every layer's token
             for kind, pairs in staged.items():
@@ -314,32 +318,6 @@ class Layers(nn.Module):
                      reduce_fn=lambda a, b_: a + b_)
 
 
-def _embedded(cfg: AfmoeConfig, embed, ids):
-    h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
-    if cfg.mup_enabled:
-        h = (h.astype(F32) * cfg.embed_scale).astype(cfg.dtype)
-    return shard_along(h, BATCH_AXES, "sequence", None)
-
-
-class _RowGroups(nn.Module):
-    """`Layers` for `rows` sequences of the batch at a time, the whole cache
-    carried: the body of the scan a large prefill runs over its rows. It
-    shares `Layers`' scope, so the parameters are the same tree. The group's
-    tokens are embedded here, and only each sequence's last position goes
-    on to the head."""
-    cfg: AfmoeConfig
-    rows: int
-
-    @nn.compact
-    def __call__(self, cache, embed, group):
-        ids, start = group
-        layers = Layers(self.cfg)
-        nn.share_scope(self, layers)
-        h, part = layers(_embedded(self.cfg, embed, ids),
-                         cache.rows(start, self.rows))
-        return cache.with_rows(part, start), h[:, -1:]
-
-
 class AfmoeForCausalLM(nn.Module):
     cfg: AfmoeConfig
     # what the layers count inside a serving program, summed over the call by
@@ -350,40 +328,9 @@ class AfmoeForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
-        cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
-        b, s = input_ids.shape
-        rows = max((r for r in range(1, b + 1)
-                    if b % r == 0 and r * s <= PREFILL_TOKENS), default=1)
-        if cache is not None and s > 1 and rows < b:
-            walk = nn.scan(_RowGroups, variable_broadcast="params",
-                           variable_axes={"counters": 0},
-                           split_rngs={"params": False},
-                           in_axes=(nn.broadcast, 0), out_axes=0)
-            cache, h = walk(cfg, rows, name="layers")(
-                cache, embed, (input_ids.reshape(b // rows, rows, s),
-                               jnp.arange(0, b, rows, dtype=jnp.int32)))
-            h = h.reshape(b, 1, -1)
-        else:
-            h, cache = Layers(cfg, name="layers")(
-                _embedded(cfg, embed, input_ids), cache)
-            if cache is not None:
-                h = h[:, -1:]
-        if cache is not None:
-            cache = cache.advance(s)
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(h)
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), F32)
-        logits = h @ lm_head.astype(cfg.dtype)
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        return hybrid.causal_lm(self, Layers, input_ids, labels, cache,
+                                eps=self.cfg.rms_norm_eps,
+                                prefill_tokens=PREFILL_TOKENS)
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -391,10 +338,8 @@ class AfmoeForCausalLM(nn.Module):
         `max_len` positions, by kind: the full layers' full-length rows and
         the window layers' rings of `sliding_window` slots."""
         from deepspeed_tpu.inference.kv_cache import HybridCache, KVCache
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("Afmoe: an int8 KV cache is not implemented "
-                             "for a hybrid cache (kv_cache_dtype=None)")
         dtype = dtype or cfg.dtype
         stacked = lambda layers, slots, ring: KVCache.create_stacked(  # noqa: E731
             layers, batch, slots, cfg.num_key_value_heads, cfg.head_dim,
@@ -404,22 +349,5 @@ class AfmoeForCausalLM(nn.Module):
             window=stacked(cfg.window_layers, cfg.sliding_window, True))
 
 
-def init_params_and_specs(cfg: AfmoeConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = AfmoeForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: AfmoeConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree is 11.3 GB beside its
-    bf16 copy)."""
-    from deepspeed_tpu.models.common import materialize
-    model = AfmoeForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def afmoe_loss_fn(model: AfmoeForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, afmoe_loss_fn = \
+    hybrid.entry_points(AfmoeForCausalLM)

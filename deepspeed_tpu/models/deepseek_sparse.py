@@ -29,7 +29,7 @@ FFN(RMSNorm(h))`; for a token t with `u = RMSNorm(h)`:
   after them `router_experts` sigmoid-routed SwiGLU experts (top 8, the
   choice limited to `topk_group` of `n_group` groups, a selection bias,
   weights over their sum times `routed_scaling_factor`) beside one shared
-  expert: `moe/layer.MoE` as `models/ling_linear.py` builds it.
+  expert: `moe/layer.MoE` as `hybrid.held_experts` builds it.
 
 then a final RMSNorm and an untied head. The multi-token-prediction block
 (`num_nextn_predict_layers`) is a drafter of its own and is not built.
@@ -41,7 +41,7 @@ step stages its token in both (`ops.attention.sparse_select`, the scores over
 a row's live index keys and its choice; `latent_sparse_decode`, the ABSORBED
 attention over the chosen rows) and lands each kind once after the layers. A
 PREFILL walks the batch a ROW and a CHUNK of queries at a time through all
-the layers (`models/keye_sparse.prefill_walk`): the chunk's rows and index
+the layers (`hybrid.prefill_walk`): the chunk's rows and index
 keys are written into the row's slabs first, then `latent_sparse_prefill`
 scores, chooses and attends, in the EXPANDED form, against the slabs up to
 each query's own position.
@@ -65,13 +65,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models import latent
-from deepspeed_tpu.models.keye_sparse import (INDEX_NORM_EPS, _embedded,
-                                              prefill_walk)
-from deepspeed_tpu.models.ling_linear import DenseFFN, _experts
+from deepspeed_tpu.models import hybrid, latent
 from deepspeed_tpu.models.llama import RMSNorm, _dense
 
 F32 = jnp.float32
+# Queries of one row that walk the layers together in a prefill
+# (`models/keye_sparse.py` has the readings its 2,048 was chosen by)
+PREFILL_CHUNK = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,7 +190,7 @@ class SparseLatentAttention(nn.Module):
         # the indexer's queries come out of the query's own compression
         q_i = _dense(hi * di, ("embed", "heads"), cfg.dtype,
                      "index_q_proj")(cq).reshape(b, s, hi, di)
-        k_i = nn.LayerNorm(epsilon=INDEX_NORM_EPS, dtype=cfg.dtype,
+        k_i = nn.LayerNorm(epsilon=hybrid.INDEX_NORM_EPS, dtype=cfg.dtype,
                            param_dtype=F32, name="index_k_norm")(
             _dense(di, ("embed", None), cfg.dtype, "index_k_proj")(x))
         w = _dense(hi, ("embed", None), cfg.dtype,
@@ -260,9 +260,13 @@ class Layers(nn.Module):
             h = h + out
             x = norm(f"layer_{i}_mlp_norm")(h)
             if i < cfg.first_k_dense_replace:
-                h = h + DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
+                h = h + hybrid.DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
             else:
-                h = h + _experts(cfg, f"layer_{i}_mlp")(x, train=False)
+                h = h + hybrid.held_experts(
+                    cfg, f"layer_{i}_mlp", held=cfg.num_experts,
+                    activation="silu", score_fn="sigmoid",
+                    shared=cfg.moe_shared_expert_intermediate_size)(
+                        x, train=False)
         if staged:      # the step's one write a kind, every layer's token
             lat, k_i = (jnp.stack(t) for t in zip(*staged))
             cache = cache.replace(latent=cache.latent.land(lat),
@@ -280,28 +284,9 @@ class DeepseekSparseForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
-        cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
-        if cache is not None and input_ids.shape[1] > 1:
-            cache, h = prefill_walk(Layers, cfg, cache, embed, input_ids)
-        else:
-            h, cache = Layers(cfg, name="layers")(
-                _embedded(cfg, embed, input_ids), cache)
-            if cache is not None:
-                cache = cache.advance(1)
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(h)
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), F32)
-        logits = h @ lm_head.astype(cfg.dtype)
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        return hybrid.causal_lm(self, Layers, input_ids, labels, cache,
+                                eps=self.cfg.rms_norm_eps,
+                                prefill_chunk=PREFILL_CHUNK)
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -309,11 +294,8 @@ class DeepseekSparseForCausalLM(nn.Module):
         `max_len` positions (`cfg.cache_slots` of them): every layer's latent
         rows and, beside them, its index keys; no K or V."""
         from deepspeed_tpu.inference.kv_cache import HybridCache, LatentCache
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("DeepseekSparse: an int8 cache is not "
-                             "implemented for a hybrid cache "
-                             "(kv_cache_dtype=None)")
         make = lambda width: LatentCache.create(  # noqa: E731
             cfg.num_hidden_layers, batch, cfg.cache_slots(max_len), width,
             dtype=dtype or cfg.dtype)
@@ -321,23 +303,5 @@ class DeepseekSparseForCausalLM(nn.Module):
                            index_keys=make(cfg.index_head_dim))
 
 
-def init_params_and_specs(cfg: DeepseekSparseConfig, rng=None,
-                          seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = DeepseekSparseForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: DeepseekSparseConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree is 18.5 GB and fits no
-    chip)."""
-    from deepspeed_tpu.models.common import materialize
-    model = DeepseekSparseForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def deepseek_sparse_loss_fn(model: DeepseekSparseForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, deepseek_sparse_loss_fn = \
+    hybrid.entry_points(DeepseekSparseForCausalLM)

@@ -1,0 +1,299 @@
+"""What the v1 hybrid families share (`nemotron_h`, `phi4flash`,
+`ling_linear`, `keye_sparse`, `deepseek_sparse`, `openpangu`, `afmoe`): a
+decision several of them must know lives here ONCE, and no family file
+imports another. How a prefill is CUT (`row_groups` under the family's
+`PREFILL_TOKENS`, `prefill_walk` under its `PREFILL_CHUNK`); what a serving
+pass hands the HEAD (`causal_lm`, the shell around a family's `Layers`, and
+its pieces for the two families whose shell differs); how a held share of
+experts is BUILT (`held_experts`, beside `DenseFFN`); the entry points a
+family's module binds (`entry_points`), every `make_cache`'s int8 refusal,
+two initialisers, the index key's epsilon.
+
+A family file holds its config, its mixers, its `Layers` walk, its
+`make_cache` and its counters. `models/common.py` is what the llama-layout
+files share, `models/latent.py` what the compressed-query MLA families do.
+`tools/hybrid_lowered.py` lowers every family's three programs: a change
+here that means to change no program proves it there.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.common import (abstract_specs, causal_lm_loss,
+                                         make_causal_loss_fn, materialize)
+from deepspeed_tpu.models.llama import RMSNorm, _dense
+from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
+
+F32 = jnp.float32
+INDEX_NORM_EPS = 1e-6   # an index key's LayerNorm (the two learned selections)
+
+
+def a_log_init(key, shape, dtype=F32):
+    """`A` uniform in [1, 16), as its log (Mamba-2's; KDA's gate keeps it)."""
+    return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0)).astype(dtype)
+
+
+def dt_bias_init(cfg):
+    def init(key, shape, dtype=F32):
+        # dt drawn log-uniform in [time_step_min, time_step_max], stored as
+        # its inverse softplus (Mamba's own initialisation)
+        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+        dt = jnp.exp(jax.random.uniform(key, shape, F32) * (hi - lo) + lo)
+        dt = jnp.maximum(dt, cfg.time_step_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+class DenseFFN(nn.Module):
+    """`W_down(silu(W_gate x) * W_up x)` at `intermediate_size`."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate, up = (_dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
+                           name)(x) for name in ("gate_proj", "up_proj"))
+        return _dense(cfg.hidden_size, ("mlp_in", "embed"), cfg.dtype,
+                      "down_proj")(jax.nn.silu(gate) * up)
+
+
+def held_experts(cfg, name: str, *, held: int, activation: str, score_fn: str,
+                 shared=None):
+    """The expert layer as `moe/layer.MoE` computes it: `held` experts (the
+    family passes the number: the configs name it differently) of the
+    `router_experts` the router scores (None: all are held) from
+    `expert_offset` on, of `activation` (`relu2` / `silu`), a shared expert
+    of width `shared` beside them (None: none), nothing dropped by capacity.
+    `score_fn` `softmax`: the taken ones' weights over their sum. `sigmoid`:
+    besides, the selection bias in the CHOICE only (none where the family's
+    `router_bias_scale` is None), the choice limited by groups (`n_group` 1:
+    the best of all at once), the weights times `routed_scaling_factor`."""
+    from deepspeed_tpu.moe.layer import MoE
+    router = {}
+    if score_fn == "sigmoid":
+        biased = cfg.router_bias_scale is not None
+        router = dict(
+            selection_bias=biased,
+            bias_init=nn.initializers.normal(cfg.router_bias_scale) if biased
+            else nn.initializers.zeros_init(),
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            n_group=cfg.n_group, topk_group=cfg.topk_group)
+    return MoE(
+        hidden_size=cfg.hidden_size, num_experts=cfg.router_experts or held,
+        k=cfg.num_experts_per_tok,
+        intermediate_size=cfg.moe_intermediate_size,
+        norm_topk_prob=cfg.norm_topk_prob, drop_tokens=False,
+        dtype=cfg.dtype, activation=activation,
+        dispatch_impl=cfg.dispatch_impl, score_fn=score_fn,
+        held_offset=cfg.expert_offset, held_experts=held,
+        shared_intermediate_size=shared, name=name, **router)
+
+
+# ---------------------------------------------------------------- the shell
+
+
+def embed_tokens(module):
+    """The `embed_tokens` parameter of `module` (inside its compact call)."""
+    cfg = module.cfg
+    return module.param("embed_tokens", nn.with_logical_partitioning(
+        nn.initializers.normal(0.02), ("vocab", "embed")),
+        (cfg.vocab_size, cfg.hidden_size), F32)
+
+
+def embedded(cfg, embed, ids):
+    """The tokens' rows of `embed`, times the muP scale where the config
+    says one (`mup_enabled`, `embed_scale`)."""
+    h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
+    if getattr(cfg, "mup_enabled", False):
+        h = (h.astype(F32) * cfg.embed_scale).astype(cfg.dtype)
+    return shard_along(h, BATCH_AXES, "sequence", None)
+
+
+def lm_logits(module, h, eps: float):
+    """`norm_f`, then the untied `lm_head` (inside `module`'s call)."""
+    cfg = module.cfg
+    h = RMSNorm(eps, cfg.dtype, name="norm_f")(h)
+    lm_head = module.param("lm_head", nn.with_logical_partitioning(
+        nn.initializers.normal(0.02), ("embed", "vocab")),
+        (cfg.hidden_size, cfg.vocab_size), F32)
+    return h @ lm_head.astype(cfg.dtype)
+
+
+def lm_output(logits, input_ids, labels, cache):
+    """(logits, cache) from a serving pass, the logits, or the loss."""
+    if cache is not None:
+        return logits, cache
+    if labels is None:
+        return logits
+    return causal_lm_loss(logits, input_ids, labels)
+
+
+# ----------------------------------------------------- how a prefill is cut
+
+
+def rows_a_group(b: int, s: int, tokens: int) -> int:
+    """The rows of a batch of `b` prompts of `s` that walk the layers
+    together under a budget of `tokens`: the largest divisor of `b` that
+    fits (one row where not even one does)."""
+    return max((r for r in range(1, b + 1)
+                if b % r == 0 and r * s <= tokens), default=1)
+
+
+class RowGroups(nn.Module):
+    """`layers` (a module class `(cfg)` called `(h, cache)`, whose result
+    ENDS with the cache) for `rows` sequences of the batch at a time, the
+    whole cache carried: the body of the scan a large prefill runs over its
+    rows. It shares the layers' scope, so the parameters are the same tree.
+    The group's tokens are embedded here (the whole batch's embedded prompt
+    is 0.67 GB at 128 x 1024) unless they arrive embedded (`embed` None),
+    and only each sequence's last position goes on to the head unless
+    `every` one does. What goes on is every stream the layers return before
+    the cache (one; Phi-4-mini-flash's two), a tuple."""
+    cfg: Any
+    layers: Any
+    rows: int
+    every: bool = False
+
+    @nn.compact
+    def __call__(self, cache, embed, group):
+        x, start = group
+        layers = self.layers(self.cfg)
+        nn.share_scope(self, layers)
+        if embed is not None:
+            x = embedded(self.cfg, embed, x)
+        *streams, part = layers(x, cache.rows(start, self.rows))
+        return cache.with_rows(part, start), tuple(
+            t if self.every else t[:, -1:] for t in streams if t is not None)
+
+
+def row_groups(layers, cfg, cache, embed, x, rows: int, *, name="layers",
+               every: bool = False):
+    """A prefill as a scan over groups of `rows` rows (`RowGroups`), the
+    parameters under `name`: (the cache filled and NOT advanced, the streams
+    (B, 1 or S, hidden) the layers returned, a tuple). `x` (B, S) tokens, or
+    (B, S, hidden) already embedded with `embed` None. What the layers sow
+    under `counters` comes out stacked a group."""
+    b = x.shape[0]
+    walk = nn.scan(RowGroups, variable_broadcast="params",
+                   variable_axes={"counters": 0},
+                   split_rngs={"params": False},
+                   in_axes=(nn.broadcast, 0), out_axes=0)
+    cache, streams = walk(cfg, layers, rows, every, name=name)(
+        cache, embed, (x.reshape((b // rows, rows) + x.shape[1:]),
+                       jnp.arange(0, b, rows, dtype=jnp.int32)))
+    return cache, tuple(t.reshape(b, t.shape[2], -1) for t in streams)
+
+
+def prefill_chunks(s: int, chunk: int):
+    """(size, starts): how a prompt of `s` tokens is walked `chunk` queries
+    at a time, every length in chunks of whole 128-query tiles (the kernels'
+    shape; a prompt under 128 is one chunk of its own length). A row's LAST
+    chunk is drawn back to end at the row's end, and what it overlaps is
+    computed and written again."""
+    size = min(chunk, s // 128 * 128 or s)
+    return size, [min(i * size, s - size) for i in range(-(-s // size))]
+
+
+class Chunks(nn.Module):
+    """The family's `layers` (a module class `(cfg)` called `(h, cache,
+    row)`) for ONE chunk of ONE row, the whole cache carried: the body of
+    the scan a prefill runs over (row, chunk) pairs. It shares the layers'
+    scope, so the parameters are the same tree. The chunk's tokens are
+    embedded here, its row's cursors move on by its length, and only its
+    last position goes on (the head reads each row's last chunk's). A chunk
+    drawn `back` over its row's last one starts that far before the cursor:
+    those positions' K, V and index keys are written again (from the same
+    tokens against the same cache) and the layers count them again."""
+    cfg: Any
+    layers: Any
+
+    @nn.compact
+    def __call__(self, cache, embed, chunk):
+        ids, row, back = chunk                              # (1, C), (), ()
+        layers = self.layers(self.cfg)
+        nn.share_scope(self, layers)
+        h, cache = layers(embedded(self.cfg, embed, ids),
+                          cache.advance_row(row, -back), row)
+        return cache.advance_row(row, ids.shape[1]), h[:, -1:]
+
+
+def prefill_walk(layers, cfg, cache, embed, input_ids, chunk: int):
+    """A prefill from the empty cache as a scan over (row, chunk) pairs
+    (`prefill_chunks`, `Chunks`), the parameters under `layers`: (the cache
+    filled AND advanced, each row's last position's hidden state (B, 1,
+    hidden))."""
+    b, s = input_ids.shape
+    if s > cache.max_len:
+        raise ValueError(f"a prefill of {s} positions into a cache of "
+                         f"{cache.max_len}")
+    size, starts = prefill_chunks(s, chunk)
+    n = len(starts)
+    back = jnp.asarray([i * size - at for i, at in enumerate(starts)],
+                       jnp.int32)
+    ids = jnp.stack([input_ids[:, at:at + size] for at in starts], 1)
+    walk = nn.scan(Chunks, variable_broadcast="params",
+                   variable_axes={"counters": 0},
+                   split_rngs={"params": False},
+                   in_axes=(nn.broadcast, 0), out_axes=0)
+    cache, h = walk(cfg, layers, name="layers")(
+        cache, embed, (ids.reshape(b * n, 1, size),
+                       jnp.repeat(jnp.arange(b, dtype=jnp.int32), n),
+                       jnp.tile(back, b)))
+    return cache, h.reshape(b, n, 1, -1)[:, -1]     # each row's last chunk's
+
+
+def causal_lm(module, layers, input_ids, labels, cache, *, eps: float,
+              prefill_tokens: int = None, prefill_chunk: int = None):
+    """A family's causal LM around its `layers` (the module class, looked up
+    by the family when its call is traced), inside `module`'s compact call:
+    `embed_tokens`, the layers under `layers`, `norm_f` at `eps`, `lm_head`,
+    the loss. A serving pass (`cache`) hands the head each row's LAST
+    position and advances the cursors; its prefill (S > 1) is cut as the
+    family says: `prefill_chunk` queries of one row at a time, from the
+    empty cache, or as many rows together as `prefill_tokens` holds."""
+    cfg = module.cfg
+    embed = embed_tokens(module)
+    b, s = input_ids.shape
+    prefill = cache is not None and s > 1
+    if prefill and prefill_chunk:
+        cache, h = prefill_walk(layers, cfg, cache, embed, input_ids,
+                                prefill_chunk)
+    elif prefill and (rows := rows_a_group(b, s, prefill_tokens)) < b:
+        cache, (h,) = row_groups(layers, cfg, cache, embed, input_ids, rows)
+        cache = cache.advance(s)
+    else:
+        h, cache = layers(cfg, name="layers")(
+            embedded(cfg, embed, input_ids), cache)
+        if cache is not None:
+            h = h[:, -1:]          # a serving pass samples the last one
+            cache = cache.advance(s)
+    return lm_output(lm_logits(module, h, eps), input_ids, labels, cache)
+
+
+def entry_points(model_cls):
+    """(`init_params_and_specs`, `materialize_params`, `<family>_loss_fn`):
+    a family's module binds them under the names its callers import."""
+    def init_params_and_specs(cfg, rng=None, seq_len: int = 8):
+        model = model_cls(cfg)
+        return model, abstract_specs(model, rng, seq_len)
+
+    def materialize_params(cfg, rng=None, seq_len: int = 8, param_dtype=None):
+        """(model, the whole tree on the device from the seed), ONE jitted
+        call; `param_dtype` casts inside it (the cells' float32 trees are 11
+        to 20 GB: none fits a chip beside its bf16 copy, most fit none)."""
+        model = model_cls(cfg)
+        return model, materialize(model, rng, seq_len, param_dtype)
+    return init_params_and_specs, materialize_params, make_causal_loss_fn
+
+
+def refuse_int8(model, quantized: bool):
+    if quantized:       # every hybrid `make_cache`'s first line
+        raise ValueError(
+            f"{type(model).__name__}: an int8 cache is not implemented for "
+            "a hybrid cache (kv_cache_dtype=None)")

@@ -70,15 +70,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.llama import RMSNorm, _dense
-from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 F32 = jnp.float32
 # Queries of one row that walk the layers together in a prefill: at 2,048 the
 # choice's bias is 136 MB a layer call, the experts' sorted rows 0.13 GB, and
 # the weights are read 16 times a 32,768-token row.
 PREFILL_CHUNK = 2048
-INDEX_NORM_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,7 +162,7 @@ class SparseAttention(nn.Module):
         k = norm("k_norm")(heads(proj(hkv * d, "k_proj")(x), hkv, d))
         v = heads(proj(hkv * d, "v_proj")(x), hkv, d)
         q_i = heads(proj(hi * di, "index_q_proj")(x), hi, di)
-        k_i = nn.LayerNorm(epsilon=INDEX_NORM_EPS, dtype=cfg.dtype,
+        k_i = nn.LayerNorm(epsilon=hybrid.INDEX_NORM_EPS, dtype=cfg.dtype,
                            param_dtype=F32, name="index_k_norm")(
             _dense(di, ("embed", None), cfg.dtype, "index_k_proj")(x))
         w = _dense(hi, ("embed", None), cfg.dtype,
@@ -244,23 +243,6 @@ def _write_chunk(cache, slot, row, start, k, v, k_i):
             c=put(cache.index_keys.c.stack, k_i[None])))
 
 
-def _experts(cfg: KeyeSparseConfig, name: str):
-    """The expert layer as `moe/layer.MoE` computes it: softmax scores over
-    every expert the router scores, the taken ones' weights over their sum,
-    SwiGLU experts of which this chip may hold a share, no shared expert,
-    nothing dropped by capacity."""
-    from deepspeed_tpu.moe.layer import MoE
-    return MoE(
-        hidden_size=cfg.hidden_size,
-        num_experts=cfg.router_experts or cfg.num_experts,
-        k=cfg.num_experts_per_tok,
-        intermediate_size=cfg.moe_intermediate_size,
-        norm_topk_prob=cfg.norm_topk_prob, drop_tokens=False,
-        dtype=cfg.dtype, activation="silu", dispatch_impl=cfg.dispatch_impl,
-        score_fn="softmax", held_offset=cfg.expert_offset,
-        held_experts=cfg.num_experts, name=name)
-
-
 class Layers(nn.Module):
     """The walk over the layers: `layer_<i>` the attention, `layer_<i>_mlp`
     the experts, each behind its norm."""
@@ -279,74 +261,17 @@ class Layers(nn.Module):
             elif made is not None:
                 cache = made
             h = h + out
-            h = h + _experts(cfg, f"layer_{i}_mlp")(
-                norm(f"layer_{i}_mlp_norm")(h), train=False)
+            # softmax scores over every expert the router scores, the taken
+            # ones' weights over their sum; no shared expert
+            h = h + hybrid.held_experts(
+                cfg, f"layer_{i}_mlp", held=cfg.num_experts,
+                activation="silu", score_fn="softmax")(
+                    norm(f"layer_{i}_mlp_norm")(h), train=False)
         if staged:      # the step's one write a kind, every layer's token
             k, v, k_i = (jnp.stack(t) for t in zip(*staged))
             cache = cache.replace(kv=cache.kv.land(k, v),
                                   index_keys=cache.index_keys.land(k_i))
         return h, cache
-
-
-def prefill_chunks(s: int):
-    """(size, starts): how a prompt of `s` tokens is walked, every length in
-    chunks of whole 128-query tiles (the kernels' shape; a prompt under 128
-    is one chunk of its own length). A row's LAST chunk is drawn back to end
-    at the row's end, and what it overlaps is computed and written again."""
-    size = min(PREFILL_CHUNK, s // 128 * 128 or s)
-    return size, [min(i * size, s - size) for i in range(-(-s // size))]
-
-
-def _embedded(cfg, embed, ids):
-    h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
-    return shard_along(h, BATCH_AXES, "sequence", None)
-
-
-class _Chunks(nn.Module):
-    """The family's `layers` (a module class `(cfg)` called `(h, cache,
-    row)`) for ONE chunk of ONE row, the whole cache carried: the body of
-    the scan a prefill runs over (row, chunk) pairs. It shares the layers'
-    scope, so the parameters are the same tree. The chunk's tokens are
-    embedded here, its row's cursors move on by its length, and only its
-    last position goes on (the head reads each row's last chunk's). A chunk
-    drawn `back` over its row's last one starts that far before the cursor:
-    those positions' K, V and index keys are written again (from the same
-    tokens against the same cache) and the layers count them again."""
-    cfg: Any
-    layers: Any
-
-    @nn.compact
-    def __call__(self, cache, embed, chunk):
-        ids, row, back = chunk                              # (1, C), (), ()
-        layers = self.layers(self.cfg)
-        nn.share_scope(self, layers)
-        h, cache = layers(_embedded(self.cfg, embed, ids),
-                          cache.advance_row(row, -back), row)
-        return cache.advance_row(row, ids.shape[1]), h[:, -1:]
-
-
-def prefill_walk(layers, cfg, cache, embed, input_ids):
-    """A prefill from the empty cache as a scan over (row, chunk) pairs
-    (`prefill_chunks`, `_Chunks`), the parameters under `layers`: (the cache
-    filled, each row's last position's hidden state (B, 1, hidden))."""
-    b, s = input_ids.shape
-    if s > cache.max_len:
-        raise ValueError(f"a prefill of {s} positions into a cache of "
-                         f"{cache.max_len}")
-    size, starts = prefill_chunks(s)
-    n = len(starts)
-    back = jnp.asarray([i * size - at for i, at in enumerate(starts)],
-                       jnp.int32)
-    ids = jnp.stack([input_ids[:, at:at + size] for at in starts], 1)
-    walk = nn.scan(_Chunks, variable_broadcast="params",
-                   variable_axes={"counters": 0},
-                   split_rngs={"params": False},
-                   in_axes=(nn.broadcast, 0), out_axes=0)
-    cache, h = walk(cfg, layers, name="layers")(
-        cache, embed, (ids.reshape(b * n, 1, size),
-                       jnp.repeat(jnp.arange(b, dtype=jnp.int32), n),
-                       jnp.tile(back, b)))
-    return cache, h.reshape(b, n, 1, -1)[:, -1]     # each row's last chunk's
 
 
 class KeyeSparseForCausalLM(nn.Module):
@@ -359,29 +284,9 @@ class KeyeSparseForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
-        cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
-        b, s = input_ids.shape
-        if cache is not None and s > 1:
-            cache, h = prefill_walk(Layers, cfg, cache, embed, input_ids)
-        else:
-            h, cache = Layers(cfg, name="layers")(
-                _embedded(cfg, embed, input_ids), cache)
-            if cache is not None:
-                cache = cache.advance(1)
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(h)
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), F32)
-        logits = h @ lm_head.astype(cfg.dtype)
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        return hybrid.causal_lm(self, Layers, input_ids, labels, cache,
+                                eps=self.cfg.rms_norm_eps,
+                                prefill_chunk=PREFILL_CHUNK)
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -390,10 +295,8 @@ class KeyeSparseForCausalLM(nn.Module):
         beside them, its index keys."""
         from deepspeed_tpu.inference.kv_cache import (HybridCache, KVCache,
                                                       LatentCache)
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("KeyeSparse: an int8 cache is not implemented "
-                             "for a hybrid cache (kv_cache_dtype=None)")
         dtype = dtype or cfg.dtype
         return HybridCache(
             kv=KVCache.create_stacked(
@@ -404,21 +307,5 @@ class KeyeSparseForCausalLM(nn.Module):
                 dtype=dtype))
 
 
-def init_params_and_specs(cfg: KeyeSparseConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = KeyeSparseForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: KeyeSparseConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it."""
-    from deepspeed_tpu.models.common import materialize
-    model = KeyeSparseForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def keye_sparse_loss_fn(model: KeyeSparseForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, keye_sparse_loss_fn = \
+    hybrid.entry_points(KeyeSparseForCausalLM)

@@ -52,12 +52,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.llama import RMSNorm, _dense
-from deepspeed_tpu.models.nemotron_h import _a_log_init
-from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 F32 = jnp.float32
-# Tokens of a prefill that walk the layers together (`_RowGroups`): at 8 x
+# Tokens of a prefill that walk the layers together (`hybrid.row_groups`): at 8 x
 # 1024 the chunked KDA form's float32 operands are 0.13 GB each, the experts'
 # sorted rows 0.34 GB, the expanded latent attention's keys 0.1 GB, beside
 # 8.8 GB of weights and 1.7 GB of cache.
@@ -288,7 +287,8 @@ class KDAMixer(nn.Module):
         conv_w = self.param(
             "conv_kernel", lambda key, sh, dt=F32: jax.random.uniform(
                 key, sh, dt, -bound, bound), (kw, 3 * di), F32).astype(F32)
-        a = jnp.exp(self.param("A_log", _a_log_init, (nh,), F32).astype(F32))
+        a = jnp.exp(self.param("A_log", hybrid.a_log_init, (nh,),
+                               F32).astype(F32))
         dt_bias = self.param("dt_bias", _dt_bias_init, (di,), F32)
         norm_w = self.param("norm_weight", nn.initializers.ones_init(), (d,),
                             F32)
@@ -408,45 +408,6 @@ class MLAMixer(nn.Module):
 # ------------------------------------------------------------------- layers
 
 
-class DenseFFN(nn.Module):
-    """`W_down(silu(W_gate x) * W_up x)` at `intermediate_size`."""
-    cfg: LingLinearConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        gate, up = (_dense(cfg.intermediate_size, ("embed", "mlp"), cfg.dtype,
-                           name)(x) for name in ("gate_proj", "up_proj"))
-        return _dense(cfg.hidden_size, ("mlp_in", "embed"), cfg.dtype,
-                      "down_proj")(jax.nn.silu(gate) * up)
-
-
-def _experts(cfg: LingLinearConfig, name: str):
-    """The expert layer as `moe/layer.MoE` computes it: sigmoid scores, the
-    selection bias in the choice only (none where the family's
-    `router_bias_scale` is None), the choice limited by groups (`n_group` 1:
-    the best of all at once), SwiGLU experts of which this chip may hold a
-    share, a shared expert, nothing dropped by capacity. The three sigmoid
-    families' (this one, `deepseek_sparse`, `openpangu`)."""
-    from deepspeed_tpu.moe.layer import MoE
-    biased = cfg.router_bias_scale is not None
-    return MoE(
-        hidden_size=cfg.hidden_size,
-        num_experts=cfg.router_experts or cfg.num_experts,
-        k=cfg.num_experts_per_tok,
-        intermediate_size=cfg.moe_intermediate_size,
-        norm_topk_prob=cfg.norm_topk_prob, drop_tokens=False,
-        dtype=cfg.dtype, activation="silu", dispatch_impl=cfg.dispatch_impl,
-        score_fn="sigmoid", selection_bias=biased,
-        bias_init=nn.initializers.normal(cfg.router_bias_scale) if biased
-        else nn.initializers.zeros_init(),
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        n_group=cfg.n_group, topk_group=cfg.topk_group,
-        held_offset=cfg.expert_offset, held_experts=cfg.num_experts,
-        shared_intermediate_size=cfg.moe_shared_expert_intermediate_size,
-        name=name)
-
-
 class Layers(nn.Module):
     """The walk over the layers: one loop over `cfg.kinds`, each layer's
     mixer (`layer_<i>`) built by its kind and handed its slab of its kind's
@@ -474,38 +435,19 @@ class Layers(nn.Module):
             h = h + out
             x = norm(f"layer_{i}_mlp_norm")(h)
             if i < cfg.first_k_dense_replace:
-                h = h + DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
+                h = h + hybrid.DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
             else:
-                h = h + _experts(cfg, f"layer_{i}_mlp")(x, train=False)
+                # group-limited sigmoid experts beside a shared one
+                h = h + hybrid.held_experts(
+                    cfg, f"layer_{i}_mlp", held=cfg.num_experts,
+                    activation="silu", score_fn="sigmoid",
+                    shared=cfg.moe_shared_expert_intermediate_size)(
+                        x, train=False)
         if staged:  # the step's one write, every MLA layer's token
             latent = latent.land(jnp.stack(staged))
         if cache is not None:
             cache = cache.replace(state=state, latent=latent)
         return h, cache
-
-
-def _embedded(cfg: LingLinearConfig, embed, ids):
-    h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
-    return shard_along(h, BATCH_AXES, "sequence", None)
-
-
-class _RowGroups(nn.Module):
-    """`Layers` for `rows` sequences of the batch at a time, the whole cache
-    carried: the body of the scan a large prefill runs over its rows. It
-    shares `Layers`' scope, so the parameters are the same tree. The group's
-    tokens are embedded here (the whole batch's embedded prompt is 0.67 GB at
-    128 x 1024), and only each sequence's last position goes on to the head."""
-    cfg: LingLinearConfig
-    rows: int
-
-    @nn.compact
-    def __call__(self, cache, embed, group):
-        ids, start = group
-        layers = Layers(self.cfg)
-        nn.share_scope(self, layers)
-        h, part = layers(_embedded(self.cfg, embed, ids),
-                         cache.rows(start, self.rows))
-        return cache.with_rows(part, start), h[:, -1:]
 
 
 class LingLinearForCausalLM(nn.Module):
@@ -517,38 +459,9 @@ class LingLinearForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
-        cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
-        b, s = input_ids.shape
-        rows = max((r for r in range(1, b + 1)
-                    if b % r == 0 and r * s <= PREFILL_TOKENS), default=1)
-        if cache is not None and s > 1 and rows < b:
-            walk = nn.scan(_RowGroups, variable_broadcast="params",
-                           variable_axes={"counters": 0},
-                           split_rngs={"params": False},
-                           in_axes=(nn.broadcast, 0), out_axes=0)
-            cache, h = walk(cfg, rows, name="layers")(
-                cache, embed, (input_ids.reshape(b // rows, rows, s),
-                               jnp.arange(0, b, rows, dtype=jnp.int32)))
-            h = h.reshape(b, 1, -1)
-        else:
-            h, cache = Layers(cfg, name="layers")(
-                _embedded(cfg, embed, input_ids), cache)
-            if cache is not None:
-                h = h[:, -1:]          # a serving pass samples the last one
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(h)
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), F32)
-        logits = h @ lm_head.astype(cfg.dtype)
-        if cache is not None:
-            return logits, cache.advance(s)
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        return hybrid.causal_lm(self, Layers, input_ids, labels, cache,
+                                eps=self.cfg.rms_norm_eps,
+                                prefill_tokens=PREFILL_TOKENS)
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -557,10 +470,8 @@ class LingLinearForCausalLM(nn.Module):
         KDA layers' matrix states and convolution tails, no K or V at all."""
         from deepspeed_tpu.inference.kv_cache import (HybridCache, LatentCache,
                                                       RecurrentState)
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("LingLinear: an int8 cache is not implemented "
-                             "for a hybrid cache (kv_cache_dtype=None)")
         dtype = dtype or cfg.dtype
         return HybridCache(
             kv=None,
@@ -571,22 +482,5 @@ class LingLinearForCausalLM(nn.Module):
                 cfg.short_conv_kernel_size, cfg.conv_dim, dtype=dtype))
 
 
-def init_params_and_specs(cfg: LingLinearConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = LingLinearForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: LingLinearConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree is 17.6 GB and fits no
-    chip)."""
-    from deepspeed_tpu.models.common import materialize
-    model = LingLinearForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def ling_linear_loss_fn(model: LingLinearForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, ling_linear_loss_fn = \
+    hybrid.entry_points(LingLinearForCausalLM)

@@ -38,13 +38,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.llama import RMSNorm, _dense
-from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 F32 = jnp.float32
 KINDS = "ME*"
 # Tokens of a prefill that walk the layers together. A larger batch goes a
-# few rows at a time (`_RowGroups`): the SSD's decay matrices (rows x blocks x
+# few rows at a time (`hybrid.row_groups`): the SSD's decay matrices (rows x blocks x
 # heads x 128 x 128 float32), attention's logits over the whole cache and the
 # experts' sorted rows are gigabytes at 64 rows x 512 tokens, beside 9 GB of
 # weights; the weights read once more a group cost a few ms each.
@@ -198,21 +198,6 @@ def ssd_chunked(x, dt, a, b, c, h0, chunk: int):
     return y, h_last.reshape(bsz, nh, p, n)
 
 
-def _dt_bias_init(cfg: NemotronHConfig):
-    def init(key, shape, dtype=F32):
-        # dt drawn log-uniform in [time_step_min, time_step_max], stored as
-        # its inverse softplus (Mamba-2's own initialisation)
-        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-        dt = jnp.exp(jax.random.uniform(key, shape, F32) * (hi - lo) + lo)
-        dt = jnp.maximum(dt, cfg.time_step_floor)
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    return init
-
-
-def _a_log_init(key, shape, dtype=F32):
-    return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0)).astype(dtype)
-
-
 class MambaMixer(nn.Module):
     """Mamba-2: `[z, xBC, dt] = in_proj(u)`; a causal depthwise convolution
     and silu over `xBC`; the recurrence; `y * silu(z)` THEN an RMS norm over
@@ -238,8 +223,9 @@ class MambaMixer(nn.Module):
                 k, sh, dt, -bound, bound), (kw, cd), F32).astype(F32)
         conv_b = self.param("conv_bias", nn.initializers.zeros_init(), (cd,),
                             F32).astype(F32) if cfg.use_conv_bias else 0.0
-        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (nh,), F32)
-        a = -jnp.exp(self.param("A_log", _a_log_init, (nh,), F32).astype(F32))
+        dt_bias = self.param("dt_bias", hybrid.dt_bias_init(cfg), (nh,), F32)
+        a = -jnp.exp(self.param("A_log", hybrid.a_log_init, (nh,),
+                                F32).astype(F32))
         d_skip = self.param("D", nn.initializers.ones_init(), (nh,), F32)
         norm_w = self.param("norm_weight", nn.initializers.ones_init(), (di,),
                             F32)
@@ -337,27 +323,6 @@ class Attention(nn.Module):
 # ------------------------------------------------------------------- layers
 
 
-def _experts(cfg: NemotronHConfig, name: str):
-    """The expert layer as `moe/layer.MoE` computes it: sigmoid scores, the
-    selection bias in the choice only, relu² experts of which this chip may
-    hold a share, a shared expert, nothing dropped by capacity."""
-    from deepspeed_tpu.moe.layer import MoE
-    return MoE(
-        hidden_size=cfg.hidden_size,
-        num_experts=cfg.router_experts or cfg.n_routed_experts,
-        k=cfg.num_experts_per_tok,
-        intermediate_size=cfg.moe_intermediate_size,
-        norm_topk_prob=cfg.norm_topk_prob, drop_tokens=False,
-        dtype=cfg.dtype, activation="relu2", dispatch_impl=cfg.dispatch_impl,
-        score_fn="sigmoid", selection_bias=True,
-        bias_init=nn.initializers.normal(cfg.router_bias_scale),
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        n_group=cfg.n_group, topk_group=cfg.topk_group,
-        held_offset=cfg.expert_offset, held_experts=cfg.n_routed_experts,
-        shared_intermediate_size=cfg.moe_shared_expert_intermediate_size,
-        name=name)
-
-
 class Layers(nn.Module):
     """The walk over the layers: one loop over the pattern string, each
     layer's mixer built by its kind (`layer_<i>`, its norm `layer_<i>_norm`)
@@ -386,29 +351,19 @@ class Layers(nn.Module):
                         kv = kv.replace(k=k_l.replace(layer=None),
                                         v=v_l.replace(layer=None))
             else:
-                out = _experts(cfg, f"layer_{i}")(x, train=False)
+                # sigmoid scores, the selection bias in the choice only,
+                # relu² experts beside a shared one
+                out = hybrid.held_experts(
+                    cfg, f"layer_{i}", held=cfg.n_routed_experts,
+                    activation="relu2", score_fn="sigmoid",
+                    shared=cfg.moe_shared_expert_intermediate_size)(
+                        x, train=False)
             h = h + out
         if staged:  # the step's one write, every attention layer's token
             kv = kv.land(*(jnp.stack(side) for side in zip(*staged)))
         if cache is not None:
             cache = cache.replace(state=state, kv=kv)
         return h, cache
-
-
-class _RowGroups(nn.Module):
-    """`Layers` for `rows` sequences of the batch at a time, the whole cache
-    carried: the body of the scan a large prefill runs over its rows. It
-    shares `Layers`' scope, so the parameters are the same tree."""
-    cfg: NemotronHConfig
-    rows: int
-
-    @nn.compact
-    def __call__(self, cache, group):
-        h, start = group
-        layers = Layers(self.cfg)
-        nn.share_scope(self, layers)
-        h, part = layers(h, cache.rows(start, self.rows))
-        return cache.with_rows(part, start), h
 
 
 class NemotronHForCausalLM(nn.Module):
@@ -420,36 +375,22 @@ class NemotronHForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
+        """`hybrid.causal_lm` but for two things this family's program
+        depends on: the whole batch is embedded BEFORE the walk, and EVERY
+        prefill position goes on to the head."""
         cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
-        h = jnp.take(embed.astype(cfg.dtype), input_ids, axis=0)
-        h = shard_along(h, BATCH_AXES, "sequence", None)
+        h = hybrid.embedded(cfg, hybrid.embed_tokens(self), input_ids)
         b, s = input_ids.shape
-        rows = max((r for r in range(1, b + 1)
-                    if b % r == 0 and r * s <= PREFILL_TOKENS), default=b)
+        rows = hybrid.rows_a_group(b, s, PREFILL_TOKENS)
         if cache is not None and s > 1 and rows < b:
-            walk = nn.scan(_RowGroups, variable_broadcast="params",
-                           variable_axes={"counters": 0},
-                           split_rngs={"params": False}, in_axes=0, out_axes=0)
-            cache, h = walk(cfg, rows, name="layers")(
-                cache, (h.reshape(b // rows, rows, s, -1),
-                        jnp.arange(0, b, rows, dtype=jnp.int32)))
-            h = h.reshape(b, s, -1)
+            cache, (h,) = hybrid.row_groups(Layers, cfg, cache, None, h, rows,
+                                            every=True)
         else:
             h, cache = Layers(cfg, name="layers")(h, cache)
-        h = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm_f")(h)
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), F32)
-        logits = h @ lm_head.astype(cfg.dtype)
-        if cache is not None:
-            return logits, cache.advance(s)
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        logits = hybrid.lm_logits(self, h, cfg.norm_eps)
+        return hybrid.lm_output(
+            logits, input_ids, labels,
+            None if cache is None else cache.advance(s))
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -459,10 +400,8 @@ class NemotronHForCausalLM(nn.Module):
         layers' state beside them."""
         from deepspeed_tpu.inference.kv_cache import (HybridCache, KVCache,
                                                       RecurrentState)
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("NemotronH: an int8 KV cache is not implemented "
-                             "for a hybrid cache (kv_cache_dtype=None)")
         dtype = dtype or cfg.dtype
         return HybridCache(
             kv=KVCache.create_stacked(cfg.count("*"), batch, max_len,
@@ -473,22 +412,5 @@ class NemotronHForCausalLM(nn.Module):
                 cfg.conv_dim, dtype=dtype))
 
 
-def init_params_and_specs(cfg: NemotronHConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = NemotronHForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: NemotronHConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree is 18 GB and fits no
-    chip beside its bf16 copy)."""
-    from deepspeed_tpu.models.common import materialize
-    model = NemotronHForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def nemotron_h_loss_fn(model: NemotronHForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, nemotron_h_loss_fn = \
+    hybrid.entry_points(NemotronHForCausalLM)

@@ -19,7 +19,7 @@ joins the residual stream (`sandwich_norm`). For a token with `u = N_in(x)`:
   `num_experts_per_tok` largest of ALL the scores at once (no groups, no
   selection bias), weights over their sum times `routed_scaling_factor`,
   beside one shared expert, unweighted (`moe/layer.MoE` as
-  `models/ling_linear._experts` builds it, `n_group` 1);
+  `hybrid.held_experts` builds it, `n_group` 1);
 
 then a final RMSNorm and an untied head. The multi-token-prediction block
 (`num_nextn_predict_layers`) is a drafter of its own and is not built.
@@ -30,7 +30,7 @@ keys. A DECODE step is the ABSORBED form over the row's whole live slab
 (`ops.attention.latent_decode` -> `ops/pallas/mla.mla_latent_decode`, its
 token staged and landed once after the layers). A PREFILL walks the batch a
 ROW and a CHUNK of queries at a time through all the layers
-(`models/keye_sparse.prefill_walk`): the chunk's rows are written into the
+(`hybrid.prefill_walk`): the chunk's rows are written into the
 row's slab first, then `ops.attention.latent_dense_prefill` attends, in the
 EXPANDED form, every block of the slab up to the chunk's own diagonal
 (`ops/pallas/mla_sparse.mla_dense_prefill`: no chunk x cache bias exists).
@@ -53,12 +53,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models import latent
-from deepspeed_tpu.models.keye_sparse import _embedded, prefill_walk
-from deepspeed_tpu.models.ling_linear import DenseFFN, _experts
+from deepspeed_tpu.models import hybrid, latent
 from deepspeed_tpu.models.llama import RMSNorm, _dense
 
 F32 = jnp.float32
+# Queries of one row that walk the layers together in a prefill
+# (`models/keye_sparse.py` has the readings its 2,048 was chosen by)
+PREFILL_CHUNK = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,7 +92,7 @@ class OpenPanguConfig:
     dtype: Any = jnp.bfloat16
     dispatch_impl: str = "auto"
 
-    # the family's ONE router, as `ling_linear._experts` reads it: the best of
+    # the family's ONE router, as `hybrid.held_experts` reads it: the best of
     # ALL the scores at once and no selection bias. Constants of the class,
     # not fields: no caller can ask for another form
     n_group = 1
@@ -192,9 +193,13 @@ class Layers(nn.Module):
             h = h + norm(f"layer_{i}_post_attn_norm")(out)
             x = norm(f"layer_{i}_mlp_norm")(h)
             if i < cfg.first_k_dense_replace:
-                out = DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
+                out = hybrid.DenseFFN(cfg, name=f"layer_{i}_mlp")(x)
             else:
-                out = _experts(cfg, f"layer_{i}_mlp")(x, train=False)
+                out = hybrid.held_experts(
+                    cfg, f"layer_{i}_mlp", held=cfg.num_experts,
+                    activation="silu", score_fn="sigmoid",
+                    shared=cfg.moe_shared_expert_intermediate_size)(
+                        x, train=False)
             h = h + norm(f"layer_{i}_post_mlp_norm")(out)
         if staged:      # the step's one write, every layer's token
             cache = cache.replace(latent=cache.latent.land(jnp.stack(staged)))
@@ -210,28 +215,9 @@ class OpenPanguForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
-        cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
-        if cache is not None and input_ids.shape[1] > 1:
-            cache, h = prefill_walk(Layers, cfg, cache, embed, input_ids)
-        else:
-            h, cache = Layers(cfg, name="layers")(
-                _embedded(cfg, embed, input_ids), cache)
-            if cache is not None:
-                cache = cache.advance(1)
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm_f")(h)
-        lm_head = self.param("lm_head", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.vocab_size), F32)
-        logits = h @ lm_head.astype(cfg.dtype)
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        return hybrid.causal_lm(self, Layers, input_ids, labels, cache,
+                                eps=self.cfg.rms_norm_eps,
+                                prefill_chunk=PREFILL_CHUNK)
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -239,31 +225,12 @@ class OpenPanguForCausalLM(nn.Module):
         `max_len` positions (`cfg.cache_slots` of them): every layer's latent
         rows; no K, V or index keys."""
         from deepspeed_tpu.inference.kv_cache import HybridCache, LatentCache
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("OpenPangu: an int8 cache is not implemented "
-                             "for a hybrid cache (kv_cache_dtype=None)")
         return HybridCache(kv=None, latent=LatentCache.create(
             cfg.num_hidden_layers, batch, cfg.cache_slots(max_len),
             cfg.latent_width, dtype=dtype or cfg.dtype))
 
 
-def init_params_and_specs(cfg: OpenPanguConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = OpenPanguForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: OpenPanguConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree is 19.7 GB and fits no
-    chip)."""
-    from deepspeed_tpu.models.common import materialize
-    model = OpenPanguForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def openpangu_loss_fn(model: OpenPanguForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, openpangu_loss_fn = \
+    hybrid.entry_points(OpenPanguForCausalLM)

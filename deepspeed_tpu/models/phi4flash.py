@@ -52,12 +52,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.llama import _dense
-from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 F32 = jnp.float32
 LAMBDA_STD = 0.1    # the four learned lambda vectors of a layer are N(0, .)
-# Tokens of a prefill that walk `front` and `mid` together (`_RowGroups`):
+# Tokens of a prefill that walk `front` and `mid` together
+# (`hybrid.row_groups`, which embeds a group's tokens inside its body):
 # at 8 x 2048 the program's temporaries are 4.6 GB beside 7.7 GB of weights
 # and 2.5 GB of cache (the widest, the FFN's 2 x 10240 and a window block's
 # logits, 0.67 GB each), and a step of the Mamba scan, which is mostly the
@@ -232,17 +233,6 @@ def selective_scan(x, dt, a, b, c, h0):
     return y, h
 
 
-def _dt_bias_init(cfg: Phi4FlashConfig):
-    def init(key, shape, dtype=F32):
-        # dt log-uniform in [time_step_min, time_step_max], stored as its
-        # inverse softplus (Mamba's own initialisation)
-        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-        dt = jnp.exp(jax.random.uniform(key, shape, F32) * (hi - lo) + lo)
-        dt = jnp.maximum(dt, cfg.time_step_floor)
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    return init
-
-
 def _a_log_init(key, shape, dtype=F32):
     """`A = -(1 .. N)` for every channel (S4D-real), as `log`: (N, C)."""
     del key
@@ -296,7 +286,7 @@ class Mamba1Mixer(nn.Module):
             di, dtype=cfg.dtype, param_dtype=F32, name="dt_proj",
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.normal(rank ** -0.5), (None, "mlp")),
-            bias_init=_dt_bias_init(cfg))(r).astype(F32))   # (S, B, C)
+            bias_init=hybrid.dt_bias_init(cfg))(r).astype(F32))   # (S, B, C)
 
         if ssm is not None and s == 1:
             from deepspeed_tpu.ops.pallas.ssm import ssm_state_update_m1
@@ -560,30 +550,6 @@ class _FrontMid(nn.Module):
             state=cache.state.replace(ssm=state[0], conv=state[1]))
 
 
-def _embedded(cfg: Phi4FlashConfig, embed, ids):
-    h = jnp.take(embed.astype(cfg.dtype), ids, axis=0)
-    return shard_along(h, BATCH_AXES, "sequence", None)
-
-
-class _RowGroups(nn.Module):
-    """`_FrontMid` for `rows` sequences of the batch at a time, the whole
-    cache carried: the body of the scan a large prefill runs over its rows.
-    It shares `_FrontMid`'s scope, so the parameters are the same tree. The
-    group's tokens are embedded here (the whole batch's embedded prompt is
-    0.67 GB at 64 x 2048), and only each sequence's last position goes on."""
-    cfg: Phi4FlashConfig
-    rows: int
-
-    @nn.compact
-    def __call__(self, cache, embed, group):
-        ids, start = group
-        walk = _FrontMid(self.cfg)
-        nn.share_scope(self, walk)
-        h, m, _, part = walk(_embedded(self.cfg, embed, ids),
-                             cache.rows(start, self.rows))
-        return cache.with_rows(part, start), (h[:, -1:], m[:, -1:])
-
-
 class Phi4FlashForCausalLM(nn.Module):
     cfg: Phi4FlashConfig
     # counted inside a serving program and summed over the call by the engine
@@ -594,24 +560,19 @@ class Phi4FlashForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):
         cfg = self.cfg
-        embed = self.param("embed_tokens", nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), F32)
+        embed = hybrid.embed_tokens(self)
         b, s = input_ids.shape
-        rows = max((r for r in range(1, b + 1)
-                    if b % r == 0 and r * s <= PREFILL_TOKENS), default=1)
+        rows = hybrid.rows_a_group(b, s, PREFILL_TOKENS)
         fresh = lengths = None
         if cache is not None and s > 1 and rows < b:
-            walk = nn.scan(_RowGroups, variable_broadcast="params",
-                           split_rngs={"params": False},
-                           in_axes=(nn.broadcast, 0), out_axes=0)
-            cache, (h, m) = walk(cfg, rows, name="decoder")(
-                cache, embed, (input_ids.reshape(b // rows, rows, s),
-                               jnp.arange(0, b, rows, dtype=jnp.int32)))
-            h, m = (t.reshape(b, 1, -1) for t in (h, m))
+            # `_FrontMid` a few rows at a time: both streams' last positions
+            # go on (its third result, a plain pass's fresh K and V, is None
+            # under a cache)
+            cache, (h, m) = hybrid.row_groups(
+                _FrontMid, cfg, cache, embed, input_ids, rows, name="decoder")
         else:
             h, m, fresh, cache = _FrontMid(cfg, name="decoder")(
-                _embedded(cfg, embed, input_ids), cache)
+                hybrid.embedded(cfg, embed, input_ids), cache)
             if cache is not None:
                 h, m = h[:, -1:], m[:, -1:]
         if cache is not None:
@@ -629,13 +590,8 @@ class Phi4FlashForCausalLM(nn.Module):
         h, _ = back(h, (m, fresh, lengths),
                     jnp.arange(cfg.back_pairs, dtype=jnp.int32))
         h = LayerNorm(cfg.layer_norm_eps, cfg.dtype, name="final_layernorm")(h)
-        logits = h @ embed.astype(cfg.dtype).T
-        if cache is not None:
-            return logits, cache
-        if labels is None:
-            return logits
-        from deepspeed_tpu.models.common import causal_lm_loss
-        return causal_lm_loss(logits, input_ids, labels)
+        return hybrid.lm_output(h @ embed.astype(cfg.dtype).T, input_ids,
+                                labels, cache)
 
     def make_cache(self, batch: int, max_len: int, dtype: Any = None,
                    quantized: bool = False):
@@ -644,10 +600,8 @@ class Phi4FlashForCausalLM(nn.Module):
         full-length slab, the Mamba-1 states and convolution tails."""
         from deepspeed_tpu.inference.kv_cache import (HybridCache, KVCache,
                                                       RecurrentState)
+        hybrid.refuse_int8(self, quantized)
         cfg = self.cfg
-        if quantized:
-            raise ValueError("Phi4Flash: an int8 KV cache is not implemented "
-                             "for a hybrid cache (kv_cache_dtype=None)")
         dtype = dtype or cfg.dtype
         return HybridCache(
             kv=KVCache.create_stacked(1, batch, max_len, cfg.pair_groups,
@@ -660,22 +614,5 @@ class Phi4FlashForCausalLM(nn.Module):
                 cfg.mamba_d_conv, cfg.d_inner, dtype=dtype))
 
 
-def init_params_and_specs(cfg: Phi4FlashConfig, rng=None, seq_len: int = 8):
-    from deepspeed_tpu.models.common import abstract_specs
-    model = Phi4FlashForCausalLM(cfg)
-    return model, abstract_specs(model, rng, seq_len)
-
-
-def materialize_params(cfg: Phi4FlashConfig, rng=None, seq_len: int = 8,
-                       param_dtype=None):
-    """(model, the whole tree on the device from the seed), ONE jitted call;
-    `param_dtype` casts inside it (the float32 tree is 15.4 GB and fits no
-    chip beside its bf16 copy)."""
-    from deepspeed_tpu.models.common import materialize
-    model = Phi4FlashForCausalLM(cfg)
-    return model, materialize(model, rng, seq_len, param_dtype)
-
-
-def phi4flash_loss_fn(model: Phi4FlashForCausalLM):
-    from deepspeed_tpu.models.common import make_causal_loss_fn
-    return make_causal_loss_fn(model)
+init_params_and_specs, materialize_params, phi4flash_loss_fn = \
+    hybrid.entry_points(Phi4FlashForCausalLM)

@@ -729,8 +729,8 @@ def sparse_prefill(q, q_index, w, k_cache, v_cache, index_keys, row, start,
     `topk` positions up to its own, and attention over them. Returns (C, H,
     D) and (C,) int32, the slots each query kept. The kernels where the
     chip's tiling takes the shapes (whole lane tiles of slots and of
-    queries: `models/keye_sparse.py` cuts every prompt of 128 tokens or more
-    into such chunks), else the plain form."""
+    queries: `models/hybrid.prefill_chunks` cuts every prompt of 128 tokens
+    or more into such chunks), else the plain form."""
     from deepspeed_tpu.ops.pallas import sparse_select as ss
     aligned = k_cache.stack.shape[3] % 128 == 0 and q.shape[0] % 128 == 0 \
         and q.shape[-1] % 128 == 0

@@ -117,7 +117,7 @@ def _per_layer_view_modules():
     equal."""
     import flax.linen as nn
     from deepspeed_tpu.inference.kv_cache import decode_mask, update_layer
-    from deepspeed_tpu.models import nemotron_h
+    from deepspeed_tpu.models import hybrid, nemotron_h
     from deepspeed_tpu.ops.attention import cached_attention
 
     class PerLayerAttention(nemotron_h.Attention):
@@ -163,7 +163,11 @@ def _per_layer_view_modules():
                     out, kv = PerLayerAttention(cfg, name=f"layer_{i}")(
                         x, kv, slot)
                 else:
-                    out = nemotron_h._experts(cfg, f"layer_{i}")(x, train=False)
+                    out = hybrid.held_experts(
+                        cfg, f"layer_{i}", held=cfg.n_routed_experts,
+                        activation="relu2", score_fn="sigmoid",
+                        shared=cfg.moe_shared_expert_intermediate_size)(
+                            x, train=False)
                 h = h + out
             return h, cache.replace(state=state, kv=kv)
 

@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference import kv_cache
-from deepspeed_tpu.models import afmoe, ling_linear, phi4flash
+from deepspeed_tpu.models import afmoe, hybrid, ling_linear, phi4flash
 from deepspeed_tpu.models.afmoe import FULL, SLIDING, AfmoeConfig
 from perfbench.manifest import Manifest
 from tests.unit.models import hybrid_families
@@ -111,13 +111,18 @@ def test_the_counts_are_the_tree_s(served):
 
 
 def test_what_the_family_shares_exists_once():
-    """The held-experts layer and the dense FFN are `ling_linear`'s, the
-    four-norm layer's norm `llama.RMSNorm`, and a prefill's write into a
+    """The held-experts layer, the dense FFN and the shell are
+    `models/hybrid.py`'s (the family reaches them through the module, and
+    imports no sibling), the four-norm layer's norm `llama.RMSNorm` under the
+    family's own name (a tool replaces it there), and a prefill's write into a
     ring is `kv_cache.write_prefill_rows`, which Phi-4-mini-flash's stacks
     go through too: nothing is copied."""
     from deepspeed_tpu.models.llama import RMSNorm
-    assert afmoe._experts is ling_linear._experts
-    assert afmoe.DenseFFN is ling_linear.DenseFFN and afmoe.RMSNorm is RMSNorm
+    assert afmoe.hybrid is hybrid and ling_linear.hybrid is hybrid
+    for module in (afmoe, ling_linear):
+        assert not {"_experts", "DenseFFN", "_RowGroups", "_embedded"} \
+            & set(vars(module))
+    assert afmoe.RMSNorm is RMSNorm
     assert not hasattr(phi4flash, "_write_prefill")
     assert not hasattr(afmoe, "write_prefill_rows")
     # positions 0 .. 10 into a ring of 4: the last four, p in slot p mod 4

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import keye_sparse
+from deepspeed_tpu.models import deepseek_sparse
 from deepspeed_tpu.models.deepseek_sparse import DeepseekSparseConfig
 from perfbench.manifest import Manifest
 from tests.unit.models import hybrid_families
@@ -110,7 +110,7 @@ def test_a_prefill_in_chunks_chooses_over_the_row(served, monkeypatch, prompt,
     none before), each against the row's slabs as the chunks before it left
     them; the counters summed over chunks."""
     model, params, ids, want = served
-    monkeypatch.setattr(keye_sparse, "PREFILL_CHUNK", 8)
+    monkeypatch.setattr(deepseek_sparse, "PREFILL_CHUNK", 8)
     (logits, cache), counted = compile_apply(mutable=["counters"])(
         model, params, ids[:, :prompt],
         model.make_cache(3, 64, dtype=jnp.float32))

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import keye_sparse
+from deepspeed_tpu.models import hybrid, keye_sparse
 from deepspeed_tpu.models.keye_sparse import KeyeSparseConfig
 from deepspeed_tpu.ops.pallas import sparse_select as ss
 from tests.unit.models import hybrid_families
@@ -67,7 +67,7 @@ def test_every_length_is_walked_in_whole_tiles(s):
     """A prime length over the chunk, the reviewer's 2,053, is two chunks of
     2,048, the second drawn back over 2,043 positions; nothing is a chunk of
     one query (that shape is a decode step's)."""
-    size, starts = keye_sparse.prefill_chunks(s)
+    size, starts = hybrid.prefill_chunks(s, keye_sparse.PREFILL_CHUNK)
     assert size == s if s < 128 else (size % 128 == 0 and size <= 2048)
     assert starts[0] == 0 and starts[-1] + size == s
     assert all(0 < b - a <= size for a, b in zip(starts, starts[1:]))

@@ -26,8 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import (deepseek_sparse, keye_sparse, latent,
-                                  ling_linear, openpangu)
+from deepspeed_tpu.models import (deepseek_sparse, hybrid, keye_sparse,
+                                  latent, openpangu)
 from deepspeed_tpu.models.openpangu import OpenPanguConfig
 from perfbench.manifest import Manifest
 from tests.unit.models import hybrid_families
@@ -98,13 +98,15 @@ def test_the_counts_are_the_tree_s(served):
 def test_what_the_two_compressed_query_families_share_exists_once():
     """Query compression, the latent's norm and rope key, absorption, the
     chunk's write and the cache's slots are `models/latent.py`'s; the walk
-    `keye_sparse.prefill_walk`; the held-experts layer
-    `ling_linear._experts`: both families IMPORT them."""
+    (`prefill_walk`), the shell and the held-experts layer
+    `models/hybrid.py`'s: both families reach them through the two modules
+    and import no sibling. The chunk a prefill is cut by is each family's
+    OWN constant, at the value all three learned or dense walks share."""
     for module in (deepseek_sparse, openpangu):
-        assert module.latent is latent
-        assert module.prefill_walk is keye_sparse.prefill_walk
-        assert module._experts is ling_linear._experts
-        assert not hasattr(module, "_write_chunk")
+        assert module.latent is latent and module.hybrid is hybrid
+        assert not {"prefill_walk", "_experts", "DenseFFN", "_embedded",
+                    "_write_chunk"} & set(vars(module))
+        assert module.PREFILL_CHUNK == keye_sparse.PREFILL_CHUNK == 2048
     assert OpenPanguConfig.cache_slots is latent.cache_slots
     assert deepseek_sparse.DeepseekSparseConfig.cache_slots \
         is latent.cache_slots
@@ -136,7 +138,7 @@ def test_a_prefill_in_chunks_attends_the_row(served, monkeypatch, prompt):
     the chunks before it left it (a chunk that attended itself alone would
     miss every earlier position); then a decode step over what they wrote."""
     model, params, ids, want = served
-    monkeypatch.setattr(keye_sparse, "PREFILL_CHUNK", 8)
+    monkeypatch.setattr(openpangu, "PREFILL_CHUNK", 8)
     (logits, cache), counted = compile_apply(mutable=["counters"])(
         model, params, ids[:, :prompt],
         model.make_cache(3, 64, dtype=jnp.float32))

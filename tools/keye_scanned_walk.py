@@ -40,6 +40,7 @@ def scanned_layers():
     under `blocks`, the cache carried and the layer named by index."""
     import flax.linen as nn
     import jax.numpy as jnp
+    from deepspeed_tpu.models import hybrid
     from deepspeed_tpu.models import keye_sparse as ks
     from deepspeed_tpu.models.llama import RMSNorm
 
@@ -57,7 +58,9 @@ def scanned_layers():
             if made is not None and staged is None:
                 cache = made
             h = h + out
-            h = h + ks._experts(cfg, "mlp")(norm("mlp_norm")(h), train=False)
+            h = h + hybrid.held_experts(
+                cfg, "mlp", held=cfg.num_experts, activation="silu",
+                score_fn="softmax")(norm("mlp_norm")(h), train=False)
             return (h, cache), staged
 
     class Layers(nn.Module):
