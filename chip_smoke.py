@@ -244,6 +244,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     from deepspeed_tpu.ops.pallas.decode_attention import (decode_attention,
                                                            decode_plan,
                                                            kv_write_dense)
+    from deepspeed_tpu.ops.pallas import flash_attention as flash
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
     from deepspeed_tpu.ops.pallas.held_combine import held_combine
@@ -351,6 +352,32 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         f"flash_band_s{fb_s}_w{fb_w}",
         lambda q, k, v: flash_attention(q, k, v, causal=True, window=fb_w),
         band_ref, qkv(fb_rows, fb_s, fb_h, fb_hkv, d)))
+
+    # ---- the forward in the projections' own order, (B, S, H, D) read as
+    # (B, S, H x D), which an undifferentiated call at this head width
+    # takes: EQUAL to the head-major kernel (the same bodies under other
+    # block specs), band and triangular, at that prefill's shapes ----
+    def head_major(window):
+        blocks = (flash.BAND_BLOCK,) * 2 if window else (
+            flash.DEFAULT_BLOCK_Q, flash.DEFAULT_BLOCK_K)
+
+        def ref(q, k, v):
+            return flash._swap(flash._flash_bhsd_fwd(
+                q, k, v, d ** -0.5, True, *blocks, window)[0])
+        return ref
+
+    # LAST in the list: a case's inputs are drawn from seed + its index
+    order_cases = [
+        KernelCase(f"flash_fwd_tok_s{fb_s}",
+                   lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   head_major(None), qkv(fb_rows, fb_s, fb_h, fb_hkv, d),
+                   tol=0.0),
+        KernelCase(f"flash_band_tok_s{fb_s}_w{fb_w}",
+                   lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                   window=fb_w),
+                   head_major(fb_w), qkv(fb_rows, fb_s, fb_h, fb_hkv, d),
+                   tol=0.0),
+    ]
 
     # ---- dense decode (v1): one query per row over a padded cache ----
     db, dm = sz.decode_batch, sz.decode_ctx
@@ -1077,7 +1104,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         lambda q, k, v: block_sparse_attention(q, k, v, idx, nlive, sblk,
                                                causal=True),
         sparse_ref, make_sparse))
-    return cases + slow_cases
+    return cases + slow_cases + order_cases
 
 
 def scopes_ms(case: KernelCase, inputs, reps: int = 10) -> Optional[float]:
@@ -1141,7 +1168,9 @@ def dispatch_calls():
     announcement — the answer to "did the engine select the kernel or
     quietly take the XLA path". The callers look these names up at call
     time, so wrapping the module attributes observes every selection
-    without touching the package."""
+    without touching the package. Beside them, from the telemetry hub's
+    own counters, the order each traced flash FORWARD took its operands in
+    (`flash_fwd/token_major`, `flash_fwd/head_major`)."""
     import deepspeed_tpu.ops.attention as disp
     import deepspeed_tpu.ops.pallas.decode_attention as dense
     import deepspeed_tpu.ops.pallas.flash_attention as flash
@@ -1164,6 +1193,8 @@ def dispatch_calls():
             return fn(*a, **kw)
         return wrapper
 
+    from deepspeed_tpu.telemetry import get_hub
+    before = dict(get_hub().counters)
     for mod, name, fn in saved:
         setattr(mod, name, counting(name, fn))
     try:
@@ -1171,6 +1202,9 @@ def dispatch_calls():
     finally:
         for mod, name, fn in saved:
             setattr(mod, name, fn)
+        for name, n in get_hub().counters.items():
+            if name.startswith("flash_fwd/") and n > before.get(name, 0):
+                counts[name] = int(n - before.get(name, 0))
 
 
 # ------------------------------------------------------------------- train

@@ -6,9 +6,21 @@ the flash-attn kernels linked by `inference/v2/kernels/ragged_ops/
 blocked_flash`, and the training softmax in `csrc/transformer/softmax_kernels.cu`.
 
 Design (standard flash attention 2 tiling, MXU-sized blocks):
-- layout (B, H, S, D); grid (B, H, Sq/blk_q, Sk/blk_k) with the KV block as
-  the fastest (sequential) grid axis, online-softmax state (m, l, acc) in VMEM
-  scratch carried across KV iterations;
+- grid (B, H, Sq/blk_q, Sk/blk_k) with the KV block as the fastest
+  (sequential) grid axis, online-softmax state (m, l, acc) in VMEM scratch
+  carried across KV iterations; a step's blocks are (blk, D) of one head;
+- the ORDER the operands lie in. Callers hand over (B, S, H, D), as the
+  projections leave them. The FORWARD of a call that is not differentiated (a
+  prefill's) reads them so, as (B, S, H x D) views in which a head is a
+  column block of D lanes, and writes its result the same way, wherever D is
+  a multiple of 128 (a block is then whole lane tiles, H tiles apart: a
+  strided DMA); no transpose stands on either side of the kernel. A narrower
+  head (64: half a lane tile), and every DIFFERENTIATED call (forward rule
+  and backward), run head-major, (B, H, S, D), between transposes. The head
+  width and the differentiation decide (`_flash_bshd`); no option does. The
+  bodies are the same under both orders' block specs (leading dims squeezed),
+  so the values are too, bit for bit. `_fwd` counts its calls by order on
+  the telemetry hub (`flash_fwd/token_major`, `flash_fwd/head_major`);
 - GQA handled in the kernel's BlockSpec index maps (KV head = q_head // n_rep)
   — no materialized `repeat_kv`;
 - causal blocks are predicated out with `pl.when` (upper-triangular block
@@ -137,7 +149,7 @@ def _fwd_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, mask_ij=None,
     runs in the base-2 domain. `mask_ij` = (qi_base, ki_base) applies the
     causal mask (and the `window`'s) — only edge blocks pay for
     iota+compare+select."""
-    s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+    s = jax.lax.dot_general(q_ref[...], k_ref[...],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = _apply_causal_mask(s, mask_ij, window)
@@ -152,7 +164,7 @@ def _fwd_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, mask_ij=None,
     alpha = jnp.exp2(m_prev - m_new)
     l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+        p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_scr[:, :1] = m_new
 
@@ -160,10 +172,10 @@ def _fwd_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, mask_ij=None,
 def _fwd_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
     l = l_scr[:, :1]
     safe_l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+    o_ref[...] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
     # base-2 lse residual: lse2 = m2 + log2(l); the bwd kernels consume it
     # with exp2 directly
-    lse_ref[0, 0] = m_scr[:, :1] + jnp.log2(safe_l)
+    lse_ref[...] = m_scr[:, :1] + jnp.log2(safe_l)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -482,20 +494,29 @@ def _use_tri(causal, sq, sk, blk_q, blk_k):
     return causal and sq == sk and blk_q == blk_k
 
 
-def _fwd(qs, k, v, causal, blk_q, blk_k, window=None):
-    """qs is the pre-scaled query (log2(e)·softmax_scale folded in)."""
-    b, h, sq, d = qs.shape
-    hkv, sk = k.shape[1], k.shape[2]
+def _fwd(qs, k, v, causal, blk_q, blk_k, window=None, token_major=False):
+    """qs is the pre-scaled query (log2(e)·softmax_scale folded in).
+    Head-major: operands and result (B, H, S, D). `token_major`: (B, S, H, D)
+    ones, the projections' own order, read through their (B, S, H x D) views,
+    where a head is a column block of D lanes (D a multiple of 128: whole
+    lane tiles, a strided DMA) and the result is written the same way. The
+    lse is (B, H, Sq, 1) in both. The order changes the block specs and
+    nothing else: one grid, one body, one arithmetic a form."""
+    if token_major:
+        b, sq, h, d = qs.shape
+        sk, hkv = k.shape[1], k.shape[2]
+        assert d % 128 == 0, d
+        qs, k, v = (t.reshape(*t.shape[:2], -1) for t in (qs, k, v))
+    else:
+        b, h, sq, d = qs.shape
+        hkv, sk = k.shape[1], k.shape[2]
+    _count_forward(token_major)
     n_rep = h // hkv
     blk_q, blk_k = _pick_blocks(sq, sk, blk_q, blk_k)
     assert sq % blk_q == 0 and sk % blk_k == 0, (sq, sk, blk_q, blk_k)
     nq, nk = sq // blk_q, sk // blk_k
     offset = sk - sq
-    out_shape = [jax.ShapeDtypeStruct((b, h, sq, d), qs.dtype),
-                 jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)]
-    scratch = [pltpu.VMEM((blk_q, 128), jnp.float32),
-               pltpu.VMEM((blk_q, 128), jnp.float32),
-               pltpu.VMEM((blk_q, d), jnp.float32)]
+    name = FWD_NAME
 
     if window is not None:
         assert causal and sq == sk, "a window bands whole causal sequences"
@@ -503,88 +524,87 @@ def _fwd(qs, k, v, causal, blk_q, blk_k, window=None):
         nb = max((i * blk_q + blk_q - 1) // blk_k
                  - max(i * blk_q - window + 1, 0) // blk_k + 1
                  for i in range(nq))
+        kernel = functools.partial(_fwd_kernel_band, blk_q=blk_q,
+                                   blk_k=blk_k, nb=nb, window=window)
+        grid, name = (b, h, nq, nb), BAND_NAME
 
-        def q_ix(b_, h_, i, j):
-            return (b_, h_, i, 0)
+        def q_at(b_, h_, i, j):
+            return b_, h_, i
 
-        def kv_ix(b_, h_, i, j):
+        def kv_at(b_, h_, i, j):
             first, last = _band_blocks(i, blk_q, blk_k, window)
-            return (b_, h_ // n_rep, jnp.minimum(first + j, last), 0)
-        return pl.pallas_call(
-            functools.partial(_fwd_kernel_band, blk_q=blk_q, blk_k=blk_k,
-                              nb=nb, window=window),
-            grid=(b, h, nq, nb),
-            in_specs=[pl.BlockSpec((1, 1, blk_q, d), q_ix),
-                      pl.BlockSpec((1, 1, blk_k, d), kv_ix),
-                      pl.BlockSpec((1, 1, blk_k, d), kv_ix)],
-            out_specs=[pl.BlockSpec((1, 1, blk_q, d), q_ix),
-                       pl.BlockSpec((1, 1, blk_q, 1), q_ix)],
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
-            interpret=_interpret(),
-            name=BAND_NAME,
-        )(qs, k, v)
+            return b_, h_ // n_rep, jnp.minimum(first + j, last)
+    elif _use_tri(causal, sq, sk, blk_q, blk_k):
+        kernel = functools.partial(_fwd_kernel_tri, blk=blk_q, n=nq)
+        grid = (b, h, nq * (nq + 1) // 2)
 
-    if _use_tri(causal, sq, sk, blk_q, blk_k):
-        n = nq
-        q_spec = pl.BlockSpec(
-            (1, 1, blk_q, d),
-            lambda b_, h_, t: (b_, h_, _tri_row(t, n)[0], 0))
-        kv_spec = pl.BlockSpec(
-            (1, 1, blk_k, d),
-            lambda b_, h_, t: (b_, h_ // n_rep, _tri_row(t, n)[1], 0))
-        o_spec = pl.BlockSpec(
-            (1, 1, blk_q, d),
-            lambda b_, h_, t: (b_, h_, _tri_row(t, n)[0], 0))
-        lse_spec = pl.BlockSpec(
-            (1, 1, blk_q, 1),
-            lambda b_, h_, t: (b_, h_, _tri_row(t, n)[0], 0))
-        out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_tri, blk=blk_q, n=n),
-            grid=(b, h, n * (n + 1) // 2),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[o_spec, lse_spec],
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
-            name=FWD_NAME,
-        )(qs, k, v)
-        return out, lse
+        def q_at(b_, h_, t):
+            return b_, h_, _tri_row(t, nq)[0]
 
-    q_spec = pl.BlockSpec((1, 1, blk_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    if causal:
-        # clamp dead kv blocks to the diagonal one: the repeated index makes
-        # Pallas elide their HBM copies — without it every q row fetches the
-        # full KV length and HALF the DMA traffic is causally dead
-        def kv_ix(b_, h_, i, j):
-            hi = (i * blk_q + blk_q - 1 + offset) // blk_k
-            return (b_, h_ // n_rep, jnp.minimum(j, hi), 0)
+        def kv_at(b_, h_, t):
+            return b_, h_ // n_rep, _tri_row(t, nq)[1]
     else:
-        def kv_ix(b_, h_, i, j):
-            return (b_, h_ // n_rep, j, 0)
-    kv_spec = pl.BlockSpec((1, 1, blk_k, d), kv_ix)
-    o_spec = pl.BlockSpec((1, 1, blk_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    lse_spec = pl.BlockSpec((1, 1, blk_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
+        kernel = functools.partial(_fwd_kernel, causal=causal, blk_q=blk_q,
+                                   blk_k=blk_k, nk=nk, offset=offset)
+        grid = (b, h, nq, nk)
+
+        def q_at(b_, h_, i, j):
+            return b_, h_, i
+
+        def kv_at(b_, h_, i, j):
+            if causal:
+                # clamp dead kv blocks to the diagonal one: the repeated
+                # index makes Pallas elide their HBM copies — without it
+                # every q row fetches the full KV length and HALF the DMA
+                # traffic is causally dead
+                hi = (i * blk_q + blk_q - 1 + offset) // blk_k
+                j = jnp.minimum(j, hi)
+            return b_, h_ // n_rep, j
+
+    def rows_spec(rows, at):
+        """`rows` rows of one head, `at` the grid's (batch, head, row
+        block): the leading dims squeezed, so a body sees (rows, d)
+        whichever order the operand lies in."""
+        if token_major:
+            def ix(*g):
+                b_, h_, r = at(*g)
+                return b_, r, h_
+            return pl.BlockSpec((None, rows, d), ix)
+        return pl.BlockSpec((None, None, rows, d), lambda *g: (*at(*g), 0))
 
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k, nk=nk, offset=offset),
-        grid=(b, h, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[o_spec, lse_spec],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
+        kernel,
+        grid=grid,
+        in_specs=[rows_spec(blk_q, q_at), rows_spec(blk_k, kv_at),
+                  rows_spec(blk_k, kv_at)],
+        out_specs=[rows_spec(blk_q, q_at),
+                   pl.BlockSpec((None, None, blk_q, 1),
+                                lambda *g: (*q_at(*g), 0))],
+        out_shape=[jax.ShapeDtypeStruct(qs.shape, qs.dtype),
+                   jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((blk_q, 128), jnp.float32),
+                        pltpu.VMEM((blk_q, 128), jnp.float32),
+                        pltpu.VMEM((blk_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",)),
         interpret=_interpret(),
-        name=FWD_NAME,
+        name=name,
     )(qs, k, v)
-    return out, lse
+    return (out.reshape(b, sq, h, d) if token_major else out), lse
+
+
+def _count_forward(token_major):
+    """Count, at trace time, the forward calls by the order their operands
+    lay in (`flash_fwd/token_major`, `flash_fwd/head_major` on the telemetry
+    hub), so a run can say which form its prefill or its step took. A count
+    of TRACES: a body that `scan` or `jax.checkpoint` traces before it is
+    differentiated traces the primal once and never lowers it, so a training
+    step at a lane-wide head reads `token_major` 1 beside the `head_major`
+    calls it runs."""
+    from deepspeed_tpu.telemetry import get_hub
+    get_hub().counter(
+        "flash_fwd/" + ("token_major" if token_major else "head_major"))
 
 
 def _announce_two_pass(sq, d):
@@ -736,12 +756,35 @@ def _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k):
     return dq, dk, dv
 
 
+def _swap(t):
+    """(B, S, H, D) <-> (B, H, S, D)."""
+    return jnp.swapaxes(t, 1, 2)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, scale, causal, blk_q, blk_k, window=None):
+def _flash_bshd(q, k, v, scale, causal, blk_q, blk_k, window=None):
+    """Flash attention of the token-major arrays, q (B, Sq, H, D), k and v
+    (B, Sk, Hkv, D). This PRIMAL is the call that is not differentiated (a
+    prefill): where a head is whole lane tiles the kernel takes the arrays
+    as they lie, and no transpose stands on either side of it; a narrower
+    head goes head-major. A differentiated call, and its rematerialised
+    forward, run `_flash_fwd_rule`, which is head-major whatever the
+    width."""
+    if q.shape[-1] % 128:
+        return _swap(_flash_bhsd_fwd(q, k, v, scale, causal, blk_q, blk_k,
+                                     window)[0])
     # fold softmax scale AND the base-2 conversion into q once
     qs = (q * (scale * LOG2E)).astype(q.dtype)
-    out, _ = _fwd(qs, k, v, causal, blk_q, blk_k, window)
-    return out
+    return _fwd(qs, k, v, causal, blk_q, blk_k, window, token_major=True)[0]
+
+
+def _flash_bhsd_fwd(q, k, v, scale, causal, blk_q, blk_k, window=None):
+    """The head-major forward of token-major arrays: (out, lse, qs, k, v),
+    all (B, H, S, D) but the lse."""
+    q, k, v = _swap(q), _swap(k), _swap(v)
+    qs = (q * (scale * LOG2E)).astype(q.dtype)
+    out, lse = _fwd(qs, k, v, causal, blk_q, blk_k, window)
+    return out, lse, qs, k, v
 
 
 def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k, window):
@@ -752,8 +795,7 @@ def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k, window):
             "exist (the forward serves a prefill's window layers; train a "
             "window family through ops.attention.attention, whose window "
             "runs XLA's masked paths)")
-    qs = (q * (scale * LOG2E)).astype(q.dtype)
-    out, lse = _fwd(qs, k, v, causal, blk_q, blk_k)
+    out, lse, qs, k, v = _flash_bhsd_fwd(q, k, v, scale, causal, blk_q, blk_k)
     # name the two residuals only the backward needs (one kernel; two past
     # ONE_PASS_DQ_BYTES of resident dq: module docstring), so remat
     # policies can save/offload them instead of re-running the fwd kernel
@@ -767,16 +809,17 @@ def _flash_fwd_rule(q, k, v, scale, causal, blk_q, blk_k, window):
     # block input)
     out = checkpoint_name(out, "flash_resid")
     lse = checkpoint_name(lse, "flash_lse")
-    return out, (qs, k, v, out, lse)
+    return _swap(out), (qs, k, v, out, lse)
 
 
 def _flash_bwd_rule(scale, causal, blk_q, blk_k, window, res, do):
     del window  # None here: the forward rule refuses a window by name
     qs, k, v, o, lse = res  # qs pre-scaled; _bwd rescales dq at finalize
-    return _bwd(qs, k, v, o, lse, do, scale, causal, blk_q, blk_k)
+    return tuple(_swap(g) for g in _bwd(qs, k, v, o, lse, _swap(do), scale,
+                                        causal, blk_q, blk_k))
 
 
-_flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_bshd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention(q, k, v, causal: bool = True,
@@ -785,6 +828,13 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: Optional[int] = None,
                     window: Optional[int] = None) -> jnp.ndarray:
     """Flash attention. q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D) → (B, Sq, H, D).
+
+    The operands stay in that order, the projections' own, where the
+    forward kernel can read them so: a head width that is whole lane tiles
+    (D a multiple of 128) in a call that is not differentiated. A narrower
+    head, and every differentiated call with its backward, run head-major
+    between transposes (`_flash_bshd`). The shape and the differentiation
+    decide; nothing else does, and the values are the same bit for bit.
 
     Block sizes: explicit args > DS_TPU_FLASH_BLOCK_Q/K env (bench sweeps) >
     defaults (`BAND_BLOCK` under a window).
@@ -802,8 +852,4 @@ def flash_attention(q, k, v, causal: bool = True,
         block_k = int(os.environ.get("DS_TPU_FLASH_BLOCK_K", DEFAULT_BLOCK_K))
     d = q.shape[-1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash_bhsd(qt, kt, vt, scale, causal, block_q, block_k, window)
-    return jnp.swapaxes(out, 1, 2)
+    return _flash_bshd(q, k, v, scale, causal, block_q, block_k, window)

@@ -8,12 +8,14 @@ The cases are `chip_smoke.kernel_cases`: what this file compiles is what
 `chip_smoke.py` runs on the chip against the `jax.numpy` references.
 """
 
+import math
 import os
 import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -119,3 +121,47 @@ def test_kernel_compiles_for_v5e(case, v5e_chip, compiled_kernels):
         # `ssm_update_ms.gen` and `moe_gmm_ms.gen` search for these
         calls = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
         assert any(re.match(rf"{other}(\.\d+)*$", c) for c in calls), calls
+
+
+@pytest.mark.parametrize("sliding", [True, False], ids=["window", "full"])
+def test_no_transpose_stands_round_a_prefill_attention(
+        sliding, v5e_chip, compiled_kernels, monkeypatch):
+    """One Trinity attention layer as generate-agent-8k's prefill calls it
+    (`afmoe.GatedAttention`: two rows of 8,192, 32 heads on 4 of 128; four
+    projections, float32 head norms, rotary in a window layer, the flash
+    kernel, the float32 gate, `o_proj`), compiled for the described chip.
+    The kernel takes (B, S, H x D) operands, so its result feeds the gate's
+    fusion as it lies: no copy of q's size stands after it, and before a
+    window layer's none in float32 (PR 63; until then two float32 copies
+    of 268 MB a call, the result's and q's). What is left before it, XLA's
+    re-layout of q after the rotary (window, bf16) or at the head norm
+    (full, float32), is another mechanism's to take."""
+    from deepspeed_tpu.models.afmoe import AfmoeConfig, GatedAttention
+    from deepspeed_tpu.ops import attention as dispatch
+    monkeypatch.setattr(dispatch, "_use_pallas", lambda: True)
+    cfg = AfmoeConfig()
+    rows, length = 2, 8192
+    layer = GatedAttention(cfg, sliding)
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=v5e_chip)
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 128, cfg.hidden_size), cfg.dtype))))
+    x = jax.ShapeDtypeStruct((rows, length, cfg.hidden_size), cfg.dtype,
+                             sharding=v5e_chip)
+    text = jax.jit(lambda p, x: layer.apply(p, x)[0]).lower(
+        params, x).compile().as_text()
+    entry = text[text.index("\nENTRY "):]  # in the schedule's order
+    kernel = re.search(r"\n[^\n]*custom_call_target=\"tpu_custom_call\"",
+                       entry)
+    assert kernel and ("flash_fwd_band" in kernel.group(0)) == sliding
+    q_size = rows * length * cfg.num_attention_heads * cfg.head_dim
+
+    def q_sized_copies(hlo):
+        found = re.findall(r"= (\w+)\[([\d,]+)\]\S* copy\(", hlo)
+        return [dtype for dtype, dims in found
+                if math.prod(map(int, dims.split(","))) == q_size]
+
+    assert q_sized_copies(entry[kernel.end():]) == []
+    if sliding:
+        assert "f32" not in q_sized_copies(entry[:kernel.start()])
