@@ -101,6 +101,11 @@ class Sizes:
     ssm_m1: Tuple[int, int, int, int]
     # a KDA decode step: layers, rows, heads, head width (the state d x d)
     kda: Tuple[int, int, int, int]
+    # softmax attention at a head width of TWO lane tiles (Qwen3-Next's full
+    # layers at the benchmark cell's batch and length): layers, rows, KV
+    # heads, query heads a KV head, slots (the decode read), head width,
+    # whole-sequence forward length, a prefill chunk's queries and slots
+    wide_heads: Tuple[int, int, int, int, int, int, int, int, int]
     # latent decode attention: layers, rows, query heads, slots, rank, rope
     mla: Tuple[int, int, int, int, int, int]
     # the same kernel where the heads' operations meet the slab's bytes:
@@ -149,6 +154,7 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              held_rows_long=(16384, 8, 2048, 1024, 16, 128),
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
              ssm_m1=(9, 64, 16, 5120), kda=(5, 128, 32, 128),
+             wide_heads=(3, 8, 2, 8, 33280, 256, 4096, 2048, 8320),
              mla=(1, 128, 32, 2048, 512, 64),
              mla_wide=(5, 8, 128, 25600, 512, 64),
              mla_dense=(2, 8, 128, 25600, 512, 64, 128, 128, 256),
@@ -170,6 +176,7 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              held_rows_long=(576, 4, 128, 32, 1, 16),
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
              ssm_m1=(2, 4, 16, 256), kda=(2, 3, 4, 16),
+             wide_heads=(2, 3, 2, 2, 64, 32, 64, 16, 48),
              mla=(2, 3, 4, 32, 32, 8),
              mla_wide=(2, 3, 128, 64, 32, 8),
              mla_dense=(2, 3, 4, 64, 32, 8, 16, 16, 16),
@@ -415,7 +422,7 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     # ---- the stacked dense cache (v1 generate): the kernel reads layer
     # `layer` of (L, B, Hkv, M, D) where it lies, with the step's new token
     # staged or already written; the writer lands a step's tokens ----
-    def make_stack(layers, rows, n_rep, m, mixed=False):
+    def make_stack(layers, rows, n_rep, m, mixed=False, hkv=hkv, d=d):
         def make(key):
             kq, kk, kv, kl, kn = jax.random.split(key, 5)
             # the cursors; the last row parked (it has no slot: the kernel
@@ -1104,7 +1111,65 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
         lambda q, k, v: block_sparse_attention(q, k, v, idx, nlive, sblk,
                                                causal=True),
         sparse_ref, make_sparse))
-    return cases + slow_cases + order_cases
+    # ---- a decay a HEAD (Gated DeltaNet): the same body, a second name ----
+    def make_gdn(key):
+        state, q, k, v, g, beta = make_kda(key)
+        return state, q, k, v, g[..., 0], beta
+
+    def gdn_ref(state, q, k, v, g, beta):
+        return kda_state_update_reference(
+            state, kl - 1, q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+            beta)
+
+    # ---- softmax attention at head width 256, 2 KV heads, n_rep 8: the
+    # flash forward over a whole sequence and over a CHUNK of a cached row,
+    # the dense decode kernel over the stack with the token staged ----
+    wl, wb, wkv, wrep, wm, wd, ws, wc, wcm = sz.wide_heads
+
+    make_wide_stack = make_stack(wl, wb, wrep, wm, hkv=wkv, d=wd)
+
+    def make_chunk(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        return (normal(kq, (wc, wkv * wrep, wd)),
+                normal(kk, (wl, wb, wkv, wcm, wd)),
+                normal(kv, (wl, wb, wkv, wcm, wd)))
+
+    # the chunk ends a block and a half into the row: live, diagonal and
+    # dead key blocks all met
+    chunk_at = dict(row=wb - 2, start=wcm // 2 - wc // 2)
+
+    def chunk_ref(q, k, v):
+        from deepspeed_tpu.inference.kv_cache import DenseLayer
+        from deepspeed_tpu.ops.attention import chunk_prefill_reference
+        return chunk_prefill_reference(
+            q, DenseLayer(k, jnp.int32(wl - 1)),
+            DenseLayer(v, jnp.int32(wl - 1)), **chunk_at)
+
+    wide_cases = [
+        KernelCase("gdn_state_update",
+                   lambda state, *rest: kda_state_update(state, kl - 1, *rest),
+                   gdn_ref, make_gdn),
+        KernelCase(f"flash_fwd_d{wd}",
+                   lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   lambda q, k, v: reference_attention(q, k, v, causal=True),
+                   qkv(1, ws, wkv * wrep, wkv, wd)),
+        KernelCase(f"flash_fwd_chunk_d{wd}",
+                   lambda q, k, v: flash.flash_prefill_chunk(
+                       q, k, v, wl - 1, chunk_at["row"], chunk_at["start"]),
+                   chunk_ref, make_chunk),
+        KernelCase(f"decode_stack_d{wd}",
+                   lambda q, k, v, index, new, layer: decode_attention(
+                       q, k, v, index + 1, layer=layer, k_new=new[0, layer],
+                       v_new=new[1, layer]),
+                   functools.partial(stack_ref, staged=True),
+                   make_wide_stack),
+        KernelCase(f"kv_write_d{wd}",
+                   lambda q, k, v, index, new, layer: kv_write_dense(
+                       k, v, new[0], new[1], index),
+                   stack_write_ref, make_wide_stack),
+    ]
+    # after every other: a case's inputs are drawn from seed + its index
+    return cases + slow_cases + order_cases + wide_cases
 
 
 def scopes_ms(case: KernelCase, inputs, reps: int = 10) -> Optional[float]:

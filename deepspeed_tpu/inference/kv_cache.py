@@ -176,13 +176,17 @@ class KVCache:
         return (DenseLayer(self.k.stack, layer, staged=staged, ring=self.ring),
                 DenseLayer(self.v.stack, layer, staged=staged, ring=self.ring))
 
-    def write_prefill(self, layer, k_new: jnp.ndarray,
-                      v_new: jnp.ndarray) -> "KVCache":
+    def write_prefill(self, layer, k_new: jnp.ndarray, v_new: jnp.ndarray,
+                      row=None, start=0) -> "KVCache":
         """A stacked cache with layer `layer`'s K and V of a prefill FROM
         THE EMPTY CACHE, `k_new`/`v_new` (B, S, Hkv, D), the tokens of
         positions 0 .. S - 1 of every row (`write_prefill_rows`: a ring
-        keeps its last `max_len`)."""
-        k, v = (write_prefill_rows(side.stack, layer, new, self.ring)
+        keeps its last `max_len`). With `row` (may be traced): a CHUNK of
+        that one sequence (B == 1), positions `start .. start + S - 1`
+        (full-length rows only; a prefill that walks a row a chunk at a
+        time)."""
+        k, v = (write_prefill_rows(side.stack, layer, new, self.ring, row,
+                                   start)
                 for side, new in ((self.k, k_new), (self.v, v_new)))
         return self.replace(k=DenseLayer(k), v=DenseLayer(v))
 
@@ -226,14 +230,20 @@ class KVCache:
         return self.replace(index=jnp.asarray(index, jnp.int32))
 
 
-def write_prefill_rows(stack, layer, new, ring: bool):
+def write_prefill_rows(stack, layer, new, ring: bool, row=None, start=0):
     """`new` (B, S, G, W), the tokens of positions 0 .. S - 1, into layer
     `layer` of the stacked cache `(L, B, G, M, W)`, which held nothing:
     positions as slots, or for a ring its last M tokens, position p in slot
-    p mod M. One dynamic-update-slice: it keeps the stack's tiling."""
+    p mod M. One dynamic-update-slice: it keeps the stack's tiling. With
+    `row`: `new` (1, S, G, W) is sequence `row`'s positions `start ..`."""
     m = stack.shape[3]
     s = new.shape[1]
     new = jnp.swapaxes(new, 1, 2).astype(stack.dtype)        # (B, G, S, W)
+    if row is not None:
+        if ring:
+            raise ValueError("a ring is written whole rows at a time")
+        return jax.lax.dynamic_update_slice(stack, new[None],
+                                            (layer, row, 0, start, 0))
     if ring and s > m:
         new = jnp.roll(new[:, :, s - m:], (s - m) % m, axis=2)
     return jax.lax.dynamic_update_slice(stack, new[None], (layer, 0, 0, 0, 0))

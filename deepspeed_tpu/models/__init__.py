@@ -40,3 +40,5 @@ from deepspeed_tpu.models.openpangu import (
     OpenPanguConfig, OpenPanguForCausalLM, openpangu_loss_fn)
 from deepspeed_tpu.models.afmoe import (
     AfmoeConfig, AfmoeForCausalLM, afmoe_loss_fn)
+from deepspeed_tpu.models.qwen3_next import (
+    Qwen3NextConfig, Qwen3NextForCausalLM, qwen3_next_loss_fn)

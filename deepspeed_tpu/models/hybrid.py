@@ -1,11 +1,14 @@
 """What the v1 hybrid families share (`nemotron_h`, `phi4flash`,
-`ling_linear`, `keye_sparse`, `deepseek_sparse`, `openpangu`, `afmoe`): a
+`ling_linear`, `keye_sparse`, `deepseek_sparse`, `openpangu`, `afmoe`,
+`qwen3_next`): a
 decision several of them must know lives here ONCE, and no family file
 imports another. How a prefill is CUT (`row_groups` under the family's
 `PREFILL_TOKENS`, `prefill_walk` under its `PREFILL_CHUNK`); what a serving
 pass hands the HEAD (`causal_lm`, the shell around a family's `Layers`, and
 its pieces for the two families whose shell differs); how a held share of
-experts is BUILT (`held_experts`, beside `DenseFFN`); the entry points a
+experts is BUILT (`held_experts`, beside `DenseFFN`); the delta rule over a
+sequence (`delta_chunked`: a decay a channel, Ling's, or a head, Qwen3-Next's);
+the entry points a
 family's module binds (`entry_points`), every `make_cache`'s int8 refusal,
 two initialisers, the index key's epsilon.
 
@@ -32,6 +35,8 @@ from deepspeed_tpu.utils.partitioning import BATCH_AXES, shard_along
 
 F32 = jnp.float32
 INDEX_NORM_EPS = 1e-6   # an index key's LayerNorm (the two learned selections)
+DELTA_CHUNK = 32    # positions a block of the chunked form (`delta_chunked`)
+L2_EPS = 1e-6       # under the root of a delta rule's q / k norm
 
 
 def a_log_init(key, shape, dtype=F32):
@@ -50,6 +55,29 @@ def dt_bias_init(cfg):
     return init
 
 
+def delta_dt_bias_init(key, shape, dtype=F32):
+    """The inverse softplus of dt log-uniform in [0.001, 0.1] (Mamba's, which
+    the open delta-rule implementations keep for their gates' bias)."""
+    lo, hi = math.log(0.001), math.log(0.1)
+    dt = jnp.exp(jax.random.uniform(key, shape, F32) * (hi - lo) + lo)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def l2_normalised(x):
+    """x over its last axis' L2 norm (a delta rule's queries and keys)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def short_conv(conv_w, window, s: int):
+    """The causal depthwise convolution of a delta-rule mixer, float32:
+    `window` (B, S + K - 1, C) is the K - 1 inputs before the S new ones and
+    those, `conv_w` (K, C). Returns (B, S, C)."""
+    w32 = window.astype(F32)
+    if s == 1:
+        return jnp.einsum("kc,bkc->bc", conv_w, w32)[:, None]
+    return sum(conv_w[j] * w32[:, j:j + s] for j in range(conv_w.shape[0]))
+
+
 class DenseFFN(nn.Module):
     """`W_down(silu(W_gate x) * W_up x)` at `intermediate_size`."""
     cfg: Any
@@ -64,12 +92,13 @@ class DenseFFN(nn.Module):
 
 
 def held_experts(cfg, name: str, *, held: int, activation: str, score_fn: str,
-                 shared=None):
+                 shared=None, shared_gate: bool = False):
     """The expert layer as `moe/layer.MoE` computes it: `held` experts (the
     family passes the number: the configs name it differently) of the
     `router_experts` the router scores (None: all are held) from
     `expert_offset` on, of `activation` (`relu2` / `silu`), a shared expert
-    of width `shared` beside them (None: none), nothing dropped by capacity.
+    of width `shared` beside them (None: none; `shared_gate`: its result
+    times a sigmoid gate a token), nothing dropped by capacity.
     `score_fn` `softmax`: the taken ones' weights over their sum. `sigmoid`:
     besides, the selection bias in the CHOICE only (none where the family's
     `router_bias_scale` is None), the choice limited by groups (`n_group` 1:
@@ -92,7 +121,103 @@ def held_experts(cfg, name: str, *, held: int, activation: str, score_fn: str,
         dtype=cfg.dtype, activation=activation,
         dispatch_impl=cfg.dispatch_impl, score_fn=score_fn,
         held_offset=cfg.expert_offset, held_experts=held,
-        shared_intermediate_size=shared, name=name, **router)
+        shared_intermediate_size=shared, shared_gate=shared_gate, name=name,
+        **router)
+
+
+# ------------------------------------------- the delta rule over a sequence
+
+
+def _neumann_inverse(a):
+    """`(I + a)^-1` for STRICTLY lower triangular `a` (..., C, C): the
+    product of `I + (-a)^(2^i)`, exact since `a^C = 0`."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    inv, power = eye - a, a @ a
+    for _ in range(max(0, math.ceil(math.log2(c)) - 1)):
+        inv, power = inv + inv @ power, power @ power
+    return inv
+
+
+def delta_chunked(q, k, v, g, beta, s0, chunk: int = DELTA_CHUNK):
+    """The gated delta rule (KDA's, a decay a channel; Gated DeltaNet's, a
+    decay a head) over a sequence in CHUNKS: inside a block of
+    `chunk` positions the delta rule's dependence of each token on the ones
+    before it is a unit lower triangular system (the WY form), solved by
+    matrix products; the state is carried between blocks. Exact against the
+    recurrence (`ops/pallas/kda.kda_step` a position).
+
+    q, k (B, S, H, dk) as they enter the recurrence; v (B, S, H, dv); g
+    (B, S, H, dk) log-decay <= 0, or (B, S, H): a decay a HEAD; beta (B, S,
+    H); s0 (B, H, dk, dv), a head's `S`: float32, products at `highest`. Returns (o (B, S, H, dv), the
+    state after position S - 1). Any S: the tail of the last block is padded
+    with g = 0, beta = 0, which leaves the state as it is.
+
+    With `G_i` the cumulative log-decay inside a block, the decay between
+    two of its positions, `exp(G_i - G_j)` a channel, is taken as
+    `exp(G_i - r) exp(r - G_j)` about the block's MIDDLE `r`: each exponent
+    is then at most `chunk / 2` steps' worth, 80 at the bound of -5 a step,
+    inside float32 either way (a whole block's, 160, is not).
+
+    A decay a HEAD is the cheaper special case: the decay between two
+    positions is ONE number, `exp(G_i - G_j) <= 1` for `j <= i` taken as it
+    stands (nothing to anchor: no exponent is positive), and it multiplies
+    the `(C, C)` products of the plain keys and queries; the two products a
+    channel (`up`, `down`) are gone."""
+    bsz, s, nh, dk = q.shape
+    dv = v.shape[-1]
+    pad = -s % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    nc = (s + pad) // chunk
+    # (nc, B, H, C, ...): the scan slices blocks, a head's rows are matrices
+    blocks = lambda t: jnp.moveaxis(  # noqa: E731
+        t.reshape((bsz, nc, chunk) + t.shape[2:]), (1, 3), (0, 2))
+    head = g.ndim == beta.ndim                      # a decay a head
+    q, k, v, g, beta = (blocks(t) for t in (q, k, v, g, beta))
+    if head:
+        g = g[..., None]                # (nc, B, H, C, 1): over the channels
+    cum = jnp.cumsum(g, axis=-2)                               # G_i, inclusive
+    if not head:
+        mid = cum[..., chunk // 2 - 1:chunk // 2, :]
+        up, down = jnp.exp(cum - mid), jnp.exp(mid - cum)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    with jax.default_matmul_precision("highest"):
+        if head:
+            between = jnp.exp(jnp.where(    # exp(G_i - G_j), j <= i; else 0
+                lower, cum - jnp.swapaxes(cum, -1, -2), -jnp.inf))
+            pairs = lambda t: jnp.einsum(  # noqa: E731
+                "...ic,...jc->...ij", t, k) * between
+        else:
+            k_down = k * down
+            pairs = lambda t: jnp.einsum(  # noqa: E731
+                "...ic,...jc->...ij", t * up, k_down)
+        # a[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j), j < i
+        a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool), pairs(k), 0.0)
+        solve = _neumann_inverse(a * beta[..., None])          # (I + A)^-1
+        # p[i, j] = sum_c q_i k_j exp(G_i - G_j), j <= i
+        p = jnp.where(lower, pairs(q), 0.0)
+        decay = jnp.exp(cum)                                   # from the start
+        k_in, q_in = k * decay, q * decay
+        to_end = k * jnp.exp(cum[..., -1:, :] - cum)
+        whole = decay[..., -1, :]                              # (nc, B, H, dk)
+
+        def block(state, blk):
+            k_in, q_in, v, beta, solve, p, to_end, whole = blk
+            # u_i = beta_i (v_i - S'^T k_i): the rows of (I + A) U = rhs
+            rhs = beta[..., None] * (v - k_in @ state)
+            u = solve @ rhs                                    # (B, H, C, dv)
+            o = q_in @ state + p @ u
+            state = state * whole[..., :, None] + jnp.einsum(
+                "...ic,...iv->...cv", to_end, u)
+            return state, o
+
+        s_last, o = jax.lax.scan(
+            block, s0, (k_in, q_in, v, beta, solve, p, to_end, whole))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, nc * chunk, nh, dv)
+    return o[:, :s], s_last
 
 
 # ---------------------------------------------------------------- the shell
@@ -115,10 +240,11 @@ def embedded(cfg, embed, ids):
     return shard_along(h, BATCH_AXES, "sequence", None)
 
 
-def lm_logits(module, h, eps: float):
-    """`norm_f`, then the untied `lm_head` (inside `module`'s call)."""
+def lm_logits(module, h, eps: float, norm=RMSNorm):
+    """`norm_f` (a `norm` module class `(eps, dtype)`), then the untied
+    `lm_head` (inside `module`'s call)."""
     cfg = module.cfg
-    h = RMSNorm(eps, cfg.dtype, name="norm_f")(h)
+    h = norm(eps, cfg.dtype, name="norm_f")(h)
     lm_head = module.param("lm_head", nn.with_logical_partitioning(
         nn.initializers.normal(0.02), ("embed", "vocab")),
         (cfg.hidden_size, cfg.vocab_size), F32)
@@ -195,9 +321,22 @@ def prefill_chunks(s: int, chunk: int):
     at a time, every length in chunks of whole 128-query tiles (the kernels'
     shape; a prompt under 128 is one chunk of its own length). A row's LAST
     chunk is drawn back to end at the row's end, and what it overlaps is
-    computed and written again."""
-    size = min(chunk, s // 128 * 128 or s)
+    computed and written again. A `chunk` that DIVIDES `s` is taken as it
+    stands, whatever its tiles (`dividing_chunk`): nothing is walked twice."""
+    size = chunk if s % chunk == 0 else min(chunk, s // 128 * 128 or s)
     return size, [min(i * size, s - size) for i in range(-(-s // size))]
+
+
+def dividing_chunk(s: int, chunk: int, least: int = 64) -> int:
+    """The chunk a family whose layers keep a RECURRENT state walks a prompt
+    of `s` in: such a layer cannot walk a position twice, so no chunk may be
+    drawn back and the size must divide `s`. The largest divisor of `s` up
+    to `chunk`, in whole 128-query tiles where `s` has such a divisor; a
+    prompt with no divisor of `least` or more under `chunk` (a prime
+    length) walks its rows whole."""
+    fits = [d for d in range(min(s, chunk), 0, -1) if s % d == 0]
+    size = next((d for d in fits if d % 128 == 0), fits[0])
+    return size if size >= min(least, s, chunk) else s
 
 
 class Chunks(nn.Module):
@@ -249,11 +388,12 @@ def prefill_walk(layers, cfg, cache, embed, input_ids, chunk: int):
 
 
 def causal_lm(module, layers, input_ids, labels, cache, *, eps: float,
-              prefill_tokens: int = None, prefill_chunk: int = None):
+              prefill_tokens: int = None, prefill_chunk: int = None,
+              norm=RMSNorm):
     """A family's causal LM around its `layers` (the module class, looked up
     by the family when its call is traced), inside `module`'s compact call:
-    `embed_tokens`, the layers under `layers`, `norm_f` at `eps`, `lm_head`,
-    the loss. A serving pass (`cache`) hands the head each row's LAST
+    `embed_tokens`, the layers under `layers`, `norm_f` (a `norm`) at `eps`,
+    `lm_head`, the loss. A serving pass (`cache`) hands the head each row's LAST
     position and advances the cursors; its prefill (S > 1) is cut as the
     family says: `prefill_chunk` queries of one row at a time, from the
     empty cache, or as many rows together as `prefill_tokens` holds."""
@@ -273,7 +413,8 @@ def causal_lm(module, layers, input_ids, labels, cache, *, eps: float,
         if cache is not None:
             h = h[:, -1:]          # a serving pass samples the last one
             cache = cache.advance(s)
-    return lm_output(lm_logits(module, h, eps), input_ids, labels, cache)
+    return lm_output(lm_logits(module, h, eps, norm), input_ids, labels,
+                     cache)
 
 
 def entry_points(model_cls):

@@ -21,7 +21,7 @@ a MATRIX `S` (d x d), float32:
 
 and the layer's output `(RMSNorm_head(o_t) * sigmoid(x W_g)) W_o`. One
 token is `ops/pallas/kda.kda_state_update` on the stored state; a sequence
-is `kda_chunked` below, exact against the recurrence.
+is `hybrid.delta_chunked`, exact against the recurrence.
 
 **MLA** (DeepSeek-V2's, no query compression): `q = x W_q` -> H x (128 nope
 + 64 rope); `[c, k_r] = x W_kva` (512 + 64), `c <- RMSNorm(c)`; a head's key
@@ -61,8 +61,6 @@ F32 = jnp.float32
 # sorted rows 0.34 GB, the expanded latent attention's keys 0.1 GB, beside
 # 8.8 GB of weights and 1.7 GB of cache.
 PREFILL_TOKENS = 8192
-KDA_CHUNK = 32      # positions a block of the chunked form (`kda_chunked`)
-L2_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,93 +176,6 @@ class LingLinearConfig:
 # ---------------------------------------------------------------------- KDA
 
 
-def _neumann_inverse(a):
-    """`(I + a)^-1` for STRICTLY lower triangular `a` (..., C, C): the
-    product of `I + (-a)^(2^i)`, exact since `a^C = 0`."""
-    c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
-    inv, power = eye - a, a @ a
-    for _ in range(max(0, math.ceil(math.log2(c)) - 1)):
-        inv, power = inv + inv @ power, power @ power
-    return inv
-
-
-def kda_chunked(q, k, v, g, beta, s0, chunk: int = KDA_CHUNK):
-    """The KDA recurrence over a sequence in CHUNKS: inside a block of
-    `chunk` positions the delta rule's dependence of each token on the ones
-    before it is a unit lower triangular system (the WY form), solved by
-    matrix products; the state is carried between blocks. Exact against the
-    recurrence (`ops/pallas/kda.kda_step` a position).
-
-    q, k (B, S, H, dk) as they enter the recurrence; v (B, S, H, dv); g
-    (B, S, H, dk) log-decay <= 0; beta (B, S, H); s0 (B, H, dk, dv), a head's
-    `S`: float32, products at `highest`. Returns (o (B, S, H, dv), the
-    state after position S - 1). Any S: the tail of the last block is padded
-    with g = 0, beta = 0, which leaves the state as it is.
-
-    With `G_i` the cumulative log-decay inside a block, the decay between
-    two of its positions, `exp(G_i - G_j)` a channel, is taken as
-    `exp(G_i - r) exp(r - G_j)` about the block's MIDDLE `r`: each exponent
-    is then at most `chunk / 2` steps' worth, 80 at the bound of -5 a step,
-    inside float32 either way (a whole block's, 160, is not)."""
-    bsz, s, nh, dk = q.shape
-    dv = v.shape[-1]
-    pad = -s % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, g, beta))
-    nc = (s + pad) // chunk
-    # (nc, B, H, C, ...): the scan slices blocks, a head's rows are matrices
-    blocks = lambda t: jnp.moveaxis(  # noqa: E731
-        t.reshape((bsz, nc, chunk) + t.shape[2:]), (1, 3), (0, 2))
-    q, k, v, g, beta = (blocks(t) for t in (q, k, v, g, beta))
-    cum = jnp.cumsum(g, axis=-2)                               # G_i, inclusive
-    mid = cum[..., chunk // 2 - 1:chunk // 2, :]
-    up, down = jnp.exp(cum - mid), jnp.exp(mid - cum)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    with jax.default_matmul_precision("highest"):
-        k_down = k * down
-        # a[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j), j < i
-        a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool),
-                      jnp.einsum("...ic,...jc->...ij", k * up, k_down), 0.0)
-        solve = _neumann_inverse(a * beta[..., None])          # (I + A)^-1
-        # p[i, j] = sum_c q_i k_j exp(G_i - G_j), j <= i
-        p = jnp.where(lower, jnp.einsum("...ic,...jc->...ij", q * up, k_down),
-                      0.0)
-        decay = jnp.exp(cum)                                   # from the start
-        k_in, q_in = k * decay, q * decay
-        to_end = k * jnp.exp(cum[..., -1:, :] - cum)
-        whole = decay[..., -1, :]                              # (nc, B, H, dk)
-
-        def block(state, blk):
-            k_in, q_in, v, beta, solve, p, to_end, whole = blk
-            # u_i = beta_i (v_i - S'^T k_i): the rows of (I + A) U = rhs
-            rhs = beta[..., None] * (v - k_in @ state)
-            u = solve @ rhs                                    # (B, H, C, dv)
-            o = q_in @ state + p @ u
-            state = state * whole[..., :, None] + jnp.einsum(
-                "...ic,...iv->...cv", to_end, u)
-            return state, o
-
-        s_last, o = jax.lax.scan(
-            block, s0, (k_in, q_in, v, beta, solve, p, to_end, whole))
-    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, nc * chunk, nh, dv)
-    return o[:, :s], s_last
-
-
-def _dt_bias_init(key, shape, dtype=F32):
-    # the inverse softplus of dt log-uniform in [0.001, 0.1] (Mamba's, which
-    # the open KDA implementation keeps for its gate's bias)
-    lo, hi = math.log(0.001), math.log(0.1)
-    dt = jnp.exp(jax.random.uniform(key, shape, F32) * (hi - lo) + lo)
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
-def _l2_normalised(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
-
-
 class KDAMixer(nn.Module):
     cfg: LingLinearConfig
 
@@ -289,7 +200,7 @@ class KDAMixer(nn.Module):
                 key, sh, dt, -bound, bound), (kw, 3 * di), F32).astype(F32)
         a = jnp.exp(self.param("A_log", hybrid.a_log_init, (nh,),
                                F32).astype(F32))
-        dt_bias = self.param("dt_bias", _dt_bias_init, (di,), F32)
+        dt_bias = self.param("dt_bias", hybrid.delta_dt_bias_init, (di,), F32)
         norm_w = self.param("norm_weight", nn.initializers.ones_init(), (d,),
                             F32)
         # the bounded gate: a log-decay a channel in (kda_lower_bound, 0)
@@ -300,14 +211,10 @@ class KDAMixer(nn.Module):
         tail = (jnp.zeros((b, kw - 1, 3 * di), qkv.dtype) if state is None
                 else state.conv[slot])
         window = jnp.concatenate([tail, qkv], axis=1)      # (B, S + K - 1, C)
-        w32 = window.astype(F32)
-        if s == 1:
-            conv = jnp.einsum("kc,bkc->bc", conv_w, w32)[:, None]
-        else:
-            conv = sum(conv_w[j] * w32[:, j:j + s] for j in range(kw))
+        conv = hybrid.short_conv(conv_w, window, s)
         q, k, v = (t.reshape(b, s, nh, d) for t in
                    jnp.split(jax.nn.silu(conv), 3, axis=-1))
-        q, k = _l2_normalised(q) * d ** -0.5, _l2_normalised(k)
+        q, k = hybrid.l2_normalised(q) * d ** -0.5, hybrid.l2_normalised(k)
 
         if state is not None and s == 1:
             from deepspeed_tpu.ops.attention import kda_update
@@ -317,7 +224,7 @@ class KDAMixer(nn.Module):
         else:
             s0 = (jnp.zeros((b, nh, d, d), F32) if state is None
                   else state.ssm[slot])
-            o, last = kda_chunked(q, k, v, g, beta, s0)
+            o, last = hybrid.delta_chunked(q, k, v, g, beta, s0)
             ssm = None if state is None else \
                 jax.lax.dynamic_update_index_in_dim(state.ssm, last, slot, 0)
         if state is not None:

@@ -217,6 +217,12 @@ class TopKGate(nn.Module):
                           self.norm_topk_prob, **scoring)
 
 
+def shared_expert_gate(x, w):
+    """`sigmoid(x . w)`, float32, one number a token: what a GATED shared
+    expert's result is multiplied by (`MoE.shared_gate`)."""
+    return jax.nn.sigmoid((x @ w).astype(jnp.float32))
+
+
 class MoE(nn.Module):
     """Drop-in MoE FFN block — reference deepspeed/moe/layer.py:MoE.
 
@@ -264,6 +270,9 @@ class MoE(nn.Module):
     # a shared expert of this width beside the routed ones, same activation,
     # run for every token and added once
     shared_intermediate_size: Optional[int] = None
+    # the shared expert's result times `sigmoid(x . w)`, one number a token
+    # (Qwen's `shared_expert_gate`; `models/qwen2_moe.py` has the dense form)
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, hidden_states, train: bool = True, valid=None):
@@ -409,7 +418,13 @@ class MoE(nn.Module):
         if self.shared_intermediate_size:
             shared = Experts(1, d, self.shared_intermediate_size, self.dtype,
                              self.activation, name="shared_expert")
-            out = out + shared(x[None])[0].astype(jnp.float32)
+            shared = shared(x[None])[0].astype(jnp.float32)
+            if self.shared_gate:
+                w = self.param("shared_expert_gate", nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), ("embed", None)), (d, 1),
+                    jnp.float32)
+                shared = shared * shared_expert_gate(x, w.astype(self.dtype))
+            out = out + shared
         total = t * k if valid is None else k * jnp.sum(valid.astype(jnp.int32))
         local = held_assignments(topk_idx, self.held_offset, count, valid)[1]
         touched = jnp.sum(held_group_sizes(local, count) > 0)

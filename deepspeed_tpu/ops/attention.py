@@ -308,6 +308,48 @@ def banded_prefill(q, k, v, window: int,
     return banded_attention(q, k, v, window, softmax_scale)
 
 
+def chunk_prefill(q, k_cache, v_cache, row, start,
+                  softmax_scale: Optional[float] = None) -> jnp.ndarray:
+    """A prefill CHUNK's causal attention over one row of the stacked dense
+    cache, FORWARD only: what a full softmax layer runs where the prefill
+    walks a row a chunk at a time (`models/hybrid.prefill_walk`). q (C, H,
+    D), the queries of positions `start .. start + C - 1` of sequence `row`
+    (both may be traced); k_cache / v_cache `DenseLayer` views with `layer`
+    set, which hold the row's keys and values up to the chunk's end. Query i
+    sees slots 0 .. start + i. On the chip in a one-device program the flash
+    forward reads the stacks in place (`flash_prefill_chunk`); elsewhere
+    `chunk_prefill_reference`."""
+    c, _, d = q.shape
+    if c % 128 == 0 and d % 128 == 0 and k_cache.stack.shape[3] % 128 == 0:
+        from deepspeed_tpu.ops.pallas import flash_attention as fa
+        if _one_device_kernel(fa.FWD_NAME):
+            return fa.flash_prefill_chunk(
+                q, k_cache.stack, v_cache.stack, k_cache.layer, row, start,
+                softmax_scale)
+    return chunk_prefill_reference(q, k_cache, v_cache, row, start,
+                                   softmax_scale)
+
+
+def chunk_prefill_reference(q, k_cache, v_cache, row, start,
+                            softmax_scale: Optional[float] = None):
+    """`chunk_prefill` in plain `jax.numpy`: the row cut out of the stacks
+    and masked."""
+    c, h, d = q.shape
+    m = k_cache.stack.shape[3]
+    k, v = (jax.lax.dynamic_slice(
+        t.stack, (t.layer, row, 0, 0, 0), (1, 1) + t.stack.shape[2:])[0, 0]
+        for t in (k_cache, v_cache))                        # (Hkv, M, D)
+    hkv = k.shape[0]
+    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    qg = q.reshape(c, hkv, h // hkv, d)
+    logits = jnp.einsum("cgrd,gmd->grcm", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(m)[None, :] <= start + jnp.arange(c)[:, None]
+    probs = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("grcm,gmd->cgrd", probs.astype(v.dtype),
+                      v).reshape(c, h, d)
+
+
 def _assert_prefix_mask(mask, index, m: int, s: int = 1):
     """Debug-mode contract check for the Pallas decode dispatch: `mask` must
     be the prefix mask implied by `index` (slots 0..index valid). Enabled by
@@ -678,11 +720,13 @@ def latent_decode(q_lat, q_rope, latent, lengths, softmax_scale: float,
 def kda_update(state, layer, q, k, v, g, beta):
     """One decode step of the gated delta rule on layer `layer` of the
     stacked float32 state (`ops/pallas/kda.py` has the layout and the
-    operands): `(o (B, H, dv) float32, state)`. The Pallas kernel, one read
-    and one write of the layer's state in place, on the chip in a one-device
-    program; elsewhere the same in plain `jax.numpy`."""
+    operands): `(o (B, H, dv) float32, state)`; `g` (B, H, dk) is a decay a
+    channel, (B, H) a decay a HEAD (`gdn_state_update`). The Pallas kernel,
+    one read and one write of the layer's state in place, on the chip in a
+    one-device program; elsewhere the same in plain `jax.numpy`."""
     from deepspeed_tpu.ops.pallas import kda
-    fn = kda.kda_state_update if _one_device_kernel(kda.KERNEL_NAME) \
+    name = kda.HEAD_DECAY_NAME if g.ndim == 2 else kda.KERNEL_NAME
+    fn = kda.kda_state_update if _one_device_kernel(name) \
         else kda.kda_state_update_reference
     return fn(state, layer, q, k, v, g, beta)
 

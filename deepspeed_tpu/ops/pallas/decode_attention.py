@@ -59,7 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _interpret
-from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF
+from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF, lane_block
 
 # VMEM for the double-buffered K and V tiles of one grid step (two tiles, two
 # buffers each), of the 16 MB a kernel may use by default; the plan below
@@ -99,8 +99,7 @@ def decode_plan(b: int, hkv: int, m: int, d: int, itemsize: int,
     `MIN_STEPS` leave, 1 at worst."""
     per_slot = 4 * hkv * d * itemsize     # one row's one slot in the tiles
     cap = min(block_k or MAX_BLOCK_K, KV_TILE_BUDGET // per_slot)
-    blk_k = next((x for x in _divisors_desc(m, cap) if x % 128 == 0),
-                 next(_divisors_desc(m, cap)))
+    blk_k = lane_block(m, cap)
     rb = next(_divisors_desc(b, min(KV_TILE_BUDGET // (per_slot * blk_k),
                                     b * (m // blk_k) // MIN_STEPS)))
     return rb, blk_k

@@ -47,6 +47,15 @@ traced as `BAND_NAME`. With `window=None` nothing here differs from the
 kernel without a band, instruction for instruction. The BACKWARD under a
 window does not exist (`_flash_bwd_rule` raises by name): no cell trains a
 window family (ROADMAP.md, B6).
+
+A CHUNK of one cached row (`flash_prefill_chunk`: a prefill that walks a row
+a chunk at a time, `models/hybrid.prefill_walk`): the chunk's queries against
+the row's K and V WHERE THEY LIE in the stacked dense cache, (L, B, Hkv, M,
+D), keys 0 .. start + i for query i, `start` a traced scalar. It is
+`_fwd_kernel`'s body with the causal offset read from a prefetched scalar
+(the layer and the row beside it, for the index maps): key blocks past the
+chunk's end are neither fetched nor computed. Same trace name (`FWD_NAME`).
+A program that does not call it is what it was.
 """
 
 from __future__ import annotations
@@ -592,6 +601,76 @@ def _fwd(qs, k, v, causal, blk_q, blk_k, window=None, token_major=False):
         name=name,
     )(qs, k, v)
     return (out.reshape(b, sq, h, d) if token_major else out), lse
+
+
+def _fwd_kernel_chunk(at_ref, *refs, blk_q, blk_k, nk):
+    """`_fwd_kernel` (causal) with the offset a prefetched scalar: `at_ref`
+    holds (layer, row, start), the first two for the index maps."""
+    _fwd_kernel(*refs, causal=True, blk_q=blk_q, blk_k=blk_k, nk=nk,
+                offset=at_ref[2])
+
+
+def lane_block(m: int, cap: int) -> int:
+    """Key slots a block of a cached row of `m`: the largest divisor up to
+    `cap`, in whole lane tiles of 128 where `m` has such a divisor."""
+    fits = [x for x in range(min(m, cap), 0, -1) if m % x == 0]
+    return next((x for x in fits if x % 128 == 0), fits[0])
+
+
+def flash_prefill_chunk(q, k_stack, v_stack, layer, row, start,
+                        softmax_scale: Optional[float] = None,
+                        block_q: int = DEFAULT_BLOCK_Q,
+                        block_k: int = DEFAULT_BLOCK_K) -> jnp.ndarray:
+    """A chunk's causal attention over ONE row of the stacked dense cache.
+
+    q (C, H, D), the queries of positions `start .. start + C - 1` of
+    sequence `row`; k_stack / v_stack (L, B, Hkv, M, D), which hold that
+    row's keys and values of layer `layer` up to the chunk's last position;
+    `layer`, `row`, `start` ints or () int32. Query i sees slots 0 .. start
+    + i. Returns (C, H, D). The stacks are read in place, a block (blk_k, D)
+    of one KV head at a time; the queries and the result go head-major (a
+    chunk's transposes, 17 MB at 2,048 x 16 x 256)."""
+    c, h, d = q.shape
+    hkv, m = k_stack.shape[2], k_stack.shape[3]
+    n_rep = h // hkv
+    scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
+    blk_q = _pick_blocks(c, c, block_q, block_q)[0]
+    blk_k = lane_block(m, block_k)
+    nq, nk = c // blk_q, m // blk_k
+    qs = jnp.swapaxes((q * (scale * LOG2E)).astype(q.dtype), 0, 1)
+    at = jnp.stack([jnp.asarray(t, jnp.int32).reshape(())
+                    for t in (layer, row, start)])
+
+    # `_fwd_kernel`'s grid, (batch, head, query block, key block): a batch
+    # of the one row
+    def q_at(_, h_, i, j, at):
+        return h_, i, 0
+
+    def kv_at(_, h_, i, j, at):
+        # dead blocks clamp to the chunk's last live one: no fetch
+        hi = (i * blk_q + blk_q - 1 + at[2]) // blk_k
+        return at[0], at[1], h_ // n_rep, jnp.minimum(j, hi), 0
+
+    kv_spec = pl.BlockSpec((None, None, None, blk_k, d), kv_at)
+    _count_forward(False)
+    out, _ = pl.pallas_call(
+        functools.partial(_fwd_kernel_chunk, blk_q=blk_q, blk_k=blk_k, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1, h, nq, nk),
+            in_specs=[pl.BlockSpec((None, blk_q, d), q_at), kv_spec, kv_spec],
+            out_specs=[pl.BlockSpec((None, blk_q, d), q_at),
+                       pl.BlockSpec((None, blk_q, 1), q_at)],
+            scratch_shapes=[pltpu.VMEM((blk_q, 128), jnp.float32),
+                            pltpu.VMEM((blk_q, 128), jnp.float32),
+                            pltpu.VMEM((blk_q, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(qs.shape, qs.dtype),
+                   jax.ShapeDtypeStruct((h, c, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
+        interpret=_interpret(),
+        name=FWD_NAME,
+    )(at, qs, k_stack, v_stack)
+    return jnp.swapaxes(out, 0, 1)
 
 
 def _count_forward(token_major):

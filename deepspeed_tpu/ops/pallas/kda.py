@@ -30,6 +30,16 @@ layer of 128 sequences, 74% against 62% of the roofline (PERF.md, PR 47).
 Grid `(B, H / hb)`: one step holds `hb` heads of one sequence (2 MB of
 state at 32 heads of 128 x 128; with the result double-buffered 8 MB; 16
 heads a step read 0.93 ms, 8 read 1.05).
+
+A decay a HEAD (`g` of shape (B, H): Gated DeltaNet, arXiv:2412.06464, and
+most published linear-attention models) is the same body with ONE scalar a
+head in the decay's place: it arrives as a ROW, `(B, H / hb, hb, d_v)` like
+`v`, broadcast over the sublanes, and the decay COLUMN and its lane
+broadcast are gone (three column operands, not four). It is traced under a
+second name, `gdn_state_update` (one kernel, two names, as
+`decode_attention.py` and `diff_attention.py` have). With `g` (B, H, d_k)
+nothing here differs from the kernel without that form, instruction for
+instruction.
 """
 
 from __future__ import annotations
@@ -45,13 +55,16 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas import _interpret
 
 KERNEL_NAME = "kda_state_update"
+HEAD_DECAY_NAME = "gdn_state_update"     # the same body, a decay a HEAD
 
 
 def _kernel(layer_ref, state_ref, decay_ref, k_ref, kb_ref, q_ref, v_ref,
-            o_ref, out_ref, *, hb):
+            o_ref, out_ref, *, hb, head_decay=False):
     del layer_ref                                   # used by the index maps
     for h in range(hb):
-        s = state_ref[h] * decay_ref[:, h:h + 1]    # (dk, dv) x a (dk, 1) column
+        # (dk, dv) x a (dk, 1) column; a decay a head: x its (1, dv) row
+        s = state_ref[h] * (decay_ref[h:h + 1] if head_decay
+                            else decay_ref[:, h:h + 1])
         # (1, dv): v less what the decayed state already answers to this key
         u = v_ref[h:h + 1] - jnp.sum(s * k_ref[:, h:h + 1], axis=0,
                                      keepdims=True)
@@ -75,14 +88,17 @@ def kda_state_update(state: jnp.ndarray, layer, q: jnp.ndarray,
 
     state (L, B, H, dk, dv) float32, a head's `S`; layer: int or () int32;
     q, k (B, H, dk), as they enter the recurrence (normalised, `q` scaled);
-    v (B, H, dv); g (B, H, dk) log-decay, <= 0; beta (B, H). Returns `(o
-    (B, H, dv) float32, state)`: the same buffer where the caller donates or
-    carries it. Everything is computed in float32."""
+    v (B, H, dv); g (B, H, dk) log-decay, <= 0, or (B, H): a decay a HEAD
+    (`HEAD_DECAY_NAME`); beta (B, H). Returns `(o (B, H, dv) float32,
+    state)`: the same buffer where the caller donates or carries it.
+    Everything is computed in float32."""
     nl, bsz, nh, dk, dv = state.shape
     if state.dtype != jnp.float32:
         raise ValueError(f"kda_state_update: the state is {state.dtype}; the "
                          "recurrence is kept in float32")
-    if q.shape != (bsz, nh, dk) or k.shape != q.shape or g.shape != q.shape \
+    head_decay = g.ndim == 2
+    if q.shape != (bsz, nh, dk) or k.shape != q.shape \
+            or g.shape != q.shape[:2 if head_decay else 3] \
             or v.shape != (bsz, nh, dv) or beta.shape != (bsz, nh):
         raise ValueError(f"kda_state_update: state {state.shape}, q {q.shape}, "
                          f"k {k.shape}, v {v.shape}, g {g.shape}, beta "
@@ -101,11 +117,17 @@ def kda_state_update(state: jnp.ndarray, layer, q: jnp.ndarray,
     slab = pl.BlockSpec((None, None, None, hb, dk, dv),
                         lambda i, j, l: (l[0], i, j, 0, 0, 0))
     stacked = state.reshape(nl, bsz, ng, hb, dk, dv)
+    if head_decay:      # a head's one decay, a row of it over the lanes
+        kernel = functools.partial(_kernel, hb=hb, head_decay=True)
+        decay = jnp.broadcast_to(jnp.exp(g).reshape(bsz, ng, hb, 1),
+                                 (bsz, ng, hb, dv))
+    else:
+        kernel, decay = functools.partial(_kernel, hb=hb), cols(jnp.exp(g))
     o, stacked = pl.pallas_call(
-        functools.partial(_kernel, hb=hb),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(bsz, ng),
-            in_specs=[slab, col, col, col, col, row],
+            in_specs=[slab, row if head_decay else col, col, col, col, row],
             out_specs=[row, slab]),
         out_shape=[jax.ShapeDtypeStruct((bsz, ng, hb, dv), f32),
                    jax.ShapeDtypeStruct(stacked.shape, f32)],
@@ -113,15 +135,18 @@ def kda_state_update(state: jnp.ndarray, layer, q: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret() if interpret is None else interpret,
-        name=KERNEL_NAME,
-    )(layer, stacked, cols(jnp.exp(g)), cols(k), cols(beta[..., None] * k),
+        name=HEAD_DECAY_NAME if head_decay else KERNEL_NAME,
+    )(layer, stacked, decay, cols(k), cols(beta[..., None] * k),
       cols(q), v.reshape(bsz, ng, hb, dv))
     return o.reshape(bsz, nh, dv), stacked.reshape(state.shape)
 
 
 def kda_step(s, q, k, v, g, beta):
     """The same step on a bare state `s` (..., dk, dv) in plain `jax.numpy`:
-    operands (..., dk) and (..., dv), beta (...). Returns (o, s)."""
+    operands (..., dk) and (..., dv), beta (...), g (..., dk) or, a decay a
+    head, (...). Returns (o, s)."""
+    if g.ndim < k.ndim:
+        g = g[..., None]
     s = s * jnp.exp(g)[..., :, None]
     u = v - jnp.sum(s * k[..., :, None], axis=-2)
     s = s + (beta[..., None] * k)[..., :, None] * u[..., None, :]

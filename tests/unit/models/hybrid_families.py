@@ -1,6 +1,7 @@
-"""What the tests of the seven hybrid families share (`test_nemotron_h.py`,
+"""What the tests of the eight hybrid families share (`test_nemotron_h.py`,
 `test_phi4flash.py`, `test_ling_linear.py`, `test_keye_sparse.py`,
-`test_deepseek_sparse.py`, `test_openpangu.py`, `test_afmoe.py`): each
+`test_deepseek_sparse.py`, `test_openpangu.py`, `test_afmoe.py`,
+`test_qwen3_next.py`): each
 family at a small size on seeded weights with the benchmark's plain float32
 reference beside it, the model's `apply` under ONE `jax.jit`, the walk
 through the caches, and the questions asked of all six alike, written once (`the_plain_forward_...`,
@@ -28,7 +29,7 @@ import pytest
 
 from deepspeed_tpu.models import (afmoe, deepseek_sparse, keye_sparse,
                                   ling_linear, nemotron_h, openpangu,
-                                  phi4flash)
+                                  phi4flash, qwen3_next)
 from perfbench.manifest import Manifest
 
 
@@ -119,6 +120,7 @@ KEYE_TOL = 3e-6         # read 4e-7
 DEEPSEEK_TOL = 5e-6
 OPENPANGU_TOL = 5e-6    # read 4e-7: absorbed against expanded, sorted rows
 AFMOE_TOL = 5e-6        # read 5e-7: rings and staged tokens against whole rows
+QWEN3_NEXT_TOL = 5e-6   # read 9e-7: the chunked solve against the recurrence
 
 NEMOTRON_CFG = nemotron_h.NemotronHConfig(
     vocab_size=128, hidden_size=64, num_hidden_layers=6,
@@ -200,6 +202,21 @@ AFMOE_SIZES = dict(
     num_shared_experts=1, route_norm=True, route_scale=2.826,
     mup_enabled=True, rms_norm_eps=1e-5, max_position_embeddings=4096,
     window_layers=3, full_layers=2)
+# the file's keys, as the reference and the adapter read them: the published
+# RATIOS at toy widths. Two periods of three GDN layers to one full layer
+# (published layers 0-7); key heads half the value heads; rotary over a
+# quarter of a head; 32 experts, top 4, experts 8-15 held; the shared
+# expert gated
+QWEN3_NEXT_SIZES = dict(
+    vocab_size=128, hidden_size=64, num_hidden_layers=8,
+    full_attention_interval=4, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
+    linear_conv_kernel_dim=4, linear_key_head_dim=8, linear_num_key_heads=2,
+    linear_num_value_heads=4, linear_value_head_dim=8, num_experts=8,
+    router_experts=32, expert_offset=8, num_experts_per_tok=4,
+    moe_intermediate_size=32, shared_expert_intermediate_size=32,
+    norm_topk_prob=True, rms_norm_eps=1e-6, max_position_embeddings=4096,
+    full_layers=2, gdn_layers=6)
 PHI4_CFG = phi4flash.Phi4FlashConfig(**PHI4_SIZES, dtype=jnp.float32)
 LING_CFG = ling_linear.LingLinearConfig(**LING_SIZES, dtype=jnp.float32)
 
@@ -333,9 +350,18 @@ def _afmoe():
                          lambda path: path.endswith("['gate']['wg']"))
 
 
+def _qwen3_next():
+    """The norms' `w`, the head norms, `A_log` / `dt_bias`, the shared
+    expert's gate and the convolution off their seeded values; the routers
+    at a range at which the choice decides."""
+    return _from_adapter("qwen3_next", qwen3_next, QWEN3_NEXT_SIZES,
+                         QWEN3_NEXT_TOL, qwen3_next.qwen3_next_loss_fn,
+                         lambda path: path.endswith("['gate']['wg']"))
+
+
 FAMILIES = {"deepseek_sparse": _deepseek_sparse, "openpangu": _openpangu, "nemotron_h": _nemotron_h, "phi4flash": _phi4flash,
             "ling_linear": _ling_linear, "keye_sparse": _keye_sparse,
-            "afmoe": _afmoe}
+            "afmoe": _afmoe, "qwen3_next": _qwen3_next}
 
 
 @functools.cache

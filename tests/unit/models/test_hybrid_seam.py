@@ -1,4 +1,4 @@
-"""The seam between the seven v1 hybrid families and what they share
+"""The seam between the eight v1 hybrid families and what they share
 (`models/hybrid.py`, ISSUE 62), and the parameter trees a benchmark cell's
 weights are drawn into.
 
@@ -26,6 +26,7 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..",
 MODELS = os.path.join(REPO, "deepspeed_tpu", "models")
 # what exists ONCE under `deepspeed_tpu/models/`, under whichever spelling
 SHARED = ("RowGroups", "Chunks", "prefill_walk", "prefill_chunks", "embedded",
+          "delta_chunked", "neumann_inverse", "dividing_chunk",
           "held_experts", "experts", "DenseFFN", "causal_lm")
 
 

@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models import ling_linear
-from deepspeed_tpu.models.ling_linear import LingLinearConfig, kda_chunked
+from deepspeed_tpu.models.hybrid import delta_chunked as kda_chunked
+from deepspeed_tpu.models.ling_linear import LingLinearConfig
 from deepspeed_tpu.ops.pallas.kda import kda_step
 from tests.unit.models import hybrid_families
 from tests.unit.models.hybrid_families import (LING_CFG as CFG,

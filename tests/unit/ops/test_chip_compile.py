@@ -48,6 +48,7 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "held_rows_long": "held_combine",
                "held_combine": "held_combine",
                "kda_state_update": "kda_state_update",
+               "gdn_state_update": "gdn_state_update",
                "mla_latent_decode": "mla_latent_decode",
                "mla_latent_decode_h128": "mla_latent_decode",
                "mla_dense_prefill": "mla_dense_prefill",

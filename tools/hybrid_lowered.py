@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""What the seven v1 hybrid families LOWER to, and their parameter trees, at
+"""What the eight v1 hybrid families LOWER to, and their parameter trees, at
 `tests/unit/models/hybrid_families.py`'s toy sizes, on the CPU, from shapes
 alone (nothing is compiled or run; about a minute): for each family
 
@@ -32,13 +32,13 @@ import re
 import sys
 
 FAMILIES = ("nemotron_h", "phi4flash", "ling_linear", "keye_sparse",
-            "deepseek_sparse", "openpangu", "afmoe")
+            "deepseek_sparse", "openpangu", "afmoe", "qwen3_next")
 # (rows, prompt, cache slots, the budget under which the prompt is walked):
 # each family's own walked-prefill test's
 WALKED = {"nemotron_h": (4, 21, 64, 2 * 21), "phi4flash": (4, 13, 32, 2 * 13),
           "ling_linear": (4, 23, 128, 2 * 23), "keye_sparse": (3, 23, 64, 8),
           "deepseek_sparse": (3, 23, 64, 8), "openpangu": (3, 23, 64, 8),
-          "afmoe": (3, 20, 64, 30)}
+          "afmoe": (3, 20, 64, 30), "qwen3_next": (3, 24, 64, 8)}
 
 
 def toy_configs():
@@ -48,7 +48,8 @@ def toy_configs():
     from tests.unit.models import hybrid_families as hf
     adapted = {"keye_sparse": hf.KEYE_SIZES,
                "deepseek_sparse": hf.DEEPSEEK_SIZES,
-               "openpangu": hf.OPENPANGU_SIZES, "afmoe": hf.AFMOE_SIZES}
+               "openpangu": hf.OPENPANGU_SIZES, "afmoe": hf.AFMOE_SIZES,
+               "qwen3_next": hf.QWEN3_NEXT_SIZES}
     manifest = Manifest()
     return {"nemotron_h": hf.NEMOTRON_CFG, "phi4flash": hf.PHI4_CFG,
             "ling_linear": hf.LING_CFG,
