@@ -41,6 +41,7 @@ import numpy as np
 # bf16 inputs round on the MXU, so kernel and XLA reference accumulate
 # differently on the chip (CLAUDE.md, tests/unit/ops/test_flash_attention.py)
 FWD_TOL, BWD_TOL = 2e-2, 1e-1
+DELTA_TOL = 2e-5    # float32 products at `highest` on both sides
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,6 +138,12 @@ class Sizes:
     # the BANDED flash forward: rows, positions, query heads, KV heads,
     # window (two of Trinity-Mini's prompts, as its prefill walks them)
     flash_band: Tuple[int, int, int, int, int]
+    # a serving prefill's chunked delta rule, a decay a head: rows,
+    # positions, key heads, value heads, head width, positions a block (a
+    # chunk of Qwen3-Next's prompt, as its prefill walks it)
+    delta_prefill: Tuple[int, int, int, int, int, int]
+    # the same with a decay a CHANNEL (a group of Ling's prompts)
+    delta_prefill_channel: Tuple[int, int, int, int, int, int]
 
 
 FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
@@ -162,7 +169,9 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              mla_sparse=(2, 8, 128, 33280, 512, 64, 128, 128, 64, 128, 2048,
                          256),
              ring_stack=(12, 32, 4, 8, 2048),
-             flash_band=(2, 8192, 32, 4, 2048))
+             flash_band=(2, 8192, 32, 4, 2048),
+             delta_prefill=(1, 2048, 16, 32, 128, 64),
+             delta_prefill_channel=(8, 1024, 32, 32, 128, 32))
 TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              micro_batch=2, global_batch=8, prompt_lens=(8, 24),
              prompts_per_len=2, new_tokens=8, v2_slots=2, v2_max_seq=64,
@@ -182,7 +191,9 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              mla_dense=(2, 3, 4, 64, 32, 8, 16, 16, 16),
              sparse=(2, 3, 2, 2, 64, 16, 4, 8, 8, 16),
              mla_sparse=(2, 3, 4, 64, 32, 8, 16, 16, 4, 8, 8, 16),
-             ring_stack=(2, 4, 2, 2, 16), flash_band=(1, 64, 4, 2, 24))
+             ring_stack=(2, 4, 2, 2, 16), flash_band=(1, 64, 4, 2, 24),
+             delta_prefill=(1, 72, 1, 2, 128, 16),
+             delta_prefill_channel=(2, 40, 2, 2, 128, 16))
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -248,6 +259,8 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                                              reference_attention)
     from deepspeed_tpu.ops.pallas.block_sparse_attention import (
         block_sparse_attention, padded_layout_indices)
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.ops.pallas.delta_rule import delta_rule_prefill
     from deepspeed_tpu.ops.pallas.decode_attention import (decode_attention,
                                                            decode_plan,
                                                            kv_write_dense)
@@ -1168,6 +1181,39 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                        k, v, new[0], new[1], index),
                    stack_write_ref, make_wide_stack),
     ]
+    # ---- a serving prefill's chunked delta rule, both decay forms, the keys
+    # as the convolution leaves them, against the plain chunked form between
+    # the same norms: float32 products at `highest` on both sides ----
+    def delta_case(name, shape, channel):
+        pb, ps, pk, pv, pd, pc = shape
+
+        def make_delta(key):
+            ks = jax.random.split(key, 7)
+            f32 = jnp.float32
+            gate = normal(ks[3], (pb, ps, pv) + ((pd,) if channel else ()),
+                          f32)
+            return (normal(ks[0], (pb, ps, pk, pd), f32),
+                    normal(ks[1], (pb, ps, pk, pd), f32),
+                    normal(ks[2], (pb, ps, pv, pd), f32),
+                    # the families' gates: a softplus a head, a bounded one
+                    # a channel
+                    -5.0 * jax.nn.sigmoid(gate) if channel
+                    else -4.0 * jax.nn.softplus(gate),
+                    jax.nn.sigmoid(normal(ks[4], (pb, ps, pv), f32)),
+                    0.1 * normal(ks[5], (pb, pv, pd, pd), f32),
+                    1.0 + 0.1 * normal(ks[6], (pd,), f32))
+
+        return KernelCase(
+            name, lambda *operands: delta_rule_prefill(
+                *operands[:-1], pc, operands[-1], l2_eps=hybrid.L2_EPS,
+                norm_eps=1e-6),
+            lambda *operands: hybrid.delta_prefill_reference(
+                *operands[:-1], pc, operands[-1], 1e-6),
+            make_delta, tol=DELTA_TOL)
+
+    wide_cases += [
+        delta_case("delta_prefill_head", sz.delta_prefill, False),
+        delta_case("delta_prefill_channel", sz.delta_prefill_channel, True)]
     # after every other: a case's inputs are drawn from seed + its index
     return cases + slow_cases + order_cases + wide_cases
 

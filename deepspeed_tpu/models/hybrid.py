@@ -7,7 +7,9 @@ imports another. How a prefill is CUT (`row_groups` under the family's
 pass hands the HEAD (`causal_lm`, the shell around a family's `Layers`, and
 its pieces for the two families whose shell differs); how a held share of
 experts is BUILT (`held_experts`, beside `DenseFFN`); the delta rule over a
-sequence (`delta_chunked`: a decay a channel, Ling's, or a head, Qwen3-Next's);
+sequence (`delta_chunked`: a decay a channel, Ling's, or a head, Qwen3-Next's;
+`delta_prefill`: a serving prefill's choice between it and the kernel, the
+norms a head round it included);
 the entry points a
 family's module binds (`entry_points`), every `make_cache`'s int8 refusal,
 two initialisers, the index key's epsilon.
@@ -218,6 +220,61 @@ def delta_chunked(q, k, v, g, beta, s0, chunk: int = DELTA_CHUNK):
             block, s0, (k_in, q_in, v, beta, solve, p, to_end, whole))
     o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, nc * chunk, nh, dv)
     return o[:, :s], s_last
+
+
+def recurrence_keys(q, k):
+    """(q, k) as they enter a delta rule's recurrence: each over its last
+    axis' L2 norm, q times `d_k ** -0.5`."""
+    return l2_normalised(q) * q.shape[-1] ** -0.5, l2_normalised(k)
+
+
+def head_norm(o, weight, eps: float):
+    """The RMS norm over each head's d_v (a PLAIN weight), float32."""
+    return o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                             + eps) * weight.astype(F32)
+
+
+def delta_prefill_reference(q, k, v, g, beta, s0, chunk: int, norm_weight,
+                            norm_eps: float):
+    """`delta_prefill` in plain `jax.numpy`: `recurrence_keys`, the keys
+    repeated over their value heads, `delta_chunked`, `head_norm`."""
+    q, k = recurrence_keys(q, k)
+    if (rep := v.shape[2] // q.shape[2]) > 1:
+        q, k = (jnp.repeat(t, rep, axis=2) for t in (q, k))
+    o, last = delta_chunked(q, k, v, g, beta, s0, chunk)
+    return head_norm(o, norm_weight, norm_eps), last
+
+
+def delta_prefill(q, k, v, g, beta, s0, chunk: int, norm_weight,
+                  norm_eps: float):
+    """A SERVING prefill's delta rule (a mixer's `state is not None`, S > 1;
+    never differentiated) between the convolution and the gate: the keys'
+    norms, the chunked rule over (B, S) from the stored state `s0`, the
+    heads' norm. q and k as the convolution leaves them, (B, S, Hk, dk), key
+    head j serving value heads (Hv / Hk) j .. of v (B, S, Hv, dv); `g` of
+    `beta`'s rank is a decay a head, one rank more a decay a channel.
+    Returns (o normalised, the state after).
+
+    On the chip in a one-device program, at whole lane tiles of d_k and
+    d_v, ONE Pallas call that keeps a head's state in VMEM over the blocks
+    and takes the two norms in on its way (`ops/pallas/delta_rule.py`: there
+    a head's channels are a vreg's lanes; XLA re-lays each operand twice for
+    them); elsewhere `delta_prefill_reference`. Which one a trace took is
+    counted on the telemetry hub (`delta_prefill/kernel`,
+    `delta_prefill/chunked`: counts of TRACES)."""
+    from deepspeed_tpu.ops.attention import _one_device_kernel
+    from deepspeed_tpu.ops.pallas import delta_rule
+    from deepspeed_tpu.telemetry import get_hub
+    kernel = not (q.shape[-1] % 128 or v.shape[-1] % 128 or chunk % 8) \
+        and _one_device_kernel(delta_rule.KERNEL_NAME if g.ndim == beta.ndim
+                               else delta_rule.CHANNEL_DECAY_NAME)
+    get_hub().counter("delta_prefill/" + ("kernel" if kernel else "chunked"))
+    if kernel:
+        return delta_rule.delta_rule_prefill(
+            q, k, v, g, beta, s0, chunk, norm_weight, l2_eps=L2_EPS,
+            norm_eps=norm_eps)
+    return delta_prefill_reference(q, k, v, g, beta, s0, chunk, norm_weight,
+                                   norm_eps)
 
 
 # ---------------------------------------------------------------- the shell

@@ -214,27 +214,33 @@ class KDAMixer(nn.Module):
         conv = hybrid.short_conv(conv_w, window, s)
         q, k, v = (t.reshape(b, s, nh, d) for t in
                    jnp.split(jax.nn.silu(conv), 3, axis=-1))
-        q, k = hybrid.l2_normalised(q) * d ** -0.5, hybrid.l2_normalised(k)
-
-        if state is not None and s == 1:
-            from deepspeed_tpu.ops.attention import kda_update
-            o, ssm = kda_update(state.ssm, slot, q[:, 0], k[:, 0], v[:, 0],
-                                g[:, 0], beta[:, 0])
-            o = o[:, None]
+        if state is not None and s > 1:
+            # a cached prefill, between the convolution and the gate in one
+            # call: the keys' norms, the rule on the stored state, the
+            # heads' norm
+            o, last = hybrid.delta_prefill(
+                q, k, v, g, beta, state.ssm[slot], hybrid.DELTA_CHUNK,
+                norm_w, cfg.rms_norm_eps)
+            ssm = jax.lax.dynamic_update_index_in_dim(state.ssm, last, slot,
+                                                      0)
         else:
-            s0 = (jnp.zeros((b, nh, d, d), F32) if state is None
-                  else state.ssm[slot])
-            o, last = hybrid.delta_chunked(q, k, v, g, beta, s0)
-            ssm = None if state is None else \
-                jax.lax.dynamic_update_index_in_dim(state.ssm, last, slot, 0)
+            q, k = hybrid.recurrence_keys(q, k)
+            if state is not None:
+                from deepspeed_tpu.ops.attention import kda_update
+                o, ssm = kda_update(state.ssm, slot, q[:, 0], k[:, 0],
+                                    v[:, 0], g[:, 0], beta[:, 0])
+                o = o[:, None]
+            else:       # the plain forward, which is differentiated
+                o, _ = hybrid.delta_chunked(
+                    q, k, v, g, beta, jnp.zeros((b, nh, d, d), F32))
+            # the norm over each head's d
+            o = hybrid.head_norm(o, norm_w, cfg.rms_norm_eps)
         if state is not None:
             state = state.replace(
                 ssm=ssm, conv=jax.lax.dynamic_update_index_in_dim(
                     state.conv, window[:, -(kw - 1):].astype(state.conv.dtype),
                     slot, 0))
-        # the norm over each head's d, then the gate (sigmoid, full rank)
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                              + cfg.rms_norm_eps) * norm_w.astype(F32)
+        # the gate (sigmoid, full rank)
         o = o.reshape(b, s, di) * jax.nn.sigmoid(gate.astype(F32))
         return _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
                       "o_proj")(o.astype(cfg.dtype)), state

@@ -256,40 +256,48 @@ class GatedDeltaNet(nn.Module):
         q, k, v = jnp.split(
             jax.nn.silu(hybrid.short_conv(conv_w, window, s)),
             [cfg.key_dim, 2 * cfg.key_dim], axis=-1)
-        q, k = (hybrid.l2_normalised(t.reshape(b, s, nk, dk)) for t in (q, k))
-        q = q * dk ** -0.5
-        # key head j serves value heads (nv / nk) j .. : each q / k repeated
-        q, k = (jnp.repeat(t, nv // nk, axis=2) for t in (q, k))
+        q, k = (t.reshape(b, s, nk, dk) for t in (q, k))
         v = v.reshape(b, s, nv, dv)
         new_tail = window[:, -(kw - 1):]
 
-        if state is not None and s == 1:
-            from deepspeed_tpu.ops.attention import kda_update
-            o, ssm = kda_update(state.ssm, slot, q[:, 0], k[:, 0], v[:, 0],
-                                g[:, 0], beta[:, 0])
-            o = o[:, None]
-            conv_state = jax.lax.dynamic_update_index_in_dim(
-                state.conv, new_tail.astype(state.conv.dtype), slot, 0)
-            _sow(self, "state_updates", b)
-        else:
+        if chunk:
+            # between the convolution and the gate in one call: the keys'
+            # norms, the rule from the row's stored state, the heads' norm
             at = (slot, row, 0, 0, 0)
             s0 = jax.lax.dynamic_slice(
-                state.ssm, at, (1, 1) + state.ssm.shape[2:])[0] if chunk \
-                else jnp.zeros((b, nv, dk, dv), F32)
+                state.ssm, at, (1, 1) + state.ssm.shape[2:])[0]
             with jax.named_scope("delta_prefill"):
-                o, last = hybrid.delta_chunked(q, k, v, g, beta, s0,
-                                               GDN_CHUNK)
-            if chunk:
-                ssm = jax.lax.dynamic_update_slice(state.ssm, last[None], at)
-                conv_state = jax.lax.dynamic_update_slice(
-                    state.conv, new_tail.astype(state.conv.dtype)[None],
-                    (slot, row, 0, 0))
-                _sow(self, "delta_prefill_positions", s)
+                o, last = hybrid.delta_prefill(
+                    q, k, v, g, beta, s0, GDN_CHUNK, norm_w,
+                    cfg.rms_norm_eps)
+            ssm = jax.lax.dynamic_update_slice(state.ssm, last[None], at)
+            conv_state = jax.lax.dynamic_update_slice(
+                state.conv, new_tail.astype(state.conv.dtype)[None],
+                (slot, row, 0, 0))
+            _sow(self, "delta_prefill_positions", s)
+        else:
+            # key head j serves value heads (nv / nk) j ..: each q / k
+            # repeated
+            q, k = (jnp.repeat(t, nv // nk, axis=2)
+                    for t in hybrid.recurrence_keys(q, k))
+            if state is not None:
+                from deepspeed_tpu.ops.attention import kda_update
+                o, ssm = kda_update(state.ssm, slot, q[:, 0], k[:, 0],
+                                    v[:, 0], g[:, 0], beta[:, 0])
+                o = o[:, None]
+                conv_state = jax.lax.dynamic_update_index_in_dim(
+                    state.conv, new_tail.astype(state.conv.dtype), slot, 0)
+                _sow(self, "state_updates", b)
+            else:       # the plain forward, which is differentiated
+                with jax.named_scope("delta_prefill"):
+                    o, _ = hybrid.delta_chunked(
+                        q, k, v, g, beta, jnp.zeros((b, nv, dk, dv), F32),
+                        GDN_CHUNK)
+            # the norm over each value head's d_v (a PLAIN weight)
+            o = hybrid.head_norm(o, norm_w, cfg.rms_norm_eps)
         if state is not None:
             state = state.replace(ssm=ssm, conv=conv_state)
-        # the norm over each value head's d_v (a PLAIN weight), then the gate
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                              + cfg.rms_norm_eps) * norm_w.astype(F32)
+        # the gate
         o = o.reshape(b, s, cfg.value_dim) * jax.nn.silu(z.astype(F32))
         return _dense(cfg.hidden_size, ("heads_in", "embed"), cfg.dtype,
                       "out_proj")(o.astype(cfg.dtype)), state

@@ -49,6 +49,8 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "held_combine": "held_combine",
                "kda_state_update": "kda_state_update",
                "gdn_state_update": "gdn_state_update",
+               "delta_prefill_head": "delta_rule_prefill",
+               "delta_prefill_channel": "delta_rule_prefill_channel",
                "mla_latent_decode": "mla_latent_decode",
                "mla_latent_decode_h128": "mla_latent_decode",
                "mla_dense_prefill": "mla_dense_prefill",
@@ -86,14 +88,14 @@ def compiled_kernels(monkeypatch):
     """Off the chip every kernel module answers `_interpret()` with True;
     steer them to the compiled path here, in the test."""
     from deepspeed_tpu.ops.pallas import (
-        block_sparse_attention, decode_attention, diff_attention,
+        block_sparse_attention, decode_attention, delta_rule, diff_attention,
         flash_attention, grouped_gemm, held_combine, kda, mla, mla_sparse,
         paged_attention, quantized_matmul, sparse_select, ssm)
     monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
-    for mod in (block_sparse_attention, decode_attention, diff_attention,
-                flash_attention, grouped_gemm, held_combine, kda, mla,
-                mla_sparse, paged_attention, quantized_matmul, sparse_select,
-                ssm):
+    for mod in (block_sparse_attention, decode_attention, delta_rule,
+                diff_attention, flash_attention, grouped_gemm, held_combine,
+                kda, mla, mla_sparse, paged_attention, quantized_matmul,
+                sparse_select, ssm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -166,3 +168,44 @@ def test_no_transpose_stands_round_a_prefill_attention(
     assert q_sized_copies(entry[kernel.end():]) == []
     if sliding:
         assert "f32" not in q_sized_copies(entry[:kernel.start()])
+
+
+def test_a_serving_chunks_delta_rule_is_one_kernel(v5e_chip, compiled_kernels,
+                                                   monkeypatch):
+    """One Gated DeltaNet layer as generate-longctx-linear's prefill calls
+    it (`qwen3_next.GatedDeltaNet` with the stacked state: one row's chunk of
+    2,048 positions, 16 key heads serving 32 value heads of 128), compiled
+    for the described chip: the delta rule is ONE `delta_rule_prefill` call,
+    no `while` (the plain form's scan over blocks) is left in the program,
+    and no float32 copy of an operand's size (q, k repeated and v as blocks,
+    `o` back: the plain form's `moveaxis`) stands on either side of it."""
+    from deepspeed_tpu.inference.kv_cache import RecurrentState
+    from deepspeed_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextConfig
+    from deepspeed_tpu.ops import attention as dispatch
+    monkeypatch.setattr(dispatch, "_use_pallas", lambda: True)
+    cfg = Qwen3NextConfig()
+    rows, length = 8, 2048
+    layer = GatedDeltaNet(cfg)
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=v5e_chip)
+    nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                  cfg.linear_value_head_dim)
+    params, state = jax.tree_util.tree_map(on_chip, jax.eval_shape(lambda: (
+        layer.init(jax.random.PRNGKey(0),
+                   jnp.zeros((1, 128, cfg.hidden_size), cfg.dtype)),
+        RecurrentState.create(3, rows, (nv, dk, dv),
+                              cfg.linear_conv_kernel_dim, cfg.conv_dim,
+                              cfg.dtype))))
+    x = jax.ShapeDtypeStruct((1, length, cfg.hidden_size), cfg.dtype,
+                             sharding=v5e_chip)
+    text = jax.jit(lambda p, x, state: layer.apply(
+        p, x, state, 1, 3, mutable=["counters"])[0]).lower(
+            params, x, state).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert len(calls) == 1 and calls[0].startswith("delta_rule_prefill")
+    assert " while(" not in text
+    sizes = {length * nv * dv, length * cfg.linear_num_key_heads * dk}
+    copies = [dims for dtype, dims in re.findall(
+        r"= (\w+)\[([\d,]+)\]\S* copy\(", text[text.index("\nENTRY "):])
+        if dtype == "f32" and math.prod(map(int, dims.split(","))) in sizes]
+    assert copies == []
