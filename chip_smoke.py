@@ -83,9 +83,12 @@ class Sizes:
     # a state-space decode step: recurrent layers, rows, heads, head dim,
     # state size, groups (Nemotron-3-Nano's at the benchmark cell's batch)
     ssm: Tuple[int, int, int, int, int, int]
-    # the grouped GEMM at a DECODE shape: rows, experts, K, N; 3 rows an
-    # expert, the rest of the rows no expert's (absent assignments)
-    gmm_decode: Tuple[int, int, int, int]
+    # the grouped GEMM at DECODE shapes, (name, rows, experts, K, N) each:
+    # 1 to 7 rows an expert, so that groups straddle the 16-row tiles, the
+    # rest of the rows no expert's (absent assignments); Nemotron-3-Nano's
+    # up projection, Ling's, DeepSeek-V3.2's and openPangu's (the longest K
+    # a cell has: too long for one K tile, `held_tiling` keeps its K tiles)
+    gmm_decode: Tuple[Tuple[str, int, int, int, int], ...]
     # a held expert layer at a PREFILL chunk's shape: tokens, top k, hidden,
     # expert width, held experts, the router's experts (DeepSeek-V3.2's chunk
     # of 2,048 tokens on a chip that holds 16 of 256)
@@ -156,7 +159,11 @@ FULL = Sizes(preset="qwen2-3b", train_layers=4, seq=2048, loss_chunk=1024,
              paged_batch=64, paged_blocks=96,
              prefill_batch=8, parked=(48, 18, 16), gmm_rows=4096,
              gmm_experts=64, gmm_width=1024, qmm_group=256,
-             ssm=(6, 64, 64, 64, 128, 8), gmm_decode=(384, 64, 2688, 1856),
+             ssm=(6, 64, 64, 64, 128, 8),
+             gmm_decode=(("", 384, 64, 2688, 1856),
+                         ("_ling", 1024, 128, 2560, 768),
+                         ("_deepseek", 64, 16, 7168, 2048),
+                         ("_openpangu", 64, 16, 7680, 2048)),
              held_rows=(2048, 8, 7168, 2048, 16, 256),
              held_rows_long=(16384, 8, 2048, 1024, 16, 128),
              diff_stack=((8, 64, 10, 512, 128), (1, 64, 10, 2816, 128)),
@@ -180,7 +187,8 @@ TINY = Sizes(preset="qwen2-tiny", train_layers=2, seq=64, loss_chunk=32,
              decode_ctx=64, dense_stack=((3, 2, 8, 64), (2, 4, 16, 32)),
              paged_batch=3, paged_blocks=9, prefill_batch=2,
              parked=(7, 4, 8), gmm_rows=64, gmm_experts=4, gmm_width=32,
-             qmm_group=32, ssm=(2, 4, 4, 8, 16, 2), gmm_decode=(32, 4, 32, 48),
+             qmm_group=32, ssm=(2, 4, 4, 8, 16, 2),
+             gmm_decode=(("", 32, 4, 32, 48), ("_ling", 32, 4, 48, 32)),
              held_rows=(288, 4, 64, 32, 1, 16),
              held_rows_long=(576, 4, 128, 32, 1, 16),
              diff_stack=((2, 3, 2, 16, 32), (1, 3, 2, 48, 32)),
@@ -266,7 +274,8 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
                                                            kv_write_dense)
     from deepspeed_tpu.ops.pallas import flash_attention as flash
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    from deepspeed_tpu.ops.pallas.grouped_gemm import grouped_gemm
+    from deepspeed_tpu.ops.pallas.grouped_gemm import (grouped_gemm,
+                                                       held_tiling)
     from deepspeed_tpu.ops.pallas.held_combine import held_combine
     from deepspeed_tpu.ops.pallas.kda import (kda_state_update,
                                               kda_state_update_reference)
@@ -731,31 +740,36 @@ def kernel_cases(sz: Sizes) -> List[KernelCase]:
     cases.append(KernelCase("grouped_gemm", grouped_gemm, gmm_ref, make_gmm))
 
     # the same kernel as a held expert layer calls it at decode: a 16-row
-    # tile, 3 rows an expert, and rows after the last group that are no
-    # expert's and must cost nothing (moe/sharded_moe.held_dispatch_gmm)
-    drows, dne, dk, dn = sz.gmm_decode
+    # tile and the whole K (`held_tiling`), groups that straddle the row
+    # tiles, and rows after the last group that are no expert's and must
+    # cost nothing (moe/sharded_moe.held_dispatch_gmm)
+    def gmm_decode_case(suffix, drows, dne, dk, dn):
+        sizes = 1 + (np.arange(dne) * 5) % 7
+        held = int(sizes.sum())
+        assert held <= drows
 
-    def make_gmm_decode(key):
-        kl, kr = jax.random.split(key)
-        return (normal(kl, (drows, dk)), normal(kr, (dne, dk, dn)) * 0.05,
-                jnp.full((dne,), 3, jnp.int32))
+        def make(key):
+            kl, kr = jax.random.split(key)
+            return (normal(kl, (drows, dk)), normal(kr, (dne, dk, dn)) * 0.05,
+                    jnp.asarray(sizes, jnp.int32))
 
-    def held_rows(out):
-        return jnp.where(jnp.arange(drows)[:, None] < 3 * dne, out, 0)
+        def held_rows(out):
+            return jnp.where(jnp.arange(drows)[:, None] < held, out, 0)
 
-    def gmm_decode(lhs, rhs, sizes):
-        return held_rows(grouped_gemm(lhs, rhs, sizes,
-                                      tiling=(16, min(dk, 1024), min(dn, 1024))))
+        def fn(lhs, rhs, sizes):
+            return held_rows(grouped_gemm(lhs, rhs, sizes,
+                                          tiling=held_tiling(16, dk, dn)))
 
-    def gmm_decode_ref(lhs, rhs, sizes):
-        w = jnp.repeat(rhs, 3, axis=0, total_repeat_length=3 * dne)
-        out = jnp.einsum("mk,mkn->mn", lhs[:3 * dne], w,
-                         preferred_element_type=jnp.float32)
-        return held_rows(jnp.pad(out, ((0, drows - 3 * dne), (0, 0)))
-                         ).astype(lhs.dtype)
+        def ref(lhs, rhs, sizes):
+            w = jnp.repeat(rhs, sizes, axis=0, total_repeat_length=held)
+            out = jnp.einsum("mk,mkn->mn", lhs[:held], w,
+                             preferred_element_type=jnp.float32)
+            return held_rows(jnp.pad(out, ((0, drows - held), (0, 0)))
+                             ).astype(lhs.dtype)
 
-    cases.append(KernelCase("grouped_gemm_decode", gmm_decode, gmm_decode_ref,
-                            make_gmm_decode))
+        return KernelCase(f"grouped_gemm_decode{suffix}", fn, ref, make)
+
+    cases.extend(gmm_decode_case(*shape) for shape in sz.gmm_decode)
 
     # a held expert layer at a prefill chunk's shape and at a long prefill's:
     # sorted rows sized by the chip's share (`held_row_bound`), against the

@@ -322,9 +322,8 @@ class AfmoeForCausalLM(nn.Module):
     cfg: AfmoeConfig
     # what the layers count inside a serving program, summed over the call by
     # the engine (`serving` event)
-    program_counters = ("assignments", "held_assignments", "held_wide_calls",
-                        "experts_touched", "experts_held",
-                        "kv_positions_window", "kv_positions_attended")
+    program_counters = hybrid.EXPERT_COUNTERS + (
+        "kv_positions_window", "kv_positions_attended")
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

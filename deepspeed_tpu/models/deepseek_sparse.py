@@ -278,9 +278,8 @@ class DeepseekSparseForCausalLM(nn.Module):
     cfg: DeepseekSparseConfig
     # what the layers count inside a serving program, summed over the call by
     # the engine (`serving` event)
-    program_counters = ("assignments", "held_assignments", "held_wide_calls",
-                        "experts_touched", "experts_held",
-                        "kv_positions_live", "kv_positions_selected")
+    program_counters = hybrid.EXPERT_COUNTERS + (
+        "kv_positions_live", "kv_positions_selected")
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

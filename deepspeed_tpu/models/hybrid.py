@@ -93,6 +93,13 @@ class DenseFFN(nn.Module):
                       "down_proj")(jax.nn.silu(gate) * up)
 
 
+# What `moe/layer.MoE._held` counts a call, the head of every held-expert
+# family's `program_counters` (the engine sums them inside its generate
+# programs: docs/telemetry.md)
+EXPERT_COUNTERS = ("assignments", "held_assignments", "held_wide_calls",
+                   "experts_touched", "experts_held", "weight_tile_revisits")
+
+
 def held_experts(cfg, name: str, *, held: int, activation: str, score_fn: str,
                  shared=None, shared_gate: bool = False):
     """The expert layer as `moe/layer.MoE` computes it: `held` experts (the

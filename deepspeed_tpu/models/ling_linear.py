@@ -367,8 +367,7 @@ class LingLinearForCausalLM(nn.Module):
     cfg: LingLinearConfig
     # what the expert layers count inside a serving program, summed over the
     # call by the engine (`serving` event)
-    program_counters = ("assignments", "held_assignments", "held_wide_calls",
-                        "experts_touched", "experts_held")
+    program_counters = hybrid.EXPERT_COUNTERS
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

@@ -371,7 +371,7 @@ class NemotronHForCausalLM(nn.Module):
     # what the expert layers count inside a serving program, summed over the
     # call by the engine (`serving` event: `assignments`, `held_assignments`,
     # `held_wide_calls`)
-    program_counters = ("assignments", "held_assignments", "held_wide_calls")
+    program_counters = hybrid.EXPERT_COUNTERS
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

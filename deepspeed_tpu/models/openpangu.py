@@ -210,8 +210,7 @@ class OpenPanguForCausalLM(nn.Module):
     cfg: OpenPanguConfig
     # what the layers count inside a serving program, summed over the call by
     # the engine (`serving` event)
-    program_counters = ("assignments", "held_assignments", "held_wide_calls",
-                        "experts_touched", "experts_held")
+    program_counters = hybrid.EXPERT_COUNTERS
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

@@ -413,10 +413,8 @@ class Qwen3NextForCausalLM(nn.Module):
     cfg: Qwen3NextConfig
     # what the layers count inside a serving program, summed over the call by
     # the engine (`serving` event)
-    program_counters = ("assignments", "held_assignments", "held_wide_calls",
-                        "experts_touched", "experts_held",
-                        "delta_prefill_positions", "state_updates",
-                        "kv_positions_attended")
+    program_counters = hybrid.EXPERT_COUNTERS + (
+        "delta_prefill_positions", "state_updates", "kv_positions_attended")
 
     @nn.compact
     def __call__(self, input_ids, labels=None, cache=None):

@@ -116,7 +116,7 @@ class Experts(nn.Module):
         if group_sizes is not None or x is None:
             from jax.ad_checkpoint import checkpoint_name
             from deepspeed_tpu.ops.pallas.grouped_gemm import (
-                grouped_gemm, sharded_grouped_gemm)
+                grouped_gemm, held_tiling, sharded_grouped_gemm)
             from deepspeed_tpu.ops.pallas.sharded import kernel_fallback
             mesh, ep = _gmm_mesh(e)
             if ep == 0:
@@ -133,8 +133,8 @@ class Experts(nn.Module):
                 # a Pallas call is not a dot, so plain checkpoint_dots
                 # recomputes the whole grouped FFN in backward
                 # (remat_policy='checkpoint_dots_gmm' in models/llama.py)
-                tiling = None if tm is None else (
-                    tm, min(lhs.shape[1], 1024), min(rhs.shape[2], 1024))
+                tiling = None if tm is None else held_tiling(
+                    tm, lhs.shape[1], rhs.shape[2], rhs.dtype.itemsize)
                 out = (sharded_grouped_gemm(lhs, rhs, sizes, mesh,
                                             tiling=tiling)
                        if mesh is not None
@@ -382,8 +382,11 @@ class MoE(nn.Module):
         held rows passed the bound the call was sized for,
         `sharded_moe.held_row_bound`, and the full-width body ran), and
         `experts_touched` of `experts_held`: the held experts that received
-        an assignment, whose weights the call reads (collection
-        `counters`)."""
+        an assignment, whose weights the call reads, and
+        `weight_tile_revisits`: the grid steps of a decode-sized call's
+        grouped GEMM that found their expert's weights resident
+        (`grouped_gemm.weight_tile_revisits`; collection `counters`)."""
+        from deepspeed_tpu.ops.pallas.grouped_gemm import weight_tile_revisits
         t, d = x.shape
         count, k = self.held_experts, self.k
         experts = Experts(count, d, f, self.dtype, self.activation,
@@ -396,12 +399,16 @@ class MoE(nn.Module):
             # at every size; a partitioned mesh takes the XLA buffer path.
             # Measured on the chip at the decode shape (64 rows, 64 held of
             # 128, top 6, 60 experts touched; PERF.md, PR 41): ALONE the buffer
-            # path's batched matmul is quicker, 1.88 against 2.16 ms, but in a
-            # program whose prefill runs the grouped GEMM it wants the experts'
-            # weights in another layout and the compiler keeps a second copy
-            # of them (temporaries 2.37 -> 5.88 GB beside 9.3 GB of weights).
+            # path's batched matmul is quicker, 1.88 against 2.16 ms (read
+            # again at PR 66, 63 touched: 1.73 against 1.92, and 1.79 with the
+            # whole contraction as one K tile, `grouped_gemm.held_tiling`),
+            # but in a program whose prefill runs the grouped GEMM it wants the
+            # experts' weights in another layout and the compiler keeps a
+            # second copy of them (temporaries 2.37 -> 5.88 GB beside 9.3 GB of
+            # weights). At Ling's and DeepSeek's shares the grouped GEMM is
+            # the quicker alone too (1.72 and 0.59 against 2.15 and 1.87 ms).
             impl = "gmm" if _unpartitioned_mesh() else "ragged"
-        wide = 0
+        wide, tm = 0, None
         if impl == "gmm":
             tm = held_row_tile(t * k, self.num_experts)
             out, held, wide = held_dispatch_gmm(
@@ -427,11 +434,13 @@ class MoE(nn.Module):
             out = out + shared
         total = t * k if valid is None else k * jnp.sum(valid.astype(jnp.int32))
         local = held_assignments(topk_idx, self.held_offset, count, valid)[1]
-        touched = jnp.sum(held_group_sizes(local, count) > 0)
+        sizes = held_group_sizes(local, count)
         for name, value in (("assignments", total), ("held_assignments", held),
                             ("held_wide_calls", wide),
-                            ("experts_touched", touched),
-                            ("experts_held", count)):
+                            ("experts_touched", jnp.sum(sizes > 0)),
+                            ("experts_held", count),
+                            ("weight_tile_revisits",
+                             weight_tile_revisits(sizes, tm))):
             self.sow("counters", name, jnp.asarray(value, jnp.int32),
                      init_fn=lambda: jnp.zeros([], jnp.int32),
                      reduce_fn=lambda a, b_: a + b_)

@@ -340,8 +340,13 @@ def held_row_tile(rows: int, num_experts: int) -> int:
     expects. A tile is multiplied, masked, against every expert whose rows
     lie in it, and an expert's weights are read once a tile its rows touch;
     on this chip the two costs meet there (64 rows an expert: 256 against
-    128, 512 and 64, PERF.md, PR 59). 16 (Mosaic's bf16 minimum) at decode:
-    a tile is then one expert's alone."""
+    128, 512 and 64, PERF.md, PR 59). 16 (Mosaic's bf16 minimum) at decode,
+    where an expert expects fewer than 8 rows. A tile is NOT one expert's
+    alone there: the sorted rows lie contiguous from row 0, so a tile holds
+    five experts of 3 rows and an expert of `s` rows straddles a boundary
+    with probability `(s - 1) / 16`; at this tile the grouped GEMM takes the
+    whole contraction as one K tile (`grouped_gemm.held_tiling`), so that
+    the straddling expert's second visit finds its weights resident."""
     return max(16, min(512, 1 << (2 * rows // num_experts).bit_length()))
 
 

@@ -14,7 +14,8 @@ weight grad), which tiles group-irregular row spans onto the MXU with
 per-tile store masks. This wrapper owns the policy bits:
 
 - tiling selection (swept on v5e at the qwen2-moe proxy shape by an r5
-  probe since deleted),
+  probe since deleted; a held expert layer's by its call's shape,
+  `held_tiling`: the whole contraction as one K tile for decode-sized rows),
 - padding rows up to an m-tile multiple (padding rows are appended to the
   LAST group; they multiply zeros and their outputs are dropped),
 - interpret-mode fallback so CPU golden tests run the same code path.
@@ -38,6 +39,62 @@ def default_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     tm never drops below 16 — Mosaic's bf16 sublane minimum — so
     decode-sized row counts pad up instead of requesting a tiny tile."""
     return (max(16, min(m, 512)), min(k, 1024), min(n, 1024))
+
+
+# `held_tiling`'s rule. The row tile of a call whose experts expect fewer
+# than 8 rows each (`sharded_moe.held_row_tile`'s floor: every decode step),
+# and the bytes two buffers of a weight tile may take of this chip's 16 MiB
+# of default scoped VMEM (megablox passes no `vmem_limit_bytes`); the rest
+# holds the rows' and the result's tiles and the float32 accumulator.
+DECODE_ROW_TILE = 16
+WEIGHT_TILES_BYTES = 12 << 20
+
+
+def held_tiling(tm: int, k: int, n: int, itemsize: int = 2
+                ) -> Tuple[int, int, int]:
+    """Tile sizes for a held expert layer's call (`moe/layer.py`) whose row
+    tile is `tm`, by shape alone.
+
+    At `DECODE_ROW_TILE` the WHOLE contraction is one K tile, where two
+    buffers of `k x min(n, 1024)` weights fit `WEIGHT_TILES_BYTES` (bf16: K
+    up to 3,072). The sorted rows lie contiguous from row 0, aligned to
+    nothing, so an expert of `s` rows straddles a row tile with probability
+    `(s - 1) / 16` and is then two grid steps. The grid is (N tiles, row
+    tiles x experts, K tiles) with K innermost and the weights' block index
+    `(expert, k_i, n_i)`: with ONE K tile the second step's index is the
+    first's, the pipeline skips the fetch and the expert's weights are read
+    once; with several, the second step starts again at `(expert, 0, n_i)`
+    and reads them all a second time (8 of the 63 experts a Nemotron decode
+    call touches: 1.92 -> 1.79 ms a call with one K tile, 1.72 with each
+    group padded to whole row tiles; PERF.md, PR 66).
+
+    Any other `tm` (a prefill's experts span many row tiles by design), or a
+    K too long for the widest N tile: 1,024 x 1,024 as swept (PERF.md, PR
+    59), remainders masked in-kernel (the mask read as free: it hides under
+    the tile's copy). A NARROWER N tile under the whole K loses at every
+    shape read (more grid steps, the rows' tile fetched again each), and at
+    K 7,168, where 512 columns are the most that fit, a decode step of 5
+    held rows read +5.6% in its cell: such a K keeps its K tiles."""
+    tn = min(n, 1024)
+    if tm == DECODE_ROW_TILE and 2 * k * tn * itemsize <= WEIGHT_TILES_BYTES:
+        return (tm, k, tn)
+    return (tm, min(k, 1024), tn)
+
+
+def weight_tile_revisits(group_sizes: jnp.ndarray, tm: int) -> jnp.ndarray:
+    """Grid steps of a call under `held_tiling`'s decode rule that find their
+    expert's weights resident: the sum over the groups that have rows of (row
+    tiles of `tm` their rows reach - 1), the groups contiguous from row 0.
+    Each was a second read of the expert's weights under K tiles (and still
+    is for a projection whose K is too long for one tile). 0 at any other
+    `tm`, where the rule keeps K tiles."""
+    if tm != DECODE_ROW_TILE:
+        return jnp.zeros((), jnp.int32)
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    starts = ends - group_sizes
+    return jnp.sum(jnp.where(group_sizes > 0,
+                             (ends - 1) // tm - starts // tm, 0),
+                   dtype=jnp.int32)
 
 
 def grouped_gemm(lhs: jnp.ndarray,

@@ -67,6 +67,12 @@ def test_generate_is_the_greedy_walk_of_the_plain_forward(hub):
     # two expert layers, a prefill of 11 and 5 decode steps of 1, 3 rows, k 2
     assert event["assignments"] == 2 * 3 * (11 + 5) * 2
     assert 0 < event["held_assignments"] < event["assignments"]
+    # what the other held-expert families count too (PR 66): the held
+    # experts a pass reads, of those held a layer a pass (a prefill and 5
+    # decode steps), and the grouped GEMM's second row tiles an expert
+    assert event["experts_held"] == 2 * (1 + 5) * CFG.n_routed_experts
+    assert 0 < event["experts_touched"] <= event["experts_held"]
+    assert 0 <= event["weight_tile_revisits"] <= event["held_assignments"]
     # a benchmark reads the same off a hub that writes no stream
     from deepspeed_tpu.telemetry import get_hub
     assert get_hub().gauges["serving_v1/state_bytes"] == event["state_bytes"]

@@ -359,3 +359,38 @@ def test_the_counts_are_bincounts_without_a_scatter(count, padded, routing):
     bins = jnp.bincount(mine.reshape(-1), length=count + 1)[:count]
     assert int(sown["counters"]["experts_touched"]) == int((bins > 0).sum())
     assert int(sown["counters"]["held_assignments"]) == int(bins.sum())
+    # 1,152 assignments over 16 experts: a row tile of 256, K tiles kept, and
+    # no grid step that finds its weights resident
+    assert int(sown["counters"]["weight_tile_revisits"]) == 0
+
+
+@pytest.mark.parametrize("impl", ["gmm", "ragged"])
+def test_a_decode_sized_call_counts_the_row_tiles_a_group_straddles(impl):
+    """15 tokens x top 4 over 16 experts, 8 held: a row tile of 16 and about
+    30 held rows, so a group reaches a second row tile. The layer's
+    `weight_tile_revisits` is the count `held_group_sizes` gives by the rule
+    (`grouped_gemm.weight_tile_revisits`), and 0 through the buffer path,
+    which runs no grouped GEMM."""
+    import numpy as np
+    from deepspeed_tpu.moe import sharded_moe as sm
+    t, count = 15, 8
+    assert sm.held_row_tile(t * NK, NE) == 16
+    moe = MoE(hidden_size=D, num_experts=NE, k=NK, intermediate_size=F,
+              drop_tokens=False, dtype=jnp.float32, activation="relu2",
+              dispatch_impl=impl, score_fn="sigmoid", held_offset=0,
+              held_experts=count)
+    x = jax.random.normal(jax.random.PRNGKey(66), (t, 1, D))
+    params = nn.meta.unbox(moe.init(jax.random.PRNGKey(4), x,
+                                    train=False)["params"])
+    params["gate"]["wg"] = params["gate"]["wg"] * 40.0
+    _, sown = moe.apply({"params": params}, x, train=False,
+                        mutable=["counters"])
+    _, chosen = route_topk(x[:, 0] @ params["gate"]["wg"], NK, "sigmoid")
+    sizes = np.bincount(np.asarray(sm.held_assignments(chosen, 0, count)[1])
+                        .reshape(-1), minlength=count + 1)[:count]
+    ends = np.cumsum(sizes)
+    want = sum(int((e - 1) // 16 - (e - s) // 16)
+               for e, s in zip(ends, sizes) if s)
+    assert want > 0
+    assert int(sown["counters"]["weight_tile_revisits"]) == \
+        (want if impl == "gmm" else 0)

@@ -44,6 +44,9 @@ OTHER_NAMES = {"ssm_state_update": "ssm_state_update",
                "diff_attn_window_decode": "diff_attn_window_decode",
                "diff_attn_shared_decode": "diff_attn_shared_decode",
                "grouped_gemm_decode": "gmm",
+               "grouped_gemm_decode_ling": "gmm",
+               "grouped_gemm_decode_deepseek": "gmm",
+               "grouped_gemm_decode_openpangu": "gmm",
                "held_rows": "held_combine",
                "held_rows_long": "held_combine",
                "held_combine": "held_combine",
